@@ -96,7 +96,7 @@ def worker(args):
                       cache_bound=args.bound)
     executor = ht.Executor(eval_nodes, comm_mode=args.comm_mode, **kwargs)
 
-    results = {}
+    results = {"epoch_losses": [], "epoch_times": []}
     for ep in range(args.nepoch):
         ep_st = time.perf_counter()
         train_loss, train_acc, train_auc = [], [], []
@@ -132,8 +132,10 @@ def worker(args):
             msg += f" | {ep_time:.2f}s/epoch, {sps:.0f} samples/sec"
         print(msg, flush=True)
         results.update(epoch_time=ep_time, samples_per_sec=sps)
+        results["epoch_times"].append(ep_time)
         if args.all and train_loss:
             results.update(loss=float(np.mean(train_loss)))
+            results["epoch_losses"].append(float(np.mean(train_loss)))
         if args.val:
             val_loss, val_acc, val_auc = [], [], []
             for _ in range(executor.get_batch_num("validate")):

@@ -33,7 +33,9 @@ def synthetic_batch(rng, batch, seq_len, vocab):
             next_sentence_label)
 
 
-def run(args):
+def build(args):
+    """Graph and session for the parsed arguments:
+    ``(executor, feed_nodes)``."""
     import jax.numpy as jnp
 
     cfg = M.BertConfig(
@@ -60,11 +62,20 @@ def run(args):
     executor = ht.Executor(
         [loss, train_op], comm_mode=args.comm_mode,
         dtype=None if args.fp32 else jnp.bfloat16)
-
-    rng = np.random.RandomState(0)
     feed_nodes = (input_ids, token_type_ids, attention_mask, mlm_labels,
                   nsp_label)
-    results = {}
+    return executor, feed_nodes
+
+
+def run(args, session=None):
+    """Train for ``args.num_steps``; ``session`` is a :func:`build`
+    result to train (callers that inspect the executor afterwards).
+    Returns the last logged loss and throughput plus every logged
+    window's ``losses`` and ``window_seconds``."""
+    executor, feed_nodes = session or build(args)
+
+    rng = np.random.RandomState(0)
+    results = {"losses": [], "window_seconds": []}
     t0 = time.perf_counter()
     window_tokens = 0
     for step in range(args.num_steps):
@@ -82,6 +93,8 @@ def run(args):
                 msg += f", {tps:.0f} tokens/sec"
             print(msg, flush=True)
             results.update(loss=loss_val, tokens_per_sec=tps)
+            results["losses"].append(loss_val)
+            results["window_seconds"].append(dt)
             t0 = time.perf_counter()
             window_tokens = 0
     return results

@@ -47,8 +47,9 @@ def load_corpus(args):
     return flat[:nseq * args.seq_len].reshape(nseq, args.seq_len)
 
 
-def main(args):
-    data = load_corpus(args)
+def build_graph(args):
+    """The causal-LM training graph for the parsed arguments:
+    ``(config, ids, labels, lm_loss, train_op)``."""
     cfg = GPTConfig(
         vocab_size=args.vocab_size, hidden_size=args.hidden_size,
         num_hidden_layers=args.num_layers,
@@ -64,19 +65,30 @@ def main(args):
     lm_loss = ht.reduce_mean_op(loss, [0, 1])
     opt = ht.optim.AdamOptimizer(learning_rate=args.learning_rate)
     train_op = opt.minimize(lm_loss)
+    return cfg, ids, labels, lm_loss, train_op
+
+
+def batches(args, data):
+    """(input, next-token label) batches of one epoch. Labels are the
+    input shifted by one; the final position has no next token — pad
+    with the sparse-CE op's ignored_index so it trains nothing."""
+    for b in range(max(1, len(data) // args.batch_size)):
+        x = data[b * args.batch_size:(b + 1) * args.batch_size]
+        y = np.concatenate(
+            [x[:, 1:], np.full((len(x), 1), -1, np.int64)], axis=1)
+        yield x, y
+
+
+def main(args):
+    data = load_corpus(args)
+    _, ids, labels, lm_loss, train_op = build_graph(args)
     executor = ht.Executor([lm_loss, train_op])
 
-    nbatch = max(1, len(data) // args.batch_size)
     results = {}
     for epoch in range(args.nepoch):
         t0 = time.time()
         losses = []
-        for b in range(nbatch):
-            x = data[b * args.batch_size:(b + 1) * args.batch_size]
-            # shift by one; the final position has no next token — pad
-            # with the sparse-CE op's ignored_index so it trains nothing
-            y = np.concatenate(
-                [x[:, 1:], np.full((len(x), 1), -1, np.int64)], axis=1)
+        for x, y in batches(args, data):
             out = executor.run(feed_dict={ids: x, labels: y},
                                convert_to_numpy_ret_vals=True)
             losses.append(float(out[0]))

@@ -60,21 +60,29 @@ def parse_bytes(value):
 
 
 def resolve_budget(budget=None):
-    """Explicit budget > HETU_HBM_BUDGET > device bytes_limit > None."""
+    """Explicit budget > HETU_HBM_BUDGET > device bytes_limit. ``None``
+    only on the CPU backend, which reports no device memory; an
+    accelerator whose ``memory_stats()`` lacks ``bytes_limit`` raises —
+    sizing a pool against a guess is worse than not starting."""
     if budget is not None:
         return parse_bytes(budget)
     env = os.environ.get("HETU_HBM_BUDGET")
     if env:
         return parse_bytes(env)
-    try:
-        import jax
-        limits = [int(d.memory_stats().get("bytes_limit", 0))
-                  for d in jax.local_devices() if d.memory_stats()]
-        if limits and min(limits) > 0:
-            return min(limits)
-    except Exception:       # noqa: BLE001 — backend-optional API
-        pass
-    return None
+    import jax
+    limits = []
+    for d in jax.local_devices():
+        if d.platform == "cpu":
+            return None
+        stats = d.memory_stats()
+        limit = int(stats.get("bytes_limit", 0)) if stats else 0
+        if limit <= 0:
+            raise RuntimeError(
+                f"{d}: memory_stats() reports no bytes_limit "
+                f"({stats!r}); pass an explicit budget or set "
+                f"HETU_HBM_BUDGET")
+        limits.append(limit)
+    return min(limits)
 
 
 def _nbytes(shape, itemsize=4):

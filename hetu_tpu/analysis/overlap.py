@@ -1,8 +1,8 @@
 """Pass 5 — host-overlap advisory (HT5xx).
 
 A PS-backed graph is feed-bound by construction: every step moves ids,
-feeds and embedding rows over the host link (the BENCH_r04/r05
-"feed-transfer-bound" caveat). The async ingest engine
+feeds and embedding rows from host to device (the
+"feed-transfer-bound" caveat of the round-4/5 records). The async ingest engine
 (``hetu_tpu/ingest.py``) exists to hide exactly that — so a config that
 is known feed-bound but runs with the engine off, or drives the session
 through a plain per-step ``run()`` loop that never reaches the

@@ -51,8 +51,8 @@ def default_db_path():
     p = os.environ.get("HETU_RANGEDB")
     if p:
         return p
-    return os.path.join(os.path.expanduser("~"), ".cache", "hetu_tpu",
-                        "ranges.json")
+    from ..cachedir import store_path
+    return store_path("ranges.json")
 
 
 class RangeDB:
@@ -298,7 +298,8 @@ def main(argv=None):
     parser.add_argument("--every-n", type=int, default=1)
     parser.add_argument("--db", default=None, metavar="PATH",
                         help="range DB path (default: $HETU_RANGEDB or "
-                             "~/.cache/hetu_tpu/ranges.json)")
+                             "the in-checkout store, "
+                             "hetu_tpu/cachedir.py)")
     parser.add_argument("--json", action="store_true")
     args = parser.parse_args(argv)
 

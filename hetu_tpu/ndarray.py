@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "DLContext", "cpu", "gpu", "tpu", "rcpu", "rgpu", "rtpu",
-    "is_gpu_ctx", "is_tpu_ctx", "device_backend",
+    "is_gpu_ctx", "is_tpu_ctx", "device_backend", "cpu_pinned",
     "NDArray", "array", "empty", "sparse_array", "ND_Sparse_Array",
     "IndexedSlices",
 ]
@@ -30,16 +30,12 @@ __all__ = [
 _DEVICE_KINDS = ("cpu", "tpu")
 
 
-def _accelerator_platform():
-    """Best accelerator platform available in this process."""
-    try:
-        backends = jax.local_devices()
-    except RuntimeError:
-        return "cpu"
-    for d in backends:
-        if d.platform != "cpu":
-            return d.platform
-    return "cpu"
+def cpu_pinned():
+    """True when this process was explicitly pinned to the CPU platform
+    (``JAX_PLATFORMS=cpu`` or ``jax.config.update("jax_platforms",
+    "cpu")`` — the test harness and chip-free rehearsals). Only then may
+    an accelerator context stand for a virtual CPU device."""
+    return (jax.config.jax_platforms or "").split(",")[0] == "cpu"
 
 
 class DLContext:
@@ -66,17 +62,33 @@ class DLContext:
         return self.kind != "cpu"
 
     def jax_device(self):
-        """Resolve to a concrete local jax device (best effort)."""
-        platform = self.kind if self.kind != "tpu" else _accelerator_platform()
-        try:
-            devs = [d for d in jax.local_devices() if
-                    (d.platform == platform or
-                     (self.kind == "tpu" and d.platform != "cpu"))]
-        except RuntimeError:
-            devs = []
-        if not devs:
-            devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+        """The concrete local jax device this context names.
+
+        ``tpu(i)`` is the i-th accelerator of this process. It stands for
+        the i-th virtual CPU device only in a process pinned to the CPU
+        platform (:func:`cpu_pinned`); anywhere else a missing
+        accelerator is an error, never a quiet CPU placement. ``cpu(i)``
+        is a logical host placement (PS-routed tables, dataloader
+        buffers): its arrays live on the i-th device of the default
+        backend so feeds need no second hop. An index beyond the devices
+        present raises — it never wraps onto device 0."""
+        devs = jax.local_devices()
+        if self.kind == "tpu":
+            accel = [d for d in devs if d.platform != "cpu"]
+            if accel:
+                devs = accel
+            elif not cpu_pinned():
+                raise RuntimeError(
+                    f"{self!r}: no accelerator in this process (default "
+                    f"backend {jax.default_backend()!r}); pin the CPU "
+                    f"platform with JAX_PLATFORMS=cpu to run accelerator "
+                    f"contexts on virtual CPU devices")
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError(
+                f"{self!r}: device index {self.device_id} out of range, "
+                f"this process has {len(devs)} "
+                f"{devs[0].platform} device(s)")
+        return devs[self.device_id]
 
     def relocalize(self):
         self.hostname = "localhost"
@@ -132,7 +144,7 @@ def is_tpu_ctx(ctx):
 
 def device_backend(ctx=None):
     if ctx is None or ctx.is_accelerator():
-        return _accelerator_platform()
+        return jax.default_backend()
     return "cpu"
 
 

@@ -64,13 +64,25 @@ def decode_attention(q, k_cache, v_cache, pos, sm_scale):
                       v_cache)
 
 
+def _gather_pool_rows(k_pool, v_pool, slot_idx, heads_dim):
+    """K/V rows at ``slot_idx`` ``[B, S]`` of one layer's pooled cache,
+    as ``[B, S, H, D]``. The pool keeps a row's heads side by side
+    (``H*D`` minor, serving/kvcache.py); only the gathered rows are
+    split back into heads."""
+    out = []
+    for pool in (k_pool, v_pool):
+        rows = pool.reshape(-1, pool.shape[-1])[slot_idx]
+        out.append(rows.reshape(*slot_idx.shape, *heads_dim))
+    return out
+
+
 def paged_decode_attention(q, k_pool, v_pool, slot_idx, positions,
                            sm_scale):
     """One query token per sequence against a block-paged KV pool.
 
     ``q`` is ``[B, H, D]``; ``k_pool`` / ``v_pool`` are one layer's
-    pooled cache, either ``[num_blocks, block_size, H, D]`` or already
-    flattened ``[num_blocks * block_size, H, D]``; ``slot_idx`` is
+    pooled cache, either ``[num_blocks, block_size, H*D]`` or already
+    flattened ``[num_blocks * block_size, H*D]``; ``slot_idx`` is
     ``[B, S]`` int32 — the flat pool slot holding position ``j`` of
     sequence ``b`` (serving/kvcache.py block-table math, computed
     host-side; out-of-range positions point at the scratch block);
@@ -84,11 +96,7 @@ def paged_decode_attention(q, k_pool, v_pool, slot_idx, positions,
     holds only the blocks live sequences actually use. Causality/
     raggedness is the ``j <= positions[b]`` validity mask — scratch
     rows gathered past a sequence's length sit behind it."""
-    if k_pool.ndim == 4:
-        k_pool = k_pool.reshape(-1, *k_pool.shape[2:])
-        v_pool = v_pool.reshape(-1, *v_pool.shape[2:])
-    k = k_pool[slot_idx]                                # [B, S, H, D]
-    v = v_pool[slot_idx]
+    k, v = _gather_pool_rows(k_pool, v_pool, slot_idx, q.shape[-2:])
     scores = jnp.einsum("bhd,bshd->bhs", q * sm_scale, k)
     valid = jnp.arange(slot_idx.shape[1])[None, :] <= positions[:, None]
     scores = jnp.where(valid[:, None, :], scores, -1e9)
@@ -103,20 +111,16 @@ def paged_prefill_attention(q, k_pool, v_pool, slot_idx, starts,
 
     ``q`` is ``[B, C, H, D]`` — ``C`` consecutive query positions per
     sequence starting at ``starts[b]`` (0-based); ``k_pool`` /
-    ``v_pool`` are one layer's pooled cache (4D blocked or already
-    flat); ``slot_idx`` is ``[B, S]`` int32 mapping position ``j`` of
-    sequence ``b`` to its flat pool slot. The chunk's own K/V rows must
+    ``v_pool`` are one layer's pooled cache (blocked or already
+    flat, rows ``H*D`` wide); ``slot_idx`` is ``[B, S]`` int32 mapping
+    position ``j`` of sequence ``b`` to its flat pool slot. The chunk's own K/V rows must
     already be scattered into the pool before the call; causality is
     the mask ``j <= starts[b] + i`` per chunk row ``i``, which makes
     prefix-cached prefill work unchanged: positions before ``starts``
     (the cached prefix, or earlier chunks of this prompt) are simply
     valid history gathered through the block table. Returns
     ``[B, C, H, D]``."""
-    if k_pool.ndim == 4:
-        k_pool = k_pool.reshape(-1, *k_pool.shape[2:])
-        v_pool = v_pool.reshape(-1, *v_pool.shape[2:])
-    k = k_pool[slot_idx]                                # [B, S, H, D]
-    v = v_pool[slot_idx]
+    k, v = _gather_pool_rows(k_pool, v_pool, slot_idx, q.shape[-2:])
     scores = jnp.einsum("bihd,bshd->bhis", q * sm_scale, k)
     pos = starts[:, None] + jnp.arange(q.shape[1])[None, :]   # [B, C]
     valid = jnp.arange(slot_idx.shape[1])[None, None, :] \
@@ -149,13 +153,11 @@ def prefill_attention(q, k, v, sm_scale, causal=True):
 
 
 def _use_pallas():
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-        from . import pallas_attention       # noqa: F401
-        return True
-    except Exception:
-        return False
+    """The Pallas kernels run on a TPU backend, the composed reference
+    everywhere else — decided by the platform alone. A kernel module
+    that fails to import, lower or compile on the chip raises at its
+    use; it is never traded for the reference."""
+    return jax.default_backend() == "tpu"
 
 
 class FlashAttentionOp(Op):

@@ -255,8 +255,7 @@ class CollectiveGPipe:
         up — the transpose of each tick's ``ppermute`` is the inverse
         permute, so cotangents flow stage S-1 -> 0 across devices inside
         the same compiled program, and no jax AD machinery ever crosses
-        the shard_map boundary (jax 0.4.x's partial-eval of shard_map
-        mis-specs scan residuals under check_rep=False)."""
+        the shard_map boundary."""
         axis = self.axis_name
         S, M, K = self.S, self.M, self.fuse_ticks
         r = lax.axis_index(axis)
@@ -268,11 +267,10 @@ class CollectiveGPipe:
         carry_dt = self.boundary_dtype or self.boundary_aval.dtype
         x0 = jnp.zeros(self.boundary_aval.shape, carry_dt)
         loss0 = jnp.float32(0.0)
-        if hasattr(lax, "pvary"):
-            # loop carries change varying-over-mesh type inside the
-            # tick loop; the initial values must already carry it
-            x0 = lax.pvary(x0, (axis,))
-            loss0 = lax.pvary(loss0, (axis,))
+        # loop carries change varying-over-mesh type inside the tick
+        # loop; the initial values must already carry it
+        x0 = lax.pcast(x0, (axis,), to="varying")
+        loss0 = lax.pcast(loss0, (axis,), to="varying")
 
         if self.feed_mode == "sharded":
             def stage_call(s):
@@ -377,10 +375,9 @@ class CollectiveGPipe:
         wbuf0 = jnp.zeros((B,) + tuple(self.boundary_aval.shape),
                           carry_dt)
         loss0 = jnp.float32(0.0)
-        if hasattr(lax, "pvary"):
-            x0 = lax.pvary(x0, (axis,))
-            wbuf0 = lax.pvary(wbuf0, (axis,))
-            loss0 = lax.pvary(loss0, (axis,))
+        x0 = lax.pcast(x0, (axis,), to="varying")
+        wbuf0 = lax.pcast(wbuf0, (axis,), to="varying")
+        loss0 = lax.pcast(loss0, (axis,), to="varying")
 
         if self.feed_mode == "sharded":
             def chunk_call(c):

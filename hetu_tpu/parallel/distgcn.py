@@ -104,11 +104,8 @@ def partition_csr_15d(adj, gr, gc):
 def dist_gcn_spmm(adj, h, mesh):
     """z = A @ h over the ("gr", "gc") mesh; h, z are [N, F] global
     (sharded over gr, replicated over gc)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:                   # older jax
-        from jax.experimental.shard_map import shard_map
 
     gr, gc, n_per, n = adj.gr, adj.gc, adj.n_per, adj.n_nodes
     padded = gr * n_per
@@ -132,14 +129,7 @@ def dist_gcn_spmm(adj, h, mesh):
 
         # z accumulates data-derived (gc-varying) terms; mark the zero
         # init as gc-varying too or the scan carry types disagree
-        z0 = jnp.zeros_like(h_local)
-        try:
-            z0 = lax.pcast(z0, to="varying", axis_name=("gc",))
-        except (AttributeError, TypeError):
-            try:
-                z0 = lax.pvary(z0, ("gc",))
-            except AttributeError:  # older jax: vma tracking absent
-                pass
+        z0 = lax.pcast(jnp.zeros_like(h_local), ("gc",), to="varying")
         # gr-1 rotations in the loop; the last block accumulates outside
         # (a gr-th ppermute would rotate into a discarded carry)
         z, h_last = lax.fori_loop(0, gr - 1, step, (z0, h_local))

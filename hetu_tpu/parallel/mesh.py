@@ -14,29 +14,16 @@ __all__ = ["build_mesh", "factorized_axes", "mesh_for_statuses",
 
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """shard_map with the static replication checker off: jax 0.4.x's
-    check_rep rejects ``lax.cond``/``lax.switch`` branches inside
-    shard_map with "mismatched replication types" even when every
-    branch's outputs are device-varying (its own error text recommends
-    this workaround; jax versions with ``lax.pvary`` renamed the flag
-    to check_vma). Numerics are unaffected — the flag gates a static
-    check and a transpose optimization, not the computation."""
-    import inspect
-    try:
-        from jax import shard_map
-    except ImportError:                   # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        params = inspect.signature(shard_map).parameters
-    except (TypeError, ValueError):
-        params = {}
-    kw = {}
-    if "check_rep" in params:
-        kw["check_rep"] = False
-    elif "check_vma" in params:
-        kw["check_vma"] = False
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
+    """``jax.shard_map`` with the varying-manual-axes check off. The
+    callers branch per device inside the body (``lax.cond`` per ring
+    hop, ``lax.switch`` per pipeline stage), and the checker rejects
+    branches whose outputs disagree on which mesh axes they vary over
+    even when every branch is device-varying. Numerics are unaffected —
+    the flag gates a static check and a transpose optimization, not the
+    computation."""
+    import jax
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def build_mesh(axis_sizes, devices=None):

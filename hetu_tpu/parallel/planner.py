@@ -46,7 +46,7 @@ def spec_for_status(status, model_axes, node=None):
     count; the duplicate (replica) axis stays unsharded. Dropping a
     *distributed* status is numerically safe (XLA picks a layout) but it
     silently forfeits the memory/compute split the user asked for — so
-    it warns, naming the node and status (VERDICT r5 #7).
+    it warns, naming the node and status (round-5 review #7).
     """
     from jax.sharding import PartitionSpec
     if status is None or status.state is None or not status.is_dist():

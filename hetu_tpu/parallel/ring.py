@@ -103,8 +103,8 @@ def shard_map_qkv(body_fn, q, k, v, mesh, axis_name, mask=None):
 
     spec = P(None, None, axis_name, None)
     mask_spec = P(None, None, None, axis_name)
-    # unchecked: the causal bodies branch per ring hop (lax.cond), which
-    # jax 0.4.x's replication checker rejects inside shard_map
+    # unchecked: the causal bodies branch per ring hop (lax.cond), and
+    # the varying-axes checker rejects branches that disagree on it
     if mask is not None:
         body = lambda q_, k_, v_, m_: body_fn(q_, k_, v_, mask=m_)  # noqa: E731
         return shard_map_unchecked(body, mesh=mesh,

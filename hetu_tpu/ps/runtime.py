@@ -105,7 +105,7 @@ class PSRuntime:
         # HT601 lockset findings)
         self._dense_mu = threading.Lock()
         self._times_mu = threading.Lock()
-        # step-phase timing (VERDICT: make the residual gap attributable)
+        # step-phase timing (review: make the residual gap attributable)
         self.times = {"slot_assign": 0.0, "miss_fill": 0.0, "refresh": 0.0,
                       "dispatch": 0.0, "drain_submit": 0.0, "dense": 0.0,
                       "host_pull": 0.0, "sync_push": 0.0,

@@ -64,6 +64,11 @@ def ensure_server(port=None, nworkers=None, wait_s=10.0, extra_env=None):
     nworkers = nworkers or int(os.environ.get("HETU_PS_NWORKERS", "1"))
     if _port_open("127.0.0.1", port):
         return None
+    # build the native library HERE, before the child exists: on a fresh
+    # checkout the compile takes longer than ``wait_s``, so a child left
+    # to build it lazily came up after its parent had given up on it
+    from .native_lib import build_lib
+    build_lib()
     lsock = socket.socket()
     lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
     try:

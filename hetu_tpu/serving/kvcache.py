@@ -5,9 +5,14 @@ The dense decode path (``serving/decode.py``) preallocates one
 for ``S_max`` positions whether it uses 8 or 800, and a new batch means
 a new allocation. This module is the vLLM/PagedAttention shape instead:
 
-* **one pooled buffer per layer** — ``[num_blocks, block_size, H, D]``
+* **one pooled buffer per layer** — ``[num_blocks, block_size, H*D]``
   for K and V, allocated once and shared by every sequence the engine
-  ever serves;
+  ever serves. A row keeps its heads side by side in the minor
+  dimension: with ``[..., H, D]`` minors the TPU tiles ``(H, D)`` to
+  ``(8, 128)`` multiples — 2.7x the bytes at 12 heads of 64 — and
+  every program that flattened the pool paid a padded pool-sized copy,
+  which at a pool sized from ``bytes_limit`` the chip's compiler
+  refused outright;
 * **per-sequence block tables** — a sequence owns an ordered list of
   block ids; token position ``j`` lives in flat pool slot
   ``table[j // block_size] * block_size + j % block_size``. Sequences
@@ -432,9 +437,8 @@ class PagedKVCache:
 
     def _init_pools(self):
         import jax.numpy as jnp
-        nh = self.config.num_attention_heads
-        hs = self.config.hidden_size // nh
-        shape = (self.num_blocks + 1, self.block_size, nh, hs)
+        shape = (self.num_blocks + 1, self.block_size,
+                 self.config.hidden_size)
         return [{"k": jnp.zeros(shape, jnp.float32),
                  "v": jnp.zeros(shape, jnp.float32)}
                 for _ in range(self.config.num_hidden_layers)]

@@ -61,8 +61,8 @@ COMM_KINDS = ("h2d", "d2h", "allreduce", "p2p", "ps_sparse_pull",
 def default_db_path():
     p = os.environ.get(_DB_ENV)
     if not p:
-        return os.path.join(os.path.expanduser("~"), ".cache",
-                            "hetu_tpu", "costdb.json")
+        from ..cachedir import store_path
+        return store_path("costdb.json")
     p = os.path.expanduser(p)
     if p.endswith(".json"):
         return p
@@ -579,7 +579,8 @@ def main(argv=None):
         description="measured per-op/per-collective cost database")
     parser.add_argument("--db", default=None,
                         help=f"DB file (default ${_DB_ENV} or "
-                             f"~/.cache/hetu_tpu/costdb.json)")
+                             f"the in-checkout store, "
+                             f"hetu_tpu/cachedir.py)")
     parser.add_argument("--sweep", action="store_true",
                         help="run the comm microbench and record curves")
     parser.add_argument("--show", action="store_true",

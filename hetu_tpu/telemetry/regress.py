@@ -193,8 +193,8 @@ def compare(old, new, tolerance):
 
 
 def _round_label(path):
-    """Short column label for a bench round file: BENCH_r05.json ->
-    r05; anything else keeps its basename stem."""
+    """Short column label for a bench round file: BENCH_<label>.json
+    -> <label>; anything else keeps its basename stem."""
     import os
     stem = os.path.splitext(os.path.basename(path))[0]
     if stem.startswith("BENCH_"):

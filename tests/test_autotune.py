@@ -160,7 +160,10 @@ def test_save_merges_concurrent_process_entries(tuner, tmp_path):
     assert merged.get("kern_b", ("k",)) == (3, 4)
 
 
-def test_failing_candidates_skipped(tuner):
+def test_partly_failing_sweep_records_the_error_text(tuner, tmp_path):
+    """A candidate the compiler refuses does not abort the sweep, and
+    its reason is kept beside the timings — in the cache file and in
+    the sweep's span."""
     table, tel = tuner
 
     def measure(cfg):
@@ -170,9 +173,26 @@ def test_failing_candidates_skipped(tuner):
 
     assert tune.autotune("demo", ("k5",), [(1,), (2,), (3,)], measure,
                          default=None) == (2,)
-    # every candidate failing -> default, nothing cached
-    assert tune.autotune("demo", ("k6",), [(1,)],
-                         lambda c: 1 / 0, default=(7,)) == (7,)
+    doc = json.load(open(tmp_path / "autotune.json"))
+    (ent,) = doc["entries"].values()
+    assert ent["candidates_ms"]["(1,)"] == \
+        "RuntimeError: does not fit in VMEM"
+    assert ent["candidates_ms"]["(2,)"] == 2.0
+    span = [e for e in tel.tracer.drain()
+            if e.get("name") == "autotune_sweep"][-1]
+    assert "does not fit in VMEM" in span["args"]["candidates_ms"]["(1,)"]
+
+
+def test_all_failing_sweep_raises(tuner):
+    """Every candidate failing is a broken kernel or backend, not a
+    tuning outcome: it raises with each error text, caches nothing, and
+    never hands back the default."""
+    table, tel = tuner
+    with pytest.raises(tune.AutotuneSweepError) as err:
+        tune.autotune("demo", ("k6",), [(1,), (2,)],
+                      lambda c: 1 / 0, default=(7,))
+    assert "all 2 candidates failed" in str(err.value)
+    assert "ZeroDivisionError" in str(err.value)
     assert table.get("demo", ("k6",)) is None
 
 

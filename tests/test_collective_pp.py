@@ -1,7 +1,7 @@
 """Collective (SPMD) pipeline mode: the whole GPipe schedule as ONE
 shard_map program over a ``stage`` mesh axis with ppermute boundary
 shifts (parallel/collective_pp.py) — loss-equivalent to the staged
-runner (VERDICT r4 #2)."""
+runner (round-4 review #2)."""
 import numpy as np
 import pytest
 

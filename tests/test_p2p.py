@@ -1,4 +1,4 @@
-"""p2p channel hardening (VERDICT r4 weak #2/#8): bounded inbox with
+"""p2p channel hardening (round-4 review weak #2/#8): bounded inbox with
 TCP backpressure, chunked large-message streaming, and loud unmapped-
 hostname errors instead of the silent rank-0 fallback."""
 import threading

@@ -123,7 +123,7 @@ def test_spec_lowering():
 def test_spec_lowering_warns_on_unmappable(caplog):
     """A distributed status the planner cannot map is left unconstrained
     (numerics safe) but must WARN naming the node and status — silently
-    forfeiting the split the user asked for was VERDICT r5 #7."""
+    forfeiting the split the user asked for was round-5 review #7."""
     import logging
     axes = factorized_axes(4)          # {tp0:2, tp1:2}
     st = NodeStatus((3, 1))            # 3-way split: no axis of size 3

@@ -1,4 +1,4 @@
-"""PS transport reliability (round-4 VERDICT #3; reference parity:
+"""PS transport reliability (round-4 review #3; reference parity:
 ps-lite/src/resender.h retry-on-timeout + customer.h request tracking).
 
 Covers: (a) requests issued while the server is dead block, retry with

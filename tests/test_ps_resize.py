@@ -1,4 +1,4 @@
-"""Checkpoint fleet-resize (round-4 VERDICT #7): a key-range-partitioned
+"""Checkpoint fleet-resize (round-4 review #7): a key-range-partitioned
 tensor saved under one server count loads under another — the client
 reassembles the saved shards from the manifest and redistributes over
 the new ranges (reference parity: ps-lite server dumps are
