@@ -803,6 +803,8 @@ class SubExecutor:
             return outputs, new_params, new_state, new_opt, ps_grads, \
                 health
 
+        # the program's name in a profile: jit_hetu_step_<subgraph>
+        step_fn.__name__ = step_fn.__qualname__ = f"hetu_step_{self.name}"
         return step_fn
 
     def _compile_step(self, args=None):
@@ -865,15 +867,17 @@ class SubExecutor:
     @contextlib.contextmanager
     def _compile_span(self, key):
         """Span + counters around a trace/compile for one feed-shape
-        signature — jit_compiles / jit_compile_ms per shape make a
+        signature — jit_compiles and the span's length per shape make a
         retrace storm (shape churn) visible in the trace instead of
         showing up only as mysterious slow steps."""
         tel = self.config.telemetry
-        if not tel.enabled:
-            yield
-            return
         t0 = tel.clock()
-        yield
+        # telemetry on or off, a profile says which step recompiled
+        with _telemetry.annotate("jit_compile", subgraph=self.name,
+                                 shape_key=str(key)):
+            yield
+        if not tel.enabled:
+            return
         t1 = tel.clock()
         args = {"subgraph": self.name, "shape_key": str(key),
                 # how many optimizer-bound allreduce collectives this
@@ -886,7 +890,6 @@ class SubExecutor:
             args.update(self._last_mem)
         tel.complete("jit_compile", t0, t1, args)
         tel.inc("jit_compiles")
-        tel.observe("jit_compile_ms", (t1 - t0) / 1e6)
 
     def _note_compile(self):
         """HT901 runtime half (analysis/efficiency.py): when a session
@@ -1110,12 +1113,15 @@ class SubExecutor:
             return executor.ps_runtime.run_step(
                 self, feed_dict, convert_to_numpy_ret_vals)
         feed_dict = feed_dict or {}
+        tel = self.config.telemetry
 
-        feed_map = {}
-        for node, value in feed_dict.items():
-            feed_map[node] = self._ingest(value)
-        for dl in self.dataloader_ops:
-            _, feed_map[dl] = self.next_dl_batch(dl)
+        # one span a step, not one an array (h2d_transfer is that)
+        with tel.span("executor.ingest"):
+            feed_map = {}
+            for node, value in feed_dict.items():
+                feed_map[node] = self._ingest(value)
+            for dl in self.dataloader_ops:
+                _, feed_map[dl] = self.next_dl_batch(dl)
 
         key = self._shape_key(feed_map)
         if key not in self.compiled:
@@ -1127,33 +1133,34 @@ class SubExecutor:
             self._note_compile()
         fn = self.compiled[key]
 
-        with self.config.telemetry.span("device_dispatch",
-                                        subgraph=self.name):
+        with tel.span("device_dispatch", subgraph=self.name):
             outputs, new_params, new_state, new_opt, _, health = fn(
                 *self.trace_args(executor, feed_map))
-        if self.training:
-            executor.params = new_params
-            executor.state = new_state
-            executor.opt_state = new_opt
-            for opt in self.optimizer_ops:
-                opt.optimizer.lr_sched.step()
-        self.step_count += 1
-        if health is not None:
-            # the aux pytree also carries the rangecheck capture, which
-            # runs without a health monitor — stash it unconditionally
-            self._last_health = health
-        hm = self.config.health_monitor
-        if hm is not None and health is not None:
-            hm.after_step(self)
+        with tel.span("executor.outputs"):
+            if self.training:
+                executor.params = new_params
+                executor.state = new_state
+                executor.opt_state = new_opt
+                for opt in self.optimizer_ops:
+                    opt.optimizer.lr_sched.step()
+            self.step_count += 1
+            if health is not None:
+                # the aux pytree also carries the rangecheck capture,
+                # which runs without a health monitor — stash it
+                # unconditionally
+                self._last_health = health
+            hm = self.config.health_monitor
+            if hm is not None and health is not None:
+                hm.after_step(self)
 
-        results = []
-        for out in outputs:
-            if out is None:
-                results.append(None)
-            elif convert_to_numpy_ret_vals:
-                results.append(np.asarray(out))
-            else:
-                results.append(ndarray.NDArray(out, _default_ctx()))
+            results = []
+            for out in outputs:
+                if out is None:
+                    results.append(None)
+                elif convert_to_numpy_ret_vals:
+                    results.append(np.asarray(out))
+                else:
+                    results.append(ndarray.NDArray(out, _default_ctx()))
         return results
 
     def next_dl_batch(self, dl):
@@ -1404,14 +1411,13 @@ class Executor:
         tel = self.config.telemetry
         tl = self._fleet_timeline
         try:
+            t0 = time.perf_counter() if tel.enabled else 0.0
+            t0_ns = tel.clock() if tl is not None else 0
+            with tel.span("step", subgraph=name):
+                if self._fault_slow_s:
+                    time.sleep(self._fault_slow_s)
+                out = sub.run(self, feed_dict, convert_to_numpy_ret_vals)
             if tel.enabled:
-                t0 = time.perf_counter()
-                t0_ns = tel.clock() if tl is not None else 0
-                with tel.span("step", subgraph=name):
-                    if self._fault_slow_s:
-                        time.sleep(self._fault_slow_s)
-                    out = sub.run(self, feed_dict,
-                                  convert_to_numpy_ret_vals)
                 wall_ms = (time.perf_counter() - t0) * 1000.0
                 tel.observe("step_wall_ms", wall_ms)
                 if tl is not None:
@@ -1422,10 +1428,6 @@ class Executor:
                 # report — memory.py caches the probe)
                 tel.flight_step(sub.step_count)
                 _memory.observe_device_memory(tel)
-            else:
-                if self._fault_slow_s:
-                    time.sleep(self._fault_slow_s)
-                out = sub.run(self, feed_dict, convert_to_numpy_ret_vals)
         except Exception as e:
             if _memory.is_oom(e):
                 self._report_oom(e)
@@ -1479,14 +1481,12 @@ class Executor:
         # paths: `steps` weights the window so bucket sums divide into
         # honest per-step numbers (a 100-step scan block is 100 steps
         # of wall, not one)
-        span = tel.span("step_block", steps=len(feed_dicts),
-                        subgraph=name) if tel.enabled else \
-            _telemetry.NULL.span("")
         tl = self._fleet_timeline if tel.enabled else None
         t0 = time.perf_counter()
         t0_ns = tel.clock() if tl is not None else 0
         try:
-            with span:
+            with tel.span("step_block", steps=len(feed_dicts),
+                          subgraph=name):
                 if self._fault_slow_s:
                     time.sleep(self._fault_slow_s * len(feed_dicts))
                 if needs_ps:
@@ -1617,10 +1617,8 @@ class Executor:
                 # engine records lands inside it, so an exposed host
                 # stall is attributable instead of falling between
                 # windows
-                span = tel.span("step_block", steps=len(cur),
-                                subgraph=name) if tel.enabled else \
-                    _telemetry.NULL.span("")
-                with span:
+                with tel.span("step_block", steps=len(cur),
+                              subgraph=name):
                     if rt is not None:
                         out = rt.run_block(sub, cur,
                                            convert_to_numpy_ret_vals,

@@ -56,7 +56,7 @@ from .. import telemetry as _telemetry
 __all__ = ["PipelineSubExecutor", "analytic_bubble_fraction",
            "virtual_stage_program"]
 
-_NULL_CM = _telemetry.NULL.span("")     # shared no-op context manager
+_NULL_CM = _telemetry._NULL_SPAN        # shared no-op context manager
 
 
 class _FlightSpan:

@@ -36,7 +36,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import telemetry as _telemetry
 from ..ndarray import IndexedSlices
 from .device_cache import DeviceCacheTable, pad_fill, pad_gather_zero
 
@@ -832,10 +831,8 @@ class PSRuntime:
                     # per step, there is no covering Executor.run span);
                     # the engine.pop wait lands inside it, so an
                     # exposed prep stall is attributable
-                    span = tel.span("step", subgraph=sub.name,
-                                    pipelined=True) if tel.enabled \
-                        else _telemetry.NULL.span("")
-                    with span:
+                    with tel.span("step", subgraph=sub.name,
+                                  pipelined=True):
                         res = self.run_step(sub, fd,
                                             convert_to_numpy_ret_vals,
                                             prepped=pre, dirty=dirty)

@@ -58,8 +58,7 @@ class RequestTimeline:
     clocks. Created only when telemetry is enabled; recording is a
     tuple append (no locks — every writer is the scheduler thread)."""
 
-    __slots__ = ("rid", "t_submit", "t_wait_start", "t_first_token",
-                 "episodes")
+    __slots__ = ("rid", "t_submit", "t_wait_start", "episodes")
 
     def __init__(self, rid, now_ns):
         self.rid = rid
@@ -67,7 +66,6 @@ class RequestTimeline:
         # waiting-episode cursor: submit time initially, reset to the
         # preemption instant when a sequence bounces back to the queue
         self.t_wait_start = now_ns
-        self.t_first_token = None       # TTFT point: last prefill end
         self.episodes = []              # (phase, t0_ns, t1_ns, attrs)
 
     def note(self, phase, t0_ns, t1_ns, attrs=None):

@@ -52,8 +52,8 @@ class SLOWindow:
     of time-to-first-token exceeds ``ttft_p99_ms`` (the
     streaming-experience SLO: a request can meet its e2e budget while
     its first token arrived unacceptably late). TTFT is recorded by
-    producers that know it (the continuous-batching engine, when
-    telemetry is on); requests noted without one simply don't count
+    producers that know it (the continuous-batching engine, with
+    telemetry on or off); requests noted without one simply don't count
     toward the TTFT percentile. Thread-safe."""
 
     def __init__(self, p99_ms=None, error_rate=None, window=128,

@@ -66,12 +66,22 @@ TPOT for every running sequence. Chunk widths snap to their own pow2
 ladder and the suffix-prefill program keys on (batch, chunk, ctx)
 buckets, so :attr:`compile_bound` stays a finite ladder product —
 HT901 holds with both features on.
+
+**Program spans.** The scheduler thread's time is tiled by leaf spans
+(``Telemetry.span``: the ring when telemetry is on, a ``hetu.<name>``
+annotation in a ``jax.profiler`` trace always): ``serve.wait`` (nothing
+waiting, nothing running), ``serve.admit``, ``serve.prefill.build`` /
+``.device`` / ``.sample``, ``serve.decode.build`` / ``.device`` /
+``.sample`` and ``serve.finish``; ``step`` is their parent. A
+``.device`` span runs from the dispatch of its program through the host
+sync of the rows the scheduler reads, so what lies between two of them
+is the host's own work. The jitted programs are named
+``hetu_paged_prefill`` / ``hetu_paged_decode`` /
+``hetu_paged_suffix_prefill``.
 """
 from __future__ import annotations
 
 import collections
-import contextlib
-import functools
 import itertools
 import os
 import threading
@@ -108,6 +118,20 @@ def _pow2_ladder(start, cap):
     return tuple(ladder)
 
 
+def _named_program(fn, name, **static):
+    """``jax.jit`` of ``fn`` with ``static`` keywords bound, under a
+    stable name: a jitted ``functools.partial`` is ``jit__unknown`` in a
+    profile, this is ``jit_<name>``. The pools (argument 1) are
+    donated."""
+    import jax
+
+    def program(*args):
+        return fn(*args, **static)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, donate_argnums=(1,))
+
+
 def _choose_token(logits_row, temperature, seed, idx):
     """Greedy or temperature sampling, host-side. Randomness is keyed
     on ``(seed, token_index)`` — NOT on any global stream — so a
@@ -126,11 +150,10 @@ def _choose_token(logits_row, temperature, seed, idx):
 class _Seq:
     __slots__ = ("id", "prompt", "max_new", "temperature", "seed",
                  "future", "generated", "pending", "n_written",
-                 "t_submit", "preempts", "rid", "tl", "tokens_lost",
-                 "cached_tokens", "prefill_pos")
+                 "t_submit_ns", "t_first_token_ns", "preempts", "rid",
+                 "tl", "tokens_lost", "cached_tokens", "prefill_pos")
 
-    def __init__(self, sid, prompt, max_new, temperature, seed, rid,
-                 tl):
+    def __init__(self, sid, prompt, max_new, temperature, seed, rid):
         self.id = sid
         self.prompt = prompt
         self.max_new = int(max_new)
@@ -140,10 +163,13 @@ class _Seq:
         self.generated = []     # chosen tokens, pending included
         self.pending = None     # chosen but not yet written to the cache
         self.n_written = 0      # cache rows written (prompt + decode)
-        self.t_submit = time.perf_counter()
+        # both stamps (perf_counter_ns) are taken with telemetry on or
+        # off and can be read on the Future, before and after it is done
+        self.t_submit_ns = self.future.t_submit_ns = time.perf_counter_ns()
+        self.t_first_token_ns = self.future.t_first_token_ns = None
         self.preempts = 0
         self.rid = rid          # request id (caller-supplied or minted)
-        self.tl = tl            # RequestTimeline, None when tel disabled
+        self.tl = None          # RequestTimeline, only with telemetry on
         # tokens the last preemption threw away; while
         # len(generated) <= tokens_lost the sequence is re-earning them
         # (its episodes are "replay", and live introspection says so)
@@ -163,6 +189,14 @@ class _Seq:
             len(self.generated) <= self.tokens_lost
 
 
+def _stamp_first_token(seq, t_ns):
+    """The TTFT point: the end of the host sync of the prefill that
+    gave token 0 (scheduler thread only). A replay after a preemption
+    does not move it."""
+    if seq.t_first_token_ns is None:
+        seq.t_first_token_ns = seq.future.t_first_token_ns = t_ns
+
+
 class ContinuousBatchingEngine:
     """See the module docstring. ``lookup(name) -> array`` resolves
     checkpoint parameter names exactly as for
@@ -174,7 +208,9 @@ class ContinuousBatchingEngine:
     drives ``step()`` directly (deterministic tests) — never both.
 
     ``submit()`` returns a Future resolving to the generated tokens as
-    a 1-D int32 array of length ``max_new_tokens``."""
+    a 1-D int32 array of length ``max_new_tokens``; the Future carries
+    ``t_submit_ns`` and ``t_first_token_ns`` (``perf_counter_ns``; the
+    latter ``None`` until the prefill's host sync ended)."""
 
     def __init__(self, config, lookup, *, num_blocks=None,
                  block_size=DEFAULT_BLOCK_SIZE, budget=None, max_len=None,
@@ -183,7 +219,6 @@ class ContinuousBatchingEngine:
                  slo_p99_ms=None, slo_error_rate=None,
                  slo_window=128, slo_ttft_p99_ms=None, telemetry=None,
                  name="engine", start=True):
-        import jax
         if admission not in ("queue", "reject"):
             raise ValueError(f"admission must be 'queue' or 'reject', "
                              f"got {admission!r}")
@@ -230,15 +265,15 @@ class ContinuousBatchingEngine:
             1, min(self.prefill_chunk or self.max_len, self.max_len))
         nh = config.num_attention_heads
         act = getattr(config, "hidden_act", "gelu")
-        self._prefill_fn = jax.jit(
-            functools.partial(gpt_paged_prefill, num_heads=nh,
-                              hidden_act=act), donate_argnums=(1,))
-        self._step_fn = jax.jit(
-            functools.partial(gpt_paged_step, num_heads=nh,
-                              hidden_act=act), donate_argnums=(1,))
-        self._sprefill_fn = jax.jit(
-            functools.partial(gpt_paged_suffix_prefill, num_heads=nh,
-                              hidden_act=act), donate_argnums=(1,))
+        self._prefill_fn = _named_program(
+            gpt_paged_prefill, "hetu_paged_prefill",
+            num_heads=nh, hidden_act=act)
+        self._step_fn = _named_program(
+            gpt_paged_step, "hetu_paged_decode",
+            num_heads=nh, hidden_act=act)
+        self._sprefill_fn = _named_program(
+            gpt_paged_suffix_prefill, "hetu_paged_suffix_prefill",
+            num_heads=nh, hidden_act=act)
         self._signatures = set()
         self._ids = itertools.count()
         self._waiting = collections.deque()
@@ -305,7 +340,7 @@ class ContinuousBatchingEngine:
         running request — id, phase (waiting / preempted / running /
         replay), tokens done vs budget, KV blocks held, preemption
         count, age. Works with telemetry disabled."""
-        now = time.perf_counter()
+        now = time.perf_counter_ns()
         with self._cond:
             snap = [(s, "waiting" if s.preempts == 0 else "preempted")
                     for s in self._waiting]
@@ -319,7 +354,7 @@ class ContinuousBatchingEngine:
                  "kv_blocks": len(tables.get(s.id, ())),
                  "cached_tokens": s.cached_tokens,
                  "preempts": s.preempts,
-                 "age_ms": round((now - s.t_submit) * 1e3, 3)}
+                 "age_ms": round((now - s.t_submit_ns) / 1e6, 3)}
                 for s, phase in snap]
 
     def stats(self):
@@ -385,11 +420,10 @@ class ContinuousBatchingEngine:
         tel = self.telemetry
         rid = str(request_id) if request_id is not None \
             else mint_request_id()
-        tl = RequestTimeline(rid, time.perf_counter_ns()) \
-            if tel.enabled else None
         seq = _Seq(next(self._ids), prompt, max_new_tokens, temperature,
-                   seed, rid, tl)
+                   seed, rid)
         if tel.enabled:
+            seq.tl = RequestTimeline(rid, seq.t_submit_ns)
             tel.flight_record("serve", "submit", tag=rid)
         with self._cond:
             if self._closed:
@@ -413,25 +447,29 @@ class ContinuousBatchingEngine:
         finish); returns the number of sequences still running."""
         tel = self.telemetry
         t0 = time.perf_counter()
-        with self._cond:
+        with tel.span("serve.admit"), self._cond:
             admitted = self._admit_locked()
         if not admitted and not self._running:
             return 0
         width = len(self._running)
-        cm = tel.span("step", subgraph="serving_engine") \
-            if tel.enabled else contextlib.nullcontext()
-        with cm:
+        with tel.span("step", subgraph="serving_engine"):
             if self._suffix_mode:
                 # chunked/prefix prefill: EVERY still-prefilling
                 # sequence (not just this step's admissions) computes
                 # one chunk, then the running batch decodes — long cold
                 # prompts interleave with decode instead of stalling it
-                prefilling = [s for s in self._running if s.prefilling()]
-                if prefilling:
-                    self._prefill_suffix_step(prefilling)
-            elif admitted:
-                self._prefill_admitted(admitted)
-            self._finish_done()
+                prefilled = [s for s in self._running if s.prefilling()]
+                if prefilled:
+                    self._prefill_suffix_step(prefilled)
+            else:
+                prefilled = admitted
+                if admitted:
+                    self._prefill_admitted(admitted)
+            if prefilled:
+                # a prefill gives token 0, which may be a request's last;
+                # without one nothing was produced since the finish that
+                # ended the previous step
+                self._finish_done()
             if self._running:
                 self._decode_once()
                 self._finish_done()
@@ -513,65 +551,68 @@ class ContinuousBatchingEngine:
         tel = self.telemetry
         if key not in self._signatures:
             self._signatures.add(key)
-            if tel.enabled:
-                with tel.span("jit_compile", subgraph="serving_engine",
-                              shape_key=str(key)):
-                    out = fn(*args)
-                tel.inc("jit_compiles")
-                return out
+            with tel.span("jit_compile", subgraph="serving_engine",
+                          shape_key=str(key)):
+                out = fn(*args)
+            tel.inc("jit_compiles")
+            return out
+        with tel.span("device_dispatch", subgraph="serving_engine"):
             return fn(*args)
-        if tel.enabled:
-            with tel.span("device_dispatch", subgraph="serving_engine"):
-                return fn(*args)
-        return fn(*args)
 
     def _prefill_admitted(self, admitted):
         import jax.numpy as jnp
         tel = self.telemetry
-        groups = {}
-        for s in admitted:
-            pb = next_bucket(s.prompt.shape[0], self.prompt_buckets)
-            groups.setdefault(pb, []).append(s)
+        with tel.span("serve.prefill.build"):
+            groups = {}
+            for s in admitted:
+                pb = next_bucket(s.prompt.shape[0], self.prompt_buckets)
+                groups.setdefault(pb, []).append(s)
         for pb, group in sorted(groups.items()):
-            bb = next_bucket(len(group), self.batch_buckets)
-            ids = np.zeros((bb, pb), np.int32)
-            slots = np.zeros((bb, pb), np.int32)   # 0 = scratch block
-            for i, s in enumerate(group):
-                p = s.prompt.shape[0]
-                ids[i, :p] = s.prompt
-                ids[i, p:] = s.prompt[-1]   # edge pad stays in-vocab
-                slots[i, :p] = self.cache.slot_mapping(s.id, 0, p)
-            t0 = time.perf_counter_ns() if tel.enabled else 0
-            logits, pools = self._dispatch(
-                ("prefill", bb, pb), self._prefill_fn, self.params,
-                self.cache.pools, jnp.asarray(ids), jnp.asarray(slots))
-            self.cache.pools = pools
-            last = np.asarray(
-                logits[jnp.arange(len(group)),
-                       jnp.asarray([s.prompt.shape[0] - 1
-                                    for s in group])])
-            # episode ends AFTER the host sync above — the wall between
-            # t0 and t1 is the prefill compute each member rode
-            t1 = time.perf_counter_ns() if tel.enabled else 0
-            for i, s in enumerate(group):
-                p = s.prompt.shape[0]
-                tok = _choose_token(last[i], s.temperature, s.seed, 0)
-                s.generated.append(tok)
-                s.pending = tok
-                s.n_written = p
-                s.prefill_pos = p
-                if s.tl is not None:
-                    s.tl.note("replay" if s.replaying() else "prefill",
-                              t0, t1, {"cached_tokens": 0,
-                                       "computed_tokens": p})
-                    if s.tl.t_first_token is None:
-                        s.tl.t_first_token = t1     # TTFT point
-            if tel.enabled:
-                real = sum(s.prompt.shape[0] for s in group)
-                tel.inc(f"{self.name}_prefill_tokens", real)
-                tel.inc(f"{self.name}_prefill_pad_tokens",
-                        bb * pb - real)
-                tel.inc(f"{self.name}_tokens", len(group))
+            with tel.span("serve.prefill.build"):
+                bb = next_bucket(len(group), self.batch_buckets)
+                ids = np.zeros((bb, pb), np.int32)
+                slots = np.zeros((bb, pb), np.int32)   # 0 = scratch block
+                for i, s in enumerate(group):
+                    p = s.prompt.shape[0]
+                    ids[i, :p] = s.prompt
+                    ids[i, p:] = s.prompt[-1]   # edge pad stays in-vocab
+                    slots[i, :p] = self.cache.slot_mapping(s.id, 0, p)
+                ids, slots = jnp.asarray(ids), jnp.asarray(slots)
+                rows = jnp.arange(len(group))
+                last_pos = jnp.asarray([s.prompt.shape[0] - 1
+                                        for s in group])
+            with tel.span("serve.prefill.device", batch_bucket=bb,
+                          prompt_bucket=pb):
+                t0 = time.perf_counter_ns() if tel.enabled else 0
+                logits, pools = self._dispatch(
+                    ("prefill", bb, pb), self._prefill_fn, self.params,
+                    self.cache.pools, ids, slots)
+                self.cache.pools = pools
+                last = np.asarray(logits[rows, last_pos])
+                # the episode ends AFTER the host sync above — the wall
+                # between t0 and t1 is the prefill compute each member
+                # rode, and t1 is its first-token time
+                t1 = time.perf_counter_ns()
+            with tel.span("serve.prefill.sample"):
+                for i, s in enumerate(group):
+                    p = s.prompt.shape[0]
+                    tok = _choose_token(last[i], s.temperature, s.seed, 0)
+                    s.generated.append(tok)
+                    s.pending = tok
+                    s.n_written = p
+                    s.prefill_pos = p
+                    _stamp_first_token(s, t1)
+                    if s.tl is not None:
+                        s.tl.note(
+                            "replay" if s.replaying() else "prefill",
+                            t0, t1, {"cached_tokens": 0,
+                                     "computed_tokens": p})
+                if tel.enabled:
+                    real = sum(s.prompt.shape[0] for s in group)
+                    tel.inc(f"{self.name}_prefill_tokens", real)
+                    tel.inc(f"{self.name}_prefill_pad_tokens",
+                            bb * pb - real)
+                    tel.inc(f"{self.name}_tokens", len(group))
 
     def _cow_or_preempt(self, s, start, stop):
         """Copy-on-write the blocks positions ``[start, stop)`` touch
@@ -600,86 +641,95 @@ class ContinuousBatchingEngine:
         import jax.numpy as jnp
         tel = self.telemetry
         chunk = self.prefill_chunk or self.max_len
-        groups = {}
-        for s in prefilling:
-            if s not in self._running:
-                continue        # preempted by an earlier group's CoW
-            w = min(chunk, s.prompt.shape[0] - s.prefill_pos)
-            # shared blocks this chunk writes into copy FIRST, so the
-            # write slots below point at private storage
-            if not self._cow_or_preempt(s, s.prefill_pos,
-                                        s.prefill_pos + w):
-                continue
-            cw = next_bucket(w, self.chunk_buckets)
-            groups.setdefault(cw, []).append((s, w))
+        with tel.span("serve.prefill.build"):
+            groups = {}
+            for s in prefilling:
+                if s not in self._running:
+                    continue    # preempted by an earlier group's CoW
+                w = min(chunk, s.prompt.shape[0] - s.prefill_pos)
+                # shared blocks this chunk writes into copy FIRST, so
+                # the write slots below point at private storage
+                if not self._cow_or_preempt(s, s.prefill_pos,
+                                            s.prefill_pos + w):
+                    continue
+                cw = next_bucket(w, self.chunk_buckets)
+                groups.setdefault(cw, []).append((s, w))
         for cw, group in sorted(groups.items()):
-            group = [(s, w) for s, w in group if s in self._running]
-            if not group:
-                continue
-            bb = next_bucket(len(group), self.batch_buckets)
-            sb = next_bucket(max(s.prefill_pos + w for s, w in group),
-                             self.ctx_buckets)
-            ids = np.zeros((bb, cw), np.int32)
-            starts = np.zeros(bb, np.int32)
-            write_slots = np.zeros((bb, cw), np.int32)  # 0 = scratch
-            slot_grid = np.zeros((bb, sb), np.int32)
-            slot_grid[:len(group)] = self.cache.gather_slots(
-                [s.id for s, _ in group], sb)
-            for i, (s, w) in enumerate(group):
-                pos = s.prefill_pos
-                ids[i, :w] = s.prompt[pos:pos + w]
-                ids[i, w:] = s.prompt[pos + w - 1]   # edge pad in-vocab
-                starts[i] = pos
-                write_slots[i, :w] = self.cache.slot_mapping(
-                    s.id, pos, pos + w)
-            t0 = time.perf_counter_ns() if tel.enabled else 0
-            logits, pools = self._dispatch(
-                ("sprefill", bb, cw, sb), self._sprefill_fn,
-                self.params, self.cache.pools, jnp.asarray(ids),
-                jnp.asarray(starts), jnp.asarray(slot_grid),
-                jnp.asarray(write_slots))
-            self.cache.pools = pools
-            finishing = [(i, s, w) for i, (s, w) in enumerate(group)
-                         if s.prefill_pos + w >= s.prompt.shape[0]]
-            last = np.asarray(
-                logits[jnp.asarray([i for i, _, _ in finishing]),
-                       jnp.asarray([w - 1 for _, _, w in finishing])]) \
-                if finishing else None
-            t1 = time.perf_counter_ns() if tel.enabled else 0
-            for j, (i, s, w) in enumerate(finishing):
-                tok = _choose_token(last[j], s.temperature, s.seed, 0)
-                s.generated.append(tok)
-                s.pending = tok
-            cached_resolved = 0
-            for i, (s, w) in enumerate(group):
-                first_chunk = s.prefill_pos == s.cached_tokens
-                if first_chunk:
-                    cached_resolved += s.cached_tokens
-                s.prefill_pos += w
-                s.n_written = s.prefill_pos
-                if s.tl is not None:
-                    s.tl.note(
-                        "replay" if s.replaying() else "prefill", t0, t1,
-                        {"cached_tokens": s.cached_tokens
-                         if first_chunk else 0, "computed_tokens": w})
-                if not s.prefilling():
-                    # prompt fully resident: publish it for later hits
-                    # (the cache freezes these blocks; the first decode
-                    # write past the tail copy-on-writes)
-                    self.cache.insert_prefix(s.id, s.prompt)
-                    if s.tl is not None and s.tl.t_first_token is None:
-                        s.tl.t_first_token = t1     # TTFT point
-            if tel.enabled:
-                computed = sum(w for _, w in group)
-                tel.complete("serve_prefill_chunk", t0, t1,
-                             {"seqs": len(group),
-                              "tokens": int(computed),
-                              "bucket": int(cw),
-                              "cached": int(cached_resolved)})
-                tel.inc(f"{self.name}_prefill_tokens", computed)
-                tel.inc(f"{self.name}_prefill_pad_tokens",
-                        bb * cw - computed)
-                tel.inc(f"{self.name}_tokens", len(finishing))
+            with tel.span("serve.prefill.build"):
+                group = [(s, w) for s, w in group if s in self._running]
+                if not group:
+                    continue
+                bb = next_bucket(len(group), self.batch_buckets)
+                sb = next_bucket(max(s.prefill_pos + w for s, w in group),
+                                 self.ctx_buckets)
+                ids = np.zeros((bb, cw), np.int32)
+                starts = np.zeros(bb, np.int32)
+                write_slots = np.zeros((bb, cw), np.int32)  # 0 = scratch
+                slot_grid = np.zeros((bb, sb), np.int32)
+                slot_grid[:len(group)] = self.cache.gather_slots(
+                    [s.id for s, _ in group], sb)
+                for i, (s, w) in enumerate(group):
+                    pos = s.prefill_pos
+                    ids[i, :w] = s.prompt[pos:pos + w]
+                    ids[i, w:] = s.prompt[pos + w - 1]  # edge pad in-vocab
+                    starts[i] = pos
+                    write_slots[i, :w] = self.cache.slot_mapping(
+                        s.id, pos, pos + w)
+                finishing = [(i, s, w) for i, (s, w) in enumerate(group)
+                             if s.prefill_pos + w >= s.prompt.shape[0]]
+                ids, starts = jnp.asarray(ids), jnp.asarray(starts)
+                slot_grid = jnp.asarray(slot_grid)
+                write_slots = jnp.asarray(write_slots)
+                if finishing:
+                    rows = jnp.asarray([i for i, _, _ in finishing])
+                    last_pos = jnp.asarray([w - 1 for _, _, w in finishing])
+            with tel.span("serve.prefill.device", batch_bucket=bb,
+                          prompt_bucket=cw, ctx_bucket=sb):
+                t0 = time.perf_counter_ns() if tel.enabled else 0
+                logits, pools = self._dispatch(
+                    ("sprefill", bb, cw, sb), self._sprefill_fn,
+                    self.params, self.cache.pools, ids, starts, slot_grid,
+                    write_slots)
+                self.cache.pools = pools
+                last = np.asarray(logits[rows, last_pos]) \
+                    if finishing else None
+                t1 = time.perf_counter_ns()
+            with tel.span("serve.prefill.sample"):
+                for j, (i, s, w) in enumerate(finishing):
+                    tok = _choose_token(last[j], s.temperature, s.seed, 0)
+                    s.generated.append(tok)
+                    s.pending = tok
+                cached_resolved = 0
+                for i, (s, w) in enumerate(group):
+                    first_chunk = s.prefill_pos == s.cached_tokens
+                    if first_chunk:
+                        cached_resolved += s.cached_tokens
+                    s.prefill_pos += w
+                    s.n_written = s.prefill_pos
+                    if s.tl is not None:
+                        s.tl.note(
+                            "replay" if s.replaying() else "prefill",
+                            t0, t1,
+                            {"cached_tokens": s.cached_tokens
+                             if first_chunk else 0, "computed_tokens": w})
+                    if not s.prefilling():
+                        # prompt fully resident: publish it for later
+                        # hits (the cache freezes these blocks; the
+                        # first decode write past the tail
+                        # copy-on-writes)
+                        self.cache.insert_prefix(s.id, s.prompt)
+                        _stamp_first_token(s, t1)
+                if tel.enabled:
+                    computed = sum(w for _, w in group)
+                    tel.complete("serve_prefill_chunk", t0, t1,
+                                 {"seqs": len(group),
+                                  "tokens": int(computed),
+                                  "bucket": int(cw),
+                                  "cached": int(cached_resolved)})
+                    tel.inc(f"{self.name}_prefill_tokens", computed)
+                    tel.inc(f"{self.name}_prefill_pad_tokens",
+                            bb * cw - computed)
+                    tel.inc(f"{self.name}_tokens", len(finishing))
 
     def _ensure_capacity_lazy(self, active):
         """Lazy-reserve growth: make every active sequence's table
@@ -728,92 +778,101 @@ class ContinuousBatchingEngine:
 
     def _decode_once(self):
         import jax.numpy as jnp
-        active = [s for s in self._running
-                  if len(s.generated) < s.max_new
-                  and not s.prefilling()]
-        if self.reserve == "lazy":
-            active = self._ensure_capacity_lazy(active)
-        if self.prefix_cache:
-            # the first write past a cached/frozen prompt tail lands in
-            # a shared block — copy it before computing write slots
-            # (reserve="full" admission pre-charged this block)
-            for s in list(active):
-                if s in self._running:
-                    self._cow_or_preempt(s, s.n_written, s.n_written + 1)
-            active = [s for s in active if s in self._running]
-        if not active:
-            return
-        bb = next_bucket(len(active), self.batch_buckets)
-        cb = next_bucket(max(s.n_written for s in active) + 1,
-                         self.ctx_buckets)
-        tokens = np.zeros(bb, np.int32)
-        positions = np.zeros(bb, np.int32)
-        write_slots = np.zeros(bb, np.int32)       # 0 = scratch block
-        slot_grid = np.zeros((bb, cb), np.int32)
-        slot_grid[:len(active)] = self.cache.gather_slots(
-            [s.id for s in active], cb)
-        for i, s in enumerate(active):
-            tokens[i] = s.pending
-            positions[i] = s.n_written
-            write_slots[i] = self.cache.slot_of(s.id, s.n_written)
         tel = self.telemetry
-        t0 = time.perf_counter_ns() if tel.enabled else 0
-        logits, pools = self._dispatch(
-            ("decode", bb, cb), self._step_fn, self.params,
-            self.cache.pools, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(slot_grid),
-            jnp.asarray(write_slots))
-        self.cache.pools = pools
-        last = np.asarray(logits[:len(active)])
-        t1 = time.perf_counter_ns() if tel.enabled else 0
-        for i, s in enumerate(active):
-            s.n_written += 1
-            tok = _choose_token(last[i], s.temperature, s.seed,
-                                len(s.generated))
-            s.generated.append(tok)
-            s.pending = tok
-            if s.tl is not None:
-                # a preempted sequence re-earning lost tokens is in
-                # "replay", not "decode" — the doctor's replay bucket
-                s.tl.note("replay" if s.replaying() else "decode",
-                          t0, t1)
-        if tel.enabled:
-            tel.inc(f"{self.name}_tokens", len(active))
+        with tel.span("serve.decode.build"):
+            active = [s for s in self._running
+                      if len(s.generated) < s.max_new
+                      and not s.prefilling()]
+            if self.reserve == "lazy":
+                active = self._ensure_capacity_lazy(active)
+            if self.prefix_cache:
+                # the first write past a cached/frozen prompt tail lands
+                # in a shared block — copy it before computing write
+                # slots (reserve="full" admission pre-charged this block)
+                for s in list(active):
+                    if s in self._running:
+                        self._cow_or_preempt(s, s.n_written,
+                                             s.n_written + 1)
+                active = [s for s in active if s in self._running]
+            if not active:
+                return
+            n = len(active)
+            bb = next_bucket(n, self.batch_buckets)
+            cb = next_bucket(max(s.n_written for s in active) + 1,
+                             self.ctx_buckets)
+            tokens = np.zeros(bb, np.int32)
+            positions = np.zeros(bb, np.int32)
+            write_slots = np.zeros(bb, np.int32)       # 0 = scratch block
+            slot_grid = np.zeros((bb, cb), np.int32)
+            slot_grid[:n] = self.cache.gather_slots(
+                [s.id for s in active], cb)
+            for i, s in enumerate(active):
+                tokens[i] = s.pending
+                positions[i] = s.n_written
+                write_slots[i] = self.cache.slot_of(s.id, s.n_written)
+            tokens, positions = jnp.asarray(tokens), jnp.asarray(positions)
+            slot_grid = jnp.asarray(slot_grid)
+            write_slots = jnp.asarray(write_slots)
+        with tel.span("serve.decode.device", width=n, batch_bucket=bb,
+                      ctx_bucket=cb):
+            t0 = time.perf_counter_ns() if tel.enabled else 0
+            logits, pools = self._dispatch(
+                ("decode", bb, cb), self._step_fn, self.params,
+                self.cache.pools, tokens, positions, slot_grid,
+                write_slots)
+            self.cache.pools = pools
+            last = np.asarray(logits[:n])
+            t1 = time.perf_counter_ns() if tel.enabled else 0
+        with tel.span("serve.decode.sample"):
+            for i, s in enumerate(active):
+                s.n_written += 1
+                tok = _choose_token(last[i], s.temperature, s.seed,
+                                    len(s.generated))
+                s.generated.append(tok)
+                s.pending = tok
+                if s.tl is not None:
+                    # a preempted sequence re-earning lost tokens is in
+                    # "replay", not "decode" — the doctor's replay bucket
+                    s.tl.note("replay" if s.replaying() else "decode",
+                              t0, t1)
+            if tel.enabled:
+                tel.inc(f"{self.name}_tokens", n)
 
     def _finish_done(self):
         tel = self.telemetry
-        with self._cond:
-            done = [s for s in self._running
-                    if len(s.generated) >= s.max_new]
+        with tel.span("serve.finish"):
+            with self._cond:
+                done = [s for s in self._running
+                        if len(s.generated) >= s.max_new]
+                for s in done:
+                    self._running.remove(s)
             for s in done:
-                self._running.remove(s)
-        for s in done:
-            self.cache.free_seq(s.id)
-            ms = (time.perf_counter() - s.t_submit) * 1e3
-            ttft_ms = None
-            if s.tl is not None:
+                self.cache.free_seq(s.id)
                 t_retire = time.perf_counter_ns()
-                _lifecycle.emit_request(tel, s.tl, t_retire,
-                                        len(s.generated), s.preempts)
-                tel.flight_record("serve", "retire", tag=s.rid)
-                if s.tl.t_first_token is not None:
-                    ttft_ms = (s.tl.t_first_token - s.tl.t_submit) / 1e6
-                    tel.observe("serve_ttft_ms", ttft_ms)
-                    tel.observe(
-                        "serve_tpot_ms",
-                        (t_retire - s.tl.t_first_token) / 1e6
-                        / max(1, len(s.generated) - 1))
-                tel.observe("serve_queue_wait_ms",
-                            sum(t1 - t0
-                                for ph, t0, t1, _ in s.tl.episodes
-                                if ph == "queue") / 1e6)
-                tel.observe("serve_preempts", s.preempts)
-            self.slo.note(True, ms, ttft_ms=ttft_ms)
-            if tel.enabled:
-                tel.observe(f"{self.name}_latency_ms", ms)
-                tel.inc(f"{self.name}_requests")
-            s.future.set_result(
-                np.asarray(s.generated[:s.max_new], np.int32))
+                ms = (t_retire - s.t_submit_ns) / 1e6
+                ttft_ms = None
+                if s.t_first_token_ns is not None:
+                    ttft_ms = (s.t_first_token_ns - s.t_submit_ns) / 1e6
+                if s.tl is not None:
+                    _lifecycle.emit_request(tel, s.tl, t_retire,
+                                            len(s.generated), s.preempts)
+                    tel.flight_record("serve", "retire", tag=s.rid)
+                    if ttft_ms is not None:
+                        tel.observe("serve_ttft_ms", ttft_ms)
+                        tel.observe(
+                            "serve_tpot_ms",
+                            (t_retire - s.t_first_token_ns) / 1e6
+                            / max(1, len(s.generated) - 1))
+                    tel.observe("serve_queue_wait_ms",
+                                sum(t1 - t0
+                                    for ph, t0, t1, _ in s.tl.episodes
+                                    if ph == "queue") / 1e6)
+                    tel.observe("serve_preempts", s.preempts)
+                self.slo.note(True, ms, ttft_ms=ttft_ms)
+                if tel.enabled:
+                    tel.observe(f"{self.name}_latency_ms", ms)
+                s.future.set_result(
+                    np.asarray(s.generated[:s.max_new], np.int32))
 
     # ------------------------------------------------------------------
     def _loop(self):
@@ -822,7 +881,8 @@ class ContinuousBatchingEngine:
                 with self._cond:
                     while not self._closed and not self._waiting \
                             and not self._running:
-                        self._cond.wait()
+                        with self.telemetry.span("serve.wait"):
+                            self._cond.wait()
                     if self._closed and not self._waiting \
                             and not self._running:
                         return

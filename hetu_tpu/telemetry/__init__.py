@@ -23,10 +23,21 @@ the executor, PS runtime, p2p channel and all pipeline runners; the
 ``HETU_TELEMETRY=<dir>`` env (exported by ``heturun --telemetry``)
 enables the process-global default and flushes per-rank files at exit.
 
-Overhead contract: with telemetry disabled the hot path costs ONE
-attribute check + a shared no-op context manager — zero per-step
-allocations (tests/test_telemetry.py pins it). Instrumentation sites
-that would build kwargs dicts guard on ``tel.enabled`` first.
+One primitive, two sinks: ``span()`` records into the ring when the
+instance is enabled and ALWAYS opens a ``jax.profiler.TraceAnnotation``
+named ``hetu.<name>`` (tracer.py:``annotate``), so under
+``hetu_tpu.profiler.trace(dir)`` the program's spans sit in the
+profile's host plane on the device planes' clock whether or not a
+``Telemetry`` was passed anywhere. Counters, gauges, histograms and
+``complete()`` / ``instant()`` feed the ring side only.
+
+Overhead contract: with telemetry disabled a span costs ONE attribute
+check + the annotation's constructor, which with no profiler session
+is the profiler's is-anyone-tracing flag (no string is built, nothing
+is kept: zero NET allocations per step, tests/test_telemetry.py pins
+it); in a process that never imported jax it is a shared no-op.
+Per-array and per-request sites, and sites that would build a dict for
+their attrs, guard on ``tel.enabled`` first.
 """
 from __future__ import annotations
 
@@ -34,29 +45,15 @@ import atexit
 import os
 import sys
 
-from .tracer import Tracer, merge_traces
+from .tracer import NULL_SPAN as _NULL_SPAN
+from .tracer import Tracer, annotate, merge_traces
 from .metrics import MetricsRegistry, uptime_gauge
 from .check import validate
 from .flight import FlightRecorder, install_crash_handlers
 
 __all__ = ["Telemetry", "Tracer", "MetricsRegistry", "FlightRecorder",
            "merge_traces", "validate", "get_telemetry", "configure",
-           "resolve", "NULL"]
-
-
-class _NullSpan:
-    """Shared no-op context manager for the disabled path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+           "resolve", "annotate", "NULL"]
 
 
 def _env_rank():
@@ -91,8 +88,10 @@ class Telemetry:
 
     # -- tracing ---------------------------------------------------------
     def span(self, name, **args):
+        """``with tel.span("device_dispatch", subgraph=...):`` — the
+        ring (when enabled) and the profiler annotation (always)."""
         if not self.enabled:
-            return _NULL_SPAN
+            return annotate(name, **args)
         return self.tracer.span(name, **args)
 
     def instant(self, name, **args):

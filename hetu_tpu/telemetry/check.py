@@ -67,6 +67,10 @@ SPAN_SCHEMA = {
     "h2d_stacked": {"bytes": _req(_INT), "overlapped": _req(_BOOL)},
     "memory_analysis": {"label": _opt(_STR), ...: True},
     "step_logged": {"step": _opt(_INT), "wall_ms": _opt(_NUM)},
+    # SubExecutor.run around device_dispatch: the feed loop and
+    # dataloader batches of one step; state swap, health monitor and
+    # output wrapping after it
+    "executor.ingest": {}, "executor.outputs": {},
     # async ingest (ingest.py)
     "ingest_wait": {"tag": _any()},
     # PS runtime / client (ps/) — PSRuntime._phase emits every phase
@@ -138,6 +142,18 @@ SPAN_SCHEMA = {
     # resolved for sequences on their first chunk
     "serve_prefill_chunk": {"seqs": _req(_INT), "tokens": _req(_INT),
                             "bucket": _opt(_INT), "cached": _opt(_INT)},
+    # the leaf spans that tile the scheduler thread (scheduler.py): a
+    # .device span runs from the dispatch of its program through the
+    # host sync of the rows the scheduler reads
+    "serve.wait": {}, "serve.admit": {}, "serve.finish": {},
+    "serve.prefill.build": {}, "serve.prefill.sample": {},
+    "serve.prefill.device": {"batch_bucket": _req(_INT),
+                             "prompt_bucket": _req(_INT),
+                             "ctx_bucket": _opt(_INT)},
+    "serve.decode.build": {}, "serve.decode.sample": {},
+    "serve.decode.device": {"width": _req(_INT),
+                            "batch_bucket": _req(_INT),
+                            "ctx_bucket": _req(_INT)},
     # autotuner / probe (tune/)
     "autotune_sweep": {"kernel": _req(_STR), "key": _req(_STR),
                        "chosen": _req(_STR), "picked_ms": _req(_NUM),

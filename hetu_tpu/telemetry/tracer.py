@@ -14,37 +14,86 @@ Design constraints (tests/test_telemetry.py pins all three):
   to the wall clock at tracer creation, so two ranks' traces (each
   exported with its own ``pid``) line up on one Perfetto timeline when
   ``merge_traces`` stitches them.
+
+The ring's clock is the host's alone. The second sink of every span,
+:func:`annotate`, is a ``jax.profiler.TraceAnnotation`` named
+``hetu.<name>``: under a ``jax.profiler`` session it lands in the
+profile's host plane, on the clock the device planes share, so a
+program span can be held against the device operations it enqueued.
 """
 from __future__ import annotations
 
 import glob
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 
-__all__ = ["Tracer", "merge_traces"]
+__all__ = ["Tracer", "merge_traces", "annotate", "NULL_SPAN"]
 
 _clock = time.perf_counter_ns
 
 
-class _Span:
-    """Context manager recording one complete ("ph":"X") event."""
+class _NullSpan:
+    """Shared no-op context manager: the span of a disabled ring in a
+    process without the profiler sink."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+NULL_SPAN = _NullSpan()
+
+# jax.profiler.TraceAnnotation once this process has imported jax;
+# never imported from here (a PS server child runs without jax)
+_trace_annotation = None
+
+
+def annotate(name, **args):
+    """Context manager putting ``hetu.<name>`` (and ``args`` as its
+    stats) into the profile of a running ``jax.profiler`` session.
+    With no session it costs the profiler's own is-anyone-tracing
+    check: the name and the args are encoded only when one is. In a
+    process that has not imported jax it is the shared no-op."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        if "jax" not in sys.modules:
+            return NULL_SPAN
+        from jax.profiler import TraceAnnotation
+        cls = _trace_annotation = TraceAnnotation
+    return cls("hetu." + name, **args)
+
+
+class _Span:
+    """Context manager recording one complete ("ph":"X") event in the
+    ring, inside the same span's profiler annotation."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer, name, args):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._annotation = annotate(name, **args)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = _clock()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.complete(self._name, self._t0, _clock(), self._args)
+        t1 = _clock()
+        self._annotation.__exit__(*exc)
+        self._tracer.complete(self._name, self._t0, t1,
+                              self._args or None)
         return False
 
 
@@ -70,7 +119,7 @@ class Tracer:
 
     def span(self, name, **args):
         """Context manager timing a complete event."""
-        return _Span(self, name, args or None)
+        return _Span(self, name, args)
 
     def complete(self, name, t0_ns, t1_ns, args=None):
         """Record a complete event from explicit begin/end clock values

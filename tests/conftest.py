@@ -48,6 +48,30 @@ def _no_telemetry_default_leak():
     _tmod._default = before
 
 
+@pytest.fixture
+def counted(monkeypatch):
+    """The names of the profiler annotations ``Telemetry.span`` opens
+    while the test runs, in order: a counting stand-in takes the place
+    of ``jax.profiler.TraceAnnotation``, so what the disabled path costs
+    is held by COUNT, never by a timing."""
+    from hetu_tpu.telemetry import tracer
+    entered = []
+
+    class CountingAnnotation:
+        def __init__(self, name, **args):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracer, "_trace_annotation", CountingAnnotation)
+    return entered
+
+
 # ---------------------------------------------------------------------------
 # thread hygiene (ISSUE 12): a test that leaks a live non-daemon thread
 # fails — leaked threads outlive the test, hang interpreter exit, and

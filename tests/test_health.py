@@ -263,12 +263,17 @@ def test_disabled_executor_has_no_monitor(monkeypatch):
 
 def test_overhead_guard_under_2pct_at_every_n_10(tmp_path):
     """The monitor's host cost at every_n=10, amortized per step, stays
-    under 2% of the measured step. Bounded deterministically (like the
-    telemetry overhead guard): the per-sample fetch+check wall is
-    measured by the monitor itself and divided by the cadence, instead
-    of differencing two noisy end-to-end timings. The device-side
-    sentinel reductions ride inside the compiled step (a handful of
-    scalar reductions against a 3072x1024 matmul)."""
+    under 2% of the step. Bounded by counts and best-of-repeats, not by
+    one micro-timing over one loaded step: the monitor samples exactly
+    at its cadence (2 records in 23 steps), and one sample's
+    fetch+check, timed by the monitor itself on sentinels that are
+    already computed (inside the loop its ``device_get`` also waits for
+    the step it rides on, which is the step's time, not the
+    monitor's), best of several repeats, over the cadence, against the
+    best of the step timings. The device-side sentinel reductions ride
+    inside the compiled step (a handful of scalar reductions against a
+    3072x1024 matmul)."""
+    import jax
     rng = np.random.RandomState(0)
     x = ht.Variable("ho_x", trainable=False)
     y_ = ht.Variable("ho_y", trainable=False)
@@ -291,10 +296,21 @@ def test_overhead_guard_under_2pct_at_every_n_10(tmp_path):
         out = exe.run(feed_dict=feeds)
         out[0].asnumpy()
         times.append(time.perf_counter() - t0)
-    step_ms = float(np.median(times)) * 1000
-    assert hm.records, "cadence must have sampled in 23 steps"
-    per_step_ms = hm.sample_wall_ms / 23.0
-    assert per_step_ms < 0.02 * step_ms, (hm.sample_wall_ms, step_ms)
+    step_ms = min(times) * 1000
+    assert len(hm.records) == 2, "23 steps at every_n=10 sample twice"
+
+    sub = exe.subexecutors["default"]
+    jax.block_until_ready(sub._last_health)
+    sample_ms = []
+    for _ in range(5):
+        sub.step_count = 30             # a cadence step: after_step samples
+        before = hm.sample_wall_ms
+        hm.after_step(sub)
+        sample_ms.append(hm.sample_wall_ms - before)
+    sub.step_count = 23
+    assert len(hm.records) == 7
+    per_step_ms = min(sample_ms) / 10.0
+    assert per_step_ms < 0.02 * step_ms, (sample_ms, step_ms)
 
 
 # ---------------------------------------------------------------------------

@@ -254,35 +254,51 @@ def test_prometheus_http_scrape():
 # ---------------------------------------------------------------------------
 
 def test_disabled_span_zero_allocations():
-    """Telemetry off: span() returns one shared no-op context manager —
-    zero net per-step allocations on the hot path."""
-    assert not NULL.enabled
-    assert NULL.span("a") is NULL.span("b")
-    for _ in range(200):                  # warm caches
-        with NULL.span("step"):
-            pass
-        NULL.inc("x")
-        NULL.observe("y", 1.0)
-    gc.collect()
-    gc.disable()
-    try:
-        before = sys.getallocatedblocks()
-        for _ in range(5000):
+    """Telemetry off: span() is the bare profiler annotation (a shared
+    no-op where jax was never imported) and nothing is kept once its
+    ``with`` ends — zero NET per-step allocations on the hot path, with
+    and without attrs. Interpreter freelists that ``gc.collect()``
+    emptied refill once (a constant, some 80 blocks with keyword
+    attrs), so the claim is held as: five times the steps allocate no
+    more."""
+    assert not NULL.enabled and NULL.tracer is None
+    assert not isinstance(NULL.span("a"), type(Tracer().span("a")))
+
+    def net_blocks(steps):
+        for _ in range(200):              # warm caches
             with NULL.span("step"):
                 pass
-        after = sys.getallocatedblocks()
-    finally:
-        gc.enable()
-    assert after - before <= 8, \
-        f"disabled span leaked {after - before} blocks over 5000 steps"
+            with NULL.span("serve.decode.device", width=3, batch_bucket=4):
+                pass
+            NULL.inc("x")
+            NULL.observe("y", 1.0)
+        gc.collect()
+        gc.disable()
+        try:
+            before = sys.getallocatedblocks()
+            for _ in range(steps):
+                with NULL.span("step"):
+                    pass
+                with NULL.span("serve.decode.device", width=3,
+                               batch_bucket=4):
+                    pass
+            return sys.getallocatedblocks() - before
+        finally:
+            gc.enable()
+
+    short, long = net_blocks(1000), net_blocks(5000)
+    assert long - short <= 8 and long <= 128, \
+        f"disabled span kept {long - short} blocks over 4000 more steps"
 
 
-def test_overhead_guard_traced_step_under_1pct():
+def test_overhead_guard_traced_step_under_1pct(counted):
     """The traced step path with telemetry DISABLED adds <1% wall time
     vs a no-telemetry build of the same step. The only delta between
     the two builds is the disabled instrumentation calls themselves, so
-    bound (sites-per-step x per-site cost) against the measured median
-    step — deterministic, unlike differencing two noisy step timings."""
+    bound (sites per step, COUNTED with a stand-in for the annotation)
+    x (per-site cost) against the step. Both timings are the best of
+    several repeats: under parallel test workers a median of either is
+    mostly the other workers' load."""
     rng = np.random.RandomState(0)
     x = ht.Variable("ov_x", trainable=False)
     y_ = ht.Variable("ov_y", trainable=False)
@@ -304,18 +320,22 @@ def test_overhead_guard_traced_step_under_1pct():
         out = exe.run(feed_dict=feeds)
         out[0].asnumpy()
         times.append(time.perf_counter() - t0)
-    step_ms = float(np.median(times)) * 1000
+    step_ms = min(times) * 1000
 
-    n = 20000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        with NULL.span("site"):
-            pass
-    per_site_ms = (time.perf_counter() - t0) / n * 1000
-    # 32 instrumented sites per step is far above the real count (the
-    # plain step path crosses ~4); even so the added wall must be <1%
-    assert 32 * per_site_ms < 0.01 * step_ms, \
-        (per_site_ms, step_ms)
+    def per_site_ms(n=2000):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with NULL.span("site", width=3):
+                pass
+        return (time.perf_counter() - t0) / n * 1000
+
+    site_ms = min(per_site_ms() for _ in range(10))
+
+    del counted[:]
+    exe.run(feed_dict=feeds)
+    sites = len(counted)
+    assert 0 < sites <= 4, counted
+    assert sites * site_ms < 0.01 * step_ms, (sites, site_ms, step_ms)
 
 
 # ---------------------------------------------------------------------------
