@@ -259,7 +259,7 @@ def gpt_paged_prefill(params, pools, ids, slot_idx, num_heads,
 
 
 def gpt_paged_step(params, pools, tokens, positions, slot_idx,
-                   write_slots, num_heads, hidden_act="gelu"):
+                   write_slots, num_heads, hidden_act="gelu", pick=None):
     """Paged single-token forward for a RAGGED batch: ``tokens`` ``[B]``
     each at its own position ``positions`` ``[B]`` (traced int32 — one
     jit program serves every mix of sequence lengths at this batch/
@@ -269,8 +269,18 @@ def gpt_paged_step(params, pools, tokens, positions, slot_idx,
     :func:`~hetu_tpu.ops.attention.paged_decode_attention`. Padded
     lanes carry ``write_slots`` = scratch and gather behind the length
     mask. Returns ``(logits [B, V], pools)``; jit with ``pools``
-    donated so updates stay in-HBM."""
+    donated so updates stay in-HBM.
+
+    ``pick`` (static) says what leaves the program in place of the
+    logits: ``"greedy"`` returns ``(argmax(logits, -1) [B] int32,
+    pools)`` — the same float32 logits, ties to the first index as
+    ``np.argmax`` breaks them, so the token is the one a host-side pick
+    over the returned logits would choose."""
+    import jax.numpy as jnp
     from ..ops.attention import paged_decode_attention
+
+    if pick not in (None, "greedy"):
+        raise ValueError(f"pick must be None or 'greedy', got {pick!r}")
 
     act = _serve_act(hidden_act)
     hidden = params["wte"].shape[1]
@@ -292,7 +302,10 @@ def gpt_paged_step(params, pools, tokens, positions, slot_idx,
         x = x + (ctx.reshape(b, hidden) @ blk["proj"][0] + blk["proj"][1])
         x = x + _serve_mlp(_serve_ln(x, blk["ln2"]), blk, act)
     x = _serve_ln(x, params["ln_f"])
-    return x @ params["lm_head"], new_pools
+    logits = x @ params["lm_head"]
+    if pick == "greedy":
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_pools
+    return logits, new_pools
 
 
 def gpt_paged_suffix_prefill(params, pools, ids, starts, slot_idx,

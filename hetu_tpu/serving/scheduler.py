@@ -26,6 +26,14 @@ scheduler step:
 4. **decode** — ALL running sequences take one token step in ONE jit
    program (``gpt_paged_step``): per-sequence position vectors make
    the batch ragged-safe, block tables make it gather from the pool.
+   Four small int32 numpy arrays go in with the call itself (no put of
+   their own); when every active sequence is greedy the program picks
+   the token itself (``pick="greedy"``) and ``bb`` int32 ids come back,
+   one host sync a step, no logits leave the device. A step with a
+   ``temperature > 0`` sequence in it runs the logits-returning twin
+   (``hetu_paged_decode_logits``, compiled on first use) and picks on
+   the host, because that draw is keyed on ``(seed, token_index)`` in
+   float64 numpy and a replay has to repeat it.
 
 **The HT901 contract is load-bearing here.** Sequences join/leave every
 step, so naive shapes would retrace constantly. Instead every dispatch
@@ -33,8 +41,9 @@ snaps to precomputed ladders — batch width to the power-of-two ladder
 (``session.py:next_bucket``), context length to a block-aligned ladder,
 prompt length to the decoder's prompt ladder — so distinct jit
 signatures are bounded by :attr:`compile_bound` =
-``|batch| x (|prompt| + |ctx|)`` ladder products no matter how churny
-the trace (the serving test measures exactly this).
+``|batch| x (|prompt| + 2 |ctx|)`` ladder products (decode has a
+greedy and a logits program per bucket) no matter how churny the trace
+(the serving test measures exactly this).
 
 ``reserve="full"`` (default) allocates a request's whole
 ``prompt + max_new_tokens`` block budget at admission — no mid-decode
@@ -77,7 +86,7 @@ waiting, nothing running), ``serve.admit``, ``serve.prefill.build`` /
 sync of the rows the scheduler reads, so what lies between two of them
 is the host's own work. The jitted programs are named
 ``hetu_paged_prefill`` / ``hetu_paged_decode`` /
-``hetu_paged_suffix_prefill``.
+``hetu_paged_decode_logits`` / ``hetu_paged_suffix_prefill``.
 """
 from __future__ import annotations
 
@@ -268,13 +277,21 @@ class ContinuousBatchingEngine:
         self._prefill_fn = _named_program(
             gpt_paged_prefill, "hetu_paged_prefill",
             num_heads=nh, hidden_act=act)
+        # the greedy hot path: token ids leave the program, not logits
         self._step_fn = _named_program(
             gpt_paged_step, "hetu_paged_decode",
+            num_heads=nh, hidden_act=act, pick="greedy")
+        # its logits-returning twin, for a step that holds a sampled
+        # sequence; nothing compiles it until such a step runs
+        self._logits_step_fn = _named_program(
+            gpt_paged_step, "hetu_paged_decode_logits",
             num_heads=nh, hidden_act=act)
         self._sprefill_fn = _named_program(
             gpt_paged_suffix_prefill, "hetu_paged_suffix_prefill",
             num_heads=nh, hidden_act=act)
         self._signatures = set()
+        self.decode_steps = 0               # decode programs dispatched
+        self.decode_device_pick_steps = 0   # ... that picked on the device
         self._ids = itertools.count()
         self._waiting = collections.deque()
         self._running = []
@@ -312,11 +329,12 @@ class ContinuousBatchingEngine:
     def compile_bound(self):
         """The HT901 ladder-product bound on distinct jit signatures:
         prefill keys on (batch, prompt) buckets, decode on (batch, ctx)
-        buckets, suffix prefill (prefix cache / chunked prefill) on
-        (batch, chunk, ctx) buckets — churn can never compile more
-        programs than this."""
+        buckets twice over (the greedy program and the logits one),
+        suffix prefill (prefix cache / chunked prefill) on (batch,
+        chunk, ctx) buckets — churn can never compile more programs
+        than this."""
         bound = len(self.batch_buckets) * (len(self.prompt_buckets)
-                                           + len(self.ctx_buckets))
+                                           + 2 * len(self.ctx_buckets))
         if self._suffix_mode:
             bound += (len(self.batch_buckets) * len(self.chunk_buckets)
                       * len(self.ctx_buckets))
@@ -375,6 +393,8 @@ class ContinuousBatchingEngine:
                "kv_hbm_utilization": round(self.cache.utilization, 4),
                "jit_compiles": self.jit_compiles,
                "compile_bound": self.compile_bound,
+               "decode_steps": self.decode_steps,
+               "decode_device_pick_steps": self.decode_device_pick_steps,
                "healthy": healthy,
                "health_reason": reason}
         out["prefix_cache"] = self.prefix_cache
@@ -777,7 +797,6 @@ class ContinuousBatchingEngine:
                 victim.tl.t_wait_start = time.perf_counter_ns()
 
     def _decode_once(self):
-        import jax.numpy as jnp
         tel = self.telemetry
         with tel.span("serve.decode.build"):
             active = [s for s in self._running
@@ -810,24 +829,35 @@ class ContinuousBatchingEngine:
                 tokens[i] = s.pending
                 positions[i] = s.n_written
                 write_slots[i] = self.cache.slot_of(s.id, s.n_written)
-            tokens, positions = jnp.asarray(tokens), jnp.asarray(positions)
-            slot_grid = jnp.asarray(slot_grid)
-            write_slots = jnp.asarray(write_slots)
+            # an all-greedy step picks inside the program; a sampled
+            # sequence needs its logits on the host (see _choose_token)
+            device_pick = not any(s.temperature > 0.0 for s in active)
         with tel.span("serve.decode.device", width=n, batch_bucket=bb,
                       ctx_bucket=cb):
             t0 = time.perf_counter_ns() if tel.enabled else 0
-            logits, pools = self._dispatch(
-                ("decode", bb, cb), self._step_fn, self.params,
-                self.cache.pools, tokens, positions, slot_grid,
-                write_slots)
+            if device_pick:
+                key, fn = ("decode", bb, cb), self._step_fn
+            else:
+                key, fn = ("decode_logits", bb, cb), self._logits_step_fn
+            # the four numpy arrays go to the device inside the jitted
+            # call, with no put of their own
+            out, pools = self._dispatch(
+                key, fn, self.params, self.cache.pools, tokens,
+                positions, slot_grid, write_slots)
             self.cache.pools = pools
-            last = np.asarray(logits[:n])
+            # the step's one host sync: [bb] int32 ids, or [bb, V]
+            # float32 logits on the sampled route
+            last = np.asarray(out)
             t1 = time.perf_counter_ns() if tel.enabled else 0
         with tel.span("serve.decode.sample"):
+            self.decode_steps += 1
+            if device_pick:
+                self.decode_device_pick_steps += 1
+                last = last.tolist()        # Python ints, in one call
             for i, s in enumerate(active):
                 s.n_written += 1
-                tok = _choose_token(last[i], s.temperature, s.seed,
-                                    len(s.generated))
+                tok = last[i] if device_pick else _choose_token(
+                    last[i], s.temperature, s.seed, len(s.generated))
                 s.generated.append(tok)
                 s.pending = tok
                 if s.tl is not None:
@@ -837,6 +867,9 @@ class ContinuousBatchingEngine:
                               t0, t1)
             if tel.enabled:
                 tel.inc(f"{self.name}_tokens", n)
+                tel.inc(f"{self.name}_decode_steps")
+                if device_pick:
+                    tel.inc(f"{self.name}_decode_device_pick_steps")
 
     def _finish_done(self):
         tel = self.telemetry

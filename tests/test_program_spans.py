@@ -233,6 +233,8 @@ def test_engine_programs_carry_stable_names():
             engine.params, engine.cache.pools, grid, grid),
         "jit_hetu_paged_decode": engine._step_fn.lower(
             engine.params, engine.cache.pools, row, row, grid, row),
+        "jit_hetu_paged_decode_logits": engine._logits_step_fn.lower(
+            engine.params, engine.cache.pools, row, row, grid, row),
         "jit_hetu_paged_suffix_prefill": engine._sprefill_fn.lower(
             engine.params, engine.cache.pools, grid, row, grid, grid),
     }
