@@ -474,10 +474,11 @@ def main(argv=None):
     dev = jax.devices()[0]
     count = len(jax.devices())
     if args.rehearse:
-        from hetu_tpu.ops import attention, pallas_attention
+        from hetu_tpu.ops import attention, pallas_attention, pallas_norm
         # steer the platform-decided kernel dispatch from here, as the
         # tests do: the program itself has no such option
         pallas_attention.INTERPRET = True
+        pallas_norm.INTERPRET = True
         attention._use_pallas = lambda: True
         check(count >= args.chips,
               f"rehearsal of --chips {args.chips} needs that many "

@@ -368,12 +368,13 @@ class BertPredictionHeadTransform:
         self.LayerNorm = BertLayerNorm(config.hidden_size,
                                        name="mlm_transform_layer_norm")
         self.hidden_size = config.hidden_size
-        self.seq_len = config.max_position_embeddings
 
-    def __call__(self, hidden_states, seq_len=None):
-        seq_len = seq_len or self.seq_len
-        shape3 = [-1, seq_len, self.hidden_size]
-        return self.LayerNorm(self.dense_act(hidden_states, shape3))
+    def __call__(self, hidden_states):
+        """``[B * S, H]``: the rows stay flat from the encoder's output
+        to the decoder's matmul, so the transform's LayerNorm and its
+        gradient see the operands that matmul reads and writes."""
+        flat = array_reshape_op(hidden_states, [-1, self.hidden_size])
+        return self.LayerNorm(self.dense_act(flat))
 
 
 class BertLMPredictionHead:
@@ -391,8 +392,7 @@ class BertLMPredictionHead:
 
     def __call__(self, hidden_states, seq_len=None):
         seq_len = seq_len or self.seq_len
-        hidden_states = self.transform(hidden_states, seq_len)
-        flat = array_reshape_op(hidden_states, [-1, self.hidden_size])
+        flat = self.transform(hidden_states)
         logits = matmul_op(flat, self.decoder_weight)
         logits = logits + broadcastto_op(self.decoder_bias, logits)
         return array_reshape_op(logits, [-1, seq_len, self.vocab_size])

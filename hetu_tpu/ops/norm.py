@@ -226,6 +226,28 @@ class LayerNormalizationOp(Op):
         return _norm_range(n, input_ranges[1], input_ranges[2])
 
 
+def layer_norm_backward_reference(dy, x, scale, eps):
+    """``(dx, dscale, dbias)`` of LayerNorm over the last axis, composed
+    from ``jax.numpy`` reductions: what runs off the TPU, in a step a
+    mesh partitions and on a last axis the kernel does not tile, and
+    what the kernel (``pallas_norm.hetu_layer_norm_bwd``) is tested
+    against."""
+    d = x.shape[-1]
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.var(x, axis=-1, keepdims=True)
+    inv = jnp.reciprocal(jnp.sqrt(var + eps))
+    xhat = (x - mean) * inv
+    reduce_axes = tuple(range(x.ndim - 1))
+    dscale = jnp.sum(dy * xhat, axis=reduce_axes)
+    dbias = jnp.sum(dy, axis=reduce_axes)
+    dxhat = dy * scale
+    dx = inv / d * (
+        d * dxhat
+        - jnp.sum(dxhat, axis=-1, keepdims=True)
+        - xhat * jnp.sum(dxhat * xhat, axis=-1, keepdims=True))
+    return (dx, dscale, dbias)
+
+
 class LayerNormalizationGradientOp(Op):
     def __init__(self, out_gradient, in_node, ln_scale, forward_node, eps,
                  ctx=None):
@@ -236,20 +258,19 @@ class LayerNormalizationGradientOp(Op):
 
     def compute(self, input_vals, ectx):
         dy, x, scale = input_vals
-        d = x.shape[-1]
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        inv = jnp.reciprocal(jnp.sqrt(var + self.eps))
-        xhat = (x - mean) * inv
-        reduce_axes = tuple(range(x.ndim - 1))
-        dscale = jnp.sum(dy * xhat, axis=reduce_axes)
-        dbias = jnp.sum(dy, axis=reduce_axes)
-        dxhat = dy * scale
-        dx = inv / d * (
-            d * dxhat
-            - jnp.sum(dxhat, axis=-1, keepdims=True)
-            - xhat * jnp.sum(dxhat * xhat, axis=-1, keepdims=True))
-        return (dx, dscale, dbias)
+        # one pass over the rows on a TPU (the kernel's rule is the
+        # flash kernels': the platform alone, and a last axis it tiles).
+        # A step traced under a mesh is partitioned by GSPMD, which
+        # cannot split a Mosaic kernel: it keeps the composed form.
+        from . import pallas_norm
+        from .attention import _use_pallas
+        mesh = getattr(getattr(ectx, "config", None), "mesh", None)
+        if _use_pallas() and (mesh is None or mesh.size == 1) \
+                and pallas_norm.supported(x.shape[-1], x.dtype.itemsize):
+            return pallas_norm.hetu_layer_norm_bwd(
+                dy, x, scale, eps=self.eps,
+                interpret=pallas_norm.INTERPRET)
+        return layer_norm_backward_reference(dy, x, scale, self.eps)
 
     def gradient(self, output_grad):
         raise NotImplementedError

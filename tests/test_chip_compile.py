@@ -10,6 +10,12 @@ and the long-sequence cell (b8, h8, S2048, padding mask) — in bf16,
 each for the plain forward, the forward with logsumexp and the fused
 backward; plus the smallest and the largest (bq, bk) the autotuner may
 pick at S=2048. A compile that passes is a compile, not a run.
+
+LayerNorm's backward kernel (``ops/pallas_norm.py``) compiles at the
+two shapes the train cells run it at, ``[16384, 768]`` (GPT-2 small,
+batch 16 x S 1024) and ``[32768, 768]`` (BERT-base, 256 x 128), bf16;
+and the gradient op compiles for four chips under a ``dp`` mesh, where
+GSPMD partitions the step and would refuse the kernel.
 """
 import os
 
@@ -21,6 +27,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from hetu_tpu.ops import pallas_attention as pk  # noqa: E402
+from hetu_tpu.ops import pallas_norm  # noqa: E402
 
 # name -> (batch, heads, seq, head_dim, causal, has_mask)
 SHAPES = {
@@ -32,8 +39,8 @@ KINDS = ("fwd", "fwd_lse", "bwd")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """Sharding on one described v5e chip. Around the module the
+def v5e():
+    """The described v5e:2x2's four devices. Around the module the
     persistent compilation cache is switched off (an entry written for
     a described device cannot be read back without a chip, and the next
     compile would warn) and the matmul precision goes back to the
@@ -41,7 +48,6 @@ def one_chip():
     for an fp32 contraction of bf16 operands, which it refuses."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -52,10 +58,17 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", False)
     jax.config.update("jax_default_matmul_precision", None)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", cache_was)
     jax.config.update("jax_default_matmul_precision", precision_was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    """Sharding on one described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e[0])
 
 
 def _compile(kind, shape, blocks, sharding):
@@ -96,3 +109,56 @@ def test_autotune_candidate_edges_compile_s2048(one_chip, edge, kind):
     block = min(cands) if edge == "smallest" else max(cands)
     assert "tpu_custom_call" in _compile(kind, shape, (block, block),
                                          one_chip)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((16384, 768), jnp.bfloat16), ((16, 1024, 768), jnp.bfloat16),
+    ((32768, 768), jnp.bfloat16), ((256, 128, 768), jnp.bfloat16),
+    # the block rule at another width and dtype (BERT-large in float32),
+    # and rows that are no multiple of the block (the masked tail)
+    ((32768, 1024), jnp.float32), ((1000, 768), jnp.bfloat16)], ids=str)
+def test_layer_norm_backward_kernel_compiles(one_chip, shape, dtype):
+    """One custom call, named for the trace's readers, and no pass over
+    the rows left to XLA: what it compiles around the kernel (the
+    ``[1, D]`` sums' relayout to ``[D]``) touches kilobytes."""
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct(shape[-1:], dtype, sharding=one_chip)
+    compiled = pallas_norm.hetu_layer_norm_bwd.lower(
+        x, x, scale, eps=1e-12).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert entry.count("tpu_custom_call") == 1
+    assert f"%{pallas_norm.KERNEL_NAME}" in entry
+    assert " fusion(" not in entry
+    assert compiled.cost_analysis()["bytes accessed"] < 64 * 1024
+
+
+def test_layer_norm_backward_compiles_under_a_dp_mesh(v5e, monkeypatch):
+    """A step over a ``("dp", 4)`` mesh is a GSPMD program, and GSPMD
+    cannot partition a Mosaic kernel (the first assertion pins that, at
+    chip_smoke's dp shapes; interpret mode on the CPU cannot see it). So
+    under a mesh the gradient op keeps the composed form, and compiles:
+    rows sharded, the column sums all-reduced."""
+    import numpy as np
+    import types
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import hetu_tpu as ht
+    from hetu_tpu.ops import attention
+
+    mesh = Mesh(np.asarray(v5e), ("dp",))
+    rows = NamedSharding(mesh, P("dp"))
+    x = jax.ShapeDtypeStruct((64, 128, 768), jnp.float32, sharding=rows)
+    scale = jax.ShapeDtypeStruct((768,), jnp.float32,
+                                 sharding=NamedSharding(mesh, P()))
+    with pytest.raises(NotImplementedError, match="Mosaic kernels"):
+        pallas_norm.hetu_layer_norm_bwd.lower(
+            x, x, scale, eps=1e-12).compile()
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    nodes = [ht.Variable(n, trainable=False) for n in ("dy", "x", "s")]
+    op = ht.layer_normalization_gradient_op(*nodes, None, 1e-12)
+    ectx = types.SimpleNamespace(config=types.SimpleNamespace(mesh=mesh))
+    text = jax.jit(lambda dy, x, s: op.compute([dy, x, s], ectx)).lower(
+        x, x, scale).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text

@@ -120,6 +120,37 @@ def test_bert_pretraining_converges():
     assert losses[-1] < losses[0], losses
 
 
+def test_bert_mlm_head_keeps_the_rows_flat():
+    """The transform hands ``[B * S, H]`` to its LayerNorm and on to
+    the decoder's matmul (no ``[B, S, H]`` round trip between the two
+    matmuls, whose relayouts of the logits' gradient the TPU paid for:
+    PERF.md §6, PR 28); the logits are what the 3-D route gives."""
+    from hetu_tpu.models import bert
+    rng = np.random.RandomState(2)
+    config = _tiny_bert_config()
+    bs, sl, hidden = 3, 16, config.hidden_size
+    table = ht.Variable("word_embeddings", value=rng.randn(
+        config.vocab_size, hidden).astype(np.float32))
+    head = bert.BertLMPredictionHead(config, table)
+    states = ht.Variable("states", trainable=False)
+    flat = head.transform(states)
+    logits = head(states)
+    transform = head.transform
+    round_trip = transform.LayerNorm(
+        transform.dense_act(states, [-1, sl, hidden]))
+    exe = Executor([flat, logits, round_trip], ctx=ht.cpu(0))
+    value = rng.randn(bs, sl, hidden).astype(np.float32)
+    flat_v, logits_v, round_trip_v = [
+        r.asnumpy() for r in exe.run(feed_dict={states: value})]
+    assert flat_v.shape == (bs * sl, hidden)
+    assert logits_v.shape == (bs, sl, config.vocab_size)
+    np.testing.assert_allclose(flat_v.reshape(bs, sl, hidden),
+                               round_trip_v, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        logits_v, round_trip_v @ np.asarray(table.tensor_value).T,
+        rtol=1e-4, atol=1e-4)
+
+
 def test_bert_classification():
     rng = np.random.RandomState(1)
     config = _tiny_bert_config()
