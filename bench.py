@@ -1186,118 +1186,23 @@ def bench_bert():
 
 def bench_serving():
     """Online-inference serving (hetu_tpu/serving/): closed-loop multi-
-    threaded clients against (1) KV-cache GPT decode behind the dynamic
-    micro-batcher — vs_baseline is the measured no-cache full-forward
-    recompute decode, so >1.0 is the KV cache's win — and (2) a
-    PS-backed Wide&Deep model behind the batcher + stdlib HTTP frontend,
-    anchored per-sample against the training-side WDL baseline."""
+    threaded clients against a PS-backed Wide&Deep model behind the
+    dynamic micro-batcher + stdlib HTTP frontend, anchored per-sample
+    against the training-side WDL baseline. (Autoregressive GPT serving
+    is ``bench_serving_continuous`` / ``bench_serving_prefix``.)"""
     import threading
 
     import hetu_tpu as ht
-    import hetu_tpu.models as M
     from hetu_tpu import telemetry as tmod
-    from hetu_tpu.serving import (GPTDecoder, InferenceSession,
-                                  MicroBatcher, ServingHTTPServer,
-                                  next_bucket, serve_embeddings_from_ps)
+    from hetu_tpu.serving import (InferenceSession, MicroBatcher,
+                                  ServingHTTPServer,
+                                  serve_embeddings_from_ps)
 
     tel = _telemetry()
     if not tel.enabled:
         tel = tmod.configure(enabled=True, service="bench")
 
-    # ---- 1. GPT decode through the micro-batcher ----------------------
-    vocab, seq, prompt, gen_len = 5000, 128, 16, 32
-    bucket = 8
-    cfg = M.GPTConfig(vocab_size=vocab, hidden_size=256,
-                      num_hidden_layers=4, num_attention_heads=8,
-                      max_position_embeddings=seq,
-                      hidden_dropout_prob=0.0, use_flash_attention=True)
-    model = M.GPTLMHeadModel(cfg)
-    ids = ht.Variable("input_ids", trainable=False)
-    logits = model(ids)
-    sess = InferenceSession([logits], seq_buckets=(seq,), telemetry=tel)
-    dec = GPTDecoder.from_session(sess, cfg, telemetry=tel)
-    rng = np.random.RandomState(0)
-    warm = rng.randint(0, vocab, (bucket, prompt))
-    # warm EVERY batch bucket the closed loop can hit (ticks coalesce
-    # 1..bucket rows -> serve_decode pads to {1,2,4,8}): compiles must
-    # not land inside the timed window
-    b = 1
-    while b <= bucket:
-        dec.generate(warm[:b], 2)
-        b *= 2
-
-    # no-cache anchor: decode by full-sequence recompute (argmax chain)
-    cur = warm
-    sess.predict({ids: cur})            # warm the bucketed full forward
-    t0 = time.perf_counter()
-    naive_steps = 4
-    for _ in range(naive_steps):
-        full = sess.predict({ids: cur})[0]
-        nxt = np.argmax(full[:, -1], axis=-1)
-        cur = np.concatenate([cur, nxt[:, None]], axis=1)
-    naive_tps = naive_steps * bucket / (time.perf_counter() - t0)
-
-    # per-decode-step latency distribution (the serving "step time")
-    _, kv = dec.prefill(warm)
-    tok = warm[:, -1]
-    step_samples = []
-    for t in range(20):
-        t0 = time.perf_counter()
-        last, kv = dec.decode_step(kv, tok, prompt + t)
-        tok = np.argmax(np.asarray(last), axis=-1)   # sync + next token
-        step_samples.append((time.perf_counter() - t0) * 1000)
-
-    def serve_decode(feeds):
-        x = feeds["ids"]
-        n = len(x)
-        b = next_bucket(n)
-        if b > n:                       # keep decode compiles bucketed
-            x = np.concatenate([x, np.repeat(x[-1:], b - n, axis=0)])
-        return dec.generate(x, gen_len)[:n]
-
-    nclients, per_client = 4, 6
-    latencies = []
-    errors = []
-    with MicroBatcher(serve_decode, max_batch_size=bucket, max_wait_ms=5,
-                      telemetry=tel, name="gpt_serve") as mb:
-        def decode_client(k):
-            crng = np.random.RandomState(100 + k)
-            try:
-                for _ in range(per_client):
-                    p = crng.randint(0, vocab, (1, prompt))
-                    t0 = time.perf_counter()
-                    out = mb.submit({"ids": p}).result(120)
-                    latencies.append((time.perf_counter() - t0) * 1000)
-                    assert out.shape == (1, gen_len)
-            except Exception as e:                  # noqa: BLE001
-                errors.append(e)
-
-        threads = [threading.Thread(target=decode_client, args=(k,))
-                   for k in range(nclients)]
-        t0 = time.perf_counter()
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        wall = time.perf_counter() - t0
-    if errors:
-        raise errors[0]
-    nreq = nclients * per_client
-    kv_tps = nreq * gen_len / wall
-    snap = {s["name"]: s for s in tel.metrics.snapshot()}
-    occ = snap.get("gpt_serve_batch_occupancy", {}).get("mean", 0.0)
-    emit("serving_gpt_decode_requests_per_s", nreq / wall, "req/s",
-         kv_tps / naive_tps if naive_tps else 0.0,
-         decode_tokens_per_s=round(kv_tps, 1),
-         no_cache_tokens_per_s=round(naive_tps, 1),
-         serve_latency_ms_p50=round(float(np.percentile(latencies, 50)), 2),
-         serve_latency_ms_p95=round(float(np.percentile(latencies, 95)), 2),
-         batch_occupancy=round(float(occ), 3), clients=nclients,
-         prompt=prompt, gen=gen_len, h2d_MBps=h2d_probe_mbps(),
-         **_pctl(step_samples))
-    sess.close()
-
-    # ---- 2. PS-backed CTR behind batcher + HTTP ------------------------
+    # ---- PS-backed CTR behind batcher + HTTP ---------------------------
     import json as _json
     import urllib.request
 
@@ -1407,16 +1312,11 @@ def bench_serving():
 
 
 def bench_serving_continuous():
-    """Continuous batching A/B (the ROADMAP item-1 headline): a closed-
-    loop high-concurrency mixed-length workload served by (a) the
-    request-level plane — dense ``GPTDecoder.generate`` behind the
-    ``MicroBatcher``, every prompt padded to the fleet prompt bucket and
-    every tick generating its longest member's length — and (b) the
-    iteration-level ``ContinuousBatchingEngine`` over the paged KV
-    cache, where sequences join/leave the running batch each step and
-    only real tokens are decoded. Identical workload (same RNG), both
-    systems fully warmed by one untimed pre-run. The claimed tokens/sec
-    is perfcheck-gated against the engine's own token counters
+    """Continuous batching: a closed-loop high-concurrency mixed-length
+    workload served by the iteration-level ``ContinuousBatchingEngine``
+    over the paged KV cache, where sequences join/leave the running
+    batch each step and only real tokens are decoded; warmed by two
+    untimed pre-runs. The claimed tokens/sec is perfcheck-gated against the engine's own token counters
     (``analysis/perfcheck.py:serving_claim_check``) — attributed, not
     asserted — and every timed request's lifecycle timeline must pass
     the serving doctor's conservation check before the TTFT/TPOT/queue
@@ -1429,20 +1329,17 @@ def bench_serving_continuous():
     import hetu_tpu.models as M
     from hetu_tpu import telemetry as tmod
     from hetu_tpu.analysis.perfcheck import serving_claim_check
-    from hetu_tpu.serving import (ContinuousBatchingEngine, GPTDecoder,
-                                  InferenceSession, MicroBatcher,
-                                  next_bucket)
+    from hetu_tpu.serving import ContinuousBatchingEngine, InferenceSession
 
     tel = _telemetry()
     if not tel.enabled:
         tel = tmod.configure(enabled=True, service="bench")
 
     vocab, seq = 5000, 128
-    width = 8                   # running-batch width both systems get
-    # 2x more clients than batch slots: keeps BOTH planes saturated —
-    # the baseline's ticks form at full width and the engine's running
-    # batch refills the moment a sequence retires (a half-empty closed
-    # loop starves iteration-level scheduling of its whole advantage)
+    width = 8                   # running-batch width
+    # 2x more clients than batch slots: the engine's running batch
+    # refills the moment a sequence retires (a half-empty closed loop
+    # starves iteration-level scheduling of its whole advantage)
     nclients, per_client = 16, 8
     cfg = M.GPTConfig(vocab_size=vocab, hidden_size=384,
                       num_hidden_layers=6, num_attention_heads=8,
@@ -1452,13 +1349,11 @@ def bench_serving_continuous():
     ids = ht.Variable("input_ids", trainable=False)
     sess = InferenceSession([model(ids)], seq_buckets=(seq,),
                             telemetry=tel)
-    dec = GPTDecoder.from_session(sess, cfg, telemetry=tel)
 
-    # one mixed-length workload, identical for both systems: prompts
-    # 8..24 tokens, outputs bimodal — mostly short (2..6) with a heavy
-    # tail of long (56..64), the serving mix where a request-level tick
-    # barrier (everyone decodes the tick's longest gen) wastes the most
-    # work
+    # one mixed-length workload: prompts 8..24 tokens, outputs bimodal —
+    # mostly short (2..6) with a heavy tail of long (56..64), the
+    # serving mix where a request-level tick barrier (everyone decodes
+    # the tick's longest gen) would waste the most work
     wrng = np.random.RandomState(7)
 
     def _gen_len():
@@ -1469,8 +1364,6 @@ def bench_serving_continuous():
               _gen_len()) for _ in range(per_client)]
             for _ in range(nclients)]
     total_tokens = sum(g for reqs in work for _, g in reqs)
-    pmax_bucket = next_bucket(max(len(p) for reqs in work
-                                  for p, _ in reqs))
 
     def run_clients(submit_one):
         latencies, errors = [], []
@@ -1496,33 +1389,6 @@ def bench_serving_continuous():
         if errors:
             raise errors[0]
         return wall, latencies
-
-    # ---- request-level baseline: MicroBatcher + dense GPTDecoder -----
-    # requests in one tick must share a prompt width, so the client
-    # plane pads every prompt to the fleet prompt bucket; the tick
-    # generates its longest member's gen length for everyone — exactly
-    # the request-level padding + barrier waste the engine deletes
-    def serve_tick(feeds):
-        x, gen = feeds["ids"], int(np.max(feeds["gen"]))
-        n = len(x)
-        b = next_bucket(n)
-        if b > n:               # keep decode compiles bucketed
-            x = np.concatenate([x, np.repeat(x[-1:], b - n, axis=0)])
-        return dec.generate(x, gen)[:n]
-
-    def pad_prompt(p):
-        return np.concatenate(
-            [p, np.repeat(p[-1:], pmax_bucket - len(p))])[None, :]
-
-    with MicroBatcher(serve_tick, max_batch_size=width, max_wait_ms=5,
-                      telemetry=tel, name="cb_base") as mb:
-        def base_one(p, g):
-            return mb.submit({"ids": pad_prompt(p),
-                              "gen": np.asarray([[g]])}).result(600)[0][:g]
-
-        run_clients(base_one)                       # untimed warm pass
-        base_wall, base_lat = run_clients(base_one)
-    base_tps = total_tokens / base_wall
 
     # ---- iteration-level engine over the paged KV cache --------------
     kw = dict(block_size=16, max_batch_size=width, telemetry=tel,
@@ -1579,11 +1445,9 @@ def bench_serving_continuous():
     step_hist = snap.get("engine_step_ms", {})
     ndev = jax.local_device_count()
     emit("serving_tokens_per_sec_per_chip", tps / ndev,
-         "tokens/sec/chip", tps / base_tps if base_tps else 0.0,
+         "tokens/sec/chip", 0.0,     # no second plane to compare with
          serve_p50_ms=round(float(np.percentile(lat, 50)), 2),
          serve_p99_ms=round(float(np.percentile(lat, 99)), 2),
-         baseline_p99_ms=round(float(np.percentile(base_lat, 99)), 2),
-         baseline_tokens_per_s=round(base_tps, 1),
          counted_tokens_per_s=round(measured_tps, 1),
          kv_hbm_utilization=round(engine.cache.peak_utilization, 4),
          kv_blocks=engine.cache.num_blocks,

@@ -26,8 +26,7 @@ from .bert import (BertLayerNorm as LayerNorm, Dropout, Embedding,
                    Linear, _act)
 
 __all__ = ["GPTConfig", "GPTModel", "GPTLMHeadModel",
-           "gpt_param_names", "gpt_serving_params", "init_kv_cache",
-           "gpt_prefill", "gpt_cached_step",
+           "gpt_param_names", "gpt_serving_params", "gpt_forward",
            "gpt_paged_prefill", "gpt_paged_step",
            "gpt_paged_suffix_prefill"]
 
@@ -62,7 +61,7 @@ def gpt_param_names(config):
     builders above assign, structured the way the serving forward wants
     them. ``Executor.save`` writes one ``<name>.npy`` per parameter, so
     this is the bridge from a training checkpoint (or a live executor's
-    ``params``) to the pure-JAX cached decode below — no re-tracing of
+    ``params``) to the pure-JAX serving block below — no re-tracing of
     the graph, just a name lookup."""
     blocks = []
     for i in range(config.num_hidden_layers):
@@ -81,7 +80,7 @@ def gpt_param_names(config):
 
 
 def gpt_serving_params(config, lookup):
-    """Assemble the cached-forward parameter pytree. ``lookup(name)``
+    """Assemble the serving block's parameter pytree. ``lookup(name)``
     returns the array for one checkpoint name (a dict's ``__getitem__``,
     an ``np.load`` closure over a checkpoint dir, ...)."""
     import jax.numpy as jnp
@@ -97,20 +96,6 @@ def gpt_serving_params(config, lookup):
         out["blocks"].append(
             {k: tuple(get(n) for n in v) for k, v in blk.items()})
     return out
-
-
-def init_kv_cache(config, batch, max_len=None):
-    """Preallocated zero K/V buffers, one ``[B, H, S_max, D]`` pair per
-    layer — the decode loop write-indexes rows in place (donated, so the
-    update is in-HBM)."""
-    import jax.numpy as jnp
-    nh = config.num_attention_heads
-    hs = config.hidden_size // nh
-    s_max = int(max_len or config.max_position_embeddings)
-    shape = (int(batch), nh, s_max, hs)
-    return [{"k": jnp.zeros(shape, jnp.float32),
-             "v": jnp.zeros(shape, jnp.float32)}
-            for _ in range(config.num_hidden_layers)]
 
 
 def _serve_ln(x, scale_bias, eps=1e-12):
@@ -135,85 +120,93 @@ def _serve_act(name):
             f"(gelu/relu/tanh)") from None
 
 
-def _serve_mlp(x, blk, act):
-    h = act(x @ blk["fc"][0] + blk["fc"][1])
-    return h @ blk["mlp_proj"][0] + blk["mlp_proj"][1]
-
-
-def gpt_prefill(params, kv, ids, num_heads, hidden_act="gelu"):
-    """Prompt phase: full causal forward over ``ids`` ``[B, S0]`` that
-    also writes rows ``0..S0-1`` of every layer's K/V cache. Attention
-    rides :func:`hetu_tpu.ops.attention.prefill_attention` (the Pallas
-    flash kernel on TPU). Returns ``(logits [B, S0, V], kv)``."""
-    from ..ops.attention import prefill_attention
-
+def _serve_forward(params, x, attend, num_heads, hidden_act):
+    """THE serving-side decoder stack, written once: embedded tokens
+    ``x`` ``[..., hidden]`` (``[B, H]`` for a decode step, ``[B, S, H]``
+    for a prefill) through every pre-LN block and ``ln_f``; returns the
+    hidden states, same shape. The cache backend is ``attend(i, q, k,
+    v)``: layer ``i``'s q/k/v arrive token-major ``[..., nh, hs]``, it
+    writes K/V wherever its cache lives, reads what it must, and returns
+    the context shaped like ``q``. It is called once per layer while
+    tracing, so whatever it collects costs nothing per step."""
     act = _serve_act(hidden_act)
-    b, s0 = ids.shape
-    hidden = params["wte"].shape[1]
-    hs = hidden // num_heads
-    x = params["wte"][ids] + params["wpe"][:s0][None]
-    new_kv = []
-    for blk, layer in zip(params["blocks"], kv):
-        h = _serve_ln(x, blk["ln1"])
-        qkv = h @ blk["qkv"][0] + blk["qkv"][1]            # [B, S0, 3H]
-        q, k, v = (qkv[..., i * hidden:(i + 1) * hidden]
-                   .reshape(b, s0, num_heads, hs).transpose(0, 2, 1, 3)
-                   for i in range(3))
-        k_cache = layer["k"].at[:, :, :s0, :].set(k)
-        v_cache = layer["v"].at[:, :, :s0, :].set(v)
-        new_kv.append({"k": k_cache, "v": v_cache})
-        ctx = prefill_attention(q, k, v, sm_scale=1.0 / float(np.sqrt(hs)),
-                                causal=True)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s0, hidden)
+    hidden = x.shape[-1]
+    heads = (*x.shape[:-1], num_heads, hidden // num_heads)
+    for i, blk in enumerate(params["blocks"]):
+        qkv = _serve_ln(x, blk["ln1"]) @ blk["qkv"][0] + blk["qkv"][1]
+        q, k, v = (qkv[..., j * hidden:(j + 1) * hidden].reshape(heads)
+                   for j in range(3))
+        ctx = attend(i, q, k, v).reshape(x.shape)
         x = x + (ctx @ blk["proj"][0] + blk["proj"][1])
-        x = x + _serve_mlp(_serve_ln(x, blk["ln2"]), blk, act)
-    x = _serve_ln(x, params["ln_f"])
-    return x @ params["lm_head"], new_kv
+        h = act(_serve_ln(x, blk["ln2"]) @ blk["fc"][0] + blk["fc"][1])
+        x = x + (h @ blk["mlp_proj"][0] + blk["mlp_proj"][1])
+    return _serve_ln(x, params["ln_f"])
 
 
-def gpt_cached_step(params, kv, tokens, pos, num_heads,
-                    hidden_act="gelu"):
-    """Cached single-token forward: ``tokens`` ``[B]`` at position
-    ``pos`` (traced int32 scalar). Writes row ``pos`` of every layer's
-    K/V buffer and attends over rows ``0..pos`` via
-    :func:`~hetu_tpu.ops.attention.decode_attention` — per step cost is
-    O(S_max) row reads, no ``[S, S]`` mask, position-indexed learned
-    embeddings. Returns ``(logits [B, V], kv)``. jit with the kv
-    argument donated so the cache updates in place in HBM."""
-    from ..ops.attention import decode_attention
+def _serve_head(params, x, pick=None):
+    """What leaves a serving program: the float32 logits of hidden
+    states ``x``, or with ``pick="greedy"`` their int32 argmax."""
+    import jax.numpy as jnp
+    if pick not in (None, "greedy"):
+        raise ValueError(f"pick must be None or 'greedy', got {pick!r}")
+    logits = x @ params["lm_head"]
+    if pick == "greedy":
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return logits
 
-    act = _serve_act(hidden_act)
-    hidden = params["wte"].shape[1]
-    hs = hidden // num_heads
-    b = tokens.shape[0]
-    x = params["wte"][tokens] + params["wpe"][pos]          # [B, H]
-    new_kv = []
-    for blk, layer in zip(params["blocks"], kv):
-        h = _serve_ln(x, blk["ln1"])
-        qkv = h @ blk["qkv"][0] + blk["qkv"][1]             # [B, 3H]
-        q, k, v = (qkv[:, i * hidden:(i + 1) * hidden]
-                   .reshape(b, num_heads, hs) for i in range(3))
-        k_cache = layer["k"].at[:, :, pos, :].set(k)
-        v_cache = layer["v"].at[:, :, pos, :].set(v)
-        new_kv.append({"k": k_cache, "v": v_cache})
-        ctx = decode_attention(q, k_cache, v_cache, pos,
-                               sm_scale=1.0 / float(np.sqrt(hs)))
-        x = x + (ctx.reshape(b, hidden) @ blk["proj"][0] + blk["proj"][1])
-        x = x + _serve_mlp(_serve_ln(x, blk["ln2"]), blk, act)
-    x = _serve_ln(x, params["ln_f"])
-    return x @ params["lm_head"], new_kv
+
+def _sm_scale(q):
+    return 1.0 / float(np.sqrt(q.shape[-1]))
+
+
+def _causal_attention(q, k, v):
+    """Causal attention among the tokens of this call alone, token-major
+    ``[B, S, nh, hs]`` in and out around
+    :func:`~hetu_tpu.ops.attention.prefill_attention`'s head-major
+    layout (the Pallas flash kernel on TPU)."""
+    from ..ops.attention import prefill_attention
+    ctx = prefill_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+                            sm_scale=_sm_scale(q), causal=True)
+    return ctx.transpose(0, 2, 1, 3)
+
+
+def gpt_forward(params, ids, num_heads, hidden_act="gelu"):
+    """Plain causal forward over ``ids`` ``[B, S]`` with no cache at
+    all: the serving block with an ``attend`` that writes nothing.
+    Returns logits ``[B, S, V]``."""
+    x = params["wte"][ids] + params["wpe"][:ids.shape[1]][None]
+    x = _serve_forward(params, x, lambda i, q, k, v:
+                       _causal_attention(q, k, v), num_heads, hidden_act)
+    return _serve_head(params, x)
 
 
 def _pool_scatter(pool, slots, rows):
-    """Write ``rows [N, H, D]`` into flat slots of one layer's pooled
-    cache ``[num_blocks, block_size, H*D]`` (heads side by side in a
-    row — serving/kvcache.py says why). Duplicate slots (padded lanes
-    all targeting the scratch block) resolve to SOME written row —
-    fine, scratch content is never read unmasked."""
+    """Write ``rows [..., H, D]`` into flat slots ``slots [...]`` of one
+    layer's pooled cache ``[num_blocks, block_size, H*D]`` (heads side
+    by side in a row — serving/kvcache.py says why). Duplicate slots
+    (padded lanes all targeting the scratch block) resolve to SOME
+    written row — fine, scratch content is never read unmasked."""
     shape = pool.shape
     flat = pool.reshape(-1, shape[-1])
-    return flat.at[slots].set(rows.reshape(rows.shape[0], -1),
-                              mode="drop").reshape(shape)
+    return flat.at[slots.reshape(-1)].set(rows.reshape(-1, shape[-1]),
+                                          mode="drop").reshape(shape)
+
+
+def _paged_attend(pools, write_slots, attention):
+    """The block-paged cache backend of :func:`_serve_forward`: layer
+    ``i``'s K/V rows scatter into ``pools[i]`` at ``write_slots`` (one
+    flat slot per token), then ``attention(q, k, v, pool)`` reads from
+    the updated layer pool. Returns ``(attend, new_pools)``; the list
+    fills, layer by layer, as the forward is traced."""
+    new_pools = []
+
+    def attend(i, q, k, v):
+        pool = {"k": _pool_scatter(pools[i]["k"], write_slots, k),
+                "v": _pool_scatter(pools[i]["v"], write_slots, v)}
+        new_pools.append(pool)
+        return attention(q, k, v, pool)
+
+    return attend, new_pools
 
 
 def gpt_paged_prefill(params, pools, ids, slot_idx, num_heads,
@@ -226,36 +219,11 @@ def gpt_paged_prefill(params, pools, ids, slot_idx, num_heads,
     end are edge-repeat padding whose K/V lands in scratch, and causal
     attention keeps them out of the real rows' context. Returns
     ``(logits [B, P, V], pools)``; jit with ``pools`` donated."""
-    from ..ops.attention import prefill_attention
-
-    act = _serve_act(hidden_act)
-    b, p = ids.shape
-    hidden = params["wte"].shape[1]
-    hs = hidden // num_heads
-    x = params["wte"][ids] + params["wpe"][:p][None]
-    flat_slots = slot_idx.reshape(b * p)
-    new_pools = []
-    for blk, pool in zip(params["blocks"], pools):
-        h = _serve_ln(x, blk["ln1"])
-        qkv = h @ blk["qkv"][0] + blk["qkv"][1]           # [B, P, 3H]
-        q, k, v = (qkv[..., i * hidden:(i + 1) * hidden]
-                   .reshape(b, p, num_heads, hs).transpose(0, 2, 1, 3)
-                   for i in range(3))
-        new_pools.append({
-            "k": _pool_scatter(pool["k"], flat_slots,
-                               k.transpose(0, 2, 1, 3)
-                               .reshape(b * p, num_heads, hs)),
-            "v": _pool_scatter(pool["v"], flat_slots,
-                               v.transpose(0, 2, 1, 3)
-                               .reshape(b * p, num_heads, hs))})
-        ctx = prefill_attention(q, k, v,
-                                sm_scale=1.0 / float(np.sqrt(hs)),
-                                causal=True)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, p, hidden)
-        x = x + (ctx @ blk["proj"][0] + blk["proj"][1])
-        x = x + _serve_mlp(_serve_ln(x, blk["ln2"]), blk, act)
-    x = _serve_ln(x, params["ln_f"])
-    return x @ params["lm_head"], new_pools
+    x = params["wte"][ids] + params["wpe"][:ids.shape[1]][None]
+    attend, new_pools = _paged_attend(
+        pools, slot_idx, lambda q, k, v, pool: _causal_attention(q, k, v))
+    x = _serve_forward(params, x, attend, num_heads, hidden_act)
+    return _serve_head(params, x), new_pools
 
 
 def gpt_paged_step(params, pools, tokens, positions, slot_idx,
@@ -276,36 +244,15 @@ def gpt_paged_step(params, pools, tokens, positions, slot_idx,
     pools)`` — the same float32 logits, ties to the first index as
     ``np.argmax`` breaks them, so the token is the one a host-side pick
     over the returned logits would choose."""
-    import jax.numpy as jnp
     from ..ops.attention import paged_decode_attention
 
-    if pick not in (None, "greedy"):
-        raise ValueError(f"pick must be None or 'greedy', got {pick!r}")
-
-    act = _serve_act(hidden_act)
-    hidden = params["wte"].shape[1]
-    hs = hidden // num_heads
-    b = tokens.shape[0]
     x = params["wte"][tokens] + params["wpe"][positions]    # [B, H]
-    new_pools = []
-    for blk, pool in zip(params["blocks"], pools):
-        h = _serve_ln(x, blk["ln1"])
-        qkv = h @ blk["qkv"][0] + blk["qkv"][1]             # [B, 3H]
-        q, k, v = (qkv[:, i * hidden:(i + 1) * hidden]
-                   .reshape(b, num_heads, hs) for i in range(3))
-        k_pool = _pool_scatter(pool["k"], write_slots, k)
-        v_pool = _pool_scatter(pool["v"], write_slots, v)
-        new_pools.append({"k": k_pool, "v": v_pool})
-        ctx = paged_decode_attention(q, k_pool, v_pool, slot_idx,
-                                     positions,
-                                     sm_scale=1.0 / float(np.sqrt(hs)))
-        x = x + (ctx.reshape(b, hidden) @ blk["proj"][0] + blk["proj"][1])
-        x = x + _serve_mlp(_serve_ln(x, blk["ln2"]), blk, act)
-    x = _serve_ln(x, params["ln_f"])
-    logits = x @ params["lm_head"]
-    if pick == "greedy":
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_pools
-    return logits, new_pools
+    attend, new_pools = _paged_attend(
+        pools, write_slots, lambda q, k, v, pool: paged_decode_attention(
+            q, pool["k"], pool["v"], slot_idx, positions,
+            sm_scale=_sm_scale(q)))
+    x = _serve_forward(params, x, attend, num_heads, hidden_act)
+    return _serve_head(params, x, pick), new_pools
 
 
 def gpt_paged_suffix_prefill(params, pools, ids, starts, slot_idx,
@@ -328,35 +275,17 @@ def gpt_paged_suffix_prefill(params, pools, ids, starts, slot_idx,
     write to scratch and rows past a chunk's true width are edge
     padding, same contract as :func:`gpt_paged_prefill`. Returns
     ``(logits [B, C, V], pools)``; jit with ``pools`` donated."""
+    import jax.numpy as jnp
     from ..ops.attention import paged_prefill_attention
 
-    act = _serve_act(hidden_act)
-    b, c = ids.shape
-    hidden = params["wte"].shape[1]
-    hs = hidden // num_heads
-    import jax.numpy as jnp
-    positions = starts[:, None] + jnp.arange(c)[None, :]    # [B, C]
-    x = params["wte"][ids] + params["wpe"][positions]
-    flat_slots = write_slots.reshape(b * c)
-    new_pools = []
-    for blk, pool in zip(params["blocks"], pools):
-        h = _serve_ln(x, blk["ln1"])
-        qkv = h @ blk["qkv"][0] + blk["qkv"][1]           # [B, C, 3H]
-        q, k, v = (qkv[..., i * hidden:(i + 1) * hidden]
-                   .reshape(b, c, num_heads, hs) for i in range(3))
-        k_pool = _pool_scatter(pool["k"], flat_slots,
-                               k.reshape(b * c, num_heads, hs))
-        v_pool = _pool_scatter(pool["v"], flat_slots,
-                               v.reshape(b * c, num_heads, hs))
-        new_pools.append({"k": k_pool, "v": v_pool})
-        ctx = paged_prefill_attention(q, k_pool, v_pool, slot_idx,
-                                      starts,
-                                      sm_scale=1.0 / float(np.sqrt(hs)))
-        ctx = ctx.reshape(b, c, hidden)
-        x = x + (ctx @ blk["proj"][0] + blk["proj"][1])
-        x = x + _serve_mlp(_serve_ln(x, blk["ln2"]), blk, act)
-    x = _serve_ln(x, params["ln_f"])
-    return x @ params["lm_head"], new_pools
+    positions = starts[:, None] + jnp.arange(ids.shape[1])[None, :]
+    x = params["wte"][ids] + params["wpe"][positions]       # [B, C, H]
+    attend, new_pools = _paged_attend(
+        pools, write_slots, lambda q, k, v, pool: paged_prefill_attention(
+            q, pool["k"], pool["v"], slot_idx, starts,
+            sm_scale=_sm_scale(q)))
+    x = _serve_forward(params, x, attend, num_heads, hidden_act)
+    return _serve_head(params, x), new_pools
 
 
 class CausalSelfAttention:

@@ -23,7 +23,7 @@ from ..graph.node import Op
 __all__ = ["flash_attention_op", "FlashAttentionOp", "attention_reference",
            "ring_attention_op", "RingAttentionOp",
            "ulysses_attention_op", "UlyssesAttentionOp",
-           "decode_attention", "prefill_attention",
+           "prefill_attention",
            "paged_decode_attention", "paged_prefill_attention"]
 
 
@@ -42,27 +42,9 @@ FUSED_BWD_MIN_SEQ = 512
 
 
 # ---------------------------------------------------------------------------
-# serving decode helpers (pure JAX, no graph nodes) — the index path the
-# KV-cache single-token forward rides (models/gpt.py, serving/decode.py)
+# serving attention (pure JAX, no graph nodes) — the three calls the one
+# serving block of models/gpt.py rides, one per cache backend
 # ---------------------------------------------------------------------------
-
-def decode_attention(q, k_cache, v_cache, pos, sm_scale):
-    """One query token against a preallocated KV cache.
-
-    ``q`` is ``[B, H, D]`` (the current position's query), ``k_cache`` /
-    ``v_cache`` are ``[B, H, S_max, D]`` with rows ``0..pos`` written and
-    the rest zero; ``pos`` is the 0-based position of the current token.
-    Returns ``[B, H, D]``. Causality is a length-``S_max`` validity
-    vector — no ``[S, S]`` mask ever materializes, and the cost per step
-    is O(S_max * D) instead of the full forward's O(S^2 * D)."""
-    s_max = k_cache.shape[2]
-    scores = jnp.einsum("bhd,bhsd->bhs", q * sm_scale, k_cache)
-    valid = jnp.arange(s_max) <= pos
-    scores = jnp.where(valid[None, None, :], scores, -1e9)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    return jnp.einsum("bhs,bhsd->bhd", probs.astype(v_cache.dtype),
-                      v_cache)
-
 
 def _gather_pool_rows(k_pool, v_pool, slot_idx, heads_dim):
     """K/V rows at ``slot_idx`` ``[B, S]`` of one layer's pooled cache,
@@ -90,8 +72,8 @@ def paged_decode_attention(q, k_pool, v_pool, slot_idx, positions,
     sequence's CURRENT token, so sequences of different lengths decode
     in the same call. Returns ``[B, H, D]``.
 
-    Unlike :func:`decode_attention` there is no per-sequence dense
-    ``S_max`` cache: K/V rows are gathered through the block table, so
+    There is no per-sequence dense ``S_max`` cache: K/V rows are
+    gathered through the block table, so
     the per-step cost is O(S_bucket * D) over a *shared* pool and HBM
     holds only the blocks live sequences actually use. Causality/
     raggedness is the ``j <= positions[b]`` validity mask — scratch

@@ -2,7 +2,7 @@
 something that answers requests.
 
 The training stack (executor/PS/telemetry) already owns compilation,
-checkpoints and sparse tables; serving composes them into four pieces:
+checkpoints and sparse tables; serving composes them into these pieces:
 
 * :class:`~hetu_tpu.serving.session.InferenceSession` — frozen-graph
   sessions over eval nodes + an ``Executor.save`` checkpoint dir, with
@@ -13,10 +13,6 @@ checkpoints and sparse tables; serving composes them into four pieces:
   batch per tick (``max_batch_size`` / ``max_wait_ms``), results split
   back per request, queue-depth / latency / occupancy metrics exported
   through ``hetu_tpu/telemetry/metrics.py``.
-* :class:`~hetu_tpu.serving.decode.GPTDecoder` — KV-cache autoregressive
-  decode for the GPT family (prefill on the flash-attention path, O(S)
-  single-token steps, greedy + temperature sampling), numerically pinned
-  against the full-sequence graph forward.
 * :mod:`~hetu_tpu.serving.embedding` — PS-backed sparse serving: eval
   graphs rewritten to pull embedding rows from the parameter server
   read-only (a push from a serving session raises), with a host row
@@ -24,12 +20,14 @@ checkpoints and sparse tables; serving composes them into four pieces:
 * :class:`~hetu_tpu.serving.http.ServingHTTPServer` — minimal stdlib
   JSON frontend over a session or batcher (``/v1/predict``, ``/healthz``,
   ``/metrics``).
-* the continuous-batching plane —
+* autoregressive decode for the GPT family —
   :class:`~hetu_tpu.serving.kvcache.PagedKVCache` (block-paged pooled
   K/V + free-list allocator, HBM-budgeted via HT4xx),
   :class:`~hetu_tpu.serving.scheduler.ContinuousBatchingEngine`
   (iteration-level join/leave scheduling over the paged cache, HT901
-  bucketed jit signatures, KV-block admission control), and
+  bucketed jit signatures, KV-block admission control, greedy +
+  temperature sampling; numerically pinned against the full-sequence
+  graph forward), and
   :class:`~hetu_tpu.serving.router.ReplicaRouter` (SLO-probed
   least-inflight routing + load shedding over N replicas).
 * :mod:`~hetu_tpu.serving.lifecycle` — request-level observability:
@@ -43,7 +41,6 @@ checkpoints and sparse tables; serving composes them into four pieces:
 """
 from .session import InferenceSession, next_bucket
 from .batcher import MicroBatcher
-from .decode import GPTDecoder
 from .embedding import ReadOnlyPSClient, serve_embeddings_from_ps
 from .http import ServingHTTPServer
 from .kvcache import (BlockAllocator, KVCacheExhausted, PagedKVCache,
@@ -52,7 +49,7 @@ from .lifecycle import RequestTimeline, mint_request_id
 from .router import ReplicaRouter, RouterOverloaded, SLOWindow
 from .scheduler import ContinuousBatchingEngine, EngineOverloaded
 
-__all__ = ["InferenceSession", "MicroBatcher", "GPTDecoder",
+__all__ = ["InferenceSession", "MicroBatcher",
            "ReadOnlyPSClient", "serve_embeddings_from_ps",
            "ServingHTTPServer", "next_bucket",
            "BlockAllocator", "KVCacheExhausted", "PagedKVCache",

@@ -1,9 +1,9 @@
 """Paged KV cache: fixed-size block pools + a block allocator.
 
-The dense decode path (``serving/decode.py``) preallocates one
-``[B, H, S_max, D]`` K/V pair per layer per batch — every sequence pays
-for ``S_max`` positions whether it uses 8 or 800, and a new batch means
-a new allocation. This module is the vLLM/PagedAttention shape instead:
+A dense cache preallocates one ``[B, H, S_max, D]`` K/V pair per layer
+per batch — every sequence pays for ``S_max`` positions whether it uses
+8 or 800, and a new batch means a new allocation. This module is the
+vLLM/PagedAttention shape instead:
 
 * **one pooled buffer per layer** — ``[num_blocks, block_size, H*D]``
   for K and V, allocated once and shared by every sequence the engine

@@ -1,10 +1,10 @@
 """Iteration-level continuous batching over the paged KV cache.
 
-``GPTDecoder.generate`` + ``MicroBatcher`` is *request-level* batching:
-a tick's requests fuse into one batch that prefills together, decodes
-together, and finishes together — every sequence pays the longest
-member's generation length, a late arrival waits for the whole batch,
-and each batch allocates dense ``[B, H, S_max, D]`` cache buffers.
+*Request-level* batching fuses a tick's requests into one batch that
+prefills together, decodes together, and finishes together — every
+sequence pays the longest member's generation length, a late arrival
+waits for the whole batch, and each batch allocates dense
+``[B, H, S_max, D]`` cache buffers.
 
 :class:`ContinuousBatchingEngine` schedules at *iteration* granularity
 instead (Orca, OSDI '22), over the block-paged cache of
@@ -208,9 +208,8 @@ def _stamp_first_token(seq, t_ns):
 
 class ContinuousBatchingEngine:
     """See the module docstring. ``lookup(name) -> array`` resolves
-    checkpoint parameter names exactly as for
-    :class:`~hetu_tpu.serving.decode.GPTDecoder`; use the classmethods
-    for the common sources.
+    checkpoint parameter names (``models/gpt.py:gpt_param_names``) to
+    arrays; use the classmethods for the common sources.
 
     With ``start=True`` (default) a daemon scheduler thread drives
     :meth:`step` whenever work exists; with ``start=False`` the caller

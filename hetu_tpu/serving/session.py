@@ -132,7 +132,7 @@ class InferenceSession:
 
     def params_by_name(self):
         """{param name: device array} — the bridge to weight-level
-        serving paths (GPTDecoder.from_session)."""
+        serving paths (ContinuousBatchingEngine.from_session)."""
         return {node.name: self.executor.params[sid]
                 for sid, node in self.executor._param_nodes.items()}
 

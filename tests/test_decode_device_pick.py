@@ -7,15 +7,12 @@ is counted or compared on the CPU, nothing is timed.
 import numpy as np
 import pytest
 
-import hetu_tpu as ht
-import hetu_tpu.models as M
 from hetu_tpu import telemetry
 from hetu_tpu.models.gpt import gpt_paged_prefill, gpt_paged_step
-from hetu_tpu.serving import (ContinuousBatchingEngine, InferenceSession,
-                              PagedKVCache)
+from hetu_tpu.serving import ContinuousBatchingEngine, PagedKVCache
 from hetu_tpu.serving.scheduler import _choose_token
 
-VOCAB, SEQ = 64, 32
+from gpt_reference import VOCAB, gpt_session
 
 # engine keywords of the three ways a decode step's build phase can go:
 # nothing special, copy-on-write out of the prefix cache with chunked
@@ -30,14 +27,7 @@ VARIANTS = {
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = M.GPTConfig(vocab_size=VOCAB, hidden_size=32,
-                      num_hidden_layers=2, num_attention_heads=4,
-                      max_position_embeddings=SEQ,
-                      hidden_dropout_prob=0.0)
-    ids = ht.Variable("input_ids", trainable=False)
-    sess = InferenceSession([M.GPTLMHeadModel(cfg)(ids)],
-                            seq_buckets=(SEQ,), seed=11)
-    return cfg, sess
+    return gpt_session(seed=11)
 
 
 def _engine(model, **kw):

@@ -1,6 +1,6 @@
 """Continuous-batching serving plane (hetu_tpu/serving/kvcache.py,
-scheduler.py, router.py): block allocator invariants, paged-vs-dense
-decode numerics pinned to the dense path's existing test tolerances,
+scheduler.py, router.py): block allocator invariants, paged decode
+numerics pinned to the graph's full forward (rtol/atol 1e-5),
 iteration-level scheduling with the HT901 compile bound measured under
 churn, KV-block admission control, lazy-reserve preemption determinism,
 and SLO-probed replica routing."""
@@ -10,31 +10,22 @@ import time
 import numpy as np
 import pytest
 
-import hetu_tpu as ht
 from hetu_tpu import telemetry
 import hetu_tpu.models as M
+from hetu_tpu.models.gpt import (gpt_paged_prefill, gpt_paged_step,
+                                 gpt_serving_params)
 from hetu_tpu.serving import (BlockAllocator, ContinuousBatchingEngine,
-                              EngineOverloaded, GPTDecoder,
-                              InferenceSession, KVCacheExhausted,
+                              EngineOverloaded, KVCacheExhausted,
                               PagedKVCache, ReplicaRouter,
                               RouterOverloaded, SLOWindow)
 
-VOCAB, SEQ = 64, 32
+from gpt_reference import VOCAB, full_forward, gpt_session, greedy_chain
+
+SEQ = 32
 
 
 def _tel():
     return telemetry.Telemetry(enabled=True)
-
-
-def _gpt_session(seed=0, layers=2):
-    cfg = M.GPTConfig(vocab_size=VOCAB, hidden_size=32,
-                      num_hidden_layers=layers, num_attention_heads=4,
-                      max_position_embeddings=SEQ,
-                      hidden_dropout_prob=0.0)
-    model = M.GPTLMHeadModel(cfg)
-    ids = ht.Variable("input_ids", trainable=False)
-    sess = InferenceSession([model(ids)], seq_buckets=(SEQ,), seed=seed)
-    return cfg, ids, sess
 
 
 def _drive(engine, futures, limit=500):
@@ -179,60 +170,57 @@ def test_cache_sizes_from_hbm_budget(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# paged numerics pinned to the dense path
+# paged numerics pinned to the graph's full forward
 # ---------------------------------------------------------------------------
 
-def test_paged_prefill_and_step_logits_match_dense():
+@pytest.mark.parametrize("hidden_act", ["gelu", "relu"])
+def test_paged_prefill_and_step_logits_match_full_forward(hidden_act):
     """Teacher-forced paged decode: prefill logits and every step's
-    logits equal the dense-cache path's within the dense path's own
-    test tolerance (rtol/atol 1e-5)."""
+    logits equal the graph's full-sequence forward at that position
+    (rtol/atol 1e-5 fp32) — with a relu MLP too, so the serving block
+    cannot silently hard-code gelu."""
     import jax.numpy as jnp
-    from hetu_tpu.models.gpt import gpt_paged_prefill, gpt_paged_step
 
-    cfg, ids, sess = _gpt_session()
-    dec = GPTDecoder.from_session(sess, cfg)
+    cfg, sess = gpt_session(hidden_act=hidden_act)
+    params = gpt_serving_params(cfg, sess.params_by_name().__getitem__)
+    kw = dict(num_heads=cfg.num_attention_heads, hidden_act=hidden_act)
     cache = PagedKVCache(cfg, num_blocks=16, block_size=4)
     rng = np.random.RandomState(0)
     x = rng.randint(0, VOCAB, (2, 14))
     prefix = 6
+    full = full_forward(sess, x)
 
-    dense_logits, kv = dec.prefill(x[:, :prefix])
     for sid in (0, 1):
         cache.add_seq(sid, 14)
     slots = np.stack([cache.slot_mapping(0, 0, prefix),
                       cache.slot_mapping(1, 0, prefix)])
     plogits, pools = gpt_paged_prefill(
-        dec.params, cache.pools, jnp.asarray(x[:, :prefix], jnp.int32),
-        jnp.asarray(slots), num_heads=cfg.num_attention_heads)
-    np.testing.assert_allclose(np.asarray(plogits),
-                               np.asarray(dense_logits),
+        params, cache.pools, jnp.asarray(x[:, :prefix], jnp.int32),
+        jnp.asarray(slots), **kw)
+    np.testing.assert_allclose(np.asarray(plogits), full[:, :prefix],
                                rtol=1e-5, atol=1e-5)
     for pos in range(prefix, 14):
-        dense_step, kv = dec.decode_step(kv, x[:, pos], pos)
         pstep, pools = gpt_paged_step(
-            dec.params, pools, jnp.asarray(x[:, pos], jnp.int32),
+            params, pools, jnp.asarray(x[:, pos], jnp.int32),
             jnp.asarray([pos, pos], jnp.int32),
             jnp.asarray(cache.gather_slots([0, 1], pos + 1)),
             jnp.asarray([cache.slot_of(0, pos), cache.slot_of(1, pos)],
-                        jnp.int32),
-            num_heads=cfg.num_attention_heads)
-        np.testing.assert_allclose(np.asarray(pstep),
-                                   np.asarray(dense_step),
+                        jnp.int32), **kw)
+        np.testing.assert_allclose(np.asarray(pstep), full[:, pos],
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_engine_greedy_matches_dense_decoder():
+def test_engine_greedy_matches_full_forward_chain():
     """The engine's continuous-batched ragged decode produces EXACTLY
-    the dense decoder's greedy tokens for every request — neighbors in
-    the running batch never perturb a sequence (isolation through the
-    block tables)."""
-    cfg, ids, sess = _gpt_session(seed=1)
-    dec = GPTDecoder.from_session(sess, cfg)
+    the argmax chain of repeated full-sequence forwards for every
+    request — neighbors in the running batch never perturb a sequence
+    (isolation through the block tables)."""
+    cfg, sess = gpt_session(seed=1)
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, VOCAB, (int(rng.randint(2, 10)),))
                for _ in range(6)]
     gens = [int(g) for g in rng.randint(1, 7, 6)]
-    want = [dec.generate(p[None, :], g)[0] for p, g in zip(prompts, gens)]
+    want = [greedy_chain(sess, p, g) for p, g in zip(prompts, gens)]
 
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
@@ -240,9 +228,80 @@ def test_engine_greedy_matches_dense_decoder():
     futs = [eng.submit(p, g) for p, g in zip(prompts, gens)]
     _drive(eng, futs)
     for w, f in zip(want, futs):
-        np.testing.assert_array_equal(np.asarray(w).ravel(), f.result(1))
+        np.testing.assert_array_equal(w, f.result(1))
     assert eng.cache.used_blocks == 0, "finished sequences leaked blocks"
     eng.close()
+
+
+def test_engine_ragged_prompts_match_exact_and_count_padding():
+    """Prompts of lengths 5, 7, 12 — none a prompt bucket — prefill
+    padded to 8, 8, 16; the padded K/V rows land in scratch or are
+    overwritten before they become attendable, so every output equals
+    the exact-length argmax chain. ``engine_prefill_tokens`` counts the
+    24 REAL prompt tokens and ``engine_prefill_pad_tokens`` the bucket
+    padding apart, so prefill throughput is not stamped from padding."""
+    tel = _tel()
+    cfg, sess = gpt_session(seed=4)
+    rng = np.random.RandomState(4)
+    prompts = [rng.randint(0, VOCAB, (p,)) for p in (5, 7, 12)]
+    eng = ContinuousBatchingEngine.from_session(
+        sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
+        telemetry=tel, start=False)
+    futs = [eng.submit(p, 4) for p in prompts]
+    _drive(eng, futs)
+    for p, f in zip(prompts, futs):
+        np.testing.assert_array_equal(greedy_chain(sess, p, 4),
+                                      f.result(1))
+    assert tel.counter_value("engine_prefill_tokens") == 5 + 7 + 12
+    # one [2, 8] program for the 5 and the 7, one [1, 16] for the 12
+    assert tel.counter_value("engine_prefill_pad_tokens") == \
+        (2 * 8 - 12) + (16 - 12)
+    eng.close()
+
+
+def test_engine_temperature_sampling_in_vocab_and_seeded():
+    """A ``temperature=1.0, seed=3`` request samples inside the
+    vocabulary, and the same seed repeats it token for token."""
+    cfg, sess = gpt_session(seed=2)
+    x = np.random.RandomState(2).randint(0, VOCAB, (4,))
+    eng = ContinuousBatchingEngine.from_session(
+        sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
+        start=False)
+    futs = [eng.submit(x, 8, temperature=1.0, seed=3) for _ in range(2)]
+    _drive(eng, futs)
+    out = futs[0].result(1)
+    assert out.shape == (8,)
+    assert (out >= 0).all() and (out < VOCAB).all()
+    np.testing.assert_array_equal(out, futs[1].result(1))
+    eng.close()
+
+
+def test_engine_from_checkpoint_matches_from_session(tmp_path):
+    """``Executor.save`` -> ``from_checkpoint`` serves the same tokens
+    as ``from_session`` over the live parameters."""
+    cfg, sess = gpt_session(seed=3)
+    sess.executor.save(str(tmp_path))
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, VOCAB, (n,)) for n in (5, 9)]
+    kw = dict(num_blocks=30, block_size=4, max_batch_size=4, start=False)
+    outs = []
+    for eng in (ContinuousBatchingEngine.from_session(sess, cfg, **kw),
+                ContinuousBatchingEngine.from_checkpoint(
+                    cfg, str(tmp_path), **kw)):
+        futs = [eng.submit(p, 5) for p in prompts]
+        _drive(eng, futs)
+        outs.append([f.result(1).tolist() for f in futs])
+        eng.close()
+    assert outs[0] == outs[1]
+
+
+def test_engine_from_checkpoint_names_missing_parameter(tmp_path):
+    cfg, sess = gpt_session(seed=3)
+    sess.executor.save(str(tmp_path))
+    (tmp_path / "gpt_h1_mlp_fc_bias.npy").unlink()
+    with pytest.raises(FileNotFoundError, match="gpt_h1_mlp_fc_bias"):
+        ContinuousBatchingEngine.from_checkpoint(
+            cfg, str(tmp_path), num_blocks=8, block_size=4, start=False)
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +313,7 @@ def test_engine_compile_bound_under_churny_trace():
     point) yet jit_compiles stays within the ladder-product bound — and
     a SECOND churn wave adds ZERO compiles (steady state)."""
     tel = _tel()
-    cfg, ids, sess = _gpt_session(seed=2)
+    cfg, sess = gpt_session(seed=2)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=40, block_size=4, max_batch_size=4,
         telemetry=tel, start=False)
@@ -292,8 +351,7 @@ def test_engine_compile_bound_under_churny_trace():
 def test_admission_queue_policy_serves_everything():
     """A pool far smaller than the offered load: queue admission holds
     the FIFO head until blocks free, and every request completes."""
-    cfg, ids, sess = _gpt_session(seed=3)
-    dec = GPTDecoder.from_session(sess, cfg)
+    cfg, sess = gpt_session(seed=3)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=6, block_size=4, max_batch_size=4,
         start=False)
@@ -302,13 +360,13 @@ def test_admission_queue_policy_serves_everything():
     futs = [eng.submit(p, 4) for p in prompts]
     _drive(eng, futs)
     for p, f in zip(prompts, futs):
-        np.testing.assert_array_equal(
-            dec.generate(p[None, :], 4)[0], f.result(1))
+        np.testing.assert_array_equal(greedy_chain(sess, p, 4),
+                                      f.result(1))
     eng.close()
 
 
 def test_admission_reject_policy_sheds_load():
-    cfg, ids, sess = _gpt_session(seed=4)
+    cfg, sess = gpt_session(seed=4)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=6, block_size=4, max_batch_size=4,
         admission="reject", start=False)
@@ -331,7 +389,7 @@ def test_admission_reject_policy_sheds_load():
 
 
 def test_submit_rejects_request_that_can_never_fit():
-    cfg, ids, sess = _gpt_session(seed=5)
+    cfg, sess = gpt_session(seed=5)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=2, block_size=4, max_batch_size=2,
         start=False)
@@ -352,7 +410,7 @@ def test_lazy_reserve_preempts_and_still_reproduces():
     preemption requeues the youngest sequence, and (seed, index)-keyed
     sampling makes its recompute reproduce the same tokens — outputs
     equal the full-reserve engine's exactly."""
-    cfg, ids, sess = _gpt_session(seed=6)
+    cfg, sess = gpt_session(seed=6)
     rng = np.random.RandomState(7)
     prompts = [rng.randint(0, VOCAB, (5,)) for _ in range(4)]
 
@@ -468,7 +526,7 @@ def test_engine_smoke_background_thread():
     submits, SLO health probe, metrics, clean close (the thread-leak
     gate in conftest watches the join)."""
     tel = _tel()
-    cfg, ids, sess = _gpt_session(seed=8)
+    cfg, sess = gpt_session(seed=8)
     with ContinuousBatchingEngine.from_session(
             sess, cfg, num_blocks=24, block_size=4, max_batch_size=4,
             telemetry=tel, slo_p99_ms=60_000.0) as eng:

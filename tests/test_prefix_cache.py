@@ -4,18 +4,21 @@ blocks with per-block refcounts, copy-on-write isolation, LRU eviction
 of cached-unreferenced blocks under pressure, chunked prefill
 interleaving with decode, and the engine-level guarantee that prefix
 sharing and chunking change NOTHING about outputs (byte-identical
-tokens, logits within the paged path's own 1e-5 pin)."""
+tokens, logits within 1e-5 of the graph's full forward)."""
 import numpy as np
 import pytest
 
-import hetu_tpu as ht
 from hetu_tpu import telemetry
 import hetu_tpu.models as M
-from hetu_tpu.serving import (ContinuousBatchingEngine, GPTDecoder,
-                              InferenceSession, PagedKVCache,
+from hetu_tpu.models.gpt import (gpt_paged_prefill,
+                                 gpt_paged_suffix_prefill,
+                                 gpt_serving_params)
+from hetu_tpu.serving import (ContinuousBatchingEngine, PagedKVCache,
                               PrefixCache)
 
-VOCAB, SEQ = 64, 64
+from gpt_reference import VOCAB, full_forward, gpt_session
+
+SEQ = 64
 
 
 def _tel():
@@ -29,12 +32,8 @@ def _cfg(layers=2):
                        hidden_dropout_prob=0.0)
 
 
-def _gpt_session(seed=0, layers=2):
-    cfg = _cfg(layers)
-    model = M.GPTLMHeadModel(cfg)
-    ids = ht.Variable("input_ids", trainable=False)
-    sess = InferenceSession([model(ids)], seq_buckets=(SEQ,), seed=seed)
-    return cfg, sess
+def _gpt_session(seed=0):
+    return gpt_session(seed=seed, seq=SEQ)
 
 
 def _drive(engine, futures, limit=800):
@@ -215,38 +214,36 @@ def test_cache_evicts_lru_cached_blocks_under_pressure():
 # suffix prefill numerics
 # ---------------------------------------------------------------------------
 
-def test_suffix_prefill_logits_match_dense():
-    """Prefill split at an arbitrary offset (the prefix-hit shape):
-    rows 0..k-1 via the batch prefill, rows k.. via
-    gpt_paged_suffix_prefill — every suffix position's logits equal the
-    dense full-prompt forward within the paged path's 1e-5 pin."""
+@pytest.mark.parametrize("split", [1, 4, 7])
+def test_suffix_prefill_logits_match_full_forward(split):
+    """Prefill split at an offset (the prefix-hit shape): rows 0..k-1
+    via the batch prefill, rows k.. via gpt_paged_suffix_prefill — every
+    suffix position's logits equal the graph's full-prompt forward
+    within 1e-5. The split falls after one token, on a block boundary
+    (block_size 4) and three rows into a block."""
     import jax.numpy as jnp
-    from hetu_tpu.models.gpt import (gpt_paged_prefill,
-                                     gpt_paged_suffix_prefill)
 
     cfg, sess = _gpt_session()
-    dec = GPTDecoder.from_session(sess, cfg)
+    params = gpt_serving_params(cfg, sess.params_by_name().__getitem__)
     cache = PagedKVCache(cfg, num_blocks=16, block_size=4)
     rng = np.random.RandomState(0)
     x = rng.randint(0, VOCAB, (1, 14))
-    split = 6
-    dense_logits, _ = dec.prefill(x)
+    full = full_forward(sess, x)
 
     cache.add_seq(0, 14)
     slots = cache.slot_mapping(0, 0, split)[None, :]
     _, pools = gpt_paged_prefill(
-        dec.params, cache.pools, jnp.asarray(x[:, :split], jnp.int32),
+        params, cache.pools, jnp.asarray(x[:, :split], jnp.int32),
         jnp.asarray(slots), num_heads=cfg.num_attention_heads)
     suffix = 14 - split
     grid = cache.gather_slots([0], 16)
     write = cache.slot_mapping(0, split, 14)[None, :]
     slogits, pools = gpt_paged_suffix_prefill(
-        dec.params, pools, jnp.asarray(x[:, split:], jnp.int32),
+        params, pools, jnp.asarray(x[:, split:], jnp.int32),
         jnp.asarray([split], jnp.int32), jnp.asarray(grid),
         jnp.asarray(write), num_heads=cfg.num_attention_heads)
     assert slogits.shape == (1, suffix, VOCAB)
-    np.testing.assert_allclose(np.asarray(slogits),
-                               np.asarray(dense_logits)[:, split:],
+    np.testing.assert_allclose(np.asarray(slogits), full[:, split:],
                                rtol=1e-5, atol=1e-5)
 
 

@@ -20,13 +20,14 @@ import numpy as np
 import pytest
 
 import hetu_tpu as ht
-import hetu_tpu.models as M
 from hetu_tpu import profiler, telemetry
 from hetu_tpu.executor import Executor
-from hetu_tpu.serving import ContinuousBatchingEngine, InferenceSession
+from hetu_tpu.serving import ContinuousBatchingEngine
 from hetu_tpu.telemetry import check, tracer
 
-VOCAB, SEQ = 64, 64
+from gpt_reference import VOCAB, gpt_session
+
+SEQ = 64
 
 LEAVES = ("serve.wait", "serve.admit", "serve.prefill.build",
           "serve.prefill.device", "serve.prefill.sample",
@@ -35,14 +36,7 @@ LEAVES = ("serve.wait", "serve.admit", "serve.prefill.build",
 
 
 def _gpt_session(seed=0):
-    cfg = M.GPTConfig(vocab_size=VOCAB, hidden_size=32,
-                      num_hidden_layers=2, num_attention_heads=4,
-                      max_position_embeddings=SEQ,
-                      hidden_dropout_prob=0.0)
-    model = M.GPTLMHeadModel(cfg)
-    ids = ht.Variable("input_ids", trainable=False)
-    sess = InferenceSession([model(ids)], seq_buckets=(SEQ,), seed=seed)
-    return cfg, sess
+    return gpt_session(seed=seed, seq=SEQ)
 
 
 def _engine(**kw):

@@ -1,6 +1,6 @@
 """Online inference subsystem (hetu_tpu/serving/): frozen-graph
 sessions with bounded-compile shape bucketing, dynamic micro-batching,
-KV-cache GPT decode pinned against the full-sequence forward, PS-backed
+the plain GPT serving forward pinned against the graph's, PS-backed
 read-only embedding serving, and the checkpoint-layout satellites
 (save-collision / load-missing / sharding-preserving state restore)."""
 import json
@@ -14,10 +14,12 @@ import pytest
 import hetu_tpu as ht
 from hetu_tpu import telemetry
 from hetu_tpu.executor import Executor
-import hetu_tpu.models as M
-from hetu_tpu.serving import (GPTDecoder, InferenceSession, MicroBatcher,
+from hetu_tpu.models.gpt import gpt_forward, gpt_serving_params
+from hetu_tpu.serving import (InferenceSession, MicroBatcher,
                               ServingHTTPServer, next_bucket,
                               serve_embeddings_from_ps)
+
+from gpt_reference import VOCAB, full_forward, gpt_session
 
 
 def _tel():
@@ -158,120 +160,20 @@ def test_dense_roundtrip_save_session_predict(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# KV-cache decode
+# the serving block, no cache
 # ---------------------------------------------------------------------------
 
-VOCAB, SEQ = 64, 32
-
-
-def _gpt_session(seed=0, layers=2):
-    cfg = M.GPTConfig(vocab_size=VOCAB, hidden_size=32,
-                      num_hidden_layers=layers, num_attention_heads=4,
-                      max_position_embeddings=SEQ,
-                      hidden_dropout_prob=0.0)
-    model = M.GPTLMHeadModel(cfg)
-    ids = ht.Variable("input_ids", trainable=False)
-    logits = model(ids)
-    sess = InferenceSession([logits], seq_buckets=(SEQ,), seed=seed)
-    return cfg, ids, sess
-
-
-def test_kv_decode_matches_full_forward_every_step():
-    """Teacher-forced decode: at every position the cached single-token
-    forward's logits equal the full-sequence graph forward's (the
-    acceptance-criteria numerics pin, rtol<=1e-5 fp32)."""
-    cfg, ids, sess = _gpt_session()
-    dec = GPTDecoder.from_session(sess, cfg)
-    rng = np.random.RandomState(0)
-    x = rng.randint(0, VOCAB, (2, 16))
-    # session pads seq to the model bucket and trims back
-    full = sess.predict({ids: x})[0]
-    assert full.shape == (2, 16, VOCAB)
-
-    prefix = 6
-    logits, kv = dec.prefill(x[:, :prefix])
-    np.testing.assert_allclose(np.asarray(logits), full[:, :prefix],
+def test_gpt_forward_matches_graph_forward_every_position():
+    """``gpt_forward`` (the one serving block with an attend that writes
+    nothing; chip_smoke.py's first-token reference) equals the GRAPH's
+    full forward at every position, rtol<=1e-5 fp32."""
+    cfg, sess = gpt_session()
+    params = gpt_serving_params(cfg, sess.params_by_name().__getitem__)
+    x = np.random.RandomState(0).randint(0, VOCAB, (2, 16))
+    got = gpt_forward(params, x, num_heads=cfg.num_attention_heads)
+    assert got.shape == (2, 16, VOCAB)
+    np.testing.assert_allclose(np.asarray(got), full_forward(sess, x),
                                rtol=1e-5, atol=1e-5)
-    last = np.asarray(logits)[:, -1]
-    for pos in range(prefix, 16):
-        step, kv = dec.decode_step(kv, x[:, pos], pos)
-        np.testing.assert_allclose(np.asarray(step), full[:, pos],
-                                   rtol=1e-5, atol=1e-5)
-
-
-def test_generate_greedy_matches_full_forward_chain():
-    """Greedy generate() reproduces the argmax chain of repeated
-    full-sequence forwards."""
-    cfg, ids, sess = _gpt_session(seed=1)
-    dec = GPTDecoder.from_session(sess, cfg)
-    rng = np.random.RandomState(1)
-    x = rng.randint(0, VOCAB, (2, 8))
-    got = dec.generate(x, max_new_tokens=6)
-
-    cur = x.copy()
-    for _ in range(6):
-        full = sess.predict({ids: cur})[0]
-        nxt = np.argmax(full[:, -1], axis=-1).astype(np.int64)
-        cur = np.concatenate([cur, nxt[:, None]], axis=1)
-    np.testing.assert_array_equal(got, cur[:, 8:])
-
-
-def test_generate_temperature_sampling_in_vocab():
-    cfg, ids, sess = _gpt_session(seed=2)
-    dec = GPTDecoder.from_session(sess, cfg)
-    x = np.random.RandomState(2).randint(0, VOCAB, (1, 4))
-    out = dec.generate(x, 8, temperature=1.0, seed=3)
-    assert out.shape == (1, 8)
-    assert (out >= 0).all() and (out < VOCAB).all()
-    # same seed is deterministic
-    np.testing.assert_array_equal(
-        out, dec.generate(x, 8, temperature=1.0, seed=3))
-
-
-def test_kv_decode_respects_hidden_act():
-    """A relu-MLP GPT decodes with relu, not a silently hard-coded
-    gelu: logits still match the graph forward."""
-    cfg = M.GPTConfig(vocab_size=VOCAB, hidden_size=32,
-                      num_hidden_layers=2, num_attention_heads=4,
-                      max_position_embeddings=SEQ, hidden_act="relu",
-                      hidden_dropout_prob=0.0)
-    model = M.GPTLMHeadModel(cfg)
-    ids = ht.Variable("input_ids", trainable=False)
-    sess = InferenceSession([model(ids)], seq_buckets=(SEQ,), seed=5)
-    dec = GPTDecoder.from_session(sess, cfg)
-    x = np.random.RandomState(5).randint(0, VOCAB, (2, 10))
-    want = sess.predict({ids: x})[0]
-    logits, _ = dec.prefill(x)
-    np.testing.assert_allclose(np.asarray(logits), want, rtol=1e-5,
-                               atol=1e-5)
-
-
-def test_prefill_counters_split_real_from_pad_tokens():
-    """decode_prefill_tokens counts only REAL prompt tokens; bucket
-    padding lands in decode_prefill_pad_tokens — the overcount that
-    used to inflate the prefill-throughput stamp."""
-    tel = _tel()
-    cfg, ids, sess = _gpt_session(seed=7)
-    dec = GPTDecoder.from_session(sess, cfg, telemetry=tel)
-    x = np.random.RandomState(7).randint(0, VOCAB, (2, 5))
-    dec.generate(x, 2)                  # prompt 5 -> bucket 8 per row
-    assert tel.counter_value("decode_prefill_tokens") == 2 * 5
-    assert tel.counter_value("decode_prefill_pad_tokens") == 2 * 3
-    # a direct exact-shape prefill is all real tokens, no pad
-    dec.prefill(x)
-    assert tel.counter_value("decode_prefill_tokens") == 2 * 5 + 2 * 5
-    assert tel.counter_value("decode_prefill_pad_tokens") == 2 * 3
-
-
-def test_decoder_from_checkpoint(tmp_path):
-    cfg, ids, sess = _gpt_session(seed=3)
-    sess.executor.save(str(tmp_path))
-    dec = GPTDecoder.from_checkpoint(cfg, str(tmp_path))
-    x = np.random.RandomState(3).randint(0, VOCAB, (1, 5))
-    logits, _ = dec.prefill(x)
-    want = sess.predict({ids: x})[0]
-    np.testing.assert_allclose(np.asarray(logits), want, rtol=1e-5,
-                               atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -328,24 +230,6 @@ def test_batcher_survives_malformed_tick():
         # the thread must still be alive and serving
         ok = mb.submit({"x": np.ones((2, 3))}).result(30)
         np.testing.assert_allclose(ok, 2.0)
-
-
-def test_generate_bucketed_ragged_prompts_match_exact():
-    """generate() buckets ragged prompt lengths for prefill; the padded
-    K/V tail rows are overwritten before they become attendable, so
-    outputs equal the exact-length argmax chain for every length."""
-    cfg, ids, sess = _gpt_session(seed=4)
-    dec = GPTDecoder.from_session(sess, cfg)
-    rng = np.random.RandomState(4)
-    for p in (5, 7, 12):              # buckets 8, 8, 16 — none exact
-        x = rng.randint(0, VOCAB, (2, p))
-        got = dec.generate(x, 4)
-        cur = x.copy()
-        for _ in range(4):
-            full = sess.predict({ids: cur})[0]
-            nxt = np.argmax(full[:, -1], axis=-1).astype(np.int64)
-            cur = np.concatenate([cur, nxt[:, None]], axis=1)
-        np.testing.assert_array_equal(got, cur[:, p:])
 
 
 def test_batcher_propagates_errors_and_rejects_after_close():

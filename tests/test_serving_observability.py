@@ -18,31 +18,20 @@ from concurrent.futures import Future
 import numpy as np
 import pytest
 
-import hetu_tpu as ht
 from hetu_tpu import telemetry
-import hetu_tpu.models as M
 from hetu_tpu.serving import (ContinuousBatchingEngine, EngineOverloaded,
-                              InferenceSession, KVCacheExhausted,
-                              MicroBatcher, ReplicaRouter, RouterOverloaded,
+                              KVCacheExhausted, MicroBatcher,
+                              ReplicaRouter, RouterOverloaded,
                               ServingHTTPServer, SLOWindow)
 from hetu_tpu.telemetry.doctor import attribute_request_events
 
-VOCAB, SEQ = 64, 32
+from gpt_reference import VOCAB, gpt_session
 
 
 def _tel():
     return telemetry.Telemetry(enabled=True)
 
 
-def _gpt_session(seed=0, layers=2):
-    cfg = M.GPTConfig(vocab_size=VOCAB, hidden_size=32,
-                      num_hidden_layers=layers, num_attention_heads=4,
-                      max_position_embeddings=SEQ,
-                      hidden_dropout_prob=0.0)
-    model = M.GPTLMHeadModel(cfg)
-    ids = ht.Variable("input_ids", trainable=False)
-    sess = InferenceSession([model(ids)], seq_buckets=(SEQ,), seed=seed)
-    return cfg, ids, sess
 
 
 def _drive(engine, futures, limit=500):
@@ -63,7 +52,7 @@ def test_request_timelines_conserve_end_to_end():
     queue/prefill/decode/replay/overhead buckets sum to its measured
     e2e — the tentpole acceptance check, in-process."""
     tel = _tel()
-    cfg, ids, sess = _gpt_session(seed=0)
+    cfg, sess = gpt_session(seed=0)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
         telemetry=tel, start=False)
@@ -97,7 +86,7 @@ def test_minted_ids_and_histograms():
     """submit() without request_id mints one; the TTFT/TPOT/queue-wait
     histograms land with one observation per retired request."""
     tel = _tel()
-    cfg, ids, sess = _gpt_session(seed=1)
+    cfg, sess = gpt_session(seed=1)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
         telemetry=tel, start=False)
@@ -119,7 +108,7 @@ def test_preemption_becomes_replay_episodes():
     request's timeline carries replay episodes, the serve_preempt
     instant fires, and conservation still holds."""
     tel = _tel()
-    cfg, ids, sess = _gpt_session(seed=6)
+    cfg, sess = gpt_session(seed=6)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=7, block_size=4, max_batch_size=4,
         reserve="lazy", telemetry=tel, start=False)
@@ -147,7 +136,7 @@ def test_preemption_becomes_replay_episodes():
 # ---------------------------------------------------------------------------
 
 def test_engine_inflight_table_and_stats():
-    cfg, ids, sess = _gpt_session(seed=2)
+    cfg, sess = gpt_session(seed=2)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
         start=False)
@@ -247,7 +236,7 @@ def test_slo_window_ttft_breach():
 def test_engine_accepts_ttft_slo():
     """An engine whose requests ALL meet the e2e SLO still flips
     /healthz when TTFT breaches (timelines feed the window tel-on)."""
-    cfg, ids, sess = _gpt_session(seed=3)
+    cfg, sess = gpt_session(seed=3)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
         slo_p99_ms=1e9, slo_ttft_p99_ms=0.0001, telemetry=_tel(),
@@ -371,7 +360,7 @@ def test_http_requests_and_stats_routes():
 # ---------------------------------------------------------------------------
 
 def test_disabled_engine_allocates_no_timelines():
-    cfg, ids, sess = _gpt_session(seed=4)
+    cfg, sess = gpt_session(seed=4)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
         start=False)
