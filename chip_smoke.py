@@ -22,6 +22,12 @@ and data made from fixed seeds:
   mode: one C++ server child, the HBM device cache, the example's own
   batch size and synthetic Criteo-shaped feed.
 
+``bert`` and ``gpt_train`` also run one dropout op in a training step of
+its own at their model's mask shape (``[256, 128, 768]``, ``[16384,
+768]``): the mask kernel is in the step, the backward multiplies by the
+forward's mask element for element, and the hardware generator keeps
+0.9 of the elements within 1e-3.
+
 Each phase prints one JSON line (``phase``, ``ok``, wall seconds split
 into compile and steady, first/last loss or tokens generated,
 ``pallas_calls`` in the compiled program, ``jax``). A failed check
@@ -167,6 +173,42 @@ def pallas_calls(jitted, args, rehearse):
     return n
 
 
+def dropout_masks(shape, rehearse):
+    """One dropout op over ones of ``shape`` in a training step of its
+    own (an optimizer over a dummy parameter makes it one): the kernel
+    is in the step, what the backward multiplied by is the forward's
+    mask element for element, and the generator keeps its share."""
+    keep_prob = 0.9
+    axes = list(range(len(shape)))
+    x = ht.Variable("smoke_dropout_x", trainable=False)
+    y = ht.dropout_op(x, keep_prob)
+    total = ht.reduce_sum_op(y, axes=axes)
+    (dx,) = ht.gradients(total, [x])
+    w = ht.Variable("smoke_dropout_w", value=np.ones((1,), np.float32))
+    train_op = ht.optim.SGDOptimizer(learning_rate=0.0).minimize(
+        total + ht.reduce_sum_op(w, axes=[0]))
+    executor = ht.Executor([y, dx, train_op])
+    feed = {x: np.ones(shape, np.float32)}
+    shares = []
+    for _ in range(2):
+        out, grad = [np.asarray(r.asnumpy())
+                     for r in executor.run(feed_dict=feed)[:2]]
+        kept = out != 0
+        check(np.allclose(out[kept], 1 / keep_prob, rtol=1e-6),
+              "a kept element is not 1/keep_prob")
+        check(np.array_equal(grad, out),
+              "the backward's mask is not the forward's")
+        shares.append(float(kept.mean()))
+    n_pallas = pallas_calls(*step_program(executor, feed), rehearse)
+    check(n_pallas >= 1, "no dropout-mask kernel in the step")
+    # 1e-3 is 12 binomial standard deviations of a [16384, 768] mask
+    check(rehearse or all(abs(s - keep_prob) < 1e-3 for s in shares),
+          f"kept shares {shares} of {shape}, want {keep_prob} +- 1e-3")
+    check(shares[0] != shares[1], "two steps drew the same mask")
+    return {"dropout_kept_shares": shares,
+            "dropout_mask_kernels": n_pallas}
+
+
 # ---------------------------------------------------------------------------
 # one chip
 # ---------------------------------------------------------------------------
@@ -204,6 +246,8 @@ def phase_bert(rehearse):
           f"{args.num_layers}-layer model: the flash path did not run")
     return {"steps": len(losses), "first_loss": losses[0],
             "last_loss": end_loss, "pallas_calls": n_pallas,
+            **dropout_masks((64, 128) if rehearse else (256, 128, 768),
+                            rehearse),
             **split_seconds(results["window_seconds"])}
 
 
@@ -245,7 +289,10 @@ def phase_gpt_train(rehearse, run):
     executor.save(run.checkpoint)
     return {"steps": len(losses), "first_loss": losses[0],
             "last_loss": losses[-1], "losses": losses,
-            "pallas_calls": n_pallas, **split_seconds(seconds)}
+            "pallas_calls": n_pallas,
+            **dropout_masks((64, 128) if rehearse else (16384, 768),
+                            rehearse),
+            **split_seconds(seconds)}
 
 
 def phase_gpt_serve(rehearse, run):
@@ -473,11 +520,13 @@ def main(argv=None):
     dev = jax.devices()[0]
     count = len(jax.devices())
     if args.rehearse:
-        from hetu_tpu.ops import attention, pallas_attention, pallas_norm
+        from hetu_tpu.ops import (attention, pallas_attention,
+                                  pallas_dropout, pallas_norm)
         # steer the platform-decided kernel dispatch from here, as the
         # tests do: the program itself has no such option
         pallas_attention.INTERPRET = True
         pallas_norm.INTERPRET = True
+        pallas_dropout.INTERPRET = True
         attention._use_pallas = lambda: True
         check(count >= args.chips,
               f"rehearsal of --chips {args.chips} needs that many "

@@ -286,13 +286,25 @@ def _dropout_range(input_ranges, keep_prob):
 
 
 def _dropout_mask(ectx, op, keep_prob, shape, dtype, per_channel=False):
+    """0 or ``1/keep_prob`` per element (per ``(N, C)`` plane for
+    dropout2d), a function of ``ectx.rng_for(op)`` alone: the gradient
+    op passes the forward node and gets the forward's mask."""
+    from . import pallas_dropout
+    from .attention import unpartitioned_tpu_step
     rng = ectx.rng_for(op)
     if per_channel:
         # dropout2d: one decision per (N, C) plane
-        mask_shape = shape[:2] + (1,) * (len(shape) - 2)
+        keep = jax.random.bernoulli(
+            rng, keep_prob, shape[:2] + (1,) * (len(shape) - 2))
+    elif unpartitioned_tpu_step(ectx) and pallas_dropout.supported(shape):
+        # the bits come from the core's generator, in a kernel that
+        # takes the key's two words (the same decisions for the same
+        # key on a TPU; not the ones threefry draws from it)
+        keep = pallas_dropout.hetu_dropout_mask(
+            pallas_dropout.seed_words(rng), tuple(shape), float(keep_prob),
+            interpret=pallas_dropout.INTERPRET)
     else:
-        mask_shape = shape
-    keep = jax.random.bernoulli(rng, keep_prob, mask_shape)
+        keep = jax.random.bernoulli(rng, keep_prob, shape)
     return keep.astype(dtype) / keep_prob
 
 

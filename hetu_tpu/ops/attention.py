@@ -142,6 +142,16 @@ def _use_pallas():
     return jax.default_backend() == "tpu"
 
 
+def unpartitioned_tpu_step(ectx):
+    """Whether an op may put a Pallas kernel of its own into the step
+    it is traced in: ``_use_pallas()``, and no mesh of more than one
+    device on the step's config — such a step is partitioned by GSPMD,
+    which cannot split a Mosaic kernel, so the op keeps its composed
+    form there."""
+    mesh = getattr(getattr(ectx, "config", None), "mesh", None)
+    return _use_pallas() and (mesh is None or mesh.size == 1)
+
+
 class FlashAttentionOp(Op):
     """Fused attention over [B, H, S, D] q/k/v with an additive mask of
     shape [B, 1, 1, S] (or None)."""

@@ -259,13 +259,11 @@ class LayerNormalizationGradientOp(Op):
     def compute(self, input_vals, ectx):
         dy, x, scale = input_vals
         # one pass over the rows on a TPU (the kernel's rule is the
-        # flash kernels': the platform alone, and a last axis it tiles).
-        # A step traced under a mesh is partitioned by GSPMD, which
-        # cannot split a Mosaic kernel: it keeps the composed form.
+        # flash kernels': the platform alone, and a last axis it tiles),
+        # in a step that no mesh partitions
         from . import pallas_norm
-        from .attention import _use_pallas
-        mesh = getattr(getattr(ectx, "config", None), "mesh", None)
-        if _use_pallas() and (mesh is None or mesh.size == 1) \
+        from .attention import unpartitioned_tpu_step
+        if unpartitioned_tpu_step(ectx) \
                 and pallas_norm.supported(x.shape[-1], x.dtype.itemsize):
             return pallas_norm.hetu_layer_norm_bwd(
                 dy, x, scale, eps=self.eps,

@@ -162,3 +162,113 @@ def test_layer_norm_backward_compiles_under_a_dp_mesh(v5e, monkeypatch):
         x, x, scale).compile().as_text()
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text
+
+
+@pytest.mark.parametrize("shape", [(16, 1024, 768), (32768, 768),
+                                   (256, 12, 128, 128), (32, 128)], ids=str)
+def test_dropout_mask_kernel_compiles(one_chip, shape):
+    """The generator's seed takes two words and no more, and the
+    compare's result packs to bytes: what only Mosaic can refuse. One
+    custom call, named for the trace's reader, nothing left to XLA."""
+    from hetu_tpu.ops import pallas_dropout
+    seed = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    text = pallas_dropout.hetu_dropout_mask.lower(
+        seed, shape, 0.9).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    assert entry.count("tpu_custom_call") == 1
+    assert f"%{pallas_dropout.KERNEL_NAME}" in entry
+    assert " fusion(" not in entry
+
+
+def _gpt2_step_text(v5e_device, monkeypatch, dropout):
+    """The optimized HLO of a GPT-2 training step (the cell's widths,
+    batch and context; one layer and a vocabulary of 1024, to keep the
+    compile short) for one described chip."""
+    import numpy as np
+    import hetu_tpu as ht
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.models import GPTConfig, GPTLMHeadModel
+    from hetu_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setenv("HETU_AUTOTUNE", "0")    # the sweep needs a device
+    model = GPTLMHeadModel(GPTConfig(
+        vocab_size=1024, hidden_size=768, num_hidden_layers=1,
+        num_attention_heads=12, max_position_embeddings=1024,
+        hidden_dropout_prob=dropout, use_flash_attention=True))
+    ids = ht.Variable("input_ids", trainable=False)
+    labels = ht.Variable("labels", trainable=False)
+    _, loss = model(ids, labels)
+    lm_loss = ht.reduce_mean_op(loss, [0, 1])
+    train_op = ht.optim.AdamOptimizer(1e-4).minimize(lm_loss)
+    executor = ht.Executor([lm_loss, train_op], dtype=jnp.bfloat16,
+                           ctx=ht.cpu(0))
+    sub = executor.subexecutors["default"]
+    feed = {ids: np.zeros((16, 1024), np.int32),
+            labels: np.zeros((16, 1024), np.int32)}
+    step = sub.prepare(executor, feed)
+    sharding = SingleDeviceSharding(v5e_device)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
+                                       if not hasattr(a, "dtype")
+                                       else a.dtype, sharding=sharding),
+        sub.trace_args(executor, feed))
+    return jax.jit(step).lower(*shapes).compile().as_text()
+
+
+def test_gpt2_step_holds_the_dropout_mask_kernel(v5e, monkeypatch):
+    """The step a train cell compiles for one chip draws its three
+    dropout masks (the embedding's and two in the layer) in
+    ``hetu_dropout_mask`` calls and no ``bernoulli`` is left in it; with
+    dropout off it holds none."""
+    text = _gpt2_step_text(v5e[0], monkeypatch, 0.1)
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "hetu_dropout_mask" in ln]
+    # forward and backward: 6 where the backward draws again, 3 where
+    # the compiler merged each pair
+    print("hetu_dropout_mask calls in the step:", len(calls))
+    assert len(calls) in (3, 6), len(calls)
+    # the per-op fold_in of the key stays threefry; no draw does
+    assert "bernoulli" not in text and "rng-bit-generator" not in text
+    off = _gpt2_step_text(v5e[0], monkeypatch, 0.0)
+    assert "hetu_dropout_mask" not in off
+
+
+def test_dropout_keeps_the_composed_draw_under_a_dp_mesh(v5e, monkeypatch):
+    """Under a ``("dp", 4)`` mesh GSPMD would have to partition the
+    kernel and cannot (the first assertion pins that); the ops keep
+    ``jax.random.bernoulli`` there, forward and backward, and compile."""
+    import numpy as np
+    import types
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+    import hetu_tpu as ht
+    from hetu_tpu.ops import attention, pallas_dropout
+
+    mesh = Mesh(np.asarray(v5e), ("dp",))
+    seed = jax.ShapeDtypeStruct((2,), jnp.int32,
+                                sharding=NamedSharding(mesh, P()))
+    with pytest.raises(NotImplementedError, match="Mosaic kernels"):
+        pallas_dropout.hetu_dropout_mask.lower(
+            seed, (64, 128, 768), 0.9).compile()
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    x_node = ht.Variable("x", trainable=False)
+    forward = ht.dropout_op(x_node, 0.9)
+    backward = forward.gradient(x_node)[0]
+    key = jax.random.PRNGKey(0)
+    ectx = types.SimpleNamespace(
+        config=types.SimpleNamespace(mesh=mesh), training=True,
+        rng_for=lambda op: jax.random.fold_in(key, op.id))
+    x = jax.ShapeDtypeStruct((64, 128, 768), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    text = jax.jit(lambda x: (forward.compute([x], ectx),
+                              backward.compute([x], ectx))).lower(
+        x).compile().as_text()
+    assert "tpu_custom_call" not in text
+    one = types.SimpleNamespace(config=None, training=True,
+                                rng_for=ectx.rng_for)
+    x1 = jax.ShapeDtypeStruct((64, 128, 768), jnp.bfloat16,
+                              sharding=SingleDeviceSharding(v5e[0]))
+    assert "tpu_custom_call" in jax.jit(
+        lambda x: forward.compute([x], one)).lower(x1).compile().as_text()
