@@ -295,6 +295,79 @@ def phase_gpt_train(rehearse, run):
             **split_seconds(seconds)}
 
 
+def latent_moe_check(rehearse):
+    """The latent-attention expert block against the plain reference,
+    in bfloat16: a prefill and 8 decode steps through the paged latent
+    cache. On the chip the preset has the published head shapes (nope
+    128 / rope 64 / value 128, latent 512) at a small hidden size, so
+    the flash call at 192 / 128, the absorbed decode kernel and the
+    grouped matmul are tried before a benchmark cell is."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.families import sarvam_mla as family
+    from benchmark.reference import sarvam_mla as reference
+    from hetu_tpu.models import latent_moe as lm
+    from hetu_tpu.serving.kvcache import PagedKVCache
+
+    heads = dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                 v_head_dim=16, hidden_size=64, intermediate_size=128,
+                 moe_intermediate_size=32) if rehearse else dict(
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, hidden_size=512, intermediate_size=1024,
+        moe_intermediate_size=256)
+    prompt_len, block = (24, 4) if rehearse else (256, 16)
+    config = dict(
+        heads, vocab_size=1024, num_hidden_layers=3, num_attention_heads=4,
+        num_experts=8, num_experts_per_tok=2, num_shared_experts=1,
+        first_k_dense_replace=1, routed_scaling_factor=2.5,
+        rms_norm_eps=1e-6, rope_theta=10000, max_position_embeddings=4096,
+        rope_scaling={"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                      "mscale": 1, "mscale_all_dim": 1, "type":
+                      "deepseek_yarn",
+                      "original_max_position_embeddings": 64},
+        deployment={"num_routed_experts": 8, "experts_first": 0},
+        assumed={"initializer_std": 0.05, "router_bias_std": 0.02},
+        serve_dtype="bfloat16")
+    weights = family.seeded_weights(config, 3)
+    cfg = family.model_config(config)
+    params = lm.latent_moe_serving_params(cfg, weights.__getitem__)
+    steps = 8
+    context = 2 * prompt_len
+    cache = PagedKVCache(cfg, num_blocks=context // block, block_size=block)
+    tokens = np.random.RandomState(0).randint(
+        0, 1024, prompt_len + steps).astype(np.int32)
+    cache.add_seq(0, context)
+    prefill = jax.jit(lm.latent_moe_paged_prefill,
+                      static_argnames="config")
+    step = jax.jit(lm.latent_moe_paged_step, static_argnames="config")
+    (logits, _), pools = prefill(
+        params, cache.pools, jnp.asarray(tokens[None, :prompt_len]),
+        jnp.asarray(cache.slot_mapping(0, 0, prompt_len)[None]),
+        jnp.asarray([prompt_len - 1]), config=cfg)
+    got = [np.asarray(logits[0])]
+    grid = jnp.asarray(cache.gather_slots([0], context))
+    for pos in range(prompt_len, prompt_len + steps):
+        (logits, _), pools = step(
+            params, pools, jnp.asarray(tokens[pos:pos + 1]),
+            jnp.asarray([pos]), grid,
+            jnp.asarray([cache.slot_of(0, pos)]), config=cfg)
+        got.append(np.asarray(logits[0]))
+    rows = np.arange(prompt_len - 1, prompt_len + steps)
+    want, layers = reference.forward(weights, config, tokens, rows)
+    # a row where an expert layer's call was closer than bfloat16
+    # resolves may have swapped an expert: held to the reference where
+    # the calls were clear
+    clear = np.min([layer["margin"] for layer in layers], axis=0) > 0.01
+    err = np.sqrt(np.mean(np.square(np.asarray(got) - want), axis=-1)) \
+        / float(want.std())
+    check(clear.sum() >= 3, f"only {clear.sum()} rows with clear calls")
+    check(float(err[clear].max()) < 0.1,
+          f"latent-MoE block is {err.tolist()} of the logits' spread "
+          f"from the reference (clear rows {clear.tolist()})")
+    return {"latent_moe_worst_row_error": float(err[clear].max()),
+            "latent_moe_rows_checked": int(clear.sum())}
+
+
 def phase_gpt_serve(rehearse, run):
     from hetu_tpu.models.gpt import gpt_forward
     from hetu_tpu.serving.scheduler import ContinuousBatchingEngine
@@ -361,7 +434,8 @@ def phase_gpt_serve(rehearse, run):
             "kv_blocks": stats["kv_blocks"],
             "kv_pool_bytes": engine.cache.hbm_bytes(),
             "jit_compiles": stats["jit_compiles"],
-            "pallas_calls": n_pallas, **split_rounds(seconds)}
+            "pallas_calls": n_pallas, **latent_moe_check(rehearse),
+            **split_rounds(seconds)}
 
 
 def phase_wdl_ps(rehearse):

@@ -25,10 +25,10 @@ from ..ops.variable import Variable
 from .bert import (BertLayerNorm as LayerNorm, Dropout, Embedding,
                    Linear, _act)
 
-__all__ = ["GPTConfig", "GPTModel", "GPTLMHeadModel",
+__all__ = ["GPTConfig", "GPTModel", "GPTLMHeadModel", "GPTServingModel",
            "gpt_param_names", "gpt_serving_params", "gpt_forward",
            "gpt_paged_prefill", "gpt_paged_step",
-           "gpt_paged_suffix_prefill"]
+           "gpt_paged_suffix_prefill", "gpt_param_bytes"]
 
 
 class GPTConfig:
@@ -54,6 +54,9 @@ class GPTConfig:
         if sequence_parallel is True:
             sequence_parallel = "ring"
         self.sequence_parallel = sequence_parallel or None
+
+    def serving_model(self):
+        return GPTServingModel(self)
 
 
 def gpt_param_names(config):
@@ -286,6 +289,80 @@ def gpt_paged_suffix_prefill(params, pools, ids, starts, slot_idx,
             sm_scale=_sm_scale(q)))
     x = _serve_forward(params, x, attend, num_heads, hidden_act)
     return _serve_head(params, x), new_pools
+
+
+def gpt_param_bytes(config, dtype_bytes=4):
+    """Parameter bytes of a ``GPTLMHeadModel`` with this config (the
+    serving-params pytree :func:`gpt_serving_params` builds) — what
+    the pool sizing subtracts from the HBM budget."""
+    h = config.hidden_size
+    i = config.intermediate_size
+    per_layer = (2 * h                      # ln1
+                 + h * 3 * h + 3 * h        # qkv
+                 + h * h + h                # attn proj
+                 + 2 * h                    # ln2
+                 + h * i + i                # mlp fc
+                 + i * h + h)               # mlp proj
+    total = (config.vocab_size * h          # wte
+             + config.max_position_embeddings * h   # wpe
+             + config.num_hidden_layers * per_layer
+             + 2 * h                        # ln_f
+             + h * config.vocab_size)       # lm_head
+    return total * dtype_bytes
+
+
+class GPTServingModel:
+    """What ``ContinuousBatchingEngine`` and ``PagedKVCache`` take a
+    :class:`GPTConfig` as: the serving-model interface of
+    ``docs/serving.md`` (parameters, the cache's row layout, the
+    programs of the three cache backends, sizes)."""
+
+    # the prefill program returns [B, P, V] logits and the engine
+    # gathers each prompt's last row
+    prefill_last_row = False
+    counter_names = ()
+    vector_counter = None
+    row_record_width = 0
+
+    def __init__(self, config):
+        self.config = config
+        self.vocab_size = config.vocab_size
+        # learned positions: the table is the longest sequence
+        self.max_positions = config.max_position_embeddings
+        self.num_cache_layers = config.num_hidden_layers
+
+    def cache_layout(self):
+        """A K and a V pool a layer, rows ``hidden`` wide (a row keeps
+        its heads side by side), float32."""
+        return (("k", self.config.hidden_size, "float32"),
+                ("v", self.config.hidden_size, "float32"))
+
+    def params(self, lookup):
+        return gpt_serving_params(self.config, lookup)
+
+    def param_bytes(self):
+        return gpt_param_bytes(self.config)
+
+    def prefill_bytes_per_token(self):
+        """Bytes one prompt token costs a prefill program: its
+        ``[B, P, V]`` float32 logits and as much again in temporaries
+        (3.29e9 + 3.31e9 for 16,384 tokens of GPT-2 small compiled for
+        the described v5e, PR 23), and the block's own rows."""
+        c = self.config
+        return 2 * 4 * c.vocab_size + 4 * (4 * c.hidden_size
+                                            + 2 * c.intermediate_size)
+
+    def program(self, kind):
+        """``(function, static keywords)`` of one of the engine's four
+        programs."""
+        fn = {"prefill": gpt_paged_prefill, "decode": gpt_paged_step,
+              "decode_logits": gpt_paged_step,
+              "suffix_prefill": gpt_paged_suffix_prefill}[kind]
+        static = {"num_heads": self.config.num_attention_heads,
+                  "hidden_act": getattr(self.config, "hidden_act", "gelu")}
+        if kind == "decode":
+            static["pick"] = "greedy"
+        return fn, static
 
 
 class CausalSelfAttention:

@@ -1,0 +1,669 @@
+"""Latent-attention mixture-of-experts decoders, on the serving path.
+
+The family of DeepSeek-V2/V3-shaped models (``sarvam_mla`` is one):
+RMS norm, multi-head LATENT attention (MLA: keys and values live as one
+low-rank latent row a token plus one rotated key all heads share),
+YaRN-scaled rotary position on part of each head, SwiGLU feed-forward,
+a few leading dense layers and then expert layers (a sigmoid router
+with a selection-only bias over all experts, ``top_k`` routed experts
+and shared experts a token), untied head.
+
+This module is the serving side only: ONE forward
+(:func:`_serve_forward`) behind the same three cache backends as
+``models/gpt.py`` — whole-prompt prefill (attention EXPANDED, over the
+flash kernel), paged decode and suffix prefill (attention ABSORBED,
+against the latent rows themselves) — plus
+:class:`LatentMoEServingModel`, the object the engine takes it as
+(``docs/serving.md``, "The serving-model interface"). Training these
+blocks through ``ht.Executor`` is open (``ROADMAP.md``).
+
+**One share of an expert-parallel deployment.** ``experts_held``
+``(first, count)`` says which routed experts THIS program holds. The
+router is as wide as the model (every token is scored against every
+expert); the expert layer computes the held experts' part and the
+shared expert's, and what the experts held elsewhere would add is left
+out: on one chip the layer runs without its exchange. ``vocab_size``
+is likewise the rows of the vocabulary held here.
+
+Weights and activations are in ``dtype`` (bfloat16 as deployed); norm
+statistics, router scores, softmax and logits are float32.
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+__all__ = ["LatentMoEConfig", "LatentMoEServingModel",
+           "latent_moe_param_shapes", "latent_moe_serving_params",
+           "latent_moe_forward", "latent_moe_paged_prefill",
+           "latent_moe_paged_step", "latent_moe_paged_suffix_prefill",
+           "yarn_inv_freq", "yarn_mscale", "COUNTERS"]
+
+# what every program returns beside its tokens or logits, int32, in
+# this order, followed by the rows of each held expert and then, for
+# each row of the batch, its record (``_records``)
+COUNTERS = ("moe_tokens", "moe_routed_rows", "moe_expert_visits",
+            "mla_context_rows", "mla_score_pairs")
+
+
+class LatentMoEConfig:
+    """Widths are the published ones; what a deployment cuts is depth
+    (``num_hidden_layers``), the experts held (``experts_held``) and
+    the vocabulary rows held (``vocab_size``)."""
+
+    def __init__(self, vocab_size, hidden_size, num_hidden_layers,
+                 num_attention_heads, kv_lora_rank, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, intermediate_size,
+                 moe_intermediate_size, num_routed_experts,
+                 num_experts_per_tok, num_shared_experts=1,
+                 first_k_dense_replace=1, experts_held=None,
+                 routed_scaling_factor=1.0, rms_norm_eps=1e-6,
+                 rope_theta=10000.0, rope_scaling=None,
+                 max_position_embeddings=131072, dtype="bfloat16"):
+        self.vocab_size = vocab_size
+        self.hidden_size = hidden_size
+        self.num_hidden_layers = num_hidden_layers
+        self.num_attention_heads = num_attention_heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.intermediate_size = intermediate_size
+        self.moe_intermediate_size = moe_intermediate_size
+        self.num_routed_experts = num_routed_experts
+        self.num_experts_per_tok = num_experts_per_tok
+        self.num_shared_experts = num_shared_experts
+        self.first_k_dense_replace = first_k_dense_replace
+        first, count = experts_held or (0, num_routed_experts)
+        if first < 0 or count < 1 or first + count > num_routed_experts:
+            raise ValueError(
+                f"experts_held {(first, count)} is not a range of the "
+                f"{num_routed_experts} routed experts")
+        self.experts_held = (int(first), int(count))
+        self.routed_scaling_factor = routed_scaling_factor
+        self.rms_norm_eps = rms_norm_eps
+        self.rope_theta = rope_theta
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        self.max_position_embeddings = max_position_embeddings
+        self.dtype = dtype
+
+    @property
+    def q_head_dim(self):
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_row_width(self):
+        """Lanes of one cache row: the latent beside the rotated key,
+        padded with zeros to whole 128-lane tiles (576 -> 640 at the
+        published widths). A TPU tiles a row's minor dimension to 128
+        lanes whatever the program says; asked for 576 it lays the pool
+        out with the BLOCK index minor instead, and every program then
+        copies each layer's whole pool into a row-major layout and
+        back: 1.24 s of a 4 s window went to those copies (my chip
+        run, PR 32)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    def is_dense(self, layer):
+        return layer < self.first_k_dense_replace
+
+    def serving_model(self):
+        return LatentMoEServingModel(self)
+
+
+# ---------------------------------------------------------------------------
+# rotary position, YaRN-scaled (the deepseek_yarn convention)
+# ---------------------------------------------------------------------------
+
+def yarn_mscale(scale, mscale=1.0):
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim, theta, scaling):
+    """``[dim / 2]`` inverse frequencies. Without scaling ``theta **
+    (-2i / dim)``; with ``deepseek_yarn``, dimensions that turn more
+    than ``beta_fast`` times over the original context keep theirs,
+    those that turn less than ``beta_slow`` times are slowed by
+    ``factor``, and a linear ramp blends between."""
+    exponent = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** exponent
+    if not scaling:
+        return extra
+    inter = extra / scaling["factor"]
+    orig = scaling["original_max_position_embeddings"]
+
+    def correction_dim(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def softmax_scale(config):
+    """``q_head_dim ** -0.5``, times ``mscale ** 2`` under YaRN with
+    ``mscale_all_dim`` (the rotary tables' own factor is then
+    ``mscale / mscale_all_dim``'s ratio, 1 for the published file)."""
+    scale = config.q_head_dim ** -0.5
+    s = config.rope_scaling
+    if s and s.get("mscale_all_dim"):
+        scale *= yarn_mscale(s["factor"], s["mscale_all_dim"]) ** 2
+    return scale
+
+
+def _rope_tables(config, positions):
+    """(cos, sin) ``[..., rope / 2]`` float32 for int32 ``positions``."""
+    import jax.numpy as jnp
+    inv = jnp.asarray(yarn_inv_freq(config.qk_rope_head_dim,
+                                    config.rope_theta, config.rope_scaling),
+                      jnp.float32)
+    angles = positions[..., None].astype(jnp.float32) * inv
+    s = config.rope_scaling
+    factor = 1.0 if not s else (
+        yarn_mscale(s["factor"], s.get("mscale", 1.0))
+        / yarn_mscale(s["factor"], s.get("mscale_all_dim", 0.0)))
+    return jnp.cos(angles) * factor, jnp.sin(angles) * factor
+
+
+def _rope(x, cos, sin):
+    """Rotate ``x [..., rope]`` in halves: pair ``(i, i + rope / 2)``
+    turns by ``position * inv_freq[i]`` (the source model stores the
+    pairs interleaved; that is a fixed permutation of the projection's
+    columns). ``cos`` / ``sin`` broadcast against ``x``'s halves."""
+    import jax.numpy as jnp
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half].astype(jnp.float32), \
+        x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _rms(x, weight, eps):
+    import jax
+    import jax.numpy as jnp
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                            + eps)
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+def latent_moe_param_shapes(config):
+    """``{name: (shape, kind)}`` of every serving parameter; ``kind``
+    is ``"matrix"`` (in the model's dtype), ``"norm"`` (float32 ones at
+    initialisation), ``"router"`` or ``"router_bias"`` (float32)."""
+    c = config
+    h, nh = c.hidden_size, c.num_attention_heads
+    held = c.experts_held[1]
+    out = {"lm_embed": ((c.vocab_size, h), "matrix"),
+           "lm_norm": ((h,), "norm"),
+           "lm_head": ((h, c.vocab_size), "matrix")}
+    for i in range(c.num_hidden_layers):
+        p = f"lm_h{i}_"
+        out.update({
+            p + "attn_norm": ((h,), "norm"),
+            p + "q": ((h, nh * c.q_head_dim), "matrix"),
+            p + "q_norm": ((c.q_head_dim,), "norm"),
+            p + "kv_a": ((h, c.kv_lora_rank + c.qk_rope_head_dim),
+                         "matrix"),
+            p + "kv_norm": ((c.kv_lora_rank,), "norm"),
+            p + "kv_b": ((c.kv_lora_rank,
+                          nh * (c.qk_nope_head_dim + c.v_head_dim)),
+                         "matrix"),
+            p + "o": ((nh * c.v_head_dim, h), "matrix"),
+            p + "ffn_norm": ((h,), "norm")})
+        if c.is_dense(i):
+            out[p + "mlp_gate_up"] = ((h, 2 * c.intermediate_size),
+                                      "matrix")
+            out[p + "mlp_down"] = ((c.intermediate_size, h), "matrix")
+            continue
+        shared = c.num_shared_experts * c.moe_intermediate_size
+        out.update({
+            p + "router": ((h, c.num_routed_experts), "router"),
+            p + "router_bias": ((c.num_routed_experts,), "router_bias"),
+            p + "shared_gate_up": ((h, 2 * shared), "matrix"),
+            p + "shared_down": ((shared, h), "matrix"),
+            p + "experts_gate_up": (
+                (held, h, 2 * c.moe_intermediate_size), "matrix"),
+            p + "experts_down": (
+                (held, c.moe_intermediate_size, h), "matrix")})
+    return out
+
+
+def latent_moe_serving_params(config, lookup):
+    """The serving block's parameter pytree from ``lookup(name)``.
+    Matrices are taken in the model's dtype (an array that already has
+    it is not copied), norms and the router in float32."""
+    import jax.numpy as jnp
+    dtype = jnp.dtype(config.dtype)
+
+    def get(name, kind):
+        return jnp.asarray(lookup(name),
+                           dtype if kind == "matrix" else jnp.float32)
+
+    flat = {name: get(name, kind) for name, (_, kind)
+            in latent_moe_param_shapes(config).items()}
+    blocks = []
+    for i in range(config.num_hidden_layers):
+        p = f"lm_h{i}_"
+        blocks.append({k[len(p):]: v for k, v in flat.items()
+                       if k.startswith(p)})
+    return {"embed": flat["lm_embed"], "norm": flat["lm_norm"],
+            "head": flat["lm_head"], "blocks": blocks}
+
+
+# ---------------------------------------------------------------------------
+# the forward
+# ---------------------------------------------------------------------------
+
+def _token_chunks(fn, x, valid):
+    """``fn(x [T, H], valid [T]) -> (y [T, H], picks [T, k], counts)``
+    over at most ``ops.moe.TOKEN_CHUNK`` tokens at a time, one pass
+    after another, so that a feed-forward's temporaries are a chunk's
+    whatever the prompt bucket; ``counts`` add up over the passes."""
+    import jax
+    import jax.numpy as jnp
+    from ..ops.moe import TOKEN_CHUNK
+    t = x.shape[0]
+    if t <= TOKEN_CHUNK:
+        return fn(x, valid)
+    pad = -t % TOKEN_CHUNK
+
+    def chunks(a):
+        a = jnp.concatenate([a, jnp.zeros((pad, *a.shape[1:]), a.dtype)])
+        return a.reshape(-1, TOKEN_CHUNK, *a.shape[1:])
+
+    y, picks, counts = jax.lax.map(lambda c: fn(*c),
+                                   (chunks(x), chunks(valid)))
+    return (y.reshape(-1, y.shape[-1])[:t],
+            picks.reshape(-1, picks.shape[-1])[:t],
+            jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), counts))
+
+
+def _feed_forward(config, blk, x, valid):
+    """One layer's feed-forward on ``x [T, H]``: SwiGLU in a dense
+    layer; in an expert layer the shared expert plus the held experts'
+    part of the routed sum. Returns ``(y [T, H], the router's picks
+    [T, k] int32 (zeros in a dense layer), (rows by held expert [held]
+    int32, held experts visited))``."""
+    import jax.numpy as jnp
+    from ..ops import moe
+    held = config.experts_held[1]
+    if "mlp_gate_up" in blk:
+        return (moe.swiglu(x, blk["mlp_gate_up"], blk["mlp_down"]),
+                jnp.zeros((x.shape[0], config.num_experts_per_tok),
+                          jnp.int32),
+                (jnp.zeros(held, jnp.int32), jnp.int32(0)))
+    experts, weights, _ = moe.route(
+        x, blk["router"], blk["router_bias"], config.num_experts_per_tok,
+        config.routed_scaling_factor)
+    routed, rows = moe.held_experts(
+        x, experts, weights, valid, blk["experts_gate_up"],
+        blk["experts_down"], first=config.experts_held[0])
+    shared = moe.swiglu(x, blk["shared_gate_up"], blk["shared_down"])
+    y = (shared.astype(jnp.float32) + routed).astype(x.dtype)
+    return y, experts, (rows, jnp.sum(rows > 0).astype(jnp.int32))
+
+
+def _serve_forward(params, config, x, positions, attend, valid):
+    """THE decoder stack, written once: embedded tokens ``x [..., H]``
+    at int32 ``positions [...]`` through every layer and the final
+    norm. The cache backend is ``attend(i, blk, q, row)``: layer
+    ``i``'s queries ``[..., heads, nope + rope]`` (normed, rotated) and
+    cache rows ``[..., row width]`` (``[c ; k_r ; 0]``) arrive, it
+    writes the rows wherever its cache lives and returns the context
+    ``[..., heads, v]``. ``valid [...]`` marks real tokens: a padded
+    one is routed to no expert and counted nowhere. Returns ``(hidden
+    states, each expert layer's picks [..., expert layers, k], rows by
+    held expert [held], held experts visited)``."""
+    import jax.numpy as jnp
+    c = config
+    lead = x.shape[:-1]
+    nh, latent, nope = c.num_attention_heads, c.kv_lora_rank, \
+        c.qk_nope_head_dim
+    cos, sin = _rope_tables(c, positions)
+    pad = c.cache_row_width - latent - c.qk_rope_head_dim
+    flat_valid = valid.reshape(-1)
+    rows_total = jnp.zeros(c.experts_held[1], jnp.int32)
+    visits = jnp.int32(0)
+    picks = []
+    for i, blk in enumerate(params["blocks"]):
+        h = _rms(x, blk["attn_norm"], c.rms_norm_eps)
+        kv = h @ blk["kv_a"]
+        row = jnp.concatenate(
+            [_rms(kv[..., :latent], blk["kv_norm"], c.rms_norm_eps),
+             _rope(kv[..., latent:], cos, sin),
+             jnp.zeros((*lead, pad), kv.dtype)], axis=-1)
+        q = _rms((h @ blk["q"]).reshape(*lead, nh, c.q_head_dim),
+                 blk["q_norm"], c.rms_norm_eps)
+        q = jnp.concatenate(
+            [q[..., :nope], _rope(q[..., nope:], cos[..., None, :],
+                                  sin[..., None, :])], axis=-1)
+        ctx = attend(i, blk, q, row).astype(x.dtype)
+        x = x + ctx.reshape(*lead, nh * c.v_head_dim) @ blk["o"]
+        h = _rms(x, blk["ffn_norm"], c.rms_norm_eps)
+        y, picked, (rows, seen) = _token_chunks(
+            lambda xc, vc, blk=blk: _feed_forward(c, blk, xc, vc),
+            h.reshape(-1, h.shape[-1]), flat_valid)
+        x = x + y.reshape(x.shape)
+        rows_total, visits = rows_total + rows, visits + seen
+        if not c.is_dense(i):
+            picks.append(picked.reshape(*lead, -1))
+    picks = jnp.stack(picks, axis=-2) if picks else jnp.zeros(
+        (*lead, 0, c.num_experts_per_tok), jnp.int32)
+    return _rms(x, params["norm"], c.rms_norm_eps), picks, rows_total, visits
+
+
+def _kv_b(config, blk):
+    """The up-projection split: ``(W_k [latent, heads, nope], W_v
+    [latent, heads, v])``."""
+    c = config
+    w = blk["kv_b"].reshape(c.kv_lora_rank, c.num_attention_heads,
+                            c.qk_nope_head_dim + c.v_head_dim)
+    return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
+
+
+def _expanded(config, blk, q, row):
+    """Causal attention among this call's tokens with per-head keys
+    and values rebuilt from the latent (``q``, ``row`` ``[B, S, ...]``)."""
+    import jax.numpy as jnp
+    from ..ops.attention import mla_expanded_attention
+    c = config
+    latent = c.kv_lora_rank
+    w_k, w_v = _kv_b(c, blk)
+    k_n = jnp.einsum("bsl,lhd->bshd", row[..., :latent], w_k)
+    v = jnp.einsum("bsl,lhd->bshd", row[..., :latent], w_v)
+    k_r = jnp.broadcast_to(
+        row[..., None, latent:latent + c.qk_rope_head_dim],
+        (*k_n.shape[:-1], c.qk_rope_head_dim))
+    return mla_expanded_attention(
+        q, jnp.concatenate([k_n, k_r], axis=-1), v, softmax_scale(c))
+
+
+def _absorb(config, blk, q):
+    """``(q~ [..., heads, latent], q_r [..., heads, row - latent])``:
+    the query through the key up-projection, and its rotated part
+    padded with zeros as the cache row is."""
+    import jax.numpy as jnp
+    c = config
+    w_k, _ = _kv_b(c, blk)
+    nope = c.qk_nope_head_dim
+    pad = c.cache_row_width - c.kv_lora_rank - c.qk_rope_head_dim
+    return (jnp.einsum("...hd,lhd->...hl", q[..., :nope], w_k),
+            jnp.pad(q[..., nope:], ((0, 0),) * (q.ndim - 1) + ((0, pad),)))
+
+
+def _unabsorb(config, blk, ctx_latent, dtype):
+    import jax.numpy as jnp
+    _, w_v = _kv_b(config, blk)
+    return jnp.einsum("...hl,lhd->...hd", ctx_latent.astype(dtype), w_v)
+
+
+def _pool_scatter(pool, slots, rows):
+    """Write ``rows [..., W]`` into flat slots ``slots [...]`` of one
+    layer's pool ``[num_blocks, block_size, W]`` (duplicates, the
+    padded lanes on the scratch block, resolve to SOME row). The slot
+    is split into (block, row in block) and the pool indexed as it
+    lies: flattening a bfloat16 pool first costs two pool-sized copies
+    a layer a step on the chip (the flat and the blocked layouts tile
+    differently)."""
+    block_size, width = pool.shape[-2:]
+    flat = slots.reshape(-1)
+    return pool.at[flat // block_size, flat % block_size].set(
+        rows.reshape(-1, width).astype(pool.dtype), mode="drop")
+
+
+def _paged_attend(pools, write_slots, attention):
+    """The block-paged backend: layer ``i``'s rows scatter into
+    ``pools[i]["c"]`` at ``write_slots``, then ``attention(blk, q, row,
+    pool)`` reads the updated pool. Returns ``(attend, new_pools)``."""
+    new_pools = []
+
+    def attend(i, blk, q, row):
+        pool = _pool_scatter(pools[i]["c"], write_slots, row)
+        new_pools.append({"c": pool})
+        return attention(blk, q, row, pool)
+
+    return attend, new_pools
+
+
+def _records(picks, logits):
+    """One int32 record a row of the batch, of the token the row's
+    float32 ``logits [B, V]`` decide: the experts each expert layer's
+    router picked for it (``picks [B, expert layers, k]``), then the
+    bits of the row's best logit. What the engine hands back beside a
+    generated token (``Future.token_records``); a checker forces a
+    reference onto the same routing with it
+    (:meth:`LatentMoEServingModel.read_records`)."""
+    import jax
+    import jax.numpy as jnp
+    best = jax.lax.bitcast_convert_type(
+        jnp.max(logits, axis=-1).astype(jnp.float32), jnp.int32)
+    return jnp.concatenate(
+        [picks.reshape(picks.shape[0], -1).astype(jnp.int32),
+         best[:, None]], axis=1)
+
+
+def _counters(config, valid, rows, visits, context_rows, score_pairs,
+              records):
+    """The int32 vector every program returns (``COUNTERS``, then the
+    rows of each held expert, of the REAL tokens of this call; then
+    each batch row's record, row after row)."""
+    import jax.numpy as jnp
+    c = config
+    moe_layers = c.num_hidden_layers - c.first_k_dense_replace
+    head = jnp.stack([
+        jnp.sum(valid).astype(jnp.int32) * moe_layers,
+        jnp.sum(rows), visits,
+        context_rows.astype(jnp.int32) * c.num_hidden_layers,
+        score_pairs.astype(jnp.int32) * c.num_hidden_layers])
+    return jnp.concatenate(
+        [head, rows, records.reshape(-1)]).astype(jnp.int32)
+
+
+def _logits(params, x):
+    import jax.numpy as jnp
+    return jnp.dot(x, params["head"], preferred_element_type=jnp.float32)
+
+
+def latent_moe_forward(params, ids, config):
+    """Plain causal forward over ``ids [B, S]`` with no cache: the
+    serving block with an ``attend`` that writes nothing. Returns
+    float32 logits ``[B, S, V]``."""
+    import jax.numpy as jnp
+    positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
+    x, _, _, _ = _serve_forward(
+        params, config, params["embed"][ids], positions,
+        lambda i, blk, q, row: _expanded(config, blk, q, row),
+        jnp.ones(ids.shape, bool))
+    return _logits(params, x)
+
+
+def latent_moe_paged_prefill(params, pools, ids, slot_idx, last_pos,
+                             config):
+    """Prompt phase over a block-paged latent pool: causal forward over
+    ``ids [B, P]`` that scatters every position's ``[c ; k_r]`` row
+    into ``slot_idx [B, P]`` (padded rows and positions point at the
+    scratch block) and attends expanded, over the flash kernel.
+    ``last_pos [B]`` is each prompt's last real position: the program
+    takes that row alone through the head, so ``[B, V]`` float32 logits
+    leave it and never ``[B, P, V]``. Returns ``((logits, counters),
+    pools)``; jit with ``pools`` donated."""
+    import jax.numpy as jnp
+    positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
+    valid = slot_idx >= pools[0]["c"].shape[1]      # off the scratch block
+    attend, new_pools = _paged_attend(
+        pools, slot_idx,
+        lambda blk, q, row, pool: _expanded(config, blk, q, row))
+    x, picks, rows, visits = _serve_forward(
+        params, config, params["embed"][ids], positions, attend, valid)
+    at = last_pos.astype(jnp.int32)
+    last = jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0]
+    picks = jnp.take_along_axis(picks, at[:, None, None, None], axis=1)[:, 0]
+    lengths = jnp.sum(valid, axis=1)
+    logits = _logits(params, last)
+    counters = _counters(config, valid, rows, visits, jnp.sum(lengths),
+                         jnp.sum(lengths * (lengths + 1) // 2),
+                         _records(picks, logits))
+    return (logits, counters), new_pools
+
+
+def latent_moe_paged_step(params, pools, tokens, positions, slot_idx,
+                          write_slots, config, pick=None):
+    """Paged single-token forward for a RAGGED batch, as
+    ``models/gpt.py:gpt_paged_step`` is: ``tokens [B]`` each at its own
+    ``positions [B]``, rows written to ``write_slots [B]``, attention
+    ABSORBED against the rows gathered through ``slot_idx [B, S]``.
+    With ``pick="greedy"`` returns ``(int32 [B + n]: the argmax of each
+    lane's float32 logits, then the counters; pools)`` — one vector,
+    one host sync; with ``pick=None`` ``((logits [B, V], counters),
+    pools)``."""
+    import jax.numpy as jnp
+    from ..ops.attention import mla_decode_attention
+    if pick not in (None, "greedy"):
+        raise ValueError(f"pick must be None or 'greedy', got {pick!r}")
+    scale = softmax_scale(config)
+    dtype = params["embed"].dtype
+    valid = write_slots >= pools[0]["c"].shape[1]
+
+    def attention(blk, q, row, pool):
+        q_abs, q_rope = _absorb(config, blk, q)
+        ctx = mla_decode_attention(q_abs, q_rope, pool, slot_idx,
+                                   positions, scale)
+        return _unabsorb(config, blk, ctx, dtype)
+
+    attend, new_pools = _paged_attend(pools, write_slots, attention)
+    x, picks, rows, visits = _serve_forward(
+        params, config, params["embed"][tokens], positions, attend, valid)
+    context = jnp.sum(jnp.where(valid, positions + 1, 0))
+    logits = _logits(params, x)
+    counters = _counters(config, valid, rows, visits, context, context,
+                         _records(picks, logits))
+    if pick == "greedy":
+        return jnp.concatenate(
+            [jnp.argmax(logits, axis=-1).astype(jnp.int32),
+             counters]), new_pools
+    return (logits, counters), new_pools
+
+
+def latent_moe_paged_suffix_prefill(params, pools, ids, starts, slot_idx,
+                                    write_slots, config):
+    """A CHUNK of prompt positions into an existing block table, as
+    ``models/gpt.py:gpt_paged_suffix_prefill`` is (chunked prefill, and
+    the suffix behind a prefix-cache hit): ``ids [B, C]`` from token
+    offset ``starts [B]``, attention ABSORBED over the whole history
+    gathered through ``slot_idx [B, S]``. Returns ``((logits [B, C, V],
+    counters), pools)``."""
+    import jax.numpy as jnp
+    from ..ops.attention import mla_prefill_attention
+    scale = softmax_scale(config)
+    dtype = params["embed"].dtype
+    positions = starts[:, None] + jnp.arange(ids.shape[1])[None, :]
+    valid = write_slots >= pools[0]["c"].shape[1]
+
+    def attention(blk, q, row, pool):
+        q_abs, q_rope = _absorb(config, blk, q)
+        ctx = mla_prefill_attention(q_abs, q_rope, pool, slot_idx, starts,
+                                    scale)
+        return _unabsorb(config, blk, ctx, dtype)
+
+    attend, new_pools = _paged_attend(pools, write_slots, attention)
+    x, picks, rows, visits = _serve_forward(
+        params, config, params["embed"][ids], positions, attend, valid)
+    context = jnp.sum(jnp.where(valid, positions + 1, 0))
+    logits = _logits(params, x)
+    # a row's record is of its last real position in this chunk
+    at = jnp.maximum(jnp.sum(valid, axis=1) - 1, 0).astype(jnp.int32)
+    counters = _counters(
+        config, valid, rows, visits, context, context, _records(
+            jnp.take_along_axis(picks, at[:, None, None, None],
+                                axis=1)[:, 0],
+            jnp.take_along_axis(logits, at[:, None, None], axis=1)[:, 0]))
+    return (logits, counters), new_pools
+
+
+# ---------------------------------------------------------------------------
+# what the engine takes the model as
+# ---------------------------------------------------------------------------
+
+class LatentMoEServingModel:
+    """The serving-model interface (``docs/serving.md``) for a
+    :class:`LatentMoEConfig`."""
+
+    # the prefill program takes each row's last position and returns
+    # [B, V] logits ([8, 8192, 65536] float32 would be 17 GB)
+    prefill_last_row = True
+
+    def __init__(self, config):
+        self.config = config
+        self.vocab_size = config.vocab_size
+        self.max_positions = config.max_position_embeddings
+        self.num_cache_layers = config.num_hidden_layers
+        self.counter_names = COUNTERS
+        self.vector_counter = ("moe_rows_by_expert",
+                               config.experts_held[1])
+        # int32 words a batch row's record takes behind the counters
+        self._moe_layers = config.num_hidden_layers \
+            - config.first_k_dense_replace
+        self.row_record_width = \
+            self._moe_layers * config.num_experts_per_tok + 1
+
+    def read_records(self, records):
+        """``Future.token_records [n, width]`` taken apart:
+        ``{"router_picks": [n, expert layers, k] int32, "best_logit":
+        [n] float32}`` of the row that decided each generated token."""
+        records = np.ascontiguousarray(records, np.int32)
+        return {"router_picks": records[:, :-1].reshape(
+                    len(records), self._moe_layers, -1),
+                "best_logit": records[:, -1].view(np.float32)}
+
+    def cache_layout(self):
+        """One pool a layer: the latent row beside the rotated key, in
+        whole 128-lane tiles (``LatentMoEConfig.cache_row_width``)."""
+        return (("c", self.config.cache_row_width, self.config.dtype),)
+
+    def params(self, lookup):
+        return latent_moe_serving_params(self.config, lookup)
+
+    @property
+    def _itemsize(self):
+        import jax.numpy as jnp     # numpy alone does not know bfloat16
+        return jnp.dtype(self.config.dtype).itemsize
+
+    def param_bytes(self):
+        """Matrices in the model's dtype; norms and the router float32."""
+        return int(sum(
+            int(np.prod(shape)) * (self._itemsize if kind == "matrix" else 4)
+            for shape, kind in
+            latent_moe_param_shapes(self.config).values()))
+
+    def prefill_bytes_per_token(self):
+        """Bytes of temporaries one prompt token costs a prefill
+        program at its widest point, the expanded attention: the
+        queries, keys and padded values, each token-major and
+        head-major, the up-projection's output and the context, and a
+        few float32 rows of the residual stream."""
+        c = self.config
+        per_head = 7 * c.q_head_dim + c.qk_nope_head_dim + c.v_head_dim
+        return (c.num_attention_heads * per_head * self._itemsize
+                + 6 * c.hidden_size * 4)
+
+    def program(self, kind):
+        """``(function, static keywords)`` of one of the engine's four
+        programs."""
+        fn = {"prefill": latent_moe_paged_prefill,
+              "decode": latent_moe_paged_step,
+              "decode_logits": latent_moe_paged_step,
+              "suffix_prefill": latent_moe_paged_suffix_prefill}[kind]
+        static = {"config": self.config}
+        if kind == "decode":
+            static["pick"] = "greedy"
+        return fn, static

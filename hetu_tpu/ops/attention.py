@@ -24,7 +24,9 @@ __all__ = ["flash_attention_op", "FlashAttentionOp", "attention_reference",
            "ring_attention_op", "RingAttentionOp",
            "ulysses_attention_op", "UlyssesAttentionOp",
            "prefill_attention",
-           "paged_decode_attention", "paged_prefill_attention"]
+           "paged_decode_attention", "paged_prefill_attention",
+           "mla_expanded_attention", "mla_decode_attention",
+           "mla_prefill_attention"]
 
 
 def attention_reference(q, k, v, mask, sm_scale):
@@ -132,6 +134,92 @@ def prefill_attention(q, k, v, sm_scale, causal=True):
         mask = jnp.where(jnp.tril(jnp.ones((s, s), bool)), 0.0,
                          -1e9)[None, None]
     return attention_reference(q, k, v, mask, sm_scale)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) attention — the three calls of models/latent_moe.py. A
+# cache row is ``[c ; k_r]``: the normed latent and the one rotated key
+# all heads share. A whole prompt attends EXPANDED (per-head keys and
+# values rebuilt from the latent, the flash kernel); a decode step or a
+# chunk behind a cached prefix attends ABSORBED, against the rows
+# themselves: ``q~ = q_n W_k^T`` scores against ``c``, the context is
+# ``softmax . c``, and the caller takes it through ``W_v``. The same
+# numbers, and no per-head key or value is ever stored.
+# ---------------------------------------------------------------------------
+
+def mla_expanded_attention(q, k, v, sm_scale):
+    """Causal attention among the tokens of this call, token-major
+    ``q`` / ``k`` ``[B, S, H, Dq]`` and ``v`` ``[B, S, H, Dv]`` with
+    ``Dv <= Dq`` (192 and 128 in the published models): the flash
+    kernel takes one head size, so the values are padded to the keys'
+    and the context cut back. Returns ``[B, S, H, Dv]``."""
+    dq, dv = q.shape[-1], v.shape[-1]
+    if dv < dq:
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, dq - dv),))
+    ctx = prefill_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+                            sm_scale=sm_scale, causal=True)
+    return ctx.transpose(0, 2, 1, 3)[..., :dv]
+
+
+def _gather_latent_rows(pool, slot_idx):
+    """One layer's latent rows at ``slot_idx`` ``[B, S]``, as ``[B, S,
+    W]``. A sequence's slots are contiguous inside a block, so where
+    the grid is whole blocks the gather moves blocks, not rows."""
+    block_size, width = pool.shape[-2:]
+    b, s = slot_idx.shape
+    if pool.ndim == 3 and s % block_size == 0:
+        blocks = slot_idx[:, ::block_size] // block_size
+        return pool[blocks].reshape(b, s, width)
+    return pool.reshape(-1, width)[slot_idx]
+
+
+def mla_decode_attention(q_abs, q_rope, pool, slot_idx, positions,
+                         sm_scale):
+    """One query token per sequence, absorbed, against a block-paged
+    latent pool. ``q_abs`` ``[B, H, L]`` (the query through the key
+    up-projection), ``q_rope`` ``[B, H, R]``; ``pool`` ``[num_blocks,
+    block_size, L + R]``; ``slot_idx`` ``[B, S]`` and ``positions``
+    ``[B]`` as :func:`paged_decode_attention` takes them. Returns the
+    context in the latent space, ``[B, H, L]`` (float32 off the
+    kernel, the queries' dtype on it)."""
+    rows = _gather_latent_rows(pool, slot_idx)
+    latent = q_abs.shape[-1]
+    if _use_pallas():
+        from . import pallas_mla
+        if pallas_mla.supported(latent, q_rope.shape[-1], rows.shape[1]):
+            q = jnp.concatenate([q_abs, q_rope], axis=-1).astype(rows.dtype)
+            return pallas_mla.mla_decode(q, rows, positions, sm_scale,
+                                         latent)
+    c, k_r = rows[..., :latent], rows[..., latent:]
+    scores = (jnp.einsum("bhl,bsl->bhs", q_abs, c,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhr,bsr->bhs", q_rope, k_r,
+                           preferred_element_type=jnp.float32)) * sm_scale
+    valid = jnp.arange(slot_idx.shape[1])[None, :] <= positions[:, None]
+    probs = jax.nn.softmax(jnp.where(valid[:, None, :], scores, -1e9),
+                           axis=-1)
+    return jnp.einsum("bhs,bsl->bhl", probs.astype(c.dtype), c,
+                      preferred_element_type=jnp.float32)
+
+
+def mla_prefill_attention(q_abs, q_rope, pool, slot_idx, starts, sm_scale):
+    """A chunk of query tokens per sequence, absorbed: the
+    suffix-prefill analogue of :func:`mla_decode_attention`. ``q_abs``
+    ``[B, C, H, L]``, ``q_rope`` ``[B, C, H, R]``; chunk row ``i`` sees
+    positions ``<= starts[b] + i``. The chunk's own rows must already
+    be in the pool. Returns ``[B, C, H, L]`` float32."""
+    rows = _gather_latent_rows(pool, slot_idx)
+    latent = q_abs.shape[-1]
+    c, k_r = rows[..., :latent], rows[..., latent:]
+    scores = (jnp.einsum("bihl,bsl->bhis", q_abs, c,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bihr,bsr->bhis", q_rope, k_r,
+                           preferred_element_type=jnp.float32)) * sm_scale
+    pos = starts[:, None] + jnp.arange(q_abs.shape[1])[None, :]
+    valid = jnp.arange(slot_idx.shape[1])[None, None, :] <= pos[:, :, None]
+    probs = jax.nn.softmax(jnp.where(valid[:, None], scores, -1e9), axis=-1)
+    return jnp.einsum("bhis,bsl->bihl", probs.astype(c.dtype), c,
+                      preferred_element_type=jnp.float32)
 
 
 def _use_pallas():

@@ -43,6 +43,21 @@ __all__ = ["flash_attention", "flash_attention_with_lse",
 
 NEG_INF = -1e30
 LANES = 128      # TPU minor-dim tile: residual vectors store lane-tiled
+# A kernel gets 16 MiB of VMEM without asking. The forward holds a
+# head's whole K and V on chip, double-buffered: past this many bytes of
+# them (S = 8192 at D = 192 is 16.8e6) it asks for what it needs.
+_DEFAULT_VMEM = 12 * 1024 * 1024
+_MOST_VMEM = 100 * 1024 * 1024
+
+
+def _forward_compiler_params(s, d, itemsize):
+    """``{}`` at every shape that fits the default VMEM (so those
+    kernels compile as they always have), else the limit to ask for."""
+    resident = 2 * 2 * s * (-(-d // LANES) * LANES) * itemsize
+    if resident <= _DEFAULT_VMEM:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(_MOST_VMEM, 2 * resident))}
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
@@ -326,6 +341,7 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
 
     o_shape = jax.ShapeDtypeStruct((b * h, s, d), q.dtype)
     o_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0))
+    more_vmem = _forward_compiler_params(s, d, q.dtype.itemsize)
     if need_lse:  # jit-ok: static argname
         # the lse residual is emitted only when a consumer exists (the
         # fused backward); the inference/serving forward skips the write
@@ -339,7 +355,7 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
             out_specs=[o_spec,
                        pl.BlockSpec((1, block_q, LANES),
                                     lambda bh, qi: (bh, qi, 0))],
-            interpret=interpret,
+            interpret=interpret, **more_vmem,
         )(*args)
         return out.reshape(b, h, s, d), lse[:, :, 0].reshape(b, h, s)
     out = pl.pallas_call(
@@ -348,7 +364,7 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=o_spec,
-        interpret=interpret,
+        interpret=interpret, **more_vmem,
     )(*args)
     return out.reshape(b, h, s, d)
 

@@ -57,6 +57,8 @@ import math
 
 import numpy as np
 
+from ..models.gpt import gpt_param_bytes  # noqa: F401  its older home
+
 __all__ = ["KVCacheExhausted", "BlockAllocator", "PagedKVCache",
            "PrefixCache", "kv_block_bytes", "gpt_param_bytes",
            "blocks_for_budget", "DEFAULT_BLOCK_SIZE"]
@@ -76,30 +78,16 @@ class KVCacheExhausted(RuntimeError):
     it escape means a caller bypassed admission control."""
 
 
-def kv_block_bytes(config, block_size, dtype_bytes=4):
-    """HBM bytes one cache block costs across ALL layers (K + V)."""
-    return (2 * config.num_hidden_layers * int(block_size)
-            * config.hidden_size * dtype_bytes)
-
-
-def gpt_param_bytes(config, dtype_bytes=4):
-    """Parameter bytes of a ``GPTLMHeadModel`` with this config (the
-    serving-params pytree ``models/gpt.py:gpt_serving_params`` builds)
-    — what the pool sizing subtracts from the HBM budget."""
-    h = config.hidden_size
-    i = config.intermediate_size
-    per_layer = (2 * h                      # ln1
-                 + h * 3 * h + 3 * h        # qkv
-                 + h * h + h                # attn proj
-                 + 2 * h                    # ln2
-                 + h * i + i                # mlp fc
-                 + i * h + h)               # mlp proj
-    total = (config.vocab_size * h          # wte
-             + config.max_position_embeddings * h   # wpe
-             + config.num_hidden_layers * per_layer
-             + 2 * h                        # ln_f
-             + h * config.vocab_size)       # lm_head
-    return total * dtype_bytes
+def kv_block_bytes(config, block_size):
+    """HBM bytes one cache block costs across ALL layers, by the row
+    layout of the configuration's serving model (a K and a V row of
+    ``hidden`` float32 for GPT; one ``latent + rope`` row in the
+    model's dtype for a latent-attention model)."""
+    import jax.numpy as jnp     # numpy alone does not know bfloat16
+    model = config.serving_model()
+    row = sum(width * jnp.dtype(dtype).itemsize
+              for _, width, dtype in model.cache_layout())
+    return model.num_cache_layers * int(block_size) * row
 
 
 def blocks_for_budget(config, block_size=DEFAULT_BLOCK_SIZE, budget=None,
@@ -112,12 +100,13 @@ def blocks_for_budget(config, block_size=DEFAULT_BLOCK_SIZE, budget=None,
     budget = resolve_budget(budget)
     if budget is None:
         return None
-    avail = int(budget * (1.0 - headroom)) - gpt_param_bytes(config)
+    param_bytes = config.serving_model().param_bytes()
+    avail = int(budget * (1.0 - headroom)) - param_bytes
     nb = avail // kv_block_bytes(config, block_size)
     if nb < 2:
         raise ValueError(
             f"HBM budget {fmt_bytes(budget)} leaves room for {nb} KV "
-            f"block(s) after {fmt_bytes(gpt_param_bytes(config))} of "
+            f"block(s) after {fmt_bytes(param_bytes)} of "
             f"parameters — the model doesn't fit a paged cache here")
     return int(nb)
 
@@ -382,20 +371,22 @@ class PrefixCache:
 
 
 def _cow_copy(pools, src, dst):
-    """Copy one block's K/V rows across every layer (jitted with the
-    pools donated, so the copy is an in-HBM row move, not a pool
-    round-trip)."""
-    return [{"k": p["k"].at[dst].set(p["k"][src]),
-             "v": p["v"].at[dst].set(p["v"][src])} for p in pools]
+    """Copy one block's rows across every pool of every layer (jitted
+    with the pools donated, so the copy is an in-HBM row move, not a
+    pool round-trip)."""
+    return [{name: pool.at[dst].set(pool[src])
+             for name, pool in layer.items()} for layer in pools]
 
 
 class PagedKVCache:
-    """Per-layer pooled K/V buffers + per-sequence block tables.
+    """Per-layer pooled cache buffers + per-sequence block tables.
 
     The pools are jax arrays the engine threads through its (donated)
     jit calls; everything else — tables, the allocator, slot math — is
-    host-side numpy. ``config`` is GPT-shaped (``num_hidden_layers``,
-    ``num_attention_heads``, ``hidden_size``).
+    host-side numpy. Which pools a layer has, how wide their rows are
+    and in what dtype is the row layout of ``config``'s serving model
+    (``config.serving_model().cache_layout()``: ``k`` and ``v`` for
+    GPT, one latent row ``c`` for a latent-attention model).
 
     With ``prefix_cache=True`` the cache grows the prefix-sharing
     plane: :meth:`add_seq_prefix` resolves a prompt's cached prefix to
@@ -437,11 +428,11 @@ class PagedKVCache:
 
     def _init_pools(self):
         import jax.numpy as jnp
-        shape = (self.num_blocks + 1, self.block_size,
-                 self.config.hidden_size)
-        return [{"k": jnp.zeros(shape, jnp.float32),
-                 "v": jnp.zeros(shape, jnp.float32)}
-                for _ in range(self.config.num_hidden_layers)]
+        model = self.config.serving_model()
+        return [{name: jnp.zeros((self.num_blocks + 1, self.block_size,
+                                  width), jnp.dtype(dtype))
+                 for name, width, dtype in model.cache_layout()}
+                for _ in range(model.num_cache_layers)]
 
     # -- accounting ------------------------------------------------------
     @property

@@ -21,10 +21,12 @@ scheduler step:
    request that could NEVER fit the pool raises
    :class:`~hetu_tpu.serving.kvcache.KVCacheExhausted` at submit;
 3. **prefill** — newly admitted prompts run one causal forward
-   (grouped per prompt bucket) that scatters their K/V rows into the
-   pool via ``models/gpt.py:gpt_paged_prefill``;
+   (grouped per prompt bucket, and split so that one program holds at
+   most ``prefill_token_cap`` prompt tokens) that scatters their cache
+   rows into the pool via the model's prefill program
+   (``models/gpt.py:gpt_paged_prefill`` for GPT);
 4. **decode** — ALL running sequences take one token step in ONE jit
-   program (``gpt_paged_step``): per-sequence position vectors make
+   program (``gpt_paged_step`` for GPT): per-sequence position vectors make
    the batch ragged-safe, block tables make it gather from the pool.
    Four small int32 numpy arrays go in with the call itself (no put of
    their own); when every active sequence is greedy the program picks
@@ -100,10 +102,9 @@ from concurrent.futures import Future
 import numpy as np
 
 from .. import telemetry as _telemetry
-from ..models.gpt import (gpt_paged_prefill, gpt_paged_step,
-                          gpt_paged_suffix_prefill, gpt_serving_params)
 from . import lifecycle as _lifecycle
-from .kvcache import DEFAULT_BLOCK_SIZE, KVCacheExhausted, PagedKVCache
+from .kvcache import (_BUDGET_HEADROOM, DEFAULT_BLOCK_SIZE,
+                      KVCacheExhausted, PagedKVCache)
 from .lifecycle import RequestTimeline, mint_request_id
 from .router import SLOWindow
 from .session import next_bucket
@@ -160,7 +161,8 @@ class _Seq:
     __slots__ = ("id", "prompt", "max_new", "temperature", "seed",
                  "future", "generated", "pending", "n_written",
                  "t_submit_ns", "t_first_token_ns", "preempts", "rid",
-                 "tl", "tokens_lost", "cached_tokens", "prefill_pos")
+                 "tl", "tokens_lost", "cached_tokens", "prefill_pos",
+                 "records")
 
     def __init__(self, sid, prompt, max_new, temperature, seed, rid):
         self.id = sid
@@ -170,12 +172,16 @@ class _Seq:
         self.seed = int(seed)
         self.future = Future()
         self.generated = []     # chosen tokens, pending included
+        # beside each, the record the model's program returned for the
+        # row that decided it (models with row_record_width only)
+        self.records = []
         self.pending = None     # chosen but not yet written to the cache
         self.n_written = 0      # cache rows written (prompt + decode)
         # both stamps (perf_counter_ns) are taken with telemetry on or
         # off and can be read on the Future, before and after it is done
         self.t_submit_ns = self.future.t_submit_ns = time.perf_counter_ns()
         self.t_first_token_ns = self.future.t_first_token_ns = None
+        self.future.token_records = None
         self.preempts = 0
         self.rid = rid          # request id (caller-supplied or minted)
         self.tl = None          # RequestTimeline, only with telemetry on
@@ -206,9 +212,20 @@ def _stamp_first_token(seq, t_ns):
         seq.t_first_token_ns = seq.future.t_first_token_ns = t_ns
 
 
+def _reachable(ladder, lo, hi):
+    """Entries of a bucket ladder that values in [lo, hi] snap to."""
+    first, last = next_bucket(lo, ladder), next_bucket(hi, ladder)
+    return [b for b in ladder if first <= b <= last]
+
+
 class ContinuousBatchingEngine:
-    """See the module docstring. ``lookup(name) -> array`` resolves
-    checkpoint parameter names (``models/gpt.py:gpt_param_names``) to
+    """See the module docstring. The model is ``config``'s serving
+    model (``config.serving_model()``: parameters, the cache's row
+    layout, the programs of the three cache backends —
+    ``docs/serving.md``, "The serving-model interface"), so the TYPE of
+    ``config`` picks it, and nothing here names a model.
+    ``lookup(name) -> array`` resolves the model's checkpoint
+    parameter names (for GPT ``models/gpt.py:gpt_param_names``) to
     arrays; use the classmethods for the common sources.
 
     With ``start=True`` (default) a daemon scheduler thread drives
@@ -218,7 +235,11 @@ class ContinuousBatchingEngine:
     ``submit()`` returns a Future resolving to the generated tokens as
     a 1-D int32 array of length ``max_new_tokens``; the Future carries
     ``t_submit_ns`` and ``t_first_token_ns`` (``perf_counter_ns``; the
-    latter ``None`` until the prefill's host sync ended)."""
+    latter ``None`` until the prefill's host sync ended) and, once
+    done, ``token_records``: for a model whose programs return a record
+    a row (``row_record_width``), int32 ``[max_new_tokens, width]``,
+    the record of the row that decided each token (``docs/serving.md``
+    says what a model puts there), else ``None``."""
 
     def __init__(self, config, lookup, *, num_blocks=None,
                  block_size=DEFAULT_BLOCK_SIZE, budget=None, max_len=None,
@@ -237,11 +258,12 @@ class ContinuousBatchingEngine:
             raise ValueError(f"prefill_chunk must be >= 1, "
                              f"got {prefill_chunk}")
         self.config = config
-        self.max_len = int(max_len or config.max_position_embeddings)
-        if self.max_len > config.max_position_embeddings:
+        self.model = model = config.serving_model()
+        self.max_len = int(max_len or model.max_positions)
+        if self.max_len > model.max_positions:
             raise ValueError(
-                f"max_len {self.max_len} exceeds the model's learned "
-                f"positions ({config.max_position_embeddings})")
+                f"max_len {self.max_len} exceeds the model's "
+                f"positions ({model.max_positions})")
         self.max_batch_size = int(max_batch_size)
         self.admission = admission
         self.max_queue = int(max_queue)
@@ -258,7 +280,7 @@ class ContinuousBatchingEngine:
         # serves both, so either knob switches prefill onto it
         self._suffix_mode = self.prefix_cache \
             or self.prefill_chunk is not None
-        self.params = gpt_serving_params(config, lookup)
+        self.params = model.params(lookup)
         self.cache = PagedKVCache(config, num_blocks=num_blocks,
                                   block_size=block_size, budget=budget,
                                   telemetry=self.telemetry,
@@ -271,23 +293,32 @@ class ContinuousBatchingEngine:
                                         self.max_len)
         self.chunk_buckets = _pow2_ladder(
             1, min(self.prefill_chunk or self.max_len, self.max_len))
-        nh = config.num_attention_heads
-        act = getattr(config, "hidden_act", "gelu")
-        self._prefill_fn = _named_program(
-            gpt_paged_prefill, "hetu_paged_prefill",
-            num_heads=nh, hidden_act=act)
+
+        def program(kind, name):
+            fn, static = model.program(kind)
+            return _named_program(fn, name, **static)
+
+        self._prefill_fn = program("prefill", "hetu_paged_prefill")
         # the greedy hot path: token ids leave the program, not logits
-        self._step_fn = _named_program(
-            gpt_paged_step, "hetu_paged_decode",
-            num_heads=nh, hidden_act=act, pick="greedy")
+        self._step_fn = program("decode", "hetu_paged_decode")
         # its logits-returning twin, for a step that holds a sampled
         # sequence; nothing compiles it until such a step runs
-        self._logits_step_fn = _named_program(
-            gpt_paged_step, "hetu_paged_decode_logits",
-            num_heads=nh, hidden_act=act)
-        self._sprefill_fn = _named_program(
-            gpt_paged_suffix_prefill, "hetu_paged_suffix_prefill",
-            num_heads=nh, hidden_act=act)
+        self._logits_step_fn = program("decode_logits",
+                                       "hetu_paged_decode_logits")
+        self._sprefill_fn = program("suffix_prefill",
+                                    "hetu_paged_suffix_prefill")
+        self.prefill_token_cap = self._prefill_token_cap(budget)
+        # what the model's programs count on the device and return with
+        # their tokens (none for GPT), summed by program kind
+        width = len(model.counter_names) + (
+            model.vector_counter[1] if model.vector_counter else 0)
+        self._model_counters = {kind: np.zeros(width, np.int64)
+                                for kind in ("prefill", "decode")}
+        # with telemetry on, the last programs that returned counters:
+        # {"kind", "t0_ns", "t1_ns" (perf_counter_ns, dispatch to the
+        # end of the host sync), "<kind>_<counter>": its own counts}
+        self.program_log = collections.deque(
+            maxlen=16384 if width else 0)
         self._signatures = set()
         self.decode_steps = 0               # decode programs dispatched
         self.decode_device_pick_steps = 0   # ... that picked on the device
@@ -398,6 +429,8 @@ class ContinuousBatchingEngine:
                "health_reason": reason}
         out["prefix_cache"] = self.prefix_cache
         out["prefill_chunk"] = self.prefill_chunk
+        for kind, vec in self._model_counters.items():
+            out.update(self._named_counters(kind, vec))
         if self.prefix_cache:
             # utilization above counts only sequence-referenced blocks;
             # the cached-unreferenced remainder is reclaimable HBM
@@ -562,6 +595,144 @@ class ContinuousBatchingEngine:
         return admitted
 
     # ------------------------------------------------------------------
+    def _prefill_token_cap(self, budget):
+        """Prompt tokens one prefill program may hold: what the HBM
+        budget (resolved as the pool's is) leaves beside the parameters,
+        the pool and the headroom, over the bytes of temporaries the
+        model says a prompt token costs. ``None`` where no budget
+        resolves (a CPU harness): no cap."""
+        from ..analysis.memory import resolve_budget
+        budget = resolve_budget(budget)
+        if budget is None:
+            return None
+        spare = (int(budget * (1.0 - _BUDGET_HEADROOM))
+                 - self.model.param_bytes() - self.cache.hbm_bytes())
+        return max(0, spare) // self.model.prefill_bytes_per_token()
+
+    def _prefill_width(self, prompt_bucket):
+        """Sequences one prefill program of this prompt bucket holds:
+        the widest batch bucket whose tokens stay under the cap (one
+        sequence at the least)."""
+        if self.prefill_token_cap is None:
+            return self.max_batch_size
+        fit = [b for b in self.batch_buckets
+               if b * prompt_bucket <= self.prefill_token_cap]
+        return fit[-1] if fit else self.batch_buckets[0]
+
+    def _named_counters(self, kind, vec):
+        """``{"<kind>_<counter>": value}`` of one counter vector."""
+        names = self.model.counter_names
+        out = {f"{kind}_{n}": int(v) for n, v in zip(names, vec)}
+        if self.model.vector_counter:
+            out[f"{kind}_{self.model.vector_counter[0]}"] = \
+                [int(v) for v in vec[len(names):]]
+        return out
+
+    def _count(self, kind, counted, t0, t1):
+        """Take apart what one program returned beside its tokens or
+        logits (``None``: the model returns nothing): its device-side
+        counters are added to the engine's and, with telemetry on, to
+        its ``{name}_*`` counters, and the program itself, with its
+        host times and its own counts, goes to :attr:`program_log`.
+        Returns the batch rows' records ``[bb, row_record_width]``
+        (``None`` for a model without)."""
+        if counted is None:
+            return None
+        counted = np.asarray(counted)
+        width = len(self._model_counters[kind])
+        records = counted[width:].reshape(-1, self.model.row_record_width) \
+            if self.model.row_record_width else None
+        counted = counted[:width].astype(np.int64)
+        self._model_counters[kind] += counted
+        tel = self.telemetry
+        if tel.enabled:
+            named = self._named_counters(kind, counted)
+            for name, value in named.items():
+                if not isinstance(value, list):
+                    tel.inc(f"{self.name}_{name}", value)
+            self.program_log.append(
+                dict(named, kind=kind, t0_ns=t0, t1_ns=t1))
+        return records
+
+    def warm_up(self, prompt_len, max_new):
+        """Compile and run once, on the scratch block, every program
+        that requests with prompts of ``prompt_len = (shortest,
+        longest)`` tokens and up to ``max_new`` new ones can reach:
+        each (batch, prompt) prefill bucket the token cap admits, each
+        (batch, context) greedy-decode bucket and, with a prefix cache
+        or chunked prefill, each (batch, chunk, context) suffix-prefill
+        bucket — with the small host reads the scheduler makes on their
+        results. Call it before the first ``submit``: it uses the pools
+        and must not run beside the scheduler's own steps. Afterwards
+        ``jit_compiles`` does not rise for such traffic (the sampled
+        route's ``hetu_paged_decode_logits`` aside, which compiles on
+        first use). Returns the buckets it ran."""
+        import jax.numpy as jnp
+        lo, hi = (int(prompt_len), int(prompt_len)) \
+            if np.isscalar(prompt_len) else map(int, prompt_len)
+        if not 1 <= lo <= hi or hi + int(max_new) > self.max_len:
+            raise ValueError(
+                f"prompts of {lo}..{hi} tokens with {max_new} new ones "
+                f"do not fit max_len {self.max_len}")
+        prompt_buckets = _reachable(self.prompt_buckets, lo, hi)
+        ctx_buckets = _reachable(self.ctx_buckets, lo + 1,
+                                 hi + int(max_new))
+        ran = {"prefill": [], "decode": [], "suffix_prefill": []}
+
+        def zeros(*shape):
+            return np.zeros(shape, np.int32)
+
+        for bb in self.batch_buckets:
+            sizes = range(bb // 2 + 1, bb + 1)   # groups that snap to bb
+            for pb in () if self._suffix_mode else prompt_buckets:
+                if bb > self._prefill_width(pb):
+                    continue
+                if self.model.prefill_last_row:
+                    (logits, _), self.cache.pools = self._dispatch(
+                        ("prefill", bb, pb), self._prefill_fn,
+                        self.params, self.cache.pools,
+                        jnp.asarray(zeros(bb, pb)),
+                        jnp.asarray(zeros(bb, pb)), zeros(bb))
+                    np.asarray(logits)
+                else:
+                    logits, self.cache.pools = self._dispatch(
+                        ("prefill", bb, pb), self._prefill_fn,
+                        self.params, self.cache.pools,
+                        jnp.asarray(zeros(bb, pb)),
+                        jnp.asarray(zeros(bb, pb)))
+                    for n in sizes:     # the last-row gather of a group
+                        np.asarray(logits[jnp.arange(n),
+                                          jnp.asarray([pb - 1] * n)])
+                del logits
+                ran["prefill"].append((bb, pb))
+            for cb in ctx_buckets:
+                out, self.cache.pools = self._dispatch(
+                    ("decode", bb, cb), self._step_fn, self.params,
+                    self.cache.pools, zeros(bb), zeros(bb), zeros(bb, cb),
+                    zeros(bb))
+                np.asarray(out)
+                ran["decode"].append((bb, cb))
+            if not self._suffix_mode:
+                continue
+            chunk = min(self.prefill_chunk or self.max_len, hi)
+            for cw in _reachable(self.chunk_buckets, 1, chunk):
+                for sb in _reachable(self.ctx_buckets, 1, hi):
+                    logits, self.cache.pools = self._dispatch(
+                        ("sprefill", bb, cw, sb), self._sprefill_fn,
+                        self.params, self.cache.pools,
+                        jnp.asarray(zeros(bb, cw)),
+                        jnp.asarray(zeros(bb)),
+                        jnp.asarray(zeros(bb, sb)),
+                        jnp.asarray(zeros(bb, cw)))
+                    if self.model.counter_names:
+                        logits, _ = logits
+                    for n in sizes:
+                        np.asarray(logits[jnp.arange(n),
+                                          jnp.asarray([cw - 1] * n)])
+                    del logits
+                    ran["suffix_prefill"].append((bb, cw, sb))
+        return ran
+
     def _dispatch(self, key, fn, *args):
         """Run one jit program, accounting compiles the way the
         executor does (HT901's runtime half): first sighting of a
@@ -569,7 +740,7 @@ class ContinuousBatchingEngine:
         span, steady-state dispatches ride ``device_dispatch``."""
         tel = self.telemetry
         if key not in self._signatures:
-            self._signatures.add(key)
+            self._signatures.add(key)   # lock-ok: HT601 warm_up is the one caller off the scheduler's thread, and runs before the first submit
             with tel.span("jit_compile", subgraph="serving_engine",
                           shape_key=str(key)):
                 out = fn(*args)
@@ -586,7 +757,15 @@ class ContinuousBatchingEngine:
             for s in admitted:
                 pb = next_bucket(s.prompt.shape[0], self.prompt_buckets)
                 groups.setdefault(pb, []).append(s)
+        last_row = self.model.prefill_last_row
+        # one program holds at most prefill_token_cap prompt tokens: a
+        # bucket's group goes in as many programs as that takes
+        split = []
         for pb, group in sorted(groups.items()):
+            width = self._prefill_width(pb)
+            split += [(pb, group[i:i + width])
+                      for i in range(0, len(group), width)]
+        for pb, group in split:
             with tel.span("serve.prefill.build"):
                 bb = next_bucket(len(group), self.batch_buckets)
                 ids = np.zeros((bb, pb), np.int32)
@@ -597,26 +776,45 @@ class ContinuousBatchingEngine:
                     ids[i, p:] = s.prompt[-1]   # edge pad stays in-vocab
                     slots[i, :p] = self.cache.slot_mapping(s.id, 0, p)
                 ids, slots = jnp.asarray(ids), jnp.asarray(slots)
-                rows = jnp.arange(len(group))
-                last_pos = jnp.asarray([s.prompt.shape[0] - 1
-                                        for s in group])
+                if last_row:
+                    last_pos = np.zeros(bb, np.int32)
+                    last_pos[:len(group)] = [s.prompt.shape[0] - 1
+                                             for s in group]
+                else:
+                    rows = jnp.arange(len(group))
+                    last_pos = jnp.asarray([s.prompt.shape[0] - 1
+                                            for s in group])
             with tel.span("serve.prefill.device", batch_bucket=bb,
                           prompt_bucket=pb):
                 t0 = time.perf_counter_ns() if tel.enabled else 0
-                logits, pools = self._dispatch(
-                    ("prefill", bb, pb), self._prefill_fn, self.params,
-                    self.cache.pools, ids, slots)
-                self.cache.pools = pools
-                last = np.asarray(logits[rows, last_pos])
+                if last_row:
+                    # the program takes each prompt's last row through
+                    # the head itself: [bb, V] leaves it
+                    (logits, counted), pools = self._dispatch(
+                        ("prefill", bb, pb), self._prefill_fn,
+                        self.params, self.cache.pools, ids, slots,
+                        last_pos)
+                    self.cache.pools = pools
+                    last = np.asarray(logits)
+                else:
+                    logits, pools = self._dispatch(
+                        ("prefill", bb, pb), self._prefill_fn,
+                        self.params, self.cache.pools, ids, slots)
+                    self.cache.pools = pools
+                    last = np.asarray(logits[rows, last_pos])
+                    counted = None
                 # the episode ends AFTER the host sync above — the wall
                 # between t0 and t1 is the prefill compute each member
                 # rode, and t1 is its first-token time
                 t1 = time.perf_counter_ns()
+                records = self._count("prefill", counted, t0, t1)
             with tel.span("serve.prefill.sample"):
                 for i, s in enumerate(group):
                     p = s.prompt.shape[0]
                     tok = _choose_token(last[i], s.temperature, s.seed, 0)
                     s.generated.append(tok)
+                    if records is not None:
+                        s.records.append(records[i])
                     s.pending = tok
                     s.n_written = p
                     s.prefill_pos = p
@@ -710,13 +908,19 @@ class ContinuousBatchingEngine:
                     self.params, self.cache.pools, ids, starts, slot_grid,
                     write_slots)
                 self.cache.pools = pools
+                counted = None
+                if self.model.counter_names:
+                    logits, counted = logits
                 last = np.asarray(logits[rows, last_pos]) \
                     if finishing else None
                 t1 = time.perf_counter_ns()
+                records = self._count("prefill", counted, t0, t1)
             with tel.span("serve.prefill.sample"):
                 for j, (i, s, w) in enumerate(finishing):
                     tok = _choose_token(last[j], s.temperature, s.seed, 0)
                     s.generated.append(tok)
+                    if records is not None:
+                        s.records.append(records[i])
                     s.pending = tok
                 cached_resolved = 0
                 for i, (s, w) in enumerate(group):
@@ -775,6 +979,7 @@ class ContinuousBatchingEngine:
         lost = len(victim.generated)
         victim.tokens_lost = lost
         victim.generated = []
+        victim.records = []
         victim.pending = None
         victim.n_written = 0
         victim.prefill_pos = 0
@@ -844,10 +1049,19 @@ class ContinuousBatchingEngine:
                 key, fn, self.params, self.cache.pools, tokens,
                 positions, slot_grid, write_slots)
             self.cache.pools = pools
-            # the step's one host sync: [bb] int32 ids, or [bb, V]
+            # the step's one host sync: [bb] int32 ids (the model's
+            # counters behind them, where it has any), or [bb, V]
             # float32 logits on the sampled route
+            counted = None
+            if self.model.counter_names:
+                if device_pick:
+                    out = np.asarray(out)
+                    out, counted = out[:bb], out[bb:]
+                else:
+                    out, counted = out
             last = np.asarray(out)
             t1 = time.perf_counter_ns() if tel.enabled else 0
+            records = self._count("decode", counted, t0, t1)
         with tel.span("serve.decode.sample"):
             self.decode_steps += 1
             if device_pick:
@@ -859,6 +1073,8 @@ class ContinuousBatchingEngine:
                     last[i], s.temperature, s.seed, len(s.generated))
                 s.generated.append(tok)
                 s.pending = tok
+                if records is not None:
+                    s.records.append(records[i])
                 if s.tl is not None:
                     # a preempted sequence re-earning lost tokens is in
                     # "replay", not "decode" — the doctor's replay bucket
@@ -903,6 +1119,8 @@ class ContinuousBatchingEngine:
                 self.slo.note(True, ms, ttft_ms=ttft_ms)
                 if tel.enabled:
                     tel.observe(f"{self.name}_latency_ms", ms)
+                s.future.token_records = np.stack(
+                    s.records[:s.max_new]) if s.records else None
                 s.future.set_result(
                     np.asarray(s.generated[:s.max_new], np.int32))
 

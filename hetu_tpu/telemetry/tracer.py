@@ -111,6 +111,12 @@ class Tracer:
         self._events = deque(maxlen=int(capacity))
         self._lock = threading.Lock()
         self._tids = {}             # thread ident -> (small tid, name)
+        # the ring is in completion order only as far as callers record
+        # an event when it ends: one recorded later with old times (a
+        # request's lifecycle episodes, at retirement) lies behind
+        # newer ends by at most _late_ns
+        self._newest_end_ns = 0
+        self._late_ns = 0
 
     # -- recording -------------------------------------------------------
     def clock(self):
@@ -125,6 +131,10 @@ class Tracer:
         """Record a complete event from explicit begin/end clock values
         (the non-``with`` form used by phase timers that also accumulate
         their own counters)."""
+        if t1_ns >= self._newest_end_ns:
+            self._newest_end_ns = t1_ns
+        elif self._newest_end_ns - t1_ns > self._late_ns:
+            self._late_ns = self._newest_end_ns - t1_ns
         self._events.append(
             (name, "X", t0_ns, max(0, t1_ns - t0_ns),
              threading.get_ident(), args))
@@ -136,18 +146,20 @@ class Tracer:
     def events_between(self, t0_ns, t1_ns):
         """Raw complete events whose END falls in ``[t0_ns, t1_ns]``
         (span clock), newest-window reads in O(window): events append
-        at completion time, so the ring is end-time ordered and a
-        reversed walk can stop at the first event older than the
-        window — the fleet timeline's per-step incremental read.
+        at completion time, so the ring is end-time ordered — but for
+        the events a caller recorded late, which lie at most
+        ``_late_ns`` out of place — and a reversed walk can stop at the
+        first event older than the window by more than that: the fleet
+        timeline's per-step incremental read.
         Returns ``(name, t0_ns, dur_ns, thread_ident, args)`` tuples
         in completion order."""
         out = []
         with self._lock:
             for name, ph, et0, dur, ident, args in reversed(self._events):
                 end = et0 + dur
-                if end < t0_ns:
+                if end < t0_ns - self._late_ns:
                     break
-                if ph == "X" and end <= t1_ns:
+                if ph == "X" and t0_ns <= end <= t1_ns:
                     out.append((name, et0, dur, ident, args))
         out.reverse()
         return out

@@ -34,16 +34,45 @@ def _reset_global_telemetry():
 # tracer
 # ---------------------------------------------------------------------------
 
+def test_events_between_finds_events_recorded_late():
+    """The ring is in completion order but for events a caller records
+    afterwards with old times (a request's lifecycle episodes, at its
+    retirement): a newest-window read must not stop at one of those."""
+    tr = Tracer()
+    tr.complete("a", 100, 200)
+    tr.complete("b", 300, 400)
+    tr.complete("late", 50, 120)        # ended long before "b"
+    tr.complete("c", 410, 500)
+    names = lambda t0, t1: [e[0] for e in tr.events_between(t0, t1)]  # noqa: E731
+    assert names(350, 600) == ["b", "c"]
+    assert names(150, 450) == ["a", "b"]
+    assert names(0, 600) == ["a", "b", "late", "c"]
+    # an in-order ring still stops early: nothing is late there
+    ordered = Tracer()
+    for i in range(5):
+        ordered.complete(str(i), 10 * i, 10 * i + 5)
+    assert ordered._late_ns == 0
+    assert [e[0] for e in ordered.events_between(32, 50)] == ["3", "4"]
+
+
 def test_spans_nest_across_threads(tmp_path):
     """Each thread records under its own tid; nested spans stay properly
     contained within their parent on that tid."""
     tr = Tracer(pid=0)
+    # Both threads are alive from before the first span to after the
+    # last: each waits for the other at both ends. A thread's ident is
+    # free for reuse the moment it exits, so on a loaded machine a
+    # second thread that starts after the first has finished can get
+    # its ident, and both would record under one tid.
+    both = threading.Barrier(2, timeout=30)
 
     def work():
+        both.wait()
         with tr.span("outer"):
             with tr.span("inner"):
                 time.sleep(0.002)
             time.sleep(0.001)
+        both.wait()
 
     threads = [threading.Thread(target=work) for _ in range(2)]
     for t in threads:
