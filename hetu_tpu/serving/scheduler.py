@@ -35,7 +35,13 @@ scheduler step:
    ``temperature > 0`` sequence in it runs the logits-returning twin
    (``hetu_paged_decode_logits``, compiled on first use) and picks on
    the host, because that draw is keyed on ``(seed, token_index)`` in
-   float64 numpy and a replay has to repeat it.
+   float64 numpy and a replay has to repeat it. The loop keeps ONE
+   greedy program in flight: when the next step holds the same rows,
+   it is dispatched before this step's ids are read, with the ids —
+   still on the device — as its ``tokens``, so the host's half of a
+   step runs beside the device's (:meth:`_decode_once` has the rule;
+   a request ends by count, so no step is ever dispatched for a row
+   that has ended, and the programs run are the synchronous loop's).
 
 **The HT901 contract is load-bearing here.** Sequences join/leave every
 step, so naive shapes would retrace constantly. Instead every dispatch
@@ -44,8 +50,9 @@ snaps to precomputed ladders — batch width to the power-of-two ladder
 prompt length to the decoder's prompt ladder — so distinct jit
 signatures are bounded by :attr:`compile_bound` =
 ``|batch| x (|prompt| + 2 |ctx|)`` ladder products (decode has a
-greedy and a logits program per bucket) no matter how churny the trace
-(the serving test measures exactly this).
+greedy and a logits program per bucket; a model with counters adds a
+slice of the ids per batch bucket) no matter how churny the trace (the
+serving test measures exactly this).
 
 ``reserve="full"`` (default) allocates a request's whole
 ``prompt + max_new_tokens`` block budget at admission — no mid-decode
@@ -82,11 +89,14 @@ HT901 holds with both features on.
 (``Telemetry.span``: the ring when telemetry is on, a ``hetu.<name>``
 annotation in a ``jax.profiler`` trace always): ``serve.wait`` (nothing
 waiting, nothing running), ``serve.admit``, ``serve.prefill.build`` /
-``.device`` / ``.sample``, ``serve.decode.build`` / ``.device`` /
-``.sample`` and ``serve.finish``; ``step`` is their parent. A
-``.device`` span runs from the dispatch of its program through the host
-sync of the rows the scheduler reads, so what lies between two of them
-is the host's own work. The jitted programs are named
+``.device`` / ``.sample``, ``serve.decode.build`` / ``.ahead`` /
+``.device`` / ``.sample`` and ``serve.finish``; ``step`` is their
+parent. A ``.device`` span runs from the dispatch of its program
+through the host sync of the rows the scheduler reads, so what lies
+between two of them is the host's own work; where a decode program
+stays in flight its dispatch and its sync are a span each, and the
+dispatch of one made while the one before is unread is
+``serve.decode.ahead``. The jitted programs are named
 ``hetu_paged_prefill`` / ``hetu_paged_decode`` /
 ``hetu_paged_decode_logits`` / ``hetu_paged_suffix_prefill``.
 """
@@ -142,6 +152,19 @@ def _named_program(fn, name, **static):
     return jax.jit(program, donate_argnums=(1,))
 
 
+def _ids_program():
+    """``jit_hetu_decode_ids``: the first ``n`` (static) entries of a
+    decode program's result. A model with counters returns them behind
+    its ``bb`` ids in one array; this takes the ids alone, on the
+    device, as the next step's ``tokens``."""
+    import jax
+
+    def hetu_decode_ids(out, n):
+        return out[:n]
+
+    return jax.jit(hetu_decode_ids, static_argnums=1)
+
+
 def _choose_token(logits_row, temperature, seed, idx):
     """Greedy or temperature sampling, host-side. Randomness is keyed
     on ``(seed, token_index)`` — NOT on any global stream — so a
@@ -162,7 +185,7 @@ class _Seq:
                  "future", "generated", "pending", "n_written",
                  "t_submit_ns", "t_first_token_ns", "preempts", "rid",
                  "tl", "tokens_lost", "cached_tokens", "prefill_pos",
-                 "records")
+                 "records", "unread")
 
     def __init__(self, sid, prompt, max_new, temperature, seed, rid):
         self.id = sid
@@ -176,7 +199,12 @@ class _Seq:
         # row that decided it (models with row_record_width only)
         self.records = []
         self.pending = None     # chosen but not yet written to the cache
-        self.n_written = 0      # cache rows written (prompt + decode)
+        # cache rows written (prompt + decode), those of a decode
+        # program that is dispatched and not read yet included
+        self.n_written = 0
+        # tokens picked on the device by dispatched decode programs that
+        # the host has not read yet (they are not in ``generated``)
+        self.unread = 0
         # both stamps (perf_counter_ns) are taken with telemetry on or
         # off and can be read on the Future, before and after it is done
         self.t_submit_ns = self.future.t_submit_ns = time.perf_counter_ns()
@@ -202,6 +230,20 @@ class _Seq:
     def replaying(self):
         return self.tokens_lost > 0 and \
             len(self.generated) <= self.tokens_lost
+
+
+class _DecodeProgram:
+    """One dispatched decode program: its rows (the active sequences,
+    in batch order), its spans' attributes, what it returned as a
+    device array, and — once :meth:`ContinuousBatchingEngine._sync` has
+    read that — the host's copy."""
+    __slots__ = ("rows", "bb", "attrs", "device_pick", "out", "t0", "t1",
+                 "last", "records")
+
+    def __init__(self, rows, bb, attrs, device_pick, out, t0):
+        self.rows, self.bb, self.attrs = rows, bb, attrs
+        self.device_pick, self.out, self.t0 = device_pick, out, t0
+        self.t1 = self.last = self.records = None
 
 
 def _stamp_first_token(seq, t_ns):
@@ -307,6 +349,7 @@ class ContinuousBatchingEngine:
                                        "hetu_paged_decode_logits")
         self._sprefill_fn = program("suffix_prefill",
                                     "hetu_paged_suffix_prefill")
+        self._ids_fn = _ids_program()
         self.prefill_token_cap = self._prefill_token_cap(budget)
         # what the model's programs count on the device and return with
         # their tokens (none for GPT), summed by program kind
@@ -322,6 +365,12 @@ class ContinuousBatchingEngine:
         self._signatures = set()
         self.decode_steps = 0               # decode programs dispatched
         self.decode_device_pick_steps = 0   # ... that picked on the device
+        # ... that were dispatched while the one before was still unread
+        self.decode_ahead_steps = 0
+        # the decode program that is dispatched and not read yet, if any
+        # (scheduler thread only; see _decode_once)
+        self._flight = None
+        self._decode_read_ns = 0    # end of the last decode host sync
         self._ids = itertools.count()
         self._waiting = collections.deque()
         self._running = []
@@ -361,10 +410,13 @@ class ContinuousBatchingEngine:
         prefill keys on (batch, prompt) buckets, decode on (batch, ctx)
         buckets twice over (the greedy program and the logits one),
         suffix prefill (prefix cache / chunked prefill) on (batch,
-        chunk, ctx) buckets — churn can never compile more programs
-        than this."""
+        chunk, ctx) buckets, the slice of a step's ids for a model with
+        counters on the batch bucket — churn can never compile more
+        programs than this."""
         bound = len(self.batch_buckets) * (len(self.prompt_buckets)
                                            + 2 * len(self.ctx_buckets))
+        if self.model.counter_names:
+            bound += len(self.batch_buckets)    # hetu_decode_ids
         if self._suffix_mode:
             bound += (len(self.batch_buckets) * len(self.chunk_buckets)
                       * len(self.ctx_buckets))
@@ -425,6 +477,7 @@ class ContinuousBatchingEngine:
                "compile_bound": self.compile_bound,
                "decode_steps": self.decode_steps,
                "decode_device_pick_steps": self.decode_device_pick_steps,
+               "decode_ahead_steps": self.decode_ahead_steps,
                "healthy": healthy,
                "health_reason": reason}
         out["prefix_cache"] = self.prefix_cache
@@ -496,7 +549,13 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------------
     def step(self):
         """One scheduler iteration (admit -> prefill -> decode ->
-        finish); returns the number of sequences still running."""
+        finish); returns the number of sequences still running. It may
+        return with ONE decode program dispatched and not read (see
+        :meth:`_decode_once`): its rows are still running and their
+        last token is not in ``generated`` yet, so a caller that drives
+        ``step()`` itself keeps calling it until its Futures are done;
+        the next ``step()`` reads it, ``close()`` drops it with its
+        rows."""
         tel = self.telemetry
         t0 = time.perf_counter()
         with tel.span("serve.admit"), self._cond:
@@ -505,19 +564,19 @@ class ContinuousBatchingEngine:
             return 0
         width = len(self._running)
         with tel.span("step", subgraph="serving_engine"):
-            if self._suffix_mode:
-                # chunked/prefix prefill: EVERY still-prefilling
-                # sequence (not just this step's admissions) computes
-                # one chunk, then the running batch decodes — long cold
-                # prompts interleave with decode instead of stalling it
-                prefilled = [s for s in self._running if s.prefilling()]
-                if prefilled:
-                    self._prefill_suffix_step(prefilled)
-            else:
-                prefilled = admitted
-                if admitted:
-                    self._prefill_admitted(admitted)
+            # chunked/prefix prefill: EVERY still-prefilling sequence
+            # (not just this step's admissions) computes one chunk, then
+            # the running batch decodes — long cold prompts interleave
+            # with decode instead of stalling it
+            prefilled = [s for s in self._running if s.prefilling()] \
+                if self._suffix_mode else admitted
             if prefilled:
+                # the rows change: what is in flight is read first
+                self._read_flight()
+                if self._suffix_mode:
+                    self._prefill_suffix_step(prefilled)
+                else:
+                    self._prefill_admitted(admitted)
                 # a prefill gives token 0, which may be a request's last;
                 # without one nothing was produced since the finish that
                 # ended the previous step
@@ -659,11 +718,13 @@ class ContinuousBatchingEngine:
         that requests with prompts of ``prompt_len = (shortest,
         longest)`` tokens and up to ``max_new`` new ones can reach:
         each (batch, prompt) prefill bucket the token cap admits, each
-        (batch, context) greedy-decode bucket and, with a prefix cache
-        or chunked prefill, each (batch, chunk, context) suffix-prefill
-        bucket — with the small host reads the scheduler makes on their
-        results. Call it before the first ``submit``: it uses the pools
-        and must not run beside the scheduler's own steps. Afterwards
+        (batch, context) greedy-decode bucket (for a model with
+        counters also the slice that hands a step's ids to the next, a
+        batch bucket) and, with a prefix cache or chunked prefill, each
+        (batch, chunk, context) suffix-prefill bucket — with the small
+        host reads the scheduler makes on their results. Call it before
+        the first ``submit``: it uses the pools and must not run beside
+        the scheduler's own steps. Afterwards
         ``jit_compiles`` does not rise for such traffic (the sampled
         route's ``hetu_paged_decode_logits`` aside, which compiles on
         first use). Returns the buckets it ran."""
@@ -677,7 +738,8 @@ class ContinuousBatchingEngine:
         prompt_buckets = _reachable(self.prompt_buckets, lo, hi)
         ctx_buckets = _reachable(self.ctx_buckets, lo + 1,
                                  hi + int(max_new))
-        ran = {"prefill": [], "decode": [], "suffix_prefill": []}
+        ran = {"prefill": [], "decode": [], "decode_ids": [],
+               "suffix_prefill": []}
 
         def zeros(*shape):
             return np.zeros(shape, np.int32)
@@ -710,6 +772,12 @@ class ContinuousBatchingEngine:
                     ("decode", bb, cb), self._step_fn, self.params,
                     self.cache.pools, zeros(bb), zeros(bb), zeros(bb, cb),
                     zeros(bb))
+                if self.model.counter_names and cb == ctx_buckets[0]:
+                    # what hands a step's ids to the step dispatched
+                    # ahead of their host read
+                    self._dispatch(("decode_ids", bb), self._ids_fn,
+                                   out, bb)
+                    ran["decode_ids"].append((bb,))
                 np.asarray(out)
                 ran["decode"].append((bb, cb))
             if not self._suffix_mode:
@@ -974,7 +1042,9 @@ class ContinuousBatchingEngine:
     def _preempt(self, victim):
         """Free a sequence's blocks and requeue it at the waiting head;
         recompute reproduces its tokens ((seed, index)-keyed
-        sampling)."""
+        sampling). A decode program in flight is read first: the
+        victim's last token goes with its blocks like the others."""
+        self._read_flight()
         self.cache.free_seq(victim.id)
         lost = len(victim.generated)
         victim.tokens_lost = lost
@@ -1001,11 +1071,27 @@ class ContinuousBatchingEngine:
                 victim.tl.t_wait_start = time.perf_counter_ns()
 
     def _decode_once(self):
+        """Dispatch one decode step for every active sequence and read
+        what is due. The loop keeps at most ONE program unread: when the
+        program in flight holds exactly this step's rows (same
+        sequences, same order, so the same batch bucket; all greedy)
+        and the build moved no block, this step is dispatched FIRST,
+        its ``tokens`` argument the device array the one in flight
+        returns, and the one in flight is read SECOND, while the device
+        runs this one. Anything else (an admission or a prefill, read
+        in :meth:`step`; a preemption, read in :meth:`_preempt`; a
+        copy-on-write) finds the program in flight read before this
+        step is built from host values. A step that ends a row (known
+        by COUNT: there is no stop token) or holds a sampled row is
+        read before this returns, so a finish, an admission and the
+        host's draw see what the synchronous loop showed them: the
+        programs run, their order and their results are the same."""
         tel = self.telemetry
         with tel.span("serve.decode.build"):
             active = [s for s in self._running
-                      if len(s.generated) < s.max_new
+                      if len(s.generated) + s.unread < s.max_new
                       and not s.prefilling()]
+            cows = self.cache.cow_copies
             if self.reserve == "lazy":
                 active = self._ensure_capacity_lazy(active)
             if self.prefix_cache:
@@ -1018,73 +1104,133 @@ class ContinuousBatchingEngine:
                                              s.n_written + 1)
                 active = [s for s in active if s in self._running]
             if not active:
-                return
+                return      # and nothing in flight: its rows would go on
             n = len(active)
             bb = next_bucket(n, self.batch_buckets)
             cb = next_bucket(max(s.n_written for s in active) + 1,
                              self.ctx_buckets)
-            tokens = np.zeros(bb, np.int32)
             positions = np.zeros(bb, np.int32)
             write_slots = np.zeros(bb, np.int32)       # 0 = scratch block
             slot_grid = np.zeros((bb, cb), np.int32)
             slot_grid[:n] = self.cache.gather_slots(
                 [s.id for s in active], cb)
             for i, s in enumerate(active):
-                tokens[i] = s.pending
                 positions[i] = s.n_written
                 write_slots[i] = self.cache.slot_of(s.id, s.n_written)
             # an all-greedy step picks inside the program; a sampled
             # sequence needs its logits on the host (see _choose_token)
             device_pick = not any(s.temperature > 0.0 for s in active)
-        with tel.span("serve.decode.device", width=n, batch_bucket=bb,
-                      ctx_bucket=cb):
+            # may this step stay unread when this returns?
+            stays = device_pick and all(
+                len(s.generated) + s.unread + 1 < s.max_new
+                for s in active)
+            flight, self._flight = self._flight, None
+            ahead = flight is not None and flight.rows == active \
+                and cows == self.cache.cow_copies
+        if flight is not None and not ahead:
+            self._read(flight)
+        attrs = {"width": n, "batch_bucket": bb, "ctx_bucket": cb}
+        with tel.span("serve.decode.ahead" if ahead
+                      else "serve.decode.device", **attrs):
             t0 = time.perf_counter_ns() if tel.enabled else 0
+            if not ahead:
+                tokens = np.zeros(bb, np.int32)
+                tokens[:n] = [s.pending for s in active]
+            elif self.model.counter_names:
+                tokens = self._dispatch(("decode_ids", bb), self._ids_fn,
+                                        flight.out, bb)
+            else:
+                tokens = flight.out
             if device_pick:
                 key, fn = ("decode", bb, cb), self._step_fn
             else:
                 key, fn = ("decode_logits", bb, cb), self._logits_step_fn
-            # the four numpy arrays go to the device inside the jitted
-            # call, with no put of their own
+            # the numpy arrays go to the device inside the jitted call,
+            # with no put of their own
             out, pools = self._dispatch(
                 key, fn, self.params, self.cache.pools, tokens,
                 positions, slot_grid, write_slots)
             self.cache.pools = pools
-            # the step's one host sync: [bb] int32 ids (the model's
-            # counters behind them, where it has any), or [bb, V]
-            # float32 logits on the sampled route
-            counted = None
-            if self.model.counter_names:
-                if device_pick:
-                    out = np.asarray(out)
-                    out, counted = out[:bb], out[bb:]
-                else:
-                    out, counted = out
-            last = np.asarray(out)
-            t1 = time.perf_counter_ns() if tel.enabled else 0
-            records = self._count("decode", counted, t0, t1)
-        with tel.span("serve.decode.sample"):
+            step = _DecodeProgram(active, bb, attrs, device_pick, out, t0)
             self.decode_steps += 1
-            if device_pick:
-                self.decode_device_pick_steps += 1
-                last = last.tolist()        # Python ints, in one call
-            for i, s in enumerate(active):
+            self.decode_device_pick_steps += device_pick
+            self.decode_ahead_steps += ahead
+            for s in active:
                 s.n_written += 1
-                tok = last[i] if device_pick else _choose_token(
+                s.unread += 1
+            if tel.enabled:
+                tel.inc(f"{self.name}_decode_steps")
+                if device_pick:
+                    tel.inc(f"{self.name}_decode_device_pick_steps")
+                if ahead:
+                    tel.inc(f"{self.name}_decode_ahead_steps")
+            if stays:
+                out.copy_to_host_async()    # lands when the program ends
+            elif not ahead:
+                # read at once: the span runs from the dispatch through
+                # the host sync, as it does in a synchronous loop
+                self._sync(step)
+        if ahead:
+            self._read(flight)
+        if stays:
+            self._flight = step
+        else:
+            self._read(step)
+
+    def _sync(self, step):
+        """The one host sync of a decode program: ``[bb]`` int32 ids
+        (the model's counters and row records behind them, where it has
+        any), or ``[bb, V]`` float32 logits on the sampled route."""
+        out, counted = step.out, None
+        if self.model.counter_names:
+            if step.device_pick:
+                out = np.asarray(out)
+                out, counted = out[:step.bb], out[step.bb:]
+            else:
+                out, counted = out
+        step.last = np.asarray(out)
+        step.out = None
+        step.t1 = time.perf_counter_ns() if self.telemetry.enabled else 0
+        step.records = self._count("decode", counted, step.t0, step.t1)
+
+    def _read(self, step):
+        """The host's half of a dispatched decode program: the sync
+        (unless :meth:`_decode_once` made it inside the dispatch's
+        span), then each row's token, record and timeline."""
+        tel = self.telemetry
+        if step.last is None:
+            with tel.span("serve.decode.device", **step.attrs):
+                self._sync(step)
+        with tel.span("serve.decode.sample"):
+            last = step.last
+            if step.device_pick:
+                last = last.tolist()        # Python ints, in one call
+            # a request's episodes tile: a program dispatched ahead
+            # starts its rows' episode where the one before was read
+            t0 = max(step.t0, self._decode_read_ns)
+            self._decode_read_ns = step.t1
+            for i, s in enumerate(step.rows):
+                s.unread -= 1
+                tok = last[i] if step.device_pick else _choose_token(
                     last[i], s.temperature, s.seed, len(s.generated))
                 s.generated.append(tok)
                 s.pending = tok
-                if records is not None:
-                    s.records.append(records[i])
+                if step.records is not None:
+                    s.records.append(step.records[i])
                 if s.tl is not None:
                     # a preempted sequence re-earning lost tokens is in
                     # "replay", not "decode" — the doctor's replay bucket
                     s.tl.note("replay" if s.replaying() else "decode",
-                              t0, t1)
+                              t0, step.t1)
             if tel.enabled:
-                tel.inc(f"{self.name}_tokens", n)
-                tel.inc(f"{self.name}_decode_steps")
-                if device_pick:
-                    tel.inc(f"{self.name}_decode_device_pick_steps")
+                tel.inc(f"{self.name}_tokens", len(step.rows))
+
+    def _read_flight(self):
+        """Read the decode program in flight, if there is one: after
+        this the host's values are what a synchronous loop holds."""
+        step, self._flight = self._flight, None
+        if step is not None:
+            self._read(step)
 
     def _finish_done(self):
         tel = self.telemetry
@@ -1153,6 +1299,7 @@ class ContinuousBatchingEngine:
             self._waiting.clear()
             self._running.clear()
             self._cond.notify_all()
+        self._flight = None     # lock-ok: HT601 the scheduler's thread has ended (close() joined it) or is the caller; its rows fail below
         for s in leftovers:
             self.cache.free_seq(s.id)
             if not s.future.done():

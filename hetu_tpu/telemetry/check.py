@@ -154,6 +154,11 @@ SPAN_SCHEMA = {
     "serve.decode.device": {"width": _req(_INT),
                             "batch_bucket": _req(_INT),
                             "ctx_bucket": _req(_INT)},
+    # the dispatch of a decode step made while the step before is
+    # still unread (its tokens stay on the device)
+    "serve.decode.ahead": {"width": _req(_INT),
+                           "batch_bucket": _req(_INT),
+                           "ctx_bucket": _req(_INT)},
     # autotuner / probe (tune/)
     "autotune_sweep": {"kernel": _req(_STR), "key": _req(_STR),
                        "chosen": _req(_STR), "picked_ms": _req(_NUM),

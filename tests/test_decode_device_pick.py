@@ -285,8 +285,10 @@ def test_greedy_traffic_compiles_no_logits_program(model):
     assert kinds == ["('decode'", "('prefill'"], kinds
     assert engine.jit_compiles == len(keys) == len(engine._signatures)
     assert engine._logits_step_fn._cache_size() == 0
-    assert engine._step_fn._cache_size() == \
-        sum(k.startswith("('decode'") for k in keys)
+    # one compile a key; a bucket's fast path holds a second entry where
+    # its tokens also came as the device array of the step before
+    decodes = sum(k.startswith("('decode'") for k in keys)
+    assert decodes <= engine._step_fn._cache_size() <= 2 * decodes
     # the first sampled step is what compiles it
     _drive(engine, [engine.submit(_prompts()[0], 2, temperature=0.5)])
     assert engine._logits_step_fn._cache_size() == 1
