@@ -316,14 +316,15 @@ class _FlashAttentionGradOp(Op):
         res = ectx.cache.get(("flash_res", fwd.id))
         if cache_key not in ectx.cache and res is not None and \
                 q.shape[-2] >= FUSED_BWD_MIN_SEQ:
-            # fused Pallas backward: rebuild score blocks in VMEM from
-            # the forward's logsumexp — the S x S matrices never hit HBM
-            # on the backward either (pallas_attention.py). Below the
-            # threshold the composed vjp wins: XLA fuses the small S^2
-            # intermediates on-chip anyway and the kernels' extra
-            # recompute pass costs more than it saves (measured: S=128
-            # BERT-base 120k tok/s composed vs 100k fused; S=2048
-            # 186k composed vs 226k fused).
+            # fused Pallas backward: ONE kernel rebuilds each score
+            # tile in VMEM from the forward's logsumexp and feeds dQ,
+            # dK and dV from it — the S x S matrices never hit HBM on
+            # the backward either (pallas_attention.py). Below the
+            # threshold the composed vjp stays: XLA fuses the small S^2
+            # intermediates on-chip anyway (measured with the older
+            # two-kernel backward: S=128 BERT-base 120k tok/s composed
+            # vs 100k fused; S=2048 186k composed vs 226k fused; the
+            # one-pass kernel alone at BERT's shape: PERF.md section 7).
             from .pallas_attention import flash_attention_bwd
             o, lse = res
             ectx.cache[cache_key] = flash_attention_bwd(

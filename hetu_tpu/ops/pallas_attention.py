@@ -7,12 +7,21 @@ K/V blocks through VMEM keeping a running (max, sum, accumulator) — the
 [S, S] score matrix never exists in HBM, so attention memory is O(S·D)
 instead of O(S²) and the MXU stays fed from VMEM.
 
-Backward is the standard recompute form: the forward also emits the
-per-row logsumexp L, and two kernels rebuild score blocks in VMEM —
-one gridded over K blocks producing dK/dV, one over Q blocks producing
-dQ — so the S×S matrices never exist in HBM on the backward pass either
-(the property training needs for long context; D = rowsum(dO ∘ O) is a
-cheap XLA elementwise reduce outside the kernels).
+Backward is the standard recompute form, in ONE kernel a call: the
+forward also emits the per-row logsumexp L, and the kernel visits each
+(q-tile, k-tile) pair once, rebuilds its score tile in VMEM and feeds
+dV, dK and dQ from that one P / dS, every sum across tiles in float32
+and rounded once. So the S×S matrices never exist in HBM on the backward
+pass either (the property training needs for long context). With
+``causal`` the walk follows the diagonal: tile pairs wholly above it are
+never run at any tile size, and only the pairs it cuts carry the iota /
+compare / select (``bwd_walk``). The tile is built transposed
+(``[block_k, block_q]``), so the row residuals L and D = rowsum(dO ∘ O)
+(a cheap XLA elementwise reduce outside the kernel) travel as
+``[B*H, 1, S]`` rows and every matmul of a pair is a plain one. Pairs
+are grouped into square REGIONS (``_bwd_span``): a region's pairs are
+straight-line code the compiler interleaves, the regions are walked by a
+loop from the diagonal down, a program per (batch*head, region row).
 
 Block sizes are AUTOTUNED per (platform, kernel, S, D, dtype, causal,
 mask): bq/bk sweep {128, 256, 512, 1024} (clipped to divisors of S)
@@ -20,16 +29,19 @@ independently for the forward, the forward-with-lse and the fused
 backward through ``hetu_tpu/tune`` — the sweep runs once at first
 compile of a shape, the winner persists in the autotune JSON cache, and
 ``HETU_AUTOTUNE=0`` falls back to the static ``_block_sizes`` defaults
-(bq≤256, bk≤512). The backward keeps a full K/V block resident across
-its whole q-loop, so its best tiles differ from the forward's — that
-per-direction freedom is the point of tuning the three kernels apart.
-Batch/heads are NOT in the key (they only size the embarrassingly
+(bq≤256, bk≤512). With ``causal`` the backward's tiles also decide how
+much of the square is skipped (a tile 1024 long on either side of S=1024
+is cut by the diagonal everywhere), so its best tiles differ from the
+forward's —
+that per-direction freedom is the point of tuning the three kernels
+apart. Batch/heads are NOT in the key (they only size the embarrassingly
 parallel grid axis; per-program work is S/D-shaped): the sweep times
 the first caller's b/h and later batch sizes share that winner.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 
@@ -42,7 +54,7 @@ __all__ = ["flash_attention", "flash_attention_with_lse",
            "flash_attention_bwd", "tune_key"]
 
 NEG_INF = -1e30
-LANES = 128      # TPU minor-dim tile: residual vectors store lane-tiled
+LANES = 128      # TPU minor-dim tile: the forward writes lse lane-tiled
 # A kernel gets 16 MiB of VMEM without asking. The forward holds a
 # head's whole K and V on chip, double-buffered: past this many bytes of
 # them (S = 8192 at D = 192 is 16.8e6) it asks for what it needs.
@@ -148,6 +160,13 @@ def _candidates(s):
     return [c for c in _CANDIDATE_BLOCKS if c <= s and s % c == 0]
 
 
+# a kernel whose tile walk changed is swept afresh: winners stored for
+# the kernel it replaced (a checkout keeps its autotune.json across a
+# pull) sit under the old name and are not read. ``bwd`` was two kernels
+# (dK/dV, dQ) that ran the whole square at the tiles they liked best.
+_KERNEL_REVISION = {"bwd": "bwd_onepass"}
+
+
 def tune_key(kind, s, d, dtype, causal, has_mask, interpret=False):
     """(name, key) under which a flash kernel's block choice is cached —
     shared by the tuner, the probe and the tests. ``kind`` is one of
@@ -158,7 +177,7 @@ def tune_key(kind, s, d, dtype, causal, has_mask, interpret=False):
            "mask" if has_mask else "nomask")
     if interpret:
         key = key + ("interp",)
-    return "flash_" + kind, key
+    return "flash_" + _KERNEL_REVISION.get(kind, kind), key
 
 
 def _measure_factory(kind, b, h, s, d, dtype, sm_scale, causal, has_mask,
@@ -370,93 +389,199 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
 
 
 # ---------------------------------------------------------------------------
-# fused backward (recompute form)
+# fused backward (recompute form, one pass over the tile pairs)
 # ---------------------------------------------------------------------------
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
-                    dk_ref, dv_ref, *, sm_scale, block_q, block_k,
-                    seq_len, causal):
+def _first_q_tile(kj, block_q, block_k):
+    """Causal: the first q-tile holding a row at or below k-tile
+    ``kj``'s first key; every q-tile before it lies wholly above the
+    diagonal and is never run. Python ints or traced ints alike."""
+    return (kj * block_k) // block_q
+
+
+def _first_unmasked_q_tile(kj, block_q, block_k):
+    """Causal: the first q-tile whose first row is at or below k-tile
+    ``kj``'s LAST key (not clipped to the tile count); from it on the
+    diagonal cuts nothing and the tile body carries no mask."""
+    return ((kj + 1) * block_k + block_q - 2) // block_q
+
+
+def bwd_walk(s, block_q, block_k, causal):
+    """The (q-tile, k-tile) pairs the backward kernel runs, and of them
+    those that carry the causal iota / compare / select — from the same
+    two bounds the kernel leaves pairs out by and masks by (inside the
+    regions on the diagonal; the regions below it hold only pairs these
+    bounds keep and do not mask). The full square, nothing masked,
+    without ``causal``."""
+    num_qb, num_kb = s // block_q, s // block_k
+    visited, masked = [], []
+    for kj in range(num_kb):
+        first, unmasked = 0, 0
+        if causal:
+            first = _first_q_tile(kj, block_q, block_k)
+            unmasked = min(num_qb,
+                           _first_unmasked_q_tile(kj, block_q, block_k))
+        visited += [(i, kj) for i in range(first, num_qb)]
+        masked += [(i, kj) for i in range(first, unmasked)]
+    return visited, masked
+
+
+def bwd_walk_counts(s, block_q, block_k, causal):
+    """How far the tile walk engages at these tiles: tiles visited, tiles
+    of the square, masked tiles, and the two shares a trace reader wants
+    (visited / square, masked / visited)."""
+    visited, masked = bwd_walk(s, block_q, block_k, causal)
+    square = (s // block_q) * (s // block_k)
+    return {"tiles_visited": len(visited), "tiles_square": square,
+            "tiles_masked": len(masked),
+            "visited_share": round(len(visited) / square, 4),
+            "masked_share": round(len(masked) / len(visited), 4)}
+
+
+# a straight-line region of the backward: at most this many tile pairs,
+# and this many rows a side (16 pairs of 1024 x 1024 compile for half a
+# minute and gain nothing over 4)
+_REGION_TILES = 16
+_REGION_ROWS = 2048
+
+
+def _bwd_span(s, block_q, block_k):
+    """Side of the square REGIONS the backward walks: the largest
+    divisor of S that is whole tiles both ways within the two bounds
+    above (one tile pair where a single one is past them). A region's
+    pairs are straight-line code, so the compiler overlaps one pair's
+    matmuls with another's elementwise work — a dependent chain of
+    three matmul stages that a pair walked alone by a loop waits out
+    (0.45 us a pair on a v5e, PERF.md PR 36); the regions themselves
+    are walked by a loop, so the code does not grow with S."""
+    tile = math.lcm(block_q, block_k)
+    return max([m for m in range(tile, min(s, _REGION_ROWS) + 1, tile)
+                if s % m == 0
+                and (m // block_q) * (m // block_k) <= _REGION_TILES],
+               default=tile)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
+                dq_ref, dk_ref, dv_ref, *acc, sm_scale, block_q, block_k,
+                span, seq_len, causal):
+    """One region row's program: K / V rows ``[kj*span, (kj+1)*span)``
+    stay resident and the program walks the q-regions the diagonal
+    leaves them — the region ON the diagonal first (its tile pairs
+    wholly above the diagonal are left out and only the pairs it cuts
+    carry the iota / compare / select, all decided while tracing), then
+    every region below it (all pairs, no mask); regions above it are
+    not run. Each pair rebuilds its score tile ONCE, TRANSPOSED
+    (``[block_k, block_q]``: the row residuals lse and D then lie on
+    lanes and dV, dK are plain matmuls; dQ is summed transposed too,
+    ``[D, block_q]``: K^T dS^T is a plain matmul where dS K would
+    transpose every tile), and feeds all three gradients from that one
+    P / dS. A region's sums are float32 values. Where one region is the
+    whole head (``span == seq_len``) they are rounded straight into the
+    outputs; else they add up in the float32 scratch ``acc`` — dK / dV
+    over the program's walk, dQ (``[D, S]``) across the programs of a
+    head — and are rounded once at the end."""
     kj = pl.program_id(1)
-    k = k_ref[0]                              # [block_k, d]
-    v = v_ref[0]
-    num_qb = seq_len // block_q
-    start = (kj * block_k) // block_q if causal else 0
+    nt = (((1,), (1,)), ((), ()))             # a @ b^T
+    nn = (((1,), (0,)), ((), ()))
+    whole = span == seq_len
+    if not whole:
+        dq_acc, dk_acc, dv_acc = acc
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = l_ref[0, pl.ds(i * block_q, block_q), 0:1][:, 0]
-        dd = d_ref[0, pl.ds(i * block_q, block_q), 0:1][:, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if mask_ref is not None:
-            s = s + mask_ref[0, 0, pl.ds(kj * block_k, block_k)][None, :]
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])         # f32 [block_q, block_k]
-        dv = dv + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dd[:, None]) * sm_scale
-        dk = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk, dv
+        @pl.when(kj == 0)
+        def _():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    zeros = jnp.zeros((block_k, k.shape[1]), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start, num_qb, body, (zeros, zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    def region(qi, diagonal, first):
+        q0 = 0 if whole else qi * span       # static where it can be
+        rows = [pl.ds(q0 + i * block_q if isinstance(q0, int) else
+                      pl.multiple_of(q0 + i * block_q, block_q), block_q)
+                for i in range(span // block_q)]
+        dqt = [0.0] * len(rows)
+        for j in range(span // block_k):
+            keys = slice(j * block_k, (j + 1) * block_k)
+            k = k_ref[0, keys, :]             # [block_k, d]
+            v = v_ref[0, keys, :]
+            kt = k.T
+            begin, unmasked = 0, 0
+            if diagonal:    # q0 is the keys' own offset: local indices
+                begin = _first_q_tile(j, block_q, block_k)
+                unmasked = _first_unmasked_q_tile(j, block_q, block_k)
+            dk = dv = 0.0
+            for i in range(begin, len(rows)):
+                q = q_ref[0, rows[i], :]      # [block_q, d]
+                do = do_ref[0, rows[i], :]
+                st = jax.lax.dot_general(
+                    k, q, nt,
+                    preferred_element_type=jnp.float32) * sm_scale
+                if mask_ref is not None:
+                    st = st + mask_ref[0, keys, :]    # [block_k, 1]
+                if i < unmasked:
+                    k_pos = j * block_k + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 0)
+                    q_pos = i * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 1)
+                    st = jnp.where(q_pos >= k_pos, st, NEG_INF)
+                pt = jnp.exp(st - l_ref[0, 0, rows[i]][None, :])  # P^T
+                dv = dv + jax.lax.dot_general(
+                    pt.astype(do.dtype), do, nn,
+                    preferred_element_type=jnp.float32)
+                dpt = jax.lax.dot_general(
+                    v, do, nt, preferred_element_type=jnp.float32)
+                dst = (pt * (dpt - d_ref[0, 0, rows[i]][None, :])
+                       * sm_scale).astype(q.dtype)                # dS^T
+                dk = dk + jax.lax.dot_general(
+                    dst, q, nn, preferred_element_type=jnp.float32)
+                dqt[i] = dqt[i] + jax.lax.dot_general(
+                    kt, dst, nn, preferred_element_type=jnp.float32)
+            if whole:
+                dk_ref[0, keys, :] = dk.astype(dk_ref.dtype)
+                dv_ref[0, keys, :] = dv.astype(dv_ref.dtype)
+            elif first:     # the program's first region: nothing to add to
+                dk_acc[keys, :] = dk
+                dv_acc[keys, :] = dv
+            else:
+                dk_acc[keys, :] += dk
+                dv_acc[keys, :] += dv
+        for i, dq in enumerate(dqt):
+            if whole:
+                dq_ref[0, rows[i], :] = dq.T.astype(dq_ref.dtype)
+            else:
+                dq_acc[:, rows[i]] += dq
+
+    # the diagonal's region with ``causal``, else the first: traced apart
+    # from the loop, for the pairs it leaves out or for ``first`` alone
+    region(kj if causal else 0, diagonal=causal, first=True)
+    if whole:
+        return
+
+    def body(qi, carry):
+        region(qi, diagonal=False, first=False)
+        return carry
+
+    jax.lax.fori_loop(kj + 1 if causal else 1, seq_len // span, body, 0)
+    dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(kj == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
-                   dq_ref, *, sm_scale, block_q, block_k, seq_len,
-                   causal):
-    qi = pl.program_id(1)
-    q = q_ref[0]                              # [block_q, d]
-    do = do_ref[0]
-    lse = l_ref[0, :, 0:1][:, 0]              # [block_q] (lane-tiled in)
-    dd = d_ref[0, :, 0:1][:, 0]
-    num_kb = seq_len // block_k
-    if causal:
-        num_kb = jnp.minimum(num_kb,
-                             pl.cdiv((qi + 1) * block_q, block_k))
-
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if mask_ref is not None:
-            s = s + mask_ref[0, 0, pl.ds(j * block_k, block_k)][None, :]
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dd[:, None]) * sm_scale
-        return dq + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    dq0 = jnp.zeros(q.shape, jnp.float32)
-    dq = jax.lax.fori_loop(0, num_kb, body, dq0)
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+def _backward_compiler_params(s, d, span, block_q, block_k, itemsize):
+    """The region axis is a reduction into ``dq_acc`` (sequential); the
+    VMEM asked for covers a head's q, dO and dQ and a region's K, V, dK
+    and dV (double-buffered), the float32 accumulators and the
+    score-tile temporaries of a region's pairs, which at the largest
+    candidate tiles pass the 16 MiB a kernel gets unasked."""
+    lanes = -(-d // LANES) * LANES
+    resident = (3 * 2 * itemsize + 4) * s * lanes \
+        + 2 * (4 * itemsize + 4) * span * lanes
+    tiles = 6 * 4 * max(block_q * block_k, span * span // 4)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=int(min(_MOST_VMEM,
+                                 max(16 * 1024 * 1024,
+                                     2 * (resident + tiles)))))
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "causal",
@@ -465,86 +590,59 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
 def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
                              interpret, block_q, block_k):
     b, h, s, d = q.shape
-    grid_kv = (b * h, s // block_k)
-    grid_q = (b * h, s // block_q)
-
     qr = q.reshape(b * h, s, d)
     kr = k.reshape(b * h, s, d)
     vr = v.reshape(b * h, s, d)
     dor = do.reshape(b * h, s, d)
-    # residual vectors travel lane-tiled (TPU 128-lane minors)
-    lser = jnp.broadcast_to(lse.reshape(b * h, s)[:, :, None],
-                            (b * h, s, LANES))
-    # D = rowsum(dO * O): cheap XLA reduce, shared by both kernels
-    dr = jnp.broadcast_to(
-        jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                axis=-1).reshape(b * h, s)[:, :, None],
-        (b * h, s, LANES))
+    # the row residuals travel as rows [B*H, 1, S] (S on lanes, where
+    # the transposed score tile wants them), not broadcast over lanes
+    lser = lse.reshape(b * h, 1, s).astype(jnp.float32)
+    # D = rowsum(dO * O): cheap XLA elementwise reduce
+    dr = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                 axis=-1).reshape(b * h, 1, s)
 
-    full = lambda bh, i: (bh, 0, 0)         # noqa: E731
-    in_specs_kv = [
-        pl.BlockSpec((1, s, d), full),
-        pl.BlockSpec((1, block_k, d), lambda bh, kj: (bh, kj, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, kj: (bh, kj, 0)),
-        pl.BlockSpec((1, s, d), full),
-        pl.BlockSpec((1, s, LANES), full),
-        pl.BlockSpec((1, s, LANES), full),
-    ]
-    in_specs_q = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, s, d), full),
-        pl.BlockSpec((1, s, d), full),
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, LANES), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, LANES), lambda bh, qi: (bh, qi, 0)),
+    span = _bwd_span(s, block_q, block_k)
+    head = lambda bh, kj: (bh, 0, 0)          # noqa: E731
+    keys = lambda bh, kj: (bh, kj, 0)         # noqa: E731
+    in_specs = [
+        pl.BlockSpec((1, s, d), head),
+        pl.BlockSpec((1, span, d), keys),
+        pl.BlockSpec((1, span, d), keys),
+        pl.BlockSpec((1, s, d), head),
+        pl.BlockSpec((1, 1, s), head),
+        pl.BlockSpec((1, 1, s), head),
     ]
     args = [qr, kr, vr, dor, lser, dr]
+    body = functools.partial(_bwd_kernel, sm_scale=sm_scale,
+                             block_q=block_q, block_k=block_k, span=span,
+                             seq_len=s, causal=causal)
     if mask is not None:  # jit-ok: structural None-check, not a traced read
-        mrow = _mask_rows(mask, b, h, s)
-        mask_spec = pl.BlockSpec((1, 1, s),
-                                 lambda bh, i, _h=h: (bh // _h, 0, 0))
-        in_specs_kv.append(mask_spec)
-        in_specs_q.append(mask_spec)
-        args = args + [mrow]
-        kv_kernel = functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, block_q=block_q,
-            block_k=block_k, seq_len=s, causal=causal)
-        q_kernel = functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, block_q=block_q,
-            block_k=block_k, seq_len=s, causal=causal)
+        # per KEY, so a column of the transposed tile: [B, S, 1]
+        in_specs.append(pl.BlockSpec(
+            (1, span, 1), lambda bh, kj, _h=h: (bh // _h, kj, 0)))
+        args.append(_mask_rows(mask, b, h, s).reshape(b, s, 1))
+        kernel = body
     else:
-        def kv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
-                      dk_ref, dv_ref):
-            _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
-                            None, dk_ref, dv_ref, sm_scale=sm_scale,
-                            block_q=block_q, block_k=block_k, seq_len=s,
-                            causal=causal)
+        def kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, *rest):
+            body(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, None, *rest)
 
-        def q_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref):
-            _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
-                           None, dq_ref, sm_scale=sm_scale,
-                           block_q=block_q, block_k=block_k, seq_len=s,
-                           causal=causal)
-
-    dk, dv = pl.pallas_call(
-        kv_kernel,
-        out_shape=[jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+                   jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
                    jax.ShapeDtypeStruct((b * h, s, d), v.dtype)],
-        grid=grid_kv,
-        in_specs=in_specs_kv,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, kj: (bh, kj, 0)),
-        ],
-        interpret=interpret,
-    )(*args)
-    dq = pl.pallas_call(
-        q_kernel,
-        out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-        grid=grid_q,
-        in_specs=in_specs_q,
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda bh, qi: (bh, qi, 0)),
+        grid=(b * h, s // span),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, s, d), head),
+                   pl.BlockSpec((1, span, d), keys),
+                   pl.BlockSpec((1, span, d), keys)],
+        # float32 sums across regions: dQ^T a head, dK and dV a program
+        scratch_shapes=[] if span == s else [
+            pltpu.VMEM((d, s), jnp.float32),
+            pltpu.VMEM((span, d), jnp.float32),
+            pltpu.VMEM((span, d), jnp.float32)],
+        compiler_params=_backward_compiler_params(
+            s, d, span, block_q, block_k, q.dtype.itemsize),
         interpret=interpret,
     )(*args)
     shape = (b, h, s, d)
@@ -553,16 +651,21 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
 
 def flash_attention_bwd(q, k, v, mask, o, lse, do, sm_scale=1.0,
                         causal=False, interpret=None):
-    """(dq, dk, dv) via the fused recompute-form kernels. ``lse`` is the
+    """(dq, dk, dv) via the fused recompute-form kernel. ``lse`` is the
     forward's logsumexp (flash_attention_with_lse). Block sizes tune
-    independently of the forward's: the dK/dV kernel holds one K/V block
-    resident across its whole q-loop, so it generally wants smaller bq /
-    larger bk tiles than the forward at long S."""
+    independently of the forward's; with ``causal`` the tiles also
+    decide how much of the square the walk skips (``bwd_walk_counts``,
+    recorded here at trace time as a ``flash_bwd_walk`` instant)."""
     if interpret is None:
         interpret = INTERPRET
     b, h, s, d = q.shape
     block_q, block_k = _tuned_block_sizes(
         "bwd", b, h, s, d, q.dtype, sm_scale, causal, mask is not None,
         interpret)
+    from .. import telemetry
+    telemetry.get_telemetry().instant(
+        "flash_bwd_walk", seq=s, head_dim=d, block_q=block_q,
+        block_k=block_k, causal=bool(causal),
+        **bwd_walk_counts(s, block_q, block_k, causal))
     return _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale,
                                     causal, interpret, block_q, block_k)

@@ -166,6 +166,16 @@ SPAN_SCHEMA = {
     "attn_probe": {"kernel": _opt(_STR), "ms": _opt(_NUM),
                    "blocks": _opt(_STR), "seq": _opt(_INT),
                    "head_dim": _opt(_INT), "dtype": _opt(_STR)},
+    # the flash backward's tile walk, recorded beside its tiles at
+    # trace time (ops/pallas_attention.py:flash_attention_bwd)
+    "flash_bwd_walk": {"seq": _req(_INT), "head_dim": _opt(_INT),
+                       "block_q": _req(_INT), "block_k": _req(_INT),
+                       "causal": _req(_BOOL),
+                       "tiles_visited": _req(_INT),
+                       "tiles_square": _req(_INT),
+                       "tiles_masked": _req(_INT),
+                       "visited_share": _req(_NUM),
+                       "masked_share": _req(_NUM)},
 }
 
 

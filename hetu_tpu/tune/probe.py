@@ -44,9 +44,12 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
     """Per-kernel milliseconds for the flash fwd(+lse)/bwd on one shape.
 
     Returns ``{"fwd_ms", "fwd_lse_ms", "bwd_ms", "blocks": {kind:
-    (bq, bk)}}`` plus ``static_*_ms`` twins measured with the untuned
-    ``_block_sizes`` defaults when ``include_static`` (the in-repo
-    tuned-vs-static evidence). Uses the tuned path, so a cold autotune
+    (bq, bk)}, "bwd_walk": {...}}`` plus ``static_*`` twins measured
+    with the untuned ``_block_sizes`` defaults when ``include_static``
+    (the in-repo tuned-vs-static evidence). ``bwd_walk`` is the one-pass
+    backward's tile walk at its tiles (``pallas_attention.
+    bwd_walk_counts``): with ``causal`` the share of the square it
+    visits and the share of visited tiles that carry the mask. Uses the tuned path, so a cold autotune
     cache sweeps here — which is the point: the probe pays the sweep
     the training step would have paid, and the cache makes both free
     afterwards."""
@@ -90,6 +93,11 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
             has_mask, interpret)
         out["blocks"][kind] = list(tuned[kind])
     static = pk._block_sizes(seq, head_dim)
+    # how far the backward's tile walk engages at the tiles it runs
+    # with: tiles visited / tiles of the square, masked / visited
+    out["bwd_walk"] = pk.bwd_walk_counts(seq, *tuned["bwd"], causal)
+    if include_static:
+        out["static_bwd_walk"] = pk.bwd_walk_counts(seq, *static, causal)
 
     def run_fwd(blocks, need_lse):
         bq, bk = blocks

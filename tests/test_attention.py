@@ -202,6 +202,135 @@ def test_flash_attention_fused_backward():
                         f"causal={causal})")
 
 
+def _causal_mask(s):
+    return jnp.where(jnp.tril(jnp.ones((s, s), bool)), 0.0,
+                     -1e30)[None, None]
+
+
+@pytest.mark.parametrize("tiles", [(128, 128), (128, 256), (256, 128),
+                                   (128, 384), (384, 128), (64, 128)],
+                         ids=lambda t: f"{t[0]}x{t[1]}")
+@pytest.mark.parametrize("use_mask", [False, True], ids=["nomask", "mask"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_one_pass_backward_matches_composed_vjp(causal, use_mask, tiles):
+    """The one-pass backward at explicit tiles (three tiles a side at
+    128, block_q != block_k in both orders, S = 384 and 768: neither a
+    power of two) against jax.vjp of the composed reference."""
+    from hetu_tpu.ops import pallas_attention as pk
+    bq, bk = tiles
+    s = 768 if 256 in tiles else 384
+    q, k, v = _qkv(b=2, h=2, s=s, seed=17)
+    mask = None
+    if use_mask:
+        m = np.zeros((2, 1, 1, s), np.float32)
+        m[0, ..., s - 50:] = -1e9       # a padded tail ...
+        m[1, ..., 130:141] = -1e9       # ... and keys masked mid-row
+        mask = jnp.asarray(m)
+    dy = jnp.asarray(np.random.RandomState(5).randn(*q.shape),
+                     jnp.float32)
+    o, lse = pk._flash_attention_jit(q, k, v, mask, 0.25, causal, True,
+                                     128, 128, True)
+    got = pk._flash_attention_bwd_jit(q, k, v, mask, o, lse, dy, 0.25,
+                                      causal, True, bq, bk)
+    m = mask
+    if causal:
+        m = _causal_mask(s) if m is None else m + _causal_mask(s)
+    _, vjp = jax.vjp(
+        lambda q_, k_, v_: attention_reference(q_, k_, v_, m, 0.25),
+        q, k, v)
+    for g, w, name in zip(got, vjp(dy), "qkv"):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4,
+                                   err_msg=f"d{name} at tiles {tiles}")
+
+
+def _cut_by_the_diagonal(s, bq, bk):
+    """From the causal matrix itself: the (q-tile, k-tile) pairs that
+    hold a kept score, and of them those that also hold a masked one."""
+    keep = np.tril(np.ones((s, s), bool)).reshape(s // bq, bq,
+                                                  s // bk, bk)
+    some, every = keep.any(axis=(1, 3)), keep.all(axis=(1, 3))
+    order = lambda pairs: sorted(map(tuple, pairs.tolist()))  # noqa: E731
+    return order(np.argwhere(some)), order(np.argwhere(some & ~every))
+
+
+@pytest.mark.parametrize("s", [384, 1024, 2048])
+def test_backward_walk_visits_what_the_diagonal_leaves(s):
+    """At every tile pair the table may choose (and two it may not),
+    with ``causal`` the walk runs exactly the pairs that hold a kept
+    score and masks exactly those the diagonal cuts; without it, the
+    whole square and no mask."""
+    from hetu_tpu.ops import pallas_attention as pk
+    sides = pk._candidates(s) + [64, 192 if s == 384 else 32]
+    for bq in sides:
+        for bk in sides:
+            visited, masked = pk.bwd_walk(s, bq, bk, True)
+            want_visited, want_masked = _cut_by_the_diagonal(s, bq, bk)
+            assert sorted(visited) == want_visited, (s, bq, bk)
+            assert sorted(masked) == want_masked, (s, bq, bk)
+            counts = pk.bwd_walk_counts(s, bq, bk, True)
+            assert counts["tiles_visited"] == len(want_visited)
+            assert counts["tiles_masked"] == len(want_masked)
+            full, none = pk.bwd_walk(s, bq, bk, False)
+            assert len(full) == (s // bq) * (s // bk) and none == []
+    # what ISSUE 36 asks of the shares at the cell's S
+    assert pk.bwd_walk_counts(1024, 256, 256, True)["visited_share"] \
+        == 0.625
+    assert pk.bwd_walk_counts(1024, 512, 512, True)["visited_share"] \
+        == 0.75
+    assert pk.bwd_walk_counts(1024, 128, 128, True)["visited_share"] \
+        == 0.5625
+
+
+@pytest.mark.parametrize("s,tiles,span", [
+    (1024, (256, 256), 1024), (1024, (512, 512), 1024),
+    (1024, (128, 128), 512), (1024, (128, 256), 512),
+    (1024, (1024, 128), 1024), (2048, (256, 256), 1024),
+    (2048, (512, 512), 2048), (768, (128, 256), 256),
+    (768, (128, 128), 384), (384, (384, 128), 384), (128, (64, 32), 128),
+    (8192, (128, 128), 512), (1536, (512, 512), 1536)],
+    ids=lambda v: str(v).replace(" ", ""))
+def test_backward_regions_are_whole_tiles_and_bounded(s, tiles, span):
+    """The square regions whose tile pairs the kernel unrolls: whole
+    tiles both ways, a divisor of S, never more than 16 pairs (so the
+    code does not grow with S), and as large as that allows."""
+    from hetu_tpu.ops import pallas_attention as pk
+    bq, bk = tiles
+    got = pk._bwd_span(s, bq, bk)
+    assert got == span
+    assert s % got == 0 and got % bq == 0 and got % bk == 0
+    pairs = (got // bq) * (got // bk)
+    assert pairs <= pk._REGION_TILES or got == max(bq, bk)
+
+
+@pytest.mark.parametrize("tiles", [(128, 128), (128, 256), (256, 128)],
+                         ids=lambda t: f"{t[0]}x{t[1]}")
+def test_backward_kernel_does_not_run_tiles_above_the_diagonal(tiles):
+    """The kernel follows the walk: NaN rows in q (and dO) of the FIRST
+    q-tile reach dK / dV of a k-tile only through a tile that was run
+    (0 x NaN is NaN even where the mask zeroes P), so the k-tiles wholly
+    after that q-tile stay finite exactly when their pairs are skipped."""
+    from hetu_tpu.ops import pallas_attention as pk
+    bq, bk = tiles
+    s = 768
+    q, k, v = _qkv(b=1, h=2, s=s, seed=23)
+    dy = jnp.asarray(np.random.RandomState(6).randn(*q.shape),
+                     jnp.float32)
+    o, lse = pk._flash_attention_jit(q, k, v, None, 0.25, True, True,
+                                     128, 128, True)
+    poison = jnp.arange(s)[None, None, :, None] < bq
+    q, dy = jnp.where(poison, jnp.nan, q), jnp.where(poison, jnp.nan, dy)
+    _, dk, dv = pk._flash_attention_bwd_jit(q, k, v, None, o, lse, dy,
+                                            0.25, True, True, bq, bk)
+    visited, _ = pk.bwd_walk(s, bq, bk, True)
+    for kj in range(s // bk):
+        ran = (0, kj) in visited
+        for g in (dk, dv):
+            tile = np.asarray(g[:, :, kj * bk:(kj + 1) * bk])
+            assert np.isnan(tile).any() == ran, (kj, ran)
+    assert not all((0, kj) in visited for kj in range(s // bk))
+
+
 def test_flash_attention_op_fused_backward_path(monkeypatch):
     """The graph op routes grads through the fused kernels when the
     forward stashed its logsumexp residual."""

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu import telemetry as tmod
+from hetu_tpu.telemetry.check import check_args
 from hetu_tpu import tune
 from hetu_tpu.ops import pallas_attention as pk
 from hetu_tpu.ops.attention import attention_reference
@@ -335,6 +336,76 @@ def test_block_independence_s128(tuner, causal):
                                    rtol=2e-4, atol=2e-4)
 
 
+def _backward_at(s, d, causal=True):
+    q, k, v = _qkv(s, d, seed=3)
+    dy = jnp.asarray(np.random.RandomState(5).randn(*q.shape) * 0.3,
+                     jnp.float32)
+    o, lse = pk._flash_attention_jit(q, k, v, None, 0.25, causal, True,
+                                     128, 128, True)
+    return pk.flash_attention_bwd(q, k, v, None, o, lse, dy,
+                                  sm_scale=0.25, causal=causal,
+                                  interpret=True)
+
+
+def test_two_kernel_backward_winners_are_not_read(tuner, monkeypatch):
+    """A checkout keeps its autotune.json across a pull: tiles the
+    two-kernel backward liked (stored as ``flash_bwd``) must not drive
+    the one-pass kernel, whose name carries a revision under the same
+    ``flash_`` prefix."""
+    table, tel = tuner
+    s, d = 512, 8
+    name, key = pk.tune_key("bwd", s, d, jnp.float32, True, False, True)
+    assert name.startswith("flash_") and name != "flash_bwd"
+    assert pk.tune_key("fwd", s, d, jnp.float32, True, False,
+                       True)[0] == "flash_fwd"
+    table.put("flash_bwd", key, (512, 128))     # the parent's winner
+    monkeypatch.setenv("HETU_AUTOTUNE", "1")    # cache only: no sweep
+    seen = []
+    orig = pk._flash_attention_bwd_jit
+
+    def spy(*args):
+        seen.append(args[-2:])
+        return orig(*args)
+
+    monkeypatch.setattr(pk, "_flash_attention_bwd_jit", spy)
+    _backward_at(s, d)
+    assert seen == [pk._block_sizes(s, d)] and _hits(tel) == 0
+    assert tel.counter_value("autotune_cache_miss") == 1
+    table.put(name, key, (128, 256))            # its own entry is read
+    _backward_at(s, d)
+    assert seen[-1] == (128, 256) and _hits(tel) == 1
+    # both stay listed under the prefix the benchmark's driver prints
+    assert len(table.chosen("flash")) == 2
+
+
+@pytest.mark.parametrize("tiles,visited,masked", [
+    ((128, 128), 10 / 16, 4 / 10), ((256, 256), 3 / 4, 2 / 3),
+    ((512, 128), 1.0, 1.0)], ids=["128x128", "256x256", "512x128"])
+def test_backward_records_its_walk_at_trace_time(tuner, tiles, visited,
+                                                 masked):
+    """Beside the tiles, the share of the square the walk visits and
+    the share of visited tiles that carry the mask, as one
+    ``flash_bwd_walk`` instant a traced call (schema-checked)."""
+    table, tel = tuner
+    s, d = 512, 8
+    name, key = pk.tune_key("bwd", s, d, jnp.float32, True, False, True)
+    table.put(name, key, tiles)
+    _backward_at(s, d)
+    events = [e for e in tel.tracer.drain()
+              if e.get("name") == "flash_bwd_walk"]
+    assert len(events) == 1
+    args = events[0]["args"]
+    assert (args["block_q"], args["block_k"]) == tiles
+    assert args["visited_share"] == pytest.approx(visited, abs=1e-4)
+    assert args["masked_share"] == pytest.approx(masked, abs=1e-4)
+    assert args["tiles_square"] == (s // tiles[0]) * (s // tiles[1])
+    assert check_args("flash_bwd_walk", args) == []
+    _backward_at(s, d, causal=False)
+    full = [e for e in tel.tracer.drain()
+            if e.get("name") == "flash_bwd_walk"][-1]["args"]
+    assert full["visited_share"] == 1.0 and full["tiles_masked"] == 0
+
+
 def test_sweep_once_then_zero_sweeps(tuner, monkeypatch, tmp_path):
     """The bench acceptance pin: first run sweeps, a second run over
     the same persisted cache performs ZERO sweeps (autotune_cache_hit
@@ -400,6 +471,10 @@ def test_probe_and_attribution(tuner, monkeypatch):
               "static_bwd_ms"):
         assert pr[f] > 0.0
     assert set(pr["blocks"]) == {"fwd", "fwd_lse", "bwd"}
+    # the backward's walk at its tiles: the full square without causal
+    assert pr["bwd_walk"]["visited_share"] == 1.0
+    assert pr["bwd_walk"]["tiles_square"] == \
+        (256 // pr["blocks"]["bwd"][0]) * (256 // pr["blocks"]["bwd"][1])
     att = tune.attribute_step(100.0, 4, pr["fwd_lse_ms"], pr["bwd_ms"])
     # fields are independently rounded to 3 decimals — compare at 2x
     # that granularity

@@ -8,8 +8,10 @@ Shapes are the three the chip paths run at real width — BERT-base
 (b64, h12, S128, padding mask), GPT-2 small (b8, h12, S1024, causal)
 and the long-sequence cell (b8, h8, S2048, padding mask) — in bf16,
 each for the plain forward, the forward with logsumexp and the fused
-backward; plus the smallest and the largest (bq, bk) the autotuner may
-pick at S=2048. A compile that passes is a compile, not a run.
+backward (one kernel since PR 36); plus the smallest and the largest
+(bq, bk) the autotuner may pick at S=2048, and the backward at the
+GPT-2 train cell's own shape (b16) over the corners of its candidate
+grid. A compile that passes is a compile, not a run.
 
 LayerNorm's backward kernel (``ops/pallas_norm.py``) compiles at the
 two shapes the train cells run it at, ``[16384, 768]`` (GPT-2 small,
@@ -95,8 +97,29 @@ def test_flash_kernel_compiles_at_static_tiles(one_chip, name, kind):
     shape = SHAPES[name]
     text = _compile(kind, shape, pk._block_sizes(shape[2], shape[3]),
                     one_chip)
-    # the backward is two kernels (dK/dV, dQ)
-    assert text.count("tpu_custom_call") >= (2 if kind == "bwd" else 1)
+    # the backward is ONE kernel (dQ, dK and dV from one pass)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 1024), (1024, 128),
+                                    (1024, 1024), (256, 256), (512, 512)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+def test_one_pass_backward_compiles_at_the_train_cell(one_chip, blocks):
+    """The GPT-2 train cell's backward (batch 16, causal) at the four
+    corners of the candidate grid and the two square tiles the walk
+    skips most at: one custom call, under the name the trace's readers
+    match, and the row residuals reach it as rows — no float32
+    ``[B*H, S, 128]`` lane broadcast is left around it."""
+    b, h, s, d = 16, 12, 1024, 64
+    assert set(blocks) <= set(pk._candidates(s))
+    text = _compile("bwd", (b, h, s, d, True, False), blocks, one_chip)
+    entry = text[text.index("ENTRY"):]
+    calls = [ln for ln in entry.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert calls[0].lstrip().startswith("%_flash_attention_bwd_jit")
+    assert f"f32[{b * h},{s},{pk.LANES}]" not in text
+    assert f"f32[{b * h},1,{s}]" in calls[0]
 
 
 @pytest.mark.parametrize("kind", KINDS)

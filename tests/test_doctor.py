@@ -375,6 +375,10 @@ def _producer_fixture_tracer():
          candidates_ms={"(128, 128)": 1.2, "(256, 256)": None})
     span("attn_probe", kernel="fwd", ms=0.5, blocks="(128, 128)",
          seq=2048, head_dim=64, dtype="bfloat16")
+    tr.instant("flash_bwd_walk", seq=1024, head_dim=64, block_q=256,
+               block_k=256, causal=True, tiles_visited=10,
+               tiles_square=16, tiles_masked=4, visited_share=0.625,
+               masked_share=0.4)
     tr.instant("h2d_stacked", bytes=4096, overlapped=False)
     tr.instant("memory_analysis", label="default", arg_bytes=1)
     tr.instant("step_logged", step=1, wall_ms=2.5)
@@ -408,6 +412,12 @@ def test_schema_accepts_every_producer_fixture(tmp_path):
                         "picked_ms": "fast", "candidates_ms": {}},
      "picked_ms"),
     ("cpp_dispatch", {"fill": 1}, "ticks"),
+    # the flash backward's walk: the shares are numbers, causal a bool
+    ("flash_bwd_walk", {"seq": 1024, "block_q": 256, "block_k": 256,
+                        "causal": 1, "tiles_visited": 10,
+                        "tiles_square": 16, "tiles_masked": 4,
+                        "visited_share": 0.625, "masked_share": 0.4},
+     "causal"),
     # fleet watch / drift (telemetry/fleet.py)
     ("fleet_watch", {"skew_ms": 0.0}, "missing"),
     ("fleet_watch", {"step": 1, "skew_ms": "big"}, "skew_ms"),
