@@ -1428,7 +1428,7 @@ def bench_serving_continuous():
 
     # request-level attribution gate (serving/lifecycle.py + the serving
     # doctor): every timed request must have a COMPLETE timeline whose
-    # queue/prefill/decode/replay/overhead buckets sum to its measured
+    # queue/prefill/decode/stalled/replay buckets sum to its measured
     # e2e — conservation checked, not hoped
     from hetu_tpu.telemetry.doctor import attribute_request_events
     rattr = attribute_request_events(tel.tracer.drain())

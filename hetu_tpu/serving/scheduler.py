@@ -88,17 +88,35 @@ HT901 holds with both features on.
 **Program spans.** The scheduler thread's time is tiled by leaf spans
 (``Telemetry.span``: the ring when telemetry is on, a ``hetu.<name>``
 annotation in a ``jax.profiler`` trace always): ``serve.wait`` (nothing
-waiting, nothing running), ``serve.admit``, ``serve.prefill.build`` /
-``.device`` / ``.sample``, ``serve.decode.build`` / ``.ahead`` /
-``.device`` / ``.sample`` and ``serve.finish``; ``step`` is their
-parent. A ``.device`` span runs from the dispatch of its program
-through the host sync of the rows the scheduler reads, so what lies
-between two of them is the host's own work; where a decode program
-stays in flight its dispatch and its sync are a span each, and the
-dispatch of one made while the one before is unread is
-``serve.decode.ahead``. The jitted programs are named
+waiting, nothing running; at most 100 ms each, so a profile that starts
+inside a wait sees the next slice), ``serve.admit``,
+``serve.prefill.build`` / ``.device`` / ``.sample``,
+``serve.decode.build`` / ``.ahead`` / ``.device`` / ``.sample`` and
+``serve.finish``; ``step`` is their parent. A ``.device`` span runs
+from the dispatch of its program through the host sync of the rows the
+scheduler reads, so what lies between two of them is the host's own
+work; where a decode program stays in flight its dispatch and its sync
+are a span each, and the dispatch of one made while the one before is
+unread is ``serve.decode.ahead``. Inside ``serve.prefill.device`` the
+host's wait for the result is the child ``serve.prefill.sync``. A
+prefill that runs while other rows could have decoded lies, with the
+finish after it, inside the parent ``serve.stall`` (``rows`` kept
+waiting, ``admitted`` being prefilled; one a chunk under
+``prefill_chunk``). The jitted programs are named
 ``hetu_paged_prefill`` / ``hetu_paged_decode`` /
 ``hetu_paged_decode_logits`` / ``hetu_paged_suffix_prefill``.
+
+**The phase clock.** The helper that opens a leaf span (:meth:`_leaf`)
+also closes a LAP of one clock (``perf_counter_ns``; plain ints, the
+scheduler thread the only writer; telemetry on or off): the time since
+the last lap goes to the phase the thread was in, so the phases
+``wait`` / ``admit`` / ``prefill_host`` / ``prefill_device`` /
+``decode_host`` / ``decode_device`` tile the thread's life exactly
+(``*_device`` is the host blocked on a result, ``*_host`` everything
+else, dispatch included), with ``stalled`` — the ``serve.stall`` spans
+— as an overlay. ``stats()["phase_ms"]`` has the totals; a request's
+account (``Future.account``, ``stats()["request_account"]``,
+``serving/lifecycle.py``) is differences of readings of this clock.
 """
 from __future__ import annotations
 
@@ -119,7 +137,20 @@ from .lifecycle import RequestTimeline, mint_request_id
 from .router import SLOWindow
 from .session import next_bucket
 
-__all__ = ["ContinuousBatchingEngine", "EngineOverloaded"]
+__all__ = ["ContinuousBatchingEngine", "EngineOverloaded", "ENGINE_PHASES"]
+
+_clock = time.perf_counter_ns
+
+# the phase clock's phases (indices into ``_phase_ns``): they tile the
+# scheduler thread; ``stalled`` is kept beside them as an overlay
+ENGINE_PHASES = ("wait", "admit", "prefill_host", "prefill_device",
+                 "decode_host", "decode_device")
+_WAIT, _ADMIT, _PREFILL_HOST, _PREFILL_DEVICE, _DECODE_HOST, \
+    _DECODE_DEVICE = range(len(ENGINE_PHASES))
+# retired requests whose accounts ``stats()["request_account"]`` covers
+_ACCOUNT_WINDOW = 256
+# the longest one ``serve.wait`` span of an idle engine lasts
+_WAIT_SLICE_S = 0.1
 
 
 class EngineOverloaded(RuntimeError):
@@ -210,12 +241,16 @@ class _Seq:
         self.t_submit_ns = self.future.t_submit_ns = time.perf_counter_ns()
         self.t_first_token_ns = self.future.t_first_token_ns = None
         self.future.token_records = None
+        # when it retired, and its latency by phase (ms;
+        # lifecycle.PHASES): both set before the result is
+        self.future.t_retire_ns = self.future.account = None
         self.preempts = 0
         self.rid = rid          # request id (caller-supplied or minted)
-        self.tl = None          # RequestTimeline, only with telemetry on
+        # the marks its account is made of, telemetry on or off
+        self.tl = RequestTimeline(rid, self.t_submit_ns)
         # tokens the last preemption threw away; while
         # len(generated) <= tokens_lost the sequence is re-earning them
-        # (its episodes are "replay", and live introspection says so)
+        # (its time is "replay", and live introspection says so)
         self.tokens_lost = 0
         # prompt tokens the prefix cache resolved at admission (their
         # K/V was already resident — never recomputed)
@@ -246,13 +281,6 @@ class _DecodeProgram:
         self.t1 = self.last = self.records = None
 
 
-def _stamp_first_token(seq, t_ns):
-    """The TTFT point: the end of the host sync of the prefill that
-    gave token 0 (scheduler thread only). A replay after a preemption
-    does not move it."""
-    if seq.t_first_token_ns is None:
-        seq.t_first_token_ns = seq.future.t_first_token_ns = t_ns
-
 
 def _reachable(ladder, lo, hi):
     """Entries of a bucket ladder that values in [lo, hi] snap to."""
@@ -278,7 +306,10 @@ class ContinuousBatchingEngine:
     a 1-D int32 array of length ``max_new_tokens``; the Future carries
     ``t_submit_ns`` and ``t_first_token_ns`` (``perf_counter_ns``; the
     latter ``None`` until the prefill's host sync ended) and, once
-    done, ``token_records``: for a model whose programs return a record
+    done, ``t_retire_ns``, ``account`` (its latency in ms by phase: ``queue``,
+    ``prefill``, ``stalled`` behind other requests' prefills,
+    ``decode_device``, ``decode_host``, ``replay``; they sum to retire
+    - submit) and ``token_records``: for a model whose programs return a record
     a row (``row_record_width``), int32 ``[max_new_tokens, width]``,
     the record of the row that decided each token (``docs/serving.md``
     says what a model puts there), else ``None``."""
@@ -370,7 +401,21 @@ class ContinuousBatchingEngine:
         # the decode program that is dispatched and not read yet, if any
         # (scheduler thread only; see _decode_once)
         self._flight = None
-        self._decode_read_ns = 0    # end of the last decode host sync
+        # the phase clock (scheduler thread only; see the module
+        # docstring): ns by phase, the phase the thread is in and since
+        # when; the stall overlay's total and, while one is open, its
+        # start; with telemetry on the last stalls (t0, t1, request ids
+        # prefilled) for the retiring requests' episodes
+        self._phase_ns = [0] * len(ENGINE_PHASES)
+        self._phase = _WAIT
+        self._lap_ns = _clock()
+        self._stalled_ns = 0
+        self._stall_t0 = 0
+        self._stalls = collections.deque(maxlen=1024)
+        # the rows that took their first token inside the open stall
+        self._born = []
+        # the last retired requests' accounts (ns, in PHASES' order)
+        self._accounts = collections.deque(maxlen=_ACCOUNT_WINDOW)
         self._ids = itertools.count()
         self._waiting = collections.deque()
         self._running = []
@@ -482,6 +527,14 @@ class ContinuousBatchingEngine:
                "health_reason": reason}
         out["prefix_cache"] = self.prefix_cache
         out["prefill_chunk"] = self.prefill_chunk
+        # closed laps only (at most one leaf span behind), cumulative
+        # since the engine was made: a scraper differences them
+        out["phase_ms"] = dict(
+            zip(ENGINE_PHASES, (ns / 1e6 for ns in self._phase_ns)),
+            stalled=self._stalled_ns / 1e6)
+        # list() first: the scheduler thread appends while this reads
+        out["request_account"] = _lifecycle.summarize_accounts(
+            list(self._accounts))
         for kind, vec in self._model_counters.items():
             out.update(self._named_counters(kind, vec))
         if self.prefix_cache:
@@ -527,9 +580,7 @@ class ContinuousBatchingEngine:
             else mint_request_id()
         seq = _Seq(next(self._ids), prompt, max_new_tokens, temperature,
                    seed, rid)
-        if tel.enabled:
-            seq.tl = RequestTimeline(rid, seq.t_submit_ns)
-            tel.flight_record("serve", "submit", tag=rid)
+        tel.flight_record("serve", "submit", tag=rid)
         with self._cond:
             if self._closed:
                 raise RuntimeError("engine closed")
@@ -537,14 +588,45 @@ class ContinuousBatchingEngine:
                 raise EngineOverloaded(
                     f"waiting queue full ({self.max_queue} requests)")
             self._waiting.append(seq)
-            self._set_depth_locked()
             self._cond.notify()
         return seq.future
 
-    def _set_depth_locked(self):
-        if self.telemetry.enabled:
-            self.telemetry.set_gauge(f"{self.name}_queue_depth",
-                                     len(self._waiting))
+    # ------------------------------------------------------------------
+    # the phase clock
+    def _lap(self, phase):
+        """Close the lap: the time since the last one goes to the phase
+        the thread was in, and it is in ``phase`` from here."""
+        now = _clock()
+        self._phase_ns[self._phase] += now - self._lap_ns
+        self._lap_ns = now
+        self._phase = phase
+
+    def _leaf(self, name, phase, **attrs):
+        """Open the leaf span ``name`` (``with self._leaf(...):``) and
+        put the thread in ``phase``. The span's end closes no lap: what
+        follows it up to the next leaf is the same phase."""
+        self._lap(phase)
+        return self.telemetry.span(name, **attrs)
+
+    def _reading(self):
+        """The clock now, as a request's mark wants it: ``(t_ns,
+        stalled_ns, decode_device_ns)`` with an open stall counted up
+        to now (no mark is taken inside a decode sync)."""
+        now = _clock()
+        stalled = self._stalled_ns
+        if self._stall_t0:
+            stalled += now - self._stall_t0
+        return now, stalled, self._phase_ns[_DECODE_DEVICE]
+
+    def _prefill(self, prefilled, admitted):
+        """One step's prefill and the finish after it (a prefill gives
+        token 0, which may be a request's last; without one nothing was
+        produced since the finish that ended the previous step)."""
+        if self._suffix_mode:
+            self._prefill_suffix_step(prefilled)
+        else:
+            self._prefill_admitted(admitted)
+        self._finish_done()
 
     # ------------------------------------------------------------------
     def step(self):
@@ -558,7 +640,7 @@ class ContinuousBatchingEngine:
         rows."""
         tel = self.telemetry
         t0 = time.perf_counter()
-        with tel.span("serve.admit"), self._cond:
+        with self._leaf("serve.admit", _ADMIT), self._cond:
             admitted = self._admit_locked()
         if not admitted and not self._running:
             return 0
@@ -573,14 +655,34 @@ class ContinuousBatchingEngine:
             if prefilled:
                 # the rows change: what is in flight is read first
                 self._read_flight()
-                if self._suffix_mode:
-                    self._prefill_suffix_step(prefilled)
+                # the rows that would decode now if no prompt had come:
+                # every running row that is not prefilling has tokens
+                # left (one that has none was retired by the finish
+                # that followed its last step)
+                rows = len(self._running) - len(prefilled)
+                if not rows:
+                    self._prefill(prefilled, admitted)
                 else:
-                    self._prefill_admitted(admitted)
-                # a prefill gives token 0, which may be a request's last;
-                # without one nothing was produced since the finish that
-                # ended the previous step
-                self._finish_done()
+                    # the stall: the parent span, the overlay's total
+                    # and, with telemetry on, who was prefilled
+                    with tel.span("serve.stall", rows=rows,
+                                  admitted=len(prefilled)):
+                        self._stall_t0 = s0 = _clock()
+                        try:
+                            self._prefill(prefilled, admitted)
+                        finally:
+                            s1 = _clock()
+                            self._stalled_ns += s1 - s0
+                            self._stall_t0 = 0
+                            # a row is not stalled by the stall its own
+                            # prompt ran in: the rest of it was its own
+                            # sample and finish
+                            for s in self._born:
+                                s.tl.skip_stall(self._stalled_ns)
+                            self._born.clear()
+                            if tel.enabled:
+                                self._stalls.append((s0, s1, tuple(
+                                    s.rid for s in prefilled)))
             if self._running:
                 self._decode_once()
                 self._finish_done()
@@ -639,18 +741,19 @@ class ContinuousBatchingEngine:
                 seq.cached_tokens = 0
                 seq.prefill_pos = 0
             admitted.append(seq)
-        self._set_depth_locked()
         self._running.extend(admitted)
-        if admitted and self.telemetry.enabled:
-            # close each admitted sequence's waiting episode: queue on
-            # first admission, replay-wait after a preemption bounce
-            now = time.perf_counter_ns()
+        if admitted:
+            # the queue ends here; a request that comes back from a
+            # preemption stays in replay unless it had no token to lose
+            reading = self._reading()
             for s in admitted:
-                if s.tl is not None:
-                    s.tl.note("queue" if s.preempts == 0 else "replay",
-                              s.tl.t_wait_start, now)
-                    self.telemetry.flight_record("serve", "admit",
-                                                 tag=s.rid)
+                if not s.tokens_lost:
+                    s.tl.mark(reading, "prefill")
+                    if not s.preempts:
+                        s.tl.cached_tokens = s.cached_tokens
+                    s.tl.computed_tokens += \
+                        s.prompt.shape[0] - s.cached_tokens
+                self.telemetry.flight_record("serve", "admit", tag=s.rid)
         return admitted
 
     # ------------------------------------------------------------------
@@ -820,7 +923,7 @@ class ContinuousBatchingEngine:
     def _prefill_admitted(self, admitted):
         import jax.numpy as jnp
         tel = self.telemetry
-        with tel.span("serve.prefill.build"):
+        with self._leaf("serve.prefill.build", _PREFILL_HOST):
             groups = {}
             for s in admitted:
                 pb = next_bucket(s.prompt.shape[0], self.prompt_buckets)
@@ -834,7 +937,7 @@ class ContinuousBatchingEngine:
             split += [(pb, group[i:i + width])
                       for i in range(0, len(group), width)]
         for pb, group in split:
-            with tel.span("serve.prefill.build"):
+            with self._leaf("serve.prefill.build", _PREFILL_HOST):
                 bb = next_bucket(len(group), self.batch_buckets)
                 ids = np.zeros((bb, pb), np.int32)
                 slots = np.zeros((bb, pb), np.int32)   # 0 = scratch block
@@ -852,9 +955,9 @@ class ContinuousBatchingEngine:
                     rows = jnp.arange(len(group))
                     last_pos = jnp.asarray([s.prompt.shape[0] - 1
                                             for s in group])
-            with tel.span("serve.prefill.device", batch_bucket=bb,
-                          prompt_bucket=pb):
-                t0 = time.perf_counter_ns() if tel.enabled else 0
+            with self._leaf("serve.prefill.device", _PREFILL_HOST,
+                            batch_bucket=bb, prompt_bucket=pb):
+                t0 = _clock() if tel.enabled else 0
                 if last_row:
                     # the program takes each prompt's last row through
                     # the head itself: [bb, V] leaves it
@@ -862,21 +965,16 @@ class ContinuousBatchingEngine:
                         ("prefill", bb, pb), self._prefill_fn,
                         self.params, self.cache.pools, ids, slots,
                         last_pos)
-                    self.cache.pools = pools
-                    last = np.asarray(logits)
                 else:
                     logits, pools = self._dispatch(
                         ("prefill", bb, pb), self._prefill_fn,
                         self.params, self.cache.pools, ids, slots)
-                    self.cache.pools = pools
-                    last = np.asarray(logits[rows, last_pos])
-                    counted = None
-                # the episode ends AFTER the host sync above — the wall
-                # between t0 and t1 is the prefill compute each member
-                # rode, and t1 is its first-token time
-                t1 = time.perf_counter_ns()
-                records = self._count("prefill", counted, t0, t1)
-            with tel.span("serve.prefill.sample"):
+                    logits, counted = logits[rows, last_pos], None
+                self.cache.pools = pools
+                # t1, the end of the host sync, is the first-token time
+                last, first = self._prefill_sync(logits)
+                records = self._count("prefill", counted, t0, first[0])
+            with self._leaf("serve.prefill.sample", _PREFILL_HOST):
                 for i, s in enumerate(group):
                     p = s.prompt.shape[0]
                     tok = _choose_token(last[i], s.temperature, s.seed, 0)
@@ -886,18 +984,40 @@ class ContinuousBatchingEngine:
                     s.pending = tok
                     s.n_written = p
                     s.prefill_pos = p
-                    _stamp_first_token(s, t1)
-                    if s.tl is not None:
-                        s.tl.note(
-                            "replay" if s.replaying() else "prefill",
-                            t0, t1, {"cached_tokens": 0,
-                                     "computed_tokens": p})
+                    self._first_token(s, first)
                 if tel.enabled:
                     real = sum(s.prompt.shape[0] for s in group)
                     tel.inc(f"{self.name}_prefill_tokens", real)
                     tel.inc(f"{self.name}_prefill_pad_tokens",
                             bb * pb - real)
                     tel.inc(f"{self.name}_tokens", len(group))
+
+    def _prefill_sync(self, logits):
+        """The host's wait for a prefill's last rows: ``(rows, the
+        clock's reading when they had come)``."""
+        with self._leaf("serve.prefill.sync", _PREFILL_DEVICE):
+            last = np.asarray(logits)
+        self._lap(_PREFILL_HOST)
+        return last, self._reading()
+
+    def _first_token(self, seq, reading):
+        """Token 0 of ``seq`` exists as of ``reading``, the end of its
+        prefill's host sync: the TTFT point (a replay after a
+        preemption does not move it) and the end of its prefill, or of
+        a replay that had only this token to earn back."""
+        if seq.t_first_token_ns is None:
+            seq.t_first_token_ns = seq.future.t_first_token_ns = reading[0]
+        self._runs_if_caught_up(seq, reading)
+
+    def _runs_if_caught_up(self, seq, reading):
+        """A prefilling request, or a replaying one that holds again as
+        many tokens as its preemption threw away, runs from here."""
+        state = seq.tl.state
+        if state == "prefill" or (state == "replay" and
+                                  len(seq.generated) >= seq.tokens_lost):
+            seq.tl.mark(reading, "run")
+            if self._stall_t0:
+                self._born.append(seq)
 
     def _cow_or_preempt(self, s, start, stop):
         """Copy-on-write the blocks positions ``[start, stop)`` touch
@@ -926,7 +1046,7 @@ class ContinuousBatchingEngine:
         import jax.numpy as jnp
         tel = self.telemetry
         chunk = self.prefill_chunk or self.max_len
-        with tel.span("serve.prefill.build"):
+        with self._leaf("serve.prefill.build", _PREFILL_HOST):
             groups = {}
             for s in prefilling:
                 if s not in self._running:
@@ -940,7 +1060,7 @@ class ContinuousBatchingEngine:
                 cw = next_bucket(w, self.chunk_buckets)
                 groups.setdefault(cw, []).append((s, w))
         for cw, group in sorted(groups.items()):
-            with tel.span("serve.prefill.build"):
+            with self._leaf("serve.prefill.build", _PREFILL_HOST):
                 group = [(s, w) for s, w in group if s in self._running]
                 if not group:
                     continue
@@ -968,9 +1088,10 @@ class ContinuousBatchingEngine:
                 if finishing:
                     rows = jnp.asarray([i for i, _, _ in finishing])
                     last_pos = jnp.asarray([w - 1 for _, _, w in finishing])
-            with tel.span("serve.prefill.device", batch_bucket=bb,
-                          prompt_bucket=cw, ctx_bucket=sb):
-                t0 = time.perf_counter_ns() if tel.enabled else 0
+            with self._leaf("serve.prefill.device", _PREFILL_HOST,
+                            batch_bucket=bb, prompt_bucket=cw,
+                            ctx_bucket=sb):
+                t0 = _clock() if tel.enabled else 0
                 logits, pools = self._dispatch(
                     ("sprefill", bb, cw, sb), self._sprefill_fn,
                     self.params, self.cache.pools, ids, starts, slot_grid,
@@ -979,11 +1100,12 @@ class ContinuousBatchingEngine:
                 counted = None
                 if self.model.counter_names:
                     logits, counted = logits
-                last = np.asarray(logits[rows, last_pos]) \
-                    if finishing else None
-                t1 = time.perf_counter_ns()
+                # a chunk that ends no prompt is not waited for
+                last, first = self._prefill_sync(logits[rows, last_pos]) \
+                    if finishing else (None, self._reading())
+                t1 = first[0]
                 records = self._count("prefill", counted, t0, t1)
-            with tel.span("serve.prefill.sample"):
+            with self._leaf("serve.prefill.sample", _PREFILL_HOST):
                 for j, (i, s, w) in enumerate(finishing):
                     tok = _choose_token(last[j], s.temperature, s.seed, 0)
                     s.generated.append(tok)
@@ -997,19 +1119,13 @@ class ContinuousBatchingEngine:
                         cached_resolved += s.cached_tokens
                     s.prefill_pos += w
                     s.n_written = s.prefill_pos
-                    if s.tl is not None:
-                        s.tl.note(
-                            "replay" if s.replaying() else "prefill",
-                            t0, t1,
-                            {"cached_tokens": s.cached_tokens
-                             if first_chunk else 0, "computed_tokens": w})
                     if not s.prefilling():
                         # prompt fully resident: publish it for later
                         # hits (the cache freezes these blocks; the
                         # first decode write past the tail
                         # copy-on-writes)
                         self.cache.insert_prefix(s.id, s.prompt)
-                        _stamp_first_token(s, t1)
+                        self._first_token(s, first)
                 if tel.enabled:
                     computed = sum(w for _, w in group)
                     tel.complete("serve_prefill_chunk", t0, t1,
@@ -1058,17 +1174,14 @@ class ContinuousBatchingEngine:
         with self._cond:
             self._running.remove(victim)
             self._waiting.appendleft(victim)
-            self._set_depth_locked()
+        # from here until it holds ``lost`` tokens again it replays
+        victim.tl.mark(self._reading(), "replay")
         if self.telemetry.enabled:
             self.telemetry.inc(f"{self.name}_preemptions")
             self.telemetry.instant("serve_preempt",
                                    request_id=victim.rid, tokens=lost)
             self.telemetry.flight_record("serve", "preempt",
                                          tag=victim.rid)
-            if victim.tl is not None:
-                # the replay-wait episode starts now and closes at
-                # re-admission (_admit_locked)
-                victim.tl.t_wait_start = time.perf_counter_ns()
 
     def _decode_once(self):
         """Dispatch one decode step for every active sequence and read
@@ -1087,7 +1200,7 @@ class ContinuousBatchingEngine:
         host's draw see what the synchronous loop showed them: the
         programs run, their order and their results are the same."""
         tel = self.telemetry
-        with tel.span("serve.decode.build"):
+        with self._leaf("serve.decode.build", _DECODE_HOST):
             active = [s for s in self._running
                       if len(s.generated) + s.unread < s.max_new
                       and not s.prefilling()]
@@ -1130,9 +1243,9 @@ class ContinuousBatchingEngine:
         if flight is not None and not ahead:
             self._read(flight)
         attrs = {"width": n, "batch_bucket": bb, "ctx_bucket": cb}
-        with tel.span("serve.decode.ahead" if ahead
-                      else "serve.decode.device", **attrs):
-            t0 = time.perf_counter_ns() if tel.enabled else 0
+        with self._leaf("serve.decode.ahead" if ahead
+                        else "serve.decode.device", _DECODE_HOST, **attrs):
+            t0 = _clock() if tel.enabled else 0
             if not ahead:
                 tokens = np.zeros(bb, np.int32)
                 tokens[:n] = [s.pending for s in active]
@@ -1182,6 +1295,7 @@ class ContinuousBatchingEngine:
         (the model's counters and row records behind them, where it has
         any), or ``[bb, V]`` float32 logits on the sampled route."""
         out, counted = step.out, None
+        self._lap(_DECODE_DEVICE)       # the host blocks on the result
         if self.model.counter_names:
             if step.device_pick:
                 out = np.asarray(out)
@@ -1189,26 +1303,24 @@ class ContinuousBatchingEngine:
             else:
                 out, counted = out
         step.last = np.asarray(out)
+        self._lap(_DECODE_HOST)
         step.out = None
-        step.t1 = time.perf_counter_ns() if self.telemetry.enabled else 0
+        step.t1 = self._lap_ns
         step.records = self._count("decode", counted, step.t0, step.t1)
 
     def _read(self, step):
         """The host's half of a dispatched decode program: the sync
         (unless :meth:`_decode_once` made it inside the dispatch's
-        span), then each row's token, record and timeline."""
+        span), then each row's token and record."""
         tel = self.telemetry
         if step.last is None:
-            with tel.span("serve.decode.device", **step.attrs):
+            with self._leaf("serve.decode.device", _DECODE_HOST,
+                            **step.attrs):
                 self._sync(step)
-        with tel.span("serve.decode.sample"):
+        with self._leaf("serve.decode.sample", _DECODE_HOST):
             last = step.last
             if step.device_pick:
                 last = last.tolist()        # Python ints, in one call
-            # a request's episodes tile: a program dispatched ahead
-            # starts its rows' episode where the one before was read
-            t0 = max(step.t0, self._decode_read_ns)
-            self._decode_read_ns = step.t1
             for i, s in enumerate(step.rows):
                 s.unread -= 1
                 tok = last[i] if step.device_pick else _choose_token(
@@ -1217,11 +1329,8 @@ class ContinuousBatchingEngine:
                 s.pending = tok
                 if step.records is not None:
                     s.records.append(step.records[i])
-                if s.tl is not None:
-                    # a preempted sequence re-earning lost tokens is in
-                    # "replay", not "decode" — the doctor's replay bucket
-                    s.tl.note("replay" if s.replaying() else "decode",
-                              t0, step.t1)
+                if s.tokens_lost:       # preempted once: replaying?
+                    self._runs_if_caught_up(s, self._reading())
             if tel.enabled:
                 tel.inc(f"{self.name}_tokens", len(step.rows))
 
@@ -1234,7 +1343,7 @@ class ContinuousBatchingEngine:
 
     def _finish_done(self):
         tel = self.telemetry
-        with tel.span("serve.finish"):
+        with self._leaf("serve.finish", _DECODE_HOST):
             with self._cond:
                 done = [s for s in self._running
                         if len(s.generated) >= s.max_new]
@@ -1242,14 +1351,23 @@ class ContinuousBatchingEngine:
                     self._running.remove(s)
             for s in done:
                 self.cache.free_seq(s.id)
-                t_retire = time.perf_counter_ns()
+                reading = self._reading()
+                t_retire = reading[0]
+                if self._born and s in self._born:
+                    s.tl.skip_stall(reading[1])     # retires in it
+                account = s.tl.account_ns(reading)
+                self._accounts.append(tuple(account.values()))
+                s.future.t_retire_ns = t_retire
+                s.future.account = {p: ns / 1e6
+                                    for p, ns in account.items()}
                 ms = (t_retire - s.t_submit_ns) / 1e6
                 ttft_ms = None
                 if s.t_first_token_ns is not None:
                     ttft_ms = (s.t_first_token_ns - s.t_submit_ns) / 1e6
-                if s.tl is not None:
-                    _lifecycle.emit_request(tel, s.tl, t_retire,
-                                            len(s.generated), s.preempts)
+                if tel.enabled:
+                    _lifecycle.emit_request(
+                        tel, s.tl, t_retire, len(s.generated), s.preempts,
+                        s.future.account, self._stalls)
                     tel.flight_record("serve", "retire", tag=s.rid)
                     if ttft_ms is not None:
                         tel.observe("serve_ttft_ms", ttft_ms)
@@ -1258,13 +1376,9 @@ class ContinuousBatchingEngine:
                             (t_retire - s.t_first_token_ns) / 1e6
                             / max(1, len(s.generated) - 1))
                     tel.observe("serve_queue_wait_ms",
-                                sum(t1 - t0
-                                    for ph, t0, t1, _ in s.tl.episodes
-                                    if ph == "queue") / 1e6)
+                                s.future.account["queue"])
                     tel.observe("serve_preempts", s.preempts)
                 self.slo.note(True, ms, ttft_ms=ttft_ms)
-                if tel.enabled:
-                    tel.observe(f"{self.name}_latency_ms", ms)
                 s.future.token_records = np.stack(
                     s.records[:s.max_new]) if s.records else None
                 s.future.set_result(
@@ -1277,8 +1391,11 @@ class ContinuousBatchingEngine:
                 with self._cond:
                     while not self._closed and not self._waiting \
                             and not self._running:
-                        with self.telemetry.span("serve.wait"):
-                            self._cond.wait()
+                        # in slices, a span each: a profile that starts
+                        # inside a wait sees its next slice, and the
+                        # clock's ``wait`` is never far behind
+                        with self._leaf("serve.wait", _WAIT):
+                            self._cond.wait(_WAIT_SLICE_S)
                     if self._closed and not self._waiting \
                             and not self._running:
                         return
@@ -1289,6 +1406,8 @@ class ContinuousBatchingEngine:
             self._fail_outstanding(
                 RuntimeError(f"engine scheduler died: {e!r}"))
             raise
+        finally:
+            self._lap(_WAIT)    # the clock ends with the thread
         # closed with work outstanding: fail it rather than hang callers
         self._fail_outstanding(RuntimeError("engine closed"))
 

@@ -121,20 +121,29 @@ SPAN_SCHEMA = {
                     "layer": _opt(_STR), "table": _opt(_STR),
                     "value": _opt(_NUM), "limit": _opt(_NUM)},
     # serving request lifecycle (serving/lifecycle.py + scheduler.py):
-    # one serve_request span per retired request (submit -> retire), one
-    # serve_phase span per recorded episode (queue / prefill / decode /
+    # one serve_request span per retired request (submit -> retire) with
+    # its account (ms by phase; they sum to the span), one serve_phase
+    # span per contiguous episode (queue / prefill / decode / stalled /
     # replay), one serve_preempt instant per preemption. request_id is
     # the end-to-end tracing id minted at ingress; the serving doctor
     # keys its per-request conservation check on these — typed strictly,
     # no open payload.
     "serve_request": {"request_id": _req(_STR), "tokens": _req(_INT),
-                      "preempts": _req(_INT), "phase": _opt(_STR)},
-    # prefill episodes under the prefix cache split prompt tokens into
+                      "preempts": _req(_INT), "phase": _opt(_STR),
+                      "queue_ms": _opt(_NUM), "prefill_ms": _opt(_NUM),
+                      "stalled_ms": _opt(_NUM),
+                      "decode_device_ms": _opt(_NUM),
+                      "decode_host_ms": _opt(_NUM),
+                      "replay_ms": _opt(_NUM)},
+    # the first prefill episode splits the prompt's tokens into
     # cache-resolved vs chip-computed (admission charged only the
-    # latter) — the doctor's cache-efficacy attribution keys on these
+    # latter) — the doctor's cache-efficacy attribution keys on these; a
+    # stalled episode names the requests whose prompts ran (their ids,
+    # comma-joined)
     "serve_phase": {"request_id": _req(_STR), "phase": _req(_STR),
                     "tokens": _opt(_INT), "cached_tokens": _opt(_INT),
-                    "computed_tokens": _opt(_INT)},
+                    "computed_tokens": _opt(_INT),
+                    "blocked_by": _opt(_STR)},
     "serve_preempt": {"request_id": _req(_STR), "tokens": _opt(_INT)},
     # one span per chunked/suffix prefill dispatch (scheduler.py
     # _prefill_suffix_step): seqs in the group, computed (real, unpadded)
@@ -147,6 +156,12 @@ SPAN_SCHEMA = {
     # host sync of the rows the scheduler reads
     "serve.wait": {}, "serve.admit": {}, "serve.finish": {},
     "serve.prefill.build": {}, "serve.prefill.sample": {},
+    # the host's wait for a prefill's rows, inside serve.prefill.device
+    "serve.prefill.sync": {},
+    # the parent of a prefill (through the finish after it) that
+    # ``rows`` decode-ready rows wait behind; ``admitted`` = the
+    # sequences whose prompts run
+    "serve.stall": {"rows": _req(_INT), "admitted": _req(_INT)},
     "serve.prefill.device": {"batch_bucket": _req(_INT),
                              "prompt_bucket": _req(_INT),
                              "ctx_bucket": _opt(_INT)},

@@ -60,7 +60,8 @@ switches the unit of attribution from the training step to the served
 **request**: each retired request's ``serve_request``/``serve_phase``
 spans (serving/lifecycle.py) are rebuilt into a timeline and its
 end-to-end latency attributed into disjoint queue / prefill / decode /
-replay / overhead buckets (conservation checked per request), with
+stalled / replay buckets (``overhead``, what they leave, is ~0;
+conservation checked per request), with
 TTFT/TPOT/queue-wait percentiles, preemption stats, and a top-bucket
 diagnosis citing the serving knobs (``num_blocks``,
 ``max_batch_size``, ``reserve``, ``prompt_buckets``, replicas).
@@ -337,15 +338,20 @@ def attribute_trace(path, tolerance=0.10):
 # The step attribution above answers "why is a training step slow"; the
 # serving plane's unit of latency is the request. ``--serving`` rebuilds
 # each retired request's lifecycle from its ``serve_request`` (submit ->
-# retire) and ``serve_phase`` (queue / prefill / decode / replay
-# episodes) spans and attributes the end-to-end latency into disjoint
-# buckets with the same conservation discipline: the engine records the
-# episodes sequentially on one scheduler thread, ``overhead`` is the
-# exact residual, and the check guards the arithmetic (an episode
-# leaking past retire, or overlapping episodes summing past e2e, fails
-# the request rather than silently misattributing it).
+# retire) and ``serve_phase`` spans — one a contiguous episode: queue,
+# prefill, a run of decode steps, a stall behind other requests'
+# prefills, a replay — and sums the end-to-end latency into disjoint
+# buckets. The engine's episodes TILE the request (they are cuts of one
+# clock, serving/lifecycle.py), so ``overhead``, what the episodes leave
+# of the latency, is ~0 and the conservation check is two-sided: an
+# episode leaking past retire, episodes summing past e2e, or a hole
+# between them fails the request rather than silently misattributing
+# it. ``decode`` is the sum of the engine's ``decode_device`` (the host
+# blocked on a decode program) and ``decode_host``; the split is in the
+# ``serve_request`` span's ``*_ms`` args and summed as ``decode_split_ms``.
 
-SERVE_BUCKETS = ("queue", "prefill", "decode", "replay", "overhead")
+SERVE_BUCKETS = ("queue", "prefill", "decode", "stalled", "replay",
+                 "overhead")
 
 
 def _pctl(vals, q):
@@ -361,10 +367,10 @@ def _pctl(vals, q):
 
 
 def _account_request(r, tolerance, slack_us=2.0):
-    """One parsed request -> accounted dict (all times ms). Buckets sum
-    to e2e by construction (overhead is the residual); ``conserved``
-    demands the residual is non-negative within tolerance AND every
-    episode lies inside the [submit, retire] window."""
+    """One parsed request -> accounted dict (all times ms).
+    ``overhead`` is what the episodes leave of e2e; ``conserved``
+    demands that it is zero within tolerance, either way, AND that
+    every episode lies inside the [submit, retire] window."""
     t0, e2e_us = r["t0"], r["e2e"]
     t1 = t0 + e2e_us
     buckets = {b: 0.0 for b in SERVE_BUCKETS}
@@ -397,10 +403,9 @@ def _account_request(r, tolerance, slack_us=2.0):
             continue
         if prefill_end is None or t > prefill_end:
             prefill_end = t
-    claimed = sum(v for b, v in buckets.items() if b != "overhead")
-    residual = e2e_us - claimed
+    residual = e2e_us - sum(buckets.values())
     conserved = in_window and \
-        residual >= -(tolerance * max(e2e_us, 1.0) + slack_us)
+        abs(residual) <= tolerance * max(e2e_us, 1.0) + slack_us
     buckets["overhead"] = max(0.0, residual)
     # a complete timeline saw the request wait (queue) and prefill and
     # produce at least one token — anything less means a recording site
@@ -424,6 +429,7 @@ def _account_request(r, tolerance, slack_us=2.0):
         "queue_ms": round(buckets["queue"] / 1000.0, 3),
         "cached_tokens": cached_tokens,
         "computed_tokens": computed_tokens,
+        "decode_split_ms": r["decode_split_ms"],
         "complete": bool(complete),
         "conserved": bool(conserved),
     }
@@ -444,13 +450,17 @@ def parse_request_events(events, tolerance=0.05):
             continue
         r = reqs.setdefault(rid, {"request_id": rid, "episodes": [],
                                   "e2e": None, "t0": None, "tokens": 0,
-                                  "preempts": 0})
+                                  "preempts": 0, "decode_split_ms": {}})
         if name == "serve_request":
             r["t0"] = e["ts"]
             r["e2e"] = e["dur"]
             try:
                 r["tokens"] = int(args.get("tokens", 0))
                 r["preempts"] = int(args.get("preempts", 0))
+                r["decode_split_ms"] = {
+                    k: float(args[k + "_ms"])
+                    for k in ("decode_device", "decode_host")
+                    if k + "_ms" in args}
             except (TypeError, ValueError):
                 pass
         else:
@@ -476,11 +486,17 @@ _SERVE_REMEDY = {
                "engine_prefill_tokens for prompt-bucket padding",
     "decode": "decode-compute bound: the device is the limit — raise "
               "max_batch_size for step occupancy, or scale replicas",
+    "stalled": "running rows stand still while other requests' "
+               "prompts prefill on the same thread: prefill_chunk=N "
+               "cuts each stall to one chunk (the same share, shorter "
+               "gaps between a caller's tokens); a faster prefill "
+               "program or a replica for long prompts cuts the share",
     "replay": "preemption replay recomputes lost tokens: "
               "reserve='full' removes mid-decode preemption, or raise "
               "num_blocks so lazy growth stops evicting",
-    "overhead": "host scheduler overhead between dispatches: raise "
-                "max_batch_size so each step carries more sequences",
+    "overhead": "time no episode covers: the engine's episodes tile a "
+                "request, so a recording site was skipped or the ring "
+                "dropped spans (raise Telemetry trace_capacity)",
 }
 
 
@@ -529,6 +545,12 @@ def summarize_requests(reqs, tolerance=0.05):
         "prefill_computed_tokens": sum(r["computed_tokens"]
                                        for r in reqs),
         "replay_fraction": round(totals["replay"] / e2e_total, 4),
+        # the engine's own split of the decode bucket, where its
+        # serve_request spans carry it: blocked on the chip / host work
+        "decode_split_ms": {
+            k: round(sum(r["decode_split_ms"].get(k, 0.0)
+                         for r in reqs), 3)
+            for k in ("decode_device", "decode_host")},
         "top_bucket": {
             "bucket": top[0],
             "ms": round(top[1], 3),
@@ -572,6 +594,11 @@ def render_serving_text(diag):
     lines.append(f"  conservation: buckets sum to each request's e2e "
                  f"for {diag['requests'] - len(diag['violations'])}"
                  f"/{diag['requests']} requests [{check}]")
+    split = diag["decode_split_ms"]
+    if any(split.values()):
+        lines.append(f"  decode = {split['decode_device']:.1f} ms blocked "
+                     f"on the chip + {split['decode_host']:.1f} ms of "
+                     f"host work")
     if diag["violations"]:
         lines.append(f"  violating: {', '.join(diag['violations'][:5])}")
     if not diag["complete"]:

@@ -261,7 +261,7 @@ def test_counter_span_and_episodes(gpt, counted):
     tel = telemetry.Telemetry(enabled=True)
     engine = _gpt_engine(gpt, num_blocks=40, telemetry=tel, name="eng")
     requests = [(p, n) for p, n in zip(_prompts(), (6, 9, 4, 7))]
-    _serve(engine, requests)
+    futures = _serve(engine, requests)
     ahead = engine.decode_ahead_steps
     assert 0 < ahead < engine.decode_steps
     assert tel.counter_value("eng_decode_ahead_steps") == ahead
@@ -271,22 +271,46 @@ def test_counter_span_and_episodes(gpt, counted):
     assert counted.count("hetu.serve.decode.ahead") == ahead
     assert counted.count("hetu.serve.decode.sample") == engine.decode_steps
     assert counted.count("hetu.serve.decode.build") == engine.decode_steps
-    # a request's episodes tile its life: none starts before the one
-    # before it ended, though its programs overlap
+    # a request's episodes tile its life though its programs overlap:
+    # each starts where the one before ended, the first at its submit
+    # and the last at its retirement, and a run of decode steps is ONE
+    # episode, cut only where another request's prompt stalled it
     events = tel.tracer.drain()
     diag = attribute_request_events(events)
     assert diag["requests"] == len(requests)
     assert diag["conserved"] and diag["complete"]
-    episodes = {}
+    assert diag["buckets_ms"]["overhead"] < 0.01 * diag["e2e_total_ms"]
+    episodes, whole = {}, {}
     for e in events:
         if e["ph"] == "X" and e["name"] == "serve_phase":
             episodes.setdefault(e["args"]["request_id"], []).append(
                 (e["ts"], e["ts"] + e["dur"], e["args"]["phase"]))
+        elif e["ph"] == "X" and e["name"] == "serve_request":
+            whole[e["args"]["request_id"]] = (e["ts"], e["ts"] + e["dur"])
     assert len(episodes) == len(requests)
-    for spans, (_, n) in zip(episodes.values(), requests):
+    stalls = counted.count("hetu.serve.stall")
+    assert stalls == len(requests) - 1      # each but the first met rows
+    for rid, spans in episodes.items():
         spans.sort()
-        assert [ph for _, _, ph in spans].count("decode") == n - 1
-        for (_, end, _), (start, _, _) in zip(spans, spans[1:]):
+        phases = [ph for _, _, ph in spans]
+        assert phases[:2] == ["queue", "prefill"]
+        assert set(phases[2:]) <= {"decode", "stalled"}
+        # at most one decode run more than stalls, never one a step
+        assert phases.count("decode") <= phases.count("stalled") + 1
+        assert phases.count("stalled") <= stalls
+        edges = [whole[rid][0]] + [t for s, e, _ in spans
+                                   for t in (s, e)] + [whole[rid][1]]
+        for end, start in zip(edges[::2], edges[1::2]):
             # microseconds near 2e15: a float holds them to 0.25
-            assert start >= end - 1.0, spans
+            assert abs(start - end) <= 1.0, spans
+    # steps dispatched ahead are counted once: decode_device,
+    # decode_host and stalled tile what follows a request's first token
+    # (the span's end is the retirement, to a float's 0.25 us)
+    for f, rid in zip(futures, episodes):
+        a = f.account
+        after = a["decode_device"] + a["decode_host"] + a["stalled"]
+        retire_us = whole[rid][1] - whole[rid][0] + f.t_submit_ns / 1e3
+        assert after * 1e3 == pytest.approx(
+            retire_us - f.t_first_token_ns / 1e3, abs=2.0)
+        assert a["decode_device"] > 0 and a["replay"] == 0
     engine.close()

@@ -146,6 +146,7 @@ def test_step_by_hand_emits_the_same_spans(counted):
     assert [n for n in counted if n != "hetu.jit_compile"] == [
         "hetu.serve.admit", "hetu.step", "hetu.serve.prefill.build",
         "hetu.serve.prefill.build", "hetu.serve.prefill.device",
+        "hetu.serve.prefill.sync",      # the wait inside .device
         "hetu.serve.prefill.sample", "hetu.serve.finish",
         "hetu.serve.decode.build", "hetu.serve.decode.device",
         "hetu.serve.decode.sample", "hetu.serve.finish"]

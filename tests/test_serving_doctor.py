@@ -31,8 +31,9 @@ def _span(name, ts, dur, **args):
 
 
 def _request_spans(rid, t0, episodes, tokens=5, preempts=0):
-    """serve_phase spans for (phase, start, end) triples (µs) plus the
-    enclosing serve_request; retire = last episode end + overhead."""
+    """serve_phase spans for (phase, start, end) triples (µs): one a
+    contiguous episode, as the engine exports them (a run of decode
+    steps is ONE episode, cut only by a stall or a replay)."""
     evs = [_span("serve_phase", s, t - s, request_id=rid, phase=ph)
            for ph, s, t in episodes]
     return evs
@@ -43,31 +44,37 @@ def _request_spans(rid, t0, episodes, tokens=5, preempts=0):
 # ---------------------------------------------------------------------------
 
 def test_attribution_math_exact():
-    """Known episode durations -> exact buckets; overhead is the exact
-    residual; TTFT is the last prefill end before decode starts (the
-    only prefill end here); conservation holds."""
+    """Known episode durations -> exact buckets; the episodes tile the
+    request, so overhead (what they leave of e2e) is zero; a stall
+    between two decode runs is its own bucket; TTFT is the last prefill
+    end before decode starts (the only prefill end here); the engine's
+    split of decode rides on the serve_request span."""
     rid = "synth-1"
     evs = _request_spans(rid, 1000, [
         ("queue", 1000, 3000),          # 2 ms
         ("prefill", 3000, 5000),        # 2 ms (TTFT point: 5000)
-        ("decode", 5000, 6000),
-        ("decode", 6500, 7500),
-        ("decode", 8000, 9000),         # 3 ms total decode
+        ("decode", 5000, 6500),
+        ("stalled", 6500, 8000),        # another request's prompt ran
+        ("decode", 8000, 11000),        # 4.5 ms total decode
     ])
     evs.append(_span("serve_request", 1000, 10000, request_id=rid,
-                     phase="retired", tokens=5, preempts=0))
+                     phase="retired", tokens=5, preempts=0,
+                     decode_device_ms=3.5, decode_host_ms=1.0))
     (r,) = parse_request_events(evs)
     assert r["conserved"] and r["complete"]
     assert r["e2e_ms"] == 10.0
     assert r["buckets_ms"] == {"queue": 2.0, "prefill": 2.0,
-                               "decode": 3.0, "replay": 0.0,
-                               "overhead": 3.0}
+                               "decode": 4.5, "stalled": 1.5,
+                               "replay": 0.0, "overhead": 0.0}
     assert sum(r["buckets_ms"].values()) == r["e2e_ms"]
+    assert r["decode_split_ms"] == {"decode_device": 3.5,
+                                    "decode_host": 1.0}
     assert r["ttft_ms"] == 4.0          # 5000 - 1000
     # TPOT: (retire - first token) / (tokens - 1) = 6ms / 4
     assert r["tpot_ms"] == 1.5
     diag = summarize_requests([r])
     assert diag["requests"] == 1 and diag["conserved"]
+    assert diag["decode_split_ms"]["decode_device"] == 3.5
     assert diag["top_bucket"]["bucket"] in SERVE_BUCKETS
     assert diag["top_bucket"]["remedy"]
     text = render_serving_text(diag)
@@ -82,7 +89,7 @@ def test_replay_bucket_and_preempt_stats():
         ("prefill", 1000, 2000),
         ("decode", 2000, 3000),
         ("replay", 3000, 7000),         # preempted: wait + re-earn
-        ("decode", 7000, 8000),
+        ("decode", 7000, 9000),
     ])
     evs.append(_span("serve_request", 0, 9000, request_id=rid,
                      phase="retired", tokens=4, preempts=1))
@@ -94,18 +101,20 @@ def test_replay_bucket_and_preempt_stats():
 
 
 def test_chunked_prefill_ttft_is_final_chunk_end():
-    """Under chunked prefill a prompt spans SEVERAL prefill episodes;
-    the first token only exists once the final chunk lands, so TTFT is
-    the LAST prefill end preceding the first decode start — the
-    first-episode end would fake a 3x-better TTFT here."""
+    """Under chunked prefill the engine exports ONE prefill episode,
+    admission to the final chunk's host sync (the others' decode steps
+    between the chunks are inside it); a trace cut into several prefill
+    episodes (a request preempted before its first token) reads the
+    same: the first token only exists once the final chunk lands, so
+    TTFT is the LAST prefill end preceding the first decode start —
+    the first-episode end would fake a 3x-better TTFT here."""
     rid = "synth-chunk"
     evs = _request_spans(rid, 0, [
         ("queue", 0, 1000),
-        ("prefill", 1000, 2000),        # chunk 1
-        ("prefill", 2500, 3500),        # chunk 2 (decode of others ran
-        ("prefill", 4000, 6000),        # chunk 3  in the 500µs gaps)
-        ("decode", 6000, 7000),
-        ("decode", 7000, 8000),
+        ("prefill", 1000, 2000),        # preempted before token 0
+        ("replay", 2000, 3000),         # back in the queue
+        ("prefill", 3000, 6000),        # every chunk of the second try
+        ("decode", 6000, 9000),
     ])
     evs.append(_span("serve_request", 0, 9000, request_id=rid,
                      phase="retired", tokens=3, preempts=0))
@@ -114,6 +123,7 @@ def test_chunked_prefill_ttft_is_final_chunk_end():
     assert r["ttft_ms"] == 6.0, \
         "TTFT must be the FINAL chunk's end, not the first's"
     assert r["buckets_ms"]["prefill"] == 4.0
+    assert r["buckets_ms"]["overhead"] == 0.0
     # TPOT spans first token -> retire over tokens-1
     assert r["tpot_ms"] == pytest.approx(3.0 / 2)
 
@@ -129,7 +139,7 @@ def test_prefill_cached_vs_computed_attribution():
         evs.append(_span("serve_phase", 500, 1000, request_id=rid,
                          phase="prefill", cached_tokens=cached,
                          computed_tokens=computed))
-        evs.append(_span("serve_phase", 1500, 500, request_id=rid,
+        evs.append(_span("serve_phase", 1500, 1000, request_id=rid,
                          phase="decode"))
         evs.append(_span("serve_request", 0, 2500, request_id=rid,
                          phase="retired", tokens=2, preempts=0))
@@ -163,6 +173,15 @@ def test_overclaim_fails_conservation():
     assert not diag["conserved"]
     assert diag["violations"] == [rid]
     assert "FAILED" in render_serving_text(diag)
+    # and so does a hole: the engine's episodes tile the request, so
+    # 3 of 10 ms that no episode covers is a skipped recording site
+    evs = _request_spans(rid, 0, [
+        ("queue", 0, 2000), ("prefill", 2000, 4000),
+        ("decode", 4000, 5000), ("decode", 8000, 10000)])
+    evs.append(_span("serve_request", 0, 10000, request_id=rid,
+                     phase="retired", tokens=3, preempts=0))
+    (r,) = parse_request_events(evs)
+    assert not r["conserved"] and r["buckets_ms"]["overhead"] == 3.0
 
 
 def test_out_of_window_episode_fails_conservation():
@@ -184,7 +203,7 @@ def test_incomplete_timeline_detected():
     rid = "synth-inc"
     evs = _request_spans(rid, 0, [
         ("prefill", 0, 2000),
-        ("decode", 2000, 3000),
+        ("decode", 2000, 4000),
     ])
     evs.append(_span("serve_request", 0, 4000, request_id=rid,
                      phase="retired", tokens=2, preempts=0))
@@ -212,7 +231,9 @@ def test_serve_span_fixtures_validate(tmp_path):
     evs = [
         _span("serve_phase", 0, 100, request_id="r1", phase="queue"),
         _span("serve_request", 0, 200, request_id="r1", phase="retired",
-              tokens=4, preempts=1),
+              tokens=4, preempts=1, queue_ms=0.1, prefill_ms=0.04,
+              stalled_ms=0.01, decode_device_ms=0.03, decode_host_ms=0.02,
+              replay_ms=0),
         _span("serve_preempt", 50, 0, request_id="r1", tokens=3),
         # chunked-prefill dispatch span + prefill episode carrying the
         # cached/computed token split
@@ -220,11 +241,17 @@ def test_serve_span_fixtures_validate(tmp_path):
               bucket=8, cached=9),
         _span("serve_phase", 100, 400, request_id="r1", phase="prefill",
               cached_tokens=9, computed_tokens=5),
+        # a stall behind two other requests' prompts: the episode, the
+        # engine's parent span, the host's wait inside the prefill
+        _span("serve_phase", 140, 10, request_id="r1", phase="stalled",
+              blocked_by="r2,r3"),
+        _span("serve.stall", 140, 10, rows=1, admitted=2),
+        _span("serve.prefill.sync", 141, 5),
     ]
     p = tmp_path / "trace_rank0.json"
     p.write_text(json.dumps({"traceEvents": evs}))
     n, errors = validate(str(p))
-    assert n == 5 and errors == [], errors
+    assert n == 8 and errors == [], errors
 
 
 def test_serve_span_schema_rejects_drift():
@@ -261,6 +288,13 @@ def test_serve_span_schema_rejects_drift():
                                       "phase": "prefill",
                                       "cached_tokens": "lots"})
     assert any("cached_tokens" in e and "type" in e for e in errs)
+    # the account's args are numbers; a stall names its rows as ints
+    errs = check_args("serve_request", {"request_id": "r", "tokens": 1,
+                                        "preempts": 0,
+                                        "stalled_ms": "long"})
+    assert any("stalled_ms" in e and "type" in e for e in errs)
+    errs = check_args("serve.stall", {"rows": 1})
+    assert any("admitted" in e and "missing" in e for e in errs)
 
 
 # ---------------------------------------------------------------------------

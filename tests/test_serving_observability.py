@@ -23,6 +23,7 @@ from hetu_tpu.serving import (ContinuousBatchingEngine, EngineOverloaded,
                               KVCacheExhausted, MicroBatcher,
                               ReplicaRouter, RouterOverloaded,
                               ServingHTTPServer, SLOWindow)
+from hetu_tpu.serving.lifecycle import PHASES
 from hetu_tpu.telemetry.doctor import attribute_request_events
 
 from gpt_reference import VOCAB, gpt_session
@@ -129,6 +130,17 @@ def test_preemption_becomes_replay_episodes():
     victim = next(r for r in diag["slowest_requests"]
                   if r["preempts"] > 0)
     assert victim["buckets_ms"]["replay"] > 0
+    # ONE replay episode a preemption (its wait, its prefill and the
+    # steps that earn its tokens back merged), and the Future's account
+    # says the same: what was lost and re-done is replay, not decode
+    replays = [e for e in events if e["name"] == "serve_phase"
+               and e["args"]["phase"] == "replay"
+               and e["args"]["request_id"] == victim["request_id"]]
+    assert len(replays) == victim["preempts"]
+    lost = [f.account for f in futs if f.account["replay"] > 0]
+    assert len(lost) == diag["preempted_requests"]
+    assert sum(a["replay"] for a in lost) == pytest.approx(
+        diag["buckets_ms"]["replay"], abs=0.01)
 
 
 # ---------------------------------------------------------------------------
@@ -360,15 +372,21 @@ def test_http_requests_and_stats_routes():
 # ---------------------------------------------------------------------------
 
 def test_disabled_engine_allocates_no_timelines():
+    """The always-on account is a few marks a request, however many
+    tokens it decodes, and nothing a step."""
     cfg, sess = gpt_session(seed=4)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
         start=False)
     assert not eng.telemetry.enabled
-    fut = eng.submit(np.arange(4), 2)
-    assert eng._waiting[0].tl is None       # no timeline object built
-    assert eng._waiting[0].rid              # the id still exists
+    fut = eng.submit(np.arange(4), 20)
+    seq = eng._waiting[0]
+    assert seq.rid                          # the id exists
+    assert len(seq.tl.marks) == 1           # submitted: in the queue
     _drive(eng, [fut])
+    # queue -> prefill -> run: three marks for twenty tokens
+    assert [m[3] for m in seq.tl.marks] == ["queue", "prefill", "run"]
+    assert set(fut.account) == set(PHASES)
 
     # idle step() (the hot steady-state poll) is allocation-free; the
     # first few thousand iterations grow interpreter freelists once, so
@@ -378,12 +396,17 @@ def test_disabled_engine_allocates_no_timelines():
     gc.collect()
     gc.disable()
     try:
-        before = sys.getallocatedblocks()
-        for _ in range(5000):
-            eng.step()
-        after = sys.getallocatedblocks()
+        grown = []
+        # two rounds: the first also pays what the measurement itself
+        # allocates once (11 blocks with the phase clock's ints, 8
+        # before it); a leak a step would show in both
+        for _ in range(2):
+            before = sys.getallocatedblocks()
+            for _ in range(5000):
+                eng.step()
+            grown.append(sys.getallocatedblocks() - before)
     finally:
         gc.enable()
-    assert after - before <= 8, \
-        f"disabled idle step leaked {after - before} blocks over 5000"
+    assert grown[0] <= 16 and grown[1] <= 8, \
+        f"disabled idle step leaked {grown} blocks over 2 x 5000"
     eng.close()
