@@ -187,8 +187,9 @@ def _flops(node, shapes):
     family (4*B*H*S^2*D — QK^T and PV)."""
     if "Attention" in node.op_type:
         q = shapes.get(node.inputs[0]) if node.inputs else None
-        if q and len(q) == 4:
-            b, h, s, d = (int(x) for x in q)
+        dims = node.attention_shape(q) if q else None
+        if dims:
+            b, h, s, d = dims
             return 4.0 * b * h * s * s * d
     from ..parallel.autoplan import flops_of
     return flops_of(node, shapes)
@@ -851,10 +852,16 @@ def _autotune_pass(topo, shapes, dtypes, db, steps, add):
         if not isinstance(node, FlashAttentionOp):
             continue            # grad ops share the forward's key
         q = shapes.get(node.inputs[0]) if node.inputs else None
-        if not q or len(q) != 4:
+        dims = node.attention_shape(q) if q else None
+        if not dims:
             continue
-        b, h, s, d = (int(x) for x in q)
+        b, h, s, d = dims
+        from ..ops.attention import flash_layout
         from ..ops.pallas_attention import _candidates, tune_key
+        # packed rows run (and are tuned) token-major where the rule
+        # lets them; the pass prices a step on one chip, no mesh
+        token_major = flash_layout(
+            s, d, h, bool(node.num_heads))[0] == "token_major"
         cands = [(bq, bk) for bq in _candidates(s)
                  for bk in _candidates(s)]
         if len(cands) < 2:
@@ -867,7 +874,7 @@ def _autotune_pass(topo, shapes, dtypes, db, steps, add):
             table = AutotuneTable()
         for kind in ("fwd", "fwd_lse", "bwd"):
             name, key = tune_key(kind, s, d, np.dtype(dt), causal,
-                                 has_mask)
+                                 has_mask, token_major=token_major)
             if table.get(name, key) is None:
                 missing.append(kind)
         if not missing:

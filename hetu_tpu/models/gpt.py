@@ -389,48 +389,50 @@ class CausalSelfAttention:
                            name=name + "_proj")
         self.dropout = Dropout(config.hidden_dropout_prob)
 
-    def _split_heads(self, x, seq_len, which):
-        # [B*S, 3H] -> [B, S, 3, nh, hs] -> take q/k/v -> [B, nh, S, hs]
-        x = array_reshape_op(
-            x, [-1, seq_len, 3, self.num_heads, self.head_size])
-        x = transpose_op(x, [2, 0, 3, 1, 4])
-        piece = split_op(x, [0], [which], [3])
-        return squeeze_op(piece, axes=[0])
-
     def __call__(self, hidden_states, seq_len=None):
         from ..ops.attention import (flash_attention_op,
                                      ring_attention_op,
                                      ulysses_attention_op)
         seq_len = seq_len or self.seq_len
-        qkv = self.qkv(hidden_states, [-1, 3 * self.hidden_size])
-        q = self._split_heads(qkv, seq_len, 0)
-        k = self._split_heads(qkv, seq_len, 1)
-        v = self._split_heads(qkv, seq_len, 2)
+        qkv = self.qkv(hidden_states, [-1, seq_len, 3 * self.hidden_size])
         scale = 1.0 / float(np.sqrt(self.head_size))
         sp = self.config.sequence_parallel
-        if sp == "ring":
-            ctx = ring_attention_op(q, k, v, sm_scale=scale, causal=True)
-        elif sp == "ulysses":
-            ctx = ulysses_attention_op(q, k, v, sm_scale=scale,
-                                       causal=True)
-        elif self.config.use_flash_attention:
-            ctx = flash_attention_op(q, k, v, sm_scale=scale, causal=True)
+        if sp is None and self.config.use_flash_attention:
+            # the flash op takes the projection's rows as they lie and
+            # hands the context back as the output projection reads it
+            ctx = flash_attention_op(qkv, num_heads=self.num_heads,
+                                     sm_scale=scale, causal=True)
         else:
-            # composed path (XLA-fused batch_matmul + softmax with a
-            # broadcast causal-mask constant) — the graph BertConfig's
-            # same-named flag selects on the encoder side
-            from ..ops import batch_matmul_op, softmax_op
-            cmask = Variable(
-                self.name + "_causal_mask",
-                value=np.where(np.tril(np.ones((seq_len, seq_len), bool)),
-                               0.0, -1e9)[None, None].astype(np.float32),
-                trainable=False)
-            k = k * scale
-            scores = batch_matmul_op(q, k, trans_B=True)
-            scores = scores + broadcastto_op(cmask, scores)
-            ctx = batch_matmul_op(softmax_op(scores), v)
-        ctx = transpose_op(ctx, [0, 2, 1, 3])
-        ctx = array_reshape_op(ctx, [-1, seq_len, self.hidden_size])
+            # [B, S, 3H] -> [3, B, nh, S, hs] -> q, k, v [B, nh, S, hs]
+            heads = transpose_op(
+                array_reshape_op(qkv, [-1, seq_len, 3, self.num_heads,
+                                       self.head_size]), [2, 0, 3, 1, 4])
+            q, k, v = (squeeze_op(split_op(heads, [0], [i], [3]), axes=[0])
+                       for i in range(3))
+            if sp == "ring":
+                ctx = ring_attention_op(q, k, v, sm_scale=scale,
+                                        causal=True)
+            elif sp == "ulysses":
+                ctx = ulysses_attention_op(q, k, v, sm_scale=scale,
+                                           causal=True)
+            else:
+                # composed path (XLA-fused batch_matmul + softmax with a
+                # broadcast causal-mask constant) — the graph
+                # BertConfig's same-named flag selects on the encoder
+                # side
+                from ..ops import batch_matmul_op, softmax_op
+                cmask = Variable(
+                    self.name + "_causal_mask",
+                    value=np.where(
+                        np.tril(np.ones((seq_len, seq_len), bool)),
+                        0.0, -1e9)[None, None].astype(np.float32),
+                    trainable=False)
+                k = k * scale
+                scores = batch_matmul_op(q, k, trans_B=True)
+                scores = scores + broadcastto_op(cmask, scores)
+                ctx = batch_matmul_op(softmax_op(scores), v)
+            ctx = array_reshape_op(transpose_op(ctx, [0, 2, 1, 3]),
+                                   [-1, seq_len, self.hidden_size])
         out = self.proj(ctx, [-1, seq_len, self.hidden_size])
         return self.dropout(out)
 

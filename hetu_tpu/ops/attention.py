@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from ..graph.node import Op
 
 __all__ = ["flash_attention_op", "FlashAttentionOp", "attention_reference",
+           "flash_layout",
            "ring_attention_op", "RingAttentionOp",
            "ulysses_attention_op", "UlyssesAttentionOp",
            "prefill_attention",
@@ -126,8 +127,10 @@ def prefill_attention(q, k, v, sm_scale, causal=True):
     consumes the logsumexp residual, it skips that output write."""
     if _use_pallas():
         from .pallas_attention import flash_attention
+        _, h, s, d = q.shape
         return flash_attention(q, k, v, None, sm_scale=sm_scale,
-                               causal=causal)
+                               causal=causal,
+                               reason=flash_layout(s, d, h, False)[1])
     mask = None
     if causal:
         s = q.shape[-2]
@@ -240,21 +243,102 @@ def unpartitioned_tpu_step(ectx):
     return _use_pallas() and (mesh is None or mesh.size == 1)
 
 
-class FlashAttentionOp(Op):
-    """Fused attention over [B, H, S, D] q/k/v with an additive mask of
-    shape [B, 1, 1, S] (or None)."""
+def flash_layout(s, d, heads, token_major, ectx=None):
+    """``(layout, reason)``: which operand form a flash call runs in,
+    from what the code can see. ``"token_major"`` (the kernels read
+    q, k and v out of the projection's own rows; ``reason`` None) where
+    (a) the fused backward runs, ``s >= FUSED_BWD_MIN_SEQ``, so both
+    directions skip the trip through ``[B, H, S, D]``; (b) the heads
+    fill whole lane blocks (``TokenMajor.fits``); (c) the step is not
+    partitioned over a mesh (:func:`unpartitioned_tpu_step`); (d) the
+    caller hands token-major operands. Else ``"head_major"`` and the
+    first condition that failed: ``short_seq``, ``lanes``, ``mesh``,
+    ``caller``."""
+    from .pallas_attention import TokenMajor
+    mesh = getattr(getattr(ectx, "config", None), "mesh", None)
+    for reason, holds in (
+            ("short_seq", s >= FUSED_BWD_MIN_SEQ),
+            ("lanes", TokenMajor(heads, d).fits(s)),
+            ("mesh", mesh is None or mesh.size == 1),
+            ("caller", token_major)):
+        if not holds:
+            return "head_major", reason
+    return "token_major", None
 
-    def __init__(self, q, k, v, mask=None, sm_scale=1.0, causal=False,
-                 ctx=None):
-        inputs = [q, k, v] + ([mask] if mask is not None else [])
+
+def _seq_len(q, layout):
+    """S of a q operand: ``[B, H, S, D]``, or rows ``[B, S, lanes]``
+    under a ``TokenMajor`` layout."""
+    return q.shape[-2] if layout is None else q.shape[1]
+
+
+class FlashAttentionOp(Op):
+    """Fused attention, in one of two operand forms. Head-major: q, k, v
+    ``[B, H, S, D]``, the context ``[B, H, S, D]``. Token-major
+    (``num_heads`` given, ``k`` and ``v`` None): ``q`` is a qkv
+    projection's packed rows ``[B, S, 3H]``, the context ``[B, S, H]``
+    and the one gradient ``[B, S, 3H]`` — where :func:`flash_layout`
+    allows, the kernels read and write those rows as they lie and no
+    transpose, split or merge runs around them; elsewhere the op makes
+    the trip through ``[B, H, S, D]`` itself. The additive mask is
+    ``[B, 1, 1, S]`` (or None) either way."""
+
+    def __init__(self, q, k=None, v=None, mask=None, sm_scale=1.0,
+                 causal=False, num_heads=None, ctx=None):
+        if (num_heads is None) == (k is None or v is None):
+            raise ValueError("flash attention takes q, k, v [B, H, S, D], "
+                             "or packed qkv rows with num_heads")
+        inputs = ([q] if num_heads else [q, k, v]) \
+            + ([mask] if mask is not None else [])
         super().__init__(FlashAttentionOp, inputs, ctx)
         self.has_mask = mask is not None
         self.sm_scale = sm_scale
         self.causal = causal
+        self.num_heads = num_heads
+
+    def attention_shape(self, q_shape):
+        """``(b, h, s, d)`` of the attention from the first input's
+        shape, in either operand form (None where it is neither)."""
+        if self.num_heads and len(q_shape) == 3:
+            b, s, width = (int(x) for x in q_shape)
+            return b, self.num_heads, s, width // (3 * self.num_heads)
+        if not self.num_heads and len(q_shape) == 4:
+            return tuple(int(x) for x in q_shape)
+        return None
+
+    def operands(self, input_vals, ectx):
+        """``(q, k, v, mask, layout, reason)`` as the kernels or the
+        reference take them: packed rows stay as they lie under a
+        ``TokenMajor`` layout where the rule allows, and are split into
+        ``[B, H, S, D]`` (layout None) where it does not."""
+        mask = input_vals[-1] if self.has_mask else None
+        if not self.num_heads:
+            q, k, v = input_vals[:3]
+            _, reason = flash_layout(q.shape[2], q.shape[3], q.shape[1],
+                                     False, ectx)
+            return q, k, v, mask, None, reason
+        qkv = input_vals[0]
+        b, h, s, d = self.attention_shape(qkv.shape)
+        form, reason = flash_layout(s, d, h, True, ectx)
+        if form == "token_major" and _use_pallas():
+            from .pallas_attention import TokenMajor
+            return qkv, qkv, qkv, mask, TokenMajor.packed(h, d), None
+        return *self._split(qkv), mask, None, reason
+
+    def _split(self, qkv):
+        """Packed rows ``[B, S, 3H]`` -> q, k, v ``[B, H, S, D]``."""
+        b, h, s, d = self.attention_shape(qkv.shape)
+        return qkv.reshape(b, s, 3, h, d).transpose(2, 0, 3, 1, 4)
 
     def compute(self, input_vals, ectx):
-        q, k, v = input_vals[:3]
-        mask = input_vals[3] if self.has_mask else None
+        q, k, v, mask, layout, reason = self.operands(input_vals, ectx)
+        o = self._attend(q, k, v, mask, layout, reason, ectx)
+        if self.num_heads and layout is None:
+            b, h, s, d = o.shape
+            o = o.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+        return o
+
+    def _attend(self, q, k, v, mask, layout, reason, ectx):
         if _use_pallas():
             # causal is a kernel flag; only the padding mask travels.
             # The logsumexp residual is stashed for the fused backward
@@ -265,16 +349,15 @@ class FlashAttentionOp(Op):
             # the autotune cache at trace time (pallas_attention.py).
             from .pallas_attention import (flash_attention,
                                            flash_attention_with_lse)
+            kw = dict(sm_scale=self.sm_scale, causal=self.causal,
+                      layout=layout, reason=reason)
             if getattr(ectx, "training", False) and \
-                    q.shape[-2] >= FUSED_BWD_MIN_SEQ:
-                o, lse = flash_attention_with_lse(
-                    q, k, v, mask, sm_scale=self.sm_scale,
-                    causal=self.causal)
+                    _seq_len(q, layout) >= FUSED_BWD_MIN_SEQ:
+                o, lse = flash_attention_with_lse(q, k, v, mask, **kw)
                 if o is not None:
                     ectx.cache[("flash_res", self.id)] = (o, lse)
                     return o
-            return flash_attention(q, k, v, mask, sm_scale=self.sm_scale,
-                                   causal=self.causal)
+            return flash_attention(q, k, v, mask, **kw)
         if self.causal:
             s = q.shape[-2]
             cmask = jnp.where(
@@ -285,37 +368,53 @@ class FlashAttentionOp(Op):
     def gradient(self, output_grad):
         grads = [
             _FlashAttentionGradOp(self, output_grad, i, ctx=self.raw_ctx)
-            for i in range(3)]
+            for i in range(1 if self.num_heads else 3)]
         if self.has_mask:
             grads.append(None)
         return grads
 
     def infer_shape(self, input_shapes):
+        if self.num_heads:
+            b, s, width = input_shapes[0]
+            return (b, s, width // 3)
         return input_shapes[0]
 
 
 class _FlashAttentionGradOp(Op):
     """dq/dk/dv via jax.vjp over the fused forward — one op per operand so
     the graph stays an adjoint DAG (the reference packs/unpacks gradients
-    the same way for BN/LN)."""
+    the same way for BN/LN). A forward over packed rows has the one
+    operand and the one gradient, ``[B, S, 3H]``."""
 
     def __init__(self, forward_op, output_grad, which, ctx=None):
         super().__init__(_FlashAttentionGradOp,
                          list(forward_op.inputs) + [output_grad], ctx)
         self.forward_op = forward_op
         self.which = which
+        self.attention_shape = forward_op.attention_shape
 
     def compute(self, input_vals, ectx):
         fwd = self.forward_op
-        nin = 4 if fwd.has_mask else 3
-        q, k, v = input_vals[:3]
-        mask = input_vals[3] if fwd.has_mask else None
-        dy = input_vals[nin]
-
         cache_key = ("flashattn_vjp", fwd.id)
+        if cache_key not in ectx.cache:
+            ectx.cache[cache_key] = self._grads(input_vals, ectx)
+        return ectx.cache[cache_key][self.which]
+
+    def _grads(self, input_vals, ectx):
+        fwd = self.forward_op
+        q, k, v, mask, layout, reason = fwd.operands(input_vals[:-1], ectx)
+        dy = input_vals[-1]
         res = ectx.cache.get(("flash_res", fwd.id))
-        if cache_key not in ectx.cache and res is not None and \
-                q.shape[-2] >= FUSED_BWD_MIN_SEQ:
+        if layout is not None and res is None:
+            # gradients asked of a step that is not training: the
+            # forward kept no residual, so the composed vjp, over heads
+            q, k, v = fwd._split(q)
+            layout = None
+        if fwd.num_heads and layout is None:
+            b, h, s, d = q.shape
+            dy = dy.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+        if res is not None and \
+                _seq_len(q, layout) >= FUSED_BWD_MIN_SEQ:
             # fused Pallas backward: ONE kernel rebuilds each score
             # tile in VMEM from the forward's logsumexp and feeds dQ,
             # dK and dV from it — the S x S matrices never hit HBM on
@@ -327,10 +426,10 @@ class _FlashAttentionGradOp(Op):
             # one-pass kernel alone at BERT's shape: PERF.md section 7).
             from .pallas_attention import flash_attention_bwd
             o, lse = res
-            ectx.cache[cache_key] = flash_attention_bwd(
+            grads = flash_attention_bwd(
                 q, k, v, mask, o, lse, dy, sm_scale=fwd.sm_scale,
-                causal=fwd.causal)
-        if cache_key not in ectx.cache:
+                causal=fwd.causal, layout=layout, reason=reason)
+        else:
             def f(q_, k_, v_):
                 m = mask
                 if fwd.causal:
@@ -341,8 +440,15 @@ class _FlashAttentionGradOp(Op):
                     m = cmask if m is None else m + cmask
                 return attention_reference(q_, k_, v_, m, fwd.sm_scale)
             _, vjp = jax.vjp(f, q, k, v)
-            ectx.cache[cache_key] = vjp(dy)
-        return ectx.cache[cache_key][self.which]
+            grads = vjp(dy)
+        if not fwd.num_heads:
+            return grads
+        if layout is not None:
+            # the rows the qkv projection's dW and dX matmuls read
+            return (jnp.concatenate(grads, axis=-1),)
+        b, h, s, d = q.shape
+        return (jnp.stack(grads).transpose(1, 3, 0, 2, 4).reshape(
+            b, s, 3 * h * d),)
 
     def gradient(self, output_grad):
         raise NotImplementedError
@@ -351,9 +457,13 @@ class _FlashAttentionGradOp(Op):
         return input_shapes[self.which]
 
 
-def flash_attention_op(q, k, v, mask=None, sm_scale=1.0, causal=False,
-                       ctx=None):
-    return FlashAttentionOp(q, k, v, mask, sm_scale, causal, ctx=ctx)
+def flash_attention_op(q, k=None, v=None, mask=None, sm_scale=1.0,
+                       causal=False, num_heads=None, ctx=None):
+    """Fused attention over q, k, v ``[B, H, S, D]``, or — ``num_heads``
+    given, k and v left out — over a qkv projection's packed rows
+    ``[B, S, 3H]``; see :class:`FlashAttentionOp`."""
+    return FlashAttentionOp(q, k, v, mask, sm_scale, causal, num_heads,
+                            ctx=ctx)
 
 
 # ---------------------------------------------------------------------------

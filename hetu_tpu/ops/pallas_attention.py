@@ -23,6 +23,18 @@ are grouped into square REGIONS (``_bwd_span``): a region's pairs are
 straight-line code the compiler interleaves, the regions are walked by a
 loop from the diagonal down, a program per (batch*head, region row).
 
+Operands come in one of two forms (``ops/attention.py:flash_layout``
+picks by what the code can see). Head-major: q, k, v ``[B, H, S, D]``,
+a program a head. Token-major (:class:`TokenMajor`): ``[B, S, lanes]``
+rows as a projection writes them — one packed ``[B, S, 3H]`` array may
+be read three times through three index maps — a program one lane
+block of ``128 // D`` heads, split inside the kernel by static lane
+windows; the context, dq, dk and dv leave as rows and the residuals as
+``[B, H, 1, S]`` rows, so no transpose, split or merge runs around the
+calls. The kernel bodies are shared (the head's lane window is a static
+parameter), the arithmetic a head is the same to the bit, and the
+head-major form lowers to the program it always did.
+
 Block sizes are AUTOTUNED per (platform, kernel, S, D, dtype, causal,
 mask): bq/bk sweep {128, 256, 512, 1024} (clipped to divisors of S)
 independently for the forward, the forward-with-lse and the fused
@@ -42,6 +54,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import numpy as np
 
@@ -51,7 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "flash_attention_bwd", "tune_key"]
+           "flash_attention_bwd", "tune_key", "TokenMajor"]
 
 NEG_INF = -1e30
 LANES = 128      # TPU minor-dim tile: the forward writes lse lane-tiled
@@ -72,54 +85,123 @@ def _forward_compiler_params(s, d, itemsize):
         vmem_limit_bytes=min(_MOST_VMEM, 2 * resident))}
 
 
+class TokenMajor(NamedTuple):
+    """The operand form of a call whose q, k and v lie as a projection
+    wrote them: ``[B, S, lanes]`` rows, a row's heads side by side. A
+    grid step then owns one lane BLOCK of a batch row — ``width`` =
+    ``max(head_dim, 128)`` lanes, ``per_block`` heads — and the kernels
+    split it by static lane windows. ``tiles`` are the lane-block
+    offsets of q, k and v in their arrays, so ONE packed ``[B, S, 3H]``
+    array (a qkv projection's rows) may be passed three times; the
+    context, dq, dk and dv are ``[B, S, H]`` and the row residuals
+    ``[B, heads, 1, S]``, the rows the backward reads (no reshape lies
+    between the two kernels: XLA would copy one into other tiles).
+    Hashable: a static argument of the jits."""
+    heads: int
+    head_dim: int
+    tiles: tuple = (0, 0, 0)
+
+    @property
+    def width(self):
+        return max(self.head_dim, LANES)
+
+    @property
+    def per_block(self):
+        return self.width // self.head_dim
+
+    @property
+    def blocks(self):
+        return self.heads * self.head_dim // self.width
+
+    @classmethod
+    def packed(cls, heads, head_dim):
+        """q, k and v as the thirds of one ``[B, S, 3H]`` array."""
+        n = cls(heads, head_dim).blocks
+        return cls(heads, head_dim, (0, n, 2 * n))
+
+    def fits(self, s):
+        """Heads fill whole lane blocks, and the residual rows whole
+        lane tiles."""
+        d, h = self.head_dim, self.heads * self.head_dim
+        return s % LANES == 0 and (
+            d % LANES == 0 or (LANES % d == 0 and h % LANES == 0))
+
+
+def _lane_windows(ref, head_dim):
+    """The static lane windows of the heads a block holds: the whole
+    block head-major (``head_dim`` None), else one window a head."""
+    if head_dim is None:
+        return [slice(None)]
+    return [slice(i * head_dim, (i + 1) * head_dim)
+            for i in range(ref.shape[-1] // head_dim)]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
-                block_k, seq_len, causal, block_q):
+                block_k, seq_len, causal, block_q, head_dim=None):
     # dots run in the INPUT dtype with f32 accumulation — on bf16 inputs
     # that is the MXU's native mode; upcasting operands to f32 first
     # would decompose every matmul into multiple f32 passes (measured
     # ~2x whole-step cost at S=2048). All softmax math stays f32.
-    q = q_ref[0]                              # [block_q, d]
+    # Token-major (``head_dim`` given) the block holds several heads
+    # side by side: each is its own chain of the SAME arithmetic over
+    # its lane window, and the chains share the K loop (2-7% faster on
+    # a v5e than a loop a head: PERF.md PR 38).
+    heads = _lane_windows(q_ref, head_dim)
+    qs = [q_ref[0, :, lanes] for lanes in heads]   # [block_q, d] each
     num_kb = seq_len // block_k
-    qi = pl.program_id(1)
+    qi = pl.program_id(1 if head_dim is None else 2)
     if causal:
         # skip K-blocks strictly in the future of this q-block
         num_kb = jnp.minimum(
             num_kb, pl.cdiv((qi + 1) * block_q, block_k))
 
     def body(i, carry):
-        m_prev, l_prev, acc = carry
-        k = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if mask_ref is not None:
-            s = s + mask_ref[0, 0, pl.ds(i * block_k, block_k)][None, :]
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
+        keep, out = None, []
+        for q, lanes, (m_prev, l_prev, acc) in zip(qs, heads, carry):
+            k = k_ref[0, pl.ds(i * block_k, block_k), lanes]
+            v = v_ref[0, pl.ds(i * block_k, block_k), lanes]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if mask_ref is not None:
+                s = s + mask_ref[0, 0, pl.ds(i * block_k, block_k)][None, :]
+            if causal:
+                if keep is None:
+                    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_k), 0)
+                    k_pos = i * block_k + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_k), 1)
+                    keep = q_pos >= k_pos
+                s = jnp.where(keep, s, NEG_INF)
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            out.append((m_new, l_new, acc))
+        return tuple(out)
 
-    m0 = jnp.full((q.shape[0], 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((q.shape[0], 1), jnp.float32)
-    acc0 = jnp.zeros(q.shape, jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    if l_ref is not None:
-        # per-row logsumexp, the backward's softmax residual — written
-        # lane-tiled [block_q, 128] (TPU blocks need 128-lane minors)
-        l_ref[0] = jnp.broadcast_to(m + jnp.log(l), (block_q, LANES))
+    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((block_q, 1), jnp.float32)
+    done = jax.lax.fori_loop(
+        0, num_kb, body,
+        tuple((m0, l0, jnp.zeros(q.shape, jnp.float32)) for q in qs))
+    for i, (lanes, (m, l, acc)) in enumerate(zip(heads, done)):
+        o_ref[0, :, lanes] = (acc / l).astype(o_ref.dtype)
+        if l_ref is None:
+            continue
+        # per-row logsumexp, the backward's softmax residual
+        lse = jnp.broadcast_to(m + jnp.log(l), (block_q, LANES))
+        if head_dim is None:
+            # head-major: lane-tiled [block_q, 128] (TPU blocks need
+            # 128-lane minors), read back at lane 0
+            l_ref[0] = lse
+        else:
+            # token-major: the row the backward reads, [1, block_q]
+            l_ref[0, i] = lse.T[0:1]
 
 
 def _block_sizes(seq_len, head_dim):
@@ -167,21 +249,25 @@ def _candidates(s):
 _KERNEL_REVISION = {"bwd": "bwd_onepass"}
 
 
-def tune_key(kind, s, d, dtype, causal, has_mask, interpret=False):
+def tune_key(kind, s, d, dtype, causal, has_mask, interpret=False,
+             token_major=False):
     """(name, key) under which a flash kernel's block choice is cached —
     shared by the tuner, the probe and the tests. ``kind`` is one of
     ``fwd`` / ``fwd_lse`` / ``bwd``; interpret-mode entries are
-    partitioned so CPU test sweeps never pollute a TPU cache."""
+    partitioned so CPU test sweeps never pollute a TPU cache, and the
+    token-major form of a kernel (other blocks, several heads a
+    program) is swept and stored apart from the head-major one."""
     key = (f"S{s}", f"D{d}", jnp.dtype(dtype).name,
            "causal" if causal else "full",
            "mask" if has_mask else "nomask")
     if interpret:
         key = key + ("interp",)
-    return "flash_" + _KERNEL_REVISION.get(kind, kind), key
+    return "flash_" + _KERNEL_REVISION.get(kind, kind) \
+        + ("_token_major" if token_major else ""), key
 
 
 def _measure_factory(kind, b, h, s, d, dtype, sm_scale, causal, has_mask,
-                     interpret):
+                     interpret, layout=None):
     """measure(config) -> seconds for the autotune engine. Inputs are
     built lazily on the first call (a cache hit never pays for them)
     with the CALLER's b/h so the sweep times the shape that triggered
@@ -194,9 +280,15 @@ def _measure_factory(kind, b, h, s, d, dtype, sm_scale, causal, has_mask,
         rng = np.random.RandomState(0)
 
         def mk():
-            return jnp.asarray(rng.randn(b, h, s, d) * 0.3, dtype)
+            shape = (b, h, s, d) if layout is None else (b, s, h * d)
+            return jnp.asarray(rng.randn(*shape) * 0.3, dtype)
 
-        state["q"], state["k"], state["v"] = mk(), mk(), mk()
+        if layout is not None and any(layout.tiles):
+            # the caller's packed rows: one array, read three times
+            state["q"] = state["k"] = state["v"] = jnp.concatenate(
+                [mk(), mk(), mk()], axis=-1)
+        else:
+            state["q"], state["k"], state["v"] = mk(), mk(), mk()
         state["mask"] = (jnp.zeros((b, 1, 1, s), jnp.float32)
                          if has_mask else None)
         if kind == "bwd":
@@ -205,7 +297,7 @@ def _measure_factory(kind, b, h, s, d, dtype, sm_scale, causal, has_mask,
             bq0, bk0 = _block_sizes(s, d)
             o, lse = _flash_attention_jit(
                 state["q"], state["k"], state["v"], state["mask"],
-                sm_scale, causal, interpret, bq0, bk0, True)
+                sm_scale, causal, interpret, bq0, bk0, True, layout)
             state["o"], state["lse"], state["do"] = o, lse, mk()
         return state
 
@@ -226,14 +318,14 @@ def _measure_factory(kind, b, h, s, d, dtype, sm_scale, causal, has_mask,
                 return _flash_attention_bwd_jit(
                     st["q"], st["k"], st["v"], st["mask"], st["o"],
                     st["lse"], st["do"], sm_scale, causal, interpret,
-                    bq, bk)
+                    bq, bk, layout)
         else:
             need_lse = kind == "fwd_lse"
 
             def run():
                 return _flash_attention_jit(
                     st["q"], st["k"], st["v"], st["mask"], sm_scale,
-                    causal, interpret, bq, bk, need_lse)
+                    causal, interpret, bq, bk, need_lse, layout)
         from ..tune import timeit
         return timeit(run, _sync, reps=_MEASURE_REPS,
                       windows=_MEASURE_WINDOWS)
@@ -242,7 +334,7 @@ def _measure_factory(kind, b, h, s, d, dtype, sm_scale, causal, has_mask,
 
 
 def _tuned_block_sizes(kind, b, h, s, d, dtype, sm_scale, causal,
-                       has_mask, interpret):
+                       has_mask, interpret, layout=None):
     """(block_q, block_k) for one kernel direction: the autotuned winner
     when tuning is on and the shape has a real sweep space, the static
     default otherwise. Runs at trace time — once per compiled shape —
@@ -252,10 +344,11 @@ def _tuned_block_sizes(kind, b, h, s, d, dtype, sm_scale, causal,
     if len(cands) < 2:
         return default              # nothing to tune (short sequences)
     from ..tune import autotune
-    name, key = tune_key(kind, s, d, dtype, causal, has_mask, interpret)
+    name, key = tune_key(kind, s, d, dtype, causal, has_mask, interpret,
+                         layout is not None)
     cfg = autotune(name, key, cands,
                    _measure_factory(kind, b, h, s, d, dtype, sm_scale,
-                                    causal, has_mask, interpret),
+                                    causal, has_mask, interpret, layout),
                    default=default)
     try:
         bq, bk = int(cfg[0]), int(cfg[1])
@@ -266,48 +359,75 @@ def _tuned_block_sizes(kind, b, h, s, d, dtype, sm_scale, causal,
     return bq, bk
 
 
+def _plan(kind, q, mask, sm_scale, causal, interpret, layout, reason):
+    """What the three entries share: ``(interpret, blocks)`` of a call —
+    blocks None where the kernel does not take the shape — and the
+    ``flash_layout`` instant, once a traced call: the operand form the
+    call runs in and, head-major, the first of the rule's conditions
+    that kept it there (``ops/attention.py:flash_layout``; a caller that
+    hands ``[B, H, S, D]`` operands and no reason is its own reason)."""
+    if interpret is None:
+        interpret = INTERPRET
+    b, h, s, d = _dims(q, layout)
+    from .. import telemetry
+    telemetry.get_telemetry().instant(
+        "flash_layout", kernel=kind, seq=s, head_dim=d,
+        layout="head_major" if layout is None else "token_major",
+        heads_per_block=1 if layout is None else layout.per_block,
+        **({"reason": reason or "caller"} if layout is None else {}))
+    if not _supported(s, d, *_block_sizes(s, d)):
+        return interpret, None
+    return interpret, _tuned_block_sizes(
+        kind, b, h, s, d, q.dtype, sm_scale, causal, mask is not None,
+        interpret, layout)
+
+
+def _form(layout):
+    """The trailing argument of a jit call: a head-major call is made
+    with the arguments it always had."""
+    return () if layout is None else (layout,)
+
+
 def flash_attention(q, k, v, mask=None, sm_scale=1.0, causal=False,
-                    interpret=None):
-    """softmax(q k^T * sm_scale + mask) v over [B, H, S, D].
+                    interpret=None, layout=None, reason=None):
+    """softmax(q k^T * sm_scale + mask) v over [B, H, S, D], or with a
+    :class:`TokenMajor` ``layout`` over ``[B, S, lanes]`` rows (the
+    context then ``[B, S, H]``; the caller has checked
+    ``layout.fits``).
 
     ``mask`` is an additive *padding* mask broadcastable to [B, 1, 1, S]
     (the BERT layout); causal masking is a kernel flag, not a mask
     argument. Tiny or oddly-shaped inputs fall back to the composed-XLA
     reference rather than violating TPU tiling constraints.
     """
-    if interpret is None:
-        interpret = INTERPRET
-    b, h, s, d = q.shape
-    if not _supported(s, d, *_block_sizes(s, d)):
+    interpret, blocks = _plan("fwd", q, mask, sm_scale, causal,
+                              interpret, layout, reason)
+    if blocks is None:
         from .attention import attention_reference
+        s = q.shape[-2]
         m = mask
         if causal:
             cmask = jnp.where(jnp.tril(jnp.ones((s, s), bool)), 0.0,
                               NEG_INF)[None, None]
             m = cmask if m is None else m + cmask
         return attention_reference(q, k, v, m, sm_scale)
-    block_q, block_k = _tuned_block_sizes(
-        "fwd", b, h, s, d, q.dtype, sm_scale, causal, mask is not None,
-        interpret)
     return _flash_attention_jit(q, k, v, mask, sm_scale, causal,
-                                interpret, block_q, block_k, False)
+                                interpret, *blocks, False, *_form(layout))
 
 
 def flash_attention_with_lse(q, k, v, mask=None, sm_scale=1.0,
-                             causal=False, interpret=None):
-    """(output, logsumexp [B, H, S]) — the pair the fused backward needs.
+                             causal=False, interpret=None, layout=None,
+                             reason=None):
+    """(output, logsumexp [B, H, S]; token-major [B, H, 1, S]) — the
+    pair the fused backward needs.
     Returns (None, None) on shapes the kernel does not support; callers
     then take the composed path for both directions."""
-    if interpret is None:
-        interpret = INTERPRET
-    b, h, s, d = q.shape
-    if not _supported(s, d, *_block_sizes(s, d)):
+    interpret, blocks = _plan("fwd_lse", q, mask, sm_scale, causal,
+                              interpret, layout, reason)
+    if blocks is None:
         return None, None
-    block_q, block_k = _tuned_block_sizes(
-        "fwd_lse", b, h, s, d, q.dtype, sm_scale, causal,
-        mask is not None, interpret)
     return _flash_attention_jit(q, k, v, mask, sm_scale, causal,
-                                interpret, block_q, block_k, True)
+                                interpret, *blocks, True, *_form(layout))
 
 
 # tests flip this to exercise the kernel without a TPU backend
@@ -320,30 +440,61 @@ def _mask_rows(mask, b, h, s):
         b, 1, s).astype(jnp.float32)
 
 
+def _dims(q, layout):
+    """(b, h, s, d) of a call from its q operand and operand form."""
+    if layout is None:
+        return q.shape
+    return q.shape[0], layout.heads, q.shape[1], layout.head_dim
+
+
 @functools.partial(jax.jit, static_argnames=("sm_scale", "causal",
                                              "interpret", "block_q",
-                                             "block_k", "need_lse"))
+                                             "block_k", "need_lse",
+                                             "layout"))
 def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
-                         block_q, block_k, need_lse):
-    b, h, s, d = q.shape
-    grid = (b * h, s // block_q)
-
-    qr = q.reshape(b * h, s, d)
-    kr = k.reshape(b * h, s, d)
-    vr = v.reshape(b * h, s, d)
-
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-        pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-    ]
-    args = [qr, kr, vr]
+                         block_q, block_k, need_lse, layout=None):
+    """``layout`` None: q, k, v ``[B, H, S, D]``, a grid step a head's
+    q-block. A :class:`TokenMajor`: ``[B, S, lanes]`` rows, a grid step
+    the q-block of one lane block of heads; the same kernel body either
+    way, and the same event name in a device trace."""
+    b, h, s, d = _dims(q, layout)
+    if layout is None:  # jit-ok: static argname
+        grid = (b * h, s // block_q)
+        args = [x.reshape(b * h, s, d) for x in (q, k, v)]
+        rows = lambda bh, qi: (bh, qi, 0)             # noqa: E731
+        whole = lambda bh, qi: (bh, 0, 0)             # noqa: E731
+        in_specs = [pl.BlockSpec((1, block_q, d), rows),
+                    pl.BlockSpec((1, s, d), whole),
+                    pl.BlockSpec((1, s, d), whole)]
+        mask_spec = pl.BlockSpec(
+            (1, 1, s), lambda bh, qi, _h=h: (bh // _h, 0, 0))
+        o_shape = jax.ShapeDtypeStruct((b * h, s, d), q.dtype)
+        o_spec = pl.BlockSpec((1, block_q, d), rows)
+        l_shape = jax.ShapeDtypeStruct((b * h, s, LANES), jnp.float32)
+        l_spec = pl.BlockSpec((1, block_q, LANES), rows)
+    else:
+        w, tq, tk, tv = layout.width, *layout.tiles
+        grid = (b, layout.blocks, s // block_q)
+        args = [q, k, v]
+        in_specs = [
+            pl.BlockSpec((1, block_q, w),
+                         lambda bi, p, qi: (bi, qi, tq + p)),
+            pl.BlockSpec((1, s, w), lambda bi, p, qi: (bi, 0, tk + p)),
+            pl.BlockSpec((1, s, w), lambda bi, p, qi: (bi, 0, tv + p))]
+        mask_spec = pl.BlockSpec((1, 1, s), lambda bi, p, qi: (bi, 0, 0))
+        o_shape = jax.ShapeDtypeStruct((b, s, h * d), q.dtype)
+        o_spec = pl.BlockSpec((1, block_q, w),
+                              lambda bi, p, qi: (bi, qi, p))
+        # the residual leaves as the rows the backward takes
+        l_shape = jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)
+        l_spec = pl.BlockSpec((1, layout.per_block, 1, block_q),
+                              lambda bi, p, qi: (bi, p, 0, qi))
     body = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                              block_k=block_k, seq_len=s, causal=causal,
-                             block_q=block_q)
+                             block_q=block_q,
+                             head_dim=None if layout is None else d)
     if mask is not None:  # jit-ok: structural None-check, not a traced read
-        in_specs.append(
-            pl.BlockSpec((1, 1, s), lambda bh, qi, _h=h: (bh // _h, 0, 0)))
+        in_specs.append(mask_spec)
         args.append(_mask_rows(mask, b, h, s))
         if need_lse:  # jit-ok: static argname
             kernel = body
@@ -358,25 +509,21 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
             def kernel(q_ref, k_ref, v_ref, o_ref):
                 body(q_ref, k_ref, v_ref, None, o_ref, None)
 
-    o_shape = jax.ShapeDtypeStruct((b * h, s, d), q.dtype)
-    o_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0))
     more_vmem = _forward_compiler_params(s, d, q.dtype.itemsize)
     if need_lse:  # jit-ok: static argname
         # the lse residual is emitted only when a consumer exists (the
         # fused backward); the inference/serving forward skips the write
         out, lse = pl.pallas_call(
             kernel,
-            out_shape=[o_shape,
-                       jax.ShapeDtypeStruct((b * h, s, LANES),
-                                            jnp.float32)],
+            out_shape=[o_shape, l_shape],
             grid=grid,
             in_specs=in_specs,
-            out_specs=[o_spec,
-                       pl.BlockSpec((1, block_q, LANES),
-                                    lambda bh, qi: (bh, qi, 0))],
+            out_specs=[o_spec, l_spec],
             interpret=interpret, **more_vmem,
         )(*args)
-        return out.reshape(b, h, s, d), lse[:, :, 0].reshape(b, h, s)
+        if layout is None:  # jit-ok: static argname
+            return out.reshape(b, h, s, d), lse[:, :, 0].reshape(b, h, s)
+        return out, lse
     out = pl.pallas_call(
         kernel,
         out_shape=o_shape,
@@ -385,7 +532,7 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
         out_specs=o_spec,
         interpret=interpret, **more_vmem,
     )(*args)
-    return out.reshape(b, h, s, d)
+    return out if layout is not None else out.reshape(b, h, s, d)
 
 
 # ---------------------------------------------------------------------------
@@ -463,7 +610,7 @@ def _bwd_span(s, block_q, block_k):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
                 dq_ref, dk_ref, dv_ref, *acc, sm_scale, block_q, block_k,
-                span, seq_len, causal):
+                span, seq_len, causal, head_dim=None):
     """One region row's program: K / V rows ``[kj*span, (kj+1)*span)``
     stay resident and the program walks the q-regions the diagonal
     leaves them — the region ON the diagonal first (its tile pairs
@@ -479,106 +626,126 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
     whole head (``span == seq_len``) they are rounded straight into the
     outputs; else they add up in the float32 scratch ``acc`` — dK / dV
     over the program's walk, dQ (``[D, S]``) across the programs of a
-    head — and are rounded once at the end."""
-    kj = pl.program_id(1)
+    head — and are rounded once at the end. Token-major (``head_dim``
+    given) the blocks hold several heads side by side: each head makes
+    that same walk over its own lane window, with its own residual rows
+    and its own three accumulators."""
+    region_axis = 1 if head_dim is None else 2
+    kj = pl.program_id(region_axis)
     nt = (((1,), (1,)), ((), ()))             # a @ b^T
     nn = (((1,), (0,)), ((), ()))
     whole = span == seq_len
-    if not whole:
-        dq_acc, dk_acc, dv_acc = acc
 
-        @pl.when(kj == 0)
+    def head(n, lanes):
+        def resid(ref, rows):     # this head's residual row, on lanes
+            if head_dim is None:
+                return ref[0, 0, rows][None, :]
+            return ref[0, n, 0, rows][None, :]
+
+        if not whole:
+            dq_acc, dk_acc, dv_acc = acc[3 * n:3 * n + 3]
+
+            @pl.when(kj == 0)
+            def _():
+                dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        def region(qi, diagonal, first):
+            q0 = 0 if whole else qi * span   # static where it can be
+            rows = [pl.ds(q0 + i * block_q if isinstance(q0, int) else
+                          pl.multiple_of(q0 + i * block_q, block_q),
+                          block_q)
+                    for i in range(span // block_q)]
+            dqt = [0.0] * len(rows)
+            for j in range(span // block_k):
+                keys = slice(j * block_k, (j + 1) * block_k)
+                k = k_ref[0, keys, lanes]         # [block_k, d]
+                v = v_ref[0, keys, lanes]
+                kt = k.T
+                begin, unmasked = 0, 0
+                if diagonal:  # q0 is the keys' own offset: local indices
+                    begin = _first_q_tile(j, block_q, block_k)
+                    unmasked = _first_unmasked_q_tile(j, block_q, block_k)
+                dk = dv = 0.0
+                for i in range(begin, len(rows)):
+                    q = q_ref[0, rows[i], lanes]  # [block_q, d]
+                    do = do_ref[0, rows[i], lanes]
+                    st = jax.lax.dot_general(
+                        k, q, nt,
+                        preferred_element_type=jnp.float32) * sm_scale
+                    if mask_ref is not None:
+                        st = st + mask_ref[0, keys, :]    # [block_k, 1]
+                    if i < unmasked:
+                        k_pos = j * block_k + jax.lax.broadcasted_iota(
+                            jnp.int32, (block_k, block_q), 0)
+                        q_pos = i * block_q + jax.lax.broadcasted_iota(
+                            jnp.int32, (block_k, block_q), 1)
+                        st = jnp.where(q_pos >= k_pos, st, NEG_INF)
+                    pt = jnp.exp(st - resid(l_ref, rows[i]))       # P^T
+                    dv = dv + jax.lax.dot_general(
+                        pt.astype(do.dtype), do, nn,
+                        preferred_element_type=jnp.float32)
+                    dpt = jax.lax.dot_general(
+                        v, do, nt, preferred_element_type=jnp.float32)
+                    dst = (pt * (dpt - resid(d_ref, rows[i]))
+                           * sm_scale).astype(q.dtype)            # dS^T
+                    dk = dk + jax.lax.dot_general(
+                        dst, q, nn, preferred_element_type=jnp.float32)
+                    dqt[i] = dqt[i] + jax.lax.dot_general(
+                        kt, dst, nn, preferred_element_type=jnp.float32)
+                if whole:
+                    dk_ref[0, keys, lanes] = dk.astype(dk_ref.dtype)
+                    dv_ref[0, keys, lanes] = dv.astype(dv_ref.dtype)
+                elif first:  # the program's first region: nothing to add to
+                    dk_acc[keys, :] = dk
+                    dv_acc[keys, :] = dv
+                else:
+                    dk_acc[keys, :] += dk
+                    dv_acc[keys, :] += dv
+            for i, dq in enumerate(dqt):
+                if whole:
+                    dq_ref[0, rows[i], lanes] = dq.T.astype(dq_ref.dtype)
+                else:
+                    dq_acc[:, rows[i]] += dq
+
+        # the diagonal's region with ``causal``, else the first: traced
+        # apart from the loop, for the pairs it leaves out or for
+        # ``first`` alone
+        region(kj if causal else 0, diagonal=causal, first=True)
+        if whole:
+            return
+
+        def body(qi, carry):
+            region(qi, diagonal=False, first=False)
+            return carry
+
+        jax.lax.fori_loop(kj + 1 if causal else 1, seq_len // span,
+                          body, 0)
+        dk_ref[0, :, lanes] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, :, lanes] = dv_acc[...].astype(dv_ref.dtype)
+
+        @pl.when(kj == pl.num_programs(region_axis) - 1)
         def _():
-            dq_acc[...] = jnp.zeros_like(dq_acc)
+            dq_ref[0, :, lanes] = dq_acc[...].T.astype(dq_ref.dtype)
 
-    def region(qi, diagonal, first):
-        q0 = 0 if whole else qi * span       # static where it can be
-        rows = [pl.ds(q0 + i * block_q if isinstance(q0, int) else
-                      pl.multiple_of(q0 + i * block_q, block_q), block_q)
-                for i in range(span // block_q)]
-        dqt = [0.0] * len(rows)
-        for j in range(span // block_k):
-            keys = slice(j * block_k, (j + 1) * block_k)
-            k = k_ref[0, keys, :]             # [block_k, d]
-            v = v_ref[0, keys, :]
-            kt = k.T
-            begin, unmasked = 0, 0
-            if diagonal:    # q0 is the keys' own offset: local indices
-                begin = _first_q_tile(j, block_q, block_k)
-                unmasked = _first_unmasked_q_tile(j, block_q, block_k)
-            dk = dv = 0.0
-            for i in range(begin, len(rows)):
-                q = q_ref[0, rows[i], :]      # [block_q, d]
-                do = do_ref[0, rows[i], :]
-                st = jax.lax.dot_general(
-                    k, q, nt,
-                    preferred_element_type=jnp.float32) * sm_scale
-                if mask_ref is not None:
-                    st = st + mask_ref[0, keys, :]    # [block_k, 1]
-                if i < unmasked:
-                    k_pos = j * block_k + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 0)
-                    q_pos = i * block_q + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_k, block_q), 1)
-                    st = jnp.where(q_pos >= k_pos, st, NEG_INF)
-                pt = jnp.exp(st - l_ref[0, 0, rows[i]][None, :])  # P^T
-                dv = dv + jax.lax.dot_general(
-                    pt.astype(do.dtype), do, nn,
-                    preferred_element_type=jnp.float32)
-                dpt = jax.lax.dot_general(
-                    v, do, nt, preferred_element_type=jnp.float32)
-                dst = (pt * (dpt - d_ref[0, 0, rows[i]][None, :])
-                       * sm_scale).astype(q.dtype)                # dS^T
-                dk = dk + jax.lax.dot_general(
-                    dst, q, nn, preferred_element_type=jnp.float32)
-                dqt[i] = dqt[i] + jax.lax.dot_general(
-                    kt, dst, nn, preferred_element_type=jnp.float32)
-            if whole:
-                dk_ref[0, keys, :] = dk.astype(dk_ref.dtype)
-                dv_ref[0, keys, :] = dv.astype(dv_ref.dtype)
-            elif first:     # the program's first region: nothing to add to
-                dk_acc[keys, :] = dk
-                dv_acc[keys, :] = dv
-            else:
-                dk_acc[keys, :] += dk
-                dv_acc[keys, :] += dv
-        for i, dq in enumerate(dqt):
-            if whole:
-                dq_ref[0, rows[i], :] = dq.T.astype(dq_ref.dtype)
-            else:
-                dq_acc[:, rows[i]] += dq
-
-    # the diagonal's region with ``causal``, else the first: traced apart
-    # from the loop, for the pairs it leaves out or for ``first`` alone
-    region(kj if causal else 0, diagonal=causal, first=True)
-    if whole:
-        return
-
-    def body(qi, carry):
-        region(qi, diagonal=False, first=False)
-        return carry
-
-    jax.lax.fori_loop(kj + 1 if causal else 1, seq_len // span, body, 0)
-    dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-    @pl.when(kj == pl.num_programs(1) - 1)
-    def _():
-        dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
+    for n, lanes in enumerate(_lane_windows(q_ref, head_dim)):
+        head(n, lanes)
 
 
-def _backward_compiler_params(s, d, span, block_q, block_k, itemsize):
+def _backward_compiler_params(s, d, span, block_q, block_k, itemsize,
+                              per_block=1, grid_rank=2):
     """The region axis is a reduction into ``dq_acc`` (sequential); the
     VMEM asked for covers a head's q, dO and dQ and a region's K, V, dK
-    and dV (double-buffered), the float32 accumulators and the
-    score-tile temporaries of a region's pairs, which at the largest
-    candidate tiles pass the 16 MiB a kernel gets unasked."""
-    lanes = -(-d // LANES) * LANES
+    and dV (double-buffered), the float32 accumulators (dK's and dV's
+    once a head of the block) and the score-tile temporaries of a
+    region's pairs, which at the largest candidate tiles pass the
+    16 MiB a kernel gets unasked."""
+    lanes = -(-d * per_block // LANES) * LANES
     resident = (3 * 2 * itemsize + 4) * s * lanes \
-        + 2 * (4 * itemsize + 4) * span * lanes
+        + 2 * (4 * itemsize + 4 * per_block) * span * lanes
     tiles = 6 * 4 * max(block_q * block_k, span * span // 4)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
+        dimension_semantics=("parallel",) * (grid_rank - 1)
+        + ("arbitrary",),
         vmem_limit_bytes=int(min(_MOST_VMEM,
                                  max(16 * 1024 * 1024,
                                      2 * (resident + tiles)))))
@@ -586,40 +753,77 @@ def _backward_compiler_params(s, d, span, block_q, block_k, itemsize):
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "causal",
                                              "interpret", "block_q",
-                                             "block_k"))
+                                             "block_k", "layout"))
 def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
-                             interpret, block_q, block_k):
-    b, h, s, d = q.shape
-    qr = q.reshape(b * h, s, d)
-    kr = k.reshape(b * h, s, d)
-    vr = v.reshape(b * h, s, d)
-    dor = do.reshape(b * h, s, d)
-    # the row residuals travel as rows [B*H, 1, S] (S on lanes, where
-    # the transposed score tile wants them), not broadcast over lanes
-    lser = lse.reshape(b * h, 1, s).astype(jnp.float32)
-    # D = rowsum(dO * O): cheap XLA elementwise reduce
-    dr = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                 axis=-1).reshape(b * h, 1, s)
-
+                             interpret, block_q, block_k, layout=None):
+    """``layout`` as :func:`_flash_attention_jit` takes it; token-major
+    ``o`` and ``do`` are ``[B, S, H]`` and so are dq, dk and dv."""
+    b, h, s, d = _dims(q, layout)
     span = _bwd_span(s, block_q, block_k)
-    head = lambda bh, kj: (bh, 0, 0)          # noqa: E731
-    keys = lambda bh, kj: (bh, kj, 0)         # noqa: E731
-    in_specs = [
-        pl.BlockSpec((1, s, d), head),
-        pl.BlockSpec((1, span, d), keys),
-        pl.BlockSpec((1, span, d), keys),
-        pl.BlockSpec((1, s, d), head),
-        pl.BlockSpec((1, 1, s), head),
-        pl.BlockSpec((1, 1, s), head),
-    ]
-    args = [qr, kr, vr, dor, lser, dr]
+
+    def row_sums(heads_shape):
+        # D = rowsum(dO * O): cheap XLA elementwise reduce. The row
+        # residuals travel as rows (S on lanes, where the transposed
+        # score tile wants them), not broadcast over lanes
+        return jnp.sum((do.astype(jnp.float32)
+                        * o.astype(jnp.float32)).reshape(heads_shape),
+                       axis=-1)
+
+    if layout is None:  # jit-ok: static argname
+        grid = (b * h, s // span)
+        args = [x.reshape(b * h, s, d) for x in (q, k, v, do)]
+        args += [lse.reshape(b * h, 1, s).astype(jnp.float32),
+                 row_sums((b, h, s, d)).reshape(b * h, 1, s)]
+        head = lambda bh, kj: (bh, 0, 0)          # noqa: E731
+        keys = lambda bh, kj: (bh, kj, 0)         # noqa: E731
+        in_specs = [pl.BlockSpec((1, s, d), head),
+                    pl.BlockSpec((1, span, d), keys),
+                    pl.BlockSpec((1, span, d), keys),
+                    pl.BlockSpec((1, s, d), head),
+                    pl.BlockSpec((1, 1, s), head),
+                    pl.BlockSpec((1, 1, s), head)]
+        # per KEY, so a column of the transposed tile: [B, S, 1]
+        mask_spec = pl.BlockSpec(
+            (1, span, 1), lambda bh, kj, _h=h: (bh // _h, kj, 0))
+        out_shape = [jax.ShapeDtypeStruct((b * h, s, d), x.dtype)
+                     for x in (q, k, v)]
+        out_specs = [pl.BlockSpec((1, s, d), head),
+                     pl.BlockSpec((1, span, d), keys),
+                     pl.BlockSpec((1, span, d), keys)]
+        per_block = 1
+    else:
+        w, per_block = layout.width, layout.per_block
+        tq, tk, tv = layout.tiles
+        grid = (b, layout.blocks, s // span)
+        args = [q, k, v, do, lse.reshape(b, h, 1, s).astype(jnp.float32),
+                row_sums((b, s, h, d)).transpose(0, 2, 1).reshape(
+                    b, h, 1, s)]
+
+        def head(tile):
+            return lambda bi, p, kj: (bi, 0, tile + p)
+
+        def keys(tile):
+            return lambda bi, p, kj: (bi, kj, tile + p)
+
+        resid = pl.BlockSpec((1, per_block, 1, s),
+                             lambda bi, p, kj: (bi, p, 0, 0))
+        in_specs = [pl.BlockSpec((1, s, w), head(tq)),
+                    pl.BlockSpec((1, span, w), keys(tk)),
+                    pl.BlockSpec((1, span, w), keys(tv)),
+                    pl.BlockSpec((1, s, w), head(0)), resid, resid]
+        mask_spec = pl.BlockSpec((1, span, 1),
+                                 lambda bi, p, kj: (bi, kj, 0))
+        out_shape = [jax.ShapeDtypeStruct((b, s, h * d), x.dtype)
+                     for x in (q, k, v)]
+        out_specs = [pl.BlockSpec((1, s, w), head(0)),
+                     pl.BlockSpec((1, span, w), keys(0)),
+                     pl.BlockSpec((1, span, w), keys(0))]
     body = functools.partial(_bwd_kernel, sm_scale=sm_scale,
                              block_q=block_q, block_k=block_k, span=span,
-                             seq_len=s, causal=causal)
+                             seq_len=s, causal=causal,
+                             head_dim=None if layout is None else d)
     if mask is not None:  # jit-ok: structural None-check, not a traced read
-        # per KEY, so a column of the transposed tile: [B, S, 1]
-        in_specs.append(pl.BlockSpec(
-            (1, span, 1), lambda bh, kj, _h=h: (bh // _h, kj, 0)))
+        in_specs.append(mask_spec)
         args.append(_mask_rows(mask, b, h, s).reshape(b, s, 1))
         kernel = body
     else:
@@ -628,44 +832,44 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
 
     dq, dk, dv = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, s, d), v.dtype)],
-        grid=(b * h, s // span),
+        out_shape=out_shape,
+        grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, s, d), head),
-                   pl.BlockSpec((1, span, d), keys),
-                   pl.BlockSpec((1, span, d), keys)],
-        # float32 sums across regions: dQ^T a head, dK and dV a program
-        scratch_shapes=[] if span == s else [
+        out_specs=out_specs,
+        # float32 sums across regions, a head: dQ^T over its programs,
+        # dK and dV over a program's walk
+        scratch_shapes=[] if span == s else per_block * [
             pltpu.VMEM((d, s), jnp.float32),
             pltpu.VMEM((span, d), jnp.float32),
             pltpu.VMEM((span, d), jnp.float32)],
         compiler_params=_backward_compiler_params(
-            s, d, span, block_q, block_k, q.dtype.itemsize),
+            s, d, span, block_q, block_k, q.dtype.itemsize, per_block,
+            len(grid)),
         interpret=interpret,
     )(*args)
+    if layout is not None:  # jit-ok: static argname
+        return dq, dk, dv
     shape = (b, h, s, d)
     return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape))
 
 
 def flash_attention_bwd(q, k, v, mask, o, lse, do, sm_scale=1.0,
-                        causal=False, interpret=None):
-    """(dq, dk, dv) via the fused recompute-form kernel. ``lse`` is the
-    forward's logsumexp (flash_attention_with_lse). Block sizes tune
-    independently of the forward's; with ``causal`` the tiles also
-    decide how much of the square the walk skips (``bwd_walk_counts``,
-    recorded here at trace time as a ``flash_bwd_walk`` instant)."""
-    if interpret is None:
-        interpret = INTERPRET
-    b, h, s, d = q.shape
-    block_q, block_k = _tuned_block_sizes(
-        "bwd", b, h, s, d, q.dtype, sm_scale, causal, mask is not None,
-        interpret)
+                        causal=False, interpret=None, layout=None,
+                        reason=None):
+    """(dq, dk, dv) via the fused recompute-form kernel, in the operand
+    form of q, k and v. ``lse`` is the forward's logsumexp
+    (flash_attention_with_lse). Block sizes tune independently of the
+    forward's; with ``causal`` the tiles also decide how much of the
+    square the walk skips (``bwd_walk_counts``, recorded here at trace
+    time as a ``flash_bwd_walk`` instant)."""
+    interpret, (block_q, block_k) = _plan(
+        "bwd", q, mask, sm_scale, causal, interpret, layout, reason)
+    s, d = _dims(q, layout)[2:]
     from .. import telemetry
     telemetry.get_telemetry().instant(
         "flash_bwd_walk", seq=s, head_dim=d, block_q=block_q,
         block_k=block_k, causal=bool(causal),
         **bwd_walk_counts(s, block_q, block_k, causal))
     return _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale,
-                                    causal, interpret, block_q, block_k)
+                                    causal, interpret, block_q, block_k,
+                                    *_form(layout))

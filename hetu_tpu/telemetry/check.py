@@ -191,6 +191,14 @@ SPAN_SCHEMA = {
                        "tiles_masked": _req(_INT),
                        "visited_share": _req(_NUM),
                        "masked_share": _req(_NUM)},
+    # the operand form a flash call runs in, at trace time (ops/
+    # pallas_attention.py:_plan): token_major reads q, k, v out of the
+    # projection's rows, heads_per_block heads a program; head_major
+    # names the first condition of ops/attention.py:flash_layout that
+    # kept it there (short_seq / lanes / mesh / caller)
+    "flash_layout": {"kernel": _req(_STR), "layout": _req(_STR),
+                     "heads_per_block": _req(_INT), "seq": _req(_INT),
+                     "head_dim": _req(_INT), "reason": _opt(_STR)},
 }
 
 

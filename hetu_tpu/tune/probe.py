@@ -46,7 +46,18 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
     Returns ``{"fwd_ms", "fwd_lse_ms", "bwd_ms", "blocks": {kind:
     (bq, bk)}, "bwd_walk": {...}}`` plus ``static_*`` twins measured
     with the untuned ``_block_sizes`` defaults when ``include_static``
-    (the in-repo tuned-vs-static evidence). ``bwd_walk`` is the one-pass
+    (the in-repo tuned-vs-static evidence). Those are the head-major
+    kernels over ``[B, H, S, D]``. ``flash_layout`` is what a caller
+    that hands this shape's packed qkv rows gets
+    (``ops/attention.py:flash_layout``: the operand form, the heads a
+    program owns, the reason when head-major); where the heads fill
+    whole lane blocks, ``token_major`` holds the same three kernels
+    over the packed ``[B, S, 3H]`` rows with their own tiles, and
+    ``layer_ms`` times ONE LAYER both ways from the same rows to the
+    same rows (context ``[B, S, H]`` and d(qkv) ``[B, S, 3H]``):
+    ``head_major`` with the split, the transposes and the merge XLA
+    runs around the kernels, ``token_major`` with the one
+    concatenation. ``bwd_walk`` is the one-pass
     backward's tile walk at its tiles (``pallas_attention.
     bwd_walk_counts``): with ``causal`` the share of the square it
     visits and the share of visited tiles that carry the mask. Uses the tuned path, so a cold autotune
@@ -86,12 +97,14 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
                      "causal": causal, "mask": has_mask},
            "blocks": {}}
 
-    tuned = {}
-    for kind in ("fwd", "fwd_lse", "bwd"):
-        tuned[kind] = pk._tuned_block_sizes(
+    def tuned_blocks(layout):
+        return {kind: pk._tuned_block_sizes(
             kind, batch, heads, seq, head_dim, dtype, sm_scale, causal,
-            has_mask, interpret)
-        out["blocks"][kind] = list(tuned[kind])
+            has_mask, interpret, layout)
+            for kind in ("fwd", "fwd_lse", "bwd")}
+
+    tuned = tuned_blocks(None)
+    out["blocks"] = {kind: list(b) for kind, b in tuned.items()}
     static = pk._block_sizes(seq, head_dim)
     # how far the backward's tile walk engages at the tiles it runs
     # with: tiles visited / tiles of the square, masked / visited
@@ -123,16 +136,76 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
         plan += [("static_fwd_ms", run_fwd(static, False), static),
                  ("static_fwd_lse_ms", run_fwd(static, True), static),
                  ("static_bwd_ms", run_bwd(static), static)]
+    from ..ops.attention import flash_layout
+    form, reason = flash_layout(seq, head_dim, heads, True)
+    packed = pk.TokenMajor.packed(heads, head_dim)
+    out["flash_layout"] = {
+        "layout": form, "reason": reason,
+        "heads_per_block": packed.per_block if reason is None else 1}
+    if packed.fits(seq):
+        rows = jnp.asarray(
+            rng.randn(batch, seq, 3 * heads * head_dim) * 0.3, dtype)
+        d_ctx = jnp.asarray(
+            rng.randn(batch, seq, heads * head_dim) * 0.3, dtype)
+        tm = tuned_blocks(packed)
+        out["token_major"] = {"blocks": {kind: list(b)
+                                         for kind, b in tm.items()}}
+
+        def tm_fwd(need_lse):
+            bq, bk = tm["fwd_lse" if need_lse else "fwd"]
+            return lambda: pk._flash_attention_jit(
+                rows, rows, rows, mask, sm_scale, causal, interpret, bq,
+                bk, need_lse, packed)
+
+        ctx, rows_lse = tm_fwd(True)()
+        plan += [("token_major.fwd_ms", tm_fwd(False), tm["fwd"]),
+                 ("token_major.fwd_lse_ms", tm_fwd(True), tm["fwd_lse"]),
+                 ("token_major.bwd_ms",
+                  lambda: pk._flash_attention_bwd_jit(
+                      rows, rows, rows, mask, ctx, rows_lse, d_ctx,
+                      sm_scale, causal, interpret, *tm["bwd"], packed),
+                  tm["bwd"])]
+
+        def layer(layout, blocks):
+            """rows -> (context rows, d(qkv) rows), one layer's two
+            flash calls with what XLA runs around them."""
+            def run(x, dy):
+                if layout is None:
+                    q_, k_, v_ = x.reshape(
+                        batch, seq, 3, heads, head_dim).transpose(
+                            2, 0, 3, 1, 4)
+                    dy = dy.reshape(batch, seq, heads,
+                                    head_dim).transpose(0, 2, 1, 3)
+                else:
+                    q_ = k_ = v_ = x
+                o_, l_ = pk._flash_attention_jit(
+                    q_, k_, v_, mask, sm_scale, causal, interpret,
+                    *blocks["fwd_lse"], True, layout)
+                grads = pk._flash_attention_bwd_jit(
+                    q_, k_, v_, mask, o_, l_, dy, sm_scale, causal,
+                    interpret, *blocks["bwd"], layout)
+                if layout is not None:
+                    return o_, jnp.concatenate(grads, axis=-1)
+                return (o_.transpose(0, 2, 1, 3).reshape(d_ctx.shape),
+                        jnp.stack(grads).transpose(1, 3, 0, 2, 4)
+                        .reshape(rows.shape))
+            jitted = jax.jit(run)
+            return lambda: jitted(rows, d_ctx)
+
+        plan += [("layer_ms.head_major", layer(None, tuned),
+                  tuned["bwd"]),
+                 ("layer_ms.token_major", layer(packed, tm), tm["bwd"])]
     for name, run, blocks in plan:
         t0 = tel.clock()
         wall0 = time.perf_counter()
         ms = timeit(run, sync, reps=reps, windows=2) * 1000
-        out[name] = round(ms, 4)
+        group, _, leaf = name.rpartition(".")
+        (out.setdefault(group, {}) if group else out)[leaf] = round(ms, 4)
         if tel.enabled:
             tel.complete(
                 "attn_probe", t0,
                 t0 + int((time.perf_counter() - wall0) * 1e9),
-                args={"kernel": name[:-3], "ms": out[name],
+                args={"kernel": name[:-3], "ms": round(ms, 4),
                       "blocks": str(tuple(blocks)), "seq": seq,
                       "head_dim": head_dim, "dtype": dtype.name})
     return out

@@ -203,6 +203,62 @@ def test_dropout_mask_kernel_compiles(one_chip, shape):
     assert " fusion(" not in entry
 
 
+def _compile_token_major(kind, blocks, sharding, d=64):
+    """The GPT-2 train cell's attention (batch 16, S 1024, 768 lanes of
+    heads, causal) over the packed ``[B, S, 3H]`` rows."""
+    b, s, lanes = 16, 1024, 768
+    layout = pk.TokenMajor.packed(lanes // d, d)
+    rows = jax.ShapeDtypeStruct((b, s, 3 * lanes), jnp.bfloat16,
+                                sharding=sharding)
+    ctx = jax.ShapeDtypeStruct((b, s, lanes), jnp.bfloat16,
+                               sharding=sharding)
+    if kind == "bwd":
+        lse = jax.ShapeDtypeStruct((b, lanes // d, 1, s), jnp.float32,
+                                   sharding=sharding)
+        lowered = pk._flash_attention_bwd_jit.lower(
+            rows, rows, rows, None, ctx, lse, ctx, 0.125, True, False,
+            *blocks, layout)
+    else:
+        lowered = pk._flash_attention_jit.lower(
+            rows, rows, rows, None, 0.125, True, False, *blocks,
+            kind == "fwd_lse", layout)
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 256), (256, 512),
+                                    (1024, 1024)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("kind", KINDS)
+def test_token_major_kernels_compile_at_the_train_cell(one_chip, kind,
+                                                       blocks):
+    """Two heads a lane tile split by static lane windows (loads and
+    stores at lane 64), the logsumexp written as ``[B, H, 1, S]`` rows:
+    Mosaic takes it at the tiles the sweep may pick, as ONE custom call
+    under the name the trace's readers match, and the operands are the
+    packed rows themselves — no copy feeds the call."""
+    text = _compile_token_major(kind, blocks, one_chip)
+    entry = text[text.index("ENTRY"):]
+    calls = [ln for ln in entry.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    name = "_flash_attention_bwd_jit" if kind == "bwd" \
+        else "_flash_attention_jit"
+    assert calls[0].lstrip().removeprefix("ROOT ").startswith("%" + name)
+    assert "bf16[16,1024,768]" in calls[0]
+    moved = [ln for ln in entry.splitlines()
+             if " copy(" in ln or " transpose(" in ln]
+    assert not any("[16,1024,2304]" in ln for ln in moved), moved
+    if kind != "bwd":   # (the backward's D row sum may re-lay o and dO)
+        assert not moved, moved
+
+
+def test_token_major_kernels_compile_at_one_head_a_block(one_chip):
+    """D = 128: a lane block is one head, no window inside it."""
+    for kind in KINDS:
+        text = _compile_token_major(kind, (256, 256), one_chip, d=128)
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 def _gpt2_step_text(v5e_device, monkeypatch, dropout):
     """The optimized HLO of a GPT-2 training step (the cell's widths,
     batch and context; one layer and a vocabulary of 1024, to keep the
@@ -255,6 +311,26 @@ def test_gpt2_step_holds_the_dropout_mask_kernel(v5e, monkeypatch):
     assert "bernoulli" not in text and "rng-bit-generator" not in text
     off = _gpt2_step_text(v5e[0], monkeypatch, 0.0)
     assert "hetu_dropout_mask" not in off
+
+
+def test_gpt2_step_reads_the_qkv_rows_where_they_lie(v5e, monkeypatch):
+    """The step a train cell compiles: both flash calls take the qkv
+    projection's result (a bitcast of it) and write rows, so none of
+    the relayouts that stood around the head-major calls is left in the
+    HLO — no ``[B, H, S, D]`` array of q, k, v, the context or a
+    gradient, no lane-tiled float32 logsumexp — and the two calls keep
+    the names the trace's readers match."""
+    text = _gpt2_step_text(v5e[0], monkeypatch, 0.1)
+    for shape in ("bf16[1,16,12,1024,64]", "bf16[16,12,1024,64]",
+                  "bf16[16,1024,3,12,64]", "bf16[3,16,12,1024,64]",
+                  "bf16[192,1024,64]", "f32[192,1024,128]"):
+        assert shape not in text, shape
+    calls = [ln.strip() for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "_flash_attention" in ln.split("=")[0]]
+    assert sorted(c.split(" ")[0].rstrip(".0123456789") for c in calls) \
+        == ["%_flash_attention_bwd_jit", "%_flash_attention_jit"]
+    assert all("f32[16,12,1,1024]" in c for c in calls)   # residual rows
 
 
 def test_dropout_keeps_the_composed_draw_under_a_dp_mesh(v5e, monkeypatch):
