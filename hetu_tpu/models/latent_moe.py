@@ -6,7 +6,20 @@ low-rank latent row a token plus one rotated key all heads share),
 YaRN-scaled rotary position on part of each head, SwiGLU feed-forward,
 a few leading dense layers and then expert layers (a sigmoid router
 with a selection-only bias over all experts, ``top_k`` routed experts
-and shared experts a token), untied head.
+and shared experts a token), untied head. Two things are
+configuration, not forks:
+
+* the QUERY is either one projection with an RMS norm over each head
+  (``q_lora_rank=None, qk_norm=True``: ``sarvam_mla``) or a low-rank
+  pair with an RMS norm over the rank between them and none a head
+  (``q_lora_rank=r, qk_norm=False``: ``h q_a -> norm -> q_b``);
+* the RESIDUAL is either one stream, ``x + F(norm(x))``, or
+  (``hyper_connections``) ``n`` streams a token that every sublayer
+  reads through one learned mix and writes back through two more
+  (``ops/mhc.py``: manifold-constrained hyper-connections; the streams
+  start as ``n`` copies of the embedding and are summed before the
+  final norm). The stream lives inside a program: nothing of it is
+  cached, so the cache, the scheduler and a replay know nothing of it.
 
 This module is the serving side only: ONE forward
 (:func:`_serve_forward`) behind the same three cache backends as
@@ -38,13 +51,17 @@ __all__ = ["LatentMoEConfig", "LatentMoEServingModel",
            "latent_moe_param_shapes", "latent_moe_serving_params",
            "latent_moe_forward", "latent_moe_paged_prefill",
            "latent_moe_paged_step", "latent_moe_paged_suffix_prefill",
-           "yarn_inv_freq", "yarn_mscale", "COUNTERS"]
+           "yarn_inv_freq", "yarn_mscale", "COUNTERS", "MHC_COUNTERS"]
 
 # what every program returns beside its tokens or logits, int32, in
 # this order, followed by the rows of each held expert and then, for
 # each row of the batch, its record (``_records``)
 COUNTERS = ("moe_tokens", "moe_routed_rows", "moe_expert_visits",
             "mla_context_rows", "mla_score_pairs")
+# ... and, of a model with hyper-connections, behind those: real tokens
+# x sublayers (two a layer) whose streams the program mixed
+MHC_COUNTERS = ("mhc_rows",)
+_HC_KEYS = ("streams", "sinkhorn_iters", "eps", "clamp")
 
 
 class LatentMoEConfig:
@@ -60,7 +77,8 @@ class LatentMoEConfig:
                  first_k_dense_replace=1, experts_held=None,
                  routed_scaling_factor=1.0, rms_norm_eps=1e-6,
                  rope_theta=10000.0, rope_scaling=None,
-                 max_position_embeddings=131072, dtype="bfloat16"):
+                 max_position_embeddings=131072, dtype="bfloat16",
+                 q_lora_rank=None, qk_norm=True, hyper_connections=None):
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
@@ -87,6 +105,20 @@ class LatentMoEConfig:
         self.rope_scaling = dict(rope_scaling) if rope_scaling else None
         self.max_position_embeddings = max_position_embeddings
         self.dtype = dtype
+        self.q_lora_rank = q_lora_rank
+        self.qk_norm = bool(qk_norm)
+        if hyper_connections is not None:
+            if sorted(hyper_connections) != sorted(_HC_KEYS):
+                raise ValueError(
+                    f"hyper_connections takes {_HC_KEYS}, got "
+                    f"{sorted(hyper_connections)}")
+            hyper_connections = {
+                "streams": int(hyper_connections["streams"]),
+                "sinkhorn_iters": int(hyper_connections["sinkhorn_iters"]),
+                "eps": float(hyper_connections["eps"]),
+                "clamp": tuple(float(v)
+                               for v in hyper_connections["clamp"])}
+        self.hyper_connections = hyper_connections
 
     @property
     def q_head_dim(self):
@@ -103,6 +135,12 @@ class LatentMoEConfig:
         back: 1.24 s of a 4 s window went to those copies (my chip
         run, PR 32)."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def streams(self):
+        """Residual streams a token: 1 without hyper-connections."""
+        hc = self.hyper_connections
+        return hc["streams"] if hc else 1
 
     def is_dense(self, layer):
         return layer < self.first_k_dense_replace
@@ -199,7 +237,9 @@ def _rms(x, weight, eps):
 def latent_moe_param_shapes(config):
     """``{name: (shape, kind)}`` of every serving parameter; ``kind``
     is ``"matrix"`` (in the model's dtype), ``"norm"`` (float32 ones at
-    initialisation), ``"router"`` or ``"router_bias"`` (float32)."""
+    initialisation), ``"router"`` or ``"router_bias"`` (float32) and,
+    with hyper-connections, each sublayer's ``"hc_phi"``, ``"hc_scale"``
+    and ``"hc_bias"`` (float32; ``ops/mhc.py:maps``)."""
     c = config
     h, nh = c.hidden_size, c.num_attention_heads
     held = c.experts_held[1]
@@ -208,10 +248,23 @@ def latent_moe_param_shapes(config):
            "lm_head": ((h, c.vocab_size), "matrix")}
     for i in range(c.num_hidden_layers):
         p = f"lm_h{i}_"
+        out[p + "attn_norm"] = ((h,), "norm")
+        if c.q_lora_rank:
+            out[p + "q_a"] = ((h, c.q_lora_rank), "matrix")
+            out[p + "q_a_norm"] = ((c.q_lora_rank,), "norm")
+            out[p + "q_b"] = ((c.q_lora_rank, nh * c.q_head_dim), "matrix")
+        else:
+            out[p + "q"] = ((h, nh * c.q_head_dim), "matrix")
+        if c.qk_norm:
+            out[p + "q_norm"] = ((c.q_head_dim,), "norm")
+        if c.hyper_connections:
+            from ..ops.mhc import map_width
+            width = map_width(c.streams)
+            for sub in ("attn", "ffn"):
+                out[p + f"hc_{sub}_phi"] = ((c.streams * h, width), "hc_phi")
+                out[p + f"hc_{sub}_scale"] = ((3,), "hc_scale")
+                out[p + f"hc_{sub}_bias"] = ((width,), "hc_bias")
         out.update({
-            p + "attn_norm": ((h,), "norm"),
-            p + "q": ((h, nh * c.q_head_dim), "matrix"),
-            p + "q_norm": ((c.q_head_dim,), "norm"),
             p + "kv_a": ((h, c.kv_lora_rank + c.qk_rope_head_dim),
                          "matrix"),
             p + "kv_norm": ((c.kv_lora_rank,), "norm"),
@@ -241,7 +294,10 @@ def latent_moe_param_shapes(config):
 def latent_moe_serving_params(config, lookup):
     """The serving block's parameter pytree from ``lookup(name)``.
     Matrices are taken in the model's dtype (an array that already has
-    it is not copied), norms and the router in float32."""
+    it is not copied), norms, the router and the hyper-connections'
+    maps in float32. A sublayer's maps become one group ``hc_attn`` /
+    ``hc_ffn`` (``{"phi", "scale", "bias"}`` and, where the kernels of
+    ``ops/mhc.py`` run, ``"kernel"``: their prepared form)."""
     import jax.numpy as jnp
     dtype = jnp.dtype(config.dtype)
 
@@ -254,8 +310,17 @@ def latent_moe_serving_params(config, lookup):
     blocks = []
     for i in range(config.num_hidden_layers):
         p = f"lm_h{i}_"
-        blocks.append({k[len(p):]: v for k, v in flat.items()
-                       if k.startswith(p)})
+        blk = {k[len(p):]: v for k, v in flat.items() if k.startswith(p)}
+        if config.hyper_connections:
+            from ..ops import mhc
+            for sub in ("attn", "ffn"):
+                maps = {k: blk.pop(f"hc_{sub}_{k}")
+                        for k in ("phi", "scale", "bias")}
+                if mhc.wants_prepared(config.streams, config.hidden_size,
+                                      dtype):
+                    maps["kernel"] = mhc.prepare(**maps)
+                blk["hc_" + sub] = maps
+        blocks.append(blk)
     return {"embed": flat["lm_embed"], "norm": flat["lm_norm"],
             "head": flat["lm_head"], "blocks": blocks}
 
@@ -332,34 +397,79 @@ def _serve_forward(params, config, x, positions, attend, valid):
     cos, sin = _rope_tables(c, positions)
     pad = c.cache_row_width - latent - c.qk_rope_head_dim
     flat_valid = valid.reshape(-1)
+    read, write, leave = _residual(c, lead)
+    if c.hyper_connections:     # n copies of the embedding, token-major
+        x = jnp.broadcast_to(x.reshape(-1, 1, x.shape[-1]),
+                             (flat_valid.shape[0], c.streams, x.shape[-1]))
     rows_total = jnp.zeros(c.experts_held[1], jnp.int32)
     visits = jnp.int32(0)
     picks = []
     for i, blk in enumerate(params["blocks"]):
-        h = _rms(x, blk["attn_norm"], c.rms_norm_eps)
+        h, carry = read(x, blk, "hc_attn")
+        h = _rms(h, blk["attn_norm"], c.rms_norm_eps)
         kv = h @ blk["kv_a"]
         row = jnp.concatenate(
             [_rms(kv[..., :latent], blk["kv_norm"], c.rms_norm_eps),
              _rope(kv[..., latent:], cos, sin),
              jnp.zeros((*lead, pad), kv.dtype)], axis=-1)
-        q = _rms((h @ blk["q"]).reshape(*lead, nh, c.q_head_dim),
-                 blk["q_norm"], c.rms_norm_eps)
+        if "q_a" in blk:
+            q = _rms(h @ blk["q_a"], blk["q_a_norm"], c.rms_norm_eps) \
+                @ blk["q_b"]
+        else:
+            q = h @ blk["q"]
+        q = q.reshape(*lead, nh, c.q_head_dim)
+        if "q_norm" in blk:
+            q = _rms(q, blk["q_norm"], c.rms_norm_eps)
         q = jnp.concatenate(
             [q[..., :nope], _rope(q[..., nope:], cos[..., None, :],
                                   sin[..., None, :])], axis=-1)
         ctx = attend(i, blk, q, row).astype(x.dtype)
-        x = x + ctx.reshape(*lead, nh * c.v_head_dim) @ blk["o"]
-        h = _rms(x, blk["ffn_norm"], c.rms_norm_eps)
+        x = write(x, ctx.reshape(*lead, nh * c.v_head_dim) @ blk["o"],
+                  carry)
+        h, carry = read(x, blk, "hc_ffn")
+        h = _rms(h, blk["ffn_norm"], c.rms_norm_eps)
         y, picked, (rows, seen) = _token_chunks(
             lambda xc, vc, blk=blk: _feed_forward(c, blk, xc, vc),
             h.reshape(-1, h.shape[-1]), flat_valid)
-        x = x + y.reshape(x.shape)
+        x = write(x, y.reshape(h.shape), carry)
         rows_total, visits = rows_total + rows, visits + seen
         if not c.is_dense(i):
             picks.append(picked.reshape(*lead, -1))
     picks = jnp.stack(picks, axis=-2) if picks else jnp.zeros(
         (*lead, 0, c.num_experts_per_tok), jnp.int32)
-    return _rms(x, params["norm"], c.rms_norm_eps), picks, rows_total, visits
+    return (_rms(leave(x), params["norm"], c.rms_norm_eps), picks,
+            rows_total, visits)
+
+
+def _residual(config, lead):
+    """The residual path around a sublayer, as three functions:
+    ``read(x, blk, name) -> (what the sublayer reads [*lead, H],
+    carry)``, ``write(x, y [*lead, H], carry) -> x'`` and ``leave(x) ->
+    [*lead, H]`` before the final norm. One stream: ``x`` itself, ``x +
+    y``, ``x``. Hyper-connections: ``x`` is ``[tokens, n, H]``, read
+    and written through ``blk[name]``'s maps (``ops/mhc.py``), and the
+    streams are summed (in float32) on the way out."""
+    hc = config.hyper_connections
+    if not hc:
+        return (lambda x, blk, name: (x, None),
+                lambda x, y, carry: x + y,
+                lambda x: x)
+    import jax.numpy as jnp
+    from ..ops import mhc
+
+    def read(x, blk, name):
+        u, carry = mhc.mhc_pre(x, blk[name], hc["sinkhorn_iters"],
+                               hc["eps"], hc["clamp"])
+        return u.reshape(*lead, u.shape[-1]), carry
+
+    def write(x, y, carry):
+        return mhc.mhc_post(x, y.reshape(-1, y.shape[-1]), carry)
+
+    def leave(x):
+        return jnp.sum(x.astype(jnp.float32), axis=1).astype(
+            x.dtype).reshape(*lead, x.shape[-1])
+
+    return read, write, leave
 
 
 def _kv_b(config, blk):
@@ -454,17 +564,22 @@ def _records(picks, logits):
 
 def _counters(config, valid, rows, visits, context_rows, score_pairs,
               records):
-    """The int32 vector every program returns (``COUNTERS``, then the
-    rows of each held expert, of the REAL tokens of this call; then
-    each batch row's record, row after row)."""
+    """The int32 vector every program returns (``COUNTERS``, with
+    hyper-connections ``MHC_COUNTERS``, then the rows of each held
+    expert, of the REAL tokens of this call; then each batch row's
+    record, row after row)."""
     import jax.numpy as jnp
     c = config
     moe_layers = c.num_hidden_layers - c.first_k_dense_replace
-    head = jnp.stack([
+    head = [
         jnp.sum(valid).astype(jnp.int32) * moe_layers,
         jnp.sum(rows), visits,
         context_rows.astype(jnp.int32) * c.num_hidden_layers,
-        score_pairs.astype(jnp.int32) * c.num_hidden_layers])
+        score_pairs.astype(jnp.int32) * c.num_hidden_layers]
+    if c.hyper_connections:     # MHC_COUNTERS
+        head.append(jnp.sum(valid).astype(jnp.int32)
+                    * 2 * c.num_hidden_layers)
+    head = jnp.stack(head)
     return jnp.concatenate(
         [head, rows, records.reshape(-1)]).astype(jnp.int32)
 
@@ -607,7 +722,8 @@ class LatentMoEServingModel:
         self.vocab_size = config.vocab_size
         self.max_positions = config.max_position_embeddings
         self.num_cache_layers = config.num_hidden_layers
-        self.counter_names = COUNTERS
+        self.counter_names = COUNTERS + (
+            MHC_COUNTERS if config.hyper_connections else ())
         self.vector_counter = ("moe_rows_by_expert",
                                config.experts_held[1])
         # int32 words a batch row's record takes behind the counters
@@ -639,22 +755,37 @@ class LatentMoEServingModel:
         return jnp.dtype(self.config.dtype).itemsize
 
     def param_bytes(self):
-        """Matrices in the model's dtype; norms and the router float32."""
-        return int(sum(
+        """Matrices in the model's dtype; norms, the router and the
+        hyper-connections' maps float32, and beside each sublayer's
+        maps what the kernels hold of them prepared
+        (``ops/mhc.py:prepare``)."""
+        c = self.config
+        held = sum(
             int(np.prod(shape)) * (self._itemsize if kind == "matrix" else 4)
-            for shape, kind in
-            latent_moe_param_shapes(self.config).values()))
+            for shape, kind in latent_moe_param_shapes(c).values())
+        if c.hyper_connections:
+            from ..ops import mhc
+            if mhc.wants_prepared(c.streams, c.hidden_size, c.dtype):
+                held += 2 * c.num_hidden_layers * mhc.prepared_bytes(
+                    c.streams, c.hidden_size)
+        return int(held)
 
     def prefill_bytes_per_token(self):
         """Bytes of temporaries one prompt token costs a prefill
         program at its widest point, the expanded attention: the
         queries, keys and padded values, each token-major and
-        head-major, the up-projection's output and the context, and a
-        few float32 rows of the residual stream."""
+        head-major, the up-projection's output and the context, six
+        float32 rows of what a sublayer reads and writes (``hidden``
+        wide whatever the residual path) and the residual state itself
+        as it enters and leaves a sublayer: ``streams`` rows in the
+        model's dtype each way (one stream: the two rows are among the
+        six)."""
         c = self.config
         per_head = 7 * c.q_head_dim + c.qk_nope_head_dim + c.v_head_dim
+        state = 2 * c.streams * c.hidden_size * self._itemsize \
+            if c.hyper_connections else 0
         return (c.num_attention_heads * per_head * self._itemsize
-                + 6 * c.hidden_size * 4)
+                + 6 * c.hidden_size * 4 + state)
 
     def program(self, kind):
         """``(function, static keywords)`` of one of the engine's four

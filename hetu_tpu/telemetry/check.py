@@ -199,6 +199,13 @@ SPAN_SCHEMA = {
     "flash_layout": {"kernel": _req(_STR), "layout": _req(_STR),
                      "heads_per_block": _req(_INT), "seq": _req(_INT),
                      "head_dim": _req(_INT), "reason": _opt(_STR)},
+    # the form a hyper-connections sublayer's residual path runs in,
+    # at trace time (ops/mhc.py:_plan): kernel (the two Pallas kernels
+    # hetu_mhc_pre / hetu_mhc_post) or composed, which names the first
+    # condition of ops/mhc.py:supported that failed (dtype / lanes /
+    # streams), or platform off a TPU
+    "mhc_plan": {"streams": _req(_INT), "iters": _req(_INT),
+                 "form": _req(_STR), "reason": _opt(_STR)},
 }
 
 

@@ -5,6 +5,12 @@ a tiny preset: hidden 64, 4 heads, latent 32, nope 16 / rope 8 / value
 16, 8 routed experts, 2 a token, 1 shared, 1 dense + 2 expert layers.
 CPU, seeded weights; the kernels' own tiles are tried by the compile
 tests at the bottom and on the chip by ``chip_smoke.py``.
+
+The block's two query forms and two residual forms
+(``LatentMoEConfig.q_lora_rank`` / ``qk_norm`` /
+``hyper_connections``) are held to their references side by side:
+``SHAPES`` is {sarvam-shaped, query LoRA + four residual streams}, the
+second against ``benchmark/reference/xing_mhc.py``.
 """
 import os
 
@@ -17,6 +23,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from benchmark.families import sarvam_mla as family  # noqa: E402
+from benchmark.families import xing_mhc as xing_family  # noqa: E402
 from benchmark.reference import sarvam_mla as reference  # noqa: E402
 from hetu_tpu.models import latent_moe as lm  # noqa: E402
 from hetu_tpu.ops import moe  # noqa: E402
@@ -44,6 +51,36 @@ def tiny(dtype="float32", held=(0, 8), layers=3):
         "assumed": {"initializer_std": 0.2, "router_bias_std": 0.05}}
 
 
+def tiny_xing(dtype="float32", layers=4, hidden=64):
+    """The other shape of the block: a query LoRA with no norm a head,
+    four residual streams, 2 dense + 2 expert layers, every expert
+    held (``configs/xing4.0-29b-a4b-stage.json``'s keys)."""
+    return {
+        "family": "xing_mhc", "vocab_size": 96, "hidden_size": hidden,
+        "num_hidden_layers": layers, "num_attention_heads": 4,
+        "kv_lora_rank": 32, "q_lora_rank": 24, "qk_nope_head_dim": 16,
+        "qk_rope_head_dim": 8, "v_head_dim": 16, "intermediate_size": 128,
+        "moe_intermediate_size": 32, "n_routed_experts": 8,
+        "n_shared_experts": 1, "num_experts": 8, "num_shared_experts": 1,
+        "num_experts_per_tok": 2, "first_k_dense_replace": 2,
+        "routed_scaling_factor": 2, "rms_norm_eps": 1e-6,
+        "rope_theta": 10000, "rope_scaling": dict(YARN, factor=64,
+                                                  type="yarn"),
+        "max_position_embeddings": 4096, "serve_dtype": dtype,
+        "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+        "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30,
+        "deployment": {"num_routed_experts": 8, "experts_first": 0,
+                       "experts_held": 8},
+        "assumed": {"initializer_std": 0.2, "router_bias_std": 0.05,
+                    "hc_phi_std": 0.05, "hc_scale_init": 0.5,
+                    "hc_bias_std": 0.5, "hc_res_diag": 2.0}}
+
+
+# shape -> (a configuration file's content, its family)
+SHAPES = {"sarvam": (tiny, family), "lora_4_streams": (tiny_xing,
+                                                       xing_family)}
+
+
 @pytest.fixture(scope="module")
 def f32():
     config = tiny()
@@ -53,7 +90,8 @@ def f32():
 def engine_for(config, weights, **kw):
     kw = dict(dict(num_blocks=48, block_size=4, max_len=64,
                    max_batch_size=4, start=False, telemetry=False), **kw)
-    return ContinuousBatchingEngine(family.model_config(config),
+    fam = xing_family if config["family"] == "xing_mhc" else family
+    return ContinuousBatchingEngine(fam.model_config(config),
                                     weights.__getitem__, **kw)
 
 
@@ -124,6 +162,168 @@ def test_prefill_then_decode_through_the_latent_cache(dtype, limit):
         for i, layer in enumerate(layers):
             assert set(record["router_picks"][0, i]) == \
                 set(layer["experts"][0])
+
+
+def _reference_logits(fam, weights, config, tokens, rows):
+    """``(logits [len(rows), V], clear [len(rows)])`` of the family's
+    plain reference: ``clear`` where every router call of the row is
+    further from a tie than rounding moves it."""
+    out = fam.reference.forward(weights, config, tokens, rows)
+    clear = np.min([layer["margin"] for layer in out[1]], axis=0) > 1e-4
+    return out[0], clear
+
+
+@pytest.mark.parametrize("path", ["paged_decode", "suffix_prefill",
+                                  "preempted_and_replayed"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_both_shapes_agree_with_their_reference_on_logits(shape, path):
+    """Prefill then decode through the paged cache, a suffix prefill
+    behind it, and a request preempted and replayed by the engine: each
+    against the reference's FULL forward, on logits, in float32 (the
+    two are then one function up to the order of additions). The
+    residual streams live inside a program: nothing of them is cached,
+    and the cache, the scheduler and the replay are the same code for
+    both shapes."""
+    make, fam = SHAPES[shape]
+    config = make()
+    weights = fam.seeded_weights(config, 11)
+    cfg = fam.model_config(config)
+    rng = np.random.RandomState(3)
+    if path == "preempted_and_replayed":
+        from hetu_tpu.telemetry import Telemetry
+        tel = Telemetry(enabled=True)
+        engine = engine_for(config, weights, num_blocks=7, reserve="lazy",
+                            telemetry=tel)
+        prompts = prompts_of(rng, [6, 9, 4, 7])
+        futures = [engine.submit(p, 8) for p in prompts]
+        while not all(f.done() for f in futures):
+            engine.step()
+        assert tel.counter_value("engine_preemptions") > 0, \
+            "the 7-block pool never preempted: the case lost its point"
+        replayed = [f for f in futures if f.account["replay"] > 0]
+        assert replayed
+        model = engine.model
+        for prompt, f in zip(prompts, futures):
+            out = f.result(timeout=0)
+            readings = fam.forced_readings(
+                config, weights, prompt, out,
+                model.read_records(f.token_records))[0]
+            assert readings["gap"].max() == 0.0
+            assert readings["value"].max() < 1e-4
+            assert readings["pick_distance"].max() < 1e-5
+        assert engine.cache.referenced_blocks == 0
+        if shape == "lora_4_streams":
+            # replayed tokens went through the streams a second time
+            s = engine.stats()
+            assert s["prefill_mhc_rows"] > 8 * sum(len(p) for p in prompts)
+            # the counter rides in stats, in every program's own row
+            # and, telemetry being on, in the ring's counters
+            log = list(engine.program_log)
+            assert sum(r["decode_mhc_rows"] for r in log
+                       if r["kind"] == "decode") == s["decode_mhc_rows"] \
+                == tel.counter_value("engine_decode_mhc_rows") > 0
+            assert sum(r["prefill_mhc_rows"] for r in log
+                       if r["kind"] == "prefill") == s["prefill_mhc_rows"]
+            # in float32 even the exit's near-hidden fault is told (the
+            # first stream alone for the sum of all: the final norm
+            # hides most of it, reference/xing_mhc.py BEHIND_THE_NORM),
+            # while the mean for the sum is gone entirely
+            record = model.read_records(futures[0].token_records)
+            out = futures[0].result(timeout=0)
+            one = fam.forced_readings(config, weights, prompts[0], out,
+                                      record, "exit_first_stream")[0]
+            assert one["value"].max() > 1e-2
+            mean = fam.forced_readings(config, weights, prompts[0], out,
+                                       record, "exit_mean")[0]
+            assert mean["value"].max() < 1e-4
+        return
+    params = lm.latent_moe_serving_params(cfg, weights.__getitem__)
+    cache = PagedKVCache(cfg, num_blocks=16, block_size=4)
+    tokens = rng.randint(0, 96, 21).astype(np.int32)
+    p = 13
+    cache.add_seq(0, len(tokens))
+    (logits, counted), pools = jax.jit(
+        lm.latent_moe_paged_prefill, static_argnames="config")(
+        params, cache.pools, jnp.asarray(tokens[None, :p]),
+        jnp.asarray(cache.slot_mapping(0, 0, p)[None]),
+        jnp.asarray([p - 1]), config=cfg)
+    grid = jnp.asarray(cache.gather_slots([0], 24))
+    if path == "suffix_prefill":
+        (chunk, _), pools = lm.latent_moe_paged_suffix_prefill(
+            params, pools, jnp.asarray(tokens[None, p:p + 5]),
+            jnp.asarray([p]), grid,
+            jnp.asarray(cache.slot_mapping(0, p, p + 5)[None]), config=cfg)
+        got, rows = np.asarray(chunk)[0], np.arange(p, p + 5)
+    else:
+        got = [np.asarray(logits[0])]
+        step = jax.jit(lm.latent_moe_paged_step, static_argnames="config")
+        for pos in range(p, len(tokens)):
+            (logits, _), pools = step(
+                params, pools, jnp.asarray(tokens[pos:pos + 1]),
+                jnp.asarray([pos]), grid,
+                jnp.asarray([cache.slot_of(0, pos)]), config=cfg)
+            got.append(np.asarray(logits[0]))
+        got, rows = np.asarray(got), np.arange(p - 1, len(tokens))
+    want, clear = _reference_logits(fam, weights, config, tokens, rows)
+    assert clear.sum() >= 3
+    err = np.sqrt(np.mean(np.square(got - want), axis=-1)) / want.std()
+    assert err[clear].max() < 1e-4, (err, want.std())
+    counted = np.asarray(counted)
+    names = cfg.serving_model().counter_names
+    assert ("mhc_rows" in names) == (shape == "lora_4_streams")
+    if shape == "lora_4_streams":
+        # 13 real tokens x 2 sublayers x 4 layers
+        assert counted[names.index("mhc_rows")] == 13 * 2 * 4
+
+
+def test_one_stream_with_identity_maps_is_not_the_plain_residual():
+    """``n = 1`` is NOT asked to equal ``x + F(norm(x))``, and does
+    not: with ``b_res`` anything a 1 x 1 ``Hres`` is 1 after the first
+    Sinkhorn row, but ``Hpre = sigmoid(.)`` and ``Hpost = 2
+    sigmoid(.)`` scale what the sublayer reads and writes."""
+    from hetu_tpu.ops import mhc
+    x = jnp.asarray(np.random.RandomState(0).randn(6, 1, 64), jnp.float32)
+    y = jnp.asarray(np.random.RandomState(1).randn(6, 64), jnp.float32)
+    maps = {"phi": jnp.zeros((64, 3)), "scale": jnp.ones(3),
+            "bias": jnp.zeros(3)}
+    u, carry = mhc.mhc_pre(x, maps, 20, 1e-6, (-30.0, 30.0))
+    out = mhc.mhc_post(x, y, carry)
+    np.testing.assert_allclose(np.asarray(carry[1]), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(u), 0.5 * np.asarray(x[:, 0]),
+                               rtol=1e-6)        # sigmoid(0) x
+    np.testing.assert_allclose(np.asarray(out[:, 0]),
+                               np.asarray(x[:, 0] + y), rtol=1e-5)
+    assert np.abs(np.asarray(u) - np.asarray(x[:, 0])).max() > 0.1
+
+
+def test_shapes_bytes_and_the_token_cap_follow_the_streams():
+    config = tiny_xing("bfloat16")
+    cfg = xing_family.model_config(config)
+    shapes = lm.latent_moe_param_shapes(cfg)
+    assert shapes["lm_h0_q_a"] == ((64, 24), "matrix")
+    assert shapes["lm_h0_q_a_norm"] == ((24,), "norm")
+    assert shapes["lm_h0_q_b"] == ((24, 4 * 24), "matrix")
+    assert "lm_h0_q" not in shapes and "lm_h0_q_norm" not in shapes
+    assert shapes["lm_h3_hc_ffn_phi"] == ((4 * 64, 24), "hc_phi")
+    assert shapes["lm_h3_hc_attn_bias"] == ((24,), "hc_bias")
+    plain = family.model_config(tiny("bfloat16"))
+    assert "lm_h0_q_norm" in lm.latent_moe_param_shapes(plain)
+    assert not any("hc_" in k for k in lm.latent_moe_param_shapes(plain))
+    model, one = cfg.serving_model(), plain.serving_model()
+    assert model.param_bytes() == sum(
+        int(np.prod(s)) * (2 if kind == "matrix" else 4)
+        for s, kind in shapes.values())
+    # the state as it enters and leaves a sublayer: 2 x 4 x 64 x 2
+    # bytes beside what one stream costs at the same widths
+    assert cfg.streams == 4 and plain.streams == 1
+    assert model.prefill_bytes_per_token() \
+        == one.prefill_bytes_per_token() + 2 * 4 * 64 * 2
+    assert model.counter_names == lm.COUNTERS + lm.MHC_COUNTERS
+    assert one.counter_names == lm.COUNTERS
+    with pytest.raises(ValueError):
+        lm.LatentMoEConfig(
+            96, 64, 2, 4, 32, 16, 8, 16, 128, 32, 8, 2,
+            hyper_connections={"streams": 4})
 
 
 def test_absorbed_attention_equals_expanded_for_one_layer(f32):
@@ -516,6 +716,29 @@ def test_the_absorbed_decode_kernel_compiles_under_its_name(
             struct((batch, context, 640), jnp.bfloat16),
             struct((batch,), jnp.int32)).compile()
     assert pallas_mla.KERNEL_NAME in _names_of_custom_calls(compiled)
+
+
+@pytest.mark.parametrize("rows", [1, 8, 32, 4096])
+def test_the_residual_kernels_compile_under_their_names(one_chip, rows):
+    """``hetu_mhc_pre`` / ``hetu_mhc_post`` at the published stream (4
+    x 3584, bfloat16): every decode batch bucket's shape class and a
+    prefill's row tiles."""
+    from hetu_tpu.ops import mhc
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, c = 4, 3584
+    assert mhc.supported(n, c, jnp.bfloat16) is None
+    pre = mhc._jitted_pre(n, 20, 1e-6, (-30.0, 30.0), False).lower(
+        struct((rows, n * c), jnp.bfloat16),
+        struct((n * c, 128), jnp.bfloat16),
+        struct((256, 128), jnp.float32)).compile()
+    assert mhc.PRE_NAME in _names_of_custom_calls(pre)
+    post = mhc._jitted_post(n, False).lower(
+        struct((rows, n * c), jnp.bfloat16), struct((rows, c), jnp.bfloat16),
+        struct((rows, 128), jnp.float32)).compile()
+    assert mhc.POST_NAME in _names_of_custom_calls(post)
 
 
 def test_the_absorbed_decode_kernel_matches_the_composed_form():
