@@ -1,27 +1,42 @@
 """Pallas TPU flash-attention kernels (forward + fused backward).
 
 No reference equivalent (the reference composes attention from cublas
-batch-matmuls, examples/nlp/bert/hetu_bert.py:191-227). Forward is the
-blocked online-softmax kernel: per (batch*head, q-block) program, stream
-K/V blocks through VMEM keeping a running (max, sum, accumulator) — the
-[S, S] score matrix never exists in HBM, so attention memory is O(S·D)
-instead of O(S²) and the MXU stays fed from VMEM.
+batch-matmuls, examples/nlp/bert/hetu_bert.py:191-227). Both kernels
+walk the (q-tile, k-tile) pairs of the score square in square REGIONS
+(``_region_span``): a region's pairs are straight-line code, whose
+independent chains the compiler interleaves (on this chip a loop
+iteration is scheduled alone, so a chain walked by a loop pays its
+matmul -> exp -> matmul latency every iteration), and the regions are
+walked by a loop, so the code does not grow with S. With ``causal``
+the walk follows the diagonal at any tile size: pairs wholly above it
+are not even traced, and only the pairs it cuts carry the iota /
+compare / select (``tile_walk``, the one function that says so for
+both kernels and their tests). The [S, S] score matrix never exists in
+HBM in either direction, so attention memory is O(S·D) instead of
+O(S²) (the property training needs for long context).
+
+Forward: a program is one region ROW of its heads — head-major a block
+of neighbouring heads (as many as leave a region within its bounds,
+``_fwd_heads``: at S = 128, where a head is one tile pair, all twelve
+of a BERT-base batch row; one or two at S = 1024), token-major the heads
+of one lane block — whose whole K and V stay resident in VMEM. Inside a
+region every q-tile meets its k-tiles in ONE softmax step (all its
+score tiles, one row max, the exps, one row sum, the context matmuls),
+so the running (max, sum, accumulator) is rescaled once a region, and
+not at all where one region is the whole head (the GPT-2 train cell:
+no loop, no running state, eight chains a program). The scale goes
+onto q where that is exact (``_scales_q``). It also emits the per-row
+logsumexp L when a backward will read it.
 
 Backward is the standard recompute form, in ONE kernel a call: the
-forward also emits the per-row logsumexp L, and the kernel visits each
-(q-tile, k-tile) pair once, rebuilds its score tile in VMEM and feeds
-dV, dK and dQ from that one P / dS, every sum across tiles in float32
-and rounded once. So the S×S matrices never exist in HBM on the backward
-pass either (the property training needs for long context). With
-``causal`` the walk follows the diagonal: tile pairs wholly above it are
-never run at any tile size, and only the pairs it cuts carry the iota /
-compare / select (``bwd_walk``). The tile is built transposed
+kernel visits each pair once, rebuilds its score tile in VMEM from L
+and feeds dV, dK and dQ from that one P / dS, every sum across tiles in
+float32 and rounded once. The tile is built transposed
 (``[block_k, block_q]``), so the row residuals L and D = rowsum(dO ∘ O)
 (a cheap XLA elementwise reduce outside the kernel) travel as
-``[B*H, 1, S]`` rows and every matmul of a pair is a plain one. Pairs
-are grouped into square REGIONS (``_bwd_span``): a region's pairs are
-straight-line code the compiler interleaves, the regions are walked by a
-loop from the diagonal down, a program per (batch*head, region row).
+``[B*H, 1, S]`` rows and every matmul of a pair is a plain one. A
+program is a (batch*head, region row) of K / V, walking the q-regions
+from the diagonal down.
 
 Operands come in one of two forms (``ops/attention.py:flash_layout``
 picks by what the code can see). Head-major: q, k, v ``[B, H, S, D]``,
@@ -32,8 +47,7 @@ block of ``128 // D`` heads, split inside the kernel by static lane
 windows; the context, dq, dk and dv leave as rows and the residuals as
 ``[B, H, 1, S]`` rows, so no transpose, split or merge runs around the
 calls. The kernel bodies are shared (the head's lane window is a static
-parameter), the arithmetic a head is the same to the bit, and the
-head-major form lowers to the program it always did.
+parameter) and the arithmetic a head is the same to the bit.
 
 Block sizes are AUTOTUNED per (platform, kernel, S, D, dtype, causal,
 mask): bq/bk sweep {128, 256, 512, 1024} (clipped to divisors of S)
@@ -41,14 +55,15 @@ independently for the forward, the forward-with-lse and the fused
 backward through ``hetu_tpu/tune`` — the sweep runs once at first
 compile of a shape, the winner persists in the autotune JSON cache, and
 ``HETU_AUTOTUNE=0`` falls back to the static ``_block_sizes`` defaults
-(bq≤256, bk≤512). With ``causal`` the backward's tiles also decide how
-much of the square is skipped (a tile 1024 long on either side of S=1024
-is cut by the diagonal everywhere), so its best tiles differ from the
-forward's —
-that per-direction freedom is the point of tuning the three kernels
-apart. Batch/heads are NOT in the key (they only size the embarrassingly
-parallel grid axis; per-program work is S/D-shaped): the sweep times
-the first caller's b/h and later batch sizes share that winner.
+(bq≤256, bk≤512). With ``causal`` the tiles also decide how much of the
+square is skipped (a tile 1024 long on either side of S=1024 is cut by
+the diagonal everywhere), and the two directions weigh that differently
+(on a v5e the forward likes 512-row q-tiles, three pairs of four run;
+the backward 256 x 256, ten of sixteen) — that per-direction freedom is
+the point of tuning the three kernels apart. Batch/heads are NOT in the
+key (they only size the embarrassingly parallel grid axis; per-program
+work is S/D-shaped): the sweep times the first caller's b/h and later
+batch sizes share that winner.
 """
 from __future__ import annotations
 
@@ -68,21 +83,24 @@ __all__ = ["flash_attention", "flash_attention_with_lse",
 
 NEG_INF = -1e30
 LANES = 128      # TPU minor-dim tile: the forward writes lse lane-tiled
-# A kernel gets 16 MiB of VMEM without asking. The forward holds a
-# head's whole K and V on chip, double-buffered: past this many bytes of
-# them (S = 8192 at D = 192 is 16.8e6) it asks for what it needs.
-_DEFAULT_VMEM = 12 * 1024 * 1024
 _MOST_VMEM = 100 * 1024 * 1024
 
 
-def _forward_compiler_params(s, d, itemsize):
-    """``{}`` at every shape that fits the default VMEM (so those
-    kernels compile as they always have), else the limit to ask for."""
-    resident = 2 * 2 * s * (-(-d // LANES) * LANES) * itemsize
-    if resident <= _DEFAULT_VMEM:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=min(_MOST_VMEM, 2 * resident))}
+def _forward_compiler_params(s, span, block_q, block_k, lanes, heads,
+                             itemsize):
+    """The VMEM a forward program may use: its K and V, its region row
+    of q and of the context (``lanes`` wide over all its ``heads``,
+    double-buffered), and a region's float32 temporaries — every
+    chain's score tiles, their exps and the running accumulators, which
+    the compiler may hold all at once. At least the 16 MiB a kernel
+    gets unasked (S = 2048 at D = 192 passed them by 0.36 MB)."""
+    resident = 2 * 2 * (s + span) * lanes * itemsize
+    tiles = 3 * 4 * heads * span * max(span, block_q, block_k) \
+        + 3 * 4 * span * lanes
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(_MOST_VMEM,
+                                 max(16 * 1024 * 1024,
+                                     2 * (resident + tiles)))))
 
 
 class TokenMajor(NamedTuple):
@@ -136,72 +154,138 @@ def _lane_windows(ref, head_dim):
             for i in range(ref.shape[-1] // head_dim)]
 
 
+def _scales_q(sm_scale, dtype):
+    """Whether ``sm_scale`` goes onto q (``[rows, D]``, once a program)
+    and not onto every score tile: where that loses nothing in the
+    operand dtype — a power of two is exact, and a float32 q trades the
+    score's one rounding for one. A bf16 q times 1/sqrt(192), rounded
+    before the dot, would be a lower precision than the caller states."""
+    return jnp.dtype(dtype) == jnp.float32 \
+        or math.frexp(sm_scale)[0] == 0.5
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
-                block_k, seq_len, causal, block_q, head_dim=None):
-    # dots run in the INPUT dtype with f32 accumulation — on bf16 inputs
-    # that is the MXU's native mode; upcasting operands to f32 first
-    # would decompose every matmul into multiple f32 passes (measured
-    # ~2x whole-step cost at S=2048). All softmax math stays f32.
-    # Token-major (``head_dim`` given) the block holds several heads
-    # side by side: each is its own chain of the SAME arithmetic over
-    # its lane window, and the chains share the K loop (2-7% faster on
-    # a v5e than a loop a head: PERF.md PR 38).
-    heads = _lane_windows(q_ref, head_dim)
-    qs = [q_ref[0, :, lanes] for lanes in heads]   # [block_q, d] each
-    num_kb = seq_len // block_k
+                block_q, block_k, span, seq_len, causal, head_dim=None):
+    """One region row's program: the q rows ``[qi*span, (qi+1)*span)``
+    of the program's heads — head-major a block of neighbouring heads
+    (``_fwd_heads``), token-major the heads of one lane block, each its
+    static lane window — against their whole K and V, resident in VMEM.
+    The square is walked in REGIONS of ``span`` rows a side, as the
+    backward walks it (``_region_span``): the regions left of the
+    diagonal by a loop (every pair, no mask), the one ON it last, where
+    ``tile_walk`` says while tracing which pairs are left out and which
+    carry the iota / compare / select; regions right of it are not run.
+    Where one region is the whole head (``span == seq_len``) there is no
+    loop and no running state at all.
+
+    Inside a region a q-tile meets its k-tiles in ONE softmax step: all
+    its score tiles, one row max over them, the exps, one row sum, the
+    context matmuls, and one rescale of the running (max, sum,
+    accumulator) a region where there is one. The q-tiles of a head and
+    the heads of a program are independent chains laid out as
+    straight-line code, so the compiler fills one chain's matmul
+    latency with another's elementwise work (a loop iteration is
+    scheduled alone on this chip: PERF.md PRs 36, 40).
+
+    Dots run in the INPUT dtype with f32 accumulation — on bf16 inputs
+    the MXU's native mode; all softmax math stays f32."""
+    heads = [(g, lanes) for g in range(q_ref.shape[0])
+             for lanes in _lane_windows(q_ref, head_dim)]
+    num_q, num_k = span // block_q, span // block_k
     qi = pl.program_id(1 if head_dim is None else 2)
-    if causal:
-        # skip K-blocks strictly in the future of this q-block
-        num_kb = jnp.minimum(
-            num_kb, pl.cdiv((qi + 1) * block_q, block_k))
+    whole = span == seq_len
+    nt = (((1,), (1,)), ((), ()))             # a @ b^T
+    nn = (((1,), (0,)), ((), ()))
+    on_q = _scales_q(sm_scale, q_ref.dtype)
+    rows = [slice(i * block_q, (i + 1) * block_q) for i in range(num_q)]
+    qs = [[q_ref[g, r, lanes] * sm_scale if on_q else q_ref[g, r, lanes]
+           for r in rows] for g, lanes in heads]
+    # row - column of a score tile; a pair the diagonal cuts keeps the
+    # entries at or under its own offset
+    below = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) \
+        - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) \
+        if causal else None
 
-    def body(i, carry):
-        keep, out = None, []
-        for q, lanes, (m_prev, l_prev, acc) in zip(qs, heads, carry):
-            k = k_ref[0, pl.ds(i * block_k, block_k), lanes]
-            v = v_ref[0, pl.ds(i * block_k, block_k), lanes]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            if mask_ref is not None:
-                s = s + mask_ref[0, 0, pl.ds(i * block_k, block_k)][None, :]
-            if causal:
-                if keep is None:
-                    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 0)
-                    k_pos = i * block_k + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1)
-                    keep = q_pos >= k_pos
-                s = jnp.where(keep, s, NEG_INF)
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            out.append((m_new, l_new, acc))
-        return tuple(out)
+    def region(kr, state, diagonal):
+        """The region at k-region ``kr`` (a static 0, or traced) for
+        every chain of the program; ``state`` None where nothing ran
+        before it. Returns the new state, ``[head][q-tile]``."""
+        visited, masked = tile_walk(span, block_q, block_k, diagonal)
+        keys = [slice(j * block_k, (j + 1) * block_k) if whole else
+                pl.ds(pl.multiple_of(kr * span + j * block_k, block_k),
+                      block_k) for j in range(num_k)]
+        bias = None if mask_ref is None else \
+            [mask_ref[0, 0, ks][None, :] for ks in keys]
+        keep = {}
+        out = []
+        for n, (g, lanes) in enumerate(heads):
+            ks = [k_ref[g, x, lanes] for x in keys]
+            vs = [v_ref[g, x, lanes] for x in keys]
+            chains = []
+            for i in range(num_q):
+                mine = [j for ii, j in visited if ii == i]
+                scores = []
+                for j in mine:
+                    s = jax.lax.dot_general(
+                        qs[n][i], ks[j], nt,
+                        preferred_element_type=jnp.float32)
+                    if not on_q:
+                        s = s * sm_scale
+                    if bias is not None:
+                        s = s + bias[j]
+                    if (i, j) in masked:
+                        at = j * block_k - i * block_q
+                        if at not in keep:
+                            keep[at] = below >= at
+                        s = jnp.where(keep[at], s, NEG_INF)
+                    scores.append(s)
+                m = jnp.max(functools.reduce(jnp.maximum, scores),
+                            axis=1, keepdims=True)
+                if state is not None:
+                    m_prev, l_prev, acc_prev = state[n][i]
+                    m = jnp.maximum(m_prev, m)
+                ps = [jnp.exp(s - m) for s in scores]
+                l = jnp.sum(functools.reduce(jnp.add, ps), axis=1,
+                            keepdims=True)
+                acc = functools.reduce(jnp.add, [
+                    jax.lax.dot_general(
+                        p.astype(vs[j].dtype), vs[j], nn,
+                        preferred_element_type=jnp.float32)
+                    for p, j in zip(ps, mine)])
+                if state is not None:
+                    alpha = jnp.exp(m_prev - m)
+                    l, acc = l_prev * alpha + l, acc_prev * alpha + acc
+                chains.append((m, l, acc))
+            out.append(chains)
+        return out
 
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    done = jax.lax.fori_loop(
-        0, num_kb, body,
-        tuple((m0, l0, jnp.zeros(q.shape, jnp.float32)) for q in qs))
-    for i, (lanes, (m, l, acc)) in enumerate(zip(heads, done)):
-        o_ref[0, :, lanes] = (acc / l).astype(o_ref.dtype)
-        if l_ref is None:
-            continue
-        # per-row logsumexp, the backward's softmax residual
-        lse = jnp.broadcast_to(m + jnp.log(l), (block_q, LANES))
-        if head_dim is None:
-            # head-major: lane-tiled [block_q, 128] (TPU blocks need
-            # 128-lane minors), read back at lane 0
-            l_ref[0] = lse
-        else:
-            # token-major: the row the backward reads, [1, block_q]
-            l_ref[0, i] = lse.T[0:1]
+    if whole:
+        done = region(0, None, diagonal=causal)
+    else:
+        d = qs[0][0].shape[-1]
+        start = [[(jnp.full((block_q, 1), NEG_INF, jnp.float32),
+                   jnp.zeros((block_q, 1), jnp.float32),
+                   jnp.zeros((block_q, d), jnp.float32))] * num_q
+                 for _ in heads]
+        done = jax.lax.fori_loop(
+            0, qi if causal else seq_len // span,
+            lambda kr, state: region(kr, state, diagonal=False), start)
+        if causal:
+            done = region(qi, done, diagonal=True)
+    for n, (g, lanes) in enumerate(heads):
+        for r, (m, l, acc) in zip(rows, done[n]):
+            o_ref[g, r, lanes] = (acc * (1.0 / l)).astype(o_ref.dtype)
+            if l_ref is None:
+                continue
+            # per-row logsumexp, the backward's softmax residual
+            lse = jnp.broadcast_to(m + jnp.log(l), (block_q, LANES))
+            if head_dim is None:
+                # head-major: lane-tiled [rows, 128] (TPU blocks need
+                # 128-lane minors), read back at lane 0
+                l_ref[g, r, :] = lse
+            else:
+                # token-major: the row the backward reads, [1, rows]
+                l_ref[0, n, :, r] = lse.T[0:1]
 
 
 def _block_sizes(seq_len, head_dim):
@@ -245,8 +329,11 @@ def _candidates(s):
 # a kernel whose tile walk changed is swept afresh: winners stored for
 # the kernel it replaced (a checkout keeps its autotune.json across a
 # pull) sit under the old name and are not read. ``bwd`` was two kernels
-# (dK/dV, dQ) that ran the whole square at the tiles they liked best.
-_KERNEL_REVISION = {"bwd": "bwd_onepass"}
+# (dK/dV, dQ) that ran the whole square at the tiles they liked best;
+# the forward a K loop a q-tile, whose (256, 512) cut the diagonal
+# coarsely and paid for it less than for its iterations.
+_KERNEL_REVISION = {"bwd": "bwd_onepass", "fwd": "fwd_regions",
+                    "fwd_lse": "fwd_lse_regions"}
 
 
 def tune_key(kind, s, d, dtype, causal, has_mask, interpret=False,
@@ -388,6 +475,26 @@ def _form(layout):
     return () if layout is None else (layout,)
 
 
+def _forward(kind, q, k, v, mask, sm_scale, causal, interpret, layout,
+             reason):
+    """What the two forward entries share: the plan, the call, and the
+    ``flash_fwd_walk`` instant, once a traced call — how far the walk
+    engages at the tiles chosen (``fwd_walk_counts``). None where the
+    kernel does not take the shape."""
+    interpret, blocks = _plan(kind, q, mask, sm_scale, causal, interpret,
+                              layout, reason)
+    if blocks is None:
+        return None
+    _, h, s, d = _dims(q, layout)
+    from .. import telemetry
+    telemetry.get_telemetry().instant(
+        "flash_fwd_walk", seq=s, head_dim=d, block_q=blocks[0],
+        block_k=blocks[1], causal=bool(causal),
+        **fwd_walk_counts(h, s, *blocks, causal, layout))
+    return _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
+                                *blocks, kind == "fwd_lse", *_form(layout))
+
+
 def flash_attention(q, k, v, mask=None, sm_scale=1.0, causal=False,
                     interpret=None, layout=None, reason=None):
     """softmax(q k^T * sm_scale + mask) v over [B, H, S, D], or with a
@@ -400,9 +507,9 @@ def flash_attention(q, k, v, mask=None, sm_scale=1.0, causal=False,
     argument. Tiny or oddly-shaped inputs fall back to the composed-XLA
     reference rather than violating TPU tiling constraints.
     """
-    interpret, blocks = _plan("fwd", q, mask, sm_scale, causal,
-                              interpret, layout, reason)
-    if blocks is None:
+    out = _forward("fwd", q, k, v, mask, sm_scale, causal, interpret,
+                   layout, reason)
+    if out is None:
         from .attention import attention_reference
         s = q.shape[-2]
         m = mask
@@ -411,8 +518,7 @@ def flash_attention(q, k, v, mask=None, sm_scale=1.0, causal=False,
                               NEG_INF)[None, None]
             m = cmask if m is None else m + cmask
         return attention_reference(q, k, v, m, sm_scale)
-    return _flash_attention_jit(q, k, v, mask, sm_scale, causal,
-                                interpret, *blocks, False, *_form(layout))
+    return out
 
 
 def flash_attention_with_lse(q, k, v, mask=None, sm_scale=1.0,
@@ -422,12 +528,9 @@ def flash_attention_with_lse(q, k, v, mask=None, sm_scale=1.0,
     pair the fused backward needs.
     Returns (None, None) on shapes the kernel does not support; callers
     then take the composed path for both directions."""
-    interpret, blocks = _plan("fwd_lse", q, mask, sm_scale, causal,
-                              interpret, layout, reason)
-    if blocks is None:
-        return None, None
-    return _flash_attention_jit(q, k, v, mask, sm_scale, causal,
-                                interpret, *blocks, True, *_form(layout))
+    out = _forward("fwd_lse", q, k, v, mask, sm_scale, causal, interpret,
+                   layout, reason)
+    return (None, None) if out is None else out
 
 
 # tests flip this to exercise the kernel without a TPU backend
@@ -453,45 +556,47 @@ def _dims(q, layout):
                                              "layout"))
 def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
                          block_q, block_k, need_lse, layout=None):
-    """``layout`` None: q, k, v ``[B, H, S, D]``, a grid step a head's
-    q-block. A :class:`TokenMajor`: ``[B, S, lanes]`` rows, a grid step
-    the q-block of one lane block of heads; the same kernel body either
-    way, and the same event name in a device trace."""
+    """``layout`` None: q, k, v ``[B, H, S, D]``, a grid step a region
+    row of a block of neighbouring heads. A :class:`TokenMajor`:
+    ``[B, S, lanes]`` rows, a grid step a region row of one lane block
+    of heads; the same kernel body either way, and the same event name
+    in a device trace."""
     b, h, s, d = _dims(q, layout)
+    span = _region_span(s, block_q, block_k)
+    group = _fwd_heads(h, s, block_q, block_k, layout)
     if layout is None:  # jit-ok: static argname
-        grid = (b * h, s // block_q)
+        grid = (b * h // group, s // span)
         args = [x.reshape(b * h, s, d) for x in (q, k, v)]
-        rows = lambda bh, qi: (bh, qi, 0)             # noqa: E731
-        whole = lambda bh, qi: (bh, 0, 0)             # noqa: E731
-        in_specs = [pl.BlockSpec((1, block_q, d), rows),
-                    pl.BlockSpec((1, s, d), whole),
-                    pl.BlockSpec((1, s, d), whole)]
+        rows = lambda p, qi: (p, qi, 0)               # noqa: E731
+        whole = lambda p, qi: (p, 0, 0)               # noqa: E731
+        in_specs = [pl.BlockSpec((group, span, d), rows),
+                    pl.BlockSpec((group, s, d), whole),
+                    pl.BlockSpec((group, s, d), whole)]
+        # a block's heads share a batch row (``group`` divides h)
         mask_spec = pl.BlockSpec(
-            (1, 1, s), lambda bh, qi, _h=h: (bh // _h, 0, 0))
+            (1, 1, s), lambda p, qi, _n=h // group: (p // _n, 0, 0))
         o_shape = jax.ShapeDtypeStruct((b * h, s, d), q.dtype)
-        o_spec = pl.BlockSpec((1, block_q, d), rows)
+        o_spec = pl.BlockSpec((group, span, d), rows)
         l_shape = jax.ShapeDtypeStruct((b * h, s, LANES), jnp.float32)
-        l_spec = pl.BlockSpec((1, block_q, LANES), rows)
+        l_spec = pl.BlockSpec((group, span, LANES), rows)
     else:
         w, tq, tk, tv = layout.width, *layout.tiles
-        grid = (b, layout.blocks, s // block_q)
+        grid = (b, layout.blocks, s // span)
         args = [q, k, v]
         in_specs = [
-            pl.BlockSpec((1, block_q, w),
-                         lambda bi, p, qi: (bi, qi, tq + p)),
+            pl.BlockSpec((1, span, w), lambda bi, p, qi: (bi, qi, tq + p)),
             pl.BlockSpec((1, s, w), lambda bi, p, qi: (bi, 0, tk + p)),
             pl.BlockSpec((1, s, w), lambda bi, p, qi: (bi, 0, tv + p))]
         mask_spec = pl.BlockSpec((1, 1, s), lambda bi, p, qi: (bi, 0, 0))
         o_shape = jax.ShapeDtypeStruct((b, s, h * d), q.dtype)
-        o_spec = pl.BlockSpec((1, block_q, w),
-                              lambda bi, p, qi: (bi, qi, p))
+        o_spec = pl.BlockSpec((1, span, w), lambda bi, p, qi: (bi, qi, p))
         # the residual leaves as the rows the backward takes
         l_shape = jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)
-        l_spec = pl.BlockSpec((1, layout.per_block, 1, block_q),
+        l_spec = pl.BlockSpec((1, group, 1, span),
                               lambda bi, p, qi: (bi, p, 0, qi))
     body = functools.partial(_fwd_kernel, sm_scale=sm_scale,
-                             block_k=block_k, seq_len=s, causal=causal,
-                             block_q=block_q,
+                             block_q=block_q, block_k=block_k, span=span,
+                             seq_len=s, causal=causal,
                              head_dim=None if layout is None else d)
     if mask is not None:  # jit-ok: structural None-check, not a traced read
         in_specs.append(mask_spec)
@@ -509,7 +614,10 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
             def kernel(q_ref, k_ref, v_ref, o_ref):
                 body(q_ref, k_ref, v_ref, None, o_ref, None)
 
-    more_vmem = _forward_compiler_params(s, d, q.dtype.itemsize)
+    params = _forward_compiler_params(
+        s, span, block_q, block_k,
+        group * -(-d // LANES) * LANES if layout is None else w, group,
+        q.dtype.itemsize)
     if need_lse:  # jit-ok: static argname
         # the lse residual is emitted only when a consumer exists (the
         # fused backward); the inference/serving forward skips the write
@@ -519,7 +627,7 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
             grid=grid,
             in_specs=in_specs,
             out_specs=[o_spec, l_spec],
-            interpret=interpret, **more_vmem,
+            compiler_params=params, interpret=interpret,
         )(*args)
         if layout is None:  # jit-ok: static argname
             return out.reshape(b, h, s, d), lse[:, :, 0].reshape(b, h, s)
@@ -530,7 +638,7 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=o_spec,
-        interpret=interpret, **more_vmem,
+        compiler_params=params, interpret=interpret,
     )(*args)
     return out if layout is not None else out.reshape(b, h, s, d)
 
@@ -553,13 +661,14 @@ def _first_unmasked_q_tile(kj, block_q, block_k):
     return ((kj + 1) * block_k + block_q - 2) // block_q
 
 
-def bwd_walk(s, block_q, block_k, causal):
-    """The (q-tile, k-tile) pairs the backward kernel runs, and of them
-    those that carry the causal iota / compare / select — from the same
-    two bounds the kernel leaves pairs out by and masks by (inside the
-    regions on the diagonal; the regions below it hold only pairs these
-    bounds keep and do not mask). The full square, nothing masked,
-    without ``causal``."""
+def tile_walk(s, block_q, block_k, causal):
+    """The (q-tile, k-tile) pairs a flash kernel runs over ``s`` rows a
+    side, and of them those that carry the causal iota / compare /
+    select — from the two bounds above, which the backward kernel leaves
+    pairs out by and masks by, and which the forward reads through this
+    function for the region on the diagonal (the regions beside it hold
+    only pairs these bounds keep and do not mask). The full square,
+    nothing masked, without ``causal``."""
     num_qb, num_kb = s // block_q, s // block_k
     visited, masked = [], []
     for kj in range(num_kb):
@@ -573,11 +682,11 @@ def bwd_walk(s, block_q, block_k, causal):
     return visited, masked
 
 
-def bwd_walk_counts(s, block_q, block_k, causal):
+def tile_walk_counts(s, block_q, block_k, causal):
     """How far the tile walk engages at these tiles: tiles visited, tiles
     of the square, masked tiles, and the two shares a trace reader wants
     (visited / square, masked / visited)."""
-    visited, masked = bwd_walk(s, block_q, block_k, causal)
+    visited, masked = tile_walk(s, block_q, block_k, causal)
     square = (s // block_q) * (s // block_k)
     return {"tiles_visited": len(visited), "tiles_square": square,
             "tiles_masked": len(masked),
@@ -585,20 +694,20 @@ def bwd_walk_counts(s, block_q, block_k, causal):
             "masked_share": round(len(masked) / len(visited), 4)}
 
 
-# a straight-line region of the backward: at most this many tile pairs,
-# and this many rows a side (16 pairs of 1024 x 1024 compile for half a
-# minute and gain nothing over 4)
+# a straight-line region of either kernel: at most this many tile pairs
+# a head, and this many rows a side (16 pairs of 1024 x 1024 compile for
+# half a minute and gain nothing over 4)
 _REGION_TILES = 16
 _REGION_ROWS = 2048
 
 
-def _bwd_span(s, block_q, block_k):
-    """Side of the square REGIONS the backward walks: the largest
+def _region_span(s, block_q, block_k):
+    """Side of the square REGIONS both kernels walk: the largest
     divisor of S that is whole tiles both ways within the two bounds
     above (one tile pair where a single one is past them). A region's
     pairs are straight-line code, so the compiler overlaps one pair's
     matmuls with another's elementwise work — a dependent chain of
-    three matmul stages that a pair walked alone by a loop waits out
+    matmul stages that a pair walked alone by a loop waits out
     (0.45 us a pair on a v5e, PERF.md PR 36); the regions themselves
     are walked by a loop, so the code does not grow with S."""
     tile = math.lcm(block_q, block_k)
@@ -606,6 +715,35 @@ def _bwd_span(s, block_q, block_k):
                 if s % m == 0
                 and (m // block_q) * (m // block_k) <= _REGION_TILES],
                default=tile)
+
+
+def _fwd_heads(h, s, block_q, block_k, layout=None):
+    """Heads a forward program takes. Token-major: the heads of a lane
+    block. Head-major, where one region is the whole head and leaves
+    room within the two bounds above, the region fills up with
+    neighbouring heads: the largest divisor of ``h`` (so a program's
+    heads share their batch row's mask row) — at S = 128 a head is ONE
+    pair, and a program a head would be a grid step's price 3,072 times
+    a layer for 13 MFLOP each (BERT-base, PERF.md PR 40)."""
+    if layout is not None:
+        return layout.per_block
+    pairs = (s // block_q) * (s // block_k)
+    if _region_span(s, block_q, block_k) != s:
+        return 1
+    return max(g for g in range(1, h + 1)
+               if h % g == 0 and (g == 1 or (
+                   g * pairs <= _REGION_TILES and g * s <= _REGION_ROWS)))
+
+
+def fwd_walk_counts(h, s, block_q, block_k, causal, layout=None):
+    """What a forward call at these tiles runs: the walk's counts over
+    the square, the heads a program takes and its independent chains
+    (a q-tile of a head each)."""
+    heads = _fwd_heads(h, s, block_q, block_k, layout)
+    span = _region_span(s, block_q, block_k)
+    return {**tile_walk_counts(s, block_q, block_k, causal),
+            "heads_per_program": heads,
+            "chains": heads * (span // block_q)}
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
@@ -759,7 +897,7 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
     """``layout`` as :func:`_flash_attention_jit` takes it; token-major
     ``o`` and ``do`` are ``[B, S, H]`` and so are dq, dk and dv."""
     b, h, s, d = _dims(q, layout)
-    span = _bwd_span(s, block_q, block_k)
+    span = _region_span(s, block_q, block_k)
 
     def row_sums(heads_shape):
         # D = rowsum(dO * O): cheap XLA elementwise reduce. The row
@@ -860,7 +998,7 @@ def flash_attention_bwd(q, k, v, mask, o, lse, do, sm_scale=1.0,
     form of q, k and v. ``lse`` is the forward's logsumexp
     (flash_attention_with_lse). Block sizes tune independently of the
     forward's; with ``causal`` the tiles also decide how much of the
-    square the walk skips (``bwd_walk_counts``, recorded here at trace
+    square the walk skips (``tile_walk_counts``, recorded here at trace
     time as a ``flash_bwd_walk`` instant)."""
     interpret, (block_q, block_k) = _plan(
         "bwd", q, mask, sm_scale, causal, interpret, layout, reason)
@@ -869,7 +1007,7 @@ def flash_attention_bwd(q, k, v, mask, o, lse, do, sm_scale=1.0,
     telemetry.get_telemetry().instant(
         "flash_bwd_walk", seq=s, head_dim=d, block_q=block_q,
         block_k=block_k, causal=bool(causal),
-        **bwd_walk_counts(s, block_q, block_k, causal))
+        **tile_walk_counts(s, block_q, block_k, causal))
     return _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale,
                                     causal, interpret, block_q, block_k,
                                     *_form(layout))

@@ -191,6 +191,19 @@ SPAN_SCHEMA = {
                        "tiles_masked": _req(_INT),
                        "visited_share": _req(_NUM),
                        "masked_share": _req(_NUM)},
+    # the flash forward's walk at the tiles a traced call runs with
+    # (ops/pallas_attention.py:_forward): the same counts, and what a
+    # program holds — its heads and its independent chains
+    "flash_fwd_walk": {"seq": _req(_INT), "head_dim": _req(_INT),
+                       "block_q": _req(_INT), "block_k": _req(_INT),
+                       "causal": _req(_BOOL),
+                       "tiles_visited": _req(_INT),
+                       "tiles_square": _req(_INT),
+                       "tiles_masked": _req(_INT),
+                       "visited_share": _req(_NUM),
+                       "masked_share": _req(_NUM),
+                       "heads_per_program": _req(_INT),
+                       "chains": _req(_INT)},
     # the operand form a flash call runs in, at trace time (ops/
     # pallas_attention.py:_plan): token_major reads q, k, v out of the
     # projection's rows, heads_per_block heads a program; head_major

@@ -44,8 +44,9 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
     """Per-kernel milliseconds for the flash fwd(+lse)/bwd on one shape.
 
     Returns ``{"fwd_ms", "fwd_lse_ms", "bwd_ms", "blocks": {kind:
-    (bq, bk)}, "bwd_walk": {...}}`` plus ``static_*`` twins measured
-    with the untuned ``_block_sizes`` defaults when ``include_static``
+    (bq, bk)}, "fwd_walk": {...}, "bwd_walk": {...}}`` plus ``static_*``
+    twins measured with the untuned ``_block_sizes`` defaults when
+    ``include_static``
     (the in-repo tuned-vs-static evidence). Those are the head-major
     kernels over ``[B, H, S, D]``. ``flash_layout`` is what a caller
     that hands this shape's packed qkv rows gets
@@ -59,8 +60,12 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
     runs around the kernels, ``token_major`` with the one
     concatenation. ``bwd_walk`` is the one-pass
     backward's tile walk at its tiles (``pallas_attention.
-    bwd_walk_counts``): with ``causal`` the share of the square it
-    visits and the share of visited tiles that carry the mask. Uses the tuned path, so a cold autotune
+    tile_walk_counts``): with ``causal`` the share of the square it
+    visits and the share of visited tiles that carry the mask;
+    ``fwd_walk`` the forward-with-lse's (``fwd_walk_counts``: the same
+    counts at ITS tiles, the heads a program takes and its independent
+    chains; under ``token_major`` the lane-block form's). Uses the
+    tuned path, so a cold autotune
     cache sweeps here — which is the point: the probe pays the sweep
     the training step would have paid, and the cache makes both free
     afterwards."""
@@ -108,9 +113,13 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
     static = pk._block_sizes(seq, head_dim)
     # how far the backward's tile walk engages at the tiles it runs
     # with: tiles visited / tiles of the square, masked / visited
-    out["bwd_walk"] = pk.bwd_walk_counts(seq, *tuned["bwd"], causal)
+    out["bwd_walk"] = pk.tile_walk_counts(seq, *tuned["bwd"], causal)
+    out["fwd_walk"] = pk.fwd_walk_counts(heads, seq, *tuned["fwd_lse"],
+                                         causal)
     if include_static:
-        out["static_bwd_walk"] = pk.bwd_walk_counts(seq, *static, causal)
+        out["static_bwd_walk"] = pk.tile_walk_counts(seq, *static, causal)
+        out["static_fwd_walk"] = pk.fwd_walk_counts(heads, seq, *static,
+                                                    causal)
 
     def run_fwd(blocks, need_lse):
         bq, bk = blocks
@@ -148,8 +157,10 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
         d_ctx = jnp.asarray(
             rng.randn(batch, seq, heads * head_dim) * 0.3, dtype)
         tm = tuned_blocks(packed)
-        out["token_major"] = {"blocks": {kind: list(b)
-                                         for kind, b in tm.items()}}
+        out["token_major"] = {
+            "blocks": {kind: list(b) for kind, b in tm.items()},
+            "fwd_walk": pk.fwd_walk_counts(heads, seq, *tm["fwd_lse"],
+                                           causal, packed)}
 
         def tm_fwd(need_lse):
             bq, bk = tm["fwd_lse" if need_lse else "fwd"]

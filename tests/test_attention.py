@@ -264,22 +264,215 @@ def test_backward_walk_visits_what_the_diagonal_leaves(s):
     sides = pk._candidates(s) + [64, 192 if s == 384 else 32]
     for bq in sides:
         for bk in sides:
-            visited, masked = pk.bwd_walk(s, bq, bk, True)
+            visited, masked = pk.tile_walk(s, bq, bk, True)
             want_visited, want_masked = _cut_by_the_diagonal(s, bq, bk)
             assert sorted(visited) == want_visited, (s, bq, bk)
             assert sorted(masked) == want_masked, (s, bq, bk)
-            counts = pk.bwd_walk_counts(s, bq, bk, True)
+            counts = pk.tile_walk_counts(s, bq, bk, True)
             assert counts["tiles_visited"] == len(want_visited)
             assert counts["tiles_masked"] == len(want_masked)
-            full, none = pk.bwd_walk(s, bq, bk, False)
+            full, none = pk.tile_walk(s, bq, bk, False)
             assert len(full) == (s // bq) * (s // bk) and none == []
     # what ISSUE 36 asks of the shares at the cell's S
-    assert pk.bwd_walk_counts(1024, 256, 256, True)["visited_share"] \
+    assert pk.tile_walk_counts(1024, 256, 256, True)["visited_share"] \
         == 0.625
-    assert pk.bwd_walk_counts(1024, 512, 512, True)["visited_share"] \
+    assert pk.tile_walk_counts(1024, 512, 512, True)["visited_share"] \
         == 0.75
-    assert pk.bwd_walk_counts(1024, 128, 128, True)["visited_share"] \
+    assert pk.tile_walk_counts(1024, 128, 128, True)["visited_share"] \
         == 0.5625
+
+
+def _forward_walk(s, bq, bk, causal):
+    """The pairs a forward call runs and masks, in tiles of the whole
+    square, put together as ``_fwd_kernel`` walks them: a region row a
+    program, the regions left of the diagonal whole, the one on it by
+    ``tile_walk`` over the region's own rows (every region, none masked,
+    without ``causal``)."""
+    from hetu_tpu.ops import pallas_attention as pk
+    span = pk._region_span(s, bq, bk)
+    nq, nk = span // bq, span // bk
+    visited, masked = [], []
+    for row in range(s // span):
+        for col in range(row if causal else s // span):
+            visited += [(row * nq + i, col * nk + j)
+                        for i in range(nq) for j in range(nk)]
+        if causal:
+            here, cut = pk.tile_walk(span, bq, bk, True)
+            visited += [(row * nq + i, row * nk + j) for i, j in here]
+            masked += [(row * nq + i, row * nk + j) for i, j in cut]
+    return visited, masked
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("s", [256, 1024, 2048])
+def test_forward_walk_visits_what_the_diagonal_leaves(s, causal):
+    """At every tile pair the sweep may choose, the forward's regions
+    add up to exactly the pairs that hold a kept score, each once, and
+    mask exactly those the diagonal cuts: no pair above the diagonal is
+    run at any tiles, whether a region is the whole head or one of
+    several a loop walks; the counts a traced call records
+    (``fwd_walk_counts``) are those."""
+    from hetu_tpu.ops import pallas_attention as pk
+    for bq in pk._candidates(s):
+        for bk in pk._candidates(s):
+            visited, masked = _forward_walk(s, bq, bk, causal)
+            assert len(set(visited)) == len(visited), (s, bq, bk)
+            if causal:
+                want_visited, want_masked = _cut_by_the_diagonal(s, bq, bk)
+            else:
+                want_visited = [(i, j) for i in range(s // bq)
+                                for j in range(s // bk)]
+                want_masked = []
+            assert sorted(visited) == want_visited, (s, bq, bk)
+            assert sorted(masked) == want_masked, (s, bq, bk)
+            assert all(i * bq + bq - 1 >= j * bk for i, j in visited) \
+                or not causal
+            counts = pk.fwd_walk_counts(12, s, bq, bk, causal)
+            assert counts["tiles_visited"] == len(want_visited)
+            assert counts["tiles_masked"] == len(want_masked)
+            span = pk._region_span(s, bq, bk)
+            assert counts["chains"] \
+                == counts["heads_per_program"] * (span // bq)
+    if causal and s == 1024:     # the GPT-2 train cell's square
+        assert pk.fwd_walk_counts(12, s, 256, 256, True)[
+            "tiles_visited"] == 10
+        assert pk.fwd_walk_counts(12, s, 512, 512, True)[
+            "visited_share"] == 0.75
+
+
+@pytest.mark.parametrize("s,tiles", [(768, (128, 128)), (768, (256, 128)),
+                                     (768, (128, 256)), (1024, (128, 128)),
+                                     (1024, (256, 512))],
+                         ids=lambda v: str(v).replace(" ", ""))
+def test_forward_kernel_does_not_run_tiles_above_the_diagonal(s, tiles):
+    """The kernel follows the walk: NaN values in ONE k-tile reach the
+    context of a q-tile only through a pair that was run (0 x NaN is
+    NaN even where the mask zeroes P), so a q-tile's rows are NaN
+    exactly when its pair with the poisoned k-tile is visited — one
+    region a head (S = 768) and regions walked by a loop (S = 1024 at
+    128 x 128: two region rows)."""
+    from hetu_tpu.ops import pallas_attention as pk
+    bq, bk = tiles
+    q, k, v = _qkv(b=1, h=2, s=s, seed=29)
+    visited, _ = _forward_walk(s, bq, bk, True)
+    assert len(visited) < (s // bq) * (s // bk)
+    for j in range(s // bk):
+        poison = (jnp.arange(s) // bk == j)[None, None, :, None]
+        out = pk._flash_attention_jit(q, k, jnp.where(poison, jnp.nan, v),
+                                      None, 0.25, True, True, bq, bk,
+                                      False)
+        for i in range(s // bq):
+            rows = np.isnan(np.asarray(out[:, :, i * bq:(i + 1) * bq]))
+            assert rows.all() == rows.any() == ((i, j) in visited), (i, j)
+
+
+FORWARD_CASES = [  # heads, S, D, tiles, causal, mask, dtype
+    (2, 1024, 64, (256, 256), True, False, jnp.float32),
+    (2, 1024, 64, (512, 256), True, True, jnp.float32),
+    (2, 1024, 64, (128, 128), True, False, jnp.float32),   # regions 512
+    (2, 1024, 64, (128, 128), False, True, jnp.float32),
+    (1, 512, 128, (256, 512), True, True, jnp.float32),
+    (1, 512, 128, (128, 128), False, False, jnp.bfloat16),
+    (2, 512, 192, (256, 256), True, False, jnp.float32),
+    (2, 512, 192, (128, 256), True, True, jnp.bfloat16),
+    (2, 4096, 64, (512, 512), True, False, jnp.float32),   # past _REGION_ROWS
+    (2, 4096, 64, (512, 1024), False, True, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize(
+    "heads,s,d,tiles,causal,use_mask,dtype", FORWARD_CASES,
+    ids=[f"h{h}-s{s}-d{d}-{bq}x{bk}-{'causal' if c else 'full'}-"
+         f"{'mask' if m else 'nomask'}-{jnp.dtype(t).name}"
+         for h, s, d, (bq, bk), c, m, t in FORWARD_CASES])
+def test_region_forward_matches_the_reference(heads, s, d, tiles, causal,
+                                              use_mask, dtype):
+    """Context and logsumexp of the region forward against the float32
+    reference: a region the whole head and regions walked by a loop
+    (S = 1,024 at 128 x 128; S = 4,096, past ``_REGION_ROWS``), the scale
+    on q (1/8: exact) and on the scores (D = 192 in bfloat16), and the
+    plain forward bit-equal to the one that also writes the residual.
+    Where the heads fill lane blocks (D = 64, 128) the token-major form
+    over the same numbers equals the head-major one BIT FOR BIT."""
+    from hetu_tpu.ops import pallas_attention as pk
+    b = 2
+    scale = d ** -0.5
+    rng = np.random.RandomState(s + d)
+    q, k, v = (jnp.asarray(rng.randn(b, heads, s, d) * 0.5, dtype)
+               for _ in range(3))
+    mask = None
+    if use_mask:
+        m = np.zeros((b, 1, 1, s), np.float32)
+        m[0, ..., s - 50:] = -1e9       # a padded tail ...
+        m[1, ..., 130:141] = -1e9       # ... and keys masked mid-row
+        mask = jnp.asarray(m)
+    full = mask
+    if causal:
+        full = _causal_mask(s) if mask is None else mask + _causal_mask(s)
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    want = attention_reference(q32, k32, v32, full, scale)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q32, k32) * scale
+    want_lse = jax.nn.logsumexp(scores if full is None else scores + full,
+                                axis=-1)
+    o, lse = pk._flash_attention_jit(q, k, v, mask, scale, causal, True,
+                                     *tiles, True)
+    plain = pk._flash_attention_jit(q, k, v, mask, scale, causal, True,
+                                    *tiles, False)
+    assert np.array_equal(np.asarray(plain), np.asarray(o))
+    tol = dict(rtol=2e-5, atol=2e-5) if dtype == jnp.float32 \
+        else dict(rtol=3e-2, atol=3e-2)
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(want), **tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               **tol)
+    layout = pk.TokenMajor(heads, d)
+    if not layout.fits(s):
+        assert d == 192
+        return
+    rows = [x.transpose(0, 2, 1, 3).reshape(b, s, heads * d)
+            for x in (q, k, v)]
+    o_t, lse_t = pk._flash_attention_jit(*rows, mask, scale, causal, True,
+                                         *tiles, True, layout)
+    assert np.array_equal(np.asarray(o_t),
+                          np.asarray(o.transpose(0, 2, 1, 3).reshape(
+                              b, s, heads * d)))
+    assert np.array_equal(np.asarray(lse_t[:, :, 0]), np.asarray(lse))
+
+
+@pytest.mark.parametrize("s,tiles,group", [
+    (384, (128, 128), 1), (512, (256, 128), 2), (640, (128, 640), 3),
+    (256, (128, 128), 4), (128, (128, 128), 12)],
+    ids=lambda v: str(v).replace(" ", ""))
+def test_grouped_heads_take_their_own_batch_rows_mask(s, tiles, group):
+    """Where one region is a whole head with room to spare, a program
+    takes a block of neighbouring heads (the largest divisor of H = 12
+    within ``_REGION_TILES`` pairs and ``_REGION_ROWS`` rows): each head
+    of a block still meets ITS batch row's padding mask (three rows
+    masked differently, so 36 / G programs cross from one to the
+    next), alone and with ``causal``."""
+    from hetu_tpu.ops import pallas_attention as pk
+    b, h, d = 3, 12, 16
+    assert pk._fwd_heads(h, s, *tiles) == group
+    assert pk.fwd_walk_counts(h, s, *tiles, False)["chains"] \
+        == group * (s // tiles[0])
+    q, k, v = _qkv(b=b, h=h, s=s, d=d, seed=31)
+    m = np.zeros((b, 1, 1, s), np.float32)
+    m[0, ..., s - 50:] = -1e9
+    m[1, ..., 20:97] = -1e9
+    m[2, ..., :11] = -1e9
+    mask = jnp.asarray(m)
+    for causal in (False, True):
+        full = mask + _causal_mask(s) if causal else mask
+        o, lse = pk._flash_attention_jit(q, k, v, mask, 0.25, causal, True,
+                                         *tiles, True)
+        np.testing.assert_allclose(
+            np.asarray(o), np.asarray(attention_reference(q, k, v, full,
+                                                          0.25)),
+            rtol=2e-5, atol=2e-5)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 0.25 + full
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(jax.nn.logsumexp(scores, axis=-1)),
+            rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("s,tiles,span", [
@@ -296,7 +489,7 @@ def test_backward_regions_are_whole_tiles_and_bounded(s, tiles, span):
     code does not grow with S), and as large as that allows."""
     from hetu_tpu.ops import pallas_attention as pk
     bq, bk = tiles
-    got = pk._bwd_span(s, bq, bk)
+    got = pk._region_span(s, bq, bk)
     assert got == span
     assert s % got == 0 and got % bq == 0 and got % bk == 0
     pairs = (got // bq) * (got // bk)
@@ -322,7 +515,7 @@ def test_backward_kernel_does_not_run_tiles_above_the_diagonal(tiles):
     q, dy = jnp.where(poison, jnp.nan, q), jnp.where(poison, jnp.nan, dy)
     _, dk, dv = pk._flash_attention_bwd_jit(q, k, v, None, o, lse, dy,
                                             0.25, True, True, bq, bk)
-    visited, _ = pk.bwd_walk(s, bq, bk, True)
+    visited, _ = pk.tile_walk(s, bq, bk, True)
     for kj in range(s // bk):
         ran = (0, kj) in visited
         for g in (dk, dv):

@@ -356,8 +356,6 @@ def test_two_kernel_backward_winners_are_not_read(tuner, monkeypatch):
     s, d = 512, 8
     name, key = pk.tune_key("bwd", s, d, jnp.float32, True, False, True)
     assert name.startswith("flash_") and name != "flash_bwd"
-    assert pk.tune_key("fwd", s, d, jnp.float32, True, False,
-                       True)[0] == "flash_fwd"
     table.put("flash_bwd", key, (512, 128))     # the parent's winner
     monkeypatch.setenv("HETU_AUTOTUNE", "1")    # cache only: no sweep
     seen = []
@@ -376,6 +374,45 @@ def test_two_kernel_backward_winners_are_not_read(tuner, monkeypatch):
     assert seen[-1] == (128, 256) and _hits(tel) == 1
     # both stay listed under the prefix the benchmark's driver prints
     assert len(table.chosen("flash")) == 2
+
+
+@pytest.mark.parametrize("kind", ["fwd", "fwd_lse"])
+def test_k_loop_forward_winners_are_not_read(tuner, monkeypatch, kind):
+    """The forward that walks regions is another kernel than the K loop
+    a q-tile it replaced (PR 40): tiles stored for that one (as
+    ``flash_fwd`` / ``flash_fwd_lse``) do not drive it, its own entry
+    does, and a traced call records the walk at the tiles it runs with
+    as one ``flash_fwd_walk`` instant (schema-checked)."""
+    table, tel = tuner
+    s, d = 512, 8
+    name, key = pk.tune_key(kind, s, d, jnp.float32, True, False, True)
+    assert name == f"flash_{kind}_regions"
+    table.put(f"flash_{kind}", key, (512, 128))  # the parent's winner
+    monkeypatch.setenv("HETU_AUTOTUNE", "1")     # cache only: no sweep
+    entry = pk.flash_attention if kind == "fwd" \
+        else pk.flash_attention_with_lse
+    q, k, v = _qkv(s, d, seed=3)
+
+    def walk():
+        entry(q, k, v, None, sm_scale=0.25, causal=True, interpret=True)
+        events = [e["args"] for e in tel.tracer.drain(clear=True)
+                  if e.get("name") == "flash_fwd_walk"]
+        assert len(events) == 1 and check_args("flash_fwd_walk",
+                                               events[0]) == []
+        return events[0]
+
+    static = pk._block_sizes(s, d)
+    got = walk()
+    assert (got["block_q"], got["block_k"]) == static and _hits(tel) == 0
+    table.put(name, key, (128, 256))             # its own entry is read
+    got = walk()
+    assert (got["block_q"], got["block_k"]) == (128, 256)
+    assert _hits(tel) == 1
+    assert got == {"seq": s, "head_dim": d, "block_q": 128,
+                   "block_k": 256, "causal": True,
+                   "heads_per_program": 1, "chains": 4,
+                   **pk.tile_walk_counts(s, 128, 256, True)}
+    assert (got["tiles_visited"], got["tiles_masked"]) == (6, 4)
 
 
 @pytest.mark.parametrize("tiles,visited,masked", [
@@ -475,6 +512,12 @@ def test_probe_and_attribution(tuner, monkeypatch):
     assert pr["bwd_walk"]["visited_share"] == 1.0
     assert pr["bwd_walk"]["tiles_square"] == \
         (256 // pr["blocks"]["bwd"][0]) * (256 // pr["blocks"]["bwd"][1])
+    # the forward's at ITS tiles, and what a program holds: 256 rows in
+    # one pair leave room for both heads, a chain a q-tile each
+    bq, bk = pr["blocks"]["fwd_lse"]
+    assert pr["fwd_walk"] == pk.fwd_walk_counts(2, 256, bq, bk, False)
+    assert pr["static_fwd_walk"]["heads_per_program"] == 2
+    assert pr["static_fwd_walk"]["chains"] == 2
     att = tune.attribute_step(100.0, 4, pr["fwd_lse_ms"], pr["bwd_ms"])
     # fields are independently rounded to 3 decimals — compare at 2x
     # that granularity

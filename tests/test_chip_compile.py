@@ -9,9 +9,11 @@ Shapes are the three the chip paths run at real width — BERT-base
 and the long-sequence cell (b8, h8, S2048, padding mask) — in bf16,
 each for the plain forward, the forward with logsumexp and the fused
 backward (one kernel since PR 36); plus the smallest and the largest
-(bq, bk) the autotuner may pick at S=2048, and the backward at the
+(bq, bk) the autotuner may pick at S=2048, the backward at the
 GPT-2 train cell's own shape (b16) over the corners of its candidate
-grid. A compile that passes is a compile, not a run.
+grid, and the forward that walks regions (PR 40) at that cell's packed
+rows, at BERT's cell (twelve heads a program) and head-major at
+S = 8,192, D = 192. A compile that passes is a compile, not a run.
 
 LayerNorm's backward kernel (``ops/pallas_norm.py``) compiles at the
 two shapes the train cells run it at, ``[16384, 768]`` (GPT-2 small,
@@ -250,6 +252,59 @@ def test_token_major_kernels_compile_at_the_train_cell(one_chip, kind,
     assert not any("[16,1024,2304]" in ln for ln in moved), moved
     if kind != "bwd":   # (the backward's D row sum may re-lay o and dO)
         assert not moved, moved
+
+
+@pytest.mark.parametrize("blocks", [(512, 512), (512, 256), (128, 1024),
+                                    (1024, 128)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+def test_region_forward_compiles_at_the_train_cell(one_chip, blocks):
+    """The forward that walks regions (PR 40) at the GPT-2 train cell's
+    shape, at the tiles a v5e's sweep liked and the two lopsided corners
+    of its grid (the square corners, the static tiles and (256, 256)
+    are in the test above): a lane block's whole triangle a program,
+    one custom call under the name the trace's readers match, the
+    residual written as the rows the backward takes."""
+    assert set(blocks) <= set(pk._candidates(1024))
+    text = _compile_token_major("fwd_lse", blocks, one_chip)
+    entry = text[text.index("ENTRY"):]
+    calls = [ln for ln in entry.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert calls[0].lstrip().removeprefix("ROOT ").startswith(
+        "%_flash_attention_jit")
+    assert "f32[16,12,1,1024]" in text
+    assert not [ln for ln in entry.splitlines()
+                if " copy(" in ln or " transpose(" in ln]
+
+
+@pytest.mark.parametrize("kind", ["fwd", "fwd_lse"])
+def test_grouped_forward_compiles_at_berts_cell(one_chip, kind):
+    """BERT-base's train cell, ``[256 x 12, 128, 64]`` with a padding
+    mask: a head is ONE tile pair, so a program takes the twelve heads
+    of a batch row (256 programs a layer where 3,072 ran) and Mosaic
+    takes the ``[12, 128, 64]`` blocks."""
+    shape = (256, 12, 128, 64, False, True)
+    blocks = pk._block_sizes(128, 64)
+    assert pk._fwd_heads(12, 128, *blocks) == 12
+    text = _compile(kind, shape, blocks, one_chip)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "bf16[3072,128,64]" in text
+
+
+@pytest.mark.parametrize("blocks", [None, (128, 128), (1024, 1024)],
+                         ids=["static", "128x128", "1024x1024"])
+def test_region_forward_compiles_at_the_longest_prefill(one_chip, blocks):
+    """Head-major at S = 8,192, D = 192 (the latent-attention cells'
+    largest prompt bucket: K and V of a head are 16.8 MB of VMEM,
+    double-buffered, and the kernel asks for what it needs), causal, the
+    regions walked by a loop: at the static tiles and at the two square
+    corners of the sweep's grid."""
+    s, d = 8192, 192
+    blocks = blocks or pk._block_sizes(s, d)
+    assert pk._region_span(s, *blocks) < s
+    assert pk._fwd_heads(64, s, *blocks) == 1
+    text = _compile("fwd", (1, 4, s, d, True, False), blocks, one_chip)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_token_major_kernels_compile_at_one_head_a_block(one_chip):

@@ -195,7 +195,7 @@ def test_region_walk_with_scratch_is_bit_equal_too():
     float32 scratch sums (one set a head of the lane tile) round to the
     head-major kernel's bits."""
     heads, d, s = 2, 64, 1024
-    assert pk._bwd_span(s, 128, 128) == 512
+    assert pk._region_span(s, 128, 128) == 512
     rows = _rows(1, s, heads, d, jnp.float32, seed=3)
     dy = jnp.asarray(np.random.RandomState(4).randn(1, s, heads * d),
                      jnp.float32)
@@ -296,7 +296,8 @@ def _packed_reference(rows, heads, d, dy, causal=True):
 def test_packed_op_runs_token_major_and_says_so(kernels_on_cpu):
     """S = 512, two heads of 64: forward with logsumexp and the fused
     backward run token-major, two heads a block, and the one gradient is
-    the ``[B, S, 3H]`` rows; the walk instant is the head-major one's."""
+    the ``[B, S, 3H]`` rows; the walk instants are the head-major ones',
+    the forward's with the lane block's two heads a program."""
     heads, d, s = 2, 64, 512
     rows = _rows(2, s, heads, d, jnp.float32, seed=9)
     dy = jnp.asarray(np.random.RandomState(1).randn(2, s, heads * d),
@@ -312,8 +313,19 @@ def test_packed_op_runs_token_major_and_says_so(kernels_on_cpu):
     walk = [e["args"] for e in events if e.get("name") == "flash_bwd_walk"]
     assert len(walk) == 1
     bq, bk = walk[0]["block_q"], walk[0]["block_k"]
-    assert {k: walk[0][k] for k in pk.bwd_walk_counts(s, bq, bk, True)} \
-        == pk.bwd_walk_counts(s, bq, bk, True)
+    assert {k: walk[0][k] for k in pk.tile_walk_counts(s, bq, bk, True)} \
+        == pk.tile_walk_counts(s, bq, bk, True)
+    # the forward's walk at ITS tiles: both heads of the lane block in
+    # one program, a chain a q-tile each, the diagonal's pairs masked
+    fwd = [e["args"] for e in events if e.get("name") == "flash_fwd_walk"]
+    assert len(fwd) == 1 and check_args("flash_fwd_walk", fwd[0]) == []
+    bq, bk = fwd[0]["block_q"], fwd[0]["block_k"]
+    assert fwd[0] == {
+        "seq": s, "head_dim": d, "block_q": bq, "block_k": bk,
+        "causal": True, "heads_per_program": 2, "chains": 2 * (s // bq),
+        **pk.tile_walk_counts(s, bq, bk, True)}
+    # the static tiles: one k-tile of 512 that the diagonal cuts twice
+    assert (bq, bk, fwd[0]["tiles_masked"]) == (256, 512, 2)
     want_out, want_grad = _packed_reference(rows, heads, d, dy)
     assert dqkv.shape == rows.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
