@@ -140,6 +140,49 @@ def prefill_attention(q, k, v, sm_scale, causal=True):
 
 
 # ---------------------------------------------------------------------------
+# grouped-query attention against a paged pool whose rows hold the
+# KEY/VALUE heads alone (``G x D`` wide, ``G`` dividing the ``H`` query
+# heads; one head: multi-query). No copy a query head is stored or
+# gathered: the ``H / G`` queries of a group score against the group's
+# one row.
+# ---------------------------------------------------------------------------
+
+def _grouped(q, k_pool, v_pool, slot_idx, valid, sm_scale):
+    """``q [B, C, H, D]`` against the rows at ``slot_idx [B, S]``,
+    ``valid [B, C, S]``; float32 softmax. Returns ``[B, C, H, D]``."""
+    b, c, h, d = q.shape
+    k, v = (_gather_latent_rows(pool, slot_idx).reshape(
+        b, slot_idx.shape[1], -1, d) for pool in (k_pool, v_pool))
+    g = k.shape[2]
+    q = (q * sm_scale).astype(k.dtype).reshape(b, c, g, h // g, d)
+    scores = jnp.einsum("bcgrd,bsgd->bgrcs", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(valid[:, None, None], scores, -1e9)
+    probs = jax.nn.softmax(scores, axis=-1)
+    ctx = jnp.einsum("bgrcs,bsgd->bcgrd", probs.astype(v.dtype), v)
+    return ctx.reshape(b, c, h, d)
+
+
+def grouped_decode_attention(q, k_pool, v_pool, slot_idx, positions,
+                             sm_scale):
+    """One query token a sequence, ``q [B, H, D]`` at ``positions
+    [B]``, as :func:`paged_decode_attention` is. Returns ``[B, H, D]``."""
+    valid = jnp.arange(slot_idx.shape[1])[None, :] <= positions[:, None]
+    return _grouped(q[:, None], k_pool, v_pool, slot_idx, valid[:, None],
+                    sm_scale)[:, 0]
+
+
+def grouped_prefill_attention(q, k_pool, v_pool, slot_idx, starts,
+                              sm_scale):
+    """A chunk of query tokens a sequence, ``q [B, C, H, D]`` from
+    ``starts [B]``, as :func:`paged_prefill_attention` is. Returns
+    ``[B, C, H, D]``."""
+    pos = starts[:, None] + jnp.arange(q.shape[1])[None, :]
+    valid = jnp.arange(slot_idx.shape[1])[None, None, :] <= pos[:, :, None]
+    return _grouped(q, k_pool, v_pool, slot_idx, valid, sm_scale)
+
+
+# ---------------------------------------------------------------------------
 # latent (MLA) attention — the three calls of models/latent_moe.py. A
 # cache row is ``[c ; k_r]``: the normed latent and the one rotated key
 # all heads share. A whole prompt attends EXPANDED (per-head keys and

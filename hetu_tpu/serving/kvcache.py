@@ -36,6 +36,11 @@ vLLM/PagedAttention shape instead:
   entry) copies it first (:meth:`PagedKVCache.ensure_writable`), so
   shared partial tails are read-shared and write-private.
 
+* **state slots** beside the rows, for a model whose layers (some of
+  them) carry a fixed-size recurrent state a sequence instead of a row
+  a token: one slot a running sequence, taken and given back with its
+  blocks (:class:`PagedKVCache` says how a model asks for them).
+
 **Physical block 0 is the scratch block.** Padded batch lanes (the
 bucketing that keeps jit signatures bounded) write their garbage K/V
 rows to slot ``0..block_size-1`` and gather from them behind a length
@@ -60,8 +65,8 @@ import numpy as np
 from ..models.gpt import gpt_param_bytes  # noqa: F401  its older home
 
 __all__ = ["KVCacheExhausted", "BlockAllocator", "PagedKVCache",
-           "PrefixCache", "kv_block_bytes", "gpt_param_bytes",
-           "blocks_for_budget", "DEFAULT_BLOCK_SIZE"]
+           "PrefixCache", "kv_block_bytes", "state_slot_bytes",
+           "gpt_param_bytes", "blocks_for_budget", "DEFAULT_BLOCK_SIZE"]
 
 DEFAULT_BLOCK_SIZE = 16
 
@@ -78,30 +83,58 @@ class KVCacheExhausted(RuntimeError):
     it escape means a caller bypassed admission control."""
 
 
-def kv_block_bytes(config, block_size):
-    """HBM bytes one cache block costs across ALL layers, by the row
-    layout of the configuration's serving model (a K and a V row of
-    ``hidden`` float32 for GPT; one ``latent + rope`` row in the
-    model's dtype for a latent-attention model)."""
+def _pool_kinds(model):
+    """What each entry of the cache's ``pools`` is, for a serving
+    model: ``"rows"`` (a layer's paged pools, a row a token) or
+    ``"state"`` (fixed-size slots, one a sequence). A model without
+    ``pool_kinds`` has rows in every layer."""
+    return getattr(model, "pool_kinds", None) \
+        or ("rows",) * model.num_cache_layers
+
+
+def _itemsize(dtype):
     import jax.numpy as jnp     # numpy alone does not know bfloat16
+    return jnp.dtype(dtype).itemsize
+
+
+def kv_block_bytes(config, block_size):
+    """HBM bytes one cache block costs across the layers that HAVE
+    rows, by the row layout of the configuration's serving model (a K
+    and a V row of ``hidden`` float32 for GPT; one ``latent + rope`` row
+    in the model's dtype for a latent-attention model; a ``k`` and a
+    ``v`` row of the key/value heads alone in a hybrid's attention
+    layers, and nothing in its state-space layers)."""
     model = config.serving_model()
-    row = sum(width * jnp.dtype(dtype).itemsize
+    row = sum(width * _itemsize(dtype)
               for _, width, dtype in model.cache_layout())
-    return model.num_cache_layers * int(block_size) * row
+    return _pool_kinds(model).count("rows") * int(block_size) * row
+
+
+def state_slot_bytes(config):
+    """HBM bytes one sequence's recurrent state costs, whatever its
+    length (0 for a model of rows alone)."""
+    model = config.serving_model()
+    entries = _pool_kinds(model).count("state")
+    if not entries:
+        return 0
+    return entries * sum(int(np.prod(shape)) * _itemsize(dtype)
+                         for _, shape, dtype in model.state_layout())
 
 
 def blocks_for_budget(config, block_size=DEFAULT_BLOCK_SIZE, budget=None,
-                      headroom=_BUDGET_HEADROOM):
+                      headroom=_BUDGET_HEADROOM, state_slots=0):
     """KV blocks the resolved HBM budget affords after the model's
-    parameters and a headroom fraction. Returns ``None`` when no budget
-    resolves (CPU harness without ``HETU_HBM_BUDGET``); raises when a
-    budget resolves but can't fit even two blocks."""
+    parameters, ``state_slots`` sequences' recurrent state (and the
+    scratch slot's) and a headroom fraction. Returns ``None`` when no
+    budget resolves (CPU harness without ``HETU_HBM_BUDGET``); raises
+    when a budget resolves but can't fit even two blocks."""
     from ..analysis.memory import fmt_bytes, resolve_budget
     budget = resolve_budget(budget)
     if budget is None:
         return None
     param_bytes = config.serving_model().param_bytes()
-    avail = int(budget * (1.0 - headroom)) - param_bytes
+    avail = int(budget * (1.0 - headroom)) - param_bytes \
+        - state_slot_bytes(config) * (int(state_slots) + 1)
     nb = avail // kv_block_bytes(config, block_size)
     if nb < 2:
         raise ValueError(
@@ -388,6 +421,20 @@ class PagedKVCache:
     (``config.serving_model().cache_layout()``: ``k`` and ``v`` for
     GPT, one latent row ``c`` for a latent-attention model).
 
+    A model may have layers whose per-sequence memory is a fixed-size
+    recurrent STATE instead of rows. It then says what each entry of
+    :attr:`pools` is (``model.pool_kinds``: ``"rows"`` for a layer with
+    rows, ``"state"`` for an entry of slots, which may hold the state
+    of many layers) and what a slot holds (``model.state_layout()``:
+    ``(name, shape, dtype)`` a buffer). A state entry is ``{name:
+    [state_slots + 1, *shape]}``: a sequence takes one SLOT (the same
+    in every such entry) with its blocks and gives it back with them;
+    slot 0 is the scratch slot padded batch lanes write, as block 0 is
+    for rows. A
+    slot is handed out as it was left: the model's prefill writes it
+    without reading it. ``state_slots`` is how many sequences may hold
+    one at a time (the engine's ``max_batch_size``).
+
     With ``prefix_cache=True`` the cache grows the prefix-sharing
     plane: :meth:`add_seq_prefix` resolves a prompt's cached prefix to
     shared blocks (refcount bumped per sharer), :meth:`insert_prefix`
@@ -400,13 +447,26 @@ class PagedKVCache:
 
     def __init__(self, config, num_blocks=None,
                  block_size=DEFAULT_BLOCK_SIZE, budget=None,
-                 telemetry=None, prefix_cache=False):
+                 telemetry=None, prefix_cache=False, state_slots=0):
         from .. import telemetry as _telemetry
         self.config = config
         self.block_size = int(block_size)
+        self._kinds = _pool_kinds(config.serving_model())
+        self.state_slots = int(state_slots) if "state" in self._kinds \
+            else 0
+        if "state" in self._kinds:
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True with a model that has recurrent "
+                    "state: a cached block of rows says nothing of the "
+                    "state at its end (state snapshots are not "
+                    "implemented; ROADMAP.md Queue 2 A6)")
+            if self.state_slots < 1:
+                raise ValueError(
+                    "a model with recurrent state needs state_slots >= 1")
         if num_blocks is None:
             num_blocks = blocks_for_budget(config, self.block_size,
-                                           budget)
+                                           budget, state_slots=state_slots)
             if num_blocks is None:
                 raise ValueError(
                     "no HBM budget resolvable to size the KV pool "
@@ -422,6 +482,8 @@ class PagedKVCache:
             else None
         self.pools = self._init_pools()
         self.tables = {}            # seq_id -> [block ids]
+        self.slots = {}             # seq_id -> state slot (1..state_slots)
+        self._free_slots = list(range(self.state_slots, 0, -1))
         self.peak_utilization = 0.0
         self.cow_copies = 0
         self._cow_fn = None         # jitted lazily (one signature)
@@ -429,10 +491,17 @@ class PagedKVCache:
     def _init_pools(self):
         import jax.numpy as jnp
         model = self.config.serving_model()
-        return [{name: jnp.zeros((self.num_blocks + 1, self.block_size,
-                                  width), jnp.dtype(dtype))
-                 for name, width, dtype in model.cache_layout()}
-                for _ in range(model.num_cache_layers)]
+
+        def layer(kind):
+            if kind == "state":
+                return {name: jnp.zeros((self.state_slots + 1, *shape),
+                                        jnp.dtype(dtype))
+                        for name, shape, dtype in model.state_layout()}
+            return {name: jnp.zeros((self.num_blocks + 1, self.block_size,
+                                     width), jnp.dtype(dtype))
+                    for name, width, dtype in model.cache_layout()}
+
+        return [layer(kind) for kind in self._kinds]
 
     # -- accounting ------------------------------------------------------
     @property
@@ -462,14 +531,35 @@ class PagedKVCache:
         """Fraction of the pool holding cached-unreferenced blocks."""
         return self.cached_blocks / self.num_blocks
 
-    def hbm_bytes(self):
-        """Bytes the pools occupy (scratch block included)."""
+    @property
+    def state_slots_used(self):
+        return len(self.slots)
+
+    def kv_bytes(self):
+        """Bytes the paged pools occupy (scratch block included)."""
         return kv_block_bytes(self.config, self.block_size) \
             * (self.num_blocks + 1)
 
+    def state_bytes(self):
+        """Bytes the state slots occupy (scratch slot included)."""
+        return state_slot_bytes(self.config) * (self.state_slots + 1)
+
+    def hbm_bytes(self):
+        """Bytes the cache occupies: rows and state."""
+        return self.kv_bytes() + self.state_bytes()
+
     def can_admit(self, ntokens):
         return self.allocator.blocks_for_tokens(ntokens) \
-            <= self.allocator.available + self.cached_blocks
+            <= self.allocator.available + self.cached_blocks \
+            and self._slot_free()
+
+    def _slot_free(self):
+        return not self.state_slots or bool(self._free_slots)
+
+    def slot_of_seq(self, seq_id):
+        """The sequence's state slot (0, the scratch slot, for a model
+        without state)."""
+        return self.slots.get(seq_id, 0)
 
     def fits_at_all(self, ntokens):
         """Whether a sequence of ``ntokens`` could EVER be served by
@@ -488,6 +578,9 @@ class PagedKVCache:
                                      self.allocator.available)
             self.telemetry.set_gauge("kv_seqs", len(self.tables))
             self.telemetry.set_gauge("kv_hbm_utilization", u)
+            if self.state_slots:
+                self.telemetry.set_gauge("state_slots_used",
+                                         len(self.slots))
             if self.prefix is not None:
                 self.telemetry.set_gauge("kv_blocks_cached",
                                          self.cached_blocks)
@@ -530,8 +623,13 @@ class PagedKVCache:
         sequence (all-or-nothing; raises :class:`KVCacheExhausted`)."""
         if seq_id in self.tables:
             raise ValueError(f"sequence {seq_id} already has a table")
+        if not self._slot_free():
+            raise KVCacheExhausted(
+                f"state slots exhausted: {self.state_slots} in use")
         blocks = self._alloc(self.allocator.blocks_for_tokens(ntokens))
         self.tables[seq_id] = blocks
+        if self.state_slots:
+            self.slots[seq_id] = self._free_slots.pop()
         self._note_util()
         return blocks
 
@@ -682,6 +780,11 @@ class PagedKVCache:
         if blocks:
             for b in blocks:
                 self._release_block(b)
+        slot = self.slots.pop(seq_id, None)
+        if slot is not None:
+            # lowest slot first, as blocks are handed out
+            self._free_slots.append(slot)
+            self._free_slots.sort(reverse=True)
         self._note_util()
 
     def capacity_tokens(self, seq_id):
@@ -706,6 +809,12 @@ class PagedKVCache:
             for b in self.prefix._lru:
                 assert alloc.refcount(b) == 1, \
                     f"LRU block {b} is still referenced"
+        if self.state_slots:
+            held = sorted(self.slots.values())
+            assert set(self.slots) == set(self.tables)
+            assert sorted(held + self._free_slots) == list(
+                range(1, self.state_slots + 1)), \
+                f"state slots {held} held, {self._free_slots} free"
 
     # -- slot math (host-side; the jit programs take these as inputs) ---
     def slot_of(self, seq_id, pos):

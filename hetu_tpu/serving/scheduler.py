@@ -85,6 +85,18 @@ ladder and the suffix-prefill program keys on (batch, chunk, ctx)
 buckets, so :attr:`compile_bound` stays a finite ladder product —
 HT901 holds with both features on.
 
+**Models with recurrent state** (``model.pool_kinds`` holds a
+``"state"`` entry: state-space layers): a running sequence holds one
+state SLOT of the cache beside its blocks, from admission to retirement
+or preemption, and every program takes each row's slot behind its other
+arguments (``_state_slots``; padded lanes name the scratch slot 0). A
+prefill writes the slot from a zero state, a decode step updates it in
+place through the donated pools, a chunk of a chunked prefill continues
+from it (the suffix program also takes each row's count of real
+tokens, and returns each row's last real position's logits alone). A
+preempted sequence's replay rebuilds its state from its tokens.
+``prefix_cache=True`` is refused for such a model.
+
 **Program spans.** The scheduler thread's time is tiled by leaf spans
 (``Telemetry.span``: the ring when telemetry is on, a ``hetu.<name>``
 annotation in a ``jax.profiler`` trace always): ``serve.wait`` (nothing
@@ -354,10 +366,14 @@ class ContinuousBatchingEngine:
         self._suffix_mode = self.prefix_cache \
             or self.prefill_chunk is not None
         self.params = model.params(lookup)
+        # layers with recurrent state: a slot a running sequence, and
+        # every program takes each row's slot behind its other arguments
+        self._stateful = "state" in getattr(model, "pool_kinds", ())
         self.cache = PagedKVCache(config, num_blocks=num_blocks,
                                   block_size=block_size, budget=budget,
                                   telemetry=self.telemetry,
-                                  prefix_cache=self.prefix_cache)
+                                  prefix_cache=self.prefix_cache,
+                                  state_slots=self.max_batch_size)
         # HT901 ladders: every dispatch dimension snaps to one of these,
         # so signatures stay bounded under per-step churn
         self.batch_buckets = _pow2_ladder(1, self.max_batch_size)
@@ -390,7 +406,8 @@ class ContinuousBatchingEngine:
                                 for kind in ("prefill", "decode")}
         # with telemetry on, the last programs that returned counters:
         # {"kind", "t0_ns", "t1_ns" (perf_counter_ns, dispatch to the
-        # end of the host sync), "<kind>_<counter>": its own counts}
+        # end of the host sync), "<kind>_<counter>": its own counts;
+        # for a model with state also "state_slots", "state_slots_used"}
         self.program_log = collections.deque(
             maxlen=16384 if width else 0)
         self._signatures = set()
@@ -518,6 +535,10 @@ class ContinuousBatchingEngine:
                "kv_blocks": self.cache.num_blocks,
                "kv_blocks_used": self.cache.used_blocks,
                "kv_hbm_utilization": round(self.cache.utilization, 4),
+               "kv_hbm_bytes": self.cache.kv_bytes(),
+               "state_slots": self.cache.state_slots,
+               "state_slots_used": self.cache.state_slots_used,
+               "state_hbm_bytes": self.cache.state_bytes(),
                "jit_compiles": self.jit_compiles,
                "compile_bound": self.compile_bound,
                "decode_steps": self.decode_steps,
@@ -781,6 +802,17 @@ class ContinuousBatchingEngine:
                if b * prompt_bucket <= self.prefill_token_cap]
         return fit[-1] if fit else self.batch_buckets[0]
 
+    def _state_slots(self, seqs, bb):
+        """What a stateful model's programs take behind their other
+        arguments: ``(slots [bb] int32,)``, each row's state slot
+        (padded lanes: the scratch slot 0); ``()`` for a model of rows
+        alone."""
+        if not self._stateful:
+            return ()
+        slots = np.zeros(bb, np.int32)
+        slots[:len(seqs)] = [self.cache.slot_of_seq(s.id) for s in seqs]
+        return (slots,)
+
     def _named_counters(self, kind, vec):
         """``{"<kind>_<counter>": value}`` of one counter vector."""
         names = self.model.counter_names
@@ -812,8 +844,11 @@ class ContinuousBatchingEngine:
             for name, value in named.items():
                 if not isinstance(value, list):
                     tel.inc(f"{self.name}_{name}", value)
-            self.program_log.append(
-                dict(named, kind=kind, t0_ns=t0, t1_ns=t1))
+            row = dict(named, kind=kind, t0_ns=t0, t1_ns=t1)
+            if self._stateful:
+                row.update(state_slots=self.cache.state_slots,
+                           state_slots_used=self.cache.state_slots_used)
+            self.program_log.append(row)
         return records
 
     def warm_up(self, prompt_len, max_new):
@@ -857,7 +892,8 @@ class ContinuousBatchingEngine:
                         ("prefill", bb, pb), self._prefill_fn,
                         self.params, self.cache.pools,
                         jnp.asarray(zeros(bb, pb)),
-                        jnp.asarray(zeros(bb, pb)), zeros(bb))
+                        jnp.asarray(zeros(bb, pb)), zeros(bb),
+                        *self._state_slots((), bb))
                     np.asarray(logits)
                 else:
                     logits, self.cache.pools = self._dispatch(
@@ -874,7 +910,7 @@ class ContinuousBatchingEngine:
                 out, self.cache.pools = self._dispatch(
                     ("decode", bb, cb), self._step_fn, self.params,
                     self.cache.pools, zeros(bb), zeros(bb), zeros(bb, cb),
-                    zeros(bb))
+                    zeros(bb), *self._state_slots((), bb))
                 if self.model.counter_names and cb == ctx_buckets[0]:
                     # what hands a step's ids to the step dispatched
                     # ahead of their host read
@@ -894,11 +930,15 @@ class ContinuousBatchingEngine:
                         jnp.asarray(zeros(bb, cw)),
                         jnp.asarray(zeros(bb)),
                         jnp.asarray(zeros(bb, sb)),
-                        jnp.asarray(zeros(bb, cw)))
+                        jnp.asarray(zeros(bb, cw)),
+                        *self._state_slots((), bb),
+                        *((zeros(bb),) if self._stateful else ()))
                     if self.model.counter_names:
                         logits, _ = logits
                     for n in sizes:
-                        np.asarray(logits[jnp.arange(n),
+                        np.asarray(logits[jnp.arange(n)]
+                                   if self._stateful else
+                                   logits[jnp.arange(n),
                                           jnp.asarray([cw - 1] * n)])
                     del logits
                     ran["suffix_prefill"].append((bb, cw, sb))
@@ -964,7 +1004,7 @@ class ContinuousBatchingEngine:
                     (logits, counted), pools = self._dispatch(
                         ("prefill", bb, pb), self._prefill_fn,
                         self.params, self.cache.pools, ids, slots,
-                        last_pos)
+                        last_pos, *self._state_slots(group, bb))
                 else:
                     logits, pools = self._dispatch(
                         ("prefill", bb, pb), self._prefill_fn,
@@ -1082,6 +1122,15 @@ class ContinuousBatchingEngine:
                         s.id, pos, pos + w)
                 finishing = [(i, s, w) for i, (s, w) in enumerate(group)
                              if s.prefill_pos + w >= s.prompt.shape[0]]
+                state_args = ()
+                if self._stateful:
+                    # each row's slot and its count of real tokens: the
+                    # program continues the slot's state over them and
+                    # puts each row's LAST real position through the head
+                    lens = np.zeros(bb, np.int32)
+                    lens[:len(group)] = [w for _, w in group]
+                    state_args = self._state_slots(
+                        [s for s, _ in group], bb) + (lens,)
                 ids, starts = jnp.asarray(ids), jnp.asarray(starts)
                 slot_grid = jnp.asarray(slot_grid)
                 write_slots = jnp.asarray(write_slots)
@@ -1095,13 +1144,15 @@ class ContinuousBatchingEngine:
                 logits, pools = self._dispatch(
                     ("sprefill", bb, cw, sb), self._sprefill_fn,
                     self.params, self.cache.pools, ids, starts, slot_grid,
-                    write_slots)
+                    write_slots, *state_args)
                 self.cache.pools = pools
                 counted = None
                 if self.model.counter_names:
                     logits, counted = logits
                 # a chunk that ends no prompt is not waited for
-                last, first = self._prefill_sync(logits[rows, last_pos]) \
+                last, first = self._prefill_sync(
+                    logits[rows] if self._stateful
+                    else logits[rows, last_pos]) \
                     if finishing else (None, self._reading())
                 t1 = first[0]
                 records = self._count("prefill", counted, t0, t1)
@@ -1262,7 +1313,8 @@ class ContinuousBatchingEngine:
             # with no put of their own
             out, pools = self._dispatch(
                 key, fn, self.params, self.cache.pools, tokens,
-                positions, slot_grid, write_slots)
+                positions, slot_grid, write_slots,
+                *self._state_slots(active, bb))
             self.cache.pools = pools
             step = _DecodeProgram(active, bb, attrs, device_pick, out, t0)
             self.decode_steps += 1

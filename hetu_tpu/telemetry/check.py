@@ -219,6 +219,13 @@ SPAN_SCHEMA = {
     # streams), or platform off a TPU
     "mhc_plan": {"streams": _req(_INT), "iters": _req(_INT),
                  "form": _req(_STR), "reason": _opt(_STR)},
+    # which form a traced state-space call runs in (ops/ssm.py): op
+    # scan (a prompt or a chunk of one) or step (one token a row);
+    # form kernel (a TPU: hetu_ssm_scan / hetu_ssm_step) or composed,
+    # which says why (a shape ops/ssm.py:supported does not take, or
+    # platform off a TPU)
+    "ssm_plan": {"op": _req(_STR), "form": _req(_STR),
+                 "reason": _opt(_STR)},
 }
 
 
