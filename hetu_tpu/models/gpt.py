@@ -85,20 +85,42 @@ def gpt_param_names(config):
 def gpt_serving_params(config, lookup):
     """Assemble the serving block's parameter pytree. ``lookup(name)``
     returns the array for one checkpoint name (a dict's ``__getitem__``,
-    an ``np.load`` closure over a checkpoint dir, ...)."""
+    an ``np.load`` closure over a checkpoint dir, ...).
+
+    ``"blocks"`` is ONE dict of the block's six entries (``ln1``,
+    ``qkv``, ``proj``, ``ln2``, ``fc``, ``mlp_proj``), each a ``(w, b)``
+    pair OVER THE LAYERS, indexed ``[i]`` either way:
+
+    * a VECTOR (a LayerNorm's scale and bias, a projection's bias: 8 of
+      the 12) is one array stacked over the layers, ``[L, n]``, made
+      here once. A jitted call walks every array of the trees handed to
+      it, on every call, and the host was the slower side of GPT-2
+      small's decode step: 8 arrays whatever the depth, not ``8 L``;
+    * a MATRIX (the four projections' weights) stays an array a layer, a
+      tuple of ``L``. XLA's TPU compiler streams a whole parameter into
+      VMEM in slices while the layer before computes, and does NOT for a
+      static slice of a stacked one: with ``[L, 768, 2304]`` stacks the
+      same decode program read 1.37 ms for 0.97 on the chip (PERF.md,
+      PR 44), more than the walk over ``4 L`` arrays costs the host.
+
+    The caller's own per-name arrays stay the caller's."""
     import jax.numpy as jnp
 
     def get(name):
         return jnp.asarray(lookup(name), jnp.float32)
 
+    def over_layers(names):
+        arrays = tuple(get(name) for name in names)
+        return jnp.stack(arrays) if arrays[0].ndim == 1 else arrays
+
     names = gpt_param_names(config)
-    out = {"wte": get(names["wte"]), "wpe": get(names["wpe"]),
-           "ln_f": tuple(get(n) for n in names["ln_f"]),
-           "lm_head": get(names["lm_head"]), "blocks": []}
-    for blk in names["blocks"]:
-        out["blocks"].append(
-            {k: tuple(get(n) for n in v) for k, v in blk.items()})
-    return out
+    return {"wte": get(names["wte"]), "wpe": get(names["wpe"]),
+            "ln_f": tuple(get(n) for n in names["ln_f"]),
+            "lm_head": get(names["lm_head"]),
+            "blocks": {role: tuple(over_layers([blk[role][j]
+                                                for blk in names["blocks"]])
+                                   for j in range(2))
+                       for role in names["blocks"][0]}}
 
 
 def _serve_ln(x, scale_bias, eps=1e-12):
@@ -127,7 +149,10 @@ def _serve_forward(params, x, attend, num_heads, hidden_act):
     """THE serving-side decoder stack, written once: embedded tokens
     ``x`` ``[..., hidden]`` (``[B, H]`` for a decode step, ``[B, S, H]``
     for a prefill) through every pre-LN block and ``ln_f``; returns the
-    hidden states, same shape. The cache backend is ``attend(i, q, k,
+    hidden states, same shape. Layer ``i``'s arrays are ``[i]`` of
+    ``params["blocks"]``' entries (:func:`gpt_serving_params`: the
+    ``i``-th of a tuple, or a static index into a stack, a view to the
+    compiler and not a copy). The cache backend is ``attend(i, q, k,
     v)``: layer ``i``'s q/k/v arrive token-major ``[..., nh, hs]``, it
     writes K/V wherever its cache lives, reads what it must, and returns
     the context shaped like ``q``. It is called once per layer while
@@ -135,7 +160,9 @@ def _serve_forward(params, x, attend, num_heads, hidden_act):
     act = _serve_act(hidden_act)
     hidden = x.shape[-1]
     heads = (*x.shape[:-1], num_heads, hidden // num_heads)
-    for i, blk in enumerate(params["blocks"]):
+    blocks = params["blocks"]
+    for i in range(len(blocks["qkv"][0])):
+        blk = {role: (w[i], b[i]) for role, (w, b) in blocks.items()}
         qkv = _serve_ln(x, blk["ln1"]) @ blk["qkv"][0] + blk["qkv"][1]
         q, k, v = (qkv[..., j * hidden:(j + 1) * hidden].reshape(heads)
                    for j in range(3))

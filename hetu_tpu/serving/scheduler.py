@@ -411,6 +411,11 @@ class ContinuousBatchingEngine:
         self.program_log = collections.deque(
             maxlen=16384 if width else 0)
         self._signatures = set()
+        # arrays the greedy decode call walks on its way in (every leaf
+        # of the parameters, the pools and the step's inputs) and wraps
+        # on its way out, counted at its first dispatch: host work a
+        # token that grows with the trees' SHAPE, not with their bytes
+        self.program_leaves = None
         self.decode_steps = 0               # decode programs dispatched
         self.decode_device_pick_steps = 0   # ... that picked on the device
         # ... that were dispatched while the one before was still unread
@@ -541,6 +546,7 @@ class ContinuousBatchingEngine:
                "state_hbm_bytes": self.cache.state_bytes(),
                "jit_compiles": self.jit_compiles,
                "compile_bound": self.compile_bound,
+               "program_leaves": self.program_leaves,
                "decode_steps": self.decode_steps,
                "decode_device_pick_steps": self.decode_device_pick_steps,
                "decode_ahead_steps": self.decode_ahead_steps,
@@ -952,9 +958,18 @@ class ContinuousBatchingEngine:
         tel = self.telemetry
         if key not in self._signatures:
             self._signatures.add(key)   # lock-ok: HT601 warm_up is the one caller off the scheduler's thread, and runs before the first submit
-            with tel.span("jit_compile", subgraph="serving_engine",
-                          shape_key=str(key)):
+            from jax.tree_util import tree_leaves
+            attrs = {"subgraph": "serving_engine", "shape_key": str(key),
+                     "leaves_in": len(tree_leaves(args))}
+            t0 = tel.clock()
+            # telemetry on or off, a profile says which step compiled
+            with _telemetry.annotate("jit_compile", **attrs):
                 out = fn(*args)
+            attrs["leaves_out"] = len(tree_leaves(out))
+            if key[0] == "decode" and self.program_leaves is None:
+                self.program_leaves = {   # lock-ok: HT601 one reference assigned once, by warm_up before the first submit or else by the scheduler's thread
+                    "in": attrs["leaves_in"], "out": attrs["leaves_out"]}
+            tel.complete("jit_compile", t0, tel.clock(), attrs)
             tel.inc("jit_compiles")
             return out
         with tel.span("device_dispatch", subgraph="serving_engine"):

@@ -200,7 +200,10 @@ def test_device_memory_stats_graceful_on_cpu():
 
 def test_oom_report_names_parameters():
     import jax.numpy as jnp
-    big = jnp.zeros((64, 64), jnp.float32)
+    # large enough to be among the five largest whatever arrays the
+    # worker's earlier test files left alive (a run of the suite found
+    # 4.2 MiB of them above a 16 KiB table)
+    big = jnp.zeros((2048, 1024), jnp.float32)
     text = memory.oom_report(named_params={"my_table": big}, limit=5)
     assert "my_table" in text and "live buffers" in text
     assert memory.is_oom(RuntimeError("RESOURCE_EXHAUSTED: oom"))

@@ -426,3 +426,67 @@ def test_dropout_keeps_the_composed_draw_under_a_dp_mesh(v5e, monkeypatch):
                               sharding=SingleDeviceSharding(v5e[0]))
     assert "tpu_custom_call" in jax.jit(
         lambda x: forward.compute([x], one)).lower(x1).compile().as_text()
+
+
+# -- GPT-2 small's serving programs, a block's vectors stacked (PR 44) -------
+
+@pytest.mark.parametrize("kind,inputs", [
+    ("decode", ((8,), (8,), (8, 1024), (8,))),
+    ("prefill", ((8, 512), (8, 512)))])
+def test_gpt_serving_program_streams_its_matrices_and_aliases_its_pools(
+        one_chip, monkeypatch, kind, inputs):
+    """``hetu_paged_decode`` at (8, 1024) and ``hetu_paged_prefill`` at
+    (8, 512), the serve cell's widths, depth and pool (1,536 + 1 blocks
+    of 16), compiled for the described chip. The donated pools are
+    updated in place (1.81e9 bytes aliased). The block's matrices are an
+    array a layer so that the compiler streams each into VMEM under the
+    layer before (``slice-start``, 4 slices a matrix in the decode
+    program; with the matrices stacked ``[12, ...]`` beside the vectors
+    it streamed none and the program ran a third slower on the chip:
+    PERF.md, PR 44). The names below are how this jax spells an
+    argument's path in the compiled text: after a jax upgrade that
+    renames them, read the new spelling off ``compiled.as_text()``."""
+    import re
+    from hetu_tpu.models.gpt import GPTConfig
+    from hetu_tpu.ops import attention
+    from hetu_tpu.serving.kvcache import PagedKVCache
+    from hetu_tpu.serving.scheduler import _named_program
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setenv("HETU_AUTOTUNE", "0")    # the sweep needs a device
+    cfg = GPTConfig(vocab_size=50257, hidden_size=768,
+                    num_hidden_layers=12, num_attention_heads=12,
+                    max_position_embeddings=1024)
+    model = cfg.serving_model()
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    h, i, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    by_suffix = {"wte": (v, h), "wpe": (1024, h), "lm_head_weights": (h, v),
+                 "qkv_weights": (h, 3 * h), "qkv_bias": (3 * h,),
+                 "attn_proj_weights": (h, h), "fc_weights": (h, i),
+                 "fc_bias": (i,), "mlp_proj_weights": (i, h)}
+
+    def lookup(name):
+        shape = next((s for suffix, s in by_suffix.items()
+                      if name.endswith(suffix)), (h,))
+        return jnp.zeros(shape, jnp.float32)
+
+    params, pools = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: (model.params(lookup),
+                 PagedKVCache(cfg, num_blocks=1536, block_size=16).pools)))
+    assert len(jax.tree_util.tree_leaves(params)) == 5 + 8 + 4 * 12
+    fn, static = model.program(kind)
+    compiled = _named_program(fn, "hetu_paged_" + kind, **static).lower(
+        params, pools, *(jax.ShapeDtypeStruct(s, jnp.int32,
+                                              sharding=one_chip)
+                         for s in inputs)).compile()
+    pool_bytes = 2 * 12 * 1537 * 16 * 768 * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    if kind == "decode":
+        streamed = re.findall(
+            r" slice-start\(%?args_0___blocks____(\w+?)___0__\d+_",
+            compiled.as_text())
+        assert sorted(set(streamed)) == ["fc", "mlp_proj", "proj", "qkv"]
+        assert len(streamed) >= 4 * 4 * 11
