@@ -198,29 +198,34 @@ class BertSelfAttention:
     def __call__(self, hidden_states, attention_mask, seq_len=None):
         seq_len = seq_len or self.seq_len
         shape3 = [-1, seq_len, self.hidden_size]
-        q = self._heads(self.query(hidden_states, shape3), seq_len)
-        k = self._heads(self.key(hidden_states, shape3), seq_len)
-        v = self._heads(self.value(hidden_states, shape3), seq_len)
+        q = self.query(hidden_states, shape3)
+        k = self.key(hidden_states, shape3)
+        v = self.value(hidden_states, shape3)
+        sm_scale = 1.0 / float(np.sqrt(self.head_size))
 
-        if self.sequence_parallel:
-            # ring attention over the "sp" mesh axis; probs-dropout is
-            # skipped exactly as on the flash path
-            from ..ops.attention import ring_attention_op
-            context = ring_attention_op(q, k, v, attention_mask,
-                                        sm_scale=1.0 / float(
-                                            np.sqrt(self.head_size)))
-        elif self.use_flash:
+        if self.use_flash and not self.sequence_parallel:
+            # the three projections' rows go to the op as they lie and
+            # the context comes back as rows: on a TPU the kernels read
+            # and write them token-major in both directions, elsewhere
+            # the op makes the trip through [B, H, S, D] itself.
             # NOTE: the fused kernel keeps attention probs in VMEM and
             # does not implement probs-dropout; attention_probs_dropout
             # is therefore skipped on this path (dropout on the output
             # projection still applies). This matches the usual flash
             # implementations and diverges from the composed path.
             from ..ops.attention import flash_attention_op
-            context = flash_attention_op(q, k, v, attention_mask,
-                                         sm_scale=1.0 / float(
-                                             np.sqrt(self.head_size)))
+            return flash_attention_op(q, k, v, attention_mask,
+                                      sm_scale=sm_scale,
+                                      num_heads=self.num_heads)
+        q, k, v = (self._heads(x, seq_len) for x in (q, k, v))
+        if self.sequence_parallel:
+            # ring attention over the "sp" mesh axis; probs-dropout is
+            # skipped exactly as on the flash path
+            from ..ops.attention import ring_attention_op
+            context = ring_attention_op(q, k, v, attention_mask,
+                                        sm_scale=sm_scale)
         else:
-            k = k * (1.0 / float(np.sqrt(self.head_size)))
+            k = k * sm_scale
             scores = batch_matmul_op(q, k, trans_B=True)
             if attention_mask is not None:
                 scores = scores + broadcastto_op(attention_mask, scores)

@@ -39,9 +39,18 @@ def attention_reference(q, k, v, mask, sm_scale):
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
 
-# sequence length above which the fused Pallas backward beats XLA's
-# composed vjp (below it the S^2 intermediates fit on-chip anyway)
+# HEAD-MAJOR callers only: the sequence length from which the fused
+# Pallas backward (a head a program) beats XLA's composed vjp. A
+# token-major call runs the fused backward at any length (its programs
+# are as wide as the forward's): :func:`fused_backward`
 FUSED_BWD_MIN_SEQ = 512
+
+
+def fused_backward(s, token_major):
+    """Whether a training call's backward is the fused kernel (so its
+    forward keeps the logsumexp), from the shape alone: token-major at
+    any S the form takes, head-major from ``FUSED_BWD_MIN_SEQ``."""
+    return token_major or s >= FUSED_BWD_MIN_SEQ
 
 
 # ---------------------------------------------------------------------------
@@ -289,18 +298,17 @@ def unpartitioned_tpu_step(ectx):
 def flash_layout(s, d, heads, token_major, ectx=None):
     """``(layout, reason)``: which operand form a flash call runs in,
     from what the code can see. ``"token_major"`` (the kernels read
-    q, k and v out of the projection's own rows; ``reason`` None) where
-    (a) the fused backward runs, ``s >= FUSED_BWD_MIN_SEQ``, so both
-    directions skip the trip through ``[B, H, S, D]``; (b) the heads
-    fill whole lane blocks (``TokenMajor.fits``); (c) the step is not
-    partitioned over a mesh (:func:`unpartitioned_tpu_step`); (d) the
-    caller hands token-major operands. Else ``"head_major"`` and the
-    first condition that failed: ``short_seq``, ``lanes``, ``mesh``,
-    ``caller``."""
+    q, k and v out of the projections' own rows in both directions —
+    :func:`fused_backward` — so nothing makes the trip through
+    ``[B, H, S, D]``; ``reason`` None) where (a) the heads fill whole
+    lane blocks and the rows whole lane tiles (``TokenMajor.fits``);
+    (b) the step is not partitioned over a mesh
+    (:func:`unpartitioned_tpu_step`); (c) the caller hands token-major
+    operands. Else ``"head_major"`` and the first condition that
+    failed: ``lanes``, ``mesh``, ``caller``."""
     from .pallas_attention import TokenMajor
     mesh = getattr(getattr(ectx, "config", None), "mesh", None)
     for reason, holds in (
-            ("short_seq", s >= FUSED_BWD_MIN_SEQ),
             ("lanes", TokenMajor(heads, d).fits(s)),
             ("mesh", mesh is None or mesh.size == 1),
             ("caller", token_major)):
@@ -316,22 +324,26 @@ def _seq_len(q, layout):
 
 
 class FlashAttentionOp(Op):
-    """Fused attention, in one of two operand forms. Head-major: q, k, v
-    ``[B, H, S, D]``, the context ``[B, H, S, D]``. Token-major
-    (``num_heads`` given, ``k`` and ``v`` None): ``q`` is a qkv
-    projection's packed rows ``[B, S, 3H]``, the context ``[B, S, H]``
-    and the one gradient ``[B, S, 3H]`` — where :func:`flash_layout`
-    allows, the kernels read and write those rows as they lie and no
-    transpose, split or merge runs around them; elsewhere the op makes
-    the trip through ``[B, H, S, D]`` itself. The additive mask is
+    """Fused attention, in one of three operand forms. Head-major: q, k,
+    v ``[B, H, S, D]``, the context ``[B, H, S, D]``. Token-major
+    (``num_heads`` given), either a qkv projection's packed rows —
+    ``q`` ``[B, S, 3H]``, ``k`` and ``v`` None, the one gradient
+    ``[B, S, 3H]`` — or three projections' rows — q, k, v ``[B, S, H]``,
+    three gradients ``[B, S, H]``, each its own projection's — and the
+    context ``[B, S, H]``: where :func:`flash_layout` allows, the
+    kernels read and write those rows as they lie and no transpose,
+    split or merge runs around them; elsewhere the op makes the trip
+    through ``[B, H, S, D]`` itself. The additive mask is
     ``[B, 1, 1, S]`` (or None) either way."""
 
     def __init__(self, q, k=None, v=None, mask=None, sm_scale=1.0,
                  causal=False, num_heads=None, ctx=None):
-        if (num_heads is None) == (k is None or v is None):
+        if (k is None) != (v is None) or (k is None and not num_heads):
             raise ValueError("flash attention takes q, k, v [B, H, S, D], "
-                             "or packed qkv rows with num_heads")
-        inputs = ([q] if num_heads else [q, k, v]) \
+                             "or with num_heads their rows [B, S, H] or "
+                             "packed qkv rows [B, S, 3H]")
+        self.packed = k is None
+        inputs = ([q] if self.packed else [q, k, v]) \
             + ([mask] if mask is not None else [])
         super().__init__(FlashAttentionOp, inputs, ctx)
         self.has_mask = mask is not None
@@ -341,17 +353,18 @@ class FlashAttentionOp(Op):
 
     def attention_shape(self, q_shape):
         """``(b, h, s, d)`` of the attention from the first input's
-        shape, in either operand form (None where it is neither)."""
+        shape, in any operand form (None where it is none of them)."""
         if self.num_heads and len(q_shape) == 3:
             b, s, width = (int(x) for x in q_shape)
-            return b, self.num_heads, s, width // (3 * self.num_heads)
+            return b, self.num_heads, s, \
+                width // ((3 if self.packed else 1) * self.num_heads)
         if not self.num_heads and len(q_shape) == 4:
             return tuple(int(x) for x in q_shape)
         return None
 
     def operands(self, input_vals, ectx):
         """``(q, k, v, mask, layout, reason)`` as the kernels or the
-        reference take them: packed rows stay as they lie under a
+        reference take them: rows stay as they lie under a
         ``TokenMajor`` layout where the rule allows, and are split into
         ``[B, H, S, D]`` (layout None) where it does not."""
         mask = input_vals[-1] if self.has_mask else None
@@ -360,18 +373,25 @@ class FlashAttentionOp(Op):
             _, reason = flash_layout(q.shape[2], q.shape[3], q.shape[1],
                                      False, ectx)
             return q, k, v, mask, None, reason
-        qkv = input_vals[0]
-        b, h, s, d = self.attention_shape(qkv.shape)
+        # packed: one array, read three times
+        q, k, v = input_vals[:1] * 3 if self.packed else input_vals[:3]
+        b, h, s, d = self.attention_shape(q.shape)
         form, reason = flash_layout(s, d, h, True, ectx)
         if form == "token_major" and _use_pallas():
             from .pallas_attention import TokenMajor
-            return qkv, qkv, qkv, mask, TokenMajor.packed(h, d), None
-        return *self._split(qkv), mask, None, reason
+            return q, k, v, mask, (TokenMajor.packed if self.packed
+                                   else TokenMajor)(h, d), None
+        return *self._split(q, k, v), mask, None, reason
 
-    def _split(self, qkv):
-        """Packed rows ``[B, S, 3H]`` -> q, k, v ``[B, H, S, D]``."""
-        b, h, s, d = self.attention_shape(qkv.shape)
-        return qkv.reshape(b, s, 3, h, d).transpose(2, 0, 3, 1, 4)
+    def _split(self, q, k, v):
+        """Token-major operands — packed rows ``[B, S, 3H]`` three
+        times, or q, k, v rows ``[B, S, H]`` — -> q, k, v
+        ``[B, H, S, D]``."""
+        b, h, s, d = self.attention_shape(q.shape)
+        if self.packed:
+            return q.reshape(b, s, 3, h, d).transpose(2, 0, 3, 1, 4)
+        return [x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+                for x in (q, k, v)]
 
     def compute(self, input_vals, ectx):
         q, k, v, mask, layout, reason = self.operands(input_vals, ectx)
@@ -386,16 +406,17 @@ class FlashAttentionOp(Op):
             # causal is a kernel flag; only the padding mask travels.
             # The logsumexp residual is stashed for the fused backward
             # (the grad op runs later in the same trace) — but only when
-            # something will consume it: training at a length where the
-            # fused path engages. Otherwise skip the residual write.
+            # something will consume it: training, in a form and at a
+            # length where the fused path engages (``fused_backward``).
+            # Otherwise skip the residual write.
             # Block sizes resolve per (S, D, dtype, causal, mask) from
             # the autotune cache at trace time (pallas_attention.py).
             from .pallas_attention import (flash_attention,
                                            flash_attention_with_lse)
             kw = dict(sm_scale=self.sm_scale, causal=self.causal,
                       layout=layout, reason=reason)
-            if getattr(ectx, "training", False) and \
-                    _seq_len(q, layout) >= FUSED_BWD_MIN_SEQ:
+            if getattr(ectx, "training", False) and fused_backward(
+                    _seq_len(q, layout), layout is not None):
                 o, lse = flash_attention_with_lse(q, k, v, mask, **kw)
                 if o is not None:
                     ectx.cache[("flash_res", self.id)] = (o, lse)
@@ -411,13 +432,13 @@ class FlashAttentionOp(Op):
     def gradient(self, output_grad):
         grads = [
             _FlashAttentionGradOp(self, output_grad, i, ctx=self.raw_ctx)
-            for i in range(1 if self.num_heads else 3)]
+            for i in range(1 if self.packed else 3)]
         if self.has_mask:
             grads.append(None)
         return grads
 
     def infer_shape(self, input_shapes):
-        if self.num_heads:
+        if self.packed:
             b, s, width = input_shapes[0]
             return (b, s, width // 3)
         return input_shapes[0]
@@ -427,7 +448,8 @@ class _FlashAttentionGradOp(Op):
     """dq/dk/dv via jax.vjp over the fused forward — one op per operand so
     the graph stays an adjoint DAG (the reference packs/unpacks gradients
     the same way for BN/LN). A forward over packed rows has the one
-    operand and the one gradient, ``[B, S, 3H]``."""
+    operand and the one gradient, ``[B, S, 3H]``; one over three
+    projections' rows three gradients ``[B, S, H]``."""
 
     def __init__(self, forward_op, output_grad, which, ctx=None):
         super().__init__(_FlashAttentionGradOp,
@@ -451,22 +473,24 @@ class _FlashAttentionGradOp(Op):
         if layout is not None and res is None:
             # gradients asked of a step that is not training: the
             # forward kept no residual, so the composed vjp, over heads
-            q, k, v = fwd._split(q)
+            q, k, v = fwd._split(q, k, v)
             layout = None
         if fwd.num_heads and layout is None:
             b, h, s, d = q.shape
             dy = dy.reshape(b, s, h, d).transpose(0, 2, 1, 3)
-        if res is not None and \
-                _seq_len(q, layout) >= FUSED_BWD_MIN_SEQ:
+        if res is not None and fused_backward(_seq_len(q, layout),
+                                              layout is not None):
             # fused Pallas backward: ONE kernel rebuilds each score
             # tile in VMEM from the forward's logsumexp and feeds dQ,
             # dK and dV from it — the S x S matrices never hit HBM on
-            # the backward either (pallas_attention.py). Below the
-            # threshold the composed vjp stays: XLA fuses the small S^2
-            # intermediates on-chip anyway (measured with the older
-            # two-kernel backward: S=128 BERT-base 120k tok/s composed
-            # vs 100k fused; S=2048 186k composed vs 226k fused; the
-            # one-pass kernel alone at BERT's shape: PERF.md section 7).
+            # the backward either (pallas_attention.py). Token-major
+            # at any length: its programs take as many heads as the
+            # forward's, and the rows need no trip through
+            # ``[B, H, S, D]``, which the composed form can never
+            # spare (BERT-base, S = 128: PERF.md PR 45). Head-major
+            # below the threshold the composed vjp stays: a head a
+            # program, the kernel is 3,072 grid steps a layer there
+            # (PERF.md section 7 has both probes).
             from .pallas_attention import flash_attention_bwd
             o, lse = res
             grads = flash_attention_bwd(
@@ -486,12 +510,13 @@ class _FlashAttentionGradOp(Op):
             grads = vjp(dy)
         if not fwd.num_heads:
             return grads
-        if layout is not None:
-            # the rows the qkv projection's dW and dX matmuls read
-            return (jnp.concatenate(grads, axis=-1),)
-        b, h, s, d = q.shape
-        return (jnp.stack(grads).transpose(1, 3, 0, 2, 4).reshape(
-            b, s, 3 * h * d),)
+        if layout is None:      # back to the rows the operands came as
+            b, h, s, d = q.shape
+            grads = [g.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+                     for g in grads]
+        # the rows each projection's dW and dX matmuls read
+        return (jnp.concatenate(grads, axis=-1),) if fwd.packed \
+            else tuple(grads)
 
     def gradient(self, output_grad):
         raise NotImplementedError
@@ -503,8 +528,9 @@ class _FlashAttentionGradOp(Op):
 def flash_attention_op(q, k=None, v=None, mask=None, sm_scale=1.0,
                        causal=False, num_heads=None, ctx=None):
     """Fused attention over q, k, v ``[B, H, S, D]``, or — ``num_heads``
-    given, k and v left out — over a qkv projection's packed rows
-    ``[B, S, 3H]``; see :class:`FlashAttentionOp`."""
+    given — over their rows ``[B, S, H]`` or, k and v left out, over a
+    qkv projection's packed rows ``[B, S, 3H]``; see
+    :class:`FlashAttentionOp`."""
     return FlashAttentionOp(q, k, v, mask, sm_scale, causal, num_heads,
                             ctx=ctx)
 

@@ -15,35 +15,43 @@ both kernels and their tests). The [S, S] score matrix never exists in
 HBM in either direction, so attention memory is O(S·D) instead of
 O(S²) (the property training needs for long context).
 
-Forward: a program is one region ROW of its heads — head-major a block
-of neighbouring heads (as many as leave a region within its bounds,
-``_fwd_heads``: at S = 128, where a head is one tile pair, all twelve
-of a BERT-base batch row; one or two at S = 1024), token-major the heads
-of one lane block — whose whole K and V stay resident in VMEM. Inside a
-region every q-tile meets its k-tiles in ONE softmax step (all its
-score tiles, one row max, the exps, one row sum, the context matmuls),
-so the running (max, sum, accumulator) is rescaled once a region, and
-not at all where one region is the whole head (the GPT-2 train cell:
-no loop, no running state, eight chains a program). The scale goes
-onto q where that is exact (``_scales_q``). It also emits the per-row
-logsumexp L when a backward will read it.
+A program's heads are ONE rule for both kernels
+(``heads_per_program``): where a head is few tile pairs — one at
+S = 128 — a program takes as many neighbouring heads as leave its
+region within the bounds, each its own straight-line chains: all twelve
+of a BERT-base batch row at S = 128 (256 programs a layer where a head
+a program is 3,072 grid steps of 13 MFLOP each), one or two at S = 1024.
+
+Forward: a program is one region ROW of its heads, whose whole K and V
+stay resident in VMEM. Inside a region every q-tile meets its k-tiles
+in ONE softmax step (all its score tiles, one row max, the exps, one
+row sum, the context matmuls), so the running (max, sum, accumulator)
+is rescaled once a region, and not at all where one region is the
+whole head (the GPT-2 train cell: no loop, no running state, eight
+chains a program). The scale goes onto q where that is exact
+(``_scales_q``). It also emits the per-row logsumexp L when a backward
+will read it.
 
 Backward is the standard recompute form, in ONE kernel a call: the
 kernel visits each pair once, rebuilds its score tile in VMEM from L
 and feeds dV, dK and dQ from that one P / dS, every sum across tiles in
 float32 and rounded once. The tile is built transposed
 (``[block_k, block_q]``), so the row residuals L and D = rowsum(dO ∘ O)
-(a cheap XLA elementwise reduce outside the kernel) travel as
+(an XLA elementwise reduce outside the kernel; where one k-tile is a
+head's whole row the kernel sums it itself, as rowsum(P ∘ dP) over the
+tile's sublanes, and no pass over dO and O runs) travel as
 ``[B*H, 1, S]`` rows and every matmul of a pair is a plain one. A
-program is a (batch*head, region row) of K / V, walking the q-regions
-from the diagonal down.
+program is a region row of K / V of its heads (head-major one head),
+walking the q-regions from the diagonal down.
 
 Operands come in one of two forms (``ops/attention.py:flash_layout``
-picks by what the code can see). Head-major: q, k, v ``[B, H, S, D]``,
-a program a head. Token-major (:class:`TokenMajor`): ``[B, S, lanes]``
-rows as a projection writes them — one packed ``[B, S, 3H]`` array may
-be read three times through three index maps — a program one lane
-block of ``128 // D`` heads, split inside the kernel by static lane
+picks by what the code can see). Head-major: q, k, v ``[B, H, S, D]``.
+Token-major (:class:`TokenMajor`): ``[B, S, lanes]`` rows as a
+projection writes them — one packed ``[B, S, 3H]`` array read three
+times through three index maps, or the three ``[B, S, H]`` arrays of
+three projections — in BOTH directions: a program owns whole lane
+blocks of ``128 // D`` heads (one block at S = 1024, all six of a
+BERT-base row at S = 128), split inside the kernel by static lane
 windows; the context, dq, dk and dv leave as rows and the residuals as
 ``[B, H, 1, S]`` rows, so no transpose, split or merge runs around the
 calls. The kernel bodies are shared (the head's lane window is a static
@@ -106,11 +114,13 @@ def _forward_compiler_params(s, span, block_q, block_k, lanes, heads,
 class TokenMajor(NamedTuple):
     """The operand form of a call whose q, k and v lie as a projection
     wrote them: ``[B, S, lanes]`` rows, a row's heads side by side. A
-    grid step then owns one lane BLOCK of a batch row — ``width`` =
-    ``max(head_dim, 128)`` lanes, ``per_block`` heads — and the kernels
-    split it by static lane windows. ``tiles`` are the lane-block
+    grid step then owns whole lane BLOCKS of a batch row — a block
+    ``width`` = ``max(head_dim, 128)`` lanes, ``per_block`` heads; how
+    many blocks is ``heads_per_program``'s to say — and the kernels
+    split them by static lane windows. ``tiles`` are the lane-block
     offsets of q, k and v in their arrays, so ONE packed ``[B, S, 3H]``
-    array (a qkv projection's rows) may be passed three times; the
+    array (a qkv projection's rows) may be passed three times, and
+    three projections' ``[B, S, H]`` arrays as they are; the
     context, dq, dk and dv are ``[B, S, H]`` and the row residuals
     ``[B, heads, 1, S]``, the rows the backward reads (no reshape lies
     between the two kernels: XLA would copy one into other tiles).
@@ -167,9 +177,10 @@ def _scales_q(sm_scale, dtype):
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
                 block_q, block_k, span, seq_len, causal, head_dim=None):
     """One region row's program: the q rows ``[qi*span, (qi+1)*span)``
-    of the program's heads — head-major a block of neighbouring heads
-    (``_fwd_heads``), token-major the heads of one lane block, each its
-    static lane window — against their whole K and V, resident in VMEM.
+    of the program's heads (``heads_per_program``) — head-major a block
+    of neighbouring heads, token-major the heads of its lane blocks,
+    each its static lane window — against their whole K and V, resident
+    in VMEM.
     The square is walked in REGIONS of ``span`` rows a side, as the
     backward walks it (``_region_span``): the regions left of the
     diagonal by a loop (every pair, no mask), the one ON it last, where
@@ -272,10 +283,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
             lambda kr, state: region(kr, state, diagonal=False), start)
         if causal:
             done = region(qi, done, diagonal=True)
+    # a program several lane blocks wide lays its heads' logsumexp
+    # columns side by side and transposes them ONCE a q-tile (a
+    # transpose a head is the longest thing such a short head does)
+    gathered = l_ref is not None and head_dim is not None \
+        and q_ref.shape[-1] > max(head_dim, LANES)
     for n, (g, lanes) in enumerate(heads):
         for r, (m, l, acc) in zip(rows, done[n]):
             o_ref[g, r, lanes] = (acc * (1.0 / l)).astype(o_ref.dtype)
-            if l_ref is None:
+            if l_ref is None or gathered:
                 continue
             # per-row logsumexp, the backward's softmax residual
             lse = jnp.broadcast_to(m + jnp.log(l), (block_q, LANES))
@@ -286,6 +302,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
             else:
                 # token-major: the row the backward reads, [1, rows]
                 l_ref[0, n, :, r] = lse.T[0:1]
+    if gathered:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, LANES), 1)
+        for i, r in enumerate(rows):
+            tile = jnp.zeros((block_q, LANES), jnp.float32)
+            for n in range(len(heads)):
+                m, l, _ = done[n][i]
+                tile = jnp.where(lane == n, m + jnp.log(l), tile)
+            tile = tile.T                       # a head a sublane
+            for n in range(len(heads)):
+                l_ref[0, n, :, r] = tile[n:n + 1]
 
 
 def _block_sizes(seq_len, head_dim):
@@ -558,12 +584,12 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
                          block_q, block_k, need_lse, layout=None):
     """``layout`` None: q, k, v ``[B, H, S, D]``, a grid step a region
     row of a block of neighbouring heads. A :class:`TokenMajor`:
-    ``[B, S, lanes]`` rows, a grid step a region row of one lane block
-    of heads; the same kernel body either way, and the same event name
-    in a device trace."""
+    ``[B, S, lanes]`` rows, a grid step a region row of the lane blocks
+    that hold its heads (``heads_per_program``); the same kernel body
+    either way, and the same event name in a device trace."""
     b, h, s, d = _dims(q, layout)
     span = _region_span(s, block_q, block_k)
-    group = _fwd_heads(h, s, block_q, block_k, layout)
+    group = heads_per_program(h, s, block_q, block_k, layout)
     if layout is None:  # jit-ok: static argname
         grid = (b * h // group, s // span)
         args = [x.reshape(b * h, s, d) for x in (q, k, v)]
@@ -579,17 +605,24 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
         o_spec = pl.BlockSpec((group, span, d), rows)
         l_shape = jax.ShapeDtypeStruct((b * h, s, LANES), jnp.float32)
         l_spec = pl.BlockSpec((group, span, LANES), rows)
+        lanes = group * -(-d // LANES) * LANES
     else:
-        w, tq, tk, tv = layout.width, *layout.tiles
-        grid = (b, layout.blocks, s // span)
+        # a program's block: ``wide`` lane blocks side by side, so the
+        # offsets of q, k and v count in blocks that wide
+        wide = group // layout.per_block
+        lanes = wide * layout.width
+        tq, tk, tv = (t // wide for t in layout.tiles)
+        grid = (b, layout.blocks // wide, s // span)
         args = [q, k, v]
         in_specs = [
-            pl.BlockSpec((1, span, w), lambda bi, p, qi: (bi, qi, tq + p)),
-            pl.BlockSpec((1, s, w), lambda bi, p, qi: (bi, 0, tk + p)),
-            pl.BlockSpec((1, s, w), lambda bi, p, qi: (bi, 0, tv + p))]
+            pl.BlockSpec((1, span, lanes),
+                         lambda bi, p, qi: (bi, qi, tq + p)),
+            pl.BlockSpec((1, s, lanes), lambda bi, p, qi: (bi, 0, tk + p)),
+            pl.BlockSpec((1, s, lanes), lambda bi, p, qi: (bi, 0, tv + p))]
         mask_spec = pl.BlockSpec((1, 1, s), lambda bi, p, qi: (bi, 0, 0))
         o_shape = jax.ShapeDtypeStruct((b, s, h * d), q.dtype)
-        o_spec = pl.BlockSpec((1, span, w), lambda bi, p, qi: (bi, qi, p))
+        o_spec = pl.BlockSpec((1, span, lanes),
+                              lambda bi, p, qi: (bi, qi, p))
         # the residual leaves as the rows the backward takes
         l_shape = jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)
         l_spec = pl.BlockSpec((1, group, 1, span),
@@ -614,10 +647,8 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
             def kernel(q_ref, k_ref, v_ref, o_ref):
                 body(q_ref, k_ref, v_ref, None, o_ref, None)
 
-    params = _forward_compiler_params(
-        s, span, block_q, block_k,
-        group * -(-d // LANES) * LANES if layout is None else w, group,
-        q.dtype.itemsize)
+    params = _forward_compiler_params(s, span, block_q, block_k, lanes,
+                                      group, q.dtype.itemsize)
     if need_lse:  # jit-ok: static argname
         # the lse residual is emitted only when a consumer exists (the
         # fused backward); the inference/serving forward skips the write
@@ -717,29 +748,37 @@ def _region_span(s, block_q, block_k):
                default=tile)
 
 
-def _fwd_heads(h, s, block_q, block_k, layout=None):
-    """Heads a forward program takes. Token-major: the heads of a lane
-    block. Head-major, where one region is the whole head and leaves
-    room within the two bounds above, the region fills up with
-    neighbouring heads: the largest divisor of ``h`` (so a program's
-    heads share their batch row's mask row) — at S = 128 a head is ONE
-    pair, and a program a head would be a grid step's price 3,072 times
-    a layer for 13 MFLOP each (BERT-base, PERF.md PR 40)."""
-    if layout is not None:
-        return layout.per_block
-    pairs = (s // block_q) * (s // block_k)
+def heads_per_program(h, s, block_q, block_k, layout=None):
+    """Heads a program takes — ONE rule, read by both kernels' grids and
+    block specs and by the walk instants. Where one region is the whole
+    head and leaves room within the two bounds above, the region fills
+    up with neighbouring heads, each its own straight-line chains — at
+    S = 128 a head is ONE pair, and a program a head would be a grid
+    step's price 3,072 times a layer for 13 MFLOP each (BERT-base,
+    PERF.md PRs 40, 45). Head-major (the forward; the head-major
+    backward is a head a program): the largest divisor of ``h``, so a
+    program's heads share their batch row's mask row. Token-major (both
+    directions): whole lane blocks, the largest count that divides the
+    row's blocks and the q / k / v offsets (so the three index maps
+    stay whole): all twelve heads of a BERT-base batch row at S = 128,
+    256 programs a layer; ONE lane block at S = 1024, whose two heads
+    alone are past the row bound."""
+    unit = 1 if layout is None else layout.per_block
     if _region_span(s, block_q, block_k) != s:
-        return 1
-    return max(g for g in range(1, h + 1)
-               if h % g == 0 and (g == 1 or (
-                   g * pairs <= _REGION_TILES and g * s <= _REGION_ROWS)))
+        return unit
+    pairs = (s // block_q) * (s // block_k)
+    units = h if layout is None else math.gcd(layout.blocks, *layout.tiles)
+    return unit * max(g for g in range(1, units + 1)
+                      if units % g == 0 and (g == 1 or (
+                          g * unit * pairs <= _REGION_TILES
+                          and g * unit * s <= _REGION_ROWS)))
 
 
 def fwd_walk_counts(h, s, block_q, block_k, causal, layout=None):
     """What a forward call at these tiles runs: the walk's counts over
     the square, the heads a program takes and its independent chains
     (a q-tile of a head each)."""
-    heads = _fwd_heads(h, s, block_q, block_k, layout)
+    heads = heads_per_program(h, s, block_q, block_k, layout)
     span = _region_span(s, block_q, block_k)
     return {**tile_walk_counts(s, block_q, block_k, causal),
             "heads_per_program": heads,
@@ -765,9 +804,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
     outputs; else they add up in the float32 scratch ``acc`` — dK / dV
     over the program's walk, dQ (``[D, S]``) across the programs of a
     head — and are rounded once at the end. Token-major (``head_dim``
-    given) the blocks hold several heads side by side: each head makes
-    that same walk over its own lane window, with its own residual rows
-    and its own three accumulators."""
+    given) the blocks hold several heads side by side
+    (``heads_per_program``): each head makes that same walk over its
+    own lane window, with its own residual rows and its own three
+    accumulators — where a head is one pair, twelve heads of
+    straight-line code a program."""
     region_axis = 1 if head_dim is None else 2
     kj = pl.program_id(region_axis)
     nt = (((1,), (1,)), ((), ()))             # a @ b^T
@@ -824,7 +865,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
                         preferred_element_type=jnp.float32)
                     dpt = jax.lax.dot_general(
                         v, do, nt, preferred_element_type=jnp.float32)
-                    dst = (pt * (dpt - resid(d_ref, rows[i]))
+                    # D: the row residual where k-tiles share a row,
+                    # else (one k-tile IS the row) summed here:
+                    # rowsum(P o dP) = rowsum(dO o O), keys on sublanes
+                    row_d = resid(d_ref, rows[i]) if d_ref is not None \
+                        else jnp.sum(pt * dpt, axis=0, keepdims=True)
+                    dst = (pt * (dpt - row_d)
                            * sm_scale).astype(q.dtype)            # dS^T
                     dk = dk + jax.lax.dot_general(
                         dst, q, nn, preferred_element_type=jnp.float32)
@@ -870,16 +916,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
 
 
 def _backward_compiler_params(s, d, span, block_q, block_k, itemsize,
-                              per_block=1, grid_rank=2):
+                              heads=1, grid_rank=2):
     """The region axis is a reduction into ``dq_acc`` (sequential); the
     VMEM asked for covers a head's q, dO and dQ and a region's K, V, dK
     and dV (double-buffered), the float32 accumulators (dK's and dV's
-    once a head of the block) and the score-tile temporaries of a
+    once a head of the program) and the score-tile temporaries of a
     region's pairs, which at the largest candidate tiles pass the
     16 MiB a kernel gets unasked."""
-    lanes = -(-d * per_block // LANES) * LANES
+    lanes = -(-d * heads // LANES) * LANES
     resident = (3 * 2 * itemsize + 4) * s * lanes \
-        + 2 * (4 * itemsize + 4 * per_block) * span * lanes
+        + 2 * (4 * itemsize + 4 * heads) * span * lanes
     tiles = 6 * 4 * max(block_q * block_k, span * span // 4)
     return pltpu.CompilerParams(
         dimension_semantics=("parallel",) * (grid_rank - 1)
@@ -899,8 +945,13 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
     b, h, s, d = _dims(q, layout)
     span = _region_span(s, block_q, block_k)
 
+    # one k-tile is a head's whole row: the kernel sums D from the P
+    # and dP it holds anyway (``_bwd_kernel``), and neither the pass
+    # over dO and O nor that residual exists
+    sums_d = block_k == s
+
     def row_sums(heads_shape):
-        # D = rowsum(dO * O): cheap XLA elementwise reduce. The row
+        # D = rowsum(dO * O): an XLA elementwise reduce. The row
         # residuals travel as rows (S on lanes, where the transposed
         # score tile wants them), not broadcast over lanes
         return jnp.sum((do.astype(jnp.float32)
@@ -910,16 +961,16 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
     if layout is None:  # jit-ok: static argname
         grid = (b * h, s // span)
         args = [x.reshape(b * h, s, d) for x in (q, k, v, do)]
-        args += [lse.reshape(b * h, 1, s).astype(jnp.float32),
-                 row_sums((b, h, s, d)).reshape(b * h, 1, s)]
+        resids = [lse.reshape(b * h, 1, s).astype(jnp.float32)]
+        if not sums_d:  # jit-ok: static argnames
+            resids.append(row_sums((b, h, s, d)).reshape(b * h, 1, s))
         head = lambda bh, kj: (bh, 0, 0)          # noqa: E731
         keys = lambda bh, kj: (bh, kj, 0)         # noqa: E731
         in_specs = [pl.BlockSpec((1, s, d), head),
                     pl.BlockSpec((1, span, d), keys),
                     pl.BlockSpec((1, span, d), keys),
-                    pl.BlockSpec((1, s, d), head),
-                    pl.BlockSpec((1, 1, s), head),
-                    pl.BlockSpec((1, 1, s), head)]
+                    pl.BlockSpec((1, s, d), head)]
+        resid = pl.BlockSpec((1, 1, s), head)
         # per KEY, so a column of the transposed tile: [B, S, 1]
         mask_spec = pl.BlockSpec(
             (1, span, 1), lambda bh, kj, _h=h: (bh // _h, kj, 0))
@@ -928,14 +979,20 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
         out_specs = [pl.BlockSpec((1, s, d), head),
                      pl.BlockSpec((1, span, d), keys),
                      pl.BlockSpec((1, span, d), keys)]
-        per_block = 1
+        group = 1
     else:
-        w, per_block = layout.width, layout.per_block
-        tq, tk, tv = layout.tiles
-        grid = (b, layout.blocks, s // span)
-        args = [q, k, v, do, lse.reshape(b, h, 1, s).astype(jnp.float32),
-                row_sums((b, s, h, d)).transpose(0, 2, 1).reshape(
-                    b, h, 1, s)]
+        # as wide as the forward's programs where a head is few tiles
+        # (``heads_per_program``, at the BACKWARD's tiles)
+        group = heads_per_program(h, s, block_q, block_k, layout)
+        wide = group // layout.per_block
+        lanes = wide * layout.width
+        tq, tk, tv = (t // wide for t in layout.tiles)
+        grid = (b, layout.blocks // wide, s // span)
+        args = [q, k, v, do]
+        resids = [lse.reshape(b, h, 1, s).astype(jnp.float32)]
+        if not sums_d:  # jit-ok: static argnames
+            resids.append(row_sums((b, s, h, d)).transpose(
+                0, 2, 1).reshape(b, h, 1, s))
 
         def head(tile):
             return lambda bi, p, kj: (bi, 0, tile + p)
@@ -943,30 +1000,40 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
         def keys(tile):
             return lambda bi, p, kj: (bi, kj, tile + p)
 
-        resid = pl.BlockSpec((1, per_block, 1, s),
+        resid = pl.BlockSpec((1, group, 1, s),
                              lambda bi, p, kj: (bi, p, 0, 0))
-        in_specs = [pl.BlockSpec((1, s, w), head(tq)),
-                    pl.BlockSpec((1, span, w), keys(tk)),
-                    pl.BlockSpec((1, span, w), keys(tv)),
-                    pl.BlockSpec((1, s, w), head(0)), resid, resid]
+        in_specs = [pl.BlockSpec((1, s, lanes), head(tq)),
+                    pl.BlockSpec((1, span, lanes), keys(tk)),
+                    pl.BlockSpec((1, span, lanes), keys(tv)),
+                    pl.BlockSpec((1, s, lanes), head(0))]
         mask_spec = pl.BlockSpec((1, span, 1),
                                  lambda bi, p, kj: (bi, kj, 0))
         out_shape = [jax.ShapeDtypeStruct((b, s, h * d), x.dtype)
                      for x in (q, k, v)]
-        out_specs = [pl.BlockSpec((1, s, w), head(0)),
-                     pl.BlockSpec((1, span, w), keys(0)),
-                     pl.BlockSpec((1, span, w), keys(0))]
+        out_specs = [pl.BlockSpec((1, s, lanes), head(0)),
+                     pl.BlockSpec((1, span, lanes), keys(0)),
+                     pl.BlockSpec((1, span, lanes), keys(0))]
     body = functools.partial(_bwd_kernel, sm_scale=sm_scale,
                              block_q=block_q, block_k=block_k, span=span,
                              seq_len=s, causal=causal,
                              head_dim=None if layout is None else d)
+    args += resids
+    in_specs += [resid] * len(resids)
+    # of the kernel's optional inputs: D (5), the mask (6)
+    absent = [5] if sums_d else []
     if mask is not None:  # jit-ok: structural None-check, not a traced read
         in_specs.append(mask_spec)
         args.append(_mask_rows(mask, b, h, s).reshape(b, s, 1))
-        kernel = body
     else:
-        def kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, *rest):
-            body(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, None, *rest)
+        absent.append(6)
+    if absent:
+        def kernel(*refs):
+            refs = list(refs)
+            for at in absent:
+                refs.insert(at, None)
+            body(*refs)
+    else:
+        kernel = body
 
     dq, dk, dv = pl.pallas_call(
         kernel,
@@ -976,12 +1043,12 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
         out_specs=out_specs,
         # float32 sums across regions, a head: dQ^T over its programs,
         # dK and dV over a program's walk
-        scratch_shapes=[] if span == s else per_block * [
+        scratch_shapes=[] if span == s else group * [
             pltpu.VMEM((d, s), jnp.float32),
             pltpu.VMEM((span, d), jnp.float32),
             pltpu.VMEM((span, d), jnp.float32)],
         compiler_params=_backward_compiler_params(
-            s, d, span, block_q, block_k, q.dtype.itemsize, per_block,
+            s, d, span, block_q, block_k, q.dtype.itemsize, group,
             len(grid)),
         interpret=interpret,
     )(*args)
@@ -999,14 +1066,17 @@ def flash_attention_bwd(q, k, v, mask, o, lse, do, sm_scale=1.0,
     (flash_attention_with_lse). Block sizes tune independently of the
     forward's; with ``causal`` the tiles also decide how much of the
     square the walk skips (``tile_walk_counts``, recorded here at trace
-    time as a ``flash_bwd_walk`` instant)."""
+    time as a ``flash_bwd_walk`` instant, beside the heads a program
+    takes)."""
     interpret, (block_q, block_k) = _plan(
         "bwd", q, mask, sm_scale, causal, interpret, layout, reason)
-    s, d = _dims(q, layout)[2:]
+    _, h, s, d = _dims(q, layout)
     from .. import telemetry
     telemetry.get_telemetry().instant(
         "flash_bwd_walk", seq=s, head_dim=d, block_q=block_q,
         block_k=block_k, causal=bool(causal),
+        heads_per_program=1 if layout is None else heads_per_program(
+            h, s, block_q, block_k, layout),
         **tile_walk_counts(s, block_q, block_k, causal))
     return _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale,
                                     causal, interpret, block_q, block_k,

@@ -182,10 +182,12 @@ SPAN_SCHEMA = {
                    "blocks": _opt(_STR), "seq": _opt(_INT),
                    "head_dim": _opt(_INT), "dtype": _opt(_STR)},
     # the flash backward's tile walk, recorded beside its tiles at
-    # trace time (ops/pallas_attention.py:flash_attention_bwd)
+    # trace time (ops/pallas_attention.py:flash_attention_bwd), and
+    # the heads a program takes (pallas_attention.heads_per_program)
     "flash_bwd_walk": {"seq": _req(_INT), "head_dim": _opt(_INT),
                        "block_q": _req(_INT), "block_k": _req(_INT),
                        "causal": _req(_BOOL),
+                       "heads_per_program": _opt(_INT),
                        "tiles_visited": _req(_INT),
                        "tiles_square": _req(_INT),
                        "tiles_masked": _req(_INT),
@@ -208,7 +210,7 @@ SPAN_SCHEMA = {
     # pallas_attention.py:_plan): token_major reads q, k, v out of the
     # projection's rows, heads_per_block heads a program; head_major
     # names the first condition of ops/attention.py:flash_layout that
-    # kept it there (short_seq / lanes / mesh / caller)
+    # kept it there (lanes / mesh / caller)
     "flash_layout": {"kernel": _req(_STR), "layout": _req(_STR),
                      "heads_per_block": _req(_INT), "seq": _req(_INT),
                      "head_dim": _req(_INT), "reason": _opt(_STR)},

@@ -58,7 +58,13 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
     same rows (context ``[B, S, H]`` and d(qkv) ``[B, S, 3H]``):
     ``head_major`` with the split, the transposes and the merge XLA
     runs around the kernels, ``token_major`` with the one
-    concatenation. ``bwd_walk`` is the one-pass
+    concatenation; and from three projections' ``[B, S, H]`` arrays
+    to three gradients (an encoder's call): ``rows`` token-major in
+    both directions, ``composed`` the head-major forward and
+    ``jax.vjp`` of the reference with the transposes around them (what
+    a head-major call below ``FUSED_BWD_MIN_SEQ`` runs;
+    ``composed_vjp_ms`` is that vjp jitted alone over ``[B, H, S, D]``,
+    its forward inside it). ``bwd_walk`` is the one-pass
     backward's tile walk at its tiles (``pallas_attention.
     tile_walk_counts``): with ``causal`` the share of the square it
     visits and the share of visited tiles that carry the mask;
@@ -137,15 +143,28 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
             q, k, v, mask, o, lse, do, sm_scale, causal, interpret,
             bq, bk)
 
+    from ..ops.attention import attention_reference, flash_layout
+
+    def reference(q_, k_, v_):
+        m = mask
+        if causal:
+            cm = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), 0.0,
+                           -1e9)[None, None]
+            m = cm if m is None else m + cm
+        return attention_reference(q_, k_, v_, m, sm_scale)
+
+    composed_vjp = jax.jit(
+        lambda q_, k_, v_, dy: jax.vjp(reference, q_, k_, v_)[1](dy))
     plan = [("fwd_ms", run_fwd(tuned["fwd"], False), tuned["fwd"]),
             ("fwd_lse_ms", run_fwd(tuned["fwd_lse"], True),
              tuned["fwd_lse"]),
-            ("bwd_ms", run_bwd(tuned["bwd"]), tuned["bwd"])]
+            ("bwd_ms", run_bwd(tuned["bwd"]), tuned["bwd"]),
+            ("composed_vjp_ms", lambda: composed_vjp(q, k, v, do),
+             tuned["bwd"])]
     if include_static:
         plan += [("static_fwd_ms", run_fwd(static, False), static),
                  ("static_fwd_lse_ms", run_fwd(static, True), static),
                  ("static_bwd_ms", run_bwd(static), static)]
-    from ..ops.attention import flash_layout
     form, reason = flash_layout(seq, head_dim, heads, True)
     packed = pk.TokenMajor.packed(heads, head_dim)
     out["flash_layout"] = {
@@ -160,7 +179,9 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
         out["token_major"] = {
             "blocks": {kind: list(b) for kind, b in tm.items()},
             "fwd_walk": pk.fwd_walk_counts(heads, seq, *tm["fwd_lse"],
-                                           causal, packed)}
+                                           causal, packed),
+            "bwd_heads_per_program": pk.heads_per_program(
+                heads, seq, *tm["bwd"], packed)}
 
         def tm_fwd(need_lse):
             bq, bk = tm["fwd_lse" if need_lse else "fwd"]
@@ -203,9 +224,42 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
             jitted = jax.jit(run)
             return lambda: jitted(rows, d_ctx)
 
+        apart = pk.TokenMajor(heads, head_dim)
+        three = jnp.split(rows, 3, axis=-1)
+
+        def to_heads(x):
+            return x.reshape(batch, seq, heads, head_dim).transpose(
+                0, 2, 1, 3)
+
+        def to_rows(x):
+            return x.transpose(0, 2, 1, 3).reshape(d_ctx.shape)
+
+        def encoder_layer(fused):
+            """Three ``[B, S, H]`` arrays -> (context rows, dq, dk, dv
+            rows)."""
+            def run(q_, k_, v_, dy):
+                if fused:
+                    o_, l_ = pk._flash_attention_jit(
+                        q_, k_, v_, mask, sm_scale, causal, interpret,
+                        *tm["fwd_lse"], True, apart)
+                    return (o_,) + tuple(pk._flash_attention_bwd_jit(
+                        q_, k_, v_, mask, o_, l_, dy, sm_scale, causal,
+                        interpret, *tm["bwd"], apart))
+                heads_ = [to_heads(x) for x in (q_, k_, v_)]
+                o_ = pk._flash_attention_jit(
+                    *heads_, mask, sm_scale, causal, interpret,
+                    *tuned["fwd"], False)
+                grads = jax.vjp(reference, *heads_)[1](to_heads(dy))
+                return (to_rows(o_),) + tuple(to_rows(g) for g in grads)
+            jitted = jax.jit(run)
+            return lambda: jitted(*three, d_ctx)
+
         plan += [("layer_ms.head_major", layer(None, tuned),
                   tuned["bwd"]),
-                 ("layer_ms.token_major", layer(packed, tm), tm["bwd"])]
+                 ("layer_ms.token_major", layer(packed, tm), tm["bwd"]),
+                 ("layer_ms.rows", encoder_layer(True), tm["bwd"]),
+                 ("layer_ms.composed", encoder_layer(False),
+                  tuned["fwd"])]
     for name, run, blocks in plan:
         t0 = tel.clock()
         wall0 = time.perf_counter()

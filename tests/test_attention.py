@@ -452,7 +452,7 @@ def test_grouped_heads_take_their_own_batch_rows_mask(s, tiles, group):
     next), alone and with ``causal``."""
     from hetu_tpu.ops import pallas_attention as pk
     b, h, d = 3, 12, 16
-    assert pk._fwd_heads(h, s, *tiles) == group
+    assert pk.heads_per_program(h, s, *tiles) == group
     assert pk.fwd_walk_counts(h, s, *tiles, False)["chains"] \
         == group * (s // tiles[0])
     q, k, v = _qkv(b=b, h=h, s=s, d=d, seed=31)

@@ -13,7 +13,10 @@ backward (one kernel since PR 36); plus the smallest and the largest
 GPT-2 train cell's own shape (b16) over the corners of its candidate
 grid, and the forward that walks regions (PR 40) at that cell's packed
 rows, at BERT's cell (twelve heads a program) and head-major at
-S = 8,192, D = 192. A compile that passes is a compile, not a run.
+S = 8,192, D = 192; and both kernels token-major at BERT's cell, a
+batch row's twelve heads a program (PR 45), alone and in BERT-base's
+whole train step, which then holds no ``[256, 12, 128, 64]`` array at
+all. A compile that passes is a compile, not a run.
 
 LayerNorm's backward kernel (``ops/pallas_norm.py``) compiles at the
 two shapes the train cells run it at, ``[16384, 768]`` (GPT-2 small,
@@ -285,7 +288,7 @@ def test_grouped_forward_compiles_at_berts_cell(one_chip, kind):
     takes the ``[12, 128, 64]`` blocks."""
     shape = (256, 12, 128, 64, False, True)
     blocks = pk._block_sizes(128, 64)
-    assert pk._fwd_heads(12, 128, *blocks) == 12
+    assert pk.heads_per_program(12, 128, *blocks) == 12
     text = _compile(kind, shape, blocks, one_chip)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "bf16[3072,128,64]" in text
@@ -302,7 +305,7 @@ def test_region_forward_compiles_at_the_longest_prefill(one_chip, blocks):
     s, d = 8192, 192
     blocks = blocks or pk._block_sizes(s, d)
     assert pk._region_span(s, *blocks) < s
-    assert pk._fwd_heads(64, s, *blocks) == 1
+    assert pk.heads_per_program(64, s, *blocks) == 1
     text = _compile("fwd", (1, 4, s, d, True, False), blocks, one_chip)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
@@ -368,6 +371,28 @@ def test_gpt2_step_holds_the_dropout_mask_kernel(v5e, monkeypatch):
     assert "hetu_dropout_mask" not in off
 
 
+def _flash_calls(text):
+    """{call name without its number: [(result type, operand types)]}
+    of the flash custom calls of a compiled step, every type without
+    its layout."""
+    import re
+    bare = lambda t: re.sub(r"\{[^{}]*\}", "", t)      # noqa: E731
+    defined = {m.group(1): bare(m.group(2)) for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) [\w\-]+\(", text,
+        re.MULTILINE)}
+    calls = {}
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%_flash_attention\w*?)(?:\.\d+)? = "
+                     r"(\(.*?\)|\S+) custom-call\((.*?)\), custom_call",
+                     ln)
+        if m:
+            operands = [defined[o.split("*/")[-1].strip()]
+                        for o in m.group(3).split(", ")]
+            calls.setdefault(m.group(1), []).append(
+                (bare(m.group(2)), operands))
+    return calls
+
+
 def test_gpt2_step_reads_the_qkv_rows_where_they_lie(v5e, monkeypatch):
     """The step a train cell compiles: both flash calls take the qkv
     projection's result (a bitcast of it) and write rows, so none of
@@ -386,6 +411,119 @@ def test_gpt2_step_reads_the_qkv_rows_where_they_lie(v5e, monkeypatch):
     assert sorted(c.split(" ")[0].rstrip(".0123456789") for c in calls) \
         == ["%_flash_attention_bwd_jit", "%_flash_attention_jit"]
     assert all("f32[16,12,1,1024]" in c for c in calls)   # residual rows
+    # the operands and results the calls hold since PR 38, a lane block
+    # of two heads a program (PR 45 widened BERT's programs, not these)
+    rows, ctx = "bf16[16,1024,2304]", "bf16[16,1024,768]"
+    lse = "f32[16,12,1,1024]"
+    assert _flash_calls(text) == {
+        "%_flash_attention_jit": [(f"({ctx}, {lse})", [rows] * 3)],
+        "%_flash_attention_bwd_jit": [
+            (f"({ctx}, {ctx}, {ctx})", [rows] * 3 + [ctx, lse, lse])]}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_token_major_kernels_compile_at_berts_cell(one_chip, kind):
+    """BERT-base's train cell token-major (PR 45): three projections'
+    ``[256, 128, 768]`` rows under a padding mask, a batch row's twelve
+    heads a program in both directions (blocks ``[1, 128, 768]``, 256
+    programs a layer), the backward summing D itself — ONE custom call
+    under the name the trace's readers match, fed by the rows as they
+    lie, and nothing around it but the mask's column."""
+    b, s, heads, d = 256, 128, 12, 64
+    layout = pk.TokenMajor(heads, d)
+    blocks = pk._block_sizes(s, d)
+    assert pk.heads_per_program(heads, s, *blocks, layout) == 12
+    rows = jax.ShapeDtypeStruct((b, s, heads * d), jnp.bfloat16,
+                                sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((b, 1, 1, s), jnp.float32,
+                                sharding=one_chip)
+    if kind == "bwd":
+        lse = jax.ShapeDtypeStruct((b, heads, 1, s), jnp.float32,
+                                   sharding=one_chip)
+        lowered = pk._flash_attention_bwd_jit.lower(
+            rows, rows, rows, mask, rows, lse, rows, 0.125, False, False,
+            *blocks, layout)
+    else:
+        lowered = pk._flash_attention_jit.lower(
+            rows, rows, rows, mask, 0.125, False, False, *blocks,
+            kind == "fwd_lse", layout)
+    text = lowered.compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    calls = [ln for ln in entry.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    name = "_flash_attention_bwd_jit" if kind == "bwd" \
+        else "_flash_attention_jit"
+    assert calls[0].lstrip().removeprefix("ROOT ").startswith("%" + name)
+    assert "bf16[256,128,768]" in calls[0]
+    moved = [ln for ln in entry.splitlines()
+             if " copy(" in ln or " transpose(" in ln]
+    assert not any("[256,128,768]" in ln or "[256,12," in ln
+                   for ln in moved), moved
+    assert " fusion(" not in entry      # no pass over dO and O for D
+
+
+def test_bert_step_holds_no_head_major_relayout(v5e, monkeypatch):
+    """BERT-base's train step (the cell's widths, batch and length; one
+    layer and a vocabulary of 1024, to keep the compile short) for one
+    described chip: the three projections' rows reach both flash calls
+    as they lie and the context and the three gradients leave as rows,
+    so no array of ``[256, 12, 128, 64]`` (or its transposes) is left
+    anywhere in the text — the q / k / v relayouts and the composed
+    attention backward are gone — and the backward call takes the
+    forward's logsumexp as the forward leaves it."""
+    import numpy as np
+    import hetu_tpu as ht
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.models import BertConfig, BertForPreTraining
+    from hetu_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setenv("HETU_AUTOTUNE", "0")    # the sweep needs a device
+    batch, seq = 256, 128
+    model = BertForPreTraining(BertConfig(
+        vocab_size=1024, hidden_size=768, num_hidden_layers=1,
+        num_attention_heads=12, intermediate_size=3072,
+        max_position_embeddings=seq, use_flash_attention=True))
+    nodes = [ht.Variable(n, trainable=False) for n in (
+        "input_ids", "token_type_ids", "attention_mask",
+        "masked_lm_labels", "next_sentence_label")]
+    _, _, mlm_loss, nsp_loss = model(*nodes)
+    loss = ht.reduce_mean_op(mlm_loss, [0, 1]) \
+        + ht.reduce_mean_op(nsp_loss, [0])
+    train_op = ht.optim.AdamOptimizer(1e-4).minimize(loss)
+    executor = ht.Executor([loss, train_op], dtype=jnp.bfloat16,
+                           ctx=ht.cpu(0))
+    sub = executor.subexecutors["default"]
+    ids = np.zeros((batch, seq), np.int32)
+    feed = dict(zip(nodes, (ids, ids, np.ones((batch, seq), np.float32),
+                            ids, np.zeros((batch,), np.int32))))
+    step = sub.prepare(executor, feed)
+    sharding = SingleDeviceSharding(v5e[0])
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=sharding),
+        sub.trace_args(executor, feed))
+    text = jax.jit(step).lower(*shapes).compile().as_text()
+    for shape in ("[256,12,128,64]", "[256,128,12,64]", "[3072,128,64]",
+                  "[256,12,64,128]", "[256,12,128,128]"):
+        assert shape not in text, shape
+    rows, lse = "bf16[256,128,768]", "f32[256,12,1,128]"
+    calls = _flash_calls(text)
+    assert sorted(calls) == ["%_flash_attention_bwd_jit",
+                             "%_flash_attention_jit"]
+    (fwd,), (bwd,) = (calls["%_flash_attention_jit"],
+                      calls["%_flash_attention_bwd_jit"])
+    assert fwd == (f"({rows}, {lse})", [rows] * 3 + ["f32[256,1,128]"])
+    # q, k, v, dO, the logsumexp and the mask's column: no D residual
+    assert bwd == (f"({rows}, {rows}, {rows})",
+                   [rows] * 4 + [lse, "f32[256,128,1]"])
+    # ... and that logsumexp is the forward call's second result as it
+    # left the kernel: nothing computes or re-lays an array of its shape
+    assert not [ln for ln in text.splitlines()
+                if ln.lstrip().split(" = ")[-1].startswith(lse)
+                and any(op in ln for op in (" copy(", " transpose(",
+                                            " fusion(", " reshape("))]
 
 
 def test_dropout_keeps_the_composed_draw_under_a_dp_mesh(v5e, monkeypatch):

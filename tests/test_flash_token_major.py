@@ -1,6 +1,7 @@
 """The flash kernels' token-major operand form (PR 38): q, k and v read
 out of a qkv projection's own ``[B, S, 3H]`` rows (or three
-``[B, S, H]`` arrays), a lane block of heads a program, the context and
+``[B, S, H]`` arrays), whole lane blocks of heads a program (one at
+S = 1024, all of a BERT-base row at S = 128: PR 45), the context and
 the gradients written as rows. On the CPU through interpret mode:
 
 * forward, forward + logsumexp and the one-pass backward against
@@ -12,7 +13,10 @@ the gradients written as rows. On the CPU through interpret mode:
 * the GPT graph's loss and every parameter gradient with
   ``use_flash_attention=True`` against the composed graph at S = 512;
 * the rule that picks the form (``ops/attention.py:flash_layout``) and
-  the ``flash_layout`` instant a traced call records.
+  the ``flash_layout`` instant a traced call records;
+* the rule that sizes a program (``heads_per_program``), and the
+  three-array form at BERT's shape through the op and through the BERT
+  graph (PR 45).
 """
 import types
 
@@ -231,14 +235,14 @@ def _mesh(size):
     (1024, 64, 12, True, None, ("token_major", None)),
     (512, 128, 8, True, None, ("token_major", None)),
     (1024, 64, 12, True, _mesh(1), ("token_major", None)),
-    (128, 64, 12, True, None, ("head_major", "short_seq")),
-    (128, 192, 64, False, _mesh(4), ("head_major", "short_seq")),
+    (128, 64, 12, True, None, ("token_major", None)),
+    (128, 192, 64, False, _mesh(4), ("head_major", "lanes")),
     (1024, 192, 64, True, None, ("head_major", "lanes")),
     (1024, 64, 3, True, None, ("head_major", "lanes")),
     (576, 64, 12, True, None, ("head_major", "lanes")),
     (1024, 64, 12, True, _mesh(4), ("head_major", "mesh")),
     (1024, 64, 12, False, None, ("head_major", "caller")),
-], ids=["gpt2", "d128", "mesh-of-one", "short_seq", "first-reason-wins",
+], ids=["gpt2", "d128", "mesh-of-one", "bert", "first-reason-wins",
         "d192", "odd-heads", "ragged-rows", "mesh", "caller"])
 def test_the_rule_on_what_the_code_can_see(s, d, heads, token_major, ectx,
                                            want):
@@ -340,17 +344,19 @@ def test_packed_op_runs_token_major_and_says_so(kernels_on_cpu):
 
 
 @pytest.mark.parametrize("s,heads,d,ectx,reason", [
-    (128, 2, 64, ExecContext(training=True), "short_seq"),
+    (192, 2, 64, ExecContext(training=True), "lanes"),
     (512, 2, 192, ExecContext(training=True), "lanes"),
     (512, 2, 64, types.SimpleNamespace(
         training=True, cache={}, config=types.SimpleNamespace(
             mesh=types.SimpleNamespace(size=4))), "mesh"),
-], ids=["s128", "d192", "mesh"])
+], ids=["s192", "d192", "mesh"])
 def test_packed_op_makes_the_trip_itself_where_the_rule_says(
         kernels_on_cpu, s, heads, d, ectx, reason):
     """Packed rows in, rows out, through the head-major kernels (and the
-    composed vjp below ``FUSED_BWD_MIN_SEQ``): the instant names the
-    condition that failed, and the numbers are the reference's."""
+    composed vjp below ``FUSED_BWD_MIN_SEQ``, which holds for
+    head-major calls alone: S = 192 is no whole lane tile of rows): the
+    instant names the condition that failed, and the numbers are the
+    reference's."""
     rows = _rows(1, s, heads, d, jnp.float32, seed=s + d)
     dy = jnp.asarray(np.random.RandomState(2).randn(1, s, heads * d),
                      jnp.float32)
@@ -379,12 +385,12 @@ def test_head_major_callers_keep_the_head_major_entry(kernels_on_cpu):
     assert [(a["kernel"], a["layout"], a["reason"])
             for a in _layout_events(kernels_on_cpu)] == [
         ("fwd_lse", "head_major", "caller"),
-        ("fwd", "head_major", "short_seq"),
+        ("fwd", "head_major", "caller"),
         ("fwd", "head_major", "caller")]
     with pytest.raises(ValueError, match="packed qkv rows"):
         FlashAttentionOp(nodes[0], num_heads=None)
     with pytest.raises(ValueError, match="packed qkv rows"):
-        FlashAttentionOp(*nodes, num_heads=2)
+        FlashAttentionOp(*nodes[:2], num_heads=2)
 
 
 def test_token_major_tiles_are_stored_apart():
@@ -452,3 +458,281 @@ def test_gpt_graph_matches_the_composed_one_at_s512(kernels_on_cpu):
         for grads in (got, untrained):
             np.testing.assert_allclose(grads[name] / scale, w / scale,
                                        atol=2e-4, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# programs several lane blocks wide, and three projections' rows (PR 45)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("s,heads,d,packed,tiles,batch,want,programs", [
+    # GPT-2's cell, forward and backward tiles: a lane block of two
+    # heads a program, the grid it always had
+    (1024, 12, 64, True, (512, 512), 16, 2, (16, 6, 1)),
+    (1024, 12, 64, True, (256, 256), 16, 2, (16, 6, 1)),
+    (1024, 12, 64, True, (128, 128), 16, 2, (16, 6, 2)),
+    # BERT-base's cell: a batch row's twelve heads, 256 programs a layer
+    (128, 12, 64, False, (128, 128), 256, 12, (256, 1, 1)),
+    (128, 12, 64, True, (128, 128), 256, 12, (256, 1, 1)),
+    # between them the two bounds decide: tile pairs, rows
+    (256, 12, 64, False, (128, 128), 8, 4, (8, 3, 1)),
+    (256, 12, 64, False, (256, 256), 8, 6, (8, 2, 1)),
+    (512, 12, 64, True, (256, 512), 8, 4, (8, 3, 1)),
+    # whole blocks only, and a count that divides the row's blocks
+    (128, 8, 128, False, (128, 128), 4, 8, (4, 1, 1)),
+    (128, 20, 64, False, (128, 128), 4, 10, (4, 2, 1)),
+    (128, 32, 64, False, (128, 128), 4, 16, (4, 2, 1)),
+    # regions walked by a loop: one lane block
+    (4096, 4, 64, False, (512, 512), 1, 2, (1, 2, 2)),
+], ids=lambda v: str(v).replace(" ", ""))
+def test_program_width_rule(s, heads, d, packed, tiles, batch, want,
+                            programs):
+    """ONE rule for both kernels (``heads_per_program``): (S, heads, D,
+    packed or three arrays, tiles) -> heads a program, and the grid
+    both jits trace to at those tiles."""
+    layout = pk.TokenMajor.packed(heads, d) if packed \
+        else pk.TokenMajor(heads, d)
+    assert layout.fits(s)
+    assert pk.heads_per_program(heads, s, *tiles, layout) == want
+    assert pk.fwd_walk_counts(heads, s, *tiles, False, layout)[
+        "heads_per_program"] == want
+    width = heads * d * (3 if packed else 1)
+    rows = jax.ShapeDtypeStruct((batch, s, width), jnp.bfloat16)
+    ctx = jax.ShapeDtypeStruct((batch, s, heads * d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((batch, heads, 1, s), jnp.float32)
+
+    def grid(fn, *operands, static):
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append(eqn.params["grid_mapping"].grid)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jax.make_jaxpr(lambda *a: fn(*a[:3], None, *a[3:], *static))(
+            *operands).jaxpr)
+        assert len(found) == 1
+        return tuple(int(g) for g in found[0])
+
+    assert grid(pk._flash_attention_jit, rows, rows, rows,
+                static=(SCALE, False, True, *tiles, True, layout)) \
+        == programs
+    assert grid(pk._flash_attention_bwd_jit, rows, rows, rows, ctx, lse,
+                ctx, static=(SCALE, False, True, *tiles, layout)) \
+        == programs
+
+
+def _three(b, s, heads, d, dtype, seed):
+    """q, k, v rows ``[B, S, H]`` of three projections, the heads of a
+    lane block at different scales, a padding mask, and dy."""
+    rng = np.random.RandomState(seed)
+    scales = np.asarray([1.0, 0.125, 0.5, 2.0] * heads)[:heads]
+    rows = [jnp.asarray((rng.randn(b, s, heads, d) * 0.5
+                         * scales[None, None, :, None]).reshape(
+                             b, s, heads * d), dtype) for _ in range(3)]
+    m = np.zeros((b, 1, 1, s), np.float32)
+    m[0, ..., s - 37:] = -1e9
+    m[-1, ..., 30:41] = -1e9
+    dy = jnp.asarray(rng.randn(b, s, heads * d), dtype)
+    return rows, jnp.asarray(m), dy
+
+
+def _to_heads(x, heads):
+    b, s, width = x.shape
+    return x.reshape(b, s, heads, width // heads).transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("s,tiles,dtype", [
+    (128, (128, 128), jnp.bfloat16), (128, (128, 128), jnp.float32),
+    (256, (128, 256), jnp.bfloat16), (256, (256, 128), jnp.float32)],
+    ids=["bert-bf16", "bert-f32", "s256-bf16", "s256-f32"])
+def test_three_arrays_match_the_composed_vjp_at_berts_shape(s, tiles,
+                                                            dtype):
+    """Twelve heads of 64 from three ``[B, S, H]`` arrays under a
+    padding mask — at S = 128 a batch row's twelve heads one program in
+    both directions, at S = 256 two tile pairs a head and six heads a
+    program — against
+    ``jax.vjp`` of the float32 reference, and against the head-major
+    kernels bit for bit."""
+    heads, d, b = 12, 64, 2
+    layout = pk.TokenMajor(heads, d)
+    group = pk.heads_per_program(heads, s, *tiles, layout)
+    assert group == (12 if s == 128 else 6)
+    (q, k, v), mask, dy = _three(b, s, heads, d, dtype, seed=s)
+    o, lse = pk._flash_attention_jit(q, k, v, mask, SCALE, False, True,
+                                     *tiles, True, layout)
+    got = pk._flash_attention_bwd_jit(q, k, v, mask, o, lse, dy, SCALE,
+                                      False, True, *tiles, layout)
+    qh, kh, vh, dyh = (_to_heads(x, heads) for x in (q, k, v, dy))
+    want_o, vjp = jax.vjp(
+        lambda q_, k_, v_: attention_reference(q_, k_, v_, mask, SCALE),
+        *(x.astype(jnp.float32) for x in (qh, kh, vh)))
+    want = vjp(dyh.astype(jnp.float32))
+    tol = _tolerance(dtype)
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(_as_rows(want_o)), **tol)
+    for g, w, name in zip(got, want, "qkv"):
+        assert g.shape == dy.shape and g.dtype == dtype
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32) / scale,
+            np.asarray(_as_rows(w)) / scale, err_msg=f"d{name}", **tol)
+    o_h, lse_h = pk._flash_attention_jit(qh, kh, vh, mask, SCALE, False,
+                                         True, *tiles, True)
+    g_h = pk._flash_attention_bwd_jit(qh, kh, vh, mask, o_h, lse_h, dyh,
+                                      SCALE, False, True, *tiles)
+    assert np.array_equal(np.asarray(o), np.asarray(_as_rows(o_h)))
+    assert np.array_equal(np.asarray(lse[:, :, 0]), np.asarray(lse_h))
+    for a, c in zip(got, g_h):
+        assert np.array_equal(np.asarray(a), np.asarray(_as_rows(c)))
+
+
+def _run_rows_op(rows, mask, heads, ectx, dy):
+    nodes = [ht.Variable(n, trainable=False) for n in "qkvm"]
+    fwd = FlashAttentionOp(*nodes, sm_scale=SCALE, num_heads=heads)
+    out = fwd.compute([*rows, mask], ectx)
+    assert fwd.infer_shape([x.shape for x in rows]) == out.shape
+    grads = fwd.gradient(ht.Variable("dy", trainable=False))
+    assert len(grads) == 4 and grads[3] is None
+    return out, [g.compute([*rows, mask, dy], ectx) for g in grads[:3]]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_rows_op_runs_token_major_at_s128_and_says_so(kernels_on_cpu,
+                                                      dtype):
+    """BERT's call: three projections' rows in, the context and three
+    gradients out as rows, forward with logsumexp and the FUSED backward
+    at S = 128 (token-major the threshold does not apply), a batch
+    row's twelve heads a program in both — and the numbers are the
+    composed vjp's over ``[B, H, S, D]``."""
+    heads, d, s = 12, 64, 128
+    rows, mask, dy = _three(2, s, heads, d, dtype, seed=11)
+    out, grads = _run_rows_op(rows, mask, heads,
+                              ExecContext(training=True), dy)
+    events = kernels_on_cpu.tracer.drain(clear=True)
+    assert [(e["args"]["kernel"], e["args"]["layout"],
+             e["args"]["heads_per_block"])
+            for e in events if e.get("name") == "flash_layout"] \
+        == [("fwd_lse", "token_major", 2), ("bwd", "token_major", 2)]
+    for name in ("flash_fwd_walk", "flash_bwd_walk"):
+        (walk,) = [e["args"] for e in events if e.get("name") == name]
+        assert check_args(name, walk) == []
+        assert walk["heads_per_program"] == 12 and walk["seq"] == s
+    qh, kh, vh, dyh = (_to_heads(x.astype(jnp.float32), heads)
+                       for x in (*rows, dy))
+    want_o, vjp = jax.vjp(
+        lambda q_, k_, v_: attention_reference(q_, k_, v_, mask, SCALE),
+        qh, kh, vh)
+    tol = _tolerance(dtype)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(_as_rows(want_o)), **tol)
+    for g, w, x in zip(grads, vjp(dyh), rows):
+        assert g.shape == x.shape and g.dtype == x.dtype
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(np.asarray(g, np.float32) / scale,
+                                   np.asarray(_as_rows(w)) / scale, **tol)
+    # not training: the plain forward, token-major too, no residual
+    ectx = ExecContext(training=False)
+    _run_rows_op(rows, mask, heads, ectx, dy)
+    assert not [key for key in ectx.cache if key[0] == "flash_res"]
+    assert [(a["kernel"], a["layout"])
+            for a in _layout_events(kernels_on_cpu)] \
+        == [("fwd", "token_major")]
+
+
+@pytest.mark.parametrize("s,heads,d,patched,ectx,kinds", [
+    (128, 2, 64, False, ExecContext(training=True), []),
+    (192, 2, 64, True, ExecContext(training=True),
+     [("fwd", "lanes")]),
+    (128, 2, 64, True, types.SimpleNamespace(
+        training=True, cache={}, config=types.SimpleNamespace(
+            mesh=types.SimpleNamespace(size=4))), [("fwd", "mesh")]),
+], ids=["off-the-chip", "s192", "mesh"])
+def test_rows_op_makes_the_trip_itself_where_the_rule_says(
+        kernels_on_cpu, monkeypatch, s, heads, d, patched, ectx, kinds):
+    """Off a TPU, at rows that are no whole lane tiles and under a mesh
+    the op splits the three arrays into ``[B, H, S, D]`` itself, runs
+    the reference or the head-major forward with the composed vjp, and
+    hands back rows: three gradients, each its projection's."""
+    if not patched:
+        monkeypatch.setattr(attn_mod, "_use_pallas", lambda: False)
+    rows, mask, dy = _three(2, s, heads, d, jnp.float32, seed=s + d)
+    out, grads = _run_rows_op(rows, mask, heads, ectx, dy)
+    assert [(a["kernel"], a["reason"])
+            for a in _layout_events(kernels_on_cpu)] == kinds
+    qh, kh, vh, dyh = (_to_heads(x, heads) for x in (*rows, dy))
+    want_o, vjp = jax.vjp(
+        lambda q_, k_, v_: attention_reference(q_, k_, v_, mask, SCALE),
+        qh, kh, vh)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_as_rows(want_o)),
+                               rtol=2e-4, atol=2e-4)
+    for g, w in zip(grads, vjp(dyh)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(_as_rows(w)),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def _bert_loss_and_grads(flash, seq, feed):
+    import hetu_tpu.models as M
+    from hetu_tpu.executor import Executor
+    cfg = M.BertConfig(vocab_size=64, hidden_size=256,
+                       num_hidden_layers=2, num_attention_heads=4,
+                       intermediate_size=512, hidden_dropout_prob=0.0,
+                       attention_probs_dropout_prob=0.0,
+                       max_position_embeddings=seq,
+                       use_flash_attention=flash)
+    nodes = [ht.Variable(n, trainable=False) for n in (
+        "input_ids", "token_type_ids", "attention_mask",
+        "masked_lm_labels", "next_sentence_label")]
+    _, _, mlm_loss, nsp_loss = M.BertForPreTraining(cfg)(*nodes)
+    loss = ht.reduce_mean_op(mlm_loss, [0, 1]) \
+        + ht.reduce_mean_op(nsp_loss, [0])
+    params = ht.optim.SGDOptimizer(0.1).get_var_list(loss)
+    grads = ht.gradients(loss, params)
+    vals = Executor([loss] + grads
+                    + [ht.optim.SGDOptimizer(0.1).minimize(loss)]).run(
+        feed_dict=dict(zip(nodes, feed)), convert_to_numpy_ret_vals=True)
+    return float(vals[0]), {
+        p.name: np.asarray(g.item().to_dense() if g.dtype == object else g)
+        for p, g in zip(params, vals[1:1 + len(params)])}
+
+
+def test_bert_graph_matches_the_composed_one_at_s128(kernels_on_cpu):
+    """Two layers, S = 128, four heads of 64 behind three projections
+    and a padding mask: the flash graph hands the op the projections'
+    rows (no reshape or transpose node around it), both kernels run
+    token-major with all four heads a program, and the loss and every
+    parameter's gradient are the composed graph's. Parameter names do
+    not change with the path."""
+    seq, batch = 128, 2
+    rng = np.random.RandomState(8)
+    ids = rng.randint(0, 64, (batch, seq)).astype(np.int32)
+    types_ = np.zeros((batch, seq), np.int32)
+    types_[:, seq // 2:] = 1
+    mask = np.ones((batch, seq), np.float32)
+    mask[0, seq - 29:] = 0
+    labels = np.where(rng.rand(batch, seq) < 0.3, ids, -1).astype(np.int32)
+    feed = (ids, types_, mask, labels, np.asarray([0, 1], np.int32))
+    want_loss, want = _bert_loss_and_grads(False, seq, feed)
+    assert not _layout_events(kernels_on_cpu)      # no kernel ran
+    got_loss, got = _bert_loss_and_grads(True, seq, feed)
+    events = kernels_on_cpu.tracer.drain(clear=True)
+    assert sorted((e["args"]["kernel"], e["args"]["layout"])
+                  for e in events if e.get("name") == "flash_layout") \
+        == 2 * [("bwd", "token_major")] + 2 * [("fwd_lse", "token_major")]
+    assert {e["args"]["heads_per_program"] for e in events
+            if e.get("name") in ("flash_fwd_walk", "flash_bwd_walk")} \
+        == {4}
+    assert got_loss == pytest.approx(want_loss, rel=1e-5)
+    assert got.keys() == want.keys()
+    assert {"layer0_attn_query_weights", "layer0_attn_key_bias",
+            "layer1_attn_value_weights"} <= got.keys()
+    for name, w in want.items():
+        # a key's bias moves every score of a row alike: its gradient
+        # is rounding around zero on both sides, held to the weights'
+        scale = np.abs(want[name.replace("_bias", "_weights")]
+                       if name.endswith("_key_bias") else w).max()
+        assert scale > 0, name
+        np.testing.assert_allclose(got[name] / scale, w / scale,
+                                   atol=2e-4, err_msg=name)
