@@ -2215,9 +2215,7 @@ def bench_autoplan():
     hand-written config on three zoo-class models, on the 8-virtual-
     device CPU mesh. value = hand_ms / auto_ms, so 1.0 is parity and
     >= 0.9 is the ISSUE-10 acceptance bar. The top-3 finalists are
-    measured through the tune/autotune engine (sweep-once, cached
-    under platform|autoplan|<model>|8), so a re-run replays the cached
-    winner deterministically."""
+    measured in the run and the least wins."""
     import subprocess
     import sys
     repo = os.path.dirname(os.path.abspath(__file__))
@@ -2281,23 +2279,13 @@ def bench_bert_long_seq():
     flops = bert_train_flops(batch, seq_len, 512, 4, 8, 2048, vocab)
     samples = _step_samples(lambda: exe.run(feed_dict=feeds),
                             lambda out: out[0].asnumpy(), 8)
-    # autotune evidence + fwd/bwd/remainder attribution: which (bq, bk)
-    # the flash kernels chose for this shape, how much of the step the
-    # tuned kernels account for, and whether the residual gap is kernel
-    # or XLA-remainder (ISSUE 5 acceptance — recorded in BENCH_r06)
+    # fwd/bwd/remainder attribution: how much of the step the flash
+    # kernels account for, and whether the residual gap is kernel or
+    # XLA-remainder (ISSUE 5 acceptance — recorded in BENCH_r06)
     extra = {}
     try:
         import jax
         from hetu_tpu import tune
-        tel = _telemetry()
-        extra["autotune_sweeps"] = tel.counter_value("autotune_sweeps")
-        extra["autotune_cache_hits"] = tel.counter_value(
-            "autotune_cache_hit")
-        blocks = {"|".join(ks.split("|")[1:]): list(cfg) for ks, cfg
-                  in tune.chosen_configs(prefix="flash_").items()
-                  if "S2048" in ks}
-        if blocks:
-            extra["tuned_blocks"] = blocks
         if jax.default_backend() == "tpu":
             pr = tune.probe_attention(batch, 8, seq_len, 64,
                                       dtype="bfloat16", sm_scale=0.125,
@@ -2309,10 +2297,9 @@ def bench_bert_long_seq():
                 attn_bwd_ms=att["attn_bwd_ms"],
                 xla_remainder_ms=att["xla_remainder_ms"],
                 attn_fraction=att["attn_fraction"],
-                kernel_ms_tuned={"fwd_lse": pr["fwd_lse_ms"],
-                                 "bwd": pr["bwd_ms"]},
-                kernel_ms_static={"fwd_lse": pr["static_fwd_lse_ms"],
-                                  "bwd": pr["static_bwd_ms"]})
+                kernel_blocks=pr["blocks"],
+                kernel_ms={"fwd_lse": pr["fwd_lse_ms"],
+                           "bwd": pr["bwd_ms"]})
     except Exception as e:                          # noqa: BLE001
         extra["probe_error"] = f"{type(e).__name__}: {e}"
     emit("bert_s2048_tokens_per_sec_per_chip", tps, "tokens/sec/chip",

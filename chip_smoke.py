@@ -110,7 +110,7 @@ def run_phase(name, fn, rehearse):
 
 def split_seconds(seconds):
     """Per-step wall seconds -> compile / steady: the first step
-    carries the trace, the autotune sweeps and the XLA compile."""
+    carries the trace and the XLA compile."""
     steady = float(np.median(seconds[1:]))
     return {"compile_s": round(max(0.0, seconds[0] - steady), 3),
             "steady_s": round(float(np.sum(seconds[1:])), 3),
@@ -254,7 +254,7 @@ def phase_bert(rehearse):
 def phase_gpt_train(rehearse, run):
     gpt = example("nlp/train_hetu_gpt.py")
     if rehearse:
-        # S=512 keeps the autotune sweep and the fused backward
+        # S=512 keeps the fused backward
         # (ops/attention.py FUSED_BWD_MIN_SEQ) on the rehearsed path
         argv = ["--vocab-size", "512", "--hidden-size", "32",
                 "--num-layers", "2", "--num-heads", "2",
@@ -393,7 +393,7 @@ def phase_gpt_serve(rehearse, run):
         cfg, run.checkpoint, **kw)
     try:
         rounds, seconds = [], []
-        for _ in range(2):      # cold (compiles, sweeps), then steady
+        for _ in range(2):      # cold (compiles), then steady
             t0 = time.perf_counter()
             futures = [engine.submit(p, new_tokens) for p in prompts]
             rounds.append([f.result(timeout=1000) for f in futures])

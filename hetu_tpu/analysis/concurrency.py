@@ -2,8 +2,8 @@
 
 PRs 2-10 made the host side a genuinely concurrent program: the ingest
 engine's worker, the micro-batcher's condition loop, p2p accept/
-connection readers, the autotune sweep worker, three HTTP servers, the
-PS push pool, signal/atexit crash handlers. The preflight stack
+connection readers, three HTTP servers, the PS push pool,
+signal/atexit crash handlers. The preflight stack
 (HT1xx-HT5xx) statically refuses to launch broken *fleets*; this pass
 extends the same philosophy to broken *threads* — the classic lockset
 (Eraser, Savage et al. 1997) and lock-order-graph (GoodLock)

@@ -35,9 +35,6 @@ and never blocks a launch; HT908 is always advisory)::
          constant feeds), priced from the comm curves
   HT906  cost-weighted dead compute: the HT110 dead-subgraph lint
          with predicted ms attached
-  HT907  untuned hot-path kernel: flash-attention call sites whose
-         autotune cache has no entry for the key — the first step
-         pays the whole sweep
   HT908  CostDB coverage gap (advisory): the plan's hot ops priced
          from guesses, not measurements
 
@@ -77,7 +74,7 @@ __all__ = ["efficiency_pass", "predict", "EfficiencyResult", "op_costs",
 # overrides per process
 DEFAULT_MS_THRESHOLD = 0.05
 
-# one-time costs (recompiles, autotune sweeps) amortize over this many
+# one-time costs (recompiles) amortize over this many
 # steps for the per-step price when the caller knows no step count
 _AMORTIZE_STEPS = 1000
 
@@ -86,8 +83,7 @@ _AMORTIZE_STEPS = 1000
 # static claim to the measured bucket through this map
 DOCTOR_BUCKET = {"HT901": "jit", "HT902": "compute",
                  "HT903": "unaccounted", "HT904": "collective",
-                 "HT905": "h2d_ingest", "HT906": "compute",
-                 "HT907": "jit"}
+                 "HT905": "h2d_ingest", "HT906": "compute"}
 
 # distinct compiled signatures a session may accumulate before HT901
 # calls it churn (train + eval + a couple of block variants)
@@ -105,10 +101,6 @@ _HBM_GBPS = 100.0
 # HT903: scalar fetches in the per-step eval list beyond this are
 # host syncs the sampling cadence should own
 _SCALAR_FETCH_BUDGET = 4
-
-# HT907: dispatches one sweep candidate costs (1 warmup + 2 windows x
-# 3 reps, pallas_attention._MEASURE_*)
-_SWEEP_DISPATCHES = 7
 
 # NOTE on pricing: unlike autoplan (fwd-only topo, x3 training
 # factor), this pass prices the FULL step topo — gradient ops are
@@ -338,7 +330,6 @@ def efficiency_pass(topo, report, shapes=None, dtypes=None, config=None,
         if extra_roots:
             _dead_compute_pass(topo, eval_nodes, extra_roots, db, add)
     _reshard_pass(topo, shapes, dtypes, db, add)
-    _autotune_pass(topo, shapes, dtypes, db, steps, add)
     _coverage_pass(topo, shapes, op_ms, sources, db, add, threshold)
     return op_ms
 
@@ -834,69 +825,6 @@ def _dead_compute_pass(topo, eval_nodes, extra_roots, db, add):
         f"evaluates them they burn ~{ms:.4f} ms/step for nothing; "
         f"delete the subgraph or fetch its outputs",
         dead[0], ms, "cold_start", dead_ops=len(dead))
-
-
-# ---------------------------------------------------------------------------
-# HT907 — untuned hot-path kernel
-# ---------------------------------------------------------------------------
-
-def _autotune_pass(topo, shapes, dtypes, db, steps, add):
-    from ..ops.attention import FlashAttentionOp
-    from ..tune.autotune import AutotuneTable, tuning_mode
-
-    mode = tuning_mode()
-    if mode in ("off", "cache"):
-        return                  # no sweep will ever run at dispatch
-    table = None
-    for node in topo:
-        if not isinstance(node, FlashAttentionOp):
-            continue            # grad ops share the forward's key
-        q = shapes.get(node.inputs[0]) if node.inputs else None
-        dims = node.attention_shape(q) if q else None
-        if not dims:
-            continue
-        b, h, s, d = dims
-        from ..ops.attention import flash_layout
-        from ..ops.pallas_attention import _candidates, tune_key
-        # packed rows run (and are tuned) token-major where the rule
-        # lets them; the pass prices a step on one chip, no mesh
-        token_major = flash_layout(
-            s, d, h, bool(node.num_heads))[0] == "token_major"
-        cands = [(bq, bk) for bq in _candidates(s)
-                 for bk in _candidates(s)]
-        if len(cands) < 2:
-            continue            # nothing to sweep (short sequences)
-        dt = dtypes.get(node.inputs[0]) or np.dtype(np.float32)
-        causal = bool(getattr(node, "causal", False))
-        has_mask = bool(getattr(node, "has_mask", False))
-        missing = []
-        if table is None:
-            table = AutotuneTable()
-        for kind in ("fwd", "fwd_lse", "bwd"):
-            name, key = tune_key(kind, s, d, np.dtype(dt), causal,
-                                 has_mask, token_major=token_major)
-            if table.get(name, key) is None:
-                missing.append(kind)
-        if not missing:
-            continue
-        ent = db.get(node.op_type, q)
-        if ent is not None:
-            op_ms, source = float(ent["ms"]), "measured"
-        else:
-            from ..telemetry.costdb import cold_start_flops_ms
-            op_ms = cold_start_flops_ms(_flops(node, shapes))
-            source = "cold_start"
-        sweep_ms = len(cands) * _SWEEP_DISPATCHES * op_ms * len(missing)
-        horizon = max(1, int(steps)) if steps else _AMORTIZE_STEPS
-        add("HT907",
-            f"flash-attention S={s} D={d} has no autotune cache entry "
-            f"for {missing} — the first step pays a "
-            f"{len(cands)}-candidate sweep (~{sweep_ms:.1f} ms, "
-            f"{source}-priced). Warm the cache (HETU_AUTOTUNE=1 after "
-            f"one tuning run) so measured steps never sweep",
-            node, sweep_ms / horizon, source,
-            estimated_ms_first_step=round(sweep_ms, 3),
-            sweep_candidates=len(cands))
 
 
 # ---------------------------------------------------------------------------

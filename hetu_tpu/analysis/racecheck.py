@@ -19,8 +19,8 @@ run. Inside a ``racecheck()`` region, ``threading.Lock`` / ``RLock`` /
 On exit (or via :meth:`RaceCheck.assert_acyclic`) the observed graph is
 checked for cycles: a cycle is a lock-order deadlock that merely hasn't
 fired yet, reported with every lock's creation site. The stress tests
-in ``tests/test_concurrency.py`` run the batcher, ingest engine,
-autotune cache, and PS-client paths under this harness at >=8-thread
+in ``tests/test_concurrency.py`` run the batcher, ingest engine
+and PS-client paths under this harness at >=8-thread
 load; the pytest ``racecheck`` fixture (tests/conftest.py) dumps the
 measured graph JSON beside the test for CI failure artifacts.
 

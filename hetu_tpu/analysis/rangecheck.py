@@ -13,7 +13,7 @@ twin relationship is enforced both ways:
   the static interval; an escape is an ``HT810`` error (the static
   model lied, which would silence every HT801/HT804 built on it), and
 * **measured-range DB** — measured ranges persist in an
-  autotune-style atomic-JSON :class:`RangeDB` keyed by
+  atomic-JSON :class:`RangeDB` keyed by
   ``numerics.stable_keys`` (topo position + op type, stable across
   rebuilds), and ``numerics_pass(measured=...)`` re-seeds from them,
   turning loose initializer bounds into tight measured ones on
@@ -56,7 +56,7 @@ def default_db_path():
 
 
 class RangeDB:
-    """Persistent measured-range database (the autotune/CostDB atomic-
+    """Persistent measured-range database (the CostDB atomic-
     JSON idiom): ``{model: {stable_key: {"lo", "hi", "n"}}}`` with
     running min/max merge across runs."""
 

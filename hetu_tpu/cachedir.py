@@ -1,8 +1,7 @@
 """Where a run keeps what the next run may reuse.
 
 Two kinds of state outlive a process: XLA's persistent compilation
-cache, and the package's small JSON stores (autotune winners, CostDB,
-RangeDB). Both default to one fixed directory inside the checkout,
+cache, and the package's small JSON stores (CostDB, RangeDB). Both default to one fixed directory inside the checkout,
 derived from this file's location — never from ``~``, a temp name, a
 pid or the time — so that a run is a function of the committed tree
 plus that directory, and a second run from the same checkout finds
@@ -11,8 +10,7 @@ what the first one compiled (the path is part of the cache key).
 The compile cache can be placed from outside: where
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
 :func:`enable_compile_cache` sets nothing. The JSON stores keep their
-own overrides (``HETU_AUTOTUNE_CACHE``, ``HETU_COSTDB``,
-``HETU_RANGEDB``).
+own overrides (``HETU_COSTDB``, ``HETU_RANGEDB``).
 
 Only the chip entry points call :func:`enable_compile_cache`
 (``chip_smoke.py``, ``bench.py``; ``heturun`` exports the directory to
@@ -46,10 +44,9 @@ def enable_compile_cache():
     serialized body carries the location it was traced at, and with
     full tracebacks those bytes — hence the key of every program that
     holds the kernel — depend on the Python call stack that FIRST traced
-    it: the autotune sweep thread in the process that sweeps, the step
-    trace in the next one, which then never hits what the first wrote
-    (measured on the chip: GPT-2's step recompiled, 28 s, in the second
-    process)."""
+    it, so a second process that reaches the kernel by another path
+    never hits what the first wrote (measured on the chip: GPT-2's step
+    recompiled, 28 s, in the second process)."""
     import jax
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
     external = os.environ.get(_COMPILE_CACHE_ENV)

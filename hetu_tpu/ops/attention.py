@@ -128,12 +128,10 @@ def prefill_attention(q, k, v, sm_scale, causal=True):
     """Dense prompt-phase attention for the serving decode path over
     ``[B, H, S, D]`` q/k/v: rides the Pallas flash kernel on TPU
     backends (blocked online softmax, no HBM score matrix), the
-    composed reference elsewhere. The kernel's block sizes come from
-    the autotune cache (``hetu_tpu/tune``) keyed per (S, D, dtype,
-    causal, mask) — prefill tunes apart from training because it rides
-    the plain-forward kernel (training's fused path uses the with-lse
-    forward, a different key) — and since the serving forward never
-    consumes the logsumexp residual, it skips that output write."""
+    composed reference elsewhere. The kernel's block sizes are the
+    kernels' static rule (``pallas_attention._block_sizes``), and since
+    the serving forward never consumes the logsumexp residual, it skips
+    that output write."""
     if _use_pallas():
         from .pallas_attention import flash_attention
         _, h, s, d = q.shape
@@ -409,8 +407,6 @@ class FlashAttentionOp(Op):
             # something will consume it: training, in a form and at a
             # length where the fused path engages (``fused_backward``).
             # Otherwise skip the residual write.
-            # Block sizes resolve per (S, D, dtype, causal, mask) from
-            # the autotune cache at trace time (pallas_attention.py).
             from .pallas_attention import (flash_attention,
                                            flash_attention_with_lse)
             kw = dict(sm_scale=self.sm_scale, causal=self.causal,

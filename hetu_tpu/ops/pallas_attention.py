@@ -57,21 +57,13 @@ windows; the context, dq, dk and dv leave as rows and the residuals as
 calls. The kernel bodies are shared (the head's lane window is a static
 parameter) and the arithmetic a head is the same to the bit.
 
-Block sizes are AUTOTUNED per (platform, kernel, S, D, dtype, causal,
-mask): bq/bk sweep {128, 256, 512, 1024} (clipped to divisors of S)
-independently for the forward, the forward-with-lse and the fused
-backward through ``hetu_tpu/tune`` — the sweep runs once at first
-compile of a shape, the winner persists in the autotune JSON cache, and
-``HETU_AUTOTUNE=0`` falls back to the static ``_block_sizes`` defaults
-(bq≤256, bk≤512). With ``causal`` the tiles also decide how much of the
-square is skipped (a tile 1024 long on either side of S=1024 is cut by
-the diagonal everywhere), and the two directions weigh that differently
-(on a v5e the forward likes 512-row q-tiles, three pairs of four run;
-the backward 256 x 256, ten of sixteen) — that per-direction freedom is
-the point of tuning the three kernels apart. Batch/heads are NOT in the
-key (they only size the embarrassingly parallel grid axis; per-program
-work is S/D-shaped): the sweep times the first caller's b/h and later
-batch sizes share that winner.
+Block sizes are ONE static rule in what a call can see
+(``_block_sizes``: kind, S, D, causal, mask), filled from the chip at
+the shapes the benchmark's cells run; with ``causal`` the tiles also
+decide how much of the square is skipped (a tile 1024 long on either
+side of S=1024 is cut by the diagonal everywhere), and the two
+directions weigh that differently. Batch and heads only size the
+embarrassingly parallel grid axis and are no input of the rule.
 """
 from __future__ import annotations
 
@@ -79,15 +71,13 @@ import functools
 import math
 from typing import NamedTuple
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "flash_attention_bwd", "tune_key", "TokenMajor"]
+           "flash_attention_bwd", "TokenMajor"]
 
 NEG_INF = -1e30
 LANES = 128      # TPU minor-dim tile: the forward writes lse lane-tiled
@@ -314,16 +304,38 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
                 l_ref[0, n, :, r] = tile[n:n + 1]
 
 
-def _block_sizes(seq_len, head_dim):
-    """Static default tiles (the pre-autotune behavior, and the
-    ``HETU_AUTOTUNE=0`` / cache-only-miss fallback)."""
-    bq = min(256, seq_len)
-    while seq_len % bq:
-        bq //= 2
-    bk = min(512, seq_len)
-    while seq_len % bk:
-        bk //= 2
-    return max(bq, 8), max(bk, 8)
+def _largest_tile(seq_len, most):
+    """``most`` halved until it divides S (at least 8)."""
+    tile = min(most, seq_len)
+    while seq_len % tile:
+        tile //= 2
+    return max(tile, 8)
+
+
+def _block_sizes(seq_len, head_dim, kind="fwd", causal=False,
+                 has_mask=False):
+    """``(block_q, block_k)`` of a flash call (``kind`` one of ``fwd`` /
+    ``fwd_lse`` / ``bwd``) from what the call can see: the ONE place
+    tiles are decided. Nothing is measured while tracing and nothing is
+    stored, so every process of a tree runs the same programs.
+
+    Causal and unmasked — every call of the benchmark's cells that has
+    a choice, read there on the chip (PERF.md section 6, PR 46): 256
+    keys a tile; 256 rows too in the backward (1.17 ms a GPT-2 layer,
+    1.24 at the next candidate) and for heads of a whole lane block or
+    more (D = 192 at S = 8,192: 17.6 ms, 17.8 at (512, 512), 18.4 at
+    (512, 256)); 512 rows in the forward where a head is half a lane
+    block (D = 64: the GPT-2 train cell's forward with ``lse`` reads
+    16.26 ms a step there, 16.29 at (512, 128), 16.44 at (512, 512)).
+    What no cell runs (no diagonal, or a padding mask beside it) was
+    never read on the chip and keeps the tiles it always had:
+    bq <= 256, bk <= 512."""
+    block_q, block_k = 256, 512
+    if causal and not has_mask:
+        block_k = 256
+        if kind != "bwd" and head_dim <= 64:
+            block_q = 512
+    return _largest_tile(seq_len, block_q), _largest_tile(seq_len, block_k)
 
 
 def _supported(s, d, block_q, block_k):
@@ -333,146 +345,13 @@ def _supported(s, d, block_q, block_k):
     return not (s < 8 or d % 8 or s % block_q or s % block_k)
 
 
-# ---------------------------------------------------------------------------
-# block-size autotuning (engine: hetu_tpu/tune/autotune.py)
-# ---------------------------------------------------------------------------
-
-# the sweep space: every candidate is a whole multiple of the TPU tile
-# and a divisor of S (enforced by _candidates), so any (bq, bk) pair in
-# it produces a valid grid
-_CANDIDATE_BLOCKS = (128, 256, 512, 1024)
-# per-candidate timing: reps amortize the host dispatch latency (one
-# readback sync per window, shared by `reps` queued kernel executions),
-# windows take the min over host jitter — candidate deltas are ~ms
-_MEASURE_REPS = 8
-_MEASURE_WINDOWS = 3
+# what this process's calls resolved, {(kind, token-major, S, D, dtype,
+# causal, has_mask): (block_q, block_k)}: the one reader is the
+# benchmark's ``flash_tiles`` log line (``tune/autotune.py:get_table``)
+RESOLVED_TILES = {}
 
 
-def _candidates(s):
-    return [c for c in _CANDIDATE_BLOCKS if c <= s and s % c == 0]
-
-
-# a kernel whose tile walk changed is swept afresh: winners stored for
-# the kernel it replaced (a checkout keeps its autotune.json across a
-# pull) sit under the old name and are not read. ``bwd`` was two kernels
-# (dK/dV, dQ) that ran the whole square at the tiles they liked best;
-# the forward a K loop a q-tile, whose (256, 512) cut the diagonal
-# coarsely and paid for it less than for its iterations.
-_KERNEL_REVISION = {"bwd": "bwd_onepass", "fwd": "fwd_regions",
-                    "fwd_lse": "fwd_lse_regions"}
-
-
-def tune_key(kind, s, d, dtype, causal, has_mask, interpret=False,
-             token_major=False):
-    """(name, key) under which a flash kernel's block choice is cached —
-    shared by the tuner, the probe and the tests. ``kind`` is one of
-    ``fwd`` / ``fwd_lse`` / ``bwd``; interpret-mode entries are
-    partitioned so CPU test sweeps never pollute a TPU cache, and the
-    token-major form of a kernel (other blocks, several heads a
-    program) is swept and stored apart from the head-major one."""
-    key = (f"S{s}", f"D{d}", jnp.dtype(dtype).name,
-           "causal" if causal else "full",
-           "mask" if has_mask else "nomask")
-    if interpret:
-        key = key + ("interp",)
-    return "flash_" + _KERNEL_REVISION.get(kind, kind) \
-        + ("_token_major" if token_major else ""), key
-
-
-def _measure_factory(kind, b, h, s, d, dtype, sm_scale, causal, has_mask,
-                     interpret, layout=None):
-    """measure(config) -> seconds for the autotune engine. Inputs are
-    built lazily on the first call (a cache hit never pays for them)
-    with the CALLER's b/h so the sweep times the shape that triggered
-    it; timing syncs by scalar readback."""
-    state = {}
-
-    def _inputs():
-        if state:
-            return state
-        rng = np.random.RandomState(0)
-
-        def mk():
-            shape = (b, h, s, d) if layout is None else (b, s, h * d)
-            return jnp.asarray(rng.randn(*shape) * 0.3, dtype)
-
-        if layout is not None and any(layout.tiles):
-            # the caller's packed rows: one array, read three times
-            state["q"] = state["k"] = state["v"] = jnp.concatenate(
-                [mk(), mk(), mk()], axis=-1)
-        else:
-            state["q"], state["k"], state["v"] = mk(), mk(), mk()
-        state["mask"] = (jnp.zeros((b, 1, 1, s), jnp.float32)
-                         if has_mask else None)
-        if kind == "bwd":
-            # consistent o/lse from the default-block forward: random
-            # residuals would exp() into inf and time a garbage kernel
-            bq0, bk0 = _block_sizes(s, d)
-            o, lse = _flash_attention_jit(
-                state["q"], state["k"], state["v"], state["mask"],
-                sm_scale, causal, interpret, bq0, bk0, True, layout)
-            state["o"], state["lse"], state["do"] = o, lse, mk()
-        return state
-
-    def _sync(out):
-        first = out[0] if isinstance(out, tuple) else out
-        return float(jnp.sum(first.astype(jnp.float32)))
-
-    def measure(cfg):
-        # NOTE: the engine calls measure on a dedicated sweep thread.
-        # The sweep fires at trace time of the surrounding step (the
-        # executor jits the whole graph), and jax's trace state is
-        # thread-local — on the caller's thread these jnp calls would
-        # silently become traced equations and the timings garbage.
-        bq, bk = int(cfg[0]), int(cfg[1])
-        st = _inputs()
-        if kind == "bwd":
-            def run():
-                return _flash_attention_bwd_jit(
-                    st["q"], st["k"], st["v"], st["mask"], st["o"],
-                    st["lse"], st["do"], sm_scale, causal, interpret,
-                    bq, bk, layout)
-        else:
-            need_lse = kind == "fwd_lse"
-
-            def run():
-                return _flash_attention_jit(
-                    st["q"], st["k"], st["v"], st["mask"], sm_scale,
-                    causal, interpret, bq, bk, need_lse, layout)
-        from ..tune import timeit
-        return timeit(run, _sync, reps=_MEASURE_REPS,
-                      windows=_MEASURE_WINDOWS)
-
-    return measure
-
-
-def _tuned_block_sizes(kind, b, h, s, d, dtype, sm_scale, causal,
-                       has_mask, interpret, layout=None):
-    """(block_q, block_k) for one kernel direction: the autotuned winner
-    when tuning is on and the shape has a real sweep space, the static
-    default otherwise. Runs at trace time — once per compiled shape —
-    so steady-state steps never touch the table."""
-    default = _block_sizes(s, d)
-    cands = [(bq, bk) for bq in _candidates(s) for bk in _candidates(s)]
-    if len(cands) < 2:
-        return default              # nothing to tune (short sequences)
-    from ..tune import autotune
-    name, key = tune_key(kind, s, d, dtype, causal, has_mask, interpret,
-                         layout is not None)
-    cfg = autotune(name, key, cands,
-                   _measure_factory(kind, b, h, s, d, dtype, sm_scale,
-                                    causal, has_mask, interpret, layout),
-                   default=default)
-    try:
-        bq, bk = int(cfg[0]), int(cfg[1])
-    except (TypeError, ValueError, IndexError):
-        return default
-    if bq < 8 or bk < 8 or s % bq or s % bk:
-        return default              # stale/foreign cache entry
-    return bq, bk
-
-
-def _plan(kind, q, mask, sm_scale, causal, interpret, layout, reason):
+def _plan(kind, q, mask, causal, interpret, layout, reason):
     """What the three entries share: ``(interpret, blocks)`` of a call —
     blocks None where the kernel does not take the shape — and the
     ``flash_layout`` instant, once a traced call: the operand form the
@@ -481,18 +360,19 @@ def _plan(kind, q, mask, sm_scale, causal, interpret, layout, reason):
     hands ``[B, H, S, D]`` operands and no reason is its own reason)."""
     if interpret is None:
         interpret = INTERPRET
-    b, h, s, d = _dims(q, layout)
+    _, _, s, d = _dims(q, layout)
     from .. import telemetry
     telemetry.get_telemetry().instant(
         "flash_layout", kernel=kind, seq=s, head_dim=d,
         layout="head_major" if layout is None else "token_major",
         heads_per_block=1 if layout is None else layout.per_block,
         **({"reason": reason or "caller"} if layout is None else {}))
-    if not _supported(s, d, *_block_sizes(s, d)):
+    blocks = _block_sizes(s, d, kind, causal, mask is not None)
+    if not _supported(s, d, *blocks):
         return interpret, None
-    return interpret, _tuned_block_sizes(
-        kind, b, h, s, d, q.dtype, sm_scale, causal, mask is not None,
-        interpret, layout)
+    RESOLVED_TILES[(kind, layout is not None, s, d, jnp.dtype(q.dtype).name,
+                    bool(causal), mask is not None)] = blocks
+    return interpret, blocks
 
 
 def _form(layout):
@@ -507,8 +387,8 @@ def _forward(kind, q, k, v, mask, sm_scale, causal, interpret, layout,
     ``flash_fwd_walk`` instant, once a traced call — how far the walk
     engages at the tiles chosen (``fwd_walk_counts``). None where the
     kernel does not take the shape."""
-    interpret, blocks = _plan(kind, q, mask, sm_scale, causal, interpret,
-                              layout, reason)
+    interpret, blocks = _plan(kind, q, mask, causal, interpret, layout,
+                              reason)
     if blocks is None:
         return None
     _, h, s, d = _dims(q, layout)
@@ -1069,7 +949,7 @@ def flash_attention_bwd(q, k, v, mask, o, lse, do, sm_scale=1.0,
     time as a ``flash_bwd_walk`` instant, beside the heads a program
     takes)."""
     interpret, (block_q, block_k) = _plan(
-        "bwd", q, mask, sm_scale, causal, interpret, layout, reason)
+        "bwd", q, mask, causal, interpret, layout, reason)
     _, h, s, d = _dims(q, layout)
     from .. import telemetry
     telemetry.get_telemetry().instant(

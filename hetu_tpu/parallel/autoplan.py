@@ -35,10 +35,8 @@ The planner:
      form included), with per-tick overhead from the p2p latency
      curve, which is what auto-picks M, V and fuse_ticks;
 
-4. **optionally refines** the top-k finalists by measurement through
-   the ``tune/autotune.py`` engine (same thread-safe sweep-once
-   cache, keyed ``platform|autoplan|model|nworld`` — deterministic
-   under ``HETU_AUTOTUNE=1`` with a warm cache), and
+4. **optionally refines** the top-k finalists by measurement (the
+   caller's ``measure``, each finalist once, the least wins), and
 5. **applies** the winner: Dispatch markers spliced for tp, stage
    contexts assigned over the balanced per-op-cost cut for pp, and
    the executor kwargs (schedule, M, ``pp_options``) returned.
@@ -136,8 +134,7 @@ class Plan:
 
 
 def plan_key(plan):
-    """Stable string form of a plan's knobs — the CI snapshot unit and
-    the autotune-refinement candidate id."""
+    """Stable string form of a plan's knobs — the CI snapshot unit."""
     return "dp{}-tp{}-pp{}-M{}-V{}-f{}".format(*plan.key())
 
 
@@ -714,11 +711,9 @@ def choose_plan(eval_nodes, nworld=None, rules=None, db=None,
     """Enumerate, score, and (optionally) measure candidates; returns
     an :class:`AutoPlanResult` with the argmin plan.
 
-    ``measure(plan) -> seconds`` activates the top-``topk`` refinement
-    through the autotune engine: the winner is cached under
-    ``platform|autoplan|<model>|<nworld>`` exactly like a kernel block
-    sweep, so a fleet of ranks plans once and CI replays
-    deterministically under ``HETU_AUTOTUNE=1``."""
+    ``measure(plan) -> seconds`` activates the top-``topk`` refinement:
+    each finalist is measured in this call and the least wins (nothing
+    is kept between processes)."""
     import jax
 
     from ..telemetry.costdb import CostDB, COMM_KINDS
@@ -760,19 +755,8 @@ def choose_plan(eval_nodes, nworld=None, rules=None, db=None,
     plans.sort(key=lambda p: p.predicted_ms)
 
     if measure is not None and len(plans) > 1:
-        winner_key = _refine_measured(plans[:max(1, topk)], measure,
-                                      model, nworld)
-        plans.sort(key=lambda p: (p.measured_ms
-                                  if p.measured_ms is not None
-                                  else p.predicted_ms))
-        if winner_key is not None:
-            # a warm autotune cache returns the winner WITHOUT
-            # re-measuring (times empty): honor it anyway, or re-runs
-            # would silently fall back to the predicted argmin
-            for i, p in enumerate(plans):
-                if plan_key(p) == winner_key:
-                    plans.insert(0, plans.pop(i))
-                    break
+        k = max(1, topk)
+        plans[:k] = _refine_measured(plans[:k], measure)
 
     comm_cov = db.coverage(COMM_KINDS)
     # fold the per-op compute coverage into the same report the doctor
@@ -790,29 +774,12 @@ def choose_plan(eval_nodes, nworld=None, rules=None, db=None,
                           info=info)
 
 
-def _refine_measured(finalists, measure, model, nworld):
-    """Measure the finalists through tune/autotune: candidates are
-    plan keys, the winner persists in the shared autotune cache.
-    Returns the winner's plan key (the cached one on a warm-cache
-    replay, where ``measure`` never runs) or None when tuning is
-    off / the sweep produced nothing."""
-    from ..tune.autotune import autotune, tuning_mode
-
-    if tuning_mode() == "off":
-        return None
-    by_key = {plan_key(p): p for p in finalists}
-    times = {}
-
-    def measure_rec(key):
-        dt = float(measure(by_key[key]))
-        times[key] = dt
-        return dt
-
-    winner = autotune("autoplan", (model, nworld), list(by_key),
-                      measure_rec, default=None)
-    for key, dt in times.items():
-        by_key[key].measured_ms = dt * 1000.0
-    return winner if winner in by_key else None
+def _refine_measured(finalists, measure):
+    """The finalists, each measured (``measured_ms``), the least
+    first."""
+    for plan in finalists:
+        plan.measured_ms = float(measure(plan)) * 1000.0
+    return sorted(finalists, key=lambda p: p.measured_ms)
 
 
 # ---------------------------------------------------------------------------

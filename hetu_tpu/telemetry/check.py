@@ -11,9 +11,8 @@ monotonic ts), known **span kinds carry a typed attr schema**: every
 instrumentation site in the codebase registers its span name and attr
 types in ``SPAN_SCHEMA`` below, and an exported trace whose known span
 carries an attr of the wrong type — or an attr the schema has never
-heard of — fails validation. That is the drift gate: PR 5's
-``autotune_sweep`` per-candidate args and PR 7's ``overlapped=`` attr
-shipped with no schema at all, so a consumer (the doctor's
+heard of — fails validation. That is the drift gate: PR 7's
+``overlapped=`` attr shipped with no schema at all, so a consumer (the doctor's
 hidden/exposed split, the regress field comparisons) could silently
 misread them. New span kinds/attrs must be added HERE and covered by a
 fixture trace in ``tests/test_doctor.py``.
@@ -34,7 +33,6 @@ _INT = (int,)
 _NUM = (int, float)
 _STR = (str,)
 _BOOL = (bool,)
-_DICT = (dict,)
 
 
 def _opt(kinds):
@@ -174,10 +172,7 @@ SPAN_SCHEMA = {
     "serve.decode.ahead": {"width": _req(_INT),
                            "batch_bucket": _req(_INT),
                            "ctx_bucket": _req(_INT)},
-    # autotuner / probe (tune/)
-    "autotune_sweep": {"kernel": _req(_STR), "key": _req(_STR),
-                       "chosen": _req(_STR), "picked_ms": _req(_NUM),
-                       "candidates_ms": _req(_DICT)},
+    # the probe (tune/)
     "attn_probe": {"kernel": _opt(_STR), "ms": _opt(_NUM),
                    "blocks": _opt(_STR), "seq": _opt(_INT),
                    "head_dim": _opt(_INT), "dtype": _opt(_STR)},

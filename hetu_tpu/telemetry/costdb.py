@@ -4,8 +4,8 @@ ROADMAP item 4's cost-model auto-parallelism planner needs costs that
 are "estimated, then refined by measurement" — the reference Hetu picks
 Hybrid vs AllReduce per table from *profiled* comm/compute ratios, not
 from an analytic model alone. This module is the measurement substrate:
-one persistent JSON table of measured milliseconds keyed exactly like
-``tune/autotune.py``'s cache — ``(platform, kind, shape, dtype)`` — so
+one persistent JSON table of measured milliseconds keyed
+``(platform, kind, shape, dtype)``, so
 an entry tuned on one chip generation is never served to another.
 
 Three producers populate it:
@@ -25,8 +25,8 @@ Three producers populate it:
   cost-model planner actually queries (``estimate_ms(kind, nbytes)``).
 
 Entries keep a running mean, min and sample count, so repeated
-measurement refines rather than overwrites. Persistence mirrors the
-autotune cache: atomic temp+rename writes under an advisory flock, with
+measurement refines rather than overwrites. Persistence is atomic
+temp+rename writes under an advisory flock, with
 a read-merge so two processes measuring different kinds against one
 file don't drop each other's entries.
 
@@ -147,7 +147,7 @@ def pow2_bucket(nbytes):
 
 
 class CostDB:
-    """Persistent measured-cost table; one JSON file, autotune-style
+    """Persistent measured-cost table; one JSON file, with
     ``platform|kind|shape|dtype`` keys."""
 
     def __init__(self, path=None):
@@ -161,7 +161,7 @@ class CostDB:
         return "|".join((_platform(), str(kind), _shape_str(shape),
                          str(dtype)))
 
-    # -- persistence (the autotune cache's discipline) -------------------
+    # -- persistence ----------------------------------------------------
     def _load(self):
         if self._entries is not None:
             return self._entries

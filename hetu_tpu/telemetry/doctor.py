@@ -14,7 +14,7 @@ buckets:
 ===============  ===========================================================
 bucket           span producers
 ===============  ===========================================================
-``jit``          ``jit_compile``, ``autotune_sweep``, ``cpp_build``
+``jit``          ``jit_compile``, ``cpp_build``
 ``compute``      ``device_dispatch``, ``block_dispatch``, ``cpp_dispatch``,
                  ``ps:dispatch``, pipeline fwd/bwd blocks
 ``collective``   ``allreduce*`` / ``collective*`` spans
@@ -50,7 +50,7 @@ prints a ranked diagnosis — top exposed bucket, bubble fraction,
 comm:compute ratio, transfer hidden fraction, cost-DB coverage gaps —
 each with a remediation pointer into the existing knobs
 (``overlap_options.lookahead`` / ``bucket_bytes``, ``pp_options`` M /
-``fuse_ticks``, ``HETU_AUTOTUNE``).
+``fuse_ticks``).
 
 **Serving mode**::
 
@@ -91,7 +91,7 @@ BUCKETS = _PRIORITY + ("unaccounted",)
 _WINDOW_NAMES = ("step", "step_block")
 
 _EXACT = {
-    "jit_compile": "jit", "autotune_sweep": "jit", "cpp_build": "jit",
+    "jit_compile": "jit", "cpp_build": "jit",
     "attn_probe": "jit",
     "device_dispatch": "compute", "block_dispatch": "compute",
     "cpp_dispatch": "compute", "ps:dispatch": "compute",
@@ -636,7 +636,7 @@ def render_serving_text(diag):
 # report cross-reference — `python -m hetu_tpu.analysis.efficiency`
 # predicts what this diagnosis measures
 _REMEDY_CODE = {
-    "h2d_ingest": "HT905", "collective": "HT904", "jit": "HT901/HT907",
+    "h2d_ingest": "HT905", "collective": "HT904", "jit": "HT901",
     "unaccounted": "HT903", "compute": "HT902/HT906",
 }
 
@@ -655,12 +655,12 @@ _REMEDY = {
               "consider the collective pipeline schedule",
     "collective": "set overlap_options.bucket_bytes to bucket gradient "
                   "allreduce and overlap it with the backward",
-    "jit": "shape churn: bucket feed shapes; warm HETU_AUTOTUNE=1 "
-           "cache so sweeps never run in measured steps",
+    "jit": "shape churn: bucket feed shapes so measured steps never "
+           "compile",
     "unaccounted": "host Python between dispatches: amortize with "
                    "run_batches / run_batches_stream (lax.scan blocks)",
-    "compute": "device-bound: tune kernels (HETU_AUTOTUNE, "
-               "tune/probe.py) or scale the mesh",
+    "compute": "device-bound: time the kernels (tune/probe.py) or "
+               "scale the mesh",
 }
 
 

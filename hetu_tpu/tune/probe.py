@@ -2,10 +2,10 @@
 
 ``bert_s2048`` runs at a fraction of roofline and the open question is
 "kernel or XLA remainder". This harness answers it with data instead of
-a guess: it times the tuned flash forward(+lse) and fused backward in
-isolation on the exact attention shape a model runs, times the STATIC
-default blocks beside them (the kernel-level before/after of the
-autotuner), and splits a measured full-step time into
+a guess: it times the flash forward(+lse) and fused backward in
+isolation on the exact attention shape a model runs, at the tiles the
+kernels' rule gives (``ops/pallas_attention.py:_block_sizes``) or at
+given ones, and splits a measured full-step time into
 attention-fwd / attention-bwd / XLA-remainder.
 
 Results flow through the process-global telemetry — one ``attn_probe``
@@ -17,7 +17,7 @@ CLI::
 
     python -m hetu_tpu.tune.probe --batch 8 --heads 8 --seq 2048 \
         --head-dim 64 --dtype bfloat16 [--causal] [--no-mask] \
-        [--step-ms 58.3 --layers 4]
+        [--blocks 512 256] [--step-ms 58.3 --layers 4]
 
 prints one JSON document; with ``--step-ms`` it includes the
 full-step attribution.
@@ -40,20 +40,19 @@ def _telemetry():
 
 def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
                     sm_scale=None, causal=False, has_mask=True,
-                    interpret=None, reps=5, include_static=True):
+                    interpret=None, reps=5, blocks=None):
     """Per-kernel milliseconds for the flash fwd(+lse)/bwd on one shape.
 
     Returns ``{"fwd_ms", "fwd_lse_ms", "bwd_ms", "blocks": {kind:
-    (bq, bk)}, "fwd_walk": {...}, "bwd_walk": {...}}`` plus ``static_*``
-    twins measured with the untuned ``_block_sizes`` defaults when
-    ``include_static``
-    (the in-repo tuned-vs-static evidence). Those are the head-major
-    kernels over ``[B, H, S, D]``. ``flash_layout`` is what a caller
+    (bq, bk)}, "fwd_walk": {...}, "bwd_walk": {...}}`` at the rule's
+    tiles, or with ``blocks = (bq, bk)`` every kernel at those (how a
+    candidate tile is measured against the rule's). Those are the
+    head-major kernels over ``[B, H, S, D]``. ``flash_layout`` is what a caller
     that hands this shape's packed qkv rows gets
     (``ops/attention.py:flash_layout``: the operand form, the heads a
     program owns, the reason when head-major); where the heads fill
     whole lane blocks, ``token_major`` holds the same three kernels
-    over the packed ``[B, S, 3H]`` rows with their own tiles, and
+    over the packed ``[B, S, 3H]`` rows at the same tiles, and
     ``layer_ms`` times ONE LAYER both ways from the same rows to the
     same rows (context ``[B, S, H]`` and d(qkv) ``[B, S, 3H]``):
     ``head_major`` with the split, the transposes and the merge XLA
@@ -70,11 +69,7 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
     visits and the share of visited tiles that carry the mask;
     ``fwd_walk`` the forward-with-lse's (``fwd_walk_counts``: the same
     counts at ITS tiles, the heads a program takes and its independent
-    chains; under ``token_major`` the lane-block form's). Uses the
-    tuned path, so a cold autotune
-    cache sweeps here — which is the point: the probe pays the sweep
-    the training step would have paid, and the cache makes both free
-    afterwards."""
+    chains; under ``token_major`` the lane-block form's)."""
     import jax
     import jax.numpy as jnp
 
@@ -108,24 +103,16 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
                      "causal": causal, "mask": has_mask},
            "blocks": {}}
 
-    def tuned_blocks(layout):
-        return {kind: pk._tuned_block_sizes(
-            kind, batch, heads, seq, head_dim, dtype, sm_scale, causal,
-            has_mask, interpret, layout)
-            for kind in ("fwd", "fwd_lse", "bwd")}
-
-    tuned = tuned_blocks(None)
+    # one rule for both operand forms
+    tuned = {kind: tuple(blocks) if blocks else pk._block_sizes(
+        seq, head_dim, kind, causal, has_mask)
+        for kind in ("fwd", "fwd_lse", "bwd")}
     out["blocks"] = {kind: list(b) for kind, b in tuned.items()}
-    static = pk._block_sizes(seq, head_dim)
     # how far the backward's tile walk engages at the tiles it runs
     # with: tiles visited / tiles of the square, masked / visited
     out["bwd_walk"] = pk.tile_walk_counts(seq, *tuned["bwd"], causal)
     out["fwd_walk"] = pk.fwd_walk_counts(heads, seq, *tuned["fwd_lse"],
                                          causal)
-    if include_static:
-        out["static_bwd_walk"] = pk.tile_walk_counts(seq, *static, causal)
-        out["static_fwd_walk"] = pk.fwd_walk_counts(heads, seq, *static,
-                                                    causal)
 
     def run_fwd(blocks, need_lse):
         bq, bk = blocks
@@ -161,10 +148,6 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
             ("bwd_ms", run_bwd(tuned["bwd"]), tuned["bwd"]),
             ("composed_vjp_ms", lambda: composed_vjp(q, k, v, do),
              tuned["bwd"])]
-    if include_static:
-        plan += [("static_fwd_ms", run_fwd(static, False), static),
-                 ("static_fwd_lse_ms", run_fwd(static, True), static),
-                 ("static_bwd_ms", run_bwd(static), static)]
     form, reason = flash_layout(seq, head_dim, heads, True)
     packed = pk.TokenMajor.packed(heads, head_dim)
     out["flash_layout"] = {
@@ -175,30 +158,29 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
             rng.randn(batch, seq, 3 * heads * head_dim) * 0.3, dtype)
         d_ctx = jnp.asarray(
             rng.randn(batch, seq, heads * head_dim) * 0.3, dtype)
-        tm = tuned_blocks(packed)
         out["token_major"] = {
-            "blocks": {kind: list(b) for kind, b in tm.items()},
-            "fwd_walk": pk.fwd_walk_counts(heads, seq, *tm["fwd_lse"],
+            "blocks": {kind: list(b) for kind, b in tuned.items()},
+            "fwd_walk": pk.fwd_walk_counts(heads, seq, *tuned["fwd_lse"],
                                            causal, packed),
             "bwd_heads_per_program": pk.heads_per_program(
-                heads, seq, *tm["bwd"], packed)}
+                heads, seq, *tuned["bwd"], packed)}
 
         def tm_fwd(need_lse):
-            bq, bk = tm["fwd_lse" if need_lse else "fwd"]
+            bq, bk = tuned["fwd_lse" if need_lse else "fwd"]
             return lambda: pk._flash_attention_jit(
                 rows, rows, rows, mask, sm_scale, causal, interpret, bq,
                 bk, need_lse, packed)
 
         ctx, rows_lse = tm_fwd(True)()
-        plan += [("token_major.fwd_ms", tm_fwd(False), tm["fwd"]),
-                 ("token_major.fwd_lse_ms", tm_fwd(True), tm["fwd_lse"]),
+        plan += [("token_major.fwd_ms", tm_fwd(False), tuned["fwd"]),
+                 ("token_major.fwd_lse_ms", tm_fwd(True), tuned["fwd_lse"]),
                  ("token_major.bwd_ms",
                   lambda: pk._flash_attention_bwd_jit(
                       rows, rows, rows, mask, ctx, rows_lse, d_ctx,
-                      sm_scale, causal, interpret, *tm["bwd"], packed),
-                  tm["bwd"])]
+                      sm_scale, causal, interpret, *tuned["bwd"], packed),
+                  tuned["bwd"])]
 
-        def layer(layout, blocks):
+        def layer(layout):
             """rows -> (context rows, d(qkv) rows), one layer's two
             flash calls with what XLA runs around them."""
             def run(x, dy):
@@ -212,10 +194,10 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
                     q_ = k_ = v_ = x
                 o_, l_ = pk._flash_attention_jit(
                     q_, k_, v_, mask, sm_scale, causal, interpret,
-                    *blocks["fwd_lse"], True, layout)
+                    *tuned["fwd_lse"], True, layout)
                 grads = pk._flash_attention_bwd_jit(
                     q_, k_, v_, mask, o_, l_, dy, sm_scale, causal,
-                    interpret, *blocks["bwd"], layout)
+                    interpret, *tuned["bwd"], layout)
                 if layout is not None:
                     return o_, jnp.concatenate(grads, axis=-1)
                 return (o_.transpose(0, 2, 1, 3).reshape(d_ctx.shape),
@@ -241,10 +223,10 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
                 if fused:
                     o_, l_ = pk._flash_attention_jit(
                         q_, k_, v_, mask, sm_scale, causal, interpret,
-                        *tm["fwd_lse"], True, apart)
+                        *tuned["fwd_lse"], True, apart)
                     return (o_,) + tuple(pk._flash_attention_bwd_jit(
                         q_, k_, v_, mask, o_, l_, dy, sm_scale, causal,
-                        interpret, *tm["bwd"], apart))
+                        interpret, *tuned["bwd"], apart))
                 heads_ = [to_heads(x) for x in (q_, k_, v_)]
                 o_ = pk._flash_attention_jit(
                     *heads_, mask, sm_scale, causal, interpret,
@@ -254,10 +236,10 @@ def probe_attention(batch, heads, seq, head_dim, dtype="bfloat16",
             jitted = jax.jit(run)
             return lambda: jitted(*three, d_ctx)
 
-        plan += [("layer_ms.head_major", layer(None, tuned),
+        plan += [("layer_ms.head_major", layer(None),
                   tuned["bwd"]),
-                 ("layer_ms.token_major", layer(packed, tm), tm["bwd"]),
-                 ("layer_ms.rows", encoder_layer(True), tm["bwd"]),
+                 ("layer_ms.token_major", layer(packed), tuned["bwd"]),
+                 ("layer_ms.rows", encoder_layer(True), tuned["bwd"]),
                  ("layer_ms.composed", encoder_layer(False),
                   tuned["fwd"])]
     for name, run, blocks in plan:
@@ -311,6 +293,10 @@ def main(argv=None):
     parser.add_argument("--causal", action="store_true")
     parser.add_argument("--no-mask", dest="mask", action="store_false")
     parser.add_argument("--reps", type=int, default=5)
+    parser.add_argument("--blocks", type=int, nargs=2, default=None,
+                        metavar=("BQ", "BK"),
+                        help="time every kernel at these tiles, not "
+                             "the rule's")
     parser.add_argument("--step-ms", type=float, default=None,
                         help="measured full-step ms to attribute")
     parser.add_argument("--layers", type=int, default=4)
@@ -318,7 +304,7 @@ def main(argv=None):
     out = probe_attention(args.batch, args.heads, args.seq,
                           args.head_dim, dtype=args.dtype,
                           causal=args.causal, has_mask=args.mask,
-                          reps=args.reps)
+                          reps=args.reps, blocks=args.blocks)
     if args.step_ms is not None:
         out["attribution"] = attribute_step(
             args.step_ms, args.layers, out["fwd_lse_ms"], out["bwd_ms"])

@@ -254,14 +254,20 @@ def _cut_by_the_diagonal(s, bq, bk):
     return order(np.argwhere(some)), order(np.argwhere(some & ~every))
 
 
+def _tile_sides(s):
+    """Every tile side a rule could return: whole lane blocks up to
+    1,024 that divide S."""
+    return [c for c in (128, 256, 512, 1024) if c <= s and s % c == 0]
+
+
 @pytest.mark.parametrize("s", [384, 1024, 2048])
 def test_backward_walk_visits_what_the_diagonal_leaves(s):
-    """At every tile pair the table may choose (and two it may not),
+    """At every tile pair the rule may return (and two it may not),
     with ``causal`` the walk runs exactly the pairs that hold a kept
     score and masks exactly those the diagonal cuts; without it, the
     whole square and no mask."""
     from hetu_tpu.ops import pallas_attention as pk
-    sides = pk._candidates(s) + [64, 192 if s == 384 else 32]
+    sides = _tile_sides(s) + [64, 192 if s == 384 else 32]
     for bq in sides:
         for bk in sides:
             visited, masked = pk.tile_walk(s, bq, bk, True)
@@ -306,15 +312,15 @@ def _forward_walk(s, bq, bk, causal):
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("s", [256, 1024, 2048])
 def test_forward_walk_visits_what_the_diagonal_leaves(s, causal):
-    """At every tile pair the sweep may choose, the forward's regions
+    """At every tile pair the rule may return, the forward's regions
     add up to exactly the pairs that hold a kept score, each once, and
     mask exactly those the diagonal cuts: no pair above the diagonal is
     run at any tiles, whether a region is the whole head or one of
     several a loop walks; the counts a traced call records
     (``fwd_walk_counts``) are those."""
     from hetu_tpu.ops import pallas_attention as pk
-    for bq in pk._candidates(s):
-        for bk in pk._candidates(s):
+    for bq in _tile_sides(s):
+        for bk in _tile_sides(s):
             visited, masked = _forward_walk(s, bq, bk, causal)
             assert len(set(visited)) == len(visited), (s, bq, bk)
             if causal:

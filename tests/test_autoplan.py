@@ -140,30 +140,27 @@ def test_cost_model_ranks_slow_axis_tp_below_good_plan(tmp_path):
 
 
 def test_measured_refinement_overrides_prediction(tmp_path):
-    """The top-k finalists run through the autotune engine; the
-    measured argmin wins even when the prediction preferred another
-    plan, and the winner is cached (second call sweeps nothing)."""
-    from hetu_tpu.tune.autotune import configure, reset
-    configure(path=str(tmp_path / "tune.json"), mode="auto")
-    try:
-        x, y_, loss, train, feeds = _chain(layers=4, h=64)
-        db = CostDB(str(tmp_path / "db.json"))
-        measured = {}
+    """The top-k finalists are measured; the measured argmin wins even
+    when the prediction preferred another plan."""
+    x, y_, loss, train, feeds = _chain(layers=4, h=64)
+    db = CostDB(str(tmp_path / "db.json"))
+    measured = {}
 
-        def measure(plan):
-            # synthetic ground truth: single-device is the fastest
-            dt = 0.001 if plan.key()[:3] == (1, 1, 1) else 0.1
-            measured[autoplan.plan_key(plan)] = dt
-            return dt
+    def measure(plan):
+        # synthetic ground truth: the LAST finalist is the fastest
+        dt = 0.1 - 0.01 * len(measured)
+        measured[autoplan.plan_key(plan)] = dt
+        return dt
 
-        res = autoplan.choose_plan([loss, train], nworld=8, db=db,
-                                   feed_shapes=feeds, model="refine",
-                                   measure=measure, topk=4)
-        assert measured, "no finalist was measured"
-        if autoplan.plan_key(res.plan) in measured:
-            assert res.plan.measured_ms is not None
-    finally:
-        reset()
+    res = autoplan.choose_plan([loss, train], nworld=8, db=db,
+                               feed_shapes=feeds, model="refine",
+                               measure=measure, topk=4)
+    assert len(measured) > 1, "the finalists were not measured"
+    assert autoplan.plan_key(res.plan) == min(measured, key=measured.get)
+    assert res.plan.measured_ms == pytest.approx(
+        1000 * min(measured.values()))
+    assert [p.measured_ms is not None for p in res.candidates] == \
+        [True] * len(measured) + [False] * (len(res.candidates) - len(measured))
 
 
 # ---------------------------------------------------------------------------
@@ -587,7 +584,6 @@ def test_autoplan_deterministic_against_fixture(monkeypatch):
     must update the snapshot deliberately)."""
     from hetu_tpu.analysis import zoo
 
-    monkeypatch.setenv("HETU_AUTOTUNE", "1")    # cache-only: no sweeps
     fixture = os.path.join(DATA, "costdb_fixture.json")
     snap_path = os.path.join(DATA, "autoplan_snapshot.json")
     snapshot = json.loads(open(snap_path).read())

@@ -8,9 +8,10 @@ Shapes are the three the chip paths run at real width — BERT-base
 (b64, h12, S128, padding mask), GPT-2 small (b8, h12, S1024, causal)
 and the long-sequence cell (b8, h8, S2048, padding mask) — in bf16,
 each for the plain forward, the forward with logsumexp and the fused
-backward (one kernel since PR 36); plus the smallest and the largest
-(bq, bk) the autotuner may pick at S=2048, the backward at the
-GPT-2 train cell's own shape (b16) over the corners of its candidate
+backward (one kernel since PR 36); plus every flash call the
+benchmark's cells trace at the tiles the rule gives it
+(``flash_cells.py``), the backward at the
+GPT-2 train cell's own shape (b16) over the corners of the tile
 grid, and the forward that walks regions (PR 40) at that cell's packed
 rows, at BERT's cell (twelve heads a program) and head-major at
 S = 8,192, D = 192; and both kernels token-major at BERT's cell, a
@@ -35,6 +36,8 @@ import jax.numpy as jnp  # noqa: E402
 
 from hetu_tpu.ops import pallas_attention as pk  # noqa: E402
 from hetu_tpu.ops import pallas_norm  # noqa: E402
+
+from flash_cells import CELL_CALLS, call_id  # noqa: E402
 
 # name -> (batch, heads, seq, head_dim, causal, has_mask)
 SHAPES = {
@@ -111,12 +114,11 @@ def test_flash_kernel_compiles_at_static_tiles(one_chip, name, kind):
                          ids=lambda b: f"{b[0]}x{b[1]}")
 def test_one_pass_backward_compiles_at_the_train_cell(one_chip, blocks):
     """The GPT-2 train cell's backward (batch 16, causal) at the four
-    corners of the candidate grid and the two square tiles the walk
+    corners of the tile grid and the two square tiles the walk
     skips most at: one custom call, under the name the trace's readers
     match, and the row residuals reach it as rows — no float32
     ``[B*H, S, 128]`` lane broadcast is left around it."""
     b, h, s, d = 16, 12, 1024, 64
-    assert set(blocks) <= set(pk._candidates(s))
     text = _compile("bwd", (b, h, s, d, True, False), blocks, one_chip)
     entry = text[text.index("ENTRY"):]
     calls = [ln for ln in entry.splitlines()
@@ -127,16 +129,39 @@ def test_one_pass_backward_compiles_at_the_train_cell(one_chip, blocks):
     assert f"f32[{b * h},1,{s}]" in calls[0]
 
 
-@pytest.mark.parametrize("kind", KINDS)
-@pytest.mark.parametrize("edge", ["smallest", "largest"])
-def test_autotune_candidate_edges_compile_s2048(one_chip, edge, kind):
-    """Every (bq, bk) the autotuner may pick must be one the compiler
-    takes; the corners of the candidate grid bound the VMEM demand."""
-    shape = SHAPES["s2048_mask"]
-    cands = pk._candidates(shape[2])
-    block = min(cands) if edge == "smallest" else max(cands)
-    assert "tpu_custom_call" in _compile(kind, shape, (block, block),
-                                         one_chip)
+@pytest.mark.parametrize("call", CELL_CALLS, ids=call_id)
+def test_every_tile_the_rule_returns_compiles(one_chip, call):
+    """Every flash call the benchmark's cells trace, at the rule's
+    tiles, in the call's own operand form and dtype, a program as wide
+    as the cell's: one the chip's compiler takes (a tile it refuses, or
+    a block set past VMEM, fails here and not in a cell)."""
+    b, h, s, d = call.batch, call.heads, call.seq, call.head_dim
+    blocks = pk._block_sizes(s, d, call.kind, call.causal, call.has_mask)
+    dtype = jnp.dtype(call.dtype)
+
+    def arr(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    mask = arr(b, 1, 1, s, dt=jnp.float32) if call.has_mask else None
+    if call.token_major:
+        packed = call.rows == "packed"
+        layout = (pk.TokenMajor.packed if packed else pk.TokenMajor)(h, d)
+        qkv, ctx = arr(b, s, (3 if packed else 1) * h * d), arr(b, s, h * d)
+        lse, form = arr(b, h, 1, s, dt=jnp.float32), (layout,)
+    else:
+        qkv = ctx = arr(b, h, s, d)
+        lse, form = arr(b, h, s, dt=jnp.float32), ()
+    scale = 1.0 / float(d) ** 0.5
+    if call.kind == "bwd":
+        lowered = pk._flash_attention_bwd_jit.lower(
+            qkv, qkv, qkv, mask, ctx, lse, ctx, scale, call.causal, False,
+            *blocks, *form)
+    else:
+        lowered = pk._flash_attention_jit.lower(
+            qkv, qkv, qkv, mask, scale, call.causal, False, *blocks,
+            call.kind == "fwd_lse", *form)
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("shape,dtype", [
@@ -238,7 +263,7 @@ def test_token_major_kernels_compile_at_the_train_cell(one_chip, kind,
                                                        blocks):
     """Two heads a lane tile split by static lane windows (loads and
     stores at lane 64), the logsumexp written as ``[B, H, 1, S]`` rows:
-    Mosaic takes it at the tiles the sweep may pick, as ONE custom call
+    Mosaic takes it over the tile grid, as ONE custom call
     under the name the trace's readers match, and the operands are the
     packed rows themselves — no copy feeds the call."""
     text = _compile_token_major(kind, blocks, one_chip)
@@ -262,12 +287,11 @@ def test_token_major_kernels_compile_at_the_train_cell(one_chip, kind,
                          ids=lambda b: f"{b[0]}x{b[1]}")
 def test_region_forward_compiles_at_the_train_cell(one_chip, blocks):
     """The forward that walks regions (PR 40) at the GPT-2 train cell's
-    shape, at the tiles a v5e's sweep liked and the two lopsided corners
-    of its grid (the square corners, the static tiles and (256, 256)
+    shape, at the rule's tiles, a square pair and the two lopsided
+    corners of the grid (the square corners, (256, 512) and (256, 256)
     are in the test above): a lane block's whole triangle a program,
     one custom call under the name the trace's readers match, the
     residual written as the rows the backward takes."""
-    assert set(blocks) <= set(pk._candidates(1024))
     text = _compile_token_major("fwd_lse", blocks, one_chip)
     entry = text[text.index("ENTRY"):]
     calls = [ln for ln in entry.splitlines()
@@ -295,15 +319,15 @@ def test_grouped_forward_compiles_at_berts_cell(one_chip, kind):
 
 
 @pytest.mark.parametrize("blocks", [None, (128, 128), (1024, 1024)],
-                         ids=["static", "128x128", "1024x1024"])
+                         ids=["rule", "128x128", "1024x1024"])
 def test_region_forward_compiles_at_the_longest_prefill(one_chip, blocks):
     """Head-major at S = 8,192, D = 192 (the latent-attention cells'
     largest prompt bucket: K and V of a head are 16.8 MB of VMEM,
     double-buffered, and the kernel asks for what it needs), causal, the
-    regions walked by a loop: at the static tiles and at the two square
-    corners of the sweep's grid."""
+    regions walked by a loop: at the rule's tiles and at the two square
+    corners of the tile grid."""
     s, d = 8192, 192
-    blocks = blocks or pk._block_sizes(s, d)
+    blocks = blocks or pk._block_sizes(s, d, "fwd", True)
     assert pk._region_span(s, *blocks) < s
     assert pk.heads_per_program(64, s, *blocks) == 1
     text = _compile("fwd", (1, 4, s, d, True, False), blocks, one_chip)
@@ -328,7 +352,6 @@ def _gpt2_step_text(v5e_device, monkeypatch, dropout):
     from hetu_tpu.ops import attention
 
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
-    monkeypatch.setenv("HETU_AUTOTUNE", "0")    # the sweep needs a device
     model = GPTLMHeadModel(GPTConfig(
         vocab_size=1024, hidden_size=768, num_hidden_layers=1,
         num_attention_heads=12, max_position_embeddings=1024,
@@ -479,7 +502,6 @@ def test_bert_step_holds_no_head_major_relayout(v5e, monkeypatch):
     from hetu_tpu.ops import attention
 
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
-    monkeypatch.setenv("HETU_AUTOTUNE", "0")    # the sweep needs a device
     batch, seq = 256, 128
     model = BertForPreTraining(BertConfig(
         vocab_size=1024, hidden_size=768, num_hidden_layers=1,
@@ -591,7 +613,6 @@ def test_gpt_serving_program_streams_its_matrices_and_aliases_its_pools(
     from hetu_tpu.serving.scheduler import _named_program
 
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
-    monkeypatch.setenv("HETU_AUTOTUNE", "0")    # the sweep needs a device
     cfg = GPTConfig(vocab_size=50257, hidden_size=768,
                     num_hidden_layers=12, num_attention_heads=12,
                     max_position_embeddings=1024)

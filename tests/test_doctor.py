@@ -370,9 +370,6 @@ def _producer_fixture_tracer():
          aligned=True, ranks=3)
     span("fleet_watch", step=-1, straggler=None, skew_ms=0.0, victims=0)
     span("health", step=10, layers=3, trips=1)
-    span("autotune_sweep", kernel="flash_fwd", key="cpu|flash|128",
-         chosen="(128, 128)", picked_ms=1.2,
-         candidates_ms={"(128, 128)": 1.2, "(256, 256)": None})
     span("attn_probe", kernel="fwd", ms=0.5, blocks="(128, 128)",
          seq=2048, head_dim=64, dtype="bfloat16")
     tr.instant("flash_bwd_walk", seq=1024, head_dim=64, block_q=256,
@@ -408,9 +405,7 @@ def test_schema_accepts_every_producer_fixture(tmp_path):
     ("ps:pull", {"bytes": 10}, "overlapped"),
     # unknown attr on a known span = schema drift
     ("step_block", {"steps": 2, "novel_attr": 1}, "unknown attr"),
-    ("autotune_sweep", {"kernel": "k", "key": "x", "chosen": "c",
-                        "picked_ms": "fast", "candidates_ms": {}},
-     "picked_ms"),
+    ("attn_probe", {"kernel": "fwd", "ms": "fast"}, "ms"),
     ("cpp_dispatch", {"fill": 1}, "ticks"),
     # the flash backward's walk: the shares are numbers, causal a bool
     ("flash_bwd_walk", {"seq": 1024, "block_q": 256, "block_k": 256,

@@ -39,13 +39,10 @@ THIS_FILE = os.path.abspath(__file__)
 
 @pytest.fixture(autouse=True)
 def _isolated_dbs(tmp_path, monkeypatch):
-    """Deterministic cold-start pricing: the developer's real cost /
-    autotune caches must not leak measured entries into fixture
+    """Deterministic cold-start pricing: the developer's real cost
+    database must not leak measured entries into fixture
     expectations."""
     monkeypatch.setenv("HETU_COSTDB", str(tmp_path / "costdb.json"))
-    monkeypatch.setenv("HETU_AUTOTUNE_CACHE",
-                       str(tmp_path / "autotune.json"))
-    monkeypatch.delenv("HETU_AUTOTUNE", raising=False)
     monkeypatch.delenv("HETU_EFF_THRESHOLD_MS", raising=False)
 
 
@@ -401,56 +398,6 @@ def test_ht906_dead_compute_fixture():
     waived, _ = run_pass(waived_nodes, feed_shapes=feeds,
                          extra_roots=roots)
     assert "HT906" not in codes(waived)
-
-
-# ---------------------------------------------------------------------------
-# HT907 — untuned hot-path kernel
-# ---------------------------------------------------------------------------
-
-def _ht907_graph(waived=False):
-    q = ht.Variable("q907", trainable=False)
-    k = ht.Variable("k907", trainable=False)
-    v = ht.Variable("v907", trainable=False)
-    if waived:
-        attn = ht.flash_attention_op(q, k, v, causal=True)  # ht-ok: HT907 test waiver: fixture pins the suppression path
-    else:
-        attn = ht.flash_attention_op(q, k, v, causal=True)
-    shp = ((2, 4, 2048, 64), np.float32)
-    return [attn], {q: shp, k: shp, v: shp}
-
-
-def test_ht907_untuned_flash_fixture(monkeypatch):
-    eval_nodes, feeds = _ht907_graph()
-    report, _ = run_pass(eval_nodes, feed_shapes=feeds, steps=100)
-    hits = [f for f in report.findings if f.code == "HT907"]
-    assert len(hits) == 1
-    f = hits[0]
-    assert f.severity == "warn"
-    assert_priced(f)
-    assert f.data["bucket"] == "jit"
-    assert f.data["estimated_ms_first_step"] > \
-        f.data["estimated_ms_per_step"]
-    assert f.data["sweep_candidates"] >= 2
-    # clean twin 1: tuning off -> no sweep will ever run
-    monkeypatch.setenv("HETU_AUTOTUNE", "0")
-    clean, _ = run_pass(eval_nodes, feed_shapes=feeds)
-    assert "HT907" not in codes(clean)
-    monkeypatch.delenv("HETU_AUTOTUNE")
-    # clean twin 2: a warmed cache
-    from hetu_tpu.ops.pallas_attention import tune_key
-    from hetu_tpu.tune.autotune import AutotuneTable
-    table = AutotuneTable()
-    for kind in ("fwd", "fwd_lse", "bwd"):
-        name, key = tune_key(kind, 2048, 64, np.float32, True, False)
-        table.put(name, key, (256, 256))
-    warm, _ = run_pass(eval_nodes, feed_shapes=feeds)
-    assert "HT907" not in codes(warm)
-
-
-def test_ht907_suppressed():
-    eval_nodes, feeds = _ht907_graph(waived=True)
-    report, _ = run_pass(eval_nodes, feed_shapes=feeds)
-    assert "HT907" not in codes(report)
 
 
 # ---------------------------------------------------------------------------

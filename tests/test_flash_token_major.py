@@ -258,7 +258,6 @@ def kernels_on_cpu(monkeypatch):
     # a whole graph traced "on the chip" meets the other kernels too
     monkeypatch.setattr(pallas_norm, "INTERPRET", True)
     monkeypatch.setattr(pallas_dropout, "INTERPRET", True)
-    monkeypatch.setenv("HETU_AUTOTUNE", "0")
     old = tmod._default
     tel = tmod.configure(enabled=True, service="test-flash-layout")
     yield tel
@@ -328,8 +327,8 @@ def test_packed_op_runs_token_major_and_says_so(kernels_on_cpu):
         "seq": s, "head_dim": d, "block_q": bq, "block_k": bk,
         "causal": True, "heads_per_program": 2, "chains": 2 * (s // bq),
         **pk.tile_walk_counts(s, bq, bk, True)}
-    # the static tiles: one k-tile of 512 that the diagonal cuts twice
-    assert (bq, bk, fwd[0]["tiles_masked"]) == (256, 512, 2)
+    # the rule's tiles: one q-tile of 512, the diagonal cuts both its k-tiles
+    assert (bq, bk, fwd[0]["tiles_masked"]) == (512, 256, 2)
     want_out, want_grad = _packed_reference(rows, heads, d, dy)
     assert dqkv.shape == rows.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
@@ -393,12 +392,26 @@ def test_head_major_callers_keep_the_head_major_entry(kernels_on_cpu):
         FlashAttentionOp(*nodes[:2], num_heads=2)
 
 
-def test_token_major_tiles_are_stored_apart():
-    head = pk.tune_key("bwd", 1024, 64, jnp.bfloat16, True, False)
-    rows = pk.tune_key("bwd", 1024, 64, jnp.bfloat16, True, False,
-                       token_major=True)
-    assert head[1] == rows[1] and head[0] != rows[0]
-    assert rows[0] == head[0] + "_token_major"
+def test_the_two_operand_forms_are_resolved_and_recorded_apart(
+        monkeypatch):
+    """The train driver's ``flash_tiles`` line: what this process's calls
+    resolved, under the key strings the swept store had."""
+    from hetu_tpu.tune.autotune import get_table
+    monkeypatch.setattr(pk, "RESOLVED_TILES", {})
+    q = jnp.zeros((1, 256, 128), jnp.float32)
+    rows = pk.TokenMajor(2, 64)
+    pk.flash_attention_with_lse(q, q, q, None, 0.125, True,
+                                interpret=True, layout=rows)
+    heads = jnp.zeros((1, 2, 256, 64), jnp.float32)
+    pk.flash_attention_with_lse(heads, heads, heads, None, 0.125, True,
+                                interpret=True)
+    chosen = get_table().chosen("flash")
+    tiles = pk._block_sizes(256, 64, "fwd_lse", True, False)
+    assert chosen == {
+        "cpu|flash_fwd_lse_regions|S256|D64|float32|causal|nomask": tiles,
+        "cpu|flash_fwd_lse_regions_token_major|S256|D64|float32|causal"
+        "|nomask": tiles}
+    assert get_table().chosen("flash_bwd") == {}
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,6 @@ from hetu_tpu import cachedir, executor, launcher, ndarray
 from hetu_tpu.analysis import memory
 from hetu_tpu.ops import attention
 
-# the package re-exports the autotune() function under the module's name
 autotune = importlib.import_module("hetu_tpu.tune.autotune")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -74,7 +73,7 @@ def test_kernel_import_error_surfaces_on_tpu(monkeypatch):
         attention.prefill_attention(q, q, q, sm_scale=1.0)
 
 
-# -- autotune ----------------------------------------------------------------
+# -- the platform tag ---------------------------------------------------------
 
 def test_platform_tag_has_no_unknown(monkeypatch):
     def boom():
@@ -149,11 +148,9 @@ def test_external_compile_cache_dir_is_left_untouched(
 def test_json_stores_default_inside_the_checkout(monkeypatch):
     from hetu_tpu.analysis import rangecheck
     from hetu_tpu.telemetry import costdb
-    for var in ("HETU_AUTOTUNE_CACHE", "HETU_COSTDB", "HETU_RANGEDB"):
+    for var in ("HETU_COSTDB", "HETU_RANGEDB"):
         monkeypatch.delenv(var, raising=False)
     root = os.path.join(REPO, ".jax_cache", "hetu_tpu")
-    assert autotune.default_cache_path() == os.path.join(
-        root, "autotune.json")
     assert costdb.default_db_path() == os.path.join(root, "costdb.json")
     assert rangecheck.default_db_path() == os.path.join(
         root, "ranges.json")
