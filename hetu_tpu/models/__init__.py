@@ -13,6 +13,7 @@ from .cnn import (logreg, mlp, cnn_3_layers, digits_cnn, lenet, alexnet,
 from .gpt import GPTConfig, GPTModel, GPTLMHeadModel
 from .latent_moe import LatentMoEConfig
 from .ssm_hybrid import SSMHybridConfig
+from .window_moe import WindowMoEConfig
 from .bert import (BertConfig, BertModel, BertForPreTraining,
                    BertForSequenceClassification, BertForMaskedLM)
 from .ctr import (wdl_criteo, wdl_adult, deepfm_criteo, dcn_criteo,

@@ -47,6 +47,9 @@ import math
 
 import numpy as np
 
+from .decoder_parts import (feed_forward, pool_scatter, records, rms, rope,
+                            token_chunks)
+
 __all__ = ["LatentMoEConfig", "LatentMoEServingModel",
            "latent_moe_param_shapes", "latent_moe_serving_params",
            "latent_moe_forward", "latent_moe_paged_prefill",
@@ -55,7 +58,7 @@ __all__ = ["LatentMoEConfig", "LatentMoEServingModel",
 
 # what every program returns beside its tokens or logits, int32, in
 # this order, followed by the rows of each held expert and then, for
-# each row of the batch, its record (``_records``)
+# each row of the batch, its record (``decoder_parts.records``)
 COUNTERS = ("moe_tokens", "moe_routed_rows", "moe_expert_visits",
             "mla_context_rows", "mla_score_pairs")
 # ... and, of a model with hyper-connections, behind those: real tokens
@@ -208,28 +211,6 @@ def _rope_tables(config, positions):
     return jnp.cos(angles) * factor, jnp.sin(angles) * factor
 
 
-def _rope(x, cos, sin):
-    """Rotate ``x [..., rope]`` in halves: pair ``(i, i + rope / 2)``
-    turns by ``position * inv_freq[i]`` (the source model stores the
-    pairs interleaved; that is a fixed permutation of the projection's
-    columns). ``cos`` / ``sin`` broadcast against ``x``'s halves."""
-    import jax.numpy as jnp
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half].astype(jnp.float32), \
-        x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _rms(x, weight, eps):
-    import jax
-    import jax.numpy as jnp
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                            + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
-
-
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
@@ -329,55 +310,6 @@ def latent_moe_serving_params(config, lookup):
 # the forward
 # ---------------------------------------------------------------------------
 
-def _token_chunks(fn, x, valid):
-    """``fn(x [T, H], valid [T]) -> (y [T, H], picks [T, k], counts)``
-    over at most ``ops.moe.TOKEN_CHUNK`` tokens at a time, one pass
-    after another, so that a feed-forward's temporaries are a chunk's
-    whatever the prompt bucket; ``counts`` add up over the passes."""
-    import jax
-    import jax.numpy as jnp
-    from ..ops.moe import TOKEN_CHUNK
-    t = x.shape[0]
-    if t <= TOKEN_CHUNK:
-        return fn(x, valid)
-    pad = -t % TOKEN_CHUNK
-
-    def chunks(a):
-        a = jnp.concatenate([a, jnp.zeros((pad, *a.shape[1:]), a.dtype)])
-        return a.reshape(-1, TOKEN_CHUNK, *a.shape[1:])
-
-    y, picks, counts = jax.lax.map(lambda c: fn(*c),
-                                   (chunks(x), chunks(valid)))
-    return (y.reshape(-1, y.shape[-1])[:t],
-            picks.reshape(-1, picks.shape[-1])[:t],
-            jax.tree_util.tree_map(lambda c: jnp.sum(c, axis=0), counts))
-
-
-def _feed_forward(config, blk, x, valid):
-    """One layer's feed-forward on ``x [T, H]``: SwiGLU in a dense
-    layer; in an expert layer the shared expert plus the held experts'
-    part of the routed sum. Returns ``(y [T, H], the router's picks
-    [T, k] int32 (zeros in a dense layer), (rows by held expert [held]
-    int32, held experts visited))``."""
-    import jax.numpy as jnp
-    from ..ops import moe
-    held = config.experts_held[1]
-    if "mlp_gate_up" in blk:
-        return (moe.swiglu(x, blk["mlp_gate_up"], blk["mlp_down"]),
-                jnp.zeros((x.shape[0], config.num_experts_per_tok),
-                          jnp.int32),
-                (jnp.zeros(held, jnp.int32), jnp.int32(0)))
-    experts, weights, _ = moe.route(
-        x, blk["router"], blk["router_bias"], config.num_experts_per_tok,
-        config.routed_scaling_factor)
-    routed, rows = moe.held_experts(
-        x, experts, weights, valid, blk["experts_gate_up"],
-        blk["experts_down"], first=config.experts_held[0])
-    shared = moe.swiglu(x, blk["shared_gate_up"], blk["shared_down"])
-    y = (shared.astype(jnp.float32) + routed).astype(x.dtype)
-    return y, experts, (rows, jnp.sum(rows > 0).astype(jnp.int32))
-
-
 def _serve_forward(params, config, x, positions, attend, valid):
     """THE decoder stack, written once: embedded tokens ``x [..., H]``
     at int32 ``positions [...]`` through every layer and the final
@@ -406,30 +338,30 @@ def _serve_forward(params, config, x, positions, attend, valid):
     picks = []
     for i, blk in enumerate(params["blocks"]):
         h, carry = read(x, blk, "hc_attn")
-        h = _rms(h, blk["attn_norm"], c.rms_norm_eps)
+        h = rms(h, blk["attn_norm"], c.rms_norm_eps)
         kv = h @ blk["kv_a"]
         row = jnp.concatenate(
-            [_rms(kv[..., :latent], blk["kv_norm"], c.rms_norm_eps),
-             _rope(kv[..., latent:], cos, sin),
+            [rms(kv[..., :latent], blk["kv_norm"], c.rms_norm_eps),
+             rope(kv[..., latent:], cos, sin),
              jnp.zeros((*lead, pad), kv.dtype)], axis=-1)
         if "q_a" in blk:
-            q = _rms(h @ blk["q_a"], blk["q_a_norm"], c.rms_norm_eps) \
+            q = rms(h @ blk["q_a"], blk["q_a_norm"], c.rms_norm_eps) \
                 @ blk["q_b"]
         else:
             q = h @ blk["q"]
         q = q.reshape(*lead, nh, c.q_head_dim)
         if "q_norm" in blk:
-            q = _rms(q, blk["q_norm"], c.rms_norm_eps)
+            q = rms(q, blk["q_norm"], c.rms_norm_eps)
         q = jnp.concatenate(
-            [q[..., :nope], _rope(q[..., nope:], cos[..., None, :],
+            [q[..., :nope], rope(q[..., nope:], cos[..., None, :],
                                   sin[..., None, :])], axis=-1)
         ctx = attend(i, blk, q, row).astype(x.dtype)
         x = write(x, ctx.reshape(*lead, nh * c.v_head_dim) @ blk["o"],
                   carry)
         h, carry = read(x, blk, "hc_ffn")
-        h = _rms(h, blk["ffn_norm"], c.rms_norm_eps)
-        y, picked, (rows, seen) = _token_chunks(
-            lambda xc, vc, blk=blk: _feed_forward(c, blk, xc, vc),
+        h = rms(h, blk["ffn_norm"], c.rms_norm_eps)
+        y, picked, (rows, seen) = token_chunks(
+            lambda xc, vc, blk=blk: feed_forward(c, blk, xc, vc),
             h.reshape(-1, h.shape[-1]), flat_valid)
         x = write(x, y.reshape(h.shape), carry)
         rows_total, visits = rows_total + rows, visits + seen
@@ -437,7 +369,7 @@ def _serve_forward(params, config, x, positions, attend, valid):
             picks.append(picked.reshape(*lead, -1))
     picks = jnp.stack(picks, axis=-2) if picks else jnp.zeros(
         (*lead, 0, c.num_experts_per_tok), jnp.int32)
-    return (_rms(leave(x), params["norm"], c.rms_norm_eps), picks,
+    return (rms(leave(x), params["norm"], c.rms_norm_eps), picks,
             rows_total, visits)
 
 
@@ -517,20 +449,6 @@ def _unabsorb(config, blk, ctx_latent, dtype):
     return jnp.einsum("...hl,lhd->...hd", ctx_latent.astype(dtype), w_v)
 
 
-def _pool_scatter(pool, slots, rows):
-    """Write ``rows [..., W]`` into flat slots ``slots [...]`` of one
-    layer's pool ``[num_blocks, block_size, W]`` (duplicates, the
-    padded lanes on the scratch block, resolve to SOME row). The slot
-    is split into (block, row in block) and the pool indexed as it
-    lies: flattening a bfloat16 pool first costs two pool-sized copies
-    a layer a step on the chip (the flat and the blocked layouts tile
-    differently)."""
-    block_size, width = pool.shape[-2:]
-    flat = slots.reshape(-1)
-    return pool.at[flat // block_size, flat % block_size].set(
-        rows.reshape(-1, width).astype(pool.dtype), mode="drop")
-
-
 def _paged_attend(pools, write_slots, attention):
     """The block-paged backend: layer ``i``'s rows scatter into
     ``pools[i]["c"]`` at ``write_slots``, then ``attention(blk, q, row,
@@ -538,28 +456,11 @@ def _paged_attend(pools, write_slots, attention):
     new_pools = []
 
     def attend(i, blk, q, row):
-        pool = _pool_scatter(pools[i]["c"], write_slots, row)
+        pool = pool_scatter(pools[i]["c"], write_slots, row)
         new_pools.append({"c": pool})
         return attention(blk, q, row, pool)
 
     return attend, new_pools
-
-
-def _records(picks, logits):
-    """One int32 record a row of the batch, of the token the row's
-    float32 ``logits [B, V]`` decide: the experts each expert layer's
-    router picked for it (``picks [B, expert layers, k]``), then the
-    bits of the row's best logit. What the engine hands back beside a
-    generated token (``Future.token_records``); a checker forces a
-    reference onto the same routing with it
-    (:meth:`LatentMoEServingModel.read_records`)."""
-    import jax
-    import jax.numpy as jnp
-    best = jax.lax.bitcast_convert_type(
-        jnp.max(logits, axis=-1).astype(jnp.float32), jnp.int32)
-    return jnp.concatenate(
-        [picks.reshape(picks.shape[0], -1).astype(jnp.int32),
-         best[:, None]], axis=1)
 
 
 def _counters(config, valid, rows, visits, context_rows, score_pairs,
@@ -627,7 +528,7 @@ def latent_moe_paged_prefill(params, pools, ids, slot_idx, last_pos,
     logits = _logits(params, last)
     counters = _counters(config, valid, rows, visits, jnp.sum(lengths),
                          jnp.sum(lengths * (lengths + 1) // 2),
-                         _records(picks, logits))
+                         records(picks, logits))
     return (logits, counters), new_pools
 
 
@@ -661,7 +562,7 @@ def latent_moe_paged_step(params, pools, tokens, positions, slot_idx,
     context = jnp.sum(jnp.where(valid, positions + 1, 0))
     logits = _logits(params, x)
     counters = _counters(config, valid, rows, visits, context, context,
-                         _records(picks, logits))
+                         records(picks, logits))
     if pick == "greedy":
         return jnp.concatenate(
             [jnp.argmax(logits, axis=-1).astype(jnp.int32),
@@ -698,7 +599,7 @@ def latent_moe_paged_suffix_prefill(params, pools, ids, starts, slot_idx,
     # a row's record is of its last real position in this chunk
     at = jnp.maximum(jnp.sum(valid, axis=1) - 1, 0).astype(jnp.int32)
     counters = _counters(
-        config, valid, rows, visits, context, context, _records(
+        config, valid, rows, visits, context, context, records(
             jnp.take_along_axis(picks, at[:, None, None, None],
                                 axis=1)[:, 0],
             jnp.take_along_axis(logits, at[:, None, None], axis=1)[:, 0]))

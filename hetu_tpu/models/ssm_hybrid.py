@@ -291,20 +291,20 @@ def _forward(params, config, pools, x, mamba, attention):
     import jax
     import jax.numpy as jnp
     from ..ops.moe import swiglu
-    from .latent_moe import _rms
+    from .decoder_parts import rms
     c = config
     eps = c.rms_norm_eps
     nq, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
 
     def feed_forward(blk, x):
-        return x + swiglu(_rms(x, blk["ffn_norm"], eps),
+        return x + swiglu(rms(x, blk["ffn_norm"], eps),
                           blk["mlp_gate_up"], blk["mlp_down"])
 
     def mamba_layer(carry, step):
         x, state = carry
         blk, layer = step
         out, state = mamba(blk, state, layer,
-                           _rms(x, blk["mixer_norm"], eps))
+                           rms(x, blk["mixer_norm"], eps))
         return (feed_forward(blk, x + out), state), None
 
     state, rows = pools[0], list(pools[1:])
@@ -318,7 +318,7 @@ def _forward(params, config, pools, x, mamba, attention):
             done += n
         if blk is None:
             continue
-        qkv = _rms(x, blk["mixer_norm"], eps) @ blk["qkv"]
+        qkv = rms(x, blk["mixer_norm"], eps) @ blk["qkv"]
         q = qkv[..., :nq * hd].reshape(*x.shape[:-1], nq, hd)
         k = qkv[..., nq * hd:(nq + nkv) * hd]
         v = qkv[..., (nq + nkv) * hd:]
@@ -332,8 +332,8 @@ def _head(params, config, x):
     """Float32 logits of rows ``x [B, hidden]``: the final norm, then
     the tied embedding."""
     import jax.numpy as jnp
-    from .latent_moe import _rms
-    x = _rms(x, params["norm"], config.rms_norm_eps)
+    from .decoder_parts import rms
+    x = rms(x, params["norm"], config.rms_norm_eps)
     return jnp.einsum("bh,vh->bv", x, params["embed"],
                       preferred_element_type=jnp.float32)
 
@@ -363,9 +363,9 @@ def _repeat_kv(config, rows):
 
 
 def _write_kv(layer, slots, k, v):
-    from .latent_moe import _pool_scatter
-    return {"k": _pool_scatter(layer["k"], slots, k),
-            "v": _pool_scatter(layer["v"], slots, v)}
+    from .decoder_parts import pool_scatter
+    return {"k": pool_scatter(layer["k"], slots, k),
+            "v": pool_scatter(layer["v"], slots, v)}
 
 
 def _scale(config):

@@ -15,6 +15,8 @@ TPU build hangs its fast paths on:
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -26,6 +28,8 @@ __all__ = ["flash_attention_op", "FlashAttentionOp", "attention_reference",
            "ulysses_attention_op", "UlyssesAttentionOp",
            "prefill_attention",
            "paged_decode_attention", "paged_prefill_attention",
+           "grouped_decode_attention", "grouped_ring_decode_attention",
+           "bracketed",
            "mla_expanded_attention", "mla_decode_attention",
            "mla_prefill_attention"]
 
@@ -124,22 +128,28 @@ def paged_prefill_attention(q, k_pool, v_pool, slot_idx, starts,
     return jnp.einsum("bhis,bshd->bihd", probs.astype(v.dtype), v)
 
 
-def prefill_attention(q, k, v, sm_scale, causal=True):
+def prefill_attention(q, k, v, sm_scale, causal=True, window=None):
     """Dense prompt-phase attention for the serving decode path over
     ``[B, H, S, D]`` q/k/v: rides the Pallas flash kernel on TPU
     backends (blocked online softmax, no HBM score matrix), the
     composed reference elsewhere. The kernel's block sizes are the
     kernels' static rule (``pallas_attention._block_sizes``), and since
     the serving forward never consumes the logsumexp residual, it skips
-    that output write."""
+    that output write. With a ``window`` (causal) a row sees the keys
+    ``i - window < j <= i`` alone."""
     if _use_pallas():
         from .pallas_attention import flash_attention
         _, h, s, d = q.shape
+        band = {} if window is None else {"window": window}
         return flash_attention(q, k, v, None, sm_scale=sm_scale,
                                causal=causal,
-                               reason=flash_layout(s, d, h, False)[1])
+                               reason=flash_layout(s, d, h, False)[1],
+                               **band)
     mask = None
-    if causal:
+    if window is not None:
+        from .pallas_attention import _band
+        mask = jnp.where(_band(q.shape[-2], window), 0.0, -1e9)[None, None]
+    elif causal:
         s = q.shape[-2]
         mask = jnp.where(jnp.tril(jnp.ones((s, s), bool)), 0.0,
                          -1e9)[None, None]
@@ -187,6 +197,69 @@ def grouped_prefill_attention(q, k_pool, v_pool, slot_idx, starts,
     pos = starts[:, None] + jnp.arange(q.shape[1])[None, :]
     valid = jnp.arange(slot_idx.shape[1])[None, None, :] <= pos[:, :, None]
     return _grouped(q, k_pool, v_pool, slot_idx, valid, sm_scale)
+
+
+def grouped_ring_decode_attention(q, k_pool, v_pool, ring_idx, positions,
+                                  window, sm_scale):
+    """One query token a sequence, ``q [B, H, D]`` at ``positions
+    [B]``, against a layer whose pool keeps each sequence a RING:
+    ``ring_idx [B, R]`` are the flat slots of the row's ring, and ring
+    slot ``r`` holds the newest position ``p <= positions[b]`` with ``p
+    % R == r`` (``serving/kvcache.py``; the current token's row is
+    written before the call). A slot is read where that ``p`` exists
+    and lies inside the window, ``positions[b] - window < p``: a ring
+    is a block longer than the window, so its oldest rows are outside
+    it. The shape is the ring's whatever the context. Returns ``[B, H,
+    D]``."""
+    r = ring_idx.shape[1]
+    at = positions[:, None]
+    held = at - (at - jnp.arange(r, dtype=at.dtype)[None, :]) % r
+    valid = (held >= 0) & (at - held < window)
+    return _grouped(q[:, None], k_pool, v_pool, ring_idx, valid[:, None],
+                    sm_scale)[:, 0]
+
+
+@functools.lru_cache(maxsize=None)
+def _marker(name):
+    """A jitted function called ``name`` that hands its arrays through
+    ONE Pallas kernel unchanged: an event of that name in a profile."""
+    from jax.experimental import pallas as pl
+
+    def kernel(*refs):
+        for src, dst in zip(refs[:len(refs) // 2], refs[len(refs) // 2:]):
+            dst[...] = src[...]
+
+    def marker(*arrays):
+        from . import pallas_attention     # tests' and rehearsals' switch
+        flat = [a.reshape(a.shape[0], -1) for a in arrays]
+        out = pl.pallas_call(
+            kernel, out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype)
+                               for a in flat],
+            interpret=pallas_attention.INTERPRET)(*flat)
+        return tuple(o.reshape(a.shape) for o, a in zip(out, arrays))
+
+    marker.__name__ = marker.__qualname__ = name
+    return jax.jit(marker)
+
+
+def bracketed(name, attention, q, k_pool, v_pool, *rest, **static):
+    """``attention(q, k_pool, v_pool, *rest, **static)`` between two
+    device events ``<name>_in`` and ``<name>_out`` (a profile; TPU
+    only). XLA inlines a composed function, jitted or not, and names its
+    fusions for their roots, so a composed attention reaches no profile
+    under a name of its own (found on the chip, PR 47): the query goes
+    through a pass-through kernel of the first name on its way in, the
+    context through one of the second on its way out, and since a core
+    runs a program's operations one after another, what lies between
+    the two events is the attention and whatever else XLA schedules
+    there. Each kernel is also a fusion barrier, so a bracketed program
+    is NOT the program an unprofiled engine runs: a caller brackets only
+    where a profile is being taken (``PERF.md`` section 7 has the
+    difference as measured)."""
+    (q,) = _marker(name + "_in")(q)
+    (out,) = _marker(name + "_out")(
+        attention(q, k_pool, v_pool, *rest, **static))
+    return out
 
 
 # ---------------------------------------------------------------------------

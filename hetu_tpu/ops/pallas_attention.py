@@ -165,7 +165,8 @@ def _scales_q(sm_scale, dtype):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
-                block_q, block_k, span, seq_len, causal, head_dim=None):
+                block_q, block_k, span, seq_len, causal, head_dim=None,
+                window=None):
     """One region row's program: the q rows ``[qi*span, (qi+1)*span)``
     of the program's heads (``heads_per_program``) — head-major a block
     of neighbouring heads, token-major the heads of its lane blocks,
@@ -188,6 +189,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
     latency with another's elementwise work (a loop iteration is
     scheduled alone on this chip: PERF.md PRs 36, 40).
 
+    With a ``window`` (causal only) a query at row ``i`` sees the keys
+    ``i - window < j <= i``: the regions wholly behind the band are not
+    run, the one or two its far edge cuts are run first, each at its
+    static distance from the diagonal (``_band_regions``; skipped by a
+    branch in the region rows that start before them), the whole ones
+    between by the loop, the diagonal's last; ``tile_walk`` says for an
+    edge region, as for the diagonal's, which pairs are left out and
+    which carry which compare.
+
     Dots run in the INPUT dtype with f32 accumulation — on bf16 inputs
     the MXU's native mode; all softmax math stays f32."""
     heads = [(g, lanes) for g in range(q_ref.shape[0])
@@ -207,11 +217,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
         - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) \
         if causal else None
 
-    def region(kr, state, diagonal):
+    def region(kr, state, diagonal, offset=0):
         """The region at k-region ``kr`` (a static 0, or traced) for
         every chain of the program; ``state`` None where nothing ran
-        before it. Returns the new state, ``[head][q-tile]``."""
-        visited, masked = tile_walk(span, block_q, block_k, diagonal)
+        before it. ``offset``: the rows its q rows lie below its keys'
+        diagonal (a region the window's far edge cuts). Returns the new
+        state, ``[head][q-tile]``."""
+        visited, masked = tile_walk(span, block_q, block_k, diagonal,
+                                    window if diagonal else None, offset)
         keys = [slice(j * block_k, (j + 1) * block_k) if whole else
                 pl.ds(pl.multiple_of(kr * span + j * block_k, block_k),
                       block_k) for j in range(num_k)]
@@ -225,6 +238,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
             chains = []
             for i in range(num_q):
                 mine = [j for ii, j in visited if ii == i]
+                if not mine:    # an edge region leaves this q-tile out
+                    chains.append(state[n][i])
+                    continue
                 scores = []
                 for j in mine:
                     s = jax.lax.dot_general(
@@ -235,10 +251,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
                     if bias is not None:
                         s = s + bias[j]
                     if (i, j) in masked:
-                        at = j * block_k - i * block_q
-                        if at not in keep:
-                            keep[at] = below >= at
-                        s = jnp.where(keep[at], s, NEG_INF)
+                        at = j * block_k - i * block_q - offset
+                        if window is None:
+                            cuts = ("diagonal",)
+                        else:
+                            cuts = _band_cuts(i, j, block_q, block_k,
+                                              offset, window)
+                        if (at, cuts) not in keep:
+                            kept = None
+                            if "diagonal" in cuts:
+                                kept = below >= at
+                            if "edge" in cuts:
+                                edge = below < at + window
+                                kept = edge if kept is None \
+                                    else kept & edge
+                            keep[at, cuts] = kept
+                        s = jnp.where(keep[at, cuts], s, NEG_INF)
                     scores.append(s)
                 m = jnp.max(functools.reduce(jnp.maximum, scores),
                             axis=1, keepdims=True)
@@ -268,8 +296,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, l_ref, *, sm_scale,
                    jnp.zeros((block_q, 1), jnp.float32),
                    jnp.zeros((block_q, d), jnp.float32))] * num_q
                  for _ in heads]
+        first = 0
+        if window is not None:
+            whole_behind, edges = _band_regions(seq_len, span, window)
+            for e in edges:
+                start = jax.lax.cond(
+                    qi >= e,
+                    lambda state, e=e: region(qi - e, state, True,
+                                              e * span),
+                    lambda state: state, start)
+            first = jnp.maximum(qi - whole_behind, 0)
         done = jax.lax.fori_loop(
-            0, qi if causal else seq_len // span,
+            first, qi if causal else seq_len // span,
             lambda kr, state: region(kr, state, diagonal=False), start)
         if causal:
             done = region(qi, done, diagonal=True)
@@ -382,7 +420,7 @@ def _form(layout):
 
 
 def _forward(kind, q, k, v, mask, sm_scale, causal, interpret, layout,
-             reason):
+             reason, window=None):
     """What the two forward entries share: the plan, the call, and the
     ``flash_fwd_walk`` instant, once a traced call — how far the walk
     engages at the tiles chosen (``fwd_walk_counts``). None where the
@@ -396,13 +434,22 @@ def _forward(kind, q, k, v, mask, sm_scale, causal, interpret, layout,
     telemetry.get_telemetry().instant(
         "flash_fwd_walk", seq=s, head_dim=d, block_q=blocks[0],
         block_k=blocks[1], causal=bool(causal),
-        **fwd_walk_counts(h, s, *blocks, causal, layout))
-    return _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
-                                *blocks, kind == "fwd_lse", *_form(layout))
+        **fwd_walk_counts(h, s, *blocks, causal, layout, window),
+        **({} if window is None else {"window": int(window)}))
+    if window is None:
+        return _flash_attention_jit(q, k, v, mask, sm_scale, causal,
+                                    interpret, *blocks, kind == "fwd_lse",
+                                    *_form(layout))
+    if not causal or mask is not None:
+        raise ValueError("a window is a band under the diagonal: "
+                         "causal=True and no padding mask")
+    return _flash_attention_window_jit(
+        q, k, v, sm_scale, interpret, *blocks, kind == "fwd_lse", layout,
+        int(window))
 
 
 def flash_attention(q, k, v, mask=None, sm_scale=1.0, causal=False,
-                    interpret=None, layout=None, reason=None):
+                    interpret=None, layout=None, reason=None, window=None):
     """softmax(q k^T * sm_scale + mask) v over [B, H, S, D], or with a
     :class:`TokenMajor` ``layout`` over ``[B, S, lanes]`` rows (the
     context then ``[B, S, H]``; the caller has checked
@@ -412,19 +459,32 @@ def flash_attention(q, k, v, mask=None, sm_scale=1.0, causal=False,
     (the BERT layout); causal masking is a kernel flag, not a mask
     argument. Tiny or oddly-shaped inputs fall back to the composed-XLA
     reference rather than violating TPU tiling constraints.
+
+    ``window`` (with ``causal``, no mask): a query at row ``i`` sees the
+    keys ``i - window < j <= i`` alone; the walk leaves out what lies
+    wholly behind the band (``tile_walk``), and the call's device events
+    are ``hetu_flash_window``'s.
     """
     out = _forward("fwd", q, k, v, mask, sm_scale, causal, interpret,
-                   layout, reason)
+                   layout, reason, window)
     if out is None:
         from .attention import attention_reference
         s = q.shape[-2]
         m = mask
         if causal:
-            cmask = jnp.where(jnp.tril(jnp.ones((s, s), bool)), 0.0,
-                              NEG_INF)[None, None]
+            cmask = jnp.where(_band(s, window), 0.0, NEG_INF)[None, None]
             m = cmask if m is None else m + cmask
         return attention_reference(q, k, v, m, sm_scale)
     return out
+
+
+def _band(s, window=None):
+    """``[s, s]`` bool: the keys a causal row sees, all ``j <= i`` or
+    with a ``window`` those ``i - window < j <= i``."""
+    seen = jnp.tril(jnp.ones((s, s), bool))
+    if window is None:
+        return seen
+    return seen & ~jnp.tril(jnp.ones((s, s), bool), -int(window))
 
 
 def flash_attention_with_lse(q, k, v, mask=None, sm_scale=1.0,
@@ -462,6 +522,26 @@ def _dims(q, layout):
                                              "layout"))
 def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
                          block_q, block_k, need_lse, layout=None):
+    return _flash_forward(q, k, v, mask, sm_scale, causal, interpret,
+                          block_q, block_k, need_lse, layout)
+
+
+# a windowed call's events carry this function's name in a profile, as
+# the others carry ``_flash_attention_jit``
+def hetu_flash_window(q, k, v, sm_scale, interpret, block_q, block_k,
+                      need_lse, layout, window):
+    return _flash_forward(q, k, v, None, sm_scale, True, interpret,
+                          block_q, block_k, need_lse, layout, window)
+
+
+_flash_attention_window_jit = jax.jit(
+    hetu_flash_window, static_argnames=("sm_scale", "interpret", "block_q",
+                                        "block_k", "need_lse", "layout",
+                                        "window"))
+
+
+def _flash_forward(q, k, v, mask, sm_scale, causal, interpret,
+                   block_q, block_k, need_lse, layout=None, window=None):
     """``layout`` None: q, k, v ``[B, H, S, D]``, a grid step a region
     row of a block of neighbouring heads. A :class:`TokenMajor`:
     ``[B, S, lanes]`` rows, a grid step a region row of the lane blocks
@@ -510,7 +590,9 @@ def _flash_attention_jit(q, k, v, mask, sm_scale, causal, interpret,
     body = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                              block_q=block_q, block_k=block_k, span=span,
                              seq_len=s, causal=causal,
-                             head_dim=None if layout is None else d)
+                             head_dim=None if layout is None else d,
+                             **({} if window is None
+                                else {"window": window}))
     if mask is not None:  # jit-ok: structural None-check, not a traced read
         in_specs.append(mask_spec)
         args.append(_mask_rows(mask, b, h, s))
@@ -572,16 +654,56 @@ def _first_unmasked_q_tile(kj, block_q, block_k):
     return ((kj + 1) * block_k + block_q - 2) // block_q
 
 
-def tile_walk(s, block_q, block_k, causal):
+def _band_cuts(i, j, block_q, block_k, offset, window):
+    """Which of the band's two edges cut the pair (q-tile ``i``, k-tile
+    ``j``) of a square whose q rows lie ``offset`` rows below its keys'
+    diagonal: ``"diagonal"`` (some key of the pair is ahead of some
+    row), ``"edge"`` (some key is ``window`` or more behind some row).
+    None where the pair lies wholly outside the band."""
+    nearest = i * block_q + offset - (j + 1) * block_k + 1
+    farthest = (i + 1) * block_q - 1 + offset - j * block_k
+    if farthest < 0 or nearest >= window:
+        return None
+    return (("diagonal",) if nearest < 0 else ()) \
+        + (("edge",) if farthest >= window else ())
+
+
+def _band_regions(s, span, window):
+    """How a region row of the forward meets the band behind its
+    diagonal region, in regions of ``span``: ``(the regions next to the
+    diagonal's that lie wholly inside the band, the distances of those
+    its far edge cuts)``. A region ``e`` behind holds the differences
+    ``(e - 1) span < i - j < (e + 1) span``."""
+    whole = max(window // span - 1, 0)
+    edges = [e for e in range(whole + 1, s // span)
+             if (e - 1) * span + 1 < window]
+    return whole, edges
+
+
+def tile_walk(s, block_q, block_k, causal, window=None, offset=0):
     """The (q-tile, k-tile) pairs a flash kernel runs over ``s`` rows a
     side, and of them those that carry the causal iota / compare /
     select — from the two bounds above, which the backward kernel leaves
     pairs out by and masks by, and which the forward reads through this
     function for the region on the diagonal (the regions beside it hold
     only pairs these bounds keep and do not mask). The full square,
-    nothing masked, without ``causal``."""
+    nothing masked, without ``causal``. With a ``window`` (the forward
+    alone) a row sees the keys ``0 <= i - j < window``: the pairs
+    wholly behind the band are left out as those above the diagonal
+    are, and the pairs either edge cuts are masked; ``offset`` is how
+    far the square's rows lie below its keys' diagonal (a region the
+    band's far edge cuts)."""
     num_qb, num_kb = s // block_q, s // block_k
     visited, masked = [], []
+    if window is not None:      # under the diagonal: ``_forward`` checks
+        for kj in range(num_kb):
+            for i in range(num_qb):
+                cuts = _band_cuts(i, kj, block_q, block_k, offset, window)
+                if cuts is not None:
+                    visited.append((i, kj))
+                    if cuts:
+                        masked.append((i, kj))
+        return visited, masked
     for kj in range(num_kb):
         first, unmasked = 0, 0
         if causal:
@@ -593,11 +715,11 @@ def tile_walk(s, block_q, block_k, causal):
     return visited, masked
 
 
-def tile_walk_counts(s, block_q, block_k, causal):
+def tile_walk_counts(s, block_q, block_k, causal, window=None):
     """How far the tile walk engages at these tiles: tiles visited, tiles
     of the square, masked tiles, and the two shares a trace reader wants
     (visited / square, masked / visited)."""
-    visited, masked = tile_walk(s, block_q, block_k, causal)
+    visited, masked = tile_walk(s, block_q, block_k, causal, window)
     square = (s // block_q) * (s // block_k)
     return {"tiles_visited": len(visited), "tiles_square": square,
             "tiles_masked": len(masked),
@@ -654,13 +776,14 @@ def heads_per_program(h, s, block_q, block_k, layout=None):
                           and g * unit * s <= _REGION_ROWS)))
 
 
-def fwd_walk_counts(h, s, block_q, block_k, causal, layout=None):
+def fwd_walk_counts(h, s, block_q, block_k, causal, layout=None,
+                    window=None):
     """What a forward call at these tiles runs: the walk's counts over
-    the square, the heads a program takes and its independent chains
-    (a q-tile of a head each)."""
+    the square (the band's, with a ``window``), the heads a program
+    takes and its independent chains (a q-tile of a head each)."""
     heads = heads_per_program(h, s, block_q, block_k, layout)
     span = _region_span(s, block_q, block_k)
-    return {**tile_walk_counts(s, block_q, block_k, causal),
+    return {**tile_walk_counts(s, block_q, block_k, causal, window),
             "heads_per_program": heads,
             "chains": heads * (span // block_q)}
 

@@ -41,6 +41,14 @@ vLLM/PagedAttention shape instead:
   a token: one slot a running sequence, taken and given back with its
   blocks (:class:`PagedKVCache` says how a model asks for them).
 
+* **window rings**, for a layer whose attention reads only the last
+  ``window`` positions: such a layer keeps a pool of its own, and a
+  sequence a second, SHORT table into it, at most ``ceil(window /
+  block_size) + 1`` blocks used as a ring (position ``j`` lies in ring
+  block ``(j // block_size) % ring``), so what a sequence holds there
+  is bounded by ``window + one block`` whatever its length
+  (:class:`PagedKVCache` says how a model asks for them).
+
 **Physical block 0 is the scratch block.** Padded batch lanes (the
 bucketing that keeps jit signatures bounded) write their garbage K/V
 rows to slot ``0..block_size-1`` and gather from them behind a length
@@ -66,7 +74,8 @@ from ..models.gpt import gpt_param_bytes  # noqa: F401  its older home
 
 __all__ = ["KVCacheExhausted", "BlockAllocator", "PagedKVCache",
            "PrefixCache", "kv_block_bytes", "state_slot_bytes",
-           "gpt_param_bytes", "blocks_for_budget", "DEFAULT_BLOCK_SIZE"]
+           "gpt_param_bytes", "blocks_for_budget", "ring_blocks",
+           "DEFAULT_BLOCK_SIZE"]
 
 DEFAULT_BLOCK_SIZE = 16
 
@@ -85,9 +94,11 @@ class KVCacheExhausted(RuntimeError):
 
 def _pool_kinds(model):
     """What each entry of the cache's ``pools`` is, for a serving
-    model: ``"rows"`` (a layer's paged pools, a row a token) or
-    ``"state"`` (fixed-size slots, one a sequence). A model without
-    ``pool_kinds`` has rows in every layer."""
+    model: ``"rows"`` (a layer's paged pools, a row a token),
+    ``"window"`` (a layer's paged pools that keep a sequence's last
+    ``model.window_size`` rows, a ring) or ``"state"`` (fixed-size
+    slots, one a sequence). A model without ``pool_kinds`` has rows in
+    every layer."""
     return getattr(model, "pool_kinds", None) \
         or ("rows",) * model.num_cache_layers
 
@@ -97,17 +108,28 @@ def _itemsize(dtype):
     return jnp.dtype(dtype).itemsize
 
 
-def kv_block_bytes(config, block_size):
+def kv_block_bytes(config, block_size, kind="rows"):
     """HBM bytes one cache block costs across the layers that HAVE
     rows, by the row layout of the configuration's serving model (a K
     and a V row of ``hidden`` float32 for GPT; one ``latent + rope`` row
     in the model's dtype for a latent-attention model; a ``k`` and a
     ``v`` row of the key/value heads alone in a hybrid's attention
-    layers, and nothing in its state-space layers)."""
+    layers, and nothing in its state-space layers). ``kind="window"``:
+    a block of the window layers' own pools."""
     model = config.serving_model()
     row = sum(width * _itemsize(dtype)
               for _, width, dtype in model.cache_layout())
-    return _pool_kinds(model).count("rows") * int(block_size) * row
+    return _pool_kinds(model).count(kind) * int(block_size) * row
+
+
+def ring_blocks(config, block_size):
+    """Blocks a sequence's table into the window layers' pools holds
+    at most, ``ceil(window / block_size) + 1`` (0 for a model without a
+    window layer): the window's rows and the block being written."""
+    model = config.serving_model()
+    if "window" not in _pool_kinds(model):
+        return 0
+    return -(-int(model.window_size) // int(block_size)) + 1
 
 
 def state_slot_bytes(config):
@@ -122,12 +144,15 @@ def state_slot_bytes(config):
 
 
 def blocks_for_budget(config, block_size=DEFAULT_BLOCK_SIZE, budget=None,
-                      headroom=_BUDGET_HEADROOM, state_slots=0):
+                      headroom=_BUDGET_HEADROOM, state_slots=0,
+                      window_blocks=0):
     """KV blocks the resolved HBM budget affords after the model's
     parameters, ``state_slots`` sequences' recurrent state (and the
-    scratch slot's) and a headroom fraction. Returns ``None`` when no
-    budget resolves (CPU harness without ``HETU_HBM_BUDGET``); raises
-    when a budget resolves but can't fit even two blocks."""
+    scratch slot's), the window layers' pools of ``window_blocks``
+    blocks (and their scratch block) and a headroom fraction. Returns
+    ``None`` when no budget resolves (CPU harness without
+    ``HETU_HBM_BUDGET``); raises when a budget resolves but can't fit
+    even two blocks."""
     from ..analysis.memory import fmt_bytes, resolve_budget
     budget = resolve_budget(budget)
     if budget is None:
@@ -135,6 +160,9 @@ def blocks_for_budget(config, block_size=DEFAULT_BLOCK_SIZE, budget=None,
     param_bytes = config.serving_model().param_bytes()
     avail = int(budget * (1.0 - headroom)) - param_bytes \
         - state_slot_bytes(config) * (int(state_slots) + 1)
+    window = kv_block_bytes(config, block_size, "window")
+    if window:
+        avail -= window * (int(window_blocks) + 1)
     nb = avail // kv_block_bytes(config, block_size)
     if nb < 2:
         raise ValueError(
@@ -435,6 +463,22 @@ class PagedKVCache:
     without reading it. ``state_slots`` is how many sequences may hold
     one at a time (the engine's ``max_batch_size``).
 
+    A model may also have layers whose attention reads a WINDOW of
+    the last ``model.window_size`` positions (``pool_kinds`` entries
+    ``"window"``; same row layout). Those entries are pools of
+    ``window_blocks`` blocks (+ scratch block 0) with an allocator of
+    their own, and a sequence holds a second table into them
+    (:attr:`window_tables`) of at most :attr:`ring` blocks, a RING
+    indexed by position: ``j`` lies in ring block ``(j // block_size) %
+    ring``, so a row is overwritten ``ring x block_size >= window +
+    block_size`` positions later, when it has left the window.
+    ``window_blocks`` defaults to ``state_slots x ring`` (every running
+    sequence a whole ring). Both tables are taken and given back
+    together, all or nothing; ``num_blocks``, ``used_blocks`` and
+    ``utilization`` keep meaning the full layers' pool.
+    ``prefix_cache=True`` is refused: a cached block of a window layer
+    is gone once it has left the window.
+
     With ``prefix_cache=True`` the cache grows the prefix-sharing
     plane: :meth:`add_seq_prefix` resolves a prompt's cached prefix to
     shared blocks (refcount bumped per sharer), :meth:`insert_prefix`
@@ -447,11 +491,29 @@ class PagedKVCache:
 
     def __init__(self, config, num_blocks=None,
                  block_size=DEFAULT_BLOCK_SIZE, budget=None,
-                 telemetry=None, prefix_cache=False, state_slots=0):
+                 telemetry=None, prefix_cache=False, state_slots=0,
+                 window_blocks=None):
         from .. import telemetry as _telemetry
         self.config = config
         self.block_size = int(block_size)
         self._kinds = _pool_kinds(config.serving_model())
+        # blocks of a sequence's ring in the window layers (0: none)
+        self.ring = ring_blocks(config, self.block_size)
+        if self.ring:
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True with a model that has window "
+                    "layers: a cached block of a window layer is gone "
+                    "once it has left the window, and a hit would need "
+                    "the window's rows rebuilt (not implemented; "
+                    "ROADMAP.md Queue 2 A3)")
+            if window_blocks is None:
+                window_blocks = int(state_slots) * self.ring
+            if int(window_blocks) < 1:
+                raise ValueError(
+                    "a model with window layers needs window_blocks >= 1 "
+                    "(or state_slots, the sequences that hold a ring)")
+        self.window_blocks = int(window_blocks) if self.ring else 0
         self.state_slots = int(state_slots) if "state" in self._kinds \
             else 0
         if "state" in self._kinds:
@@ -465,8 +527,9 @@ class PagedKVCache:
                 raise ValueError(
                     "a model with recurrent state needs state_slots >= 1")
         if num_blocks is None:
-            num_blocks = blocks_for_budget(config, self.block_size,
-                                           budget, state_slots=state_slots)
+            num_blocks = blocks_for_budget(
+                config, self.block_size, budget, state_slots=state_slots,
+                window_blocks=self.window_blocks)
             if num_blocks is None:
                 raise ValueError(
                     "no HBM budget resolvable to size the KV pool "
@@ -482,6 +545,11 @@ class PagedKVCache:
             else None
         self.pools = self._init_pools()
         self.tables = {}            # seq_id -> [block ids]
+        # the window layers' pools: an allocator and a ring a sequence
+        self.window_allocator = BlockAllocator(
+            self.window_blocks, self.block_size, first_id=1)
+        self.window_tables = {}     # seq_id -> [block ids], <= ring
+        self._ring_rows = {}        # seq_id -> (blocks, its ring's slots)
         self.slots = {}             # seq_id -> state slot (1..state_slots)
         self._free_slots = list(range(self.state_slots, 0, -1))
         self.peak_utilization = 0.0
@@ -497,8 +565,10 @@ class PagedKVCache:
                 return {name: jnp.zeros((self.state_slots + 1, *shape),
                                         jnp.dtype(dtype))
                         for name, shape, dtype in model.state_layout()}
-            return {name: jnp.zeros((self.num_blocks + 1, self.block_size,
-                                     width), jnp.dtype(dtype))
+            blocks = self.window_blocks if kind == "window" \
+                else self.num_blocks
+            return {name: jnp.zeros((blocks + 1, self.block_size, width),
+                                    jnp.dtype(dtype))
                     for name, width, dtype in model.cache_layout()}
 
         return [layer(kind) for kind in self._kinds]
@@ -535,10 +605,23 @@ class PagedKVCache:
     def state_slots_used(self):
         return len(self.slots)
 
+    @property
+    def window_blocks_used(self):
+        return self.window_allocator.used
+
+    def window_bytes(self):
+        """Bytes the window layers' pools occupy (scratch block
+        included; 0 for a model without a window layer)."""
+        if not self.ring:
+            return 0
+        return kv_block_bytes(self.config, self.block_size, "window") \
+            * (self.window_blocks + 1)
+
     def kv_bytes(self):
-        """Bytes the paged pools occupy (scratch block included)."""
+        """Bytes the paged pools occupy (scratch blocks included), the
+        window layers' pools at their own size."""
         return kv_block_bytes(self.config, self.block_size) \
-            * (self.num_blocks + 1)
+            * (self.num_blocks + 1) + self.window_bytes()
 
     def state_bytes(self):
         """Bytes the state slots occupy (scratch slot included)."""
@@ -551,10 +634,15 @@ class PagedKVCache:
     def can_admit(self, ntokens):
         return self.allocator.blocks_for_tokens(ntokens) \
             <= self.allocator.available + self.cached_blocks \
-            and self._slot_free()
+            and self._slot_free() \
+            and self._ring_need(ntokens) <= self.window_allocator.available
 
     def _slot_free(self):
         return not self.state_slots or bool(self._free_slots)
+
+    def _ring_need(self, ntokens):
+        """Window blocks a sequence of ``ntokens`` positions holds."""
+        return min(self.ring, self.allocator.blocks_for_tokens(ntokens))
 
     def slot_of_seq(self, seq_id):
         """The sequence's state slot (0, the scratch slot, for a model
@@ -565,7 +653,8 @@ class PagedKVCache:
         """Whether a sequence of ``ntokens`` could EVER be served by
         this pool (the submit-time guard)."""
         return self.allocator.blocks_for_tokens(ntokens) \
-            <= self.allocator.num_blocks
+            <= self.allocator.num_blocks \
+            and self._ring_need(ntokens) <= self.window_allocator.num_blocks
 
     def _note_util(self):
         u = self.utilization
@@ -581,6 +670,9 @@ class PagedKVCache:
             if self.state_slots:
                 self.telemetry.set_gauge("state_slots_used",
                                          len(self.slots))
+            if self.ring:
+                self.telemetry.set_gauge("window_blocks_used",
+                                         self.window_allocator.used)
             if self.prefix is not None:
                 self.telemetry.set_gauge("kv_blocks_cached",
                                          self.cached_blocks)
@@ -626,8 +718,17 @@ class PagedKVCache:
         if not self._slot_free():
             raise KVCacheExhausted(
                 f"state slots exhausted: {self.state_slots} in use")
-        blocks = self._alloc(self.allocator.blocks_for_tokens(ntokens))
+        # the ring first: it takes nothing when it cannot take all, and
+        # goes back if the full layers' blocks cannot be had
+        ring = self.window_allocator.alloc(self._ring_need(ntokens))
+        try:
+            blocks = self._alloc(self.allocator.blocks_for_tokens(ntokens))
+        except KVCacheExhausted:
+            self.window_allocator.free(ring)
+            raise
         self.tables[seq_id] = blocks
+        if self.ring:
+            self.window_tables[seq_id] = ring
         if self.state_slots:
             self.slots[seq_id] = self._free_slots.pop()
         self._note_util()
@@ -768,7 +869,15 @@ class PagedKVCache:
         table = self.tables[seq_id]
         need = self.allocator.blocks_for_tokens(ntokens) - len(table)
         if need > 0:
-            table.extend(self._alloc(need))
+            ring = self.window_tables.get(seq_id, [])
+            more = self.window_allocator.alloc(
+                self._ring_need(ntokens) - len(ring))
+            try:
+                table.extend(self._alloc(need))
+            except KVCacheExhausted:
+                self.window_allocator.free(more)
+                raise
+            ring.extend(more)
             self._note_util()
         return table
 
@@ -780,6 +889,10 @@ class PagedKVCache:
         if blocks:
             for b in blocks:
                 self._release_block(b)
+        ring = self.window_tables.pop(seq_id, None)
+        if ring:
+            self.window_allocator.free(ring)
+            self._ring_rows.pop(seq_id, None)
         slot = self.slots.pop(seq_id, None)
         if slot is not None:
             # lowest slot first, as blocks are handed out
@@ -809,6 +922,15 @@ class PagedKVCache:
             for b in self.prefix._lru:
                 assert alloc.refcount(b) == 1, \
                     f"LRU block {b} is still referenced"
+        if self.ring:
+            ring = self.window_allocator
+            held = [b for t in self.window_tables.values() for b in t]
+            assert set(self.window_tables) == set(self.tables)
+            assert all(len(t) <= self.ring
+                       for t in self.window_tables.values())
+            assert collections.Counter(held) == ring._ref, \
+                f"window blocks {held} in tables, {ring._ref} allocated"
+            assert len(ring._free) + len(ring._ref) == ring.num_blocks
         if self.state_slots:
             held = sorted(self.slots.values())
             assert set(self.slots) == set(self.tables)
@@ -844,4 +966,44 @@ class PagedKVCache:
             w = min(width, cap)
             out[i, :w] = (table[off[:w] // bs] * bs
                           + off[:w] % bs).astype(np.int32)
+        return out
+
+    # -- the window layers' ring (a model with "window" entries) ---------
+    def _ring_slots(self, seq_id, pos):
+        """Flat slots, in the window layers' pools, of positions ``pos``
+        (int64 array): ring block ``(j // block_size) % ring``."""
+        table = np.asarray(self.window_tables[seq_id], np.int64)
+        bs = self.block_size
+        return (table[(pos // bs) % self.ring] * bs + pos % bs).astype(
+            np.int32)
+
+    def window_slot_of(self, seq_id, pos):
+        """Flat ring slot of one position."""
+        bs = self.block_size
+        return self.window_tables[seq_id][(pos // bs) % self.ring] * bs \
+            + pos % bs
+
+    def window_slot_mapping(self, seq_id, start, stop):
+        """Flat ring slots for positions ``[start, stop)`` as int32; at
+        most ``ring x block_size`` of them, or later ones would land on
+        earlier ones."""
+        return self._ring_slots(seq_id, np.arange(start, stop))
+
+    def ring_slots(self, seq_ids):
+        """``[len(seq_ids), ring x block_size]`` int32: each sequence's
+        ring as it lies, ring slot ``r`` in column ``r``; blocks a short
+        sequence never took point at the scratch block (no position is
+        ever held there)."""
+        bs = self.block_size
+        out = np.zeros((len(seq_ids), self.ring * bs), np.int32)
+        for i, sid in enumerate(seq_ids):
+            table = self.window_tables[sid]
+            # a decode step asks every step, and a ring changes only
+            # while it grows: the row is kept with the table's length
+            kept = self._ring_rows.get(sid)
+            if kept is None or kept[0] != len(table):
+                row = (np.repeat(np.asarray(table, np.int32) * bs, bs)
+                       + np.tile(np.arange(bs, dtype=np.int32), len(table)))
+                kept = self._ring_rows[sid] = (len(table), row)
+            out[i, :len(kept[1])] = kept[1]
         return out

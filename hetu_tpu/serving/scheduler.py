@@ -97,6 +97,19 @@ tokens, and returns each row's last real position's logits alone). A
 preempted sequence's replay rebuilds its state from its tokens.
 ``prefix_cache=True`` is refused for such a model.
 
+**Models with window layers** (``model.pool_kinds`` holds ``"window"``
+entries: attention over the last ``model.window_size`` positions): a
+running sequence holds a second, short table into those layers' own
+pools, a ring (``serving/kvcache.py``), beside its table into the full
+layers' pool. Every program takes the window layers' slots behind its
+other arguments: a prefill each position's ring slot (``[B, P]``; only a
+prompt's last ``window`` rows are written, the earlier ones and padded
+lanes name the scratch block), a decode step each row's whole ring
+(``[B, ring x block_size]``, a fixed shape whatever the context bucket)
+and its write slot there. A preempted sequence's replay rebuilds both
+tables from its tokens. ``prefix_cache=True`` and ``prefill_chunk`` are
+refused for such a model.
+
 **Program spans.** The scheduler thread's time is tiled by leaf spans
 (``Telemetry.span``: the ring when telemetry is on, a ``hetu.<name>``
 annotation in a ``jax.profiler`` trace always): ``serve.wait`` (nothing
@@ -369,6 +382,15 @@ class ContinuousBatchingEngine:
         # layers with recurrent state: a slot a running sequence, and
         # every program takes each row's slot behind its other arguments
         self._stateful = "state" in getattr(model, "pool_kinds", ())
+        # layers that keep a window of rows: a ring a running sequence,
+        # and every program takes the ring's slots behind the others
+        self._windowed = "window" in getattr(model, "pool_kinds", ())
+        if self._windowed and self.prefill_chunk is not None:
+            raise ValueError(
+                "prefill_chunk with a model that has window layers: a "
+                "chunk would have to read the previous chunk's tail out "
+                "of the ring, and no suffix-prefill program does (not "
+                "implemented; ROADMAP.md Queue 2 A3)")
         self.cache = PagedKVCache(config, num_blocks=num_blocks,
                                   block_size=block_size, budget=budget,
                                   telemetry=self.telemetry,
@@ -407,7 +429,9 @@ class ContinuousBatchingEngine:
         # with telemetry on, the last programs that returned counters:
         # {"kind", "t0_ns", "t1_ns" (perf_counter_ns, dispatch to the
         # end of the host sync), "<kind>_<counter>": its own counts;
-        # for a model with state also "state_slots", "state_slots_used"}
+        # for a model with state also "state_slots", "state_slots_used",
+        # for one with window layers "window_blocks", "window_blocks_used"
+        # and "window_hbm_bytes"}
         self.program_log = collections.deque(
             maxlen=16384 if width else 0)
         self._signatures = set()
@@ -544,6 +568,9 @@ class ContinuousBatchingEngine:
                "state_slots": self.cache.state_slots,
                "state_slots_used": self.cache.state_slots_used,
                "state_hbm_bytes": self.cache.state_bytes(),
+               "window_blocks": self.cache.window_blocks,
+               "window_blocks_used": self.cache.window_blocks_used,
+               "window_hbm_bytes": self.cache.window_bytes(),
                "jit_compiles": self.jit_compiles,
                "compile_bound": self.compile_bound,
                "program_leaves": self.program_leaves,
@@ -819,6 +846,38 @@ class ContinuousBatchingEngine:
         slots[:len(seqs)] = [self.cache.slot_of_seq(s.id) for s in seqs]
         return (slots,)
 
+    def _window_prefill_slots(self, group, bb, pb):
+        """What a windowed model's prefill takes behind its other
+        arguments: ``(slots [bb, pb] int32,)``, each position's slot in
+        the window layers' ring — a prompt's last ``window`` rows; the
+        rows before them, and padded lanes, name the scratch block.
+        ``()`` for a model without a window layer."""
+        if not self._windowed:
+            return ()
+        import jax.numpy as jnp
+        slots = np.zeros((bb, pb), np.int32)
+        for i, s in enumerate(group):
+            p = s.prompt.shape[0]
+            first = max(0, p - self.model.window_size)
+            slots[i, first:p] = self.cache.window_slot_mapping(
+                s.id, first, p)
+        return (jnp.asarray(slots),)
+
+    def _window_decode_slots(self, active, bb):
+        """What a windowed model's decode step takes behind its other
+        arguments: ``(ring [bb, ring x block_size], write [bb])``
+        int32, each row's ring as it lies and the ring slot its token's
+        row goes to (padded lanes: the scratch block)."""
+        if not self._windowed:
+            return ()
+        ring = np.zeros((bb, self.cache.ring * self.cache.block_size),
+                        np.int32)
+        write = np.zeros(bb, np.int32)
+        ring[:len(active)] = self.cache.ring_slots([s.id for s in active])
+        write[:len(active)] = [self.cache.window_slot_of(s.id, s.n_written)
+                               for s in active]
+        return ring, write
+
     def _named_counters(self, kind, vec):
         """``{"<kind>_<counter>": value}`` of one counter vector."""
         names = self.model.counter_names
@@ -854,6 +913,11 @@ class ContinuousBatchingEngine:
             if self._stateful:
                 row.update(state_slots=self.cache.state_slots,
                            state_slots_used=self.cache.state_slots_used)
+            if self._windowed:
+                row.update(
+                    window_blocks=self.cache.window_blocks,
+                    window_blocks_used=self.cache.window_blocks_used,
+                    window_hbm_bytes=self.cache.window_bytes())
             self.program_log.append(row)
         return records
 
@@ -899,7 +963,8 @@ class ContinuousBatchingEngine:
                         self.params, self.cache.pools,
                         jnp.asarray(zeros(bb, pb)),
                         jnp.asarray(zeros(bb, pb)), zeros(bb),
-                        *self._state_slots((), bb))
+                        *self._state_slots((), bb),
+                        *self._window_prefill_slots((), bb, pb))
                     np.asarray(logits)
                 else:
                     logits, self.cache.pools = self._dispatch(
@@ -916,7 +981,8 @@ class ContinuousBatchingEngine:
                 out, self.cache.pools = self._dispatch(
                     ("decode", bb, cb), self._step_fn, self.params,
                     self.cache.pools, zeros(bb), zeros(bb), zeros(bb, cb),
-                    zeros(bb), *self._state_slots((), bb))
+                    zeros(bb), *self._state_slots((), bb),
+                    *self._window_decode_slots((), bb))
                 if self.model.counter_names and cb == ctx_buckets[0]:
                     # what hands a step's ids to the step dispatched
                     # ahead of their host read
@@ -1002,6 +1068,7 @@ class ContinuousBatchingEngine:
                     ids[i, p:] = s.prompt[-1]   # edge pad stays in-vocab
                     slots[i, :p] = self.cache.slot_mapping(s.id, 0, p)
                 ids, slots = jnp.asarray(ids), jnp.asarray(slots)
+                window_slots = self._window_prefill_slots(group, bb, pb)
                 if last_row:
                     last_pos = np.zeros(bb, np.int32)
                     last_pos[:len(group)] = [s.prompt.shape[0] - 1
@@ -1019,7 +1086,8 @@ class ContinuousBatchingEngine:
                     (logits, counted), pools = self._dispatch(
                         ("prefill", bb, pb), self._prefill_fn,
                         self.params, self.cache.pools, ids, slots,
-                        last_pos, *self._state_slots(group, bb))
+                        last_pos, *self._state_slots(group, bb),
+                        *window_slots)
                 else:
                     logits, pools = self._dispatch(
                         ("prefill", bb, pb), self._prefill_fn,
@@ -1303,6 +1371,7 @@ class ContinuousBatchingEngine:
             stays = device_pick and all(
                 len(s.generated) + s.unread + 1 < s.max_new
                 for s in active)
+            window_slots = self._window_decode_slots(active, bb)
             flight, self._flight = self._flight, None
             ahead = flight is not None and flight.rows == active \
                 and cows == self.cache.cow_copies
@@ -1329,7 +1398,7 @@ class ContinuousBatchingEngine:
             out, pools = self._dispatch(
                 key, fn, self.params, self.cache.pools, tokens,
                 positions, slot_grid, write_slots,
-                *self._state_slots(active, bb))
+                *self._state_slots(active, bb), *window_slots)
             self.cache.pools = pools
             step = _DecodeProgram(active, bb, attrs, device_pick, out, t0)
             self.decode_steps += 1

@@ -200,7 +200,10 @@ SPAN_SCHEMA = {
                        "visited_share": _req(_NUM),
                        "masked_share": _req(_NUM),
                        "heads_per_program": _req(_INT),
-                       "chains": _req(_INT)},
+                       "chains": _req(_INT),
+                       # a windowed call (the band i - window < j <= i;
+                       # the counts are the band's)
+                       "window": _opt(_INT)},
     # the operand form a flash call runs in, at trace time (ops/
     # pallas_attention.py:_plan): token_major reads q, k, v out of the
     # projection's rows, heads_per_block heads a program; head_major
@@ -223,6 +226,13 @@ SPAN_SCHEMA = {
     # platform off a TPU)
     "ssm_plan": {"op": _req(_STR), "form": _req(_STR),
                  "reason": _opt(_STR)},
+    # which form a traced program's window layers attend in (models/
+    # window_moe.py): op prefill (the flash forward with the band:
+    # kernel on a TPU, hetu_flash_window; composed off one, reason
+    # platform) or decode (the ring gathered behind its mask, composed:
+    # grouped_ring_decode_attention; reason no_paged_grouped_kernel)
+    "attn_window_plan": {"op": _req(_STR), "window": _req(_INT),
+                         "form": _req(_STR), "reason": _opt(_STR)},
 }
 
 

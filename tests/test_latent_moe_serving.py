@@ -414,7 +414,7 @@ def test_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer(f32):
                                  w["experts_down"], 0)
     np.testing.assert_allclose(np.asarray(shared + sum(parts)),
                                np.asarray(uncut), atol=1e-4)
-    y, _, (all_rows, visits) = lm._feed_forward(
+    y, _, (all_rows, visits) = lm.feed_forward(
         cfg, {k: v for k, v in w.items()}, x, valid)
     np.testing.assert_allclose(np.asarray(y), np.asarray(uncut), atol=1e-4)
     assert np.asarray(all_rows).tolist() == np.concatenate(rows).tolist()
