@@ -99,6 +99,30 @@ class SoftmaxCrossEntropyGradientOp(Op):
         return (-m, m)
 
 
+def _log_sum_exp(logits):
+    """float32 ``[rows]`` log-sum-exp over the class axis: the maximum,
+    then the exponentials summed in float32 — elementwise work and
+    reductions only, so the logits are read in the layout their
+    producer left them. The sparse pair's one ``lse``: the forward
+    computes it and hands it to its gradient op."""
+    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
+    m = m.astype(jnp.float32)
+    e = jnp.exp(logits.astype(jnp.float32) - m)
+    return m[..., 0] + jnp.log(jnp.sum(e, axis=-1))
+
+
+def _label_hit(logits, labels):
+    """``[..., C]`` bool, true at each row's label (clipped into the
+    classes as a gather would be): a comparison with an iota, which a
+    fusion reads in place where ``take_along_axis`` wants the class
+    axis laid out its way — two copies of BERT's 2 GB of MLM logits a
+    step (PERF.md, PR 48)."""
+    nclass = logits.shape[-1]
+    classes = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                       logits.ndim - 1)
+    return classes == jnp.clip(labels, 0, nclass - 1)[..., None]
+
+
 class SoftmaxCrossEntropySparseOp(Op):
     """CE vs integer labels with an ignored index (reference
     SoftmaxCrossEntropySparse.py — used by BERT MLM)."""
@@ -110,18 +134,20 @@ class SoftmaxCrossEntropySparseOp(Op):
     def compute(self, input_vals, ectx):
         logits, labels = input_vals
         labels = labels.astype(jnp.int32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(
-            logits, jnp.clip(labels, 0, logits.shape[-1] - 1)[..., None],
-            axis=-1)[..., 0]
-        loss = logz - picked
+        hit = _label_hit(logits, labels)
+        lse = _log_sum_exp(logits)
+        # the residual the gradient op reads, as the flash forward hands
+        # its (o, lse) on (ops/attention.py)
+        ectx.cache[("sparse_ce_lse", self.id)] = lse
+        picked = jnp.sum(jnp.where(hit, logits, 0).astype(jnp.float32),
+                         axis=-1)
         mask = (labels != self.ignored_index)
-        return jnp.where(mask, loss, 0.0)
+        return jnp.where(mask, lse - picked, 0.0).astype(logits.dtype)
 
     def gradient(self, output_grad):
         grad = softmaxcrossentropy_sparse_gradient_op(
             self.inputs[0], self.inputs[1], output_grad,
-            self.ignored_index, ctx=self.raw_ctx)
+            self.ignored_index, forward_op=self, ctx=self.raw_ctx)
         return [grad, None]
 
     def infer_shape(self, input_shapes):
@@ -133,20 +159,28 @@ class SoftmaxCrossEntropySparseOp(Op):
 
 
 class SoftmaxCrossEntropySparseGradientOp(Op):
-    def __init__(self, node_A, node_B, node_C, ignored_index=-1, ctx=None):
+    """``(softmax(logits) - onehot(label)) * grad`` from the forward's
+    log-sum-exp: ``forward_op``'s residual where that op ran in this
+    trace, else the same function of the logits here."""
+
+    def __init__(self, node_A, node_B, node_C, ignored_index=-1,
+                 forward_op=None, ctx=None):
         super().__init__(SoftmaxCrossEntropySparseGradientOp,
                          [node_A, node_B, node_C], ctx)
         self.ignored_index = ignored_index
+        self.forward_op = forward_op
 
     def compute(self, input_vals, ectx):
         logits, labels, grad = input_vals
         labels = labels.astype(jnp.int32)
-        nclass = logits.shape[-1]
-        onehot = jax.nn.one_hot(jnp.clip(labels, 0, nclass - 1), nclass,
-                                dtype=logits.dtype)
-        mask = (labels != self.ignored_index)[..., None]
-        d = (jax.nn.softmax(logits, axis=-1) - onehot) * grad[..., None]
-        return jnp.where(mask, d, 0.0)
+        fwd = self.forward_op
+        lse = ectx.cache.get(("sparse_ce_lse", fwd.id)) if fwd else None
+        if lse is None:
+            lse = _log_sum_exp(logits)
+        p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+        d = p - _label_hit(logits, labels).astype(jnp.float32)
+        g = jnp.where(labels != self.ignored_index, grad, 0)
+        return (d * g[..., None].astype(jnp.float32)).astype(logits.dtype)
 
     def gradient(self, output_grad):
         raise NotImplementedError
@@ -272,9 +306,11 @@ def softmaxcrossentropy_sparse_op(node_A, node_B, ignored_index=-1,
 
 
 def softmaxcrossentropy_sparse_gradient_op(node_A, node_B, node_C,
-                                           ignored_index=-1, ctx=None):
+                                           ignored_index=-1,
+                                           forward_op=None, ctx=None):
     return SoftmaxCrossEntropySparseGradientOp(node_A, node_B, node_C,
-                                               ignored_index, ctx=ctx)
+                                               ignored_index, forward_op,
+                                               ctx=ctx)
 
 
 def binarycrossentropy_op(node_A, node_B, ctx=None):

@@ -17,7 +17,11 @@ rows, at BERT's cell (twelve heads a program) and head-major at
 S = 8,192, D = 192; and both kernels token-major at BERT's cell, a
 batch row's twelve heads a program (PR 45), alone and in BERT-base's
 whole train step, which then holds no ``[256, 12, 128, 64]`` array at
-all. A compile that passes is a compile, not a run.
+all; and both train cells' steps at their FULL vocabularies, where the
+sparse cross-entropy reads the logits as the head's matmul wrote them
+(PR 48: no whole copy, no gather, five instructions that touch them in
+BERT's step and four in GPT-2's). A compile that passes is a compile,
+not a run.
 
 LayerNorm's backward kernel (``ops/pallas_norm.py``) compiles at the
 two shapes the train cells run it at, ``[16384, 768]`` (GPT-2 small,
@@ -25,7 +29,9 @@ batch 16 x S 1024) and ``[32768, 768]`` (BERT-base, 256 x 128), bf16;
 and the gradient op compiles for four chips under a ``dp`` mesh, where
 GSPMD partitions the step and would refuse the kernel.
 """
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs under /tmp
 
@@ -341,10 +347,11 @@ def test_token_major_kernels_compile_at_one_head_a_block(one_chip):
         assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
-def _gpt2_step_text(v5e_device, monkeypatch, dropout):
+def _gpt2_step_text(v5e_device, monkeypatch, dropout, vocab=1024):
     """The optimized HLO of a GPT-2 training step (the cell's widths,
-    batch and context; one layer and a vocabulary of 1024, to keep the
-    compile short) for one described chip."""
+    batch and context; one layer and, unless the test is about the
+    logits, a vocabulary of 1024, to keep the compile short) for one
+    described chip."""
     import numpy as np
     import hetu_tpu as ht
     from jax.sharding import SingleDeviceSharding
@@ -353,7 +360,7 @@ def _gpt2_step_text(v5e_device, monkeypatch, dropout):
 
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
     model = GPTLMHeadModel(GPTConfig(
-        vocab_size=1024, hidden_size=768, num_hidden_layers=1,
+        vocab_size=vocab, hidden_size=768, num_hidden_layers=1,
         num_attention_heads=12, max_position_embeddings=1024,
         hidden_dropout_prob=dropout, use_flash_attention=True))
     ids = ht.Variable("input_ids", trainable=False)
@@ -394,15 +401,19 @@ def test_gpt2_step_holds_the_dropout_mask_kernel(v5e, monkeypatch):
     assert "hetu_dropout_mask" not in off
 
 
+# an HLO instruction: its name, its result type(s), its opcode and what
+# stands between the opcode's parentheses
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) "
+                          r"([\w\-]+)\((.*?)\)(?:, |$)", re.MULTILINE)
+
+
 def _flash_calls(text):
     """{call name without its number: [(result type, operand types)]}
     of the flash custom calls of a compiled step, every type without
     its layout."""
-    import re
     bare = lambda t: re.sub(r"\{[^{}]*\}", "", t)      # noqa: E731
-    defined = {m.group(1): bare(m.group(2)) for m in re.finditer(
-        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) [\w\-]+\(", text,
-        re.MULTILINE)}
+    defined = {m.group(1): bare(m.group(2))
+               for m in _INSTRUCTION.finditer(text)}
     calls = {}
     for ln in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?(%_flash_attention\w*?)(?:\.\d+)? = "
@@ -486,15 +497,11 @@ def test_token_major_kernels_compile_at_berts_cell(one_chip, kind):
     assert " fusion(" not in entry      # no pass over dO and O for D
 
 
-def test_bert_step_holds_no_head_major_relayout(v5e, monkeypatch):
-    """BERT-base's train step (the cell's widths, batch and length; one
-    layer and a vocabulary of 1024, to keep the compile short) for one
-    described chip: the three projections' rows reach both flash calls
-    as they lie and the context and the three gradients leave as rows,
-    so no array of ``[256, 12, 128, 64]`` (or its transposes) is left
-    anywhere in the text — the q / k / v relayouts and the composed
-    attention backward are gone — and the backward call takes the
-    forward's logsumexp as the forward leaves it."""
+def _bert_step_text(v5e_device, monkeypatch, vocab=1024):
+    """The optimized HLO of BERT-base's pre-training step (the cell's
+    widths, batch 256 and length 128; one layer and, unless the test is
+    about the logits, a vocabulary of 1024, to keep the compile short)
+    for one described chip."""
     import numpy as np
     import hetu_tpu as ht
     from jax.sharding import SingleDeviceSharding
@@ -504,7 +511,7 @@ def test_bert_step_holds_no_head_major_relayout(v5e, monkeypatch):
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
     batch, seq = 256, 128
     model = BertForPreTraining(BertConfig(
-        vocab_size=1024, hidden_size=768, num_hidden_layers=1,
+        vocab_size=vocab, hidden_size=768, num_hidden_layers=1,
         num_attention_heads=12, intermediate_size=3072,
         max_position_embeddings=seq, use_flash_attention=True))
     nodes = [ht.Variable(n, trainable=False) for n in (
@@ -521,12 +528,24 @@ def test_bert_step_holds_no_head_major_relayout(v5e, monkeypatch):
     feed = dict(zip(nodes, (ids, ids, np.ones((batch, seq), np.float32),
                             ids, np.zeros((batch,), np.int32))))
     step = sub.prepare(executor, feed)
-    sharding = SingleDeviceSharding(v5e[0])
+    sharding = SingleDeviceSharding(v5e_device)
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                        sharding=sharding),
         sub.trace_args(executor, feed))
-    text = jax.jit(step).lower(*shapes).compile().as_text()
+    return jax.jit(step).lower(*shapes).compile().as_text()
+
+
+def test_bert_step_holds_no_head_major_relayout(v5e, monkeypatch):
+    """BERT-base's train step (the cell's widths, batch and length; one
+    layer and a vocabulary of 1024, to keep the compile short) for one
+    described chip: the three projections' rows reach both flash calls
+    as they lie and the context and the three gradients leave as rows,
+    so no array of ``[256, 12, 128, 64]`` (or its transposes) is left
+    anywhere in the text — the q / k / v relayouts and the composed
+    attention backward are gone — and the backward call takes the
+    forward's logsumexp as the forward leaves it."""
+    text = _bert_step_text(v5e[0], monkeypatch)
     for shape in ("[256,12,128,64]", "[256,128,12,64]", "[3072,128,64]",
                   "[256,12,64,128]", "[256,12,128,128]"):
         assert shape not in text, shape
@@ -546,6 +565,66 @@ def test_bert_step_holds_no_head_major_relayout(v5e, monkeypatch):
                 if ln.lstrip().split(" = ")[-1].startswith(lse)
                 and any(op in ln for op in (" copy(", " transpose(",
                                             " fusion(", " reshape("))]
+
+
+def _touching(text, elements):
+    """The ENTRY instructions of a compiled step that read or write an
+    array of ``elements`` elements or more — bitcasts and tuple
+    plumbing move nothing and are left out: ``[(name, opcode, the
+    result is that large, the text of what it calls)]``."""
+    sized = lambda t: max(                                  # noqa: E731
+        [math.prod(int(d) for d in dims.split(",") if d)
+         for dims in re.findall(r"\w+\[([\d,]*)\]", t)] or [0]) >= elements
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(%[\w.\-]+) \(.*?\{\n(.*?)^\}", text, re.MULTILINE | re.DOTALL)}
+    entry = text[text.index("ENTRY"):]
+    types, found = {}, []
+    for m in _INSTRUCTION.finditer(entry):
+        name, result, opcode, operands = m.groups()
+        types[name] = result
+        if opcode in ("bitcast", "get-tuple-element", "parameter", "tuple"):
+            continue
+        reads = any(sized(types.get(o, ""))
+                    for o in re.findall(r"%[\w.\-]+", operands))
+        if sized(result) or reads:
+            line = entry[m.start():entry.find("\n", m.end())]
+            called = re.search(r"calls=(%[\w.\-]+)", line)
+            found.append((name, opcode, sized(result),
+                          bodies.get(called.group(1), "") if called else ""))
+    return found
+
+
+@pytest.mark.parametrize("model,logits,most", [
+    ("bert", 256 * 128 * 30522, 5), ("gpt2", 16 * 1024 * 50257, 4)])
+def test_step_reads_the_logits_where_the_head_left_them(
+        v5e, monkeypatch, model, logits, most):
+    """A train cell's step at its batch, length and FULL vocabulary, one
+    layer, for one described chip: the sparse cross-entropy reads the
+    logits where the head's matmul wrote them (PR 48). ONE instruction
+    has a result as large as the logits and it is that matmul's fusion
+    (no ``copy``, no ``transpose``, no slice / bitcast fusion: no whole
+    copy); nothing that reads them holds a ``gather``; and at most five
+    instructions touch them in BERT's step (the matmul with the row
+    maximum in its epilogue, ONE reduction pass for the sum of
+    exponentials and the label's logit, the decoder bias's gradient,
+    the dX and the dW fusions), four in GPT-2's (no bias).
+
+    On the parent (c450c58) BERT's step fails on all three counts — the
+    five, ``copy`` and ``slice_bitcast_fusion`` (two whole copies, 2 GB
+    each way each) and the gather fusion that reads the second: eight
+    in this compile — and GPT-2's on the gather alone: five, no copy."""
+    text = (_bert_step_text(v5e[0], monkeypatch, vocab=30522)
+            if model == "bert" else
+            _gpt2_step_text(v5e[0], monkeypatch, 0.1, vocab=50257))
+    found = _touching(text, logits)
+    print("\n".join(f"{name} {opcode}" for name, opcode, _, _ in found))
+    written = [(name, opcode, body) for name, opcode, large, body in found
+               if large]
+    assert len(written) == 1, [w[:2] for w in written]
+    (name, opcode, body), = written
+    assert opcode == "fusion" and " convolution(" in body, (name, opcode)
+    assert not [name for name, _, _, body in found if " gather(" in body]
+    assert 2 <= len(found) <= most, [f[:2] for f in found]
 
 
 def test_dropout_keeps_the_composed_draw_under_a_dp_mesh(v5e, monkeypatch):
