@@ -96,6 +96,11 @@ class ExecContext:
       * ``cache``      — intra-trace saved activations (dropout masks, softmax
                          outputs) shared between forward and gradient ops
       * ``opt_state`` / ``new_opt_state`` — optimizer slot variables
+      * ``master_params`` — under mixed precision the float32 masters
+                         (``params`` then holds the compute-dtype values)
+      * ``work`` / ``new_work`` — the masters' compute-dtype working copies
+                         that came into the step, by parameter, and the
+                         ones OptimizerOp writes beside each new master
     """
 
     def __init__(self, training=True, base_rng=None, params=None, state=None,
@@ -106,6 +111,9 @@ class ExecContext:
                          else jax.random.PRNGKey(0))
         self.params = params or {}
         self.new_params = {}
+        self.master_params = None
+        self.work = {}
+        self.new_work = {}
         self.state = state or {}
         self.new_state = {}
         self.cache = {}

@@ -300,8 +300,10 @@ class AdamWOptimizer(AdamOptimizer):
 class OptimizerOp(Op):
     """Graph node applying the optimizer to its gradient inputs
     (reference optimizer.py:88-177). Inside a compiled step it writes the
-    functional parameter/slot updates into the ExecContext; the executor
-    threads them to the next step with buffer donation.
+    functional parameter/slot updates into the ExecContext, and beside
+    each new master whose working copy came into the step
+    (``ectx.work``) that copy's successor (``ectx.new_work``); the
+    executor threads them to the next step with buffer donation.
     """
 
     def __init__(self, grads, optimizer):
@@ -323,7 +325,7 @@ class OptimizerOp(Op):
                                                    input_vals, ectx)
         # mixed precision: update the fp32 masters, upcasting the (bf16)
         # gradients — ectx.params holds the compute-dtype copies
-        masters = getattr(ectx, "master_params", None) or ectx.params
+        masters = ectx.master_params or ectx.params
         grad_vals = {}
         param_vals = {}
         for node, gval in zip(params, input_vals):
@@ -368,6 +370,12 @@ class OptimizerOp(Op):
                     pval, opt._unscale(grad_vals[node]),
                     new_params.get(node, pval))))
         ectx.new_params.update(new_params)
+        # mixed precision: the compute-dtype copy the NEXT step's matmuls
+        # read is one more result of this update (2 bytes a parameter
+        # written where 12 are), so no step converts a master again
+        for node, value in new_params.items():
+            if node in ectx.work:
+                ectx.new_work[node] = value.astype(ectx.work[node].dtype)
         ectx.new_opt_state = {**(ectx.opt_state or {}), **new_state}
         return jnp.zeros((1,), dtype=jnp.float32)
 

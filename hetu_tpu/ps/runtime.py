@@ -537,13 +537,12 @@ class PSRuntime:
                     sub._ensure_state(executor)
                     sub.compiled[key] = sub._compile_step(
                         sub.trace_args(executor, feed_map))
+                    sub._note_copies(executor)
             fn = sub.compiled[key]
-            outputs, new_params, new_state, new_opt, ps_grads, health \
+            outputs, *trees, ps_grads, health \
                 = fn(*sub.trace_args(executor, feed_map))
             if sub.training:
-                executor.params = new_params
-                executor.state = new_state
-                executor.opt_state = new_opt
+                executor.adopt(*trees)
                 for opt in sub.optimizer_ops:
                     opt.optimizer.lr_sched.step()
             sub.step_count += 1

@@ -64,6 +64,13 @@ SPAN_SCHEMA = {
     "h2d_transfer": {"bytes": _req(_INT), "overlapped": _req(_BOOL)},
     "h2d_stacked": {"bytes": _req(_INT), "overlapped": _req(_BOOL)},
     "memory_analysis": {"label": _opt(_STR), ...: True},
+    # one a compiled step of an Executor(dtype=...): how many of the
+    # subgraph's parameters the step reads as working copies (the
+    # masters in the compute dtype, written by the step before), their
+    # bytes, and the names (up to ten) of the floating parameters it
+    # converts itself: those the PS runtime writes between steps
+    "working_copies": {"subgraph": _req(_STR), "params": _req(_INT),
+                       "bytes": _req(_INT), "in_step_casts": _any()},
     "step_logged": {"step": _opt(_INT), "wall_ms": _opt(_NUM)},
     # SubExecutor.run around device_dispatch: the feed loop and
     # dataloader batches of one step; state swap, health monitor and

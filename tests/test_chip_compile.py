@@ -20,7 +20,11 @@ whole train step, which then holds no ``[256, 12, 128, 64]`` array at
 all; and both train cells' steps at their FULL vocabularies, where the
 sparse cross-entropy reads the logits as the head's matmul wrote them
 (PR 48: no whole copy, no gather, five instructions that touch them in
-BERT's step and four in GPT-2's). A compile that passes is a compile,
+BERT's step and four in GPT-2's); and in neither step a matmul that
+reads a float32 weight: every one reads the bfloat16 working copy that
+the update of the step before wrote beside its master, and with the
+options the executor gives a training step on a TPU each update runs
+beside its layer's backward (PR 49). A compile that passes is a compile,
 not a run.
 
 LayerNorm's backward kernel (``ops/pallas_norm.py``) compiles at the
@@ -44,6 +48,7 @@ from hetu_tpu.ops import pallas_attention as pk  # noqa: E402
 from hetu_tpu.ops import pallas_norm  # noqa: E402
 
 from flash_cells import CELL_CALLS, call_id  # noqa: E402
+from hlo_matmuls import matmul_fusions, result_types  # noqa: E402
 
 # name -> (batch, heads, seq, head_dim, causal, has_mask)
 SHAPES = {
@@ -347,11 +352,27 @@ def test_token_major_kernels_compile_at_one_head_a_block(one_chip):
         assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
-def _gpt2_step_text(v5e_device, monkeypatch, dropout, vocab=1024):
+_STEP_TEXTS = {}        # a compile a (model, arguments), shared by tests
+
+
+def _gpt2_step_text(v5e_device, monkeypatch, dropout, vocab=1024,
+                    layers=1, as_the_executor=False):
     """The optimized HLO of a GPT-2 training step (the cell's widths,
     batch and context; one layer and, unless the test is about the
     logits, a vocabulary of 1024, to keep the compile short) for one
-    described chip."""
+    described chip; ``as_the_executor`` compiles it as
+    ``SubExecutor._jit`` does on a TPU: four donated trees and
+    ``TPU_TRAIN_STEP_OPTIONS``."""
+    key = ("gpt2", dropout, vocab, layers, as_the_executor)
+    if key not in _STEP_TEXTS:
+        _STEP_TEXTS[key] = _compile_gpt2_step(
+            v5e_device, monkeypatch, dropout, vocab, layers,
+            as_the_executor)
+    return _STEP_TEXTS[key]
+
+
+def _compile_gpt2_step(v5e_device, monkeypatch, dropout, vocab, layers,
+                       as_the_executor):
     import numpy as np
     import hetu_tpu as ht
     from jax.sharding import SingleDeviceSharding
@@ -360,7 +381,7 @@ def _gpt2_step_text(v5e_device, monkeypatch, dropout, vocab=1024):
 
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
     model = GPTLMHeadModel(GPTConfig(
-        vocab_size=vocab, hidden_size=768, num_hidden_layers=1,
+        vocab_size=vocab, hidden_size=768, num_hidden_layers=layers,
         num_attention_heads=12, max_position_embeddings=1024,
         hidden_dropout_prob=dropout, use_flash_attention=True))
     ids = ht.Variable("input_ids", trainable=False)
@@ -380,6 +401,11 @@ def _gpt2_step_text(v5e_device, monkeypatch, dropout, vocab=1024):
                                        if not hasattr(a, "dtype")
                                        else a.dtype, sharding=sharding),
         sub.trace_args(executor, feed))
+    if as_the_executor:
+        from hetu_tpu.executor import TPU_TRAIN_STEP_OPTIONS
+        return jax.jit(step, donate_argnums=(0, 1, 2, 3)).lower(
+            *shapes).compile(
+                compiler_options=TPU_TRAIN_STEP_OPTIONS).as_text()
     return jax.jit(step).lower(*shapes).compile().as_text()
 
 
@@ -502,6 +528,13 @@ def _bert_step_text(v5e_device, monkeypatch, vocab=1024):
     widths, batch 256 and length 128; one layer and, unless the test is
     about the logits, a vocabulary of 1024, to keep the compile short)
     for one described chip."""
+    if ("bert", vocab) not in _STEP_TEXTS:
+        _STEP_TEXTS["bert", vocab] = _compile_bert_step(
+            v5e_device, monkeypatch, vocab)
+    return _STEP_TEXTS["bert", vocab]
+
+
+def _compile_bert_step(v5e_device, monkeypatch, vocab):
     import numpy as np
     import hetu_tpu as ht
     from jax.sharding import SingleDeviceSharding
@@ -625,6 +658,72 @@ def test_step_reads_the_logits_where_the_head_left_them(
     assert opcode == "fusion" and " convolution(" in body, (name, opcode)
     assert not [name for name, _, _, body in found if " gather(" in body]
     assert 2 <= len(found) <= most, [f[:2] for f in found]
+
+
+def _update_results(rows):
+    """{matrix shape: the sorted dtypes of the results} of the fusions
+    that hold a matmul and return one array a result, all of one shape:
+    the optimizer's update with the matmul of its gradient inside."""
+    updates = {}
+    for row in rows:
+        results = result_types(row)
+        shapes = {shape for _, shape, _ in results}
+        if len(results) >= 3 and len(shapes) == 1:
+            updates[shapes.pop()] = sorted(d for d, _, _ in results)
+    return updates
+
+
+@pytest.mark.parametrize("model", ["bert", "gpt2"])
+def test_no_matmul_of_the_step_reads_a_float32_weight(v5e, monkeypatch,
+                                                      model):
+    """A train cell's step at the cell's widths, one layer, for one
+    described chip (PR 49): no operand of any ``convolution`` is a
+    float32 operand of its fusion, moved or converted inside it — on the
+    parent (e33ac3f) every weight was, 18 of them in BERT's compile —
+    and the update of each of the block's matrices, which XLA fuses into
+    the matmul that makes its gradient, returns FOUR arrays of the
+    matrix's shape: the master and Adam's two moments in float32 and the
+    next step's working copy in bfloat16."""
+    text = (_bert_step_text(v5e[0], monkeypatch) if model == "bert" else
+            _gpt2_step_text(v5e[0], monkeypatch, 0.1))
+    rows = matmul_fusions(text)
+    assert len(rows) >= (20 if model == "bert" else 12), len(rows)
+    weights = [o for row in rows for o in row["operands"]
+               if len(o["shape"]) == 2 and o["shape"][0] % 768 == 0]
+    assert len(weights) >= (12 if model == "bert" else 8), weights
+    read = [(row["name"], o) for row in rows for o in row["operands"]
+            if o["dtype"] == "f32" or o["converted"]]
+    assert not read, read
+    updates = _update_results(rows)
+    for shape in ((768, 768), (768, 3072), (3072, 768)) + (
+            ((768, 2304),) if model == "gpt2" else ()):
+        assert updates[shape] == ["bf16", "f32", "f32", "f32"], updates
+
+
+def test_gpt2_step_runs_each_update_beside_its_layers_backward(
+        v5e, monkeypatch):
+    """XLA's default memory scheduler keeps one of several orders of the
+    step, and for GPT-2 small's step with its 50,257-word head it kept
+    one that runs the optimizer's updates after the WHOLE backward pass,
+    with their operands gone from on-chip memory (110.9 ms a step for
+    106.1 on the chip, PERF.md section 6, PR 49). Compiled as the
+    executor compiles a training step on a TPU (four donated trees,
+    ``TPU_TRAIN_STEP_OPTIONS``), four layers' worth of that step for one
+    described chip: the update of every layer's second feed-forward
+    matrix, with the matmul of its gradient inside, is scheduled before
+    the flash backward of the SAME layer, which the backward pass
+    reaches next."""
+    text = _gpt2_step_text(v5e[0], monkeypatch, 0.1, vocab=50257,
+                           layers=4, as_the_executor=True)
+    entry = text[text.index("\nENTRY "):]
+    order = []
+    for line in entry.splitlines():
+        if "_flash_attention_bwd" in line and " custom-call(" in line:
+            order.append("backward")
+        elif " fusion(" in line and \
+                line.split(" fusion(")[0].count("f32[3072,768]") == 3:
+            order.append("update")
+    assert order == ["update", "backward"] * 4, order
 
 
 def test_dropout_keeps_the_composed_draw_under_a_dp_mesh(v5e, monkeypatch):
