@@ -662,7 +662,8 @@ class SubExecutor:
             init = {}
             for k, shp in shapes.items():
                 fill = 1.0 if "var" in k else 0.0
-                init[k] = jnp.full(shp, fill, dtype=jnp.float32)
+                init[k] = jnp.full(shp, fill, dtype=getattr(
+                    node, "state_dtype", jnp.float32))
             executor.state[sid] = init
 
     def _note_copies(self, executor):
@@ -790,6 +791,12 @@ class SubExecutor:
             # (an evaluation step hands none back: nothing adopts them)
             new_work = {str(n.id): ectx.new_work.get(n, v)
                         for n, v in ectx.work.items()} if training else {}
+            if not training:
+                # nor the trees it did not change: a step that is not
+                # donated would hand each back as a COPY (9.1e9 bytes of
+                # outputs beside 9.2e9 of arguments for a 656M-parameter
+                # model with Adam's moments: no room on one chip, PR 50)
+                new_params, new_state, new_opt = {}, {}, None
             # PS-managed gradients leave the compiled region as outputs;
             # the PS runtime pushes them after the step
             ps_grads = [env[op.inputs[0]] if op.inputs else None
@@ -982,10 +989,16 @@ class SubExecutor:
         out_is_none = [n in set(self.optimizer_ops)
                        for n in self.eval_node_list]
 
+        training = self.training
+
         def block_fn(params, state, opt_state, work, feeds_stacked, lrs,
                      step0, rng):
+            trees = (params, state, opt_state, work)
+
             def body(carry, xs):
-                params, state, opt, work = carry
+                # an evaluation step changes no tree and hands none
+                # back: its block reads them as constants of the scan
+                params, state, opt, work = carry if training else trees
                 step_idx, lr = xs[0], xs[1]
                 feeds = list(xs[2:])
                 outputs, p, s, o, w, _, h = step_fn(
@@ -995,10 +1008,10 @@ class SubExecutor:
                 # health sentinels stack along the scan axis (None —
                 # an empty pytree — when the monitor is off, so the
                 # disabled program is unchanged)
-                return (p, s, o, w), (outs, h)
+                return ((p, s, o, w) if training else ()), (outs, h)
             steps = step0 + jnp.arange(nsteps, dtype=jnp.int32)
             carry, (outs, health) = jax.lax.scan(
-                body, (params, state, opt_state, work),
+                body, trees if training else (),
                 tuple([steps, lrs] + list(feeds_stacked)))
             return (outs, health) + carry
 
@@ -1490,6 +1503,33 @@ class Executor:
         self.params, self.state, self.opt_state, self.work = \
             params, state, opt_state, work
         self._work_from = {sid: params[sid] for sid in work}
+
+    def moe_counters(self):
+        """What the held-expert layers of the graph counted on the
+        device, summed over every TRAINING step since the session began
+        (``ops/moe.py:HeldExpertsOp`` keeps the counts in its op state;
+        a step adds to them and nothing is read inside one): a dict a
+        layer, in graph order — ``moe_rows_by_expert`` (the (token,
+        pick) pairs that landed on each held expert), ``moe_routed_rows``
+        (their sum), ``moe_expert_visits`` (held experts that got a row,
+        a step each), ``steps``. Reading it waits for the last step.
+        The counts are int32 on the device: a session of more than
+        2**31 rows on one expert wraps them."""
+        from .ops.moe import HeldExpertsOp
+        nodes = {node.id: node for sub in self.subexecutors.values()
+                 for node in sub.stateful_ops
+                 if isinstance(node, HeldExpertsOp)}
+        out = []
+        for nid in sorted(nodes):
+            state = self.state.get(str(nid))
+            if state is None:
+                continue
+            rows = np.asarray(state["moe_rows_by_expert"]).tolist()
+            out.append({"moe_rows_by_expert": rows,
+                        "moe_routed_rows": int(sum(rows)),
+                        "moe_expert_visits": int(state["moe_expert_visits"]),
+                        "steps": int(state["steps"])})
+        return out
 
     # ------------------------------------------------------------------
     def ingest_stats(self):

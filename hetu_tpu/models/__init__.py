@@ -14,6 +14,8 @@ from .gpt import GPTConfig, GPTModel, GPTLMHeadModel
 from .latent_moe import LatentMoEConfig
 from .ssm_hybrid import SSMHybridConfig
 from .window_moe import WindowMoEConfig
+from .sparse_decoder import (SparseDecoderConfig, SparseDecoderModel,
+                             SparseDecoderLMHeadModel)
 from .bert import (BertConfig, BertModel, BertForPreTraining,
                    BertForSequenceClassification, BertForMaskedLM)
 from .ctr import (wdl_criteo, wdl_adult, deepfm_criteo, dcn_criteo,

@@ -41,7 +41,10 @@ from .norm import (
     layer_normalization_gradient_of_scale_op,
     layer_normalization_gradient_of_bias_op,
     instance_normalization2d_op, instance_normalization2d_gradient_op,
+    rms_normalization_op, rms_normalization_gradient_op,
 )
+from .rotary import rotary_op
+from .moe import router_op, router_picks_op, held_experts_op
 from .embedding import embedding_lookup_op, embedding_lookup_gradient_op
 from .sparse import csrmv_op, csrmm_op, distgcn_15d_op
 from .attention import (flash_attention_op, ring_attention_op,

@@ -366,7 +366,7 @@ def unpartitioned_tpu_step(ectx):
     return _use_pallas() and (mesh is None or mesh.size == 1)
 
 
-def flash_layout(s, d, heads, token_major, ectx=None):
+def flash_layout(s, d, heads, token_major, ectx=None, kv_heads=None):
     """``(layout, reason)``: which operand form a flash call runs in,
     from what the code can see. ``"token_major"`` (the kernels read
     q, k and v out of the projections' own rows in both directions —
@@ -376,11 +376,13 @@ def flash_layout(s, d, heads, token_major, ectx=None):
     (b) the step is not partitioned over a mesh
     (:func:`unpartitioned_tpu_step`); (c) the caller hands token-major
     operands. Else ``"head_major"`` and the first condition that
-    failed: ``lanes``, ``mesh``, ``caller``."""
+    failed: ``lanes``, ``mesh``, ``caller``. With fewer key/value
+    heads than query heads (``kv_heads``) a head has to BE a lane
+    block."""
     from .pallas_attention import TokenMajor
     mesh = getattr(getattr(ectx, "config", None), "mesh", None)
     for reason, holds in (
-            ("lanes", TokenMajor(heads, d).fits(s)),
+            ("lanes", TokenMajor(heads, d, kv_heads=kv_heads).fits(s)),
             ("mesh", mesh is None or mesh.size == 1),
             ("caller", token_major)):
         if not holds:
@@ -405,14 +407,36 @@ class FlashAttentionOp(Op):
     kernels read and write those rows as they lie and no transpose,
     split or merge runs around them; elsewhere the op makes the trip
     through ``[B, H, S, D]`` itself. The additive mask is
-    ``[B, 1, 1, S]`` (or None) either way."""
+    ``[B, 1, 1, S]`` (or None) either way.
+
+    Two things a decoder's three-projection call may add (causal, no
+    mask). ``num_kv_heads`` < ``num_heads``: k and v are ``[B, S,
+    num_kv_heads * D]``, query head ``h`` reads key/value head ``h //
+    (num_heads // num_kv_heads)``, and dk / dv are the sums over a
+    group's query heads. ``window``: a query at row ``i`` sees the keys
+    ``i - window < j <= i`` alone, in both directions: the kernels
+    leave out the tiles wholly behind the band
+    (``pallas_attention.tile_walk``)."""
 
     def __init__(self, q, k=None, v=None, mask=None, sm_scale=1.0,
-                 causal=False, num_heads=None, ctx=None):
+                 causal=False, num_heads=None, ctx=None, num_kv_heads=None,
+                 window=None):
         if (k is None) != (v is None) or (k is None and not num_heads):
             raise ValueError("flash attention takes q, k, v [B, H, S, D], "
                              "or with num_heads their rows [B, S, H] or "
                              "packed qkv rows [B, S, 3H]")
+        grouped = bool(num_kv_heads) and num_kv_heads != num_heads
+        if (grouped or window is not None) and (
+                k is None or not num_heads or not causal
+                or mask is not None):
+            raise ValueError(
+                "num_kv_heads and window belong to a causal call over "
+                "three projections' rows (num_heads given, no mask)")
+        if grouped and num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads do not split over "
+                             f"{num_kv_heads} key/value heads")
+        self.num_kv_heads = num_kv_heads if grouped else None
+        self.window = None if window is None else int(window)
         self.packed = k is None
         inputs = ([q] if self.packed else [q, k, v]) \
             + ([mask] if mask is not None else [])
@@ -447,9 +471,12 @@ class FlashAttentionOp(Op):
         # packed: one array, read three times
         q, k, v = input_vals[:1] * 3 if self.packed else input_vals[:3]
         b, h, s, d = self.attention_shape(q.shape)
-        form, reason = flash_layout(s, d, h, True, ectx)
+        form, reason = flash_layout(s, d, h, True, ectx, self.num_kv_heads)
         if form == "token_major" and _use_pallas():
             from .pallas_attention import TokenMajor
+            if self.num_kv_heads:
+                return q, k, v, mask, TokenMajor(
+                    h, d, kv_heads=self.num_kv_heads), None
             return q, k, v, mask, (TokenMajor.packed if self.packed
                                    else TokenMajor)(h, d), None
         return *self._split(q, k, v), mask, None, reason
@@ -461,8 +488,24 @@ class FlashAttentionOp(Op):
         b, h, s, d = self.attention_shape(q.shape)
         if self.packed:
             return q.reshape(b, s, 3, h, d).transpose(2, 0, 3, 1, 4)
-        return [x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+        # (grouped: k and v keep their own head count; ``_spread`` gives
+        # each query head its group's)
+        return [x.reshape(b, s, -1, d).transpose(0, 2, 1, 3)
                 for x in (q, k, v)]
+
+    def _spread(self, x):
+        """Key/value heads ``[B, G, S, D]`` -> a copy a query head
+        ``[B, H, S, D]`` (the form the head-major paths take; its vjp
+        is the sum over a group)."""
+        if not self.num_kv_heads:
+            return x
+        return jnp.repeat(x, self.num_heads // self.num_kv_heads, axis=1)
+
+    def _band_mask(self, s):
+        """The additive ``[1, 1, S, S]`` mask of a causal call off the
+        kernels: the diagonal's, or with a window the band's."""
+        from .pallas_attention import _band
+        return jnp.where(_band(s, self.window), 0.0, -1e9)[None, None]
 
     def compute(self, input_vals, ectx):
         q, k, v, mask, layout, reason = self.operands(input_vals, ectx)
@@ -473,6 +516,9 @@ class FlashAttentionOp(Op):
         return o
 
     def _attend(self, q, k, v, mask, layout, reason, ectx):
+        if layout is None:
+            k, v = self._spread(k), self._spread(v)
+        band = {} if self.window is None else {"window": self.window}
         if _use_pallas():
             # causal is a kernel flag; only the padding mask travels.
             # The logsumexp residual is stashed for the fused backward
@@ -486,15 +532,14 @@ class FlashAttentionOp(Op):
                       layout=layout, reason=reason)
             if getattr(ectx, "training", False) and fused_backward(
                     _seq_len(q, layout), layout is not None):
-                o, lse = flash_attention_with_lse(q, k, v, mask, **kw)
+                o, lse = flash_attention_with_lse(q, k, v, mask, **kw,
+                                                  **band)
                 if o is not None:
                     ectx.cache[("flash_res", self.id)] = (o, lse)
                     return o
-            return flash_attention(q, k, v, mask, **kw)
+            return flash_attention(q, k, v, mask, **kw, **band)
         if self.causal:
-            s = q.shape[-2]
-            cmask = jnp.where(
-                jnp.tril(jnp.ones((s, s), bool)), 0.0, -1e9)[None, None]
+            cmask = self._band_mask(q.shape[-2])
             mask = cmask if mask is None else mask + cmask
         return attention_reference(q, k, v, mask, self.sm_scale)
 
@@ -562,26 +607,32 @@ class _FlashAttentionGradOp(Op):
             # (PERF.md section 7 has both probes).
             from .pallas_attention import flash_attention_bwd
             o, lse = res
+            band = {} if fwd.window is None else {"window": fwd.window}
+            if layout is None and fwd.num_kv_heads:
+                # head-major kernels take a copy a query head; a group's
+                # dk / dv are the sums over its copies
+                (k, v), spread = jax.vjp(
+                    lambda k_, v_: (fwd._spread(k_), fwd._spread(v_)), k, v)
             grads = flash_attention_bwd(
                 q, k, v, mask, o, lse, dy, sm_scale=fwd.sm_scale,
-                causal=fwd.causal, layout=layout, reason=reason)
+                causal=fwd.causal, layout=layout, reason=reason, **band)
+            if layout is None and fwd.num_kv_heads:
+                grads = (grads[0], *spread((grads[1], grads[2])))
         else:
             def f(q_, k_, v_):
                 m = mask
                 if fwd.causal:
-                    s = q_.shape[-2]
-                    cmask = jnp.where(
-                        jnp.tril(jnp.ones((s, s), bool)), 0.0,
-                        -1e9)[None, None]
+                    cmask = fwd._band_mask(q_.shape[-2])
                     m = cmask if m is None else m + cmask
-                return attention_reference(q_, k_, v_, m, fwd.sm_scale)
+                return attention_reference(
+                    q_, fwd._spread(k_), fwd._spread(v_), m, fwd.sm_scale)
             _, vjp = jax.vjp(f, q, k, v)
             grads = vjp(dy)
         if not fwd.num_heads:
             return grads
         if layout is None:      # back to the rows the operands came as
-            b, h, s, d = q.shape
-            grads = [g.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+            b, _, s, d = q.shape
+            grads = [g.transpose(0, 2, 1, 3).reshape(b, s, -1)
                      for g in grads]
         # the rows each projection's dW and dX matmuls read
         return (jnp.concatenate(grads, axis=-1),) if fwd.packed \
@@ -595,13 +646,16 @@ class _FlashAttentionGradOp(Op):
 
 
 def flash_attention_op(q, k=None, v=None, mask=None, sm_scale=1.0,
-                       causal=False, num_heads=None, ctx=None):
+                       causal=False, num_heads=None, ctx=None,
+                       num_kv_heads=None, window=None):
     """Fused attention over q, k, v ``[B, H, S, D]``, or — ``num_heads``
     given — over their rows ``[B, S, H]`` or, k and v left out, over a
-    qkv projection's packed rows ``[B, S, 3H]``; see
-    :class:`FlashAttentionOp`."""
+    qkv projection's packed rows ``[B, S, 3H]``; a causal call over
+    three projections' rows may have fewer key/value heads
+    (``num_kv_heads``) and a ``window``; see :class:`FlashAttentionOp`."""
     return FlashAttentionOp(q, k, v, mask, sm_scale, causal, num_heads,
-                            ctx=ctx)
+                            ctx=ctx, num_kv_heads=num_kv_heads,
+                            window=window)
 
 
 # ---------------------------------------------------------------------------

@@ -16,11 +16,16 @@ __all__ = ["matmul_op", "batch_matmul_op"]
 
 
 class MatMulOp(Op):
+    """``out_dtype``: the product leaves the accumulator in that dtype
+    (float32 logits out of bfloat16 operands, say) and not in the
+    operands'; its gradients then arrive in it too."""
+
     def __init__(self, node_A, node_B, trans_A=False, trans_B=False,
-                 ctx=None):
+                 ctx=None, out_dtype=None):
         super().__init__(MatMulOp, [node_A, node_B], ctx)
         self.matmul_attr_trans_A = trans_A
         self.matmul_attr_trans_B = trans_B
+        self.out_dtype = out_dtype
 
     def compute(self, input_vals, ectx):
         a, b = input_vals
@@ -28,6 +33,8 @@ class MatMulOp(Op):
             a = a.T
         if self.matmul_attr_trans_B:
             b = b.T
+        if self.out_dtype is not None:
+            return jnp.dot(a, b, preferred_element_type=self.out_dtype)
         return jnp.dot(a, b)
 
     def gradient(self, output_grad):
@@ -172,8 +179,10 @@ class BatchMatMulOp(Op):
             status.set_attr(dup, (-1,) + tuple(range(len(batch) + 2)))
 
 
-def matmul_op(node_A, node_B, trans_A=False, trans_B=False, ctx=None):
-    return MatMulOp(node_A, node_B, trans_A, trans_B, ctx=ctx)
+def matmul_op(node_A, node_B, trans_A=False, trans_B=False, ctx=None,
+              out_dtype=None):
+    return MatMulOp(node_A, node_B, trans_A, trans_B, ctx=ctx,
+                    out_dtype=out_dtype)
 
 
 def batch_matmul_op(node_A, node_B, trans_A=False, trans_B=False, ctx=None):

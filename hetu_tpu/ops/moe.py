@@ -1,6 +1,9 @@
 """Mixture-of-experts routing and the expert layer of ONE share of an
-expert-parallel deployment (pure JAX, no graph nodes: the serving
-block of ``models/latent_moe.py`` rides these).
+expert-parallel deployment: pure-JAX functions (the serving blocks of
+``models/latent_moe.py`` and ``models/window_moe.py`` ride these) and,
+for training through ``ht.Executor``, graph ops over them with gradient
+ops of their own (``router_op``, ``router_picks_op``,
+``held_experts_op``; ``models/sparse_decoder.py`` is built from them).
 
 No reference equivalent. The layer is told WHICH experts it holds
 (``first``, and as many as its weight stack has) and computes their part
@@ -22,6 +25,21 @@ elsewhere would add is the exchange's business, not this module's.
   profile; ``ops/pallas_norm.py`` says why the jitted function carries
   it); elsewhere, and for widths the kernel's tiles do not take,
   ``jax.lax.ragged_dot``.
+* :func:`router_op` / :func:`router_picks_op` — a softmax router as
+  graph nodes: float32 logits at the highest matmul precision from the
+  float32 MASTER of the router's weights, the ``top_k`` largest, a
+  softmax over those alone. The gradient flows through the chosen
+  weights into the router's weights and its input, never through the
+  indices.
+* :func:`held_experts_op` — :func:`held_experts` as a graph node over
+  the stacked parameters of the experts held here, with a counter the
+  compiled training step accumulates on the device (rows by held
+  expert, experts visited, steps: ``Executor.moe_counters()``). Its
+  backward is two grouped products for the rows (``hetu_moe_experts_dx``:
+  the kernel with its right side transposed) and two transposed grouped
+  products for the weights (``hetu_moe_experts_dw``: megablox's
+  ``tgmm``); the rows of experts held elsewhere are never computed in
+  either direction.
 """
 from __future__ import annotations
 
@@ -31,10 +49,21 @@ import importlib
 import jax
 import jax.numpy as jnp
 
+from ..graph.node import Op
+from .norm import PackedPartOp as _Part
+
 __all__ = ["route", "held_experts", "grouped_matmul", "swiglu",
-           "KERNEL_NAME", "TOKEN_CHUNK"]
+           "KERNEL_NAME", "ROWS_GRAD_KERNEL_NAME",
+           "WEIGHTS_GRAD_KERNEL_NAME", "TOKEN_CHUNK", "ACTIVATIONS",
+           "route_softmax_top_k", "router_op", "router_picks_op",
+           "held_experts_op", "RouterOp", "HeldExpertsOp"]
 
 KERNEL_NAME = "hetu_moe_experts"
+# the backward's grouped products, as a profile names their events
+ROWS_GRAD_KERNEL_NAME = "hetu_moe_experts_dx"
+WEIGHTS_GRAD_KERNEL_NAME = "hetu_moe_experts_dw"
+# the gate's activation of an expert: down(act(gate x) * up x)
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 LANES = 128
 # tokens a caller should pass at a time: the sorted copies of a pass are
 # [tokens * top_k, hidden], 268 MB at 4096 tokens x 8 picks x 4096 wide
@@ -104,6 +133,32 @@ def _kernel(tiles, out_dtype, interpret):
     return jax.jit(hetu_moe_experts)
 
 
+@functools.lru_cache(maxsize=None)
+def _grad_kernel(which, tiles, out_dtype, interpret, groups=None):
+    """The backward's two kernels under their stable names: ``"rows"``
+    is ``gmm`` with the right side transposed (``dy @ rhs[g]^T``),
+    ``"weights"`` megablox's ``tgmm`` over the first ``groups`` groups
+    (``lhs[rows of g]^T @ dy[rows of g]``). Neither has a vjp of its
+    own in the library's ``__wrapped__`` form: these ARE the vjp."""
+    lib = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    if which == "rows":
+        def fn(dy, rhs, group_sizes):
+            return lib.gmm.__wrapped__(
+                dy, rhs, group_sizes, preferred_element_type=out_dtype,
+                tiling=tiles, transpose_rhs=True, interpret=interpret)
+        name = ROWS_GRAD_KERNEL_NAME
+    else:
+        def fn(lhs, dy, group_sizes):
+            return lib.tgmm.__wrapped__(
+                lhs.swapaxes(0, 1), dy, group_sizes,
+                preferred_element_type=out_dtype, tiling=tiles,
+                num_actual_groups=groups, interpret=interpret)
+        name = WEIGHTS_GRAD_KERNEL_NAME
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 # tests flip this to exercise the kernel without a TPU backend
 INTERPRET = False
 
@@ -128,19 +183,61 @@ def grouped_matmul(lhs, rhs, group_sizes):
     return jnp.where(computed, out, 0.0).astype(lhs.dtype)
 
 
-def held_experts(x, experts, weights, valid, w_gate_up, w_down, first=0):
-    """The held experts' part of an expert layer.
+def _computed_rows(m, group_sizes):
+    """``[m, 1]`` bool: the rows of the groups held here (the sorted
+    rows before the last group)."""
+    return jnp.arange(m)[:, None] < jnp.sum(group_sizes[:-1])
 
-    ``x`` ``[T, hidden]``; ``experts`` / ``weights`` ``[T, k]`` from
-    :func:`route`; ``valid`` ``[T]`` bool (a padded token is routed
-    nowhere); ``w_gate_up`` ``[held, hidden, 2 * width]`` and
-    ``w_down`` ``[held, width, hidden]`` are the stacks of the experts
-    ``first .. first + held - 1``. Returns ``(sum over the held picks
-    of weight * expert(x) [T, hidden] float32, rows by held expert
-    [held] int32)``. The sorted copies are ``[T * k, hidden]``: a
-    caller with many tokens passes ``TOKEN_CHUNK`` at a time."""
+
+def grouped_matmul_rows_grad(dy, rhs, group_sizes):
+    """``d lhs`` of :func:`grouped_matmul`: ``dy[rows of g] @ rhs[g]^T``,
+    ``[m, k]`` in ``dy``'s dtype; the rows behind the held groups come
+    back as zeros."""
+    m, n = dy.shape
+    tiles = _kernel_tiles(m, n, rhs.shape[1]) \
+        if (_use_pallas() or INTERPRET) else None
+    if tiles is not None:
+        return _grad_kernel("rows", tiles, jnp.dtype(dy.dtype), INTERPRET)(
+            dy, rhs, group_sizes)
+    out = jax.lax.ragged_dot(dy, rhs.swapaxes(1, 2), group_sizes[:-1],
+                             preferred_element_type=jnp.float32)
+    return jnp.where(_computed_rows(m, group_sizes), out,
+                     0.0).astype(dy.dtype)
+
+
+def grouped_matmul_weights_grad(lhs, dy, group_sizes):
+    """``d rhs`` of :func:`grouped_matmul`: ``lhs[rows of g]^T @ dy[rows
+    of g]`` for the ``G`` held groups, ``[G, k, n]`` float32 (a group
+    that got no row is zeros)."""
+    m, k = lhs.shape
+    groups = group_sizes.shape[0] - 1
+    tiles = _kernel_tiles(m, k, dy.shape[-1]) \
+        if (_use_pallas() or INTERPRET) else None
+    if tiles is not None:
+        return _grad_kernel("weights", tiles, jnp.dtype(jnp.float32),
+                            INTERPRET, groups)(lhs, dy, group_sizes)
+    ends = jnp.cumsum(group_sizes[:-1])
+    row = jnp.arange(m)
+    member = (row[None, :] >= (ends - group_sizes[:-1])[:, None]) \
+        & (row[None, :] < ends[:, None])                    # [G, m]
+    return jnp.einsum("gm,mk,mn->gkn", member.astype(jnp.float32),
+                      lhs.astype(jnp.float32), dy.astype(jnp.float32))
+
+
+def _held_pairs(experts, valid, held_n, first):
+    """``[T, k]`` bool: the picks of real tokens whose expert is one of
+    ``first .. first + held_n - 1``."""
+    local = experts - first
+    return (local >= 0) & (local < held_n) & valid[:, None]
+
+
+def _sorted_pairs(experts, valid, held_n, first):
+    """The (token, pick) pairs sorted by held expert: ``(order [rows +
+    pad], sizes [held + 1], held [T, k] bool, rows)``. The pairs of
+    experts held elsewhere and of padded tokens are the last group; on
+    the kernel's path the rows are padded to its row tile and the pad
+    joins that group."""
     t, k = experts.shape
-    held_n = w_gate_up.shape[0]
     local = experts - first
     held = (local >= 0) & (local < held_n) & valid[:, None]
     group = jnp.where(held, local, held_n).reshape(-1)       # [t * k]
@@ -151,10 +248,29 @@ def held_experts(x, experts, weights, valid, w_gate_up, w_down, first=0):
     if pad:     # the kernel's row tile; the pad rows join the last group
         order = jnp.concatenate([order, jnp.zeros(pad, order.dtype)])
         sizes = sizes.at[held_n].add(pad)
+    return order, sizes, held, rows
+
+
+def held_experts(x, experts, weights, valid, w_gate_up, w_down, first=0,
+                 activation="silu"):
+    """The held experts' part of an expert layer.
+
+    ``x`` ``[T, hidden]``; ``experts`` / ``weights`` ``[T, k]`` from
+    :func:`route`; ``valid`` ``[T]`` bool (a padded token is routed
+    nowhere); ``w_gate_up`` ``[held, hidden, 2 * width]`` and
+    ``w_down`` ``[held, width, hidden]`` are the stacks of the experts
+    ``first .. first + held - 1``. Returns ``(sum over the held picks
+    of weight * expert(x) [T, hidden] float32, rows by held expert
+    [held] int32)``. The sorted copies are ``[T * k, hidden]``: a
+    caller with many tokens passes ``TOKEN_CHUNK`` at a time.
+    ``activation`` names the gate's (``ACTIVATIONS``)."""
+    t, k = experts.shape
+    held_n = w_gate_up.shape[0]
+    order, sizes, held, rows = _sorted_pairs(experts, valid, held_n, first)
     xs = x[order // k]
     h = grouped_matmul(xs, w_gate_up, sizes)
     width = h.shape[-1] // 2
-    act = (jax.nn.silu(h[:, :width].astype(jnp.float32))
+    act = (ACTIVATIONS[activation](h[:, :width].astype(jnp.float32))
            * h[:, width:].astype(jnp.float32)).astype(x.dtype)
     ys = grouped_matmul(act, w_down, sizes)
     # back to (token, pick) order; a pair held elsewhere weighs nothing
@@ -164,3 +280,292 @@ def held_experts(x, experts, weights, valid, w_gate_up, w_down, first=0):
     out = jnp.einsum("tk,tkh->th", jnp.where(held, weights, 0.0),
                      pairs.astype(jnp.float32))
     return out, sizes[:held_n]
+
+
+# ---------------------------------------------------------------------------
+# graph ops (training through ht.Executor)
+# ---------------------------------------------------------------------------
+
+def route_softmax_top_k(x, w_router, top_k):
+    """A softmax router over ALL experts: ``x [..., hidden]``,
+    ``w_router [hidden, E]``. Logits in float32 (the product at the
+    highest precision, as :func:`route`'s), the ``top_k`` largest, a
+    softmax over those alone — which is the softmax over all ``E``
+    renormalised over the chosen. Returns ``(experts [..., k] int32,
+    weights [..., k] float32)``."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    picked, experts = jax.lax.top_k(logits, top_k)
+    return experts.astype(jnp.int32), jax.nn.softmax(picked, axis=-1)
+
+
+def _master(ectx, node, value):
+    """The float32 master of parameter ``node`` where the step keeps
+    one (mixed precision), else the value the step handed the op."""
+    masters = getattr(ectx, "master_params", None)
+    if masters is not None and node in masters:
+        return masters[node]
+    return value
+
+
+class RouterOp(Op):
+    """The chosen experts' WEIGHTS ``[..., k]`` float32 of
+    :func:`route_softmax_top_k`; the indices are
+    :class:`RouterPicksOp`'s. The router's weights are read from their
+    float32 master whatever the step's compute dtype."""
+
+    def __init__(self, node_in, w_router, top_k, ctx=None):
+        super().__init__(RouterOp, [node_in, w_router], ctx)
+        self.top_k = top_k
+
+    def routed(self, input_vals, ectx):
+        """``(experts, weights)``, computed once a trace."""
+        key = ("router", self.id)
+        if key not in ectx.cache:
+            x, w = input_vals
+            ectx.cache[key] = route_softmax_top_k(
+                x, _master(ectx, self.inputs[1], w), self.top_k)
+        return ectx.cache[key]
+
+    def compute(self, input_vals, ectx):
+        return self.routed(input_vals, ectx)[1]
+
+    def gradient(self, output_grad):
+        packed = _RouterGradientOp(self, output_grad, ctx=self.raw_ctx)
+        return [_Part(packed, self.inputs[0], 0, ctx=self.raw_ctx),
+                _Part(packed, self.inputs[1], 1, ctx=self.raw_ctx)]
+
+    def infer_shape(self, input_shapes):
+        return tuple(input_shapes[0][:-1]) + (self.top_k,)
+
+
+class RouterPicksOp(Op):
+    """The chosen experts' indices ``[..., k]`` int32 of a
+    :class:`RouterOp`. No gradient: a pick is a discrete choice."""
+
+    def __init__(self, router, ctx=None):
+        super().__init__(RouterPicksOp, list(router.inputs), ctx)
+        self.router = router
+
+    def compute(self, input_vals, ectx):
+        return self.router.routed(input_vals, ectx)[0]
+
+    def gradient(self, output_grad):
+        return [None, None]
+
+    def infer_shape(self, input_shapes):
+        return self.router.infer_shape(input_shapes)
+
+
+class _RouterGradientOp(Op):
+    """Packed ``(dx, dw_router)``: the softmax's Jacobian over the
+    chosen ``k``, scattered onto the chosen columns of the ``E`` logits
+    (every other logit's gradient is zero: the indices carry none)."""
+
+    def __init__(self, forward_op, output_grad, ctx=None):
+        super().__init__(_RouterGradientOp,
+                         list(forward_op.inputs) + [output_grad], ctx)
+        self.forward_op = forward_op
+
+    def compute(self, input_vals, ectx):
+        x, w, dweights = input_vals
+        fwd = self.forward_op
+        w = _master(ectx, fwd.inputs[1], w).astype(jnp.float32)
+        experts, weights = fwd.routed([x, w], ectx)
+        dweights = dweights.astype(jnp.float32)
+        dpicked = weights * (dweights - jnp.sum(
+            weights * dweights, axis=-1, keepdims=True))
+        dlogits = jnp.einsum(
+            "...k,...ke->...e", dpicked,
+            jax.nn.one_hot(experts, w.shape[-1], dtype=jnp.float32))
+        flat = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+        dw = jnp.dot(flat.T, dlogits.reshape(-1, w.shape[-1]),
+                     precision=jax.lax.Precision.HIGHEST)
+        dx = jnp.dot(dlogits, w.T, precision=jax.lax.Precision.HIGHEST)
+        return (dx.astype(x.dtype), dw)
+
+    def gradient(self, output_grad):
+        raise NotImplementedError
+
+    def infer_shape(self, input_shapes):
+        return input_shapes[0]
+
+
+def _expert_rows(x, weights, experts, first, held_n, kept=None):
+    """What both directions of :class:`HeldExpertsOp` share: the flat
+    operands and the sort (``kept``: the forward's ``(order, sizes,
+    back)``, where the backward runs in its trace). ``(x [T, hidden],
+    weights [T, k] with 0 for a pair held elsewhere, held [T, k],
+    order, sizes, back, token of each sorted row)``."""
+    k = experts.shape[-1]
+    flat = x.reshape(-1, x.shape[-1])
+    experts = experts.reshape(-1, k)
+    valid = jnp.ones(experts.shape[0], bool)
+    if kept is None:
+        order, sizes, held, rows = _sorted_pairs(experts, valid, held_n,
+                                                 first)
+        # a sorted row's place, by (token, pick)
+        back = jnp.zeros(rows, jnp.int32).at[order[:rows]].set(
+            jnp.arange(rows, dtype=jnp.int32))
+    else:
+        order, sizes, back = kept
+        held = _held_pairs(experts, valid, held_n, first)
+    weights = jnp.where(held, weights.reshape(-1, k), 0.0)
+    return flat, weights, held, order, sizes, back, order // k
+
+
+def _gate_up(h, activation):
+    """``(act(gate), up)`` of a ``[rows, 2 * width]`` product, float32."""
+    width = h.shape[-1] // 2
+    return (ACTIVATIONS[activation](h[:, :width].astype(jnp.float32)),
+            h[:, width:].astype(jnp.float32))
+
+
+class HeldExpertsOp(Op):
+    """:func:`held_experts` as a graph node: ``x [B, S, hidden]``, the
+    router's ``weights`` and ``experts`` ``[B, S, k]``, and the stacked
+    parameters ``w_gate_up [held, hidden, 2 * width]`` / ``w_down [held,
+    width, hidden]`` of the experts ``first .. first + held - 1``.
+    Returns their part of the layer's sum, ``[B, S, hidden]`` in ``x``'s
+    dtype; what the experts held elsewhere would add is left out. No
+    token is dropped under any imbalance.
+
+    A training step counts on the device, in the op's state: rows by
+    held expert, held experts that got a row, steps
+    (``Executor.moe_counters()``; nothing is read inside a step)."""
+
+    stateful = True
+    state_dtype = jnp.int32
+
+    def __init__(self, node_in, weights, experts, w_gate_up, w_down,
+                 first=0, activation="silu", ctx=None):
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"activation {activation!r}: one of "
+                             f"{sorted(ACTIVATIONS)}")
+        super().__init__(HeldExpertsOp,
+                         [node_in, weights, experts, w_gate_up, w_down], ctx)
+        self.first = first
+        self.activation = activation
+
+    def state_shapes(self, input_shapes):
+        return {"moe_rows_by_expert": (input_shapes[3][0],),
+                "moe_expert_visits": (), "steps": ()}
+
+    def compute(self, input_vals, ectx):
+        x, weights, experts, w_gate_up, w_down = input_vals
+        flat, weights, _, order, sizes, back, token = _expert_rows(
+            x, weights, experts, self.first, w_gate_up.shape[0])
+        k = experts.shape[-1]
+        h = grouped_matmul(flat[token], w_gate_up, sizes)
+        gate, up = _gate_up(h, self.activation)
+        ys = grouped_matmul((gate * up).astype(x.dtype), w_down, sizes)
+        out = jnp.einsum("tk,tkh->th", weights,
+                         ys[back].reshape(-1, k, ys.shape[-1])
+                         .astype(jnp.float32))
+        if ectx.training:
+            # the backward reads the sort and the first product again
+            ectx.cache[("held_experts", self.id)] = (order, sizes, back, h)
+            state = ectx.get_state(self)
+            if state is not None:
+                held = sizes[:-1]
+                ectx.put_state(self, {
+                    "moe_rows_by_expert": state["moe_rows_by_expert"] + held,
+                    "moe_expert_visits": state["moe_expert_visits"]
+                    + jnp.sum(held > 0, dtype=jnp.int32),
+                    "steps": state["steps"] + 1})
+        return out.reshape(x.shape).astype(x.dtype)
+
+    def gradient(self, output_grad):
+        packed = _HeldExpertsGradientOp(self, output_grad, ctx=self.raw_ctx)
+        return [_Part(packed, self.inputs[0], 0, ctx=self.raw_ctx),
+                _Part(packed, self.inputs[1], 1, ctx=self.raw_ctx),
+                None,
+                _Part(packed, self.inputs[3], 2, ctx=self.raw_ctx),
+                _Part(packed, self.inputs[4], 3, ctx=self.raw_ctx)]
+
+    def infer_shape(self, input_shapes):
+        return input_shapes[0]
+
+
+class _HeldExpertsGradientOp(Op):
+    """Packed ``(dx, dweights, dw_gate_up, dw_down)``. With the sorted
+    rows ``xs``, ``h = xs W_in[g]``, ``a = act(gate) * up`` and ``dy_r``
+    the output gradient at a sorted row's token:
+
+    * ``da' = dy_r W_down[g]^T`` (a grouped product, rows), the pair's
+      weight gradient ``<da', a>`` and ``da = w_r da'``;
+    * ``dW_down[g] = (w_r a)^T dy_r`` (a transposed grouped product);
+    * ``dh = [da * up * act'(gate), da * act(gate)]``;
+    * ``dW_in[g] = xs^T dh`` (transposed), ``dxs = dh W_in[g]^T`` (rows),
+      and a token's ``dx`` the sum of its pairs' rows.
+
+    The rows of experts held elsewhere are computed by none of the four
+    and come back as zeros."""
+
+    def __init__(self, forward_op, output_grad, ctx=None):
+        super().__init__(_HeldExpertsGradientOp,
+                         list(forward_op.inputs) + [output_grad], ctx)
+        self.forward_op = forward_op
+
+    def compute(self, input_vals, ectx):
+        x, weights, experts, w_gate_up, w_down, dy = input_vals
+        fwd = self.forward_op
+        k = experts.shape[-1]
+        kept = ectx.cache.get(("held_experts", fwd.id))
+        flat, weights, held, order, sizes, back, token = _expert_rows(
+            x, weights, experts, fwd.first, w_gate_up.shape[0],
+            None if kept is None else kept[:3])
+        xs = flat[token]
+        h = grouped_matmul(xs, w_gate_up, sizes) if kept is None \
+            else kept[3]
+        width = h.shape[-1] // 2
+        gate, up = _gate_up(h, fwd.activation)
+        act = gate * up
+        dys = dy.reshape(flat.shape)[token]                 # [rows, hidden]
+        w_row = weights.reshape(-1)[order][:, None]         # pads weigh 0
+        w_row = jnp.where(_computed_rows(order.shape[0], sizes), w_row, 0.0)
+        da = grouped_matmul_rows_grad(dys, w_down, sizes).astype(
+            jnp.float32)                                    # [rows, width]
+        dweights = jnp.sum(da * act, axis=-1)[back].reshape(-1, k)
+        dweights = jnp.where(held, dweights, 0.0).reshape(experts.shape)
+        dw_down = grouped_matmul_weights_grad(
+            (w_row * act).astype(x.dtype), dys, sizes)
+        da = da * w_row
+        _, slope = jax.jvp(ACTIVATIONS[fwd.activation],
+                           (h[:, :width].astype(jnp.float32),),
+                           (jnp.ones_like(gate),))
+        dh = jnp.concatenate([da * up * slope, da * gate],
+                             axis=-1).astype(x.dtype)
+        dw_gate_up = grouped_matmul_weights_grad(xs, dh, sizes)
+        dxs = grouped_matmul_rows_grad(dh, w_gate_up, sizes)
+        dx = jnp.sum(dxs[back].reshape(-1, k, dxs.shape[-1])
+                     .astype(jnp.float32)
+                     * held[:, :, None], axis=1)
+        return (dx.reshape(x.shape).astype(x.dtype),
+                dweights.astype(jnp.float32), dw_gate_up, dw_down)
+
+    def gradient(self, output_grad):
+        raise NotImplementedError
+
+    def infer_shape(self, input_shapes):
+        return input_shapes[0]
+
+
+def router_op(node_in, w_router, top_k, ctx=None):
+    """The ``top_k`` chosen experts' softmax weights ``[..., k]``
+    (float32) of ``node_in [..., hidden]`` under ``w_router [hidden,
+    E]``; see :class:`RouterOp`."""
+    return RouterOp(node_in, w_router, top_k, ctx=ctx)
+
+
+def router_picks_op(router, ctx=None):
+    """The indices ``[..., k]`` int32 a :func:`router_op` node chose."""
+    return RouterPicksOp(router, ctx=ctx)
+
+
+def held_experts_op(node_in, weights, experts, w_gate_up, w_down, first=0,
+                    activation="silu", ctx=None):
+    """The part of an expert layer's sum that the experts ``first ..
+    first + held - 1`` give; see :class:`HeldExpertsOp`."""
+    return HeldExpertsOp(node_in, weights, experts, w_gate_up, w_down,
+                         first, activation, ctx=ctx)

@@ -26,6 +26,7 @@ __all__ = [
     "layer_normalization_gradient_of_scale_op",
     "layer_normalization_gradient_of_bias_op",
     "instance_normalization2d_op", "instance_normalization2d_gradient_op",
+    "rms_normalization_op", "rms_normalization_gradient_op",
 ]
 
 
@@ -156,6 +157,16 @@ class _PackedIndexOp(Op):
 
     def infer_shape(self, input_shapes):
         return input_shapes[1]
+
+
+class PackedPartOp(_PackedIndexOp):
+    """Entry ``idx`` of any packed gradient, shaped like ``like``: what
+    an op without the reference's named unpack ops uses (RMS norm, the
+    router, the held experts)."""
+
+    def __init__(self, packed, like, idx, ctx=None):
+        super().__init__(PackedPartOp, packed, like, ctx=ctx)
+        self.idx = idx
 
 
 class BatchNormalizationGradientOfDataOp(_PackedIndexOp):
@@ -307,6 +318,75 @@ class LayerNormalizationGradientOfBiasOp(_PackedIndexOp):
         return input_vals[0][self.idx].reshape(input_vals[1].shape)
 
 
+def rms_norm_reference(x, scale, eps):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, the
+    statistics and the product in float32, the result in ``x``'s
+    dtype."""
+    xf = x.astype(jnp.float32)
+    inv = jnp.reciprocal(jnp.sqrt(
+        jnp.mean(xf * xf, axis=-1, keepdims=True) + eps))
+    return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+class RMSNormalizationOp(Op):
+    """RMS norm over the last axis: no mean is taken off and there is
+    no bias. Statistics in float32 whatever the stream's dtype (no
+    reference equivalent: the reference's zoo stops at LayerNorm)."""
+
+    def __init__(self, node_in, scale, eps=1e-6, ctx=None):
+        super().__init__(RMSNormalizationOp, [node_in, scale], ctx)
+        self.eps = eps
+
+    def compute(self, input_vals, ectx):
+        x, scale = input_vals
+        return rms_norm_reference(x, scale, self.eps)
+
+    def gradient(self, output_grad):
+        packed = rms_normalization_gradient_op(
+            output_grad, self.inputs[0], self.inputs[1], self.eps,
+            ctx=self.raw_ctx)
+        return [PackedPartOp(packed, node, i, ctx=self.raw_ctx)
+                for i, node in enumerate(self.inputs)]
+
+    def infer_shape(self, input_shapes):
+        return input_shapes[0]
+
+    def infer_range(self, input_ranges, input_shapes=None):
+        n = 1
+        if input_shapes and input_shapes[0]:
+            n = input_shapes[0][-1]
+        return _norm_range(n, input_ranges[1], None)
+
+
+class RMSNormalizationGradientOp(Op):
+    """Packed ``(dx, dscale)`` of :class:`RMSNormalizationOp`, closed
+    form in float32: with ``xhat = x * inv`` and ``g = dy * scale``,
+    ``dx = inv * (g - xhat * mean(g * xhat))`` and ``dscale`` the sum of
+    ``dy * xhat`` over the rows."""
+
+    def __init__(self, out_gradient, in_node, scale, eps, ctx=None):
+        super().__init__(RMSNormalizationGradientOp,
+                         [out_gradient, in_node, scale], ctx)
+        self.eps = eps
+
+    def compute(self, input_vals, ectx):
+        dy, x, scale = input_vals
+        xf, dyf = x.astype(jnp.float32), dy.astype(jnp.float32)
+        inv = jnp.reciprocal(jnp.sqrt(
+            jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps))
+        xhat = xf * inv
+        g = dyf * scale.astype(jnp.float32)
+        dx = inv * (g - xhat * jnp.mean(g * xhat, axis=-1, keepdims=True))
+        dscale = jnp.sum(dyf * xhat, axis=tuple(range(x.ndim - 1)))
+        return (dx.astype(x.dtype), dscale)
+
+    def gradient(self, output_grad):
+        raise NotImplementedError
+
+    def infer_shape(self, input_shapes):
+        return input_shapes[1]
+
+
 class InstanceNormalization2dOp(Op):
     def __init__(self, node_in, eps=0.01, ctx=None):
         super().__init__(InstanceNormalization2dOp, [node_in], ctx)
@@ -409,6 +489,16 @@ def layer_normalization_gradient_of_scale_op(ln_gradient, in_scale,
 
 def layer_normalization_gradient_of_bias_op(ln_gradient, in_bias, ctx=None):
     return LayerNormalizationGradientOfBiasOp(ln_gradient, in_bias, ctx=ctx)
+
+
+def rms_normalization_op(node_in, scale, eps=1e-6, ctx=None):
+    return RMSNormalizationOp(node_in, scale, eps=eps, ctx=ctx)
+
+
+def rms_normalization_gradient_op(out_gradient, in_node, scale, eps,
+                                  ctx=None):
+    return RMSNormalizationGradientOp(out_gradient, in_node, scale, eps,
+                                      ctx=ctx)
 
 
 def instance_normalization2d_op(node_in, eps=0.01, ctx=None):

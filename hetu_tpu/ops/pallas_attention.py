@@ -57,6 +57,19 @@ windows; the context, dq, dk and dv leave as rows and the residuals as
 calls. The kernel bodies are shared (the head's lane window is a static
 parameter) and the arithmetic a head is the same to the bit.
 
+Two things a decoder's token-major call may add (causal, no padding
+mask), in both directions. FEWER KEY/VALUE HEADS than query heads
+(``TokenMajor.kv_heads``; a head a lane block): a program is one query
+head whose k / v index maps name its group's block, so a group's rows
+are read where they lie and nothing is broadcast in HBM; the backward
+writes dk / dv a query head and sums a group's in float32 inside its
+jit. A BAND (``window``): a query at row ``i`` sees the keys ``i -
+window < j <= i``; both kernels walk the same tiles (``tile_walk``,
+``_band_regions``), a tile wholly behind the band is neither loaded nor
+computed and a tile either edge cuts is masked. Such calls are jitted
+under names of their own (``_NAMED_FORWARD`` / ``_NAMED_BACKWARD``), so
+a profile tells them from the equal-heads calls.
+
 Block sizes are ONE static rule in what a call can see
 (``_block_sizes``: kind, S, D, causal, mask), filled from the chip at
 the shapes the benchmark's cells run; with ``causal`` the tiles also
@@ -69,6 +82,7 @@ from __future__ import annotations
 
 import functools
 import math
+import types
 from typing import NamedTuple
 
 import jax
@@ -114,10 +128,24 @@ class TokenMajor(NamedTuple):
     context, dq, dk and dv are ``[B, S, H]`` and the row residuals
     ``[B, heads, 1, S]``, the rows the backward reads (no reshape lies
     between the two kernels: XLA would copy one into other tiles).
-    Hashable: a static argument of the jits."""
+    Hashable: a static argument of the jits.
+
+    ``kv_heads`` (grouped-query attention; causal calls without a
+    padding mask): k and v are ``[B, S, kv_heads * head_dim]``, query
+    head ``h`` reads key/value head ``h // (heads // kv_heads)`` — the
+    k / v index maps alone say so, nothing is broadcast in HBM — and a
+    program is ONE head (a head a lane block: ``head_dim`` whole lane
+    tiles). The backward writes dk and dv a QUERY head and sums a
+    group's inside its jit."""
     heads: int
     head_dim: int
     tiles: tuple = (0, 0, 0)
+    kv_heads: int | None = None
+
+    @property
+    def group(self):
+        """Query heads a key/value head (1: as many of both)."""
+        return self.heads // (self.kv_heads or self.heads)
 
     @property
     def width(self):
@@ -139,8 +167,10 @@ class TokenMajor(NamedTuple):
 
     def fits(self, s):
         """Heads fill whole lane blocks, and the residual rows whole
-        lane tiles."""
+        lane tiles; grouped, a head IS a lane block."""
         d, h = self.head_dim, self.heads * self.head_dim
+        if self.group > 1 and d % LANES:
+            return False
         return s % LANES == 0 and (
             d % LANES == 0 or (LANES % d == 0 and h % LANES == 0))
 
@@ -436,16 +466,18 @@ def _forward(kind, q, k, v, mask, sm_scale, causal, interpret, layout,
         block_k=blocks[1], causal=bool(causal),
         **fwd_walk_counts(h, s, *blocks, causal, layout, window),
         **({} if window is None else {"window": int(window)}))
-    if window is None:
+    grouped = layout is not None and layout.group > 1
+    if window is None and not grouped:
         return _flash_attention_jit(q, k, v, mask, sm_scale, causal,
                                     interpret, *blocks, kind == "fwd_lse",
                                     *_form(layout))
     if not causal or mask is not None:
-        raise ValueError("a window is a band under the diagonal: "
+        raise ValueError("a window is a band under the diagonal, and "
+                         "grouped key/value heads are a decoder's: "
                          "causal=True and no padding mask")
-    return _flash_attention_window_jit(
+    return _NAMED_FORWARD[grouped, window is not None](
         q, k, v, sm_scale, interpret, *blocks, kind == "fwd_lse", layout,
-        int(window))
+        None if window is None else int(window))
 
 
 def flash_attention(q, k, v, mask=None, sm_scale=1.0, causal=False,
@@ -489,13 +521,13 @@ def _band(s, window=None):
 
 def flash_attention_with_lse(q, k, v, mask=None, sm_scale=1.0,
                              causal=False, interpret=None, layout=None,
-                             reason=None):
+                             reason=None, window=None):
     """(output, logsumexp [B, H, S]; token-major [B, H, 1, S]) — the
     pair the fused backward needs.
     Returns (None, None) on shapes the kernel does not support; callers
     then take the composed path for both directions."""
     out = _forward("fwd_lse", q, k, v, mask, sm_scale, causal, interpret,
-                   layout, reason)
+                   layout, reason, window)
     return (None, None) if out is None else out
 
 
@@ -534,10 +566,28 @@ def hetu_flash_window(q, k, v, sm_scale, interpret, block_q, block_k,
                           block_q, block_k, need_lse, layout, window)
 
 
-_flash_attention_window_jit = jax.jit(
-    hetu_flash_window, static_argnames=("sm_scale", "interpret", "block_q",
-                                        "block_k", "need_lse", "layout",
-                                        "window"))
+def _named(name, fn, static):
+    """``fn`` jitted under ``name``: a program's instructions, and so a
+    profile's events, are named for the innermost jitted function (a
+    copy of the function, so the static names still find their
+    positions)."""
+    named = types.FunctionType(fn.__code__, fn.__globals__, name,
+                               fn.__defaults__, fn.__closure__)
+    named.__qualname__ = name
+    return jax.jit(named, static_argnames=static)
+
+
+_WINDOW_STATIC = ("sm_scale", "interpret", "block_q", "block_k", "need_lse",
+                  "layout", "window")
+_flash_attention_window_jit = jax.jit(hetu_flash_window,
+                                      static_argnames=_WINDOW_STATIC)
+# (grouped key/value heads, a band) -> the forward under its event name
+_NAMED_FORWARD = {
+    (False, True): _flash_attention_window_jit,
+    (True, False): _named("hetu_flash_gqa_fwd", hetu_flash_window,
+                          _WINDOW_STATIC),
+    (True, True): _named("hetu_flash_gqa_window_fwd", hetu_flash_window,
+                         _WINDOW_STATIC)}
 
 
 def _flash_forward(q, k, v, mask, sm_scale, causal, interpret,
@@ -579,6 +629,12 @@ def _flash_forward(q, k, v, mask, sm_scale, causal, interpret,
                          lambda bi, p, qi: (bi, qi, tq + p)),
             pl.BlockSpec((1, s, lanes), lambda bi, p, qi: (bi, 0, tk + p)),
             pl.BlockSpec((1, s, lanes), lambda bi, p, qi: (bi, 0, tv + p))]
+        if layout.group > 1:  # jit-ok: static argname
+            # a group's query heads (one a program) read ONE k / v block
+            in_specs[1:] = [
+                pl.BlockSpec((1, s, lanes), lambda bi, p, qi, t=t:
+                             (bi, 0, t + p // layout.group))
+                for t in (tk, tv)]
         mask_spec = pl.BlockSpec((1, 1, s), lambda bi, p, qi: (bi, 0, 0))
         o_shape = jax.ShapeDtypeStruct((b, s, h * d), q.dtype)
         o_spec = pl.BlockSpec((1, span, lanes),
@@ -764,9 +820,11 @@ def heads_per_program(h, s, block_q, block_k, layout=None):
     row's blocks and the q / k / v offsets (so the three index maps
     stay whole): all twelve heads of a BERT-base batch row at S = 128,
     256 programs a layer; ONE lane block at S = 1024, whose two heads
-    alone are past the row bound."""
+    alone are past the row bound. Grouped key/value heads: one head (its
+    k / v block is its group's, which its neighbour may not share)."""
     unit = 1 if layout is None else layout.per_block
-    if _region_span(s, block_q, block_k) != s:
+    if _region_span(s, block_q, block_k) != s or (
+            layout is not None and layout.group > 1):
         return unit
     pairs = (s // block_q) * (s // block_k)
     units = h if layout is None else math.gcd(layout.blocks, *layout.tiles)
@@ -790,7 +848,7 @@ def fwd_walk_counts(h, s, block_q, block_k, causal, layout=None,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
                 dq_ref, dk_ref, dv_ref, *acc, sm_scale, block_q, block_k,
-                span, seq_len, causal, head_dim=None):
+                span, seq_len, causal, head_dim=None, window=None):
     """One region row's program: K / V rows ``[kj*span, (kj+1)*span)``
     stay resident and the program walks the q-regions the diagonal
     leaves them — the region ON the diagonal first (its tile pairs
@@ -811,7 +869,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
     (``heads_per_program``): each head makes that same walk over its
     own lane window, with its own residual rows and its own three
     accumulators — where a head is one pair, twelve heads of
-    straight-line code a program."""
+    straight-line code a program.
+
+    With a ``window`` (causal only) the program walks the tiles the
+    forward walks, seen from the keys' side: the diagonal's region
+    (``tile_walk`` with the band: a pair wholly behind it is neither
+    loaded nor computed, a pair either edge cuts is masked), the
+    regions below it that lie wholly inside the band by the loop, then
+    the one or two the band's far edge cuts (``_band_regions``), each
+    at its static distance and skipped by a branch where the sequence
+    ends before it; a q-region wholly behind ``i - window`` is not
+    run."""
     region_axis = 1 if head_dim is None else 2
     kj = pl.program_id(region_axis)
     nt = (((1,), (1,)), ((), ()))             # a @ b^T
@@ -831,24 +899,37 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
             def _():
                 dq_acc[...] = jnp.zeros_like(dq_acc)
 
-        def region(qi, diagonal, first):
+        def region(qi, diagonal, first, offset=None):
+            """``offset``: the rows this region's q rows lie below its
+            keys' diagonal where the band's far edge cuts it, else
+            None."""
             q0 = 0 if whole else qi * span   # static where it can be
             rows = [pl.ds(q0 + i * block_q if isinstance(q0, int) else
                           pl.multiple_of(q0 + i * block_q, block_q),
                           block_q)
                     for i in range(span // block_q)]
             dqt = [0.0] * len(rows)
+            # a region either edge of the band cuts: its pairs by the
+            # forward's own walk
+            cut = window is not None and (diagonal or offset is not None)
+            if cut:
+                below = offset or 0
+                visited, masked = tile_walk(span, block_q, block_k, True,
+                                            window, below)
             for j in range(span // block_k):
                 keys = slice(j * block_k, (j + 1) * block_k)
                 k = k_ref[0, keys, lanes]         # [block_k, d]
                 v = v_ref[0, keys, lanes]
                 kt = k.T
                 begin, unmasked = 0, 0
-                if diagonal:  # q0 is the keys' own offset: local indices
+                if diagonal and not cut:
+                    # q0 is the keys' own offset: local indices
                     begin = _first_q_tile(j, block_q, block_k)
                     unmasked = _first_unmasked_q_tile(j, block_q, block_k)
                 dk = dv = 0.0
                 for i in range(begin, len(rows)):
+                    if cut and (i, j) not in visited:
+                        continue
                     q = q_ref[0, rows[i], lanes]  # [block_q, d]
                     do = do_ref[0, rows[i], lanes]
                     st = jax.lax.dot_general(
@@ -856,7 +937,23 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
                         preferred_element_type=jnp.float32) * sm_scale
                     if mask_ref is not None:
                         st = st + mask_ref[0, keys, :]    # [block_k, 1]
-                    if i < unmasked:
+                    if cut and (i, j) in masked:
+                        # row - key of the transposed tile's entries
+                        ahead = (i * block_q + below - j * block_k) \
+                            + jax.lax.broadcasted_iota(
+                                jnp.int32, (block_k, block_q), 1) \
+                            - jax.lax.broadcasted_iota(
+                                jnp.int32, (block_k, block_q), 0)
+                        cuts = _band_cuts(i, j, block_q, block_k, below,
+                                          window)
+                        kept = None
+                        if "diagonal" in cuts:
+                            kept = ahead >= 0
+                        if "edge" in cuts:
+                            edge = ahead < window
+                            kept = edge if kept is None else kept & edge
+                        st = jnp.where(kept, st, NEG_INF)
+                    elif i < unmasked:
                         k_pos = j * block_k + jax.lax.broadcasted_iota(
                             jnp.int32, (block_k, block_q), 0)
                         q_pos = i * block_q + jax.lax.broadcasted_iota(
@@ -879,6 +976,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
                         dst, q, nn, preferred_element_type=jnp.float32)
                     dqt[i] = dqt[i] + jax.lax.dot_general(
                         kt, dst, nn, preferred_element_type=jnp.float32)
+                if isinstance(dk, float):   # the band left the tile out
+                    if not (whole or first):
+                        continue
+                    dk = dv = jnp.zeros(k.shape, jnp.float32)
                 if whole:
                     dk_ref[0, keys, lanes] = dk.astype(dk_ref.dtype)
                     dv_ref[0, keys, lanes] = dv.astype(dv_ref.dtype)
@@ -889,6 +990,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
                     dk_acc[keys, :] += dk
                     dv_acc[keys, :] += dv
             for i, dq in enumerate(dqt):
+                if isinstance(dq, float):   # no pair of the tile was run
+                    if not whole:
+                        continue
+                    dq = jnp.zeros((k_ref.shape[-1] if head_dim is None
+                                    else head_dim, block_q), jnp.float32)
                 if whole:
                     dq_ref[0, rows[i], lanes] = dq.T.astype(dq_ref.dtype)
                 else:
@@ -905,8 +1011,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, mask_ref,
             region(qi, diagonal=False, first=False)
             return carry
 
-        jax.lax.fori_loop(kj + 1 if causal else 1, seq_len // span,
-                          body, 0)
+        regions = seq_len // span
+        if window is None:
+            jax.lax.fori_loop(kj + 1 if causal else 1, regions, body, 0)
+        else:
+            inside, edges = _band_regions(seq_len, span, window)
+            jax.lax.fori_loop(kj + 1,
+                              jnp.minimum(kj + inside + 1, regions),
+                              body, 0)
+            for e in edges:
+                @pl.when(kj + e < regions)
+                def _(e=e):
+                    region(kj + e, diagonal=False, first=False,
+                           offset=e * span)
         dk_ref[0, :, lanes] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, :, lanes] = dv_acc[...].astype(dv_ref.dtype)
 
@@ -943,8 +1060,34 @@ def _backward_compiler_params(s, d, span, block_q, block_k, itemsize,
                                              "block_k", "layout"))
 def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
                              interpret, block_q, block_k, layout=None):
+    return _flash_backward(q, k, v, mask, o, lse, do, sm_scale, causal,
+                           interpret, block_q, block_k, layout)
+
+
+def _banded_backward(q, k, v, o, lse, do, sm_scale, interpret, block_q,
+                     block_k, layout, window):
+    return _flash_backward(q, k, v, None, o, lse, do, sm_scale, True,
+                           interpret, block_q, block_k, layout, window)
+
+
+_BANDED_STATIC = ("sm_scale", "interpret", "block_q", "block_k", "layout",
+                  "window")
+# (grouped key/value heads, a band) -> the backward under its event name
+_NAMED_BACKWARD = {
+    (False, True): _named("hetu_flash_window_bwd", _banded_backward,
+                          _BANDED_STATIC),
+    (True, False): _named("hetu_flash_gqa_bwd", _banded_backward,
+                          _BANDED_STATIC),
+    (True, True): _named("hetu_flash_gqa_window_bwd", _banded_backward,
+                         _BANDED_STATIC)}
+
+
+def _flash_backward(q, k, v, mask, o, lse, do, sm_scale, causal, interpret,
+                    block_q, block_k, layout=None, window=None):
     """``layout`` as :func:`_flash_attention_jit` takes it; token-major
-    ``o`` and ``do`` are ``[B, S, H]`` and so are dq, dk and dv."""
+    ``o`` and ``do`` are ``[B, S, H]`` and so are dq, dk and dv (grouped:
+    dk and dv ``[B, S, kv_heads * head_dim]``, a group's query heads
+    summed in float32)."""
     b, h, s, d = _dims(q, layout)
     span = _region_span(s, block_q, block_k)
 
@@ -1009,6 +1152,13 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
                     pl.BlockSpec((1, span, lanes), keys(tk)),
                     pl.BlockSpec((1, span, lanes), keys(tv)),
                     pl.BlockSpec((1, s, lanes), head(0))]
+        if layout.group > 1:  # jit-ok: static argname
+            # a program a QUERY head: it reads its group's k / v rows
+            # and writes its own dk / dv
+            in_specs[1:3] = [
+                pl.BlockSpec((1, span, lanes), lambda bi, p, kj, t=t:
+                             (bi, kj, t + p // layout.group))
+                for t in (tk, tv)]
         mask_spec = pl.BlockSpec((1, span, 1),
                                  lambda bi, p, kj: (bi, kj, 0))
         out_shape = [jax.ShapeDtypeStruct((b, s, h * d), x.dtype)
@@ -1019,7 +1169,9 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
     body = functools.partial(_bwd_kernel, sm_scale=sm_scale,
                              block_q=block_q, block_k=block_k, span=span,
                              seq_len=s, causal=causal,
-                             head_dim=None if layout is None else d)
+                             head_dim=None if layout is None else d,
+                             **({} if window is None
+                                else {"window": window}))
     args += resids
     in_specs += [resid] * len(resids)
     # of the kernel's optional inputs: D (5), the mask (6)
@@ -1055,6 +1207,11 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
             len(grid)),
         interpret=interpret,
     )(*args)
+    if layout is not None and layout.group > 1:  # jit-ok: static argname
+        dk, dv = (x.astype(jnp.float32).reshape(
+            b, s, layout.kv_heads, layout.group, d).sum(axis=3).reshape(
+                b, s, layout.kv_heads * d).astype(x.dtype)
+            for x in (dk, dv))
     if layout is not None:  # jit-ok: static argname
         return dq, dk, dv
     shape = (b, h, s, d)
@@ -1063,7 +1220,7 @@ def _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale, causal,
 
 def flash_attention_bwd(q, k, v, mask, o, lse, do, sm_scale=1.0,
                         causal=False, interpret=None, layout=None,
-                        reason=None):
+                        reason=None, window=None):
     """(dq, dk, dv) via the fused recompute-form kernel, in the operand
     form of q, k and v. ``lse`` is the forward's logsumexp
     (flash_attention_with_lse). Block sizes tune independently of the
@@ -1080,7 +1237,17 @@ def flash_attention_bwd(q, k, v, mask, o, lse, do, sm_scale=1.0,
         block_k=block_k, causal=bool(causal),
         heads_per_program=1 if layout is None else heads_per_program(
             h, s, block_q, block_k, layout),
-        **tile_walk_counts(s, block_q, block_k, causal))
-    return _flash_attention_bwd_jit(q, k, v, mask, o, lse, do, sm_scale,
-                                    causal, interpret, block_q, block_k,
-                                    *_form(layout))
+        **tile_walk_counts(s, block_q, block_k, causal, window),
+        **({} if window is None else {"window": int(window)}))
+    grouped = layout is not None and layout.group > 1
+    if window is None and not grouped:
+        return _flash_attention_bwd_jit(q, k, v, mask, o, lse, do,
+                                        sm_scale, causal, interpret,
+                                        block_q, block_k, *_form(layout))
+    if not causal or mask is not None:
+        raise ValueError("a window is a band under the diagonal, and "
+                         "grouped key/value heads are a decoder's: "
+                         "causal=True and no padding mask")
+    return _NAMED_BACKWARD[grouped, window is not None](
+        q, k, v, o, lse, do, sm_scale, interpret, block_q, block_k, layout,
+        None if window is None else int(window))

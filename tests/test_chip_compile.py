@@ -827,3 +827,149 @@ def test_gpt_serving_program_streams_its_matrices_and_aliases_its_pools(
             compiled.as_text())
         assert sorted(set(streamed)) == ["fc", "mlp_proj", "proj", "qkv"]
         assert len(streamed) >= 4 * 4 * 11
+
+
+# -- the sparse-expert decoder's training kernels (PR 50) --------------------
+
+def _gqa_call(kind, window, one_chip, s=8192, heads=28, groups=4, d=128):
+    """The compiled text of one grouped-query flash call at the
+    published widths of the train cell ``smallthinker21b-train-s8192``
+    (28 query heads on 4 key/value heads of 128, one 8,192-token
+    sequence), token-major, at the rule's tiles."""
+    lay = pk.TokenMajor(heads, d, kv_heads=groups)
+    assert lay.fits(s)
+
+    def sds(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1, s, width), dtype, sharding=one_chip)
+
+    q, k = sds(heads * d), sds(groups * d)
+    if kind == "bwd":
+        lse = jax.ShapeDtypeStruct((1, heads, 1, s), jnp.float32,
+                                   sharding=one_chip)
+        fn = lambda q, k, v, o, lse, do: pk.flash_attention_bwd(  # noqa: E731
+            q, k, v, None, o, lse, do, d ** -0.5, True, False, lay,
+            window=window)
+        return jax.jit(fn).lower(q, k, k, q, lse, q).compile().as_text()
+    fn = lambda q, k, v: pk.flash_attention_with_lse(  # noqa: E731
+        q, k, v, None, d ** -0.5, True, False, lay, window=window)
+    return jax.jit(fn).lower(q, k, k).compile().as_text()
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "global"])
+@pytest.mark.parametrize("kind", ["fwd_lse", "bwd"])
+def test_grouped_query_kernels_compile_at_the_sparse_decoders_cell(
+        one_chip, kind, window):
+    """S = 8,192 is eight regions of 1,024 a side: both kernels walk
+    them by their loops, the band's edge regions behind a branch, q,
+    dO and the float32 dQ^T of a head resident in the backward (the
+    VMEM it asks for is under the 100 MiB bound), a head a program."""
+    blocks = pk._block_sizes(8192, 128, "bwd" if kind == "bwd" else
+                             "fwd_lse", True)
+    assert pk._region_span(8192, *blocks) == 1024
+    assert pk._band_regions(8192, 1024, 4096) == (3, [4])
+    text = _gqa_call(kind, window, one_chip)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    name = "hetu_flash_gqa_" + ("window_" if window else "") \
+        + ("bwd" if kind == "bwd" else "fwd")
+    assert name in text
+    if kind == "bwd":       # dk / dv of a group: summed over its 7 heads
+        assert "bf16[1,8192,512]" in text
+
+
+def test_the_banded_walk_leaves_out_a_quarter_of_the_pairs():
+    """At S = 8,192 under a window of 4,096 the walk visits 408 of the
+    diagonal's 528 tiles of 256 x 256 (25.2M of 33.6M pairs a head)."""
+    whole = pk.tile_walk_counts(8192, 256, 256, True)
+    band = pk.tile_walk_counts(8192, 256, 256, True, 4096)
+    assert whole["tiles_visited"] == 32 * 33 // 2 == 528
+    assert band["tiles_visited"] == 16 * 17 // 2 + 16 * 17 == 408
+    assert band["tiles_masked"] == 32 + 16
+
+
+@pytest.mark.parametrize("which", ["forward", "rows", "weights"])
+def test_expert_products_compile_at_the_sparse_decoders_cell(one_chip,
+                                                              which):
+    """The three grouped products of a held-expert layer's training
+    step at the cell's widths: 8,192 x 6 sorted rows, 16 experts held,
+    hidden 2,560, gate|up 1,536 wide — the forward kernel, the same with
+    its right side transposed (``hetu_moe_experts_dx``) and megablox's
+    ``tgmm`` (``hetu_moe_experts_dw``)."""
+    from hetu_tpu.ops import moe
+    rows, hidden, wide, held = 8192 * 6, 2560, 1536, 16
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    sizes = sds((held + 1,), jnp.int32)
+    xs, h, w = sds((rows, hidden)), sds((rows, wide)), \
+        sds((held, hidden, wide))
+    if which == "forward":
+        fn = moe._kernel(moe._kernel_tiles(rows, hidden, wide),
+                         jnp.dtype(jnp.bfloat16), False)
+        text, name = fn.lower(xs, w, sizes).compile().as_text(), \
+            moe.KERNEL_NAME
+    elif which == "rows":
+        fn = moe._grad_kernel("rows", moe._kernel_tiles(rows, wide, hidden),
+                              jnp.dtype(jnp.bfloat16), False)
+        text, name = fn.lower(h, w, sizes).compile().as_text(), \
+            moe.ROWS_GRAD_KERNEL_NAME
+        assert "bf16[49152,2560]" in text
+    else:
+        fn = moe._grad_kernel("weights",
+                              moe._kernel_tiles(rows, hidden, wide),
+                              jnp.dtype(jnp.float32), False, held)
+        text, name = fn.lower(xs, h, sizes).compile().as_text(), \
+            moe.WEIGHTS_GRAD_KERNEL_NAME
+        assert "f32[16,2560,1536]" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert name in text
+
+
+def test_sparse_decoder_step_holds_the_banded_grouped_backward(
+        v5e, monkeypatch):
+    """One WINDOW layer of the sparse-expert decoder at the published
+    widths and S = 8,192 (2 experts held and 1,024 rows of vocabulary,
+    to keep the compile short), as the executor compiles a training
+    step: the step holds the banded grouped-query forward and backward
+    and the experts' three kinds of grouped product (two of each),
+    eight kernels."""
+    import numpy as np
+    import hetu_tpu as ht
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.executor import TPU_TRAIN_STEP_OPTIONS
+    from hetu_tpu.models import SparseDecoderConfig, \
+        SparseDecoderLMHeadModel
+    from hetu_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    model = SparseDecoderLMHeadModel(SparseDecoderConfig(
+        vocab_size=1024, hidden_size=2560, num_attention_heads=28,
+        num_key_value_heads=4, head_dim=128, window_layout=[1],
+        rope_layout=[1], sliding_window=4096, moe_ffn_hidden_size=768,
+        num_experts=64, num_experts_per_tok=6, experts_held=(0, 2),
+        rope_theta=1.5e6))
+    ids = ht.Variable("input_ids", trainable=False)
+    labels = ht.Variable("labels", trainable=False)
+    _, loss = model(ids, labels, seq_len=8192)
+    lm_loss = ht.reduce_mean_op(loss, [0, 1])
+    train_op = ht.optim.AdamOptimizer(1e-4).minimize(lm_loss)
+    executor = ht.Executor([lm_loss, train_op], dtype=jnp.bfloat16,
+                           ctx=ht.cpu(0))
+    sub = executor.subexecutors["default"]
+    feed = {ids: np.zeros((1, 8192), np.int32),
+            labels: np.zeros((1, 8192), np.int32)}
+    step = sub.prepare(executor, feed)
+    sharding = SingleDeviceSharding(v5e[0])
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            np.shape(a), a.dtype if hasattr(a, "dtype")
+            else np.asarray(a).dtype, sharding=sharding),
+        sub.trace_args(executor, feed))
+    text = jax.jit(step, donate_argnums=(0, 1, 2, 3)).lower(
+        *shapes).compile(compiler_options=TPU_TRAIN_STEP_OPTIONS).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    for name in ("hetu_flash_gqa_window_fwd", "hetu_flash_gqa_window_bwd",
+                 "hetu_moe_experts_dx", "hetu_moe_experts_dw"):
+        assert name in text, name
+    # a group's dk / dv leave the backward summed over its 7 heads
+    assert "bf16[1,8192,512]" in text

@@ -301,3 +301,27 @@ def test_stream_non_ps_matches_run_batches():
     np.testing.assert_allclose(got, want, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(exe2.params[str(w2.id)]),
                                want_w, rtol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [None, "bfloat16"], ids=["f32", "bf16"])
+def test_evaluation_block_matches_sequential(dtype):
+    """A block of an INFERENCE subgraph: the step hands no tree back, so
+    the scan carries none; the losses are the sequential ones and the
+    parameters are left as they were."""
+    import jax.numpy as jnp
+    rng = np.random.RandomState(1)
+    data = _batches(rng, 5)
+    x, y_, loss, train = _mlp()
+    kw = {} if dtype is None else {"dtype": getattr(jnp, dtype)}
+    exe = Executor({"train": [loss, train], "validate": [loss]}, **kw)
+    feeds = [{x: d["x"], y_: d["y"]} for d in data]
+    exe.run("train", feed_dict=feeds[0])
+    before = {sid: np.asarray(v) for sid, v in exe.params.items()}
+    want = [float(exe.run("validate", feed_dict=fd,
+                          convert_to_numpy_ret_vals=True)[0])
+            for fd in feeds]
+    got = [float(r[0]) for r in exe.run_batches(
+        feeds, "validate", convert_to_numpy_ret_vals=True)]
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for sid, v in exe.params.items():
+        np.testing.assert_array_equal(np.asarray(v), before[sid])

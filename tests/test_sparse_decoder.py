@@ -1,0 +1,640 @@
+"""The graph ops a sparse-expert decoder trains through
+(``rms_normalization_op``, ``rotary_op``, ``flash_attention_op`` with
+fewer key/value heads and a window, ``router_op``, ``held_experts_op``)
+and the model built from them (``hetu_tpu/models/sparse_decoder.py``):
+every op's value and every gradient through ``ht.Executor`` against
+``jax.grad`` of plain ``jax.numpy`` written out here, in float32 at
+small widths that keep the shape of the thing (8 query heads on 2
+key/value heads, and a group of 7; window 8 at S = 32; 8 experts top-3
+with 4 held from the third on); the banded grouped-query kernels
+interpreted against a masked dense attention at an S that has tiles
+wholly behind the band; the whole graph's loss, scores and every
+parameter's gradient against ``benchmark/reference/smallthinker_moe.py``;
+two Adam steps; the device counter against a count by hand; and the
+share test: the four shares' expert sums add up to the uncut layer's
+and the four vocabulary slices' logits concatenate to the whole head's.
+"""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import hetu_tpu as ht
+from hetu_tpu.models import SparseDecoderLMHeadModel
+from hetu_tpu.ops import attention, moe
+from hetu_tpu.ops import pallas_attention as pk
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmark.families.smallthinker_moe import model_config  # noqa: E402
+from benchmark.reference import smallthinker_moe as reference  # noqa: E402
+
+S, VOCAB, HIDDEN, WIDTH = 32, 96, 64, 32
+EXPERTS, TOP_K = 8, 3
+
+
+def run_graph(outputs, feeds):
+    """The values of ``outputs`` (graph nodes) under ``feeds`` ({node:
+    array}) through one float32 ``ht.Executor``."""
+    ex = ht.Executor(list(outputs))
+    return [np.asarray(o.asnumpy()) for o in ex.run(feed_dict=feeds)]
+
+
+def op_and_grads(build, arrays, upstream):
+    """``build(*nodes) -> node``: the op's value and the gradients of
+    ``sum(value * upstream)`` to every float input, through the graph's
+    own gradient ops (``ht.gradients`` with ``upstream`` as the seed)."""
+    nodes = [ht.Variable(f"in{i}", trainable=False,
+                         dtype=np.asarray(a).dtype.type)
+             for i, a in enumerate(arrays)]
+    seed = ht.Variable("seed", trainable=False)
+    out = build(*nodes)
+    wrt = [n for n, a in zip(nodes, arrays)
+           if np.issubdtype(np.asarray(a).dtype, np.floating)]
+    grads = ht.gradients(out, wrt, insert_grad=seed)
+    feeds = dict(zip(nodes, arrays))
+    feeds[seed] = upstream
+    got = run_graph([out] + grads, feeds)
+    return got[0], got[1:]
+
+
+def plain_and_grads(fn, arrays, upstream):
+    floats = [i for i, a in enumerate(arrays)
+              if np.issubdtype(np.asarray(a).dtype, np.floating)]
+    arrays = [jnp.asarray(a) for a in arrays]
+
+    def scalar(*fl):
+        full = list(arrays)
+        for i, a in zip(floats, fl):
+            full[i] = a
+        return jnp.sum(fn(*full) * upstream)
+
+    value = fn(*arrays)
+    grads = jax.grad(scalar, argnums=tuple(range(len(floats))))(
+        *[arrays[i] for i in floats])
+    return np.asarray(value), [np.asarray(g) for g in grads]
+
+
+def close(got, want, tol=2e-5):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape
+    scale = max(float(np.abs(want).max()), 1e-6)
+    assert float(np.abs(got - want).max()) <= tol * scale, \
+        (float(np.abs(got - want).max()), scale)
+
+
+def check(build, plain, arrays, seed=0):
+    shape = jax.eval_shape(plain, *arrays).shape
+    upstream = np.random.RandomState(seed).randn(*shape).astype(np.float32)
+    want, want_grads = plain_and_grads(plain, arrays, upstream)
+    got, got_grads = op_and_grads(build, arrays, upstream)
+    close(got, want)
+    assert len(got_grads) == len(want_grads)
+    for g, w in zip(got_grads, want_grads):
+        close(g, w)
+
+
+# -- RMS norm and rotary positions -------------------------------------------
+
+@pytest.mark.parametrize("shape", [(2, S, HIDDEN), (24, 40)])
+def test_rms_norm_value_and_gradients(shape):
+    rng = np.random.RandomState(1)
+    x = rng.randn(*shape).astype(np.float32)
+    scale = (1.0 + 0.3 * rng.randn(shape[-1])).astype(np.float32)
+
+    def plain(x, scale):
+        return x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) \
+            * scale
+
+    check(lambda x, s: ht.rms_normalization_op(x, s, eps=1e-6), plain,
+          [x, scale])
+
+
+def _plain_rotary(x, heads, theta):
+    b, s, width = x.shape
+    d = width // heads
+    x = x.reshape(b, s, heads, d)
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None]
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    lo, hi = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                           -1).reshape(b, s, width)
+
+
+@pytest.mark.parametrize("heads", [8, 2])
+def test_rotary_value_and_gradient(heads):
+    x = np.random.RandomState(2).randn(2, S, heads * 16).astype(np.float32)
+    check(lambda x: ht.rotary_op(x, heads, 1.5e6),
+          lambda x: _plain_rotary(x, heads, 1.5e6), [x])
+
+
+def test_rotary_turns_a_pair_by_its_position():
+    """Dimension i pairs with i + D/2; position 0 is not turned."""
+    x = np.zeros((1, 4, 8), np.float32)
+    x[..., 0] = 1.0
+    (got,) = run_graph(
+        [ht.rotary_op(v := ht.Variable("x", trainable=False), 1, 10000.0)],
+        {v: x})
+    np.testing.assert_allclose(got[0, 0], x[0, 0], atol=1e-7)
+    np.testing.assert_allclose(got[0, 3, 0], np.cos(3.0), atol=1e-6)
+    np.testing.assert_allclose(got[0, 3, 4], np.sin(3.0), atol=1e-6)
+
+
+# -- the router --------------------------------------------------------------
+
+def _plain_router(x, w, k):
+    logits = jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
+    picked, experts = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(picked, -1), experts
+
+
+def test_router_weights_picks_and_gradients():
+    rng = np.random.RandomState(3)
+    x = rng.randn(2, S, HIDDEN).astype(np.float32)
+    w = (0.3 * rng.randn(HIDDEN, EXPERTS)).astype(np.float32)
+    check(lambda x, w: ht.router_op(x, w, TOP_K),
+          lambda x, w: _plain_router(x, w, TOP_K)[0], [x, w])
+    xn, wn = (ht.Variable(n, trainable=False) for n in ("x", "w"))
+    router = ht.router_op(xn, wn, TOP_K)
+    weights, picks = run_graph([router, ht.router_picks_op(router)],
+                               {xn: x, wn: w})
+    want_w, want_p = _plain_router(jnp.asarray(x), jnp.asarray(w), TOP_K)
+    assert picks.dtype == np.int32
+    np.testing.assert_array_equal(picks, np.asarray(want_p))
+    np.testing.assert_allclose(weights.sum(-1), 1.0, atol=1e-6)
+    # the same numbers as a softmax over all 64 renormalised over the k
+    full = jax.nn.softmax(jnp.asarray(x) @ jnp.asarray(w), -1)
+    chosen = jnp.take_along_axis(full, want_p, -1)
+    close(weights, chosen / chosen.sum(-1, keepdims=True))
+
+
+def test_router_reads_the_float32_master_under_mixed_precision():
+    """A bfloat16 executor: the logits come from the float32 master of
+    the router's weights, not from their bfloat16 working copy."""
+    rng = np.random.RandomState(4)
+    x = rng.randn(1, S, HIDDEN).astype(np.float32)
+    w = ht.Variable("router_w", value=(0.3 * rng.randn(
+        HIDDEN, EXPERTS)).astype(np.float32))
+    xn = ht.Variable("x", trainable=False)
+    router = ht.router_op(xn, w, TOP_K)
+    ex = ht.Executor([router, ht.router_picks_op(router)],
+                     dtype=jnp.bfloat16)
+    weights, picks = (np.asarray(o.asnumpy())
+                      for o in ex.run(feed_dict={xn: x}))
+    held = jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32)
+    want_w, want_p = _plain_router(held, jnp.asarray(w.tensor_value), TOP_K)
+    assert weights.dtype == np.float32
+    np.testing.assert_array_equal(picks, np.asarray(want_p))
+    close(weights, want_w, 1e-6)
+
+
+# -- attention: fewer key/value heads, a band --------------------------------
+
+def _dense_attention(q, k, v, heads, groups, window):
+    b, s, _ = q.shape
+    d = q.shape[-1] // heads
+    q4 = q.reshape(b, s, heads, d)
+    k4, v4 = (jnp.repeat(t.reshape(b, s, groups, d), heads // groups, 2)
+              for t in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q4, k4) / np.sqrt(d)
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = j <= i if window is None else (j <= i) & (i - j < window)
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v4).reshape(b, s, -1)
+
+
+def _qkv(b, s, heads, groups, d, seed=5):
+    rng = np.random.RandomState(seed)
+    return [rng.randn(b, s, n * d).astype(np.float32)
+            for n in (heads, groups, groups)]
+
+
+@pytest.mark.parametrize("heads,groups", [(8, 2), (7, 1), (4, 4)])
+@pytest.mark.parametrize("window", [8, None])
+def test_grouped_window_attention_value_and_gradients(heads, groups, window):
+    arrays = _qkv(2, S, heads, groups, 16)
+    check(lambda q, k, v: ht.flash_attention_op(
+        q, k, v, sm_scale=0.25, causal=True, num_heads=heads,
+        num_kv_heads=groups, window=window),
+        lambda q, k, v: _dense_attention(q, k, v, heads, groups, window),
+        arrays)
+
+
+def test_grouped_or_banded_attention_refuses_other_forms():
+    q = ht.Variable("q", trainable=False)
+    with pytest.raises(ValueError, match="causal"):
+        ht.flash_attention_op(q, q, q, num_heads=8, num_kv_heads=2)
+    with pytest.raises(ValueError, match="causal"):
+        ht.flash_attention_op(q, num_heads=8, causal=True, window=8)
+    with pytest.raises(ValueError, match="split"):
+        ht.flash_attention_op(q, q, q, num_heads=8, num_kv_heads=3,
+                              causal=True)
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """Both kinds of kernel interpreted, chosen as on a TPU."""
+    monkeypatch.setattr(pk, "INTERPRET", True)
+    monkeypatch.setattr(moe, "INTERPRET", True)
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+
+
+class _Training:
+    """What an op's ``compute`` needs of a training step's context."""
+    training = True
+    config = None
+    master_params = None
+
+    def __init__(self):
+        self.cache = {}
+
+    def get_state(self, key, default=None):
+        return default
+
+
+# S = 1024 at tiles of 256: with a window of 300 the tiles (2, 0), (3, 0)
+# and (3, 1) lie wholly behind the band. ``rows`` bounds a region's side:
+# 256 makes four regions a side, so the loop, the edge regions and their
+# branches all run (window 512 = two regions is the cell's own ratio).
+@pytest.mark.parametrize("heads,groups,window,rows", [
+    (4, 2, 300, None), (7, 1, None, None), (4, 2, 300, 256),
+    (4, 2, 512, 256), (2, 2, 300, 256)])
+def test_banded_grouped_kernels_against_masked_dense_attention(
+        kernels, monkeypatch, heads, groups, window, rows):
+    if rows:
+        monkeypatch.setattr(pk, "_REGION_ROWS", rows)
+    s, d = 1024, 128
+    q, k, v = (jnp.asarray(a) for a in _qkv(1, s, heads, groups, d, 6))
+    dy = jnp.asarray(np.random.RandomState(7).randn(1, s, heads * d),
+                     jnp.float32)
+    walk = pk.tile_walk_counts(s, 256, 256, True, window)
+    if window:
+        assert walk["tiles_visited"] < 10       # of the diagonal's ten
+    op = ht.flash_attention_op(
+        *(ht.Variable(n, trainable=False) for n in "qkv"),
+        sm_scale=d ** -0.5, causal=True, num_heads=heads,
+        num_kv_heads=groups, window=window)
+    grad_ops = op.gradient(ht.Variable("dy", trainable=False))
+    ectx = _Training()
+    out = op.compute([q, k, v], ectx)
+    assert ("flash_res", op.id) in ectx.cache       # the fused backward
+    grads = [g.compute([q, k, v, dy], ectx) for g in grad_ops]
+    want, vjp = jax.vjp(lambda q, k, v: _dense_attention(
+        q, k, v, heads, groups, window), q, k, v)
+    close(out, want, 1e-5)
+    for got, wanted in zip(grads, vjp(dy)):
+        assert got.shape == wanted.shape
+        close(got, wanted, 1e-5)
+
+
+def test_kernel_events_carry_their_own_names(kernels):
+    """One name for the grouped-query forward and one for its backward,
+    with the band and without; the equal-heads calls keep theirs."""
+    def names(heads, groups, window):
+        lay = pk.TokenMajor(heads, 128, kv_heads=groups)
+        sd = jax.ShapeDtypeStruct
+        q = sd((1, 256, heads * 128), jnp.float32)
+        k = sd((1, 256, (groups or heads) * 128), jnp.float32)
+        lse = sd((1, heads, 1, 256), jnp.float32)
+        fwd = str(jax.make_jaxpr(lambda q, k: pk.flash_attention_with_lse(
+            q, k, k, None, 0.1, True, True, lay, window=window))(q, k))
+        bwd = str(jax.make_jaxpr(lambda q, k, lse: pk.flash_attention_bwd(
+            q, k, k, None, q, lse, q, 0.1, True, True, lay,
+            window=window))(q, k, lse))
+        return fwd, bwd
+
+    for (heads, groups, window), (f, b) in {
+            (4, 2, None): ("hetu_flash_gqa_fwd", "hetu_flash_gqa_bwd"),
+            (4, 2, 100): ("hetu_flash_gqa_window_fwd",
+                          "hetu_flash_gqa_window_bwd"),
+            (4, None, 100): ("hetu_flash_window", "hetu_flash_window_bwd"),
+            (4, None, None): ("_flash_attention_jit",
+                              "_flash_attention_bwd_jit")}.items():
+        fwd, bwd = names(heads, groups, window)
+        assert f"name={f}\n" in fwd or f"name={f} " in fwd, fwd[:400]
+        assert f"name={b}\n" in bwd or f"name={b} " in bwd, bwd[:400]
+
+
+# -- the held experts --------------------------------------------------------
+
+def _plain_experts(x, weights, picks, w_in, w_out, first, act=jax.nn.relu):
+    """A dense loop over the held experts: every token through each."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    width = w_in.shape[-1] // 2
+    for e in range(w_in.shape[0]):
+        share = jnp.sum(jnp.where(picks == first + e, weights, 0.0), -1)
+        h = x @ w_in[e]
+        out = out + share[..., None] * (
+            (act(h[..., :width]) * h[..., width:]) @ w_out[e])
+    return out
+
+
+def _expert_case(routing, hidden=HIDDEN, width=WIDTH, held=4, first=2,
+                 tokens=(2, S), seed=8):
+    rng = np.random.RandomState(seed)
+    x = rng.randn(*tokens, hidden).astype(np.float32)
+    if routing == "random":
+        picks = np.stack([rng.permutation(EXPERTS)[:TOP_K]
+                          for _ in range(tokens[0] * tokens[1])])
+    elif routing == "all_to_one":       # every token's first pick: expert 3
+        picks = np.tile(np.array([3, 0, 7]), (tokens[0] * tokens[1], 1))
+    else:                               # "none_held": experts 0, 1, 6, 7
+        picks = np.tile(np.array([0, 7, 1]), (tokens[0] * tokens[1], 1))
+    picks = picks.reshape(*tokens, TOP_K).astype(np.int32)
+    weights = rng.rand(*tokens, TOP_K).astype(np.float32)
+    weights /= weights.sum(-1, keepdims=True)
+    w_in = (0.2 * rng.randn(held, hidden, 2 * width)).astype(np.float32)
+    w_out = (0.2 * rng.randn(held, width, hidden)).astype(np.float32)
+    return [x, weights, picks, w_in, w_out], first
+
+
+@pytest.mark.parametrize("routing", ["random", "all_to_one", "none_held"])
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+def test_held_experts_value_and_gradients(routing, activation):
+    arrays, first = _expert_case(routing)
+    act = moe.ACTIVATIONS[activation]
+    check(lambda x, w, p, w_in, w_out: ht.held_experts_op(
+        x, w, p, w_in, w_out, first=first, activation=activation),
+        lambda x, w, p, w_in, w_out: _plain_experts(
+            x, w, p, w_in, w_out, first, act), arrays)
+    if routing == "none_held":
+        got, grads = op_and_grads(
+            lambda x, w, p, w_in, w_out: ht.held_experts_op(
+                x, w, p, w_in, w_out, first=first, activation=activation),
+            arrays, np.ones(arrays[0].shape, np.float32))
+        assert not got.any() and not any(g.any() for g in grads)
+
+
+@pytest.mark.parametrize("routing", ["random", "all_to_one"])
+def test_held_experts_kernels_interpreted(kernels, routing):
+    """The three grouped-matmul kernels (whole-lane widths, the rows
+    padded to the kernel's tile) give what the ragged products give."""
+    arrays, first = _expert_case(routing, hidden=128, width=128,
+                                 tokens=(1, 40))
+    dy = jnp.asarray(np.random.RandomState(9).randn(1, 40, 128), jnp.float32)
+    nodes = [ht.Variable(f"n{i}", trainable=False) for i in range(5)]
+    op = ht.held_experts_op(*nodes, first=first, activation="relu")
+    packed = op.gradient(ht.Variable("dy", trainable=False))[0].inputs[0]
+    values = [jnp.asarray(a) for a in arrays]
+    ectx = _Training()
+    out = op.compute(values, ectx)
+    grads = packed.compute(values + [dy], ectx)
+    want, vjp = jax.vjp(lambda x, w, w_in, w_out: _plain_experts(
+        x, w, values[2], w_in, w_out, first),
+        values[0], values[1], values[3], values[4])
+    close(out, want, 1e-5)
+    for got, wanted in zip(grads, vjp(dy)):
+        close(got, wanted, 1e-5)
+
+
+def test_held_experts_activation_is_an_argument_of_the_serving_function():
+    arrays, first = _expert_case("random", tokens=(1, S))
+    x, w, p, w_in, w_out = (jnp.asarray(a[0]) if a.ndim == 3 and i < 3
+                            else jnp.asarray(a)
+                            for i, a in enumerate(arrays))
+    valid = jnp.ones(x.shape[0], bool)
+    for name, act in moe.ACTIVATIONS.items():
+        got, rows = moe.held_experts(x, p, w, valid, w_in, w_out, first,
+                                     activation=name)
+        close(got, _plain_experts(x, w, p, w_in, w_out, first, act))
+    silu, _ = moe.held_experts(x, p, w, valid, w_in, w_out, first)
+    close(silu, _plain_experts(x, w, p, w_in, w_out, first, jax.nn.silu))
+    assert int(rows.sum()) == int(((p >= first) & (p < first + 4)).sum())
+
+
+# -- the whole graph against the reference -----------------------------------
+
+def tiny_config(first=2, held=4, vocab=VOCAB):
+    """A configuration file's content at the test's widths."""
+    return {"num_attention_heads": 8, "num_key_value_heads": 2,
+            "head_dim": 16, "hidden_size": HIDDEN,
+            "moe_ffn_hidden_size": WIDTH, "num_routed_experts": EXPERTS,
+            "moe_num_primary_experts": held, "first_expert": first,
+            "moe_num_active_primary_experts": TOP_K,
+            "sliding_window_size": 8, "sliding_window_layout": [0, 1, 1, 1],
+            "rope_layout": [0, 1, 1, 1], "rope_theta": 1.5e6,
+            "rms_norm_eps": 1e-6, "num_hidden_layers": 4,
+            "vocab_size": vocab, "assumed": {"initializer_std": 0.3}}
+
+
+def batch(b=2, seed=10, vocab=VOCAB):
+    ids = np.random.RandomState(seed).randint(0, vocab, (b, S)).astype(
+        np.int32)
+    labels = np.concatenate([ids[:, 1:], np.full((b, 1), -1, np.int32)], 1)
+    return ids, labels
+
+
+class Graph:
+    def __init__(self, config, extra=(), **executor_kw):
+        self.config = config
+        self.model = SparseDecoderLMHeadModel(model_config(config))
+        self.ids = ht.Variable("input_ids", trainable=False)
+        self.labels = ht.Variable("labels", trainable=False)
+        self.logits, loss = self.model(self.ids, self.labels, seq_len=S)
+        self.loss = ht.reduce_mean_op(loss, [0, 1])
+        self.groups = {"validate": [self.loss, self.logits],
+                       "picks": list(self.model.picks)}
+        self.groups.update(extra(self) if extra else {})
+        self.executor = ht.Executor(self.groups, seed=3, **executor_kw)
+        self.nodes = {n.name: n for n in
+                      self.executor.config.placeholder_to_arr_map}
+
+    def params(self):
+        return {node.name: np.asarray(arr) for node, arr in
+                self.executor.config.placeholder_to_arr_map.items()}
+
+    def run(self, group, ids, labels=None):
+        feeds = {self.ids: ids}
+        if labels is not None:
+            feeds[self.labels] = labels
+        def value(o):       # the embedding's gradient comes as its rows
+            held = getattr(o, "jax_array", o)
+            return np.asarray(held.to_dense() if hasattr(held, "to_dense")
+                              else o.asnumpy())
+
+        return [None if o is None else value(o)
+                for o in self.executor.run(group, feed_dict=feeds)]
+
+
+@pytest.fixture(scope="module")
+def graph_and_gradients():
+    """The graph with one more group: the loss's gradient to EVERY
+    parameter, by the graph's own gradient ops."""
+    def extra(g):
+        names = sorted(n.name for n in ht.executor.find_topo_sort([g.loss])
+                       if getattr(n, "trainable", False))
+        by_name = {n.name: n for n in ht.executor.find_topo_sort([g.loss])
+                   if getattr(n, "trainable", False)}
+        g.grad_names = names
+        return {"grads": ht.gradients(g.loss,
+                                      [by_name[n] for n in names])}
+    return Graph(tiny_config(), extra)
+
+
+def test_every_parameter_of_the_model_is_named_by_the_shapes_function(
+        graph_and_gradients):
+    g = graph_and_gradients
+    from hetu_tpu.models.sparse_decoder import sparse_decoder_param_shapes
+    shapes = sparse_decoder_param_shapes(model_config(g.config))
+    assert sorted(shapes) == g.grad_names
+    assert {k: v.shape for k, v in g.params().items()} == shapes
+    assert shapes["sparse_h0_experts_gate_up"] == (4, HIDDEN, 2 * WIDTH)
+    assert shapes["sparse_h0_router"] == (HIDDEN, EXPERTS)
+
+
+def test_loss_and_scores_agree_with_the_reference(graph_and_gradients):
+    g = graph_and_gradients
+    ids, labels = batch()
+    loss, logits = g.run("validate", ids, labels)
+    picks = g.run("picks", ids)
+    log = []
+    want_loss, (want_logits,) = reference.loss_and_scores(
+        g.params(), g.config, ids, labels, forced=picks, log=log.append)
+    assert log[0]["rows_differing_by_layer"] == [0, 0, 0, 0]
+    assert logits.dtype == np.float32
+    assert abs(loss - want_loss) <= 1e-5 * abs(want_loss)
+    close(logits, want_logits, 2e-5)
+    free_loss, _ = reference.loss_and_scores(g.params(), g.config, ids,
+                                             labels)
+    assert free_loss == want_loss
+
+
+def test_every_parameters_gradient_agrees_with_the_reference(
+        graph_and_gradients):
+    g = graph_and_gradients
+    ids, labels = batch()
+    grads = dict(zip(g.grad_names, g.run("grads", ids, labels)))
+    params = {k: jnp.asarray(v) for k, v in g.params().items()}
+    want = jax.grad(reference.loss_fn)(params, g.config, ids, labels)
+    assert sorted(want) == sorted(grads)
+    for name in g.grad_names:
+        got = grads[name]
+        assert np.abs(want[name]).max() > 0, name
+        close(got, want[name], 1e-4)
+
+
+def test_a_flipped_pick_beyond_the_margin_fails_the_comparison(
+        graph_and_gradients):
+    g = graph_and_gradients
+    ids, labels = batch()
+    picks = g.run("picks", ids)
+    log = []
+    flipped = [p.copy() for p in picks]
+    own = set(flipped[1][0, 5].tolist())
+    flipped[1][0, 5, 0] = next(e for e in range(EXPERTS) if e not in own)
+    loss, _ = reference.loss_and_scores(g.params(), g.config, ids, labels,
+                                        forced=flipped, log=log.append)
+    assert log[0]["rows_differing_by_layer"] == [0, 1, 0, 0]
+    assert log[0]["worst_margin_by_layer"][1] > reference.PICK_MARGIN
+    assert np.isnan(loss)
+
+
+def test_the_eight_bit_control_fails_the_tolerances(graph_and_gradients):
+    """The reference with every matrix in 8 bits, on the same picks, is
+    further from the sound one than the scores' tolerance allows (the
+    loss hardly moves with precision: its limit is the harness's)."""
+    from benchmark.harness import stats
+    g = graph_and_gradients
+    ids, labels = batch()
+    picks = g.run("picks", ids)
+    loss, (logits,) = reference.loss_and_scores(
+        g.params(), g.config, ids, labels, forced=picks)
+    bad_loss, (bad,) = reference.loss_and_scores(
+        g.params(), g.config, ids, labels, forced=picks, control="all_8bit")
+    assert stats.row_errors(bad, logits).max() > reference.OUTPUT_TOLERANCE
+    assert np.median(stats.row_errors(bad, logits)) \
+        > reference.OUTPUT_TOLERANCE
+    assert np.isfinite(bad_loss) and bad_loss != loss
+
+
+def test_two_adam_steps_reproduce_the_references_losses():
+    config = tiny_config()
+    lr = 1e-2
+    g = Graph(config, lambda g: {"default": [
+        g.loss, ht.optim.AdamOptimizer(learning_rate=lr).minimize(g.loss)]})
+    ids, labels = batch()
+    params = {k: jnp.asarray(v) for k, v in g.params().items()}
+    m = {k: jnp.zeros_like(v) for k, v in params.items()}
+    v = {k: jnp.zeros_like(p) for k, p in params.items()}
+    want = []
+    for t in range(1, 4):
+        loss, grads = jax.value_and_grad(reference.loss_fn)(
+            params, config, ids, labels)
+        want.append(float(loss))
+        for k in params:
+            m[k] = 0.9 * m[k] + 0.1 * grads[k]
+            v[k] = 0.999 * v[k] + 0.001 * grads[k] ** 2
+            scale = lr * np.sqrt(1 - 0.999 ** t) / (1 - 0.9 ** t)
+            params[k] = params[k] - scale * m[k] / (jnp.sqrt(v[k]) + 1e-7)
+    got = [float(g.run("default", ids, labels)[0]) for _ in range(3)]
+    assert got[2] < got[1] < got[0]
+    np.testing.assert_allclose(got, want, rtol=5e-3)
+
+
+def test_the_step_counts_rows_and_visits_on_the_device():
+    config = tiny_config()
+    g = Graph(config, lambda g: {"default": [
+        g.loss, ht.optim.SGDOptimizer(0.0).minimize(g.loss)]})
+    ids, labels = batch()
+    assert g.executor.moe_counters() == []          # no step compiled yet
+    picks = g.run("picks", ids)
+    for _ in range(3):
+        g.run("default", ids, labels)
+    g.run("validate", ids, labels)                  # inference counts nothing
+    counted = g.executor.moe_counters()
+    assert len(counted) == 4
+    for layer, c in zip(picks, counted):
+        by_hand = [int((layer == 2 + e).sum()) for e in range(4)]
+        assert c["moe_rows_by_expert"] == [3 * n for n in by_hand]
+        assert c["moe_routed_rows"] == 3 * sum(by_hand)
+        assert c["moe_expert_visits"] == 3 * sum(n > 0 for n in by_hand)
+        assert c["steps"] == 3
+
+
+# -- the share test ----------------------------------------------------------
+
+def test_four_shares_of_the_experts_add_up_to_the_uncut_layer():
+    """first = 0, 2, 4, 6 of 8, two held each, through the graph op:
+    their sums add up to the reference's whole expert layer."""
+    arrays, _ = _expert_case("random", held=EXPERTS, first=0)
+    x, weights, picks, w_in, w_out = arrays
+    whole = reference.held_experts(
+        jnp.asarray(x.reshape(-1, HIDDEN)),
+        jnp.asarray(picks.reshape(-1, TOP_K)),
+        jnp.asarray(weights.reshape(-1, TOP_K)),
+        jnp.asarray(w_in), jnp.asarray(w_out), 0).reshape(x.shape)
+    nodes = [ht.Variable(f"n{i}", trainable=False) for i in range(3)]
+    total = 0.0
+    for first in (0, 2, 4, 6):
+        w1 = ht.Variable(f"w_in{first}", trainable=False)
+        w2 = ht.Variable(f"w_out{first}", trainable=False)
+        (part,) = run_graph(
+            [ht.held_experts_op(*nodes, w1, w2, first=first,
+                                activation="relu")],
+            {**dict(zip(nodes, (x, weights, picks))),
+             w1: w_in[first:first + 2], w2: w_out[first:first + 2]})
+        assert np.abs(part).max() > 0
+        total = total + part
+    close(total, whole, 2e-5)
+
+
+def test_four_slices_of_the_vocabulary_concatenate_to_the_whole_head():
+    """A sliced vocabulary is a smaller vocabulary: the head's columns
+    cut in four give the four quarters of the whole head's logits."""
+    g = Graph(tiny_config())
+    ids, labels = batch()
+    _, whole = g.run("validate", ids, labels)
+    params = g.params()
+    parts = []
+    for n in range(4):
+        cut = dict(params)
+        cut["sparse_lm_head"] = params["sparse_lm_head"][
+            :, n * VOCAB // 4:(n + 1) * VOCAB // 4]
+        quarter = dict(tiny_config(), vocab_size=VOCAB // 4)
+        _, (logits,) = reference.loss_and_scores(
+            cut, quarter, ids, np.full_like(labels, -1))
+        parts.append(logits)
+    close(np.concatenate(parts, -1), whole, 2e-5)
