@@ -595,12 +595,14 @@ def main(argv=None):
     count = len(jax.devices())
     if args.rehearse:
         from hetu_tpu.ops import (attention, pallas_attention,
-                                  pallas_dropout, pallas_norm)
+                                  pallas_dropout, pallas_norm,
+                                  pallas_sparse_update)
         # steer the platform-decided kernel dispatch from here, as the
         # tests do: the program itself has no such option
         pallas_attention.INTERPRET = True
         pallas_norm.INTERPRET = True
         pallas_dropout.INTERPRET = True
+        pallas_sparse_update.INTERPRET = True
         attention._use_pallas = lambda: True
         check(count >= args.chips,
               f"rehearsal of --chips {args.chips} needs that many "

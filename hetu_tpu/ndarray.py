@@ -306,6 +306,19 @@ class IndexedSlices:
         summed = jax.ops.segment_sum(rows, inv, num_segments=flat_idx.shape[0])
         return uniq, summed
 
+    def sorted_rows(self):
+        """``(ids, rows)`` in ascending order of the ids, duplicates
+        kept, each beside its own row, in the order they came (a stable
+        sort): what ``hetu_sparse_rows_update`` takes, which adds the
+        rows of one id up itself. One sort of the ids and one gather of
+        the rows, where ``dedup`` asks XLA for a scatter-add of every
+        row as well (on a TPU a microsecond a row: PERF.md section 6,
+        PR 51)."""
+        flat_idx = self.get_flat_indices()
+        ids, order = jax.lax.sort_key_val(
+            flat_idx, jnp.arange(flat_idx.shape[0], dtype=jnp.int32))
+        return ids, self.get_dense_rows()[order]
+
     def to_dense(self):
         out = jnp.zeros(self.dense_shape, dtype=self.values.dtype)
         return out.at[self.get_flat_indices()].add(self.get_dense_rows())

@@ -8,8 +8,37 @@ splices the per-parameter communication op chosen by the node strategy
 
 TPU-native: ``update`` is a *pure function* (params, grads, slots, lr) ->
 (new params, new slots) executed inside the compiled train step, with
-parameter donation making it in-place in HBM. Sparse gradients apply as
-scatter-add / row-wise slot updates without densifying the table.
+parameter donation making it in-place in HBM.
+
+Each optimizer's arithmetic is written ONCE, as a rule over rows
+(``sgd_rows``, ``adagrad_rows``, ``adam_rows``): the dense branch
+applies it to the whole arrays, the sparse branch (an ``IndexedSlices``
+gradient: an embedding table) to the rows the step looked up, lazily —
+a row not looked up keeps its parameter and slots bit for bit. Which
+tables take which sparse path (``sparse_update_path``, one
+``sparse_update`` instant a traced table says which and why):
+
+- ``kernel``: a float32 table of whole 128-lane rows, eight rows or
+  more, in a step traced for a TPU that no mesh partitions (GPT-2's
+  ``wte`` / ``wpe``, the sparse decoder's token table, BERT's position
+  table). ``ops/pallas_sparse_update.py`` takes the ids sorted, each
+  beside its gradient row (``IndexedSlices.sorted_rows``), adds the
+  gradients of one row up, and reads and writes each looked-up row of
+  the parameter and its slots once, in place.
+- ``composed``: everything else — the CTR tables of width 4 to 16
+  (``lanes``), BERT's two-row token-type table (``rows``), a step under
+  ``dp`` (GSPMD cannot partition a Mosaic kernel: ``mesh``), any
+  backend but a TPU (``platform``), a caller that hands no step context
+  (``caller``: the pipeline drivers). ``IndexedSlices.dedup``, then one
+  row gather and one row scatter a table; ``dedup``'s padding ids lie
+  past the table and are dropped.
+
+The bfloat16 working copy of a table (``OptimizerOp.compute``) is still
+a whole ``astype`` of the new master: under the ``(8,128)(2,1)`` tiling
+a bfloat16 row shares its 32-bit words with its neighbour, so a row-wise
+write of the copy is a read-modify-write of pairs of rows, a different
+problem from the float32 rows here; the whole convert costs what the
+bandwidth allows (PERF.md §5).
 """
 from __future__ import annotations
 
@@ -23,7 +52,92 @@ from .ops.variable import PlaceholderOp
 
 __all__ = ["Optimizer", "OptimizerOp", "SGDOptimizer", "MomentumOptimizer",
            "AdaGradOptimizer", "AdamOptimizer", "AdamWOptimizer",
-           "sentinel_stats"]
+           "sentinel_stats", "sparse_update_path"]
+
+
+# ---------------------------------------------------------------------------
+# The update rules: rule(g, rows, s, *hyper) -> new rows. ``g`` the
+# gradient, ``rows`` the parameter and then each slot (whole arrays, or
+# the looked-up rows of each), ``s`` the step's traced scalars (a
+# sequence, or the kernel's SMEM ref), ``hyper`` Python numbers. Traced
+# as they stand into the dense branch, the composed sparse branch and
+# the body of ``hetu_sparse_rows_update``.
+# ---------------------------------------------------------------------------
+
+def sgd_rows(g, rows, s):
+    (p,) = rows
+    return [p - s[0] * g]
+
+
+def adagrad_rows(g, rows, s, eps):
+    p, accum = rows
+    accum = accum + g * g
+    return [p - s[0] * g / (jnp.sqrt(accum) + eps), accum]
+
+
+def adam_rows(g, rows, s, beta1, beta2, epsilon):
+    """``s[0]`` is the bias-corrected step size; a fourth array is
+    amsgrad's running maximum."""
+    p, m, v, *vmax = rows
+    m = beta1 * m + (1 - beta1) * g
+    v = beta2 * v + (1 - beta2) * g * g
+    vhat = jnp.maximum(vmax[0], v) if vmax else v
+    return [p - s[0] * m / (jnp.sqrt(vhat) + epsilon), m, v,
+            *([vhat] if vmax else [])]
+
+
+def sparse_update_path(param, slots, site):
+    """``(path, reason)`` of one table's sparse update, from what the
+    code can see: ``"kernel"`` (``hetu_sparse_rows_update``; ``reason``
+    None) in a step traced for a TPU that no mesh partitions, on
+    float32 tables whose rows are whole lanes; else ``"composed"`` and
+    the first condition that failed: ``caller`` (no step context came
+    with the call), ``platform``, ``mesh``, ``lanes``, ``rows``,
+    ``dtype``. ``site`` is ``(table name, ectx)`` or None."""
+    from .ops import pallas_sparse_update as kernel
+    from .ops.attention import _use_pallas
+    ectx = site[1] if site else None
+    mesh = getattr(getattr(ectx, "config", None), "mesh", None)
+    for reason, holds in (
+            ("caller", ectx is not None),
+            ("platform", _use_pallas()),
+            ("mesh", mesh is None or mesh.size == 1)):
+        if not holds:
+            return "composed", reason
+    reason = kernel.supported(
+        *(param.shape if param.ndim == 2 else (0, 0)),
+        [param.dtype, *(v.dtype for v in slots.values())])
+    return ("composed", reason) if reason else ("kernel", None)
+
+
+def _update_rows(rule, hyper, scalars, param, grad, slots, site,
+                 composed=None):
+    """``rule`` applied to the rows of ``param`` and ``slots`` that the
+    sparse ``grad`` names, the gradients of one row summed first; every
+    other row is left as it lies. ``composed`` is a branch's own
+    composed form, where it has one that needs no dedup. One
+    ``sparse_update`` instant a traced call."""
+    from . import telemetry
+    path, reason = sparse_update_path(param, slots, site)
+    telemetry.get_telemetry().instant(
+        "sparse_update", table=site[0] if site else "", rows=param.shape[0],
+        width=param.shape[-1], ids=grad.get_flat_indices().shape[0],
+        slots=len(slots), path=path, **({"reason": reason} if reason else {}))
+    if path == "composed" and composed is not None:
+        return composed(), slots
+    tables = [param, *slots.values()]
+    if path == "kernel":
+        from .ops.pallas_sparse_update import hetu_sparse_rows_update
+        new = hetu_sparse_rows_update(rule, hyper, *grad.sorted_rows(),
+                                      scalars, tables)
+    else:
+        # dedup pads with ids past the table: the gather clamps them,
+        # the scatter drops them
+        idx, g = grad.dedup()
+        new = rule(g, [t[idx] for t in tables], scalars, *hyper)
+        new = [t.at[idx].set(rows, mode="drop")
+               for t, rows in zip(tables, new)]
+    return new[0], dict(zip(slots, new[1:]))
 
 
 def sentinel_stats(param, grad, new_param):
@@ -137,11 +251,13 @@ class Optimizer:
                                  dense_shape=grad.dense_shape)
         return grad * inv
 
-    def update_one(self, param, grad, slots, lr, step):
-        """(new_param, new_slots) for one parameter."""
+    def update_one(self, param, grad, slots, lr, step, site=None):
+        """(new_param, new_slots) for one parameter. ``site`` is
+        ``(parameter name, ectx)`` where the caller has them: what a
+        sparse update's path is chosen from (``sparse_update_path``)."""
         raise NotImplementedError
 
-    def update(self, param_vals, grad_vals, state, lr, step):
+    def update(self, param_vals, grad_vals, state, lr, step, ectx=None):
         """Pure update over dicts keyed by param node. Empty slot dicts are
         not inserted, so opt_state keeps a stable pytree structure across
         steps (a structure change would force a full re-trace)."""
@@ -150,7 +266,7 @@ class Optimizer:
             grad = grad_vals[node]
             slots = state.get(node.id, {})
             p, s = self.update_one(param, self._apply_l2(param, grad),
-                                   slots, lr, step)
+                                   slots, lr, step, (node.name, ectx))
             new_params[node] = p
             if s or node.id in state:
                 new_state[node.id] = s
@@ -160,11 +276,15 @@ class Optimizer:
 class SGDOptimizer(Optimizer):
     name = "SGD"
 
-    def update_one(self, param, grad, slots, lr, step):
+    def update_one(self, param, grad, slots, lr, step, site=None):
         if isinstance(grad, IndexedSlices):
-            return (param.at[grad.get_flat_indices()].add(
-                -lr * grad.get_dense_rows()), slots)
-        return param - lr * grad, slots
+            # no slot to keep lazy: composed, the scatter itself adds
+            # duplicates up, and a row not looked up gets nothing
+            return _update_rows(
+                sgd_rows, (), [lr], param, grad, slots, site,
+                composed=lambda: param.at[grad.get_flat_indices()].add(
+                    -lr * grad.get_dense_rows()))
+        return sgd_rows(grad, [param], [lr])[0], slots
 
 
 class MomentumOptimizer(Optimizer):
@@ -180,7 +300,7 @@ class MomentumOptimizer(Optimizer):
         return {node.id: {"velocity": jnp.zeros_like(v)}
                 for node, v in param_vals.items()}
 
-    def update_one(self, param, grad, slots, lr, step):
+    def update_one(self, param, grad, slots, lr, step, site=None):
         if isinstance(grad, IndexedSlices):
             grad = grad.to_dense()
         v = self.momentum * slots["velocity"] - lr * grad
@@ -205,20 +325,14 @@ class AdaGradOptimizer(Optimizer):
             v, self.initial_accumulator_value)}
             for node, v in param_vals.items()}
 
-    def update_one(self, param, grad, slots, lr, step):
-        accum = slots["accum"]
+    def update_one(self, param, grad, slots, lr, step, site=None):
+        slots = {"accum": slots["accum"]}
         if isinstance(grad, IndexedSlices):
-            idx, rows = grad.dedup()
-            safe = jnp.clip(idx, 0, param.shape[0] - 1)
-            picked = accum[safe] + rows * rows
-            accum = accum.at[safe].set(picked)
-            upd = lr * rows / (jnp.sqrt(picked) + self.eps)
-            valid = (idx < param.shape[0])[:, None]
-            param = param.at[safe].add(jnp.where(valid, -upd, 0.0))
-            return param, {"accum": accum}
-        accum = accum + grad * grad
-        return (param - lr * grad / (jnp.sqrt(accum) + self.eps),
-                {"accum": accum})
+            return _update_rows(adagrad_rows, (self.eps,), [lr], param,
+                                grad, slots, site)
+        param, accum = adagrad_rows(grad, [param, slots["accum"]], [lr],
+                                    self.eps)
+        return param, {"accum": accum}
 
 
 class AdamOptimizer(Optimizer):
@@ -247,37 +361,17 @@ class AdamOptimizer(Optimizer):
         bc2 = 1 - self.beta2 ** t
         return lr * jnp.sqrt(bc2) / bc1
 
-    def update_one(self, param, grad, slots, lr, step):
+    def update_one(self, param, grad, slots, lr, step, site=None):
+        names = ("m", "v", "vmax") if self.amsgrad else ("m", "v")
+        slots = {name: slots[name] for name in names}
+        hyper = (self.beta1, self.beta2, self.epsilon)
+        scalars = [self._step_scale(lr, step)]
         if isinstance(grad, IndexedSlices):
-            idx, rows = grad.dedup()
-            safe = jnp.clip(idx, 0, param.shape[0] - 1)
-            valid = (idx < param.shape[0])[:, None]
-            m_rows = self.beta1 * slots["m"][safe] + (1 - self.beta1) * rows
-            v_rows = (self.beta2 * slots["v"][safe]
-                      + (1 - self.beta2) * rows * rows)
-            m = slots["m"].at[safe].set(
-                jnp.where(valid, m_rows, slots["m"][safe]))
-            v = slots["v"].at[safe].set(
-                jnp.where(valid, v_rows, slots["v"][safe]))
-            out = {"m": m, "v": v}
-            vhat_rows = v_rows
-            if self.amsgrad:
-                vhat_rows = jnp.maximum(slots["vmax"][safe], v_rows)
-                out["vmax"] = slots["vmax"].at[safe].set(
-                    jnp.where(valid, vhat_rows, slots["vmax"][safe]))
-            scale = self._step_scale(lr, step)
-            upd = scale * m_rows / (jnp.sqrt(vhat_rows) + self.epsilon)
-            param = param.at[safe].add(jnp.where(valid, -upd, 0.0))
-            return param, out
-        m = self.beta1 * slots["m"] + (1 - self.beta1) * grad
-        v = self.beta2 * slots["v"] + (1 - self.beta2) * grad * grad
-        out = {"m": m, "v": v}
-        vhat = v
-        if self.amsgrad:
-            vhat = jnp.maximum(slots["vmax"], v)
-            out["vmax"] = vhat
-        scale = self._step_scale(lr, step)
-        return param - scale * m / (jnp.sqrt(vhat) + self.epsilon), out
+            return _update_rows(adam_rows, hyper, scalars, param, grad,
+                                slots, site)
+        param, *new = adam_rows(grad, [param, *slots.values()], scalars,
+                                *hyper)
+        return param, dict(zip(names, new))
 
 
 class AdamWOptimizer(AdamOptimizer):
@@ -290,8 +384,9 @@ class AdamWOptimizer(AdamOptimizer):
                          loss_scale=loss_scale)
         self.weight_decay = weight_decay
 
-    def update_one(self, param, grad, slots, lr, step):
-        new_param, out = super().update_one(param, grad, slots, lr, step)
+    def update_one(self, param, grad, slots, lr, step, site=None):
+        new_param, out = super().update_one(param, grad, slots, lr, step,
+                                            site)
         if not isinstance(grad, IndexedSlices):
             new_param = new_param - lr * self.weight_decay * param
         return new_param, out
@@ -356,7 +451,7 @@ class OptimizerOp(Op):
         if lr is None:
             lr = opt.learning_rate
         new_params, new_state = opt.update(
-            param_vals, grad_vals, ectx.opt_state or {}, lr, ectx.step)
+            param_vals, grad_vals, ectx.opt_state or {}, lr, ectx.step, ectx)
         sentinels = getattr(ectx, "health_sentinels", None)
         if sentinels is not None:
             # training health monitor: per-layer grad norm / nonfinite

@@ -71,6 +71,17 @@ SPAN_SCHEMA = {
     # converts itself: those the PS runtime writes between steps
     "working_copies": {"subgraph": _req(_STR), "params": _req(_INT),
                        "bytes": _req(_INT), "in_step_casts": _any()},
+    # one a table whose gradient reaches the optimizer sparse, a
+    # compiled step (optimizer.py:_update_rows): the table's name and
+    # shape, the ids the step looks up, the optimizer's slots, and the
+    # path its rows take: kernel (hetu_sparse_rows_update) or composed,
+    # which names the first condition of optimizer.py:
+    # sparse_update_path that failed (caller / platform / mesh / lanes /
+    # rows / dtype)
+    "sparse_update": {"table": _req(_STR), "rows": _req(_INT),
+                      "width": _req(_INT), "ids": _req(_INT),
+                      "slots": _req(_INT), "path": _req(_STR),
+                      "reason": _opt(_STR)},
     "step_logged": {"step": _opt(_INT), "wall_ms": _opt(_NUM)},
     # SubExecutor.run around device_dispatch: the feed loop and
     # dataloader batches of one step; state swap, health monitor and

@@ -27,6 +27,12 @@ options the executor gives a training step on a TPU each update runs
 beside its layer's backward (PR 49). A compile that passes is a compile,
 not a run.
 
+The optimizer's sparse row update (``ops/pallas_sparse_update.py``,
+PR 51) compiles at the three tables the train cells run it at, and in
+GPT-2's step and the sparse decoder's every table is an aliased operand
+of that one call: no scatter with a table-shaped result, no whole copy
+of a table, no sort of the ids beyond ``dedup``'s own.
+
 LayerNorm's backward kernel (``ops/pallas_norm.py``) compiles at the
 two shapes the train cells run it at, ``[16384, 768]`` (GPT-2 small,
 batch 16 x S 1024) and ``[32768, 768]`` (BERT-base, 256 x 128), bf16;
@@ -600,6 +606,12 @@ def test_bert_step_holds_no_head_major_relayout(v5e, monkeypatch):
                                             " fusion(", " reshape("))]
 
 
+def _called_bodies(text):
+    """{computation name: its text} of a compiled module."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(%[\w.\-]+) \(.*?\{\n(.*?)^\}", text, re.MULTILINE | re.DOTALL)}
+
+
 def _touching(text, elements):
     """The ENTRY instructions of a compiled step that read or write an
     array of ``elements`` elements or more — bitcasts and tuple
@@ -608,8 +620,7 @@ def _touching(text, elements):
     sized = lambda t: max(                                  # noqa: E731
         [math.prod(int(d) for d in dims.split(",") if d)
          for dims in re.findall(r"\w+\[([\d,]*)\]", t)] or [0]) >= elements
-    bodies = {m.group(1): m.group(2) for m in re.finditer(
-        r"^(%[\w.\-]+) \(.*?\{\n(.*?)^\}", text, re.MULTILINE | re.DOTALL)}
+    bodies = _called_bodies(text)
     entry = text[text.index("ENTRY"):]
     types, found = {}, []
     for m in _INSTRUCTION.finditer(entry):
@@ -925,14 +936,14 @@ def test_expert_products_compile_at_the_sparse_decoders_cell(one_chip,
     assert name in text
 
 
-def test_sparse_decoder_step_holds_the_banded_grouped_backward(
-        v5e, monkeypatch):
+def _sparse_decoder_step_text(v5e_device, monkeypatch):
     """One WINDOW layer of the sparse-expert decoder at the published
     widths and S = 8,192 (2 experts held and 1,024 rows of vocabulary,
     to keep the compile short), as the executor compiles a training
-    step: the step holds the banded grouped-query forward and backward
-    and the experts' three kinds of grouped product (two of each),
-    eight kernels."""
+    step."""
+    key = ("sparse_decoder",)
+    if key in _STEP_TEXTS:
+        return _STEP_TEXTS[key]
     import numpy as np
     import hetu_tpu as ht
     from jax.sharding import SingleDeviceSharding
@@ -959,7 +970,7 @@ def test_sparse_decoder_step_holds_the_banded_grouped_backward(
     feed = {ids: np.zeros((1, 8192), np.int32),
             labels: np.zeros((1, 8192), np.int32)}
     step = sub.prepare(executor, feed)
-    sharding = SingleDeviceSharding(v5e[0])
+    sharding = SingleDeviceSharding(v5e_device)
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(
             np.shape(a), a.dtype if hasattr(a, "dtype")
@@ -967,9 +978,116 @@ def test_sparse_decoder_step_holds_the_banded_grouped_backward(
         sub.trace_args(executor, feed))
     text = jax.jit(step, donate_argnums=(0, 1, 2, 3)).lower(
         *shapes).compile(compiler_options=TPU_TRAIN_STEP_OPTIONS).as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    _STEP_TEXTS[key] = text
+    return text
+
+
+def test_sparse_decoder_step_holds_the_banded_grouped_backward(
+        v5e, monkeypatch):
+    """The step holds the banded grouped-query forward and backward,
+    the experts' three kinds of grouped product (two of each) and the
+    token table's sparse row update (PR 51): nine kernels."""
+    text = _sparse_decoder_step_text(v5e[0], monkeypatch)
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
     for name in ("hetu_flash_gqa_window_fwd", "hetu_flash_gqa_window_bwd",
-                 "hetu_moe_experts_dx", "hetu_moe_experts_dw"):
+                 "hetu_moe_experts_dx", "hetu_moe_experts_dw",
+                 "hetu_sparse_rows_update"):
         assert name in text, name
     # a group's dk / dv leave the backward summed over its 7 heads
     assert "bf16[1,8192,512]" in text
+
+
+# (rows, width, ids a step) of the tables the train cells update sparsely
+SPARSE_TABLES = {"smallthinker": (37984, 2560, 8192),
+                 "gpt2_wte": (50257, 768, 16384),
+                 "gpt2_wpe": (1024, 768, 16384)}
+
+
+@pytest.mark.parametrize("slots", [0, 1, 2, 3],
+                         ids=["sgd", "adagrad", "adam", "amsgrad"])
+@pytest.mark.parametrize("table", list(SPARSE_TABLES))
+def test_sparse_update_kernel_compiles_at_the_train_cells_tables(
+        one_chip, table, slots):
+    """One custom call named for the trace's readers; the parameter and
+    every slot are aliased to its results (donated, nothing copies a
+    table), and what XLA compiles around it is the count of the real
+    ids: it reads no table."""
+    from hetu_tpu import optimizer as optim
+    from hetu_tpu.ops import pallas_sparse_update as kernel
+    rows, width, n = SPARSE_TABLES[table]
+    rule, hyper = [(optim.sgd_rows, ()), (optim.adagrad_rows, (1e-7,)),
+                   (optim.adam_rows, (0.9, 0.999, 1e-7)),
+                   (optim.adam_rows, (0.9, 0.999, 1e-7))][slots]
+
+    def update(ids, g, scale, *tables):
+        return kernel.hetu_sparse_rows_update(
+            rule, hyper, ids, g, [scale], list(tables), interpret=False)
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(    # noqa: E731
+        shape, dtype, sharding=one_chip)
+    tables = [sds((rows, width), jnp.float32)] * (1 + slots)
+    compiled = jax.jit(update, donate_argnums=tuple(
+        range(3, 3 + len(tables)))).lower(
+            sds((n,), jnp.int32), sds((n, width), jnp.float32),
+            sds((), jnp.float32), *tables).compile()
+    entry = compiled.as_text()
+    entry = entry[entry.index("ENTRY"):]
+    assert entry.count("tpu_custom_call") == 1
+    assert f"%{kernel.KERNEL_NAME}" in entry
+    aliased = re.search(r"output_to_operand_aliasing=\{(.*?)\}, ", entry)
+    assert aliased and aliased.group(1).count("{})") == len(tables), entry
+    assert not re.search(r"f32\[%d,%d\]\S* copy\(" % (rows, width), entry)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= len(tables) * rows * width * 4
+    assert memory.temp_size_in_bytes < 1024 * 1024
+
+
+def _table_sized(text, rows, width):
+    """ENTRY instructions (but plumbing) with a float32 result of a
+    table's shape: ``[(name, opcode, line, the text of what it
+    calls)]``."""
+    bodies = _called_bodies(text)
+    entry = text[text.index("\nENTRY "):]
+    found = []
+    for m in _INSTRUCTION.finditer(entry):
+        name, result, opcode, _ = m.groups()
+        if opcode in ("bitcast", "get-tuple-element", "parameter", "tuple") \
+                or f"f32[{rows},{width}]" not in result:
+            continue
+        line = entry[m.start():entry.find("\n", m.end())]
+        called = re.search(r"calls=(%[\w.\-]+)", line)
+        found.append((name, opcode, line,
+                      bodies.get(called.group(1), "") if called else ""))
+    return found
+
+
+@pytest.mark.parametrize("model,ids,tables", [
+    ("gpt2", 16384, [(50257, 768), (1024, 768)]),
+    ("sparse_decoder", 8192, [(1024, 2560)])])
+def test_step_updates_its_sparse_tables_in_one_call_each(
+        v5e, monkeypatch, model, ids, tables):
+    """GPT-2's four-layer step (the one the order test compiles) and a
+    one-layer sparse-decoder step, as the executor compiles them: every
+    sparsely updated table — master and both of Adam's moments — is an
+    aliased operand of ONE ``hetu_sparse_rows_update`` call; no
+    instruction with a table-shaped float32 result is a scatter or a
+    copy (on the parent: three scatter fusions a table, each sorting
+    the ids again); and the ids are sorted by ``dedup`` alone, twice a
+    table at most (three times on the parent)."""
+    text = (_gpt2_step_text(v5e[0], monkeypatch, 0.1, vocab=50257,
+                            layers=4, as_the_executor=True)
+            if model == "gpt2" else
+            _sparse_decoder_step_text(v5e[0], monkeypatch))
+    for rows, width in tables:
+        found = _table_sized(text, rows, width)
+        calls = [f for f in found if "hetu_sparse_rows_update" in f[0]]
+        assert len(calls) == 1, [f[:2] for f in found]
+        aliasing = re.search(r"output_to_operand_aliasing=\{(.*?)\}, ",
+                             calls[0][2]).group(1)
+        assert aliasing.count("{})") == 3, aliasing
+        for name, opcode, _, body in found:
+            assert opcode != "copy" and " scatter(" not in body \
+                and " sort(" not in body, (name, opcode)
+    sorts = re.findall(r"= \(?s32\[%d\]\S*(?:, s32\[%d\]\S*)?\)? sort\("
+                       % (ids, ids), text)
+    assert 1 <= len(sorts) <= 2 * len(tables), sorts

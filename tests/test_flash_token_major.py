@@ -31,7 +31,7 @@ from hetu_tpu import telemetry as tmod
 from hetu_tpu.graph.node import ExecContext
 from hetu_tpu.ops import attention as attn_mod
 from hetu_tpu.ops import pallas_attention as pk
-from hetu_tpu.ops import pallas_dropout, pallas_norm
+from hetu_tpu.ops import pallas_dropout, pallas_norm, pallas_sparse_update
 from hetu_tpu.ops.attention import (FlashAttentionOp, attention_reference,
                                     flash_layout)
 from hetu_tpu.telemetry.check import check_args
@@ -258,6 +258,7 @@ def kernels_on_cpu(monkeypatch):
     # a whole graph traced "on the chip" meets the other kernels too
     monkeypatch.setattr(pallas_norm, "INTERPRET", True)
     monkeypatch.setattr(pallas_dropout, "INTERPRET", True)
+    monkeypatch.setattr(pallas_sparse_update, "INTERPRET", True)
     old = tmod._default
     tel = tmod.configure(enabled=True, service="test-flash-layout")
     yield tel
