@@ -42,13 +42,22 @@ def enable_compile_cache():
 
     Also keeps cache keys a function of the program: a Pallas kernel's
     serialized body carries the location it was traced at, and with
-    full tracebacks those bytes — hence the key of every program that
+    whole tracebacks those bytes — hence the key of every program that
     holds the kernel — depend on the Python call stack that FIRST traced
     it, so a second process that reaches the kernel by another path
     never hits what the first wrote (measured on the chip: GPT-2's step
-    recompiled, 28 s, in the second process)."""
+    recompiled, 28 s, in the second process). So a location keeps ONE
+    frame, the innermost of the program's own. It stays a traceback of
+    one frame and not the bare file and line that
+    ``jax_include_full_tracebacks_in_locations=False`` gives: under that
+    flag this jax names an operation lowered outside a nested jit by its
+    primitive alone (``op_name="add"``), and the scopes a compiled step
+    runs its graph ops under (``Op.scope``: what a profile's device time
+    is joined to graph ops by, docs/tools.md) never reach the compiled
+    program's metadata."""
     import jax
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     external = os.environ.get(_COMPILE_CACHE_ENV)
     if external:
         return external

@@ -727,9 +727,15 @@ class SubExecutor:
 
         def step_fn(params, state, opt_state, work, feeds, lr, step_idx,
                     rng):
+            # Every operation traced here gets a scope in its op_name
+            # (compiled metadata: no instruction changes): a node's
+            # compute its Op.scope(), what the step itself does a
+            # hetu.step/<what>. A profile's device time is joined to
+            # graph ops by them (docs/tools.md).
             # per-step key folded INSIDE the jit: an eager fold_in per
             # step would be one more host-dispatched device program
-            rng = jax.random.fold_in(rng, step_idx)
+            with jax.named_scope("hetu.step/rng"):
+                rng = jax.random.fold_in(rng, step_idx)
             ectx = ExecContext(training=training, base_rng=rng,
                                config=config)
             if health_on:
@@ -750,11 +756,12 @@ class SubExecutor:
                 ectx.master_params = ectx.params
                 ectx.work = {n: work[str(n.id)] for n in param_order
                              if str(n.id) in work}
-                ectx.params = {
-                    n: ectx.work[n] if n in ectx.work else
-                    (v.astype(config.dtype)
-                     if jnp.issubdtype(v.dtype, jnp.floating) else v)
-                    for n, v in ectx.params.items()}
+                with jax.named_scope("hetu.step/convert"):
+                    ectx.params = {
+                        n: ectx.work[n] if n in ectx.work else
+                        (v.astype(config.dtype)
+                         if jnp.issubdtype(v.dtype, jnp.floating) else v)
+                        for n, v in ectx.params.items()}
             ectx.state = {n: state[str(n.id)] for n in state_order}
             ectx.opt_state = opt_state
             ectx.lr = lr
@@ -763,7 +770,8 @@ class SubExecutor:
             for n, v in zip(feed_order, feeds):
                 if config.dtype is not None and hasattr(v, "dtype") and \
                         jnp.issubdtype(v.dtype, jnp.floating):
-                    v = v.astype(config.dtype)  # avoid fp32 re-promotion
+                    with jax.named_scope("hetu.step/feeds"):
+                        v = v.astype(config.dtype)  # no fp32 re-promotion
                 env[n] = v
             for node in topo:
                 if node in env:
@@ -778,8 +786,9 @@ class SubExecutor:
                     # steps) or an unmaterialized PS table: no device value
                     env[node] = None
                     continue
-                env[node] = node.compute(
-                    [env[i] for i in node.inputs], ectx)
+                with jax.named_scope(node.scope()):
+                    env[node] = node.compute(
+                        [env[i] for i in node.inputs], ectx)
             outputs = [None if n in optimizer_set else env[n]
                        for n in eval_nodes]
             new_params = {str(n.id): ectx.new_params.get(
@@ -815,8 +824,9 @@ class SubExecutor:
                 # embedding gradient is as visible as a dense one
                 for op, g in zip(ps_ops, ps_grads):
                     if g is not None and hasattr(op, "parameter"):
-                        layers[f"ps:{op.parameter.name}"] = \
-                            sentinel_stats(None, g, None)
+                        with jax.named_scope("hetu.step/health"):
+                            layers[f"ps:{op.parameter.name}"] = \
+                                sentinel_stats(None, g, None)
                 health = {"layers": layers}
                 # the loss sentinel: a scalar floating eval output,
                 # preferring one whose NAME says loss (a scalar metric
@@ -843,8 +853,9 @@ class SubExecutor:
                         if loss_node is None:
                             loss_node, loss_val = n, v
                 if loss_node is not None:
-                    health["loss"] = jnp.reshape(loss_val, ()).astype(
-                        jnp.float32)
+                    with jax.named_scope("hetu.step/health"):
+                        health["loss"] = jnp.reshape(
+                            loss_val, ()).astype(jnp.float32)
                     # trace-time side effect: deterministic per build,
                     # read by the monitor for trip naming
                     self._health_loss_name = loss_node.name
@@ -860,9 +871,10 @@ class SubExecutor:
                             or not all(isinstance(d, int) and d > 0
                                        for d in v.shape):
                         continue
-                    rng_out[node.name] = (
-                        jnp.min(v).astype(jnp.float32),
-                        jnp.max(v).astype(jnp.float32))
+                    with jax.named_scope("hetu.step/ranges"):
+                        rng_out[node.name] = (
+                            jnp.min(v).astype(jnp.float32),
+                            jnp.max(v).astype(jnp.float32))
                 if health is None:
                     health = {}
                 health["ranges"] = rng_out
@@ -1009,7 +1021,8 @@ class SubExecutor:
                 # an empty pytree — when the monitor is off, so the
                 # disabled program is unchanged)
                 return ((p, s, o, w) if training else ()), (outs, h)
-            steps = step0 + jnp.arange(nsteps, dtype=jnp.int32)
+            with jax.named_scope("hetu.step/block"):
+                steps = step0 + jnp.arange(nsteps, dtype=jnp.int32)
             carry, (outs, health) = jax.lax.scan(
                 body, trees if training else (),
                 tuple([steps, lrs] + list(feeds_stacked)))

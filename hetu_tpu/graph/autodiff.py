@@ -49,8 +49,10 @@ def gradients(output_node, node_list, insert_grad=None):
     insert_grad: optional op to use as the seed adjoint of output_node
     (defaults to OnesLike, i.e. d(output)/d(output) = 1).
     """
+    from . import node as graph_node
     from ..ops.shape import oneslike_op
 
+    first_minted = graph_node.G_NODE_ID
     if insert_grad is None:
         insert_grad = oneslike_op(output_node, ctx=output_node.raw_ctx)
     node_to_grads = {output_node: [insert_grad]}
@@ -74,6 +76,11 @@ def gradients(output_node, node_list, insert_grad=None):
                 continue
             node_to_grads.setdefault(inp, []).append(ig)
 
+    # every node minted here is the backward pass's (Op.role): the seed,
+    # each node.gradient(...) result and what it is built from, the sums
+    for node in find_topo_sort(list(node_to_grad.values())):
+        if node.id >= first_minted:
+            node.role = "bwd"
     results = []
     for node in node_list:
         assert node in node_to_grad, \

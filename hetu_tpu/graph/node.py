@@ -136,6 +136,11 @@ class ExecContext:
 class Op:
     """A node in the dataflow graph (reference Node.py:9)."""
 
+    # who made the node, for the scope its compute is traced under
+    # (``scope()``): ``fwd`` unless ``autodiff.gradients`` minted it
+    # (``bwd``) or it is an OptimizerOp (``opt``)
+    role = "fwd"
+
     def __init__(self, op_type, inputs, ctx=None):
         global G_NODE_ID
         self.inputs = list(inputs)
@@ -160,6 +165,14 @@ class Op:
             inp.name for inp in self.inputs) + ")"
 
     # ------------------------------------------------------------------ core
+    def scope(self):
+        """What a compiled step calls everything this node traces:
+        ``hetu.<role>/<op_type>/<node>``, in the ``op_name`` of each
+        instruction (docs/tools.md; a profile's reader joins device
+        time to graph ops by it)."""
+        return f"hetu.{self.role}/{self.op_type}/" \
+            + self.name.replace("/", ".")
+
     def compute(self, input_vals, ectx):
         """Pure computation: list of jax values -> output jax value."""
         raise NotImplementedError

@@ -43,6 +43,7 @@ bandwidth allows (PERF.md §5).
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .graph.node import Op
@@ -138,6 +139,14 @@ def _update_rows(rule, hyper, scalars, param, grad, slots, site,
         new = [t.at[idx].set(rows, mode="drop")
                for t, rows in zip(tables, new)]
     return new[0], dict(zip(slots, new[1:]))
+
+
+def parameter_scope(node):
+    """One more level under the OptimizerOp's scope (``Op.scope``):
+    ``.../<parameter name>``, around everything a step traces for that
+    parameter's update, so a profile's optimizer time divides by
+    table (docs/tools.md)."""
+    return jax.named_scope(node.name.replace("/", "."))
 
 
 def sentinel_stats(param, grad, new_param):
@@ -265,8 +274,9 @@ class Optimizer:
         for node, param in param_vals.items():
             grad = grad_vals[node]
             slots = state.get(node.id, {})
-            p, s = self.update_one(param, self._apply_l2(param, grad),
-                                   slots, lr, step, (node.name, ectx))
+            with parameter_scope(node):
+                p, s = self.update_one(param, self._apply_l2(param, grad),
+                                       slots, lr, step, (node.name, ectx))
             new_params[node] = p
             if s or node.id in state:
                 new_state[node.id] = s
@@ -401,6 +411,8 @@ class OptimizerOp(Op):
     executor threads them to the next step with buffer donation.
     """
 
+    role = "opt"
+
     def __init__(self, grads, optimizer):
         super().__init__(OptimizerOp, grads, None)
         self.name = "Optimizer_%s" % optimizer.name
@@ -427,26 +439,28 @@ class OptimizerOp(Op):
             if gval is None:
                 continue            # PS-managed parameter: updated server-side
             pval = masters[node]
-            if hasattr(gval, "astype") and gval.dtype != pval.dtype:
-                gval = gval.astype(pval.dtype)
-            elif hasattr(gval, "values") and \
-                    gval.values.dtype != pval.dtype:
-                gval = type(gval)(indices=gval.indices,
-                                  values=gval.values.astype(pval.dtype),
-                                  dense_shape=gval.dense_shape)
+            with parameter_scope(node):
+                if hasattr(gval, "astype") and gval.dtype != pval.dtype:
+                    gval = gval.astype(pval.dtype)
+                elif hasattr(gval, "values") and \
+                        gval.values.dtype != pval.dtype:
+                    gval = type(gval)(
+                        indices=gval.indices,
+                        values=gval.values.astype(pval.dtype),
+                        dense_shape=gval.dense_shape)
+                if getattr(node, "device_cached", False):
+                    # HET push accumulator: raw grads accumulate in HBM
+                    # state; the PS runtime drains it to the server every
+                    # cache_bound steps (ps/runtime.py drain paths)
+                    acc = ectx.state[node]["acc"]
+                    if isinstance(gval, IndexedSlices):
+                        acc = acc.at[gval.get_flat_indices()].add(
+                            gval.get_dense_rows().astype(acc.dtype))
+                    else:
+                        acc = acc + gval.astype(acc.dtype)
+                    ectx.new_state[node] = {"acc": acc}
             grad_vals[node] = gval
             param_vals[node] = pval
-            if getattr(node, "device_cached", False):
-                # HET push accumulator: raw grads accumulate in HBM
-                # state; the PS runtime drains it to the server every
-                # cache_bound steps (ps/runtime.py drain paths)
-                acc = ectx.state[node]["acc"]
-                if isinstance(gval, IndexedSlices):
-                    acc = acc.at[gval.get_flat_indices()].add(
-                        gval.get_dense_rows().astype(acc.dtype))
-                else:
-                    acc = acc + gval.astype(acc.dtype)
-                ectx.new_state[node] = {"acc": acc}
         lr = getattr(ectx, "lr", None)
         if lr is None:
             lr = opt.learning_rate
@@ -461,16 +475,19 @@ class OptimizerOp(Op):
                 # sentinel the UNSCALED gradient: with loss_scale set
                 # the raw grads are scale-times reality, which would
                 # poison every grad_norm the health monitor records
-                sentinels.append((node.name, sentinel_stats(
-                    pval, opt._unscale(grad_vals[node]),
-                    new_params.get(node, pval))))
+                with parameter_scope(node):
+                    sentinels.append((node.name, sentinel_stats(
+                        pval, opt._unscale(grad_vals[node]),
+                        new_params.get(node, pval))))
         ectx.new_params.update(new_params)
         # mixed precision: the compute-dtype copy the NEXT step's matmuls
         # read is one more result of this update (2 bytes a parameter
         # written where 12 are), so no step converts a master again
         for node, value in new_params.items():
             if node in ectx.work:
-                ectx.new_work[node] = value.astype(ectx.work[node].dtype)
+                with parameter_scope(node):
+                    ectx.new_work[node] = value.astype(
+                        ectx.work[node].dtype)
         ectx.new_opt_state = {**(ectx.opt_state or {}), **new_state}
         return jnp.zeros((1,), dtype=jnp.float32)
 
@@ -533,6 +550,7 @@ class OptimizerOp(Op):
                 comm = allreduceCommunicate_op(grad, ctx=grad.raw_ctx)
             else:
                 comm = grad
+            comm.role = grad.role       # a gradient's sync is backward's
             new_inputs.append(comm)
         self.inputs = new_inputs
 

@@ -10,6 +10,11 @@ Three levels:
   eagerly op by op with a sync after each, returning (and optionally
   printing) the cost ranking. Eager timing is orders slower than the
   jitted step — it attributes cost, it does not measure the fused step.
+  The fused step's own device time by graph op is in a ``trace`` of it:
+  every ``node.compute`` is traced under ``hetu.<role>/<op_type>/<node>``
+  (``Op.scope``), and ``python -m benchmark.tools.step_account <dir>``
+  joins the profile's device events to those scopes (docs/tools.md,
+  "Scopes: whose a device operation is").
 * ``trace(logdir)`` — the operator's entry to ``jax.profiler``:
   ``with ht.profiler.trace(dir):`` around any training loop or serving
   engine writes ONE profile holding the device's operations and the
@@ -160,7 +165,10 @@ def profile_ops(executor, feed_dict=None, name="default", top=20,
     """Per-op cost attribution: execute the step's topo order eagerly,
     blocking after each op (reference HetuProfiler's per-node timers).
     Returns [(op_name, ms)] sorted by cost; ``costdb=`` additionally
-    persists each measurement (see ``profile_op_records``)."""
+    persists each measurement (see ``profile_op_records``). Attribution
+    only; the jitted step fuses these: what each graph op costs INSIDE
+    the fused step is read from a ``trace`` of it, by the scopes the
+    step runs its ops under (``benchmark/tools/step_account.py``)."""
     records = profile_op_records(executor, feed_dict, name=name,
                                  costdb=costdb)
     times = [(r["name"], r["ms"]) for r in records]

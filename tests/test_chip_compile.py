@@ -1091,3 +1091,39 @@ def test_step_updates_its_sparse_tables_in_one_call_each(
     sorts = re.findall(r"= \(?s32\[%d\]\S*(?:, s32\[%d\]\S*)?\)? sort\("
                        % (ids, ids), text)
     assert 1 <= len(sorts) <= 2 * len(tables), sorts
+
+
+# ---------------------------------------------------------------------------
+# a kernel's bytes under the chip entry points' locations (PR 52)
+# ---------------------------------------------------------------------------
+
+def _flash_text(sharding):
+    x = jax.ShapeDtypeStruct((2, 12, 1024, 64), jnp.bfloat16,
+                             sharding=sharding)
+    jax.clear_caches()          # this call traces the kernel anew
+    return pk._flash_attention_jit.lower(
+        x, x, x, None, 0.125, True, False, 512, 512, False).as_text()
+
+
+@pytest.mark.parametrize("frames,same", [(1, True), (10, False)],
+                         ids=["one_frame", "jax_default"])
+def test_a_kernels_bytes_do_not_depend_on_who_traced_it(one_chip, frames,
+                                                        same):
+    """``cachedir.enable_compile_cache`` keeps ONE frame of a location's
+    traceback: the lowered text of a flash call, its serialized Mosaic
+    body included (what the persistent cache's key is made of), is then
+    the same from two call stacks. jax's own ten frames show that the
+    comparison can fail."""
+    names = ("jax_include_full_tracebacks_in_locations",
+             "jax_traceback_in_locations_limit")
+    was = {n: getattr(jax.config, n) for n in names}
+    jax.config.update(names[0], True)
+    jax.config.update(names[1], frames)
+    try:
+        direct = _flash_text(one_chip)
+        nested = (lambda: (lambda: _flash_text(one_chip))())()
+    finally:
+        for n, v in was.items():
+            jax.config.update(n, v)
+    assert "tpu_custom_call" in direct
+    assert (direct == nested) == same

@@ -120,7 +120,8 @@ def test_cpu_backend_has_no_budget(monkeypatch):
 def cache_config():
     """Restore the jax config enable_compile_cache() touches."""
     names = ("jax_compilation_cache_dir",
-             "jax_include_full_tracebacks_in_locations")
+             "jax_include_full_tracebacks_in_locations",
+             "jax_traceback_in_locations_limit")
     was = {n: getattr(jax.config, n) for n in names}
     yield was
     for n, v in was.items():
@@ -133,8 +134,11 @@ def test_compile_cache_defaults_to_fixed_in_checkout_path(
     assert cachedir.enable_compile_cache() == cachedir.STATE_ROOT
     assert jax.config.jax_compilation_cache_dir == cachedir.STATE_ROOT
     assert cachedir.STATE_ROOT == os.path.join(REPO, ".jax_cache")
-    # kernel bodies must not carry the tracing call stack into the key
-    assert not jax.config.jax_include_full_tracebacks_in_locations
+    # kernel bodies must not carry the tracing call stack into the key:
+    # one frame, as a traceback (the bare file and line of the other
+    # flag loses the scopes of a step's graph ops: test_step_account.py)
+    assert jax.config.jax_traceback_in_locations_limit == 1
+    assert jax.config.jax_include_full_tracebacks_in_locations
 
 
 def test_external_compile_cache_dir_is_left_untouched(
