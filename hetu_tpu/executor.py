@@ -1525,7 +1525,13 @@ class Executor:
         layer, in graph order — ``moe_rows_by_expert`` (the (token,
         pick) pairs that landed on each held expert), ``moe_routed_rows``
         (their sum), ``moe_expert_visits`` (held experts that got a row,
-        a step each), ``steps``. Reading it waits for the last step.
+        a step each), ``moe_row_tiles`` (the row tiles of ``ops/moe.py:
+        ROW_TILE`` sorted rows that each composed pass of the op ran: a
+        step adds ``ceil(held rows / tile)``, the trip count of its
+        loops), ``moe_row_tiles_of`` (the tiles that ALL ``T x k`` rows
+        are, a step each: the quotient of the two is the share of the
+        passes' work that the held extent leaves standing), ``steps``.
+        Reading it waits for the last step.
         The counts are int32 on the device: a session of more than
         2**31 rows on one expert wraps them."""
         from .ops.moe import HeldExpertsOp
@@ -1541,6 +1547,9 @@ class Executor:
             out.append({"moe_rows_by_expert": rows,
                         "moe_routed_rows": int(sum(rows)),
                         "moe_expert_visits": int(state["moe_expert_visits"]),
+                        "moe_row_tiles": int(state.get("moe_row_tiles", 0)),
+                        "moe_row_tiles_of": int(
+                            state.get("moe_row_tiles_of", 0)),
                         "steps": int(state["steps"])})
         return out
 

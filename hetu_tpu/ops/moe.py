@@ -34,12 +34,35 @@ elsewhere would add is the exchange's business, not this module's.
 * :func:`held_experts_op` — :func:`held_experts` as a graph node over
   the stacked parameters of the experts held here, with a counter the
   compiled training step accumulates on the device (rows by held
-  expert, experts visited, steps: ``Executor.moe_counters()``). Its
-  backward is two grouped products for the rows (``hetu_moe_experts_dx``:
-  the kernel with its right side transposed) and two transposed grouped
-  products for the weights (``hetu_moe_experts_dw``: megablox's
-  ``tgmm``); the rows of experts held elsewhere are never computed in
-  either direction.
+  expert, experts visited, row tiles, steps:
+  ``Executor.moe_counters()``). Its backward is two grouped products for
+  the rows (``hetu_moe_experts_dx``: the kernel with its right side
+  transposed) and two transposed grouped products for the weights
+  (``hetu_moe_experts_dw``: megablox's ``tgmm``); the rows of experts
+  held elsewhere are never computed in either direction.
+
+**The held extent** (the graph op only; :func:`held_experts`, which
+serving runs at 128 padded rows a decode step, keeps its whole-array
+body). The sort puts the ``n = sum(sizes[:-1])`` rows of the experts
+held here first, and ``n`` is on the device. Every composed pass of the
+op whose result is indexed by SORTED ROW — the gathers ``flat[token]``
+and ``dy[token]``, the activation between the two products, the
+backward's ``da``, ``da * act``, ``w_row * act``, the activation's slope
+and ``dh`` — runs under :func:`_over_held_rows`: a loop over row tiles of ``ROW_TILE``
+rows whose trip count is ``ceil(n / tile)``, each tile written in place
+into a buffer of the full ``[T x k, ...]`` shape that starts with no
+value (:func:`_fresh`). Nothing is chosen: no capacity, no fallback, no
+dropped row; the extent follows the routing. The rows past the last
+tile that ran hold whatever the allocation held, and every reader
+selects them away: the grouped kernels mask their operands and their
+store by group, and the way back (``ys[back]``, ``dxs[back]``: indexed
+by (token, pick), so over all ``T x k`` whatever the extent) reads the
+kernels' own outputs, whose rows behind the held groups are zeros. The
+op's state counts the tiles (``moe_row_tiles``, beside
+``moe_row_tiles_of``, the tiles that all ``T x k`` rows are): their
+quotient is the share of the passes' work that is left. The three
+permutations of a scalar a pair (``back``, ``w_row``, the pairs' weight
+gradient) are sorts (:func:`_moved`), not gathers or scatters.
 """
 from __future__ import annotations
 
@@ -48,6 +71,7 @@ import importlib
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from ..graph.node import Op
 from .norm import PackedPartOp as _Part
@@ -62,6 +86,8 @@ KERNEL_NAME = "hetu_moe_experts"
 # the backward's grouped products, as a profile names their events
 ROWS_GRAD_KERNEL_NAME = "hetu_moe_experts_dx"
 WEIGHTS_GRAD_KERNEL_NAME = "hetu_moe_experts_dw"
+# the allocation of a row buffer that a pass fills to the held extent
+FRESH_KERNEL_NAME = "hetu_moe_rows_buffer"
 # the gate's activation of an expert: down(act(gate x) * up x)
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 LANES = 128
@@ -69,6 +95,12 @@ LANES = 128
 # [tokens * top_k, hidden], 268 MB at 4096 tokens x 8 picks x 4096 wide
 # in bfloat16, whatever the prompt bucket
 TOKEN_CHUNK = 4096
+# sorted rows a composed pass of the graph op takes at a time: its loop
+# runs the row tiles that hold a held expert's row and no other
+# (``_over_held_rows``). A tile's gather moves 10 MB at 2560 wide in
+# bfloat16, tens of microseconds beside a loop iteration's few, and a
+# pass rounds its rows up by half a tile on average
+ROW_TILE = 2048
 
 
 def _use_pallas():
@@ -220,8 +252,12 @@ def grouped_matmul_weights_grad(lhs, dy, group_sizes):
     row = jnp.arange(m)
     member = (row[None, :] >= (ends - group_sizes[:-1])[:, None]) \
         & (row[None, :] < ends[:, None])                    # [G, m]
+    # a row behind the held groups may hold anything: selected away
+    # (the kernel selects by group too), never multiplied by a zero
+    here = _computed_rows(m, group_sizes)
     return jnp.einsum("gm,mk,mn->gkn", member.astype(jnp.float32),
-                      lhs.astype(jnp.float32), dy.astype(jnp.float32))
+                      jnp.where(here, lhs, 0).astype(jnp.float32),
+                      jnp.where(here, dy, 0).astype(jnp.float32))
 
 
 def _held_pairs(experts, valid, held_n, first):
@@ -404,14 +440,106 @@ def _expert_rows(x, weights, experts, first, held_n, kept=None):
     if kept is None:
         order, sizes, held, rows = _sorted_pairs(experts, valid, held_n,
                                                  first)
-        # a sorted row's place, by (token, pick)
-        back = jnp.zeros(rows, jnp.int32).at[order[:rows]].set(
-            jnp.arange(rows, dtype=jnp.int32))
+        # a sorted row's place, by (token, pick): the sort's inverse
+        back = _moved(jnp.arange(rows, dtype=jnp.int32), order[:rows])
     else:
         order, sizes, back = kept
         held = _held_pairs(experts, valid, held_n, first)
     weights = jnp.where(held, weights.reshape(-1, k), 0.0)
     return flat, weights, held, order, sizes, back, order // k
+
+
+def _moved(values, place):
+    """``out[place[i]] = values[i]`` for a permutation ``place``, by a
+    sort on its keys: 0.05 ms for 49,152 scalars, where XLA's gather or
+    scatter of scalars walks them one by one (0.23-0.47 ms)."""
+    return jax.lax.sort((place, values), num_keys=1)[1]
+
+
+def _interpret():
+    """Off a TPU a kernel can only be interpreted (a rehearsal steers
+    ``_use_pallas`` to the kernels on any backend)."""
+    return INTERPRET or jax.default_backend() != "tpu"
+
+
+def _fresh(shapes, after):
+    """The starts of the buffers that :func:`_over_held_rows` fills tile
+    by tile (``shapes``: a ``ShapeDtypeStruct`` each): no value at all.
+    Every reader of such a buffer SELECTS the rows past the extent away
+    (the grouped kernels mask by group, the way back by ``held``); none
+    multiplies them by zero.
+
+    Where the kernels run they are the results of ONE kernel that takes
+    the pass's operands ``after`` and writes nothing: allocations the
+    compiler cannot make before the pass could run, nor fold into one.
+    (``jax.lax.empty`` depends on nothing, and the TPU's scheduler made
+    every expert layer's buffers of both directions at the step's first
+    instruction: 2.2 GB on top of the step's peak.) Elsewhere
+    ``jax.lax.empty``, which is zeros on a backend without uninitialised
+    memory."""
+    if not (_use_pallas() or INTERPRET):
+        return tuple(jax.lax.empty(s.shape, s.dtype) for s in shapes)
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    # one element of each: the dependence is all that is wanted, and a
+    # whole operand would be held to a kernel's row-major layout
+    after = [jax.lax.slice(a, (0,) * a.ndim, (1,) * a.ndim) for a in after]
+    return tuple(pl.pallas_call(
+        lambda *refs: None, out_shape=list(shapes),
+        in_specs=[anywhere] * len(after), out_specs=[anywhere] * len(shapes),
+        interpret=_interpret(), name=FRESH_KERNEL_NAME)(*after))
+
+
+def _held_row_tiles(sizes, rows):
+    """``(tile, tiles)``: the row tile of a pass over ``rows`` sorted
+    rows, and how many of them hold a row of an expert held here (a
+    traced int32: ``ceil(sum(sizes[:-1]) / tile)``)."""
+    tile = min(ROW_TILE, rows)
+    return tile, (jnp.sum(sizes[:-1]) + (tile - 1)) // tile
+
+
+def _over_held_rows(fn, sizes, by_row, whole=()):
+    """``fn`` over the sorted rows that landed here, and over no other.
+
+    ``by_row`` are ``[rows, ...]`` arrays indexed by sorted row, ``whole``
+    arrays that every row may read, and ``fn(*tiles, *whole) -> tuple of
+    [tile, ...] arrays`` works row by row. It is applied a row tile at a
+    time under a loop whose TRIP COUNT is the number of tiles below the
+    held extent (``_held_row_tiles``), each result written into a
+    ``[rows, ...]`` buffer in place. The rows past the last tile that
+    ran are never written and hold no defined value (``_fresh``). Where
+    ``rows`` is no multiple of the tile the last tile overlaps the one
+    before it and computes those rows again, to the same values."""
+    rows = by_row[0].shape[0]
+    tile, tiles = _held_row_tiles(sizes, rows)
+    shapes = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+        (tile,) + a.shape[1:], a.dtype) for a in by_row), *whole)
+
+    def body(i, filled):
+        # a plain multiple of the tile where the tiles divide the rows:
+        # the compiler then knows the slices aligned and writes a tile's
+        # results in place (behind a ``minimum`` it computed them into a
+        # buffer of their own and copied that: 41 of 55 us a tile)
+        at = i * tile if rows % tile == 0 \
+            else jnp.minimum(i * tile, rows - tile)
+        got = fn(*(jax.lax.dynamic_slice_in_dim(a, at, tile)
+                   for a in by_row), *whole)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(f, g, at, 0)
+                     for f, g in zip(filled, got))
+
+    return jax.lax.fori_loop(0, tiles, body, _fresh(
+        [jax.ShapeDtypeStruct((rows,) + s.shape[1:], s.dtype)
+         for s in shapes], (*by_row, *whole)))
+
+
+def _held_rows_of(source, token, sizes):
+    """``source[token]`` (``[T, hidden]`` rows gathered to the sorted
+    rows) for the rows that landed here. A source a loop: XLA keeps a
+    loop's ``[T, hidden]`` source in on-chip memory (a tile's gather 14
+    us for 67 from HBM), and two of them in one loop took the place of
+    the head's operand there (its weight gradient ran 18.5 ms for
+    11.6)."""
+    return _over_held_rows(lambda t, source: (source[t],), sizes,
+                           (token,), (source,))[0]
 
 
 def _gate_up(h, activation):
@@ -430,8 +558,12 @@ class HeldExpertsOp(Op):
     dtype; what the experts held elsewhere would add is left out. No
     token is dropped under any imbalance.
 
+    The passes round the two products run to the held extent (the
+    module's docstring; ``_over_held_rows``).
+
     A training step counts on the device, in the op's state: rows by
-    held expert, held experts that got a row, steps
+    held expert, held experts that got a row, the row tiles its passes
+    ran and the tiles that all the rows are, steps
     (``Executor.moe_counters()``; nothing is read inside a step)."""
 
     stateful = True
@@ -449,16 +581,23 @@ class HeldExpertsOp(Op):
 
     def state_shapes(self, input_shapes):
         return {"moe_rows_by_expert": (input_shapes[3][0],),
-                "moe_expert_visits": (), "steps": ()}
+                "moe_expert_visits": (), "moe_row_tiles": (),
+                "moe_row_tiles_of": (), "steps": ()}
 
     def compute(self, input_vals, ectx):
         x, weights, experts, w_gate_up, w_down = input_vals
         flat, weights, _, order, sizes, back, token = _expert_rows(
             x, weights, experts, self.first, w_gate_up.shape[0])
         k = experts.shape[-1]
-        h = grouped_matmul(flat[token], w_gate_up, sizes)
-        gate, up = _gate_up(h, self.activation)
-        ys = grouped_matmul((gate * up).astype(x.dtype), w_down, sizes)
+        h = grouped_matmul(_held_rows_of(flat, token, sizes), w_gate_up,
+                           sizes)
+
+        def activated(h):
+            gate, up = _gate_up(h, self.activation)
+            return ((gate * up).astype(x.dtype),)
+
+        (act,) = _over_held_rows(activated, sizes, (h,))
+        ys = grouped_matmul(act, w_down, sizes)
         out = jnp.einsum("tk,tkh->th", weights,
                          ys[back].reshape(-1, k, ys.shape[-1])
                          .astype(jnp.float32))
@@ -468,10 +607,16 @@ class HeldExpertsOp(Op):
             state = ectx.get_state(self)
             if state is not None:
                 held = sizes[:-1]
+                rows = order.shape[0]
+                tile, tiles = _held_row_tiles(sizes, rows)
                 ectx.put_state(self, {
                     "moe_rows_by_expert": state["moe_rows_by_expert"] + held,
                     "moe_expert_visits": state["moe_expert_visits"]
                     + jnp.sum(held > 0, dtype=jnp.int32),
+                    # (a state restored from before these two has none)
+                    "moe_row_tiles": state.get("moe_row_tiles", 0) + tiles,
+                    "moe_row_tiles_of": state.get("moe_row_tiles_of", 0)
+                    + jnp.int32(-(-rows // tile)),
                     "steps": state["steps"] + 1})
         return out.reshape(x.shape).astype(x.dtype)
 
@@ -500,7 +645,8 @@ class _HeldExpertsGradientOp(Op):
       and a token's ``dx`` the sum of its pairs' rows.
 
     The rows of experts held elsewhere are computed by none of the four
-    and come back as zeros."""
+    and come back as zeros. The gathers and the elementwise work between
+    the products run to the held extent (``_over_held_rows``)."""
 
     def __init__(self, forward_op, output_grad, ctx=None):
         super().__init__(_HeldExpertsGradientOp,
@@ -515,27 +661,36 @@ class _HeldExpertsGradientOp(Op):
         flat, weights, held, order, sizes, back, token = _expert_rows(
             x, weights, experts, fwd.first, w_gate_up.shape[0],
             None if kept is None else kept[:3])
-        xs = flat[token]
+        xs = _held_rows_of(flat, token, sizes)
+        dys = _held_rows_of(dy.reshape(flat.shape), token, sizes)
         h = grouped_matmul(xs, w_gate_up, sizes) if kept is None \
             else kept[3]
         width = h.shape[-1] // 2
-        gate, up = _gate_up(h, fwd.activation)
-        act = gate * up
-        dys = dy.reshape(flat.shape)[token]                 # [rows, hidden]
-        w_row = weights.reshape(-1)[order][:, None]         # pads weigh 0
-        w_row = jnp.where(_computed_rows(order.shape[0], sizes), w_row, 0.0)
-        da = grouped_matmul_rows_grad(dys, w_down, sizes).astype(
-            jnp.float32)                                    # [rows, width]
-        dweights = jnp.sum(da * act, axis=-1)[back].reshape(-1, k)
+        da = grouped_matmul_rows_grad(dys, w_down, sizes)   # [rows, width]
+
+        # a pair's weight at its sorted row; the pad rows weigh nothing
+        w_row = _moved(weights.reshape(-1), back)
+        w_row = jnp.pad(w_row, (0, order.shape[0] - w_row.shape[0]))
+
+        def between(h, da, w_row):
+            gate, up = _gate_up(h, fwd.activation)
+            act = gate * up
+            da = da.astype(jnp.float32)
+            w_row = w_row[:, None]
+            _, slope = jax.jvp(ACTIVATIONS[fwd.activation],
+                               (h[:, :width].astype(jnp.float32),),
+                               (jnp.ones_like(gate),))
+            dw_row = jnp.sum(da * act, axis=-1)
+            da = da * w_row
+            dh = jnp.concatenate([da * up * slope, da * gate], axis=-1)
+            return dw_row, (w_row * act).astype(x.dtype), dh.astype(x.dtype)
+
+        dw_row, weighted, dh = _over_held_rows(between, sizes,
+                                               (h, da, w_row))
+        rows = back.shape[0]
+        dweights = _moved(dw_row[:rows], order[:rows]).reshape(-1, k)
         dweights = jnp.where(held, dweights, 0.0).reshape(experts.shape)
-        dw_down = grouped_matmul_weights_grad(
-            (w_row * act).astype(x.dtype), dys, sizes)
-        da = da * w_row
-        _, slope = jax.jvp(ACTIVATIONS[fwd.activation],
-                           (h[:, :width].astype(jnp.float32),),
-                           (jnp.ones_like(gate),))
-        dh = jnp.concatenate([da * up * slope, da * gate],
-                             axis=-1).astype(x.dtype)
+        dw_down = grouped_matmul_weights_grad(weighted, dys, sizes)
         dw_gate_up = grouped_matmul_weights_grad(xs, dh, sizes)
         dxs = grouped_matmul_rows_grad(dh, w_gate_up, sizes)
         dx = jnp.sum(dxs[back].reshape(-1, k, dxs.shape[-1])

@@ -950,9 +950,11 @@ def _sparse_decoder_step_text(v5e_device, monkeypatch):
     from hetu_tpu.executor import TPU_TRAIN_STEP_OPTIONS
     from hetu_tpu.models import SparseDecoderConfig, \
         SparseDecoderLMHeadModel
-    from hetu_tpu.ops import attention
+    from hetu_tpu.ops import attention, moe
 
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    # the row buffers' allocation as the chip runs it: a kernel
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
     model = SparseDecoderLMHeadModel(SparseDecoderConfig(
         vocab_size=1024, hidden_size=2560, num_attention_heads=28,
         num_key_value_heads=4, head_dim=128, window_layout=[1],
@@ -988,13 +990,57 @@ def test_sparse_decoder_step_holds_the_banded_grouped_backward(
     the experts' three kinds of grouped product (two of each) and the
     token table's sparse row update (PR 51): nine kernels."""
     text = _sparse_decoder_step_text(v5e[0], monkeypatch)
-    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert text.count('custom_call_target="tpu_custom_call"') == 9 + 4
     for name in ("hetu_flash_gqa_window_fwd", "hetu_flash_gqa_window_bwd",
                  "hetu_moe_experts_dx", "hetu_moe_experts_dw",
-                 "hetu_sparse_rows_update"):
+                 "hetu_sparse_rows_update", "hetu_moe_rows_buffer"):
         assert name in text, name
     # a group's dk / dv leave the backward summed over its 7 heads
     assert "bf16[1,8192,512]" in text
+
+
+def test_sparse_decoder_step_runs_the_experts_passes_to_the_held_extent(
+        v5e, monkeypatch):
+    """The expert layer's composed passes as the chip's compiler leaves
+    them (PR 53). Four loops (the forward's ``flat[token]`` is also the
+    backward's; ``dy[token]``; the activation; the backward's
+    elementwise work), each with its row buffers out of ONE
+    ``hetu_moe_rows_buffer`` call: nothing fills a ``[T x k, ...]``
+    buffer before a loop does (no broadcast into one). A tile's results
+    are written in place: no ``dynamic-update-slice`` stands alone in a
+    loop's body with a ``[T x k, width]`` result (one did while the
+    tile's start lay behind a ``minimum``: a copy of every tile). And outside
+    the loops no instruction with a ``[T x k, hidden]`` result reads the
+    ``[T, hidden]`` tokens: the whole-array gathers that are left are
+    the way back's."""
+    from hlo_matmuls import _computations, describe
+    text = _sparse_decoder_step_text(v5e[0], monkeypatch)
+    rows, tokens, hidden = 8192 * 6, 8192, 2560
+    comps, entry = _computations(text)
+    buffers = [v for v in comps[entry].values()
+               if "hetu_moe_rows_buffer" in v[3] and v[1] == "custom-call"]
+    assert len(buffers) == 4
+    for name, (result, opcode, _, _) in comps[entry].items():
+        if opcode == "broadcast" and not result.startswith("("):
+            dims = describe(result)[1]
+            assert not (len(dims) == 2 and dims[0] == rows), name
+    bodies = {re.search(r"body=(%[\w.\-]+)", v[3]).group(1)
+              for v in comps[entry].values()
+              if v[1] == "while" and "HeldExperts" in v[3]
+              and "searchsorted" not in v[3]}     # the kernels' own
+    assert len(bodies) == 4
+    for body in bodies:
+        for name, (result, opcode, _, _) in comps[body].items():
+            if opcode == "dynamic-update-slice":    # a [rows] vector may
+                dims = describe(result)[1]
+                assert not (len(dims) == 2 and dims[0] == rows), (body, name)
+    for name, (result, opcode, operands, _) in comps[entry].items():
+        if opcode in ("fusion", "gather") and result.startswith("bf16[") \
+                and describe(result)[1] == (rows, hidden):
+            reads = [describe(comps[entry][o][0])[1] for o in operands
+                     if o in comps[entry]
+                     and not comps[entry][o][0].startswith("(")]
+            assert (tokens, hidden) not in reads, name
 
 
 # (rows, width, ids a step) of the tables the train cells update sparsely

@@ -15,6 +15,7 @@ share test: the four shares' expert sums add up to the uncut layer's
 and the four vocabulary slices' logits concatenate to the whole head's.
 """
 import os
+import re
 import sys
 
 import jax
@@ -341,6 +342,8 @@ def _expert_case(routing, hidden=HIDDEN, width=WIDTH, held=4, first=2,
                           for _ in range(tokens[0] * tokens[1])])
     elif routing == "all_to_one":       # every token's first pick: expert 3
         picks = np.tile(np.array([3, 0, 7]), (tokens[0] * tokens[1], 1))
+    elif routing == "all_held":         # every pick one of experts 2 .. 5
+        picks = np.tile(np.array([5, 2, 3]), (tokens[0] * tokens[1], 1))
     else:                               # "none_held": experts 0, 1, 6, 7
         picks = np.tile(np.array([0, 7, 1]), (tokens[0] * tokens[1], 1))
     picks = picks.reshape(*tokens, TOP_K).astype(np.int32)
@@ -374,19 +377,110 @@ def test_held_experts_kernels_interpreted(kernels, routing):
     padded to the kernel's tile) give what the ragged products give."""
     arrays, first = _expert_case(routing, hidden=128, width=128,
                                  tokens=(1, 40))
-    dy = jnp.asarray(np.random.RandomState(9).randn(1, 40, 128), jnp.float32)
-    nodes = [ht.Variable(f"n{i}", trainable=False) for i in range(5)]
-    op = ht.held_experts_op(*nodes, first=first, activation="relu")
-    packed = op.gradient(ht.Variable("dy", trainable=False))[0].inputs[0]
+    dy = np.random.RandomState(9).randn(1, 40, 128).astype(np.float32)
+    out, grads = _op_and_packed_gradients(arrays, first, dy)
     values = [jnp.asarray(a) for a in arrays]
-    ectx = _Training()
-    out = op.compute(values, ectx)
-    grads = packed.compute(values + [dy], ectx)
     want, vjp = jax.vjp(lambda x, w, w_in, w_out: _plain_experts(
         x, w, values[2], w_in, w_out, first),
         values[0], values[1], values[3], values[4])
     close(out, want, 1e-5)
-    for got, wanted in zip(grads, vjp(dy)):
+    for got, wanted in zip(grads, vjp(jnp.asarray(dy))):
+        close(got, wanted, 1e-5)
+
+
+def _whole_array_experts(x, weights, picks, w_in, w_out, first,
+                         act=jax.nn.relu):
+    """The graph op as it stood before the held extent, kept here as the
+    plain reference: every pass over ALL ``T x k`` sorted rows (the
+    gather, both ragged products with the rows of the last group zeroed,
+    the activation, the way back), differentiated by ``jax.vjp``."""
+    k, hidden = picks.shape[-1], x.shape[-1]
+    held_n, width = w_in.shape[0], w_in.shape[-1] // 2
+    local = picks.reshape(-1, k) - first
+    held = (local >= 0) & (local < held_n)
+    group = jnp.where(held, local, held_n).reshape(-1)
+    sizes = jnp.zeros(held_n + 1, jnp.int32).at[group].add(1)
+    order = jnp.argsort(group, stable=True)
+    rows = order.shape[0]
+    here = jnp.arange(rows)[:, None] < jnp.sum(sizes[:-1])
+    xs = x.reshape(-1, hidden)[order // k]
+    h = jnp.where(here, jax.lax.ragged_dot(xs, w_in, sizes[:-1]), 0.0)
+    a = act(h[:, :width]) * h[:, width:]
+    ys = jnp.where(here, jax.lax.ragged_dot(a, w_out, sizes[:-1]), 0.0)
+    back = jnp.zeros(rows, jnp.int32).at[order].set(
+        jnp.arange(rows, dtype=jnp.int32))
+    out = jnp.einsum("tk,tkh->th",
+                     jnp.where(held, weights.reshape(-1, k), 0.0),
+                     ys[back].reshape(-1, k, hidden))
+    return out.reshape(x.shape)
+
+
+def _op_and_packed_gradients(arrays, first, upstream, activation="relu"):
+    """The op's value and its packed ``(dx, dweights, dw_in, dw_out)``
+    by ``compute`` under a training context, no executor."""
+    nodes = [ht.Variable(f"n{i}", trainable=False) for i in range(5)]
+    op = ht.held_experts_op(*nodes, first=first, activation=activation)
+    packed = op.gradient(ht.Variable("dy", trainable=False))[0].inputs[0]
+    values = [jnp.asarray(a) for a in arrays]
+    ectx = _Training()
+    out = op.compute(values, ectx)
+    return out, packed.compute(values + [jnp.asarray(upstream)], ectx)
+
+
+def _nan_buffers(shapes, after):
+    return tuple(jnp.full(s.shape, jnp.nan, s.dtype) for s in shapes)
+
+
+# (routing, the pass's row tile, interpreted kernels, poisoned buffers);
+# 2 x 32 tokens x 3 picks = 192 sorted rows composed, 1 x 40 x 3 = 120
+# padded to 128 under the kernels
+EXTENT_CASES = {
+    "no_pair_held": ("none_held", 16, False, False),
+    "every_pair_held": ("all_held", 16, False, False),
+    "uneven_routing": ("all_to_one", 16, False, False),
+    "extent_no_multiple_of_the_tile": ("random", 16, False, False),
+    "rows_no_multiple_of_the_tile": ("random", 80, False, False),
+    "rows_under_one_tile": ("random", 2048, False, False),
+    "kernels_interpreted": ("random", 48, True, False),
+    "kernels_interpreted_every_pair_held": ("all_held", 48, True, False),
+    "rows_past_the_extent_poisoned": ("random", 16, False, True),
+    "rows_past_the_extent_poisoned_kernels": ("random", 48, True, True),
+    "no_pair_held_poisoned_kernels": ("none_held", 48, True, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXTENT_CASES))
+def test_held_experts_passes_run_to_the_held_extent(case, monkeypatch):
+    """Value and all four gradients of the op, whose composed passes
+    run the row tiles below the held extent, against the whole-array
+    form. A poisoned case starts every carried buffer from NaN: a row
+    past the extent that reached a result would show."""
+    routing, tile, interpreted, poisoned = EXTENT_CASES[case]
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    if interpreted:
+        monkeypatch.setattr(moe, "INTERPRET", True)
+        monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+        arrays, first = _expert_case(routing, hidden=128, width=128,
+                                     tokens=(1, 40))
+    else:
+        arrays, first = _expert_case(routing)
+    if poisoned:
+        monkeypatch.setattr(moe, "_fresh", _nan_buffers)
+    picks = arrays[2]
+    held = int(((picks >= first) & (picks < first + 4)).sum())
+    if case == "extent_no_multiple_of_the_tile":
+        assert held % tile and held > tile
+    upstream = np.random.RandomState(9).randn(*arrays[0].shape).astype(
+        np.float32)
+    out, grads = _op_and_packed_gradients(arrays, first, upstream)
+    values = [jnp.asarray(a) for a in arrays]
+    want, vjp = jax.vjp(lambda x, w, w_in, w_out: _whole_array_experts(
+        x, w, values[2], w_in, w_out, first),
+        values[0], values[1], values[3], values[4])
+    assert np.isfinite(np.asarray(out)).all()
+    close(out, want, 1e-5)
+    for got, wanted in zip(grads, vjp(jnp.asarray(upstream))):
+        assert np.isfinite(np.asarray(got)).all()
         close(got, wanted, 1e-5)
 
 
@@ -574,7 +668,8 @@ def test_two_adam_steps_reproduce_the_references_losses():
     np.testing.assert_allclose(got, want, rtol=5e-3)
 
 
-def test_the_step_counts_rows_and_visits_on_the_device():
+def test_the_step_counts_rows_and_visits_on_the_device(monkeypatch):
+    monkeypatch.setattr(moe, "ROW_TILE", 16)    # 192 sorted rows: 12 tiles
     config = tiny_config()
     g = Graph(config, lambda g: {"default": [
         g.loss, ht.optim.SGDOptimizer(0.0).minimize(g.loss)]})
@@ -591,7 +686,80 @@ def test_the_step_counts_rows_and_visits_on_the_device():
         assert c["moe_rows_by_expert"] == [3 * n for n in by_hand]
         assert c["moe_routed_rows"] == 3 * sum(by_hand)
         assert c["moe_expert_visits"] == 3 * sum(n > 0 for n in by_hand)
+        assert c["moe_row_tiles"] == 3 * -(-sum(by_hand) // 16)
+        assert c["moe_row_tiles_of"] == 3 * 12
+        assert 0 < c["moe_row_tiles"] < c["moe_row_tiles_of"]
         assert c["steps"] == 3
+
+
+def _hlo_computations(text):
+    """``{computation: [(name, result type, opcode, [operand names],
+    the line)]}`` of a lowered (not yet optimised) HLO module's text."""
+    found, body = {}, None
+    for line in text.split("\n"):
+        if line.endswith("{") and not line.startswith(" "):
+            body = found.setdefault(line.split()[-2], [])
+        m = re.match(r"\s+(?:ROOT )?([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([\w\-]+)\((.*?)\)(?:, |$)", line)
+        if m and body is not None:
+            body.append((m.group(1), m.group(2), m.group(3),
+                         re.findall(r"[\w.\-]+", m.group(4)), line))
+    return found
+
+
+def _dims(type_text):
+    return tuple(int(d) for d in
+                 re.search(r"\[([\d,]*)\]", type_text).group(1).split(",")
+                 if d)
+
+
+def test_no_gather_of_the_tokens_to_all_sorted_rows_outside_a_loop(
+        monkeypatch):
+    """In the lowered training step, ``flat[token]`` and ``dy[token]``
+    of every expert layer (a gather from the ``[T, hidden]`` tokens) are
+    row tiles inside a ``while`` body, in both directions, and the only
+    gathers with a ``[T x k, hidden]`` result are the way back's, which
+    read the ``[T x k, hidden]`` sorted rows (``ys[back]``,
+    ``dxs[back]``: indexed by (token, pick), the next issue's): a
+    refactor that puts a whole-array pass back fails here."""
+    tile = 16
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    g = Graph(tiny_config(), lambda g: {"default": [
+        g.loss, ht.optim.SGDOptimizer(0.1).minimize(g.loss)]})
+    ids, labels = batch()
+    sub = g.executor.subexecutors["default"]
+    feed = {g.ids: ids, g.labels: labels}
+    step = sub.prepare(g.executor, feed)
+    text = jax.jit(step).lower(*sub.trace_args(g.executor, feed)) \
+        .compiler_ir(dialect="hlo").as_hlo_text()
+    comps = _hlo_computations(text)
+    called = {c: {w for *_, line in instrs for w in re.findall(
+        r"(?:body|to_apply|calls)=([\w.\-]+)", line)}
+        for c, instrs in comps.items()}
+    in_a_loop = {w for instrs in comps.values() for *_, op, _, line in instrs
+                 if op == "while"
+                 for w in re.findall(r"body=([\w.\-]+)", line)}
+    grown = True
+    while grown:
+        more = {w for c in in_a_loop for w in called.get(c, ())} - in_a_loop
+        in_a_loop |= more
+        grown = bool(more)
+    tokens, rows = ids.size, ids.size * TOP_K
+    tiled, way_back = [], []
+    for comp, instrs in comps.items():
+        types = {name: result for name, result, *_ in instrs}
+        for name, result, opcode, operands, _ in instrs:
+            if opcode != "gather":
+                continue
+            source, got = _dims(types[operands[0]]), _dims(result)
+            if source == (tokens, HIDDEN) and got[1:] == (HIDDEN,):
+                assert comp in in_a_loop and got[0] == tile, (comp, name)
+                tiled.append(name)
+            if got == (rows, HIDDEN):
+                assert source == (rows, HIDDEN), (comp, name)
+                way_back.append(name)
+    # a layer: flat[token] forward, flat[token] and dy[token] backward
+    assert len(tiled) == 3 * 4 and len(way_back) == 2 * 4
 
 
 # -- the share test ----------------------------------------------------------
