@@ -60,7 +60,8 @@ def token_chunks(fn, x, valid):
 def feed_forward(config, blk, x, valid):
     """One layer's feed-forward on ``x [T, H]``: SwiGLU in a dense
     layer; in an expert layer the shared expert plus the held experts'
-    part of the routed sum. Returns ``(y [T, H], the router's picks
+    part of the routed sum, the router group-limited where
+    ``config.n_group`` is over 1. Returns ``(y [T, H], the router's picks
     [T, k] int32 (zeros in a dense layer), (rows by held expert [held]
     int32, held experts visited))``."""
     import jax.numpy as jnp
@@ -73,7 +74,7 @@ def feed_forward(config, blk, x, valid):
                 (jnp.zeros(held, jnp.int32), jnp.int32(0)))
     experts, weights, _ = moe.route(
         x, blk["router"], blk["router_bias"], config.num_experts_per_tok,
-        config.routed_scaling_factor)
+        config.routed_scaling_factor, config.n_group, config.topk_group)
     routed, rows = moe.held_experts(
         x, experts, weights, valid, blk["experts_gate_up"],
         blk["experts_down"], first=config.experts_held[0])

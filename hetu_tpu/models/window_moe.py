@@ -134,6 +134,9 @@ class WindowMoEConfig:
     def routed_scaling_factor(self):
         return self.route_scale
 
+    # and what it asks besides: this router picks among all its experts
+    n_group = topk_group = 1
+
     def is_dense(self, layer):
         return layer < self.num_dense_layers
 

@@ -116,7 +116,7 @@ def swiglu(x, w_gate_up, w_down):
     return (jax.nn.silu(h[..., :width]) * h[..., width:]) @ w_down
 
 
-def route(x, w_router, bias, top_k, scale):
+def route(x, w_router, bias, top_k, scale, n_group=1, topk_group=1):
     """Router of the aux-loss-free family over ALL experts.
 
     ``x`` ``[T, hidden]``; ``w_router`` ``[hidden, E]`` and ``bias``
@@ -125,12 +125,25 @@ def route(x, w_router, bias, top_k, scale):
     operands to bfloat16, and two scores that nearly tie would flip);
     the bias is added for the SELECTION of the ``top_k`` and never
     enters a weight; the chosen scores are normalised to sum 1 and
-    scaled. Returns ``(experts [T, k] int32, weights [T, k] float32,
+    scaled. With ``n_group > 1`` the selection is GROUP-LIMITED: the
+    experts lie in ``n_group`` groups of consecutive ids, a group's
+    score is the sum of its two largest ``score + bias``, and only the
+    experts of the ``topk_group`` best groups may be picked (under
+    expert parallelism a token then visits that many groups' chips at
+    most). Returns ``(experts [T, k] int32, weights [T, k] float32,
     scores [T, E] float32)``."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        grouped = choice.reshape(*choice.shape[:-1], n_group, -1)
+        best_two, _ = jax.lax.top_k(grouped, 2)
+        _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+        keep = jnp.any(kept[..., None] == jnp.arange(n_group), axis=-2)
+        choice = jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
+            choice.shape)
+    _, experts = jax.lax.top_k(choice, top_k)
     picked = jnp.take_along_axis(scores, experts, axis=-1)
     weights = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
     return experts.astype(jnp.int32), weights, scores

@@ -244,6 +244,12 @@ SPAN_SCHEMA = {
     # platform off a TPU)
     "ssm_plan": {"op": _req(_STR), "form": _req(_STR),
                  "reason": _opt(_STR)},
+    # ops/kda.py: the form a traced call of the delta rule runs in:
+    # op (chunk / step), form (kernel / composed), reason (composed
+    # only: "platform", or why supported() says the kernels do not take
+    # the head's widths)
+    "kda_plan": {"op": _req(_STR), "form": _req(_STR),
+                 "reason": _opt(_STR)},
     # which form a traced program's window layers attend in (models/
     # window_moe.py): op prefill (the flash forward with the band:
     # kernel on a TPU, hetu_flash_window; composed off one, reason

@@ -140,6 +140,42 @@ def test_cache_prefix_hit_shares_blocks_and_caps_at_last_token():
     cache.assert_consistent()
 
 
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_free_seq_hands_its_blocks_back_in_one_allocator_call(prefix_cache):
+    """A finished sequence's table goes back in ONE ``allocator.free``
+    (a call re-sorts the whole free list: a block at a time that was
+    hundreds of milliseconds for a long sequence in a large pool), and
+    the pool is left as a block at a time left it: the free list sorted,
+    cached blocks evictable in the table's order."""
+    cache = PagedKVCache(_cfg(), num_blocks=16, block_size=4,
+                         prefix_cache=prefix_cache)
+    prompt = np.arange(13, dtype=np.int32)
+    if prefix_cache:
+        blocks, _ = cache.add_seq_prefix(0, 22, prompt)
+        cache.insert_prefix(0, prompt)
+    else:
+        blocks = list(cache.add_seq(0, 22))
+    assert len(blocks) == 6
+    cache.add_seq(1, 5)                 # another sequence's stay put
+    calls, free = [], cache.allocator.free
+    cache.allocator.free = lambda b: calls.append(list(b)) or free(b)
+    cache.free_seq(0)
+    assert calls == [blocks]
+    cache.assert_consistent()
+    # 13 tokens: 3 full blocks and the frozen tail stay the cache's
+    kept = blocks[:4] if prefix_cache else []
+    assert [b for b in blocks if b in cache.allocator._ref] == kept
+    assert list(cache.allocator._free) == sorted(cache.allocator._free)
+    assert set(blocks) - set(kept) <= set(cache.allocator._free)
+    if prefix_cache:
+        assert list(cache.prefix._lru) == kept
+        assert cache.cached_blocks == 4 and cache.referenced_blocks == 2
+    with pytest.raises(ValueError, match="double free"):
+        free(blocks)                    # nothing is released twice
+    cache.free_seq(1)
+    cache.assert_consistent()
+
+
 def test_cache_cow_isolates_sharers():
     """A sequence extending into a shared tail block copies it first:
     the sharer's rows and the cache's frozen entry never see the
