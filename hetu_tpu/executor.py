@@ -1530,7 +1530,11 @@ class Executor:
         step adds ``ceil(held rows / tile)``, the trip count of its
         loops), ``moe_row_tiles_of`` (the tiles that ALL ``T x k`` rows
         are, a step each: the quotient of the two is the share of the
-        passes' work that the held extent leaves standing), ``steps``.
+        passes' work that the held extent leaves standing),
+        ``moe_back_rows`` (the rows of the grouped products' outputs
+        that the way back to token order read: a step adds the held
+        pairs once a direction), ``moe_back_rows_of`` (what all ``T x
+        k`` pairs would be, twice a step), ``steps``.
         Reading it waits for the last step.
         The counts are int32 on the device: a session of more than
         2**31 rows on one expert wraps them."""
@@ -1550,6 +1554,9 @@ class Executor:
                         "moe_row_tiles": int(state.get("moe_row_tiles", 0)),
                         "moe_row_tiles_of": int(
                             state.get("moe_row_tiles_of", 0)),
+                        "moe_back_rows": int(state.get("moe_back_rows", 0)),
+                        "moe_back_rows_of": int(
+                            state.get("moe_back_rows_of", 0)),
                         "steps": int(state["steps"])})
         return out
 

@@ -48,21 +48,32 @@ held here first, and ``n`` is on the device. Every composed pass of the
 op whose result is indexed by SORTED ROW — the gathers ``flat[token]``
 and ``dy[token]``, the activation between the two products, the
 backward's ``da``, ``da * act``, ``w_row * act``, the activation's slope
-and ``dh`` — runs under :func:`_over_held_rows`: a loop over row tiles of ``ROW_TILE``
-rows whose trip count is ``ceil(n / tile)``, each tile written in place
-into a buffer of the full ``[T x k, ...]`` shape that starts with no
-value (:func:`_fresh`). Nothing is chosen: no capacity, no fallback, no
-dropped row; the extent follows the routing. The rows past the last
-tile that ran hold whatever the allocation held, and every reader
-selects them away: the grouped kernels mask their operands and their
-store by group, and the way back (``ys[back]``, ``dxs[back]``: indexed
-by (token, pick), so over all ``T x k`` whatever the extent) reads the
-kernels' own outputs, whose rows behind the held groups are zeros. The
-op's state counts the tiles (``moe_row_tiles``, beside
-``moe_row_tiles_of``, the tiles that all ``T x k`` rows are): their
-quotient is the share of the passes' work that is left. The three
-permutations of a scalar a pair (``back``, ``w_row``, the pairs' weight
-gradient) are sorts (:func:`_moved`), not gathers or scatters.
+and ``dh`` — runs under :func:`_over_held_rows`: a loop over row tiles
+of ``ROW_TILE`` rows whose trip count is ``ceil(n / tile)``, each tile
+written in place into a buffer of the full ``[T x k, ...]`` shape that
+starts with no value (:func:`_fresh`). Nothing is chosen: no capacity,
+no fallback, no dropped row; the extent follows the routing. The rows
+past the last tile that ran hold whatever the allocation held, and every
+reader selects them away: the grouped kernels mask their operands and
+their store by group. The way back to token order (:func:`_token_sums`:
+the forward's weighted sum and the backward's ``dx`` are one function)
+is indexed by (token, pick) and visits the pairs that landed here, too:
+the tokens sorted by how many picks they hold here, a loop over the
+tiles of ``TOKEN_TILE`` tokens that hold any, and inside it a loop over
+a token's held picks that gathers a tile of the product's rows and adds
+it to a float32 tile; a token then reads its sum from its place in that
+order. So nothing reads a row of the op's four grouped products (``h``,
+``ys``, ``da``, ``dxs``) behind the held groups, and the op has the
+kernels write into buffers that start with no value
+(``grouped_matmul(..., out=)``): the library's zero fill behind the
+groups is serving's alone. The op's state counts the tiles
+(``moe_row_tiles``, beside ``moe_row_tiles_of``, the tiles that all ``T
+x k`` rows are: their quotient is the share of the passes' work that is
+left) and the rows the way back read (``moe_back_rows``, beside
+``moe_back_rows_of``, the ``T x k`` a direction that all the pairs
+are). The permutations of a scalar a pair or a token (``back``,
+``w_row``, the pairs' weight gradient, a token's place in the way
+back's order) are sorts (:func:`_moved`), not gathers or scatters.
 """
 from __future__ import annotations
 
@@ -101,6 +112,10 @@ TOKEN_CHUNK = 4096
 # bfloat16, tens of microseconds beside a loop iteration's few, and a
 # pass rounds its rows up by half a tile on average
 ROW_TILE = 2048
+# sorted tokens the way back takes at a time (``_token_sums``): each of
+# a token's k ranks of held picks rounds its tokens up to a tile, so a
+# small one (14 us of gather); 256 and 1,024 both ran slower on the chip
+TOKEN_TILE = 512
 
 
 def _use_pallas():
@@ -166,13 +181,15 @@ def _kernel(tiles, out_dtype, interpret):
     """The megablox kernel behind a jitted function of the stable
     name. The library's own entry point is a ``jax.jit`` called
     ``gmm``, and a program's instructions are named for the innermost
-    jitted function, so its body is wrapped anew."""
+    jitted function, so its body is wrapped anew. Given ``out`` (the
+    graph op's: :func:`grouped_matmul`), the kernel writes the held
+    groups' rows into it and the library fills nothing behind them."""
     gmm = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm").gmm.__wrapped__
 
-    def hetu_moe_experts(lhs, rhs, group_sizes):
+    def hetu_moe_experts(lhs, rhs, group_sizes, out=None):
         return gmm(lhs, rhs, group_sizes, preferred_element_type=out_dtype,
-                   tiling=tiles, interpret=interpret)
+                   tiling=tiles, interpret=interpret, existing_out=out)
 
     hetu_moe_experts.__name__ = hetu_moe_experts.__qualname__ = KERNEL_NAME
     return jax.jit(hetu_moe_experts)
@@ -188,10 +205,11 @@ def _grad_kernel(which, tiles, out_dtype, interpret, groups=None):
     lib = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
     if which == "rows":
-        def fn(dy, rhs, group_sizes):
+        def fn(dy, rhs, group_sizes, out=None):
             return lib.gmm.__wrapped__(
                 dy, rhs, group_sizes, preferred_element_type=out_dtype,
-                tiling=tiles, transpose_rhs=True, interpret=interpret)
+                tiling=tiles, transpose_rhs=True, interpret=interpret,
+                existing_out=out)
         name = ROWS_GRAD_KERNEL_NAME
     else:
         def fn(lhs, dy, group_sizes):
@@ -208,24 +226,28 @@ def _grad_kernel(which, tiles, out_dtype, interpret, groups=None):
 INTERPRET = False
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def grouped_matmul(lhs, rhs, group_sizes, out=None):
     """``out[rows of group g] = lhs[rows of group g] @ rhs[g]``.
 
     ``lhs`` ``[m, k]`` with its rows sorted by group; ``rhs`` ``[G, k,
     n]``; ``group_sizes`` ``[G + 1]`` int32 — the last entry counts the
     rows behind the ``G`` groups (pairs of experts held elsewhere),
     which are not computed and come back as zeros. Returns ``[m, n]``
-    in ``lhs``'s dtype, accumulated in float32."""
+    in ``lhs``'s dtype, accumulated in float32.
+
+    A caller that reads no row behind the held groups (the graph op)
+    passes ``out``, an ``[m, n]`` array in ``lhs``'s dtype: the held
+    groups' rows are written into it and the rows behind them keep what
+    it held, so nothing is spent on zeros that nobody reads."""
     m, k = lhs.shape
     tiles = _kernel_tiles(m, k, rhs.shape[-1]) \
         if (_use_pallas() or INTERPRET) else None
     if tiles is not None:
         return _kernel(tiles, jnp.dtype(lhs.dtype), INTERPRET)(
-            lhs, rhs, group_sizes)
-    out = jax.lax.ragged_dot(lhs, rhs, group_sizes[:-1],
+            lhs, rhs, group_sizes, out)
+    got = jax.lax.ragged_dot(lhs, rhs, group_sizes[:-1],
                              preferred_element_type=jnp.float32)
-    computed = jnp.arange(m)[:, None] < jnp.sum(group_sizes[:-1])
-    return jnp.where(computed, out, 0.0).astype(lhs.dtype)
+    return _behind_kept(got, group_sizes, out).astype(lhs.dtype)
 
 
 def _computed_rows(m, group_sizes):
@@ -234,20 +256,26 @@ def _computed_rows(m, group_sizes):
     return jnp.arange(m)[:, None] < jnp.sum(group_sizes[:-1])
 
 
-def grouped_matmul_rows_grad(dy, rhs, group_sizes):
+def _behind_kept(got, group_sizes, out):
+    """A ragged product's rows with those behind the held groups as the
+    kernels leave them: zeros, or what the caller's ``out`` held."""
+    return jnp.where(_computed_rows(got.shape[0], group_sizes), got,
+                     0.0 if out is None else out)
+
+
+def grouped_matmul_rows_grad(dy, rhs, group_sizes, out=None):
     """``d lhs`` of :func:`grouped_matmul`: ``dy[rows of g] @ rhs[g]^T``,
     ``[m, k]`` in ``dy``'s dtype; the rows behind the held groups come
-    back as zeros."""
+    back as zeros, or as ``out``'s where that is given."""
     m, n = dy.shape
     tiles = _kernel_tiles(m, n, rhs.shape[1]) \
         if (_use_pallas() or INTERPRET) else None
     if tiles is not None:
         return _grad_kernel("rows", tiles, jnp.dtype(dy.dtype), INTERPRET)(
-            dy, rhs, group_sizes)
-    out = jax.lax.ragged_dot(dy, rhs.swapaxes(1, 2), group_sizes[:-1],
+            dy, rhs, group_sizes, out)
+    got = jax.lax.ragged_dot(dy, rhs.swapaxes(1, 2), group_sizes[:-1],
                              preferred_element_type=jnp.float32)
-    return jnp.where(_computed_rows(m, group_sizes), out,
-                     0.0).astype(dy.dtype)
+    return _behind_kept(got, group_sizes, out).astype(dy.dtype)
 
 
 def grouped_matmul_weights_grad(lhs, dy, group_sizes):
@@ -479,8 +507,8 @@ def _fresh(shapes, after):
     """The starts of the buffers that :func:`_over_held_rows` fills tile
     by tile (``shapes``: a ``ShapeDtypeStruct`` each): no value at all.
     Every reader of such a buffer SELECTS the rows past the extent away
-    (the grouped kernels mask by group, the way back by ``held``); none
-    multiplies them by zero.
+    (the grouped kernels mask by group, the way back reads none of
+    them); none multiplies them by zero.
 
     Where the kernels run they are the results of ONE kernel that takes
     the pass's operands ``after`` and writes nothing: allocations the
@@ -510,6 +538,17 @@ def _held_row_tiles(sizes, rows):
     return tile, (jnp.sum(sizes[:-1]) + (tile - 1)) // tile
 
 
+def _tile_start(i, tile, rows):
+    """Where tile ``i`` of ``rows`` rows starts. A plain multiple of the
+    tile where the tiles divide the rows: the compiler then knows the
+    slices aligned and writes a tile's results in place (behind a
+    ``minimum`` it computed them into a buffer of their own and copied
+    that: 41 of 55 us a tile); else the last tile overlaps the one
+    before it."""
+    return i * tile if rows % tile == 0 \
+        else jnp.minimum(i * tile, rows - tile)
+
+
 def _over_held_rows(fn, sizes, by_row, whole=()):
     """``fn`` over the sorted rows that landed here, and over no other.
 
@@ -528,12 +567,7 @@ def _over_held_rows(fn, sizes, by_row, whole=()):
         (tile,) + a.shape[1:], a.dtype) for a in by_row), *whole)
 
     def body(i, filled):
-        # a plain multiple of the tile where the tiles divide the rows:
-        # the compiler then knows the slices aligned and writes a tile's
-        # results in place (behind a ``minimum`` it computed them into a
-        # buffer of their own and copied that: 41 of 55 us a tile)
-        at = i * tile if rows % tile == 0 \
-            else jnp.minimum(i * tile, rows - tile)
+        at = _tile_start(i, tile, rows)
         got = fn(*(jax.lax.dynamic_slice_in_dim(a, at, tile)
                    for a in by_row), *whole)
         return tuple(jax.lax.dynamic_update_slice_in_dim(f, g, at, 0)
@@ -562,6 +596,89 @@ def _gate_up(h, activation):
             h[:, width:].astype(jnp.float32))
 
 
+def _way_back_counted(state, sizes, back):
+    """One direction's way back added to the op's two counters of it:
+    the rows of a product's output that it read, and the ``T x k`` that
+    all the pairs are (a state restored from before them has none)."""
+    return {"moe_back_rows": state.get("moe_back_rows", 0)
+            + jnp.sum(sizes[:-1]),
+            "moe_back_rows_of": state.get("moe_back_rows_of", 0)
+            + jnp.int32(back.shape[0])}
+
+
+def _into_fresh(product, lhs, rhs, sizes):
+    """A grouped product of the graph op (``grouped_matmul`` or
+    ``grouped_matmul_rows_grad``) written into a buffer that starts with
+    no value: every reader of the op's four row products stops at the
+    held extent, so no zeros are written behind it."""
+    (out,) = _fresh([jax.eval_shape(product, lhs, rhs, sizes)], (lhs,))
+    return product(lhs, rhs, sizes, out=out)
+
+
+def _token_sums(rows, back, held, coeff, dtype):
+    """The way back to token order: ``sums[t] = sum over the picks j of
+    token t that are held here of coeff[t, j] * rows[back[t, j]]``
+    (``coeff`` None: 1), accumulated in float32 and cast to ``dtype``
+    once. ``rows`` ``[T x k (+ pad), hidden]`` a product's output by
+    sorted row, ``back`` ``[T x k]`` a pair's sorted row, ``held`` ``[T,
+    k]``. Returns ``[T, hidden]``; a token none of whose picks is held
+    gets exactly 0.
+
+    It visits the pairs that landed here and reads their rows, none past
+    the held extent (where a product of the op leaves no defined value).
+    The tokens are sorted by HOW MANY of their picks are held, most
+    first; the tokens that hold an ``r``-th pick are then the first
+    ``live[r]`` of that order, whatever ``r``. A loop over tiles of
+    ``TOKEN_TILE`` sorted tokens runs the tiles that hold a pair at all
+    (``ceil(live[0] / tile)``); inside it a loop over ``r`` runs as far
+    as the tile's first token holds picks, gathers the tile's ``r``-th
+    rows and adds them to a float32 tile, selecting away the tokens past
+    ``live[r]`` (their index reads row 0, a held row wherever a tile
+    runs). The gathers read ``n`` rows plus under a tile a rank; nothing
+    is shifted and no ``[T x k, ...]`` array is made. Last, a token
+    reads the sum at its place in the sorted order."""
+    tokens, k = held.shape
+    count = jnp.sum(held, axis=1, dtype=jnp.int32)
+    # a token's r-th held pick, [T, pick, r]: its row and coefficient
+    # by r (row 0 and no weight where the token holds fewer)
+    rank = jnp.cumsum(held, axis=1, dtype=jnp.int32) - 1
+    is_rth = held[:, :, None] & (rank[:, :, None] == jnp.arange(k))
+    by_rank = [jnp.where(is_rth, a.reshape(tokens, k, 1), 0).sum(axis=1)
+               for a in ([back] if coeff is None else [back, coeff])]
+    # one sort carries a token's k rows (and coefficients) to its place
+    most, order, *sorted_by_rank = jax.lax.sort(
+        [-count, jnp.arange(tokens, dtype=jnp.int32)]
+        + [a[:, r] for a in by_rank for r in range(k)], num_keys=1)
+    source = jnp.stack(sorted_by_rank[:k])                      # [r, T]
+    scale = jnp.stack(sorted_by_rank[k:]) if coeff is not None else None
+    live = jnp.sum(count[None, :] > jnp.arange(k)[:, None], axis=1,
+                   dtype=jnp.int32)
+    tile = min(TOKEN_TILE, tokens)
+    place_in_tile = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+
+    def a_tile(i, sums):
+        at = _tile_start(i, tile, tokens)
+
+        def a_rank(r, acc):
+            got = rows[jax.lax.dynamic_slice(source, (r, at), (1, tile))[0]] \
+                .astype(jnp.float32)
+            if scale is not None:
+                got = got * jax.lax.dynamic_slice(
+                    scale, (r, at), (1, tile))[0][:, None]
+            return acc + jnp.where(at + place_in_tile < live[r], got, 0.0)
+
+        acc = jax.lax.fori_loop(0, -most[at], a_rank, jnp.zeros(
+            (tile, rows.shape[1]), jnp.float32))
+        return jax.lax.dynamic_update_slice_in_dim(
+            sums, acc.astype(dtype), at, 0)
+
+    (sums,) = _fresh([jax.ShapeDtypeStruct((tokens, rows.shape[1]), dtype)],
+                     (rows,))
+    sums = jax.lax.fori_loop(0, (live[0] + tile - 1) // tile, a_tile, sums)
+    place = _moved(jnp.arange(tokens, dtype=jnp.int32), order)
+    return jnp.where((count > 0)[:, None], sums[place], 0)
+
+
 class HeldExpertsOp(Op):
     """:func:`held_experts` as a graph node: ``x [B, S, hidden]``, the
     router's ``weights`` and ``experts`` ``[B, S, k]``, and the stacked
@@ -576,7 +693,8 @@ class HeldExpertsOp(Op):
 
     A training step counts on the device, in the op's state: rows by
     held expert, held experts that got a row, the row tiles its passes
-    ran and the tiles that all the rows are, steps
+    ran and the tiles that all the rows are, the rows the way back read
+    and the pairs there are, steps
     (``Executor.moe_counters()``; nothing is read inside a step)."""
 
     stateful = True
@@ -595,43 +713,43 @@ class HeldExpertsOp(Op):
     def state_shapes(self, input_shapes):
         return {"moe_rows_by_expert": (input_shapes[3][0],),
                 "moe_expert_visits": (), "moe_row_tiles": (),
-                "moe_row_tiles_of": (), "steps": ()}
+                "moe_row_tiles_of": (), "moe_back_rows": (),
+                "moe_back_rows_of": (), "steps": ()}
 
     def compute(self, input_vals, ectx):
         x, weights, experts, w_gate_up, w_down = input_vals
-        flat, weights, _, order, sizes, back, token = _expert_rows(
+        flat, weights, held, order, sizes, back, token = _expert_rows(
             x, weights, experts, self.first, w_gate_up.shape[0])
-        k = experts.shape[-1]
-        h = grouped_matmul(_held_rows_of(flat, token, sizes), w_gate_up,
-                           sizes)
+        h = _into_fresh(grouped_matmul, _held_rows_of(flat, token, sizes),
+                        w_gate_up, sizes)
 
         def activated(h):
             gate, up = _gate_up(h, self.activation)
             return ((gate * up).astype(x.dtype),)
 
         (act,) = _over_held_rows(activated, sizes, (h,))
-        ys = grouped_matmul(act, w_down, sizes)
-        out = jnp.einsum("tk,tkh->th", weights,
-                         ys[back].reshape(-1, k, ys.shape[-1])
-                         .astype(jnp.float32))
+        ys = _into_fresh(grouped_matmul, act, w_down, sizes)
+        out = _token_sums(ys, back, held, weights, x.dtype)
         if ectx.training:
             # the backward reads the sort and the first product again
             ectx.cache[("held_experts", self.id)] = (order, sizes, back, h)
             state = ectx.get_state(self)
             if state is not None:
-                held = sizes[:-1]
+                landed = sizes[:-1]
                 rows = order.shape[0]
                 tile, tiles = _held_row_tiles(sizes, rows)
                 ectx.put_state(self, {
-                    "moe_rows_by_expert": state["moe_rows_by_expert"] + held,
+                    "moe_rows_by_expert": state["moe_rows_by_expert"]
+                    + landed,
                     "moe_expert_visits": state["moe_expert_visits"]
-                    + jnp.sum(held > 0, dtype=jnp.int32),
+                    + jnp.sum(landed > 0, dtype=jnp.int32),
                     # (a state restored from before these two has none)
                     "moe_row_tiles": state.get("moe_row_tiles", 0) + tiles,
                     "moe_row_tiles_of": state.get("moe_row_tiles_of", 0)
                     + jnp.int32(-(-rows // tile)),
+                    **_way_back_counted(state, sizes, back),
                     "steps": state["steps"] + 1})
-        return out.reshape(x.shape).astype(x.dtype)
+        return out.reshape(x.shape)
 
     def gradient(self, output_grad):
         packed = _HeldExpertsGradientOp(self, output_grad, ctx=self.raw_ctx)
@@ -658,8 +776,9 @@ class _HeldExpertsGradientOp(Op):
       and a token's ``dx`` the sum of its pairs' rows.
 
     The rows of experts held elsewhere are computed by none of the four
-    and come back as zeros. The gathers and the elementwise work between
-    the products run to the held extent (``_over_held_rows``)."""
+    and are read by nothing. The gathers, the elementwise work between
+    the products and the way back (``_token_sums``) run to the held
+    extent (``_over_held_rows``)."""
 
     def __init__(self, forward_op, output_grad, ctx=None):
         super().__init__(_HeldExpertsGradientOp,
@@ -679,7 +798,8 @@ class _HeldExpertsGradientOp(Op):
         h = grouped_matmul(xs, w_gate_up, sizes) if kept is None \
             else kept[3]
         width = h.shape[-1] // 2
-        da = grouped_matmul_rows_grad(dys, w_down, sizes)   # [rows, width]
+        da = _into_fresh(grouped_matmul_rows_grad, dys, w_down,
+                         sizes)                             # [rows, width]
 
         # a pair's weight at its sorted row; the pad rows weigh nothing
         w_row = _moved(weights.reshape(-1), back)
@@ -705,12 +825,13 @@ class _HeldExpertsGradientOp(Op):
         dweights = jnp.where(held, dweights, 0.0).reshape(experts.shape)
         dw_down = grouped_matmul_weights_grad(weighted, dys, sizes)
         dw_gate_up = grouped_matmul_weights_grad(xs, dh, sizes)
-        dxs = grouped_matmul_rows_grad(dh, w_gate_up, sizes)
-        dx = jnp.sum(dxs[back].reshape(-1, k, dxs.shape[-1])
-                     .astype(jnp.float32)
-                     * held[:, :, None], axis=1)
-        return (dx.reshape(x.shape).astype(x.dtype),
-                dweights.astype(jnp.float32), dw_gate_up, dw_down)
+        dxs = _into_fresh(grouped_matmul_rows_grad, dh, w_gate_up, sizes)
+        dx = _token_sums(dxs, back, held, None, x.dtype)
+        if kept is not None and ectx.get_state(fwd) is not None:
+            put = ectx.new_state[fwd]       # the forward's, this step
+            ectx.put_state(fwd, {**put, **_way_back_counted(put, sizes, back)})
+        return (dx.reshape(x.shape), dweights.astype(jnp.float32),
+                dw_gate_up, dw_down)
 
     def gradient(self, output_grad):
         raise NotImplementedError

@@ -988,9 +988,10 @@ def test_sparse_decoder_step_holds_the_banded_grouped_backward(
         v5e, monkeypatch):
     """The step holds the banded grouped-query forward and backward,
     the experts' three kinds of grouped product (two of each) and the
-    token table's sparse row update (PR 51): nine kernels."""
+    token table's sparse row update (PR 51): nine kernels, and the ten
+    allocations of the expert layer's row buffers (PRs 53 and 55)."""
     text = _sparse_decoder_step_text(v5e[0], monkeypatch)
-    assert text.count('custom_call_target="tpu_custom_call"') == 9 + 4
+    assert text.count('custom_call_target="tpu_custom_call"') == 9 + 10
     for name in ("hetu_flash_gqa_window_fwd", "hetu_flash_gqa_window_bwd",
                  "hetu_moe_experts_dx", "hetu_moe_experts_dw",
                  "hetu_sparse_rows_update", "hetu_moe_rows_buffer"):
@@ -1002,45 +1003,84 @@ def test_sparse_decoder_step_holds_the_banded_grouped_backward(
 def test_sparse_decoder_step_runs_the_experts_passes_to_the_held_extent(
         v5e, monkeypatch):
     """The expert layer's composed passes as the chip's compiler leaves
-    them (PR 53). Four loops (the forward's ``flat[token]`` is also the
-    backward's; ``dy[token]``; the activation; the backward's
-    elementwise work), each with its row buffers out of ONE
-    ``hetu_moe_rows_buffer`` call: nothing fills a ``[T x k, ...]``
-    buffer before a loop does (no broadcast into one). A tile's results
-    are written in place: no ``dynamic-update-slice`` stands alone in a
-    loop's body with a ``[T x k, width]`` result (one did while the
-    tile's start lay behind a ``minimum``: a copy of every tile). And outside
-    the loops no instruction with a ``[T x k, hidden]`` result reads the
-    ``[T, hidden]`` tokens: the whole-array gathers that are left are
-    the way back's."""
+    them (PRs 53 and 55). Six loops: the forward's ``flat[token]`` (also
+    the backward's), ``dy[token]``, the activation, the backward's
+    elementwise work, and the way back of each direction, whose body
+    holds the loop over a token's picks (``_token_sums``). Ten
+    ``hetu_moe_rows_buffer`` calls: one a loop, one for each of the four
+    grouped products to write into: nothing fills a ``[T x k, ...]``
+    buffer before a loop or a kernel does (no broadcast into one, and no
+    whole-array select follows a ``hetu_moe_experts*`` call: the
+    library's zero fill behind the held groups is gone). A tile's
+    results are written in place: no ``dynamic-update-slice`` stands
+    alone in a loop's body with a ``[T x k, width]`` result (one did
+    while the tile's start lay behind a ``minimum``: a copy of every
+    tile). Outside the loops no instruction with a ``[T x k, hidden]``
+    result reads the ``[T, hidden]`` tokens, no gather has a ``[T x k,
+    hidden]`` result or operand (the way back reads the products'
+    outputs a token tile at a time, inside its loops), and no float32
+    ``[T, k, hidden]`` array is left anywhere."""
     from hlo_matmuls import _computations, describe
+    from hetu_tpu.ops import moe
     text = _sparse_decoder_step_text(v5e[0], monkeypatch)
     rows, tokens, hidden = 8192 * 6, 8192, 2560
     comps, entry = _computations(text)
-    buffers = [v for v in comps[entry].values()
-               if "hetu_moe_rows_buffer" in v[3] and v[1] == "custom-call"]
-    assert len(buffers) == 4
-    for name, (result, opcode, _, _) in comps[entry].items():
-        if opcode == "broadcast" and not result.startswith("("):
-            dims = describe(result)[1]
-            assert not (len(dims) == 2 and dims[0] == rows), name
+    buffers = [name for name, v in comps[entry].items()
+               if name.startswith("%hetu_moe_rows_buffer")
+               and v[1] == "custom-call"]
+    assert len(buffers) == 6 + 4
+    for name, (result, opcode, _, line) in comps[entry].items():
+        if result.startswith("("):
+            continue
+        dims = describe(result)[1]
+        whole = len(dims) == 2 and dims[0] == rows
+        if opcode == "broadcast":
+            assert not whole, name
+        if "jit(hetu_moe_experts" in line and opcode != "custom-call":
+            assert not whole, name      # (megablox's where after its call)
+    assert "f32[8192,6,2560]" not in text
     bodies = {re.search(r"body=(%[\w.\-]+)", v[3]).group(1)
               for v in comps[entry].values()
               if v[1] == "while" and "HeldExperts" in v[3]
               and "searchsorted" not in v[3]}     # the kernels' own
-    assert len(bodies) == 4
+    assert len(bodies) == 6
+    nested = [body for body in bodies
+              if any(v[1] == "while" for v in comps[body].values())]
+    assert len(nested) == 2                       # the way back, a direction
     for body in bodies:
         for name, (result, opcode, _, _) in comps[body].items():
             if opcode == "dynamic-update-slice":    # a [rows] vector may
                 dims = describe(result)[1]
                 assert not (len(dims) == 2 and dims[0] == rows), (body, name)
     for name, (result, opcode, operands, _) in comps[entry].items():
-        if opcode in ("fusion", "gather") and result.startswith("bf16[") \
+        if opcode not in ("fusion", "gather") or result.startswith("("):
+            continue
+        reads = [describe(comps[entry][o][0])[1] for o in operands
+                 if o in comps[entry]
+                 and not comps[entry][o][0].startswith("(")]
+        if result.startswith("bf16[") \
                 and describe(result)[1] == (rows, hidden):
-            reads = [describe(comps[entry][o][0])[1] for o in operands
-                     if o in comps[entry]
-                     and not comps[entry][o][0].startswith("(")]
             assert (tokens, hidden) not in reads, name
+    # every gather of [T x k, hidden] rows is a token tile in a loop
+    in_loops = set(bodies)
+    for body in nested:
+        in_loops |= {re.search(r"body=(%[\w.\-]+)", v[3]).group(1)
+                     for v in comps[body].values() if v[1] == "while"}
+    tile = min(moe.TOKEN_TILE, tokens)
+    way_back = 0
+    for comp, body in comps.items():
+        for name, (result, opcode, operands, _) in body.items():
+            if opcode != "gather":
+                continue
+            got = describe(result)[1]
+            assert got != (rows, hidden), (comp, name)
+            if describe(body[operands[0]][0])[1] == (rows, hidden):
+                assert got == (tile, hidden), (comp, name)
+                users = [c for c in in_loops if any(
+                    f"calls={comp}" in v[3] for v in comps[c].values())]
+                assert users, (comp, name)
+                way_back += 1
+    assert way_back == 2
 
 
 # (rows, width, ids a step) of the tables the train cells update sparsely
@@ -1134,8 +1174,10 @@ def test_step_updates_its_sparse_tables_in_one_call_each(
         for name, opcode, _, body in found:
             assert opcode != "copy" and " scatter(" not in body \
                 and " sort(" not in body, (name, opcode)
-    sorts = re.findall(r"= \(?s32\[%d\]\S*(?:, s32\[%d\]\S*)?\)? sort\("
-                       % (ids, ids), text)
+    # (the expert op sorts its 8,192 tokens too: ``moe._token_sums``)
+    sorts = [line for line in text.split("\n") if "HeldExperts" not in line
+             and re.search(r"= \(?s32\[%d\]\S*(?:, s32\[%d\]\S*)?\)? "
+                           r"sort\(" % (ids, ids), line)]
     assert 1 <= len(sorts) <= 2 * len(tables), sorts
 
 

@@ -431,6 +431,27 @@ def _nan_buffers(shapes, after):
     return tuple(jnp.full(s.shape, jnp.nan, s.dtype) for s in shapes)
 
 
+def _check_op_against_the_whole_array_form(arrays, first,
+                                           activation="relu"):
+    """The op's value and packed gradients (``compute`` under a training
+    context), finite and equal to ``_whole_array_experts``' by
+    ``jax.vjp``; returns ``(value, gradients)``."""
+    upstream = np.random.RandomState(9).randn(*arrays[0].shape).astype(
+        np.float32)
+    out, grads = _op_and_packed_gradients(arrays, first, upstream,
+                                          activation)
+    values = [jnp.asarray(a) for a in arrays]
+    want, vjp = jax.vjp(lambda x, w, w_in, w_out: _whole_array_experts(
+        x, w, values[2], w_in, w_out, first, moe.ACTIVATIONS[activation]),
+        values[0], values[1], values[3], values[4])
+    assert np.isfinite(np.asarray(out)).all()
+    close(out, want, 1e-5)
+    for got, wanted in zip(grads, vjp(jnp.asarray(upstream))):
+        assert np.isfinite(np.asarray(got)).all()
+        close(got, wanted, 1e-5)
+    return out, grads
+
+
 # (routing, the pass's row tile, interpreted kernels, poisoned buffers);
 # 2 x 32 tokens x 3 picks = 192 sorted rows composed, 1 x 40 x 3 = 120
 # padded to 128 under the kernels
@@ -470,18 +491,130 @@ def test_held_experts_passes_run_to_the_held_extent(case, monkeypatch):
     held = int(((picks >= first) & (picks < first + 4)).sum())
     if case == "extent_no_multiple_of_the_tile":
         assert held % tile and held > tile
-    upstream = np.random.RandomState(9).randn(*arrays[0].shape).astype(
-        np.float32)
-    out, grads = _op_and_packed_gradients(arrays, first, upstream)
-    values = [jnp.asarray(a) for a in arrays]
-    want, vjp = jax.vjp(lambda x, w, w_in, w_out: _whole_array_experts(
-        x, w, values[2], w_in, w_out, first),
-        values[0], values[1], values[3], values[4])
-    assert np.isfinite(np.asarray(out)).all()
-    close(out, want, 1e-5)
-    for got, wanted in zip(grads, vjp(jnp.asarray(upstream))):
+    _check_op_against_the_whole_array_form(arrays, first)
+
+
+def test_serving_held_experts_hands_the_products_no_out(monkeypatch):
+    """``held_experts`` (serving) reads ``ys[back]`` past the extent and
+    keeps the library's zeros there: it never passes ``out``."""
+    seen = []
+    real = moe.grouped_matmul
+
+    def recorded(lhs, rhs, group_sizes, out=None):
+        seen.append(out)
+        return real(lhs, rhs, group_sizes, out)
+
+    monkeypatch.setattr(moe, "grouped_matmul", recorded)
+    arrays, first = _expert_case("random", tokens=(1, S))
+    x, w, p = (jnp.asarray(a[0]) for a in arrays[:3])
+    moe.held_experts(x, p, w, jnp.ones(S, bool), jnp.asarray(arrays[3]),
+                     jnp.asarray(arrays[4]), first, activation="relu")
+    assert seen == [None, None]
+
+
+# (routing, the passes' row tile, the way back's token tile, the gate's
+# activation, interpreted kernels): 2 x 32 tokens x 3 picks = 192 pairs
+# composed (1 x 40 tokens = 120 pairs padded to 128 under the kernels), 8
+# experts of which 2 .. 5 are held
+WAY_BACK_CASES = {
+    "no_pair_held": ("none_held", 16, 8, "relu", False),
+    "every_pair_held": ("all_held", 16, 8, "silu", False),
+    "one_pick_of_every_token_held": ("all_to_one", 16, 8, "relu", False),
+    "tokens_with_none_one_and_all_picks_held":
+        ("random", 16, 8, "relu", False),
+    "a_ranks_tokens_end_inside_a_tile": ("random", 16, 16, "silu", False),
+    "one_token_a_tile": ("random", 16, 1, "silu", False),
+    "tokens_no_multiple_of_the_tile": ("random", 80, 24, "silu", False),
+    "tokens_under_one_tile": ("random", 2048, 512, "relu", False),
+    "kernels_interpreted": ("random", 48, 16, "relu", True),
+    "kernels_interpreted_every_pair_held": ("all_held", 48, 16, "silu", True),
+    "kernels_interpreted_no_pair_held": ("none_held", 48, 8, "silu", True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WAY_BACK_CASES))
+def test_the_way_back_visits_the_held_pairs(case, monkeypatch):
+    """``moe._token_sums`` (a token's held rows summed from where they
+    lie, over the tokens that hold a pair and no further) against the
+    whole-array expression it replaced (a gather of all ``T x k`` rows
+    and a weighted sum over ``k``), in both directions' form, and
+    through it the op's value and four gradients. Every buffer starts
+    from NaN, the four products' outputs among them: a row past the
+    extent that the way back read would show in a result."""
+    routing, row_tile, token_tile, activation, interpreted = \
+        WAY_BACK_CASES[case]
+    monkeypatch.setattr(moe, "ROW_TILE", row_tile)
+    monkeypatch.setattr(moe, "TOKEN_TILE", token_tile)
+    monkeypatch.setattr(moe, "_fresh", _nan_buffers)
+    if interpreted:
+        monkeypatch.setattr(moe, "INTERPRET", True)
+        monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+        arrays, first = _expert_case(routing, hidden=128, width=128,
+                                     tokens=(1, 40))
+    else:
+        arrays, first = _expert_case(routing)
+    x, weights, picks = arrays[:3]
+    hidden, k = x.shape[-1], TOP_K
+    flat_picks = jnp.asarray(picks.reshape(-1, k))
+    order, sizes, held, pairs = moe._sorted_pairs(
+        flat_picks, jnp.ones(flat_picks.shape[0], bool), 4, first)
+    back = moe._moved(jnp.arange(pairs, dtype=jnp.int32), order[:pairs])
+    n = int(sizes[:-1].sum())
+    count = np.asarray(held).sum(-1)
+    if case == "tokens_with_none_one_and_all_picks_held":
+        assert {0, 1, k} <= set(count.tolist())
+    if case == "a_ranks_tokens_end_inside_a_tile":
+        assert all(int((count > r).sum()) % token_tile for r in range(k))
+    if case == "tokens_no_multiple_of_the_tile":
+        assert len(count) % token_tile and (count > 0).sum() > token_tile
+    # the products' rows: defined to the extent, NaN behind it
+    rng = np.random.RandomState(11)
+    rows = rng.randn(order.shape[0], hidden).astype(np.float32)
+    poisoned = jnp.asarray(np.where(np.arange(len(rows))[:, None] < n,
+                                    rows, np.nan))
+    zeroed = np.where(np.arange(len(rows))[:, None] < n, rows, 0.0)
+    coeff = jnp.where(held, jnp.asarray(weights.reshape(-1, k)), 0.0)
+    for c in (coeff, None):
+        got = moe._token_sums(poisoned, back, held, c, jnp.float32)
+        want = jnp.einsum(
+            "tk,tkh->th", held.astype(jnp.float32) if c is None else c,
+            zeroed[np.asarray(back)].reshape(-1, k, hidden))
         assert np.isfinite(np.asarray(got)).all()
-        close(got, wanted, 1e-5)
+        close(got, want, 1e-6)
+        # a token none of whose picks is held: exactly 0
+        assert not np.asarray(got)[count == 0].any()
+    # the op through it
+    out, grads = _check_op_against_the_whole_array_form(arrays, first,
+                                                        activation)
+    if n == 0:
+        assert not np.asarray(out).any() and not np.asarray(grads[0]).any()
+
+
+@pytest.mark.parametrize("product", ["forward", "rows_grad"])
+@pytest.mark.parametrize("out", ["absent", "given"])
+def test_a_product_without_out_still_zeroes_the_rows_behind(kernels,
+                                                            product, out):
+    """Serving's call of the grouped products (no ``out``): the rows
+    behind the held groups come back as zeros whatever ``lhs`` holds
+    there, a NaN included. With ``out`` (the graph op's call) they keep
+    what ``out`` held, and the held groups' rows are the same."""
+    m, wide, held_n, n = 256, 128, 2, 100
+    rng = np.random.RandomState(12)
+    lhs = rng.randn(m, wide).astype(np.float32)
+    lhs[n:] = np.nan
+    rhs = jnp.asarray(0.1 * rng.randn(held_n, wide, wide), jnp.float32)
+    sizes = jnp.asarray([60, 40, m - n], jnp.int32)
+    fn = moe.grouped_matmul if product == "forward" \
+        else moe.grouped_matmul_rows_grad
+    kept = jnp.full((m, wide), 7.0, jnp.float32)
+    got = np.asarray(fn(jnp.asarray(lhs), rhs, sizes) if out == "absent"
+                     else fn(jnp.asarray(lhs), rhs, sizes, out=kept))
+    assert (got[n:] == (0.0 if out == "absent" else 7.0)).all()
+    group = np.repeat(np.arange(held_n), [60, 40])
+    mats = np.asarray(rhs)[group]
+    want = np.einsum("mk,mkn->mn" if product == "forward" else "mn,mkn->mk",
+                     lhs[:n], mats)
+    close(got[:n], want, 1e-5)
 
 
 def test_held_experts_activation_is_an_argument_of_the_serving_function():
@@ -689,7 +822,22 @@ def test_the_step_counts_rows_and_visits_on_the_device(monkeypatch):
         assert c["moe_row_tiles"] == 3 * -(-sum(by_hand) // 16)
         assert c["moe_row_tiles_of"] == 3 * 12
         assert 0 < c["moe_row_tiles"] < c["moe_row_tiles_of"]
+        # the way back reads the held pairs' rows, once a direction
+        assert c["moe_back_rows"] == 2 * 3 * sum(by_hand)
+        assert c["moe_back_rows_of"] == 2 * 3 * 192
         assert c["steps"] == 3
+    # a state restored from before the way back's counters has none:
+    # they read 0 and count from there
+    for state in g.executor.state.values():
+        if "moe_back_rows" in state:
+            del state["moe_back_rows"], state["moe_back_rows_of"]
+    assert all(c["moe_back_rows"] == 0 and c["moe_back_rows_of"] == 0
+               and c["steps"] == 3 for c in g.executor.moe_counters())
+    g.run("default", ids, labels)
+    for layer, c in zip(picks, g.executor.moe_counters()):
+        assert c["moe_back_rows"] == 2 * int(
+            ((layer >= 2) & (layer < 6)).sum())
+        assert c["moe_back_rows_of"] == 2 * 192 and c["steps"] == 4
 
 
 def _hlo_computations(text):
@@ -715,15 +863,18 @@ def _dims(type_text):
 
 def test_no_gather_of_the_tokens_to_all_sorted_rows_outside_a_loop(
         monkeypatch):
-    """In the lowered training step, ``flat[token]`` and ``dy[token]``
-    of every expert layer (a gather from the ``[T, hidden]`` tokens) are
-    row tiles inside a ``while`` body, in both directions, and the only
-    gathers with a ``[T x k, hidden]`` result are the way back's, which
-    read the ``[T x k, hidden]`` sorted rows (``ys[back]``,
-    ``dxs[back]``: indexed by (token, pick), the next issue's): a
-    refactor that puts a whole-array pass back fails here."""
-    tile = 16
+    """In the lowered training step every gather of an expert layer
+    that reads or makes ``[T x k, hidden]`` rows is a tile inside a
+    ``while`` body, in both directions: ``flat[token]`` and
+    ``dy[token]`` (from the ``[T, hidden]`` tokens, a row tile) and the
+    way back's reads of the products' outputs (from ``[T x k, hidden]``,
+    a token tile: ``_token_sums``). NO gather has a ``[T x k, hidden]``
+    result. The one whole-array gather a direction that is left reads
+    the ``[T, hidden]`` sums in token order. A refactor that puts a
+    whole-array pass back fails here."""
+    tile, token_tile = 16, 8
     monkeypatch.setattr(moe, "ROW_TILE", tile)
+    monkeypatch.setattr(moe, "TOKEN_TILE", token_tile)
     g = Graph(tiny_config(), lambda g: {"default": [
         g.loss, ht.optim.SGDOptimizer(0.1).minimize(g.loss)]})
     ids, labels = batch()
@@ -745,21 +896,31 @@ def test_no_gather_of_the_tokens_to_all_sorted_rows_outside_a_loop(
         in_a_loop |= more
         grown = bool(more)
     tokens, rows = ids.size, ids.size * TOP_K
-    tiled, way_back = [], []
+    tiled, way_back, token_order = [], [], []
     for comp, instrs in comps.items():
         types = {name: result for name, result, *_ in instrs}
         for name, result, opcode, operands, _ in instrs:
             if opcode != "gather":
                 continue
             source, got = _dims(types[operands[0]]), _dims(result)
-            if source == (tokens, HIDDEN) and got[1:] == (HIDDEN,):
-                assert comp in in_a_loop and got[0] == tile, (comp, name)
-                tiled.append(name)
-            if got == (rows, HIDDEN):
-                assert source == (rows, HIDDEN), (comp, name)
+            assert got != (rows, HIDDEN), (comp, name)
+            if source == (rows, HIDDEN):
+                assert comp in in_a_loop and got == (token_tile, HIDDEN), \
+                    (comp, name)
                 way_back.append(name)
-    # a layer: flat[token] forward, flat[token] and dy[token] backward
+            if source == (tokens, HIDDEN) and got[1:] == (HIDDEN,):
+                if comp in in_a_loop:
+                    assert got[0] == tile, (comp, name)
+                    tiled.append(name)
+                else:
+                    assert got[0] == tokens, (comp, name)
+                    token_order.append(name)
+    # a layer: flat[token] forward, flat[token] and dy[token] backward;
+    # the way back and its token order, a direction each
     assert len(tiled) == 3 * 4 and len(way_back) == 2 * 4
+    assert len(token_order) == 2 * 4
+    # and no float32 [T, k, hidden] array anywhere
+    assert not re.search(rf"f32\[{tokens},{TOP_K},{HIDDEN}\]", text)
 
 
 # -- the share test ----------------------------------------------------------
