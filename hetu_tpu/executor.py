@@ -1534,14 +1534,22 @@ class Executor:
         ``moe_back_rows`` (the rows of the grouped products' outputs
         that the way back to token order read: a step adds the held
         pairs once a direction), ``moe_back_rows_of`` (what all ``T x
-        k`` pairs would be, twice a step), ``steps``.
+        k`` pairs would be, twice a step), ``steps``; and for a layer
+        whose router selects by a bias (``router_op(bias=)``)
+        ``moe_bias_flipped_picks``, the router's own count: the picks,
+        of ALL the layer's ``T x k``, that are not among the ``top_k`` of
+        the scores alone.
         Reading it waits for the last step.
         The counts are int32 on the device: a session of more than
         2**31 rows on one expert wraps them."""
-        from .ops.moe import HeldExpertsOp
+        from .ops.moe import HeldExpertsOp, RouterOp
         nodes = {node.id: node for sub in self.subexecutors.values()
                  for node in sub.stateful_ops
                  if isinstance(node, HeldExpertsOp)}
+        # what the layer's router counted itself (RouterOp's state)
+        routers = {nid: self.state.get(str(node.inputs[1].id)) or {}
+                   for nid, node in nodes.items()
+                   if isinstance(node.inputs[1], RouterOp)}
         out = []
         for nid in sorted(nodes):
             state = self.state.get(str(nid))
@@ -1557,6 +1565,8 @@ class Executor:
                         "moe_back_rows": int(state.get("moe_back_rows", 0)),
                         "moe_back_rows_of": int(
                             state.get("moe_back_rows_of", 0)),
+                        **{k: int(v) for k, v in routers.get(
+                            nid, {}).items()},
                         "steps": int(state["steps"])})
         return out
 

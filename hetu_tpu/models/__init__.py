@@ -16,6 +16,8 @@ from .ssm_hybrid import SSMHybridConfig
 from .window_moe import WindowMoEConfig
 from .sparse_decoder import (SparseDecoderConfig, SparseDecoderModel,
                              SparseDecoderLMHeadModel)
+from .hybrid_decoder import (HybridDecoderConfig, HybridDecoderModel,
+                             HybridDecoderLMHeadModel)
 from .bert import (BertConfig, BertModel, BertForPreTraining,
                    BertForSequenceClassification, BertForMaskedLM)
 from .ctr import (wdl_criteo, wdl_adult, deepfm_criteo, dcn_criteo,

@@ -16,7 +16,8 @@ from .shape import (
 )
 from .activations import (
     relu_op, relu_gradient_op, leaky_relu_op, leaky_relu_gradient_op,
-    sigmoid_op, tanh_op, gelu_op, sign_op, softmax_func, softmax_op,
+    sigmoid_op, tanh_op, gelu_op, swiglu_op, swiglu_gradient_op, sign_op,
+    softmax_func, softmax_op,
     softmax_gradient_op, dropout_op, dropout_gradient_op, dropout2d_op,
     dropout2d_gradient_op,
 )
@@ -45,6 +46,7 @@ from .norm import (
 )
 from .rotary import rotary_op
 from .moe import router_op, router_picks_op, held_experts_op
+from .short_conv import short_conv_op
 from .embedding import embedding_lookup_op, embedding_lookup_gradient_op
 from .sparse import csrmv_op, csrmm_op, distgcn_15d_op
 from .attention import (flash_attention_op, ring_attention_op,

@@ -32,7 +32,8 @@ def _saturate(lo, hi, floor, ceil):
 
 __all__ = [
     "relu_op", "relu_gradient_op", "leaky_relu_op", "leaky_relu_gradient_op",
-    "sigmoid_op", "tanh_op", "gelu_op", "sign_op", "softmax_func",
+    "sigmoid_op", "tanh_op", "gelu_op", "swiglu_op", "swiglu_gradient_op",
+    "sign_op", "softmax_func",
     "softmax_op", "softmax_gradient_op", "dropout_op", "dropout_gradient_op",
     "dropout2d_op", "dropout2d_gradient_op",
 ]
@@ -199,6 +200,59 @@ class GeluOp(Op):
             return None
         lo = -0.171 if a[0] < 0.0 else 0.0
         return (lo, max(a[1], 0.0))
+
+
+class SwiGLUOp(Op):
+    """``silu(gate) * up`` of a ``[..., 2 * width]`` product whose two
+    halves lie side by side (gate first), ``[..., width]``: the gated
+    unit between a dense feed-forward's two matmuls,
+    ``down(silu(gate x) * up x)``. Float32 between the read and the
+    write. No reference equivalent (its zoo stops at GELU)."""
+
+    def __init__(self, node_A, ctx=None):
+        super().__init__(SwiGLUOp, [node_A], ctx)
+
+    def compute(self, input_vals, ectx):
+        h = input_vals[0]
+        width = h.shape[-1] // 2
+        gate = h[..., :width].astype(jnp.float32)
+        return (jax.nn.silu(gate) * h[..., width:].astype(jnp.float32)
+                ).astype(h.dtype)
+
+    def gradient(self, output_grad):
+        return [swiglu_gradient_op(self.inputs[0], output_grad,
+                                   ctx=self.raw_ctx)]
+
+    def infer_shape(self, input_shapes):
+        shape = tuple(input_shapes[0])
+        assert shape[-1] % 2 == 0, f"swiglu halves {shape}"
+        return shape[:-1] + (shape[-1] // 2,)
+
+
+class SwiGLUGradientOp(Op):
+    """``[dy * up * silu'(gate), dy * silu(gate)]``, side by side as the
+    input lies: the matmul before it gets one gradient."""
+
+    def __init__(self, node_A, node_B, ctx=None):
+        super().__init__(SwiGLUGradientOp, [node_A, node_B], ctx)
+
+    def compute(self, input_vals, ectx):
+        h, dy = input_vals
+        width = h.shape[-1] // 2
+        gate = h[..., :width].astype(jnp.float32)
+        up = h[..., width:].astype(jnp.float32)
+        dy = dy.astype(jnp.float32)
+        sig = jax.nn.sigmoid(gate)
+        act = gate * sig
+        slope = sig * (1.0 + gate * (1.0 - sig))
+        return jnp.concatenate([dy * up * slope, dy * act],
+                               axis=-1).astype(h.dtype)
+
+    def gradient(self, output_grad):
+        raise NotImplementedError
+
+    def infer_shape(self, input_shapes):
+        return input_shapes[0]
 
 
 class GeluGradientOp(Op):
@@ -426,6 +480,15 @@ def sigmoid_op(node, ctx=None):
 
 def tanh_op(node, ctx=None):
     return TanhOp(node, ctx=ctx)
+
+
+def swiglu_op(node, ctx=None):
+    """``silu(gate) * up`` of ``node [..., 2 * width]`` (gate | up)."""
+    return SwiGLUOp(node, ctx=ctx)
+
+
+def swiglu_gradient_op(node_A, node_B, ctx=None):
+    return SwiGLUGradientOp(node_A, node_B, ctx=ctx)
 
 
 def gelu_op(node, ctx=None):

@@ -90,6 +90,18 @@ class AddOp(Op):
                                   b.get_flat_indices()]),
                 _jnp.concatenate([a.get_dense_rows(), b.get_dense_rows()]),
                 a.dense_shape)
+        # a table that is also a dense product's operand (a tied head):
+        # the sum is ONE dense array, applied once by the dense update.
+        # Where the dense adjoint is wider than the rows (float32 out of
+        # a float32 head over a bfloat16 stream) the rows are added INTO
+        # it, so rows of one id add up in float32
+        for rows, dense in ((a, b), (b, a)):
+            if isinstance(rows, IndexedSlices) \
+                    and dense.dtype != rows.values.dtype \
+                    and jnp.promote_types(dense.dtype, rows.values.dtype) \
+                    == dense.dtype:
+                return dense.at[rows.get_flat_indices()].add(
+                    rows.get_dense_rows().astype(dense.dtype))
         if isinstance(a, IndexedSlices):
             return a.to_dense() + b
         if isinstance(b, IndexedSlices):

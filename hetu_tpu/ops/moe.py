@@ -25,17 +25,21 @@ elsewhere would add is the exchange's business, not this module's.
   profile; ``ops/pallas_norm.py`` says why the jitted function carries
   it); elsewhere, and for widths the kernel's tiles do not take,
   ``jax.lax.ragged_dot``.
-* :func:`router_op` / :func:`router_picks_op` — a softmax router as
-  graph nodes: float32 logits at the highest matmul precision from the
-  float32 MASTER of the router's weights, the ``top_k`` largest, a
-  softmax over those alone. The gradient flows through the chosen
-  weights into the router's weights and its input, never through the
-  indices.
+* :func:`router_op` / :func:`router_picks_op` — a router as graph
+  nodes: float32 logits at the highest matmul precision from the
+  float32 MASTER of the router's weights; by default the ``top_k``
+  largest and a softmax over those alone, with ``scoring="sigmoid"``
+  :func:`route` itself (sigmoid scores, selection by ``score + bias``
+  with the bias a non-trainable float32 buffer, the chosen scores
+  normalised and scaled). The gradient flows through the chosen weights
+  into the router's weights and its input, never through the indices
+  nor into the bias.
 * :func:`held_experts_op` — :func:`held_experts` as a graph node over
   the stacked parameters of the experts held here, with a counter the
   compiled training step accumulates on the device (rows by held
-  expert, experts visited, row tiles, steps:
-  ``Executor.moe_counters()``). Its backward is two grouped products for
+  expert, experts visited, row tiles, steps: ``Executor.moe_counters()``,
+  which adds the picks that a router selecting by a bias counted as
+  changed by it). Its backward is two grouped products for
   the rows (``hetu_moe_experts_dx``: the kernel with its right side
   transposed) and two transposed grouped products for the weights
   (``hetu_moe_experts_dw``: megablox's ``tgmm``); the rows of experts
@@ -131,7 +135,8 @@ def swiglu(x, w_gate_up, w_down):
     return (jax.nn.silu(h[..., :width]) * h[..., width:]) @ w_down
 
 
-def route(x, w_router, bias, top_k, scale, n_group=1, topk_group=1):
+def route(x, w_router, bias, top_k, scale, n_group=1, topk_group=1,
+          norm_eps=0.0):
     """Router of the aux-loss-free family over ALL experts.
 
     ``x`` ``[T, hidden]``; ``w_router`` ``[hidden, E]`` and ``bias``
@@ -145,8 +150,9 @@ def route(x, w_router, bias, top_k, scale, n_group=1, topk_group=1):
     score is the sum of its two largest ``score + bias``, and only the
     experts of the ``topk_group`` best groups may be picked (under
     expert parallelism a token then visits that many groups' chips at
-    most). Returns ``(experts [T, k] int32, weights [T, k] float32,
-    scores [T, E] float32)``."""
+    most). ``norm_eps`` is added under the chosen scores' sum where a
+    family's code has one. Returns ``(experts [T, k] int32, weights
+    [T, k] float32, scores [T, E] float32)``."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -160,8 +166,10 @@ def route(x, w_router, bias, top_k, scale, n_group=1, topk_group=1):
             choice.shape)
     _, experts = jax.lax.top_k(choice, top_k)
     picked = jnp.take_along_axis(scores, experts, axis=-1)
-    weights = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
-    return experts.astype(jnp.int32), weights, scores
+    weights = scale * picked
+    summed = jnp.sum(picked, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), \
+        weights / (summed + norm_eps if norm_eps else summed), scores
 
 
 def _kernel_tiles(m, k, n):
@@ -385,32 +393,88 @@ def _master(ectx, node, value):
     return value
 
 
-class RouterOp(Op):
-    """The chosen experts' WEIGHTS ``[..., k]`` float32 of
-    :func:`route_softmax_top_k`; the indices are
-    :class:`RouterPicksOp`'s. The router's weights are read from their
-    float32 master whatever the step's compute dtype."""
+SCORINGS = ("softmax", "sigmoid")
 
-    def __init__(self, node_in, w_router, top_k, ctx=None):
-        super().__init__(RouterOp, [node_in, w_router], ctx)
+
+class RouterOp(Op):
+    """The chosen experts' WEIGHTS ``[..., k]`` float32; the indices are
+    :class:`RouterPicksOp`'s. The router's weights (and its bias) are
+    read from their float32 masters whatever the step's compute dtype.
+
+    ``scoring="softmax"`` (the default): :func:`route_softmax_top_k`,
+    the ``top_k`` largest logits and a softmax over those alone.
+    ``scoring="sigmoid"``: :func:`route`, the aux-loss-free family's —
+    sigmoid scores over ALL experts, the ``top_k`` chosen by ``score +
+    bias`` where a ``bias`` node ``[E]`` is given (a float32 BUFFER: a
+    non-trainable variable that gets no gradient and enters no weight),
+    the weights the chosen scores over their sum plus ``norm_eps``,
+    times ``scale``."""
+
+    def __init__(self, node_in, w_router, top_k, ctx=None,
+                 scoring="softmax", bias=None, scale=1.0, norm_eps=0.0):
+        if scoring not in SCORINGS:
+            raise ValueError(f"scoring {scoring!r}: one of {SCORINGS}")
+        if scoring == "softmax" and (bias is not None or scale != 1.0
+                                     or norm_eps):
+            raise ValueError("bias, scale and norm_eps belong to the "
+                             "sigmoid router")
+        super().__init__(RouterOp, [node_in, w_router]
+                         + ([] if bias is None else [bias]), ctx)
         self.top_k = top_k
+        self.scoring = scoring
+        self.selects_by_bias = bias is not None
+        self.scale = float(scale)
+        self.norm_eps = float(norm_eps)
+        # a router that selects by a bias counts, on the device, the
+        # picks the bias changed (``Executor.moe_counters()``)
+        self.stateful = self.selects_by_bias
+        self.state_dtype = jnp.int32
+
+    def state_shapes(self, input_shapes):
+        return {"moe_bias_flipped_picks": ()}
 
     def routed(self, input_vals, ectx):
-        """``(experts, weights)``, computed once a trace."""
+        """``(experts, weights)`` — the sigmoid router's ``(experts,
+        weights, scores [..., E])`` —, computed once a trace."""
         key = ("router", self.id)
         if key not in ectx.cache:
-            x, w = input_vals
-            ectx.cache[key] = route_softmax_top_k(
-                x, _master(ectx, self.inputs[1], w), self.top_k)
+            x, w = input_vals[:2]
+            w = _master(ectx, self.inputs[1], w)
+            if self.scoring == "softmax":
+                ectx.cache[key] = route_softmax_top_k(x, w, self.top_k)
+            else:
+                bias = _master(ectx, self.inputs[2], input_vals[2]) \
+                    if self.selects_by_bias \
+                    else jnp.zeros(w.shape[-1], jnp.float32)
+                ectx.cache[key] = route(x, w, bias, self.top_k, self.scale,
+                                        norm_eps=self.norm_eps)
         return ectx.cache[key]
 
     def compute(self, input_vals, ectx):
-        return self.routed(input_vals, ectx)[1]
+        routed = self.routed(input_vals, ectx)
+        state = ectx.get_state(self) if self.stateful and ectx.training \
+            else None
+        if state is not None:
+            # the picks that are not among the ``top_k`` of the scores
+            # alone, by each pick's RANK among them (ties to the lower
+            # index, as ``top_k`` breaks them): one fused compare and
+            # sum over ``[T, k, E]``, no second top-k
+            experts, _, scores = routed
+            picked = jnp.take_along_axis(scores, experts, axis=-1)
+            others, own = scores[..., None, :], picked[..., :, None]
+            ahead = (others > own) | ((others == own) & (
+                jnp.arange(scores.shape[-1]) < experts[..., :, None]))
+            flipped = jnp.sum(ahead, axis=-1) >= self.top_k
+            ectx.put_state(self, {
+                "moe_bias_flipped_picks": state["moe_bias_flipped_picks"]
+                + jnp.sum(flipped, dtype=jnp.int32)})
+        return routed[1]
 
     def gradient(self, output_grad):
         packed = _RouterGradientOp(self, output_grad, ctx=self.raw_ctx)
         return [_Part(packed, self.inputs[0], 0, ctx=self.raw_ctx),
-                _Part(packed, self.inputs[1], 1, ctx=self.raw_ctx)]
+                _Part(packed, self.inputs[1], 1, ctx=self.raw_ctx)] \
+            + [None] * self.selects_by_bias
 
     def infer_shape(self, input_shapes):
         return tuple(input_shapes[0][:-1]) + (self.top_k,)
@@ -428,16 +492,21 @@ class RouterPicksOp(Op):
         return self.router.routed(input_vals, ectx)[0]
 
     def gradient(self, output_grad):
-        return [None, None]
+        return [None] * len(self.inputs)
 
     def infer_shape(self, input_shapes):
         return self.router.infer_shape(input_shapes)
 
 
 class _RouterGradientOp(Op):
-    """Packed ``(dx, dw_router)``: the softmax's Jacobian over the
-    chosen ``k``, scattered onto the chosen columns of the ``E`` logits
-    (every other logit's gradient is zero: the indices carry none)."""
+    """Packed ``(dx, dw_router)``: the Jacobian of the weights over the
+    chosen ``k`` — the softmax's, or the sigmoid's and the
+    normalisation's: with ``p`` the chosen scores, ``D = sum(p) +
+    norm_eps`` and ``w = scale * p / D``, ``dp_i = (scale * dw_i - sum_j
+    dw_j w_j) / D`` and ``dlogit_i = dp_i p_i (1 - p_i)`` —, scattered
+    onto the chosen columns of the ``E`` logits (every other logit's
+    gradient is zero: the indices carry none, and neither does the
+    bias)."""
 
     def __init__(self, forward_op, output_grad, ctx=None):
         super().__init__(_RouterGradientOp,
@@ -445,13 +514,21 @@ class _RouterGradientOp(Op):
         self.forward_op = forward_op
 
     def compute(self, input_vals, ectx):
-        x, w, dweights = input_vals
+        x, w, dweights = input_vals[0], input_vals[1], input_vals[-1]
         fwd = self.forward_op
         w = _master(ectx, fwd.inputs[1], w).astype(jnp.float32)
-        experts, weights = fwd.routed([x, w], ectx)
+        routed = fwd.routed([x, w, *input_vals[2:-1]], ectx)
+        experts, weights = routed[:2]
         dweights = dweights.astype(jnp.float32)
-        dpicked = weights * (dweights - jnp.sum(
-            weights * dweights, axis=-1, keepdims=True))
+        if fwd.scoring == "softmax":
+            dpicked = weights * (dweights - jnp.sum(
+                weights * dweights, axis=-1, keepdims=True))
+        else:
+            p = jnp.take_along_axis(routed[2], experts, axis=-1)
+            total = jnp.sum(p, axis=-1, keepdims=True) + fwd.norm_eps
+            dpicked = (fwd.scale * dweights - jnp.sum(
+                weights * dweights, axis=-1, keepdims=True)) / total \
+                * p * (1.0 - p)
         dlogits = jnp.einsum(
             "...k,...ke->...e", dpicked,
             jax.nn.one_hot(experts, w.shape[-1], dtype=jnp.float32))
@@ -840,11 +917,15 @@ class _HeldExpertsGradientOp(Op):
         return input_shapes[0]
 
 
-def router_op(node_in, w_router, top_k, ctx=None):
-    """The ``top_k`` chosen experts' softmax weights ``[..., k]``
-    (float32) of ``node_in [..., hidden]`` under ``w_router [hidden,
-    E]``; see :class:`RouterOp`."""
-    return RouterOp(node_in, w_router, top_k, ctx=ctx)
+def router_op(node_in, w_router, top_k, ctx=None, scoring="softmax",
+              bias=None, scale=1.0, norm_eps=0.0):
+    """The ``top_k`` chosen experts' weights ``[..., k]`` (float32) of
+    ``node_in [..., hidden]`` under ``w_router [hidden, E]``: a softmax
+    over the chosen, or with ``scoring="sigmoid"`` the chosen sigmoid
+    scores normalised and scaled, selected by ``score + bias``; see
+    :class:`RouterOp`."""
+    return RouterOp(node_in, w_router, top_k, ctx=ctx, scoring=scoring,
+                    bias=bias, scale=scale, norm_eps=norm_eps)
 
 
 def router_picks_op(router, ctx=None):

@@ -257,6 +257,16 @@ SPAN_SCHEMA = {
     # grouped_ring_decode_attention; reason no_paged_grouped_kernel)
     "attn_window_plan": {"op": _req(_STR), "window": _req(_INT),
                          "form": _req(_STR), "reason": _opt(_STR)},
+    # ops/short_conv.py: the form a traced gated short convolution runs
+    # in: composed (one jitted function a direction, hetu_short_conv_fwd
+    # / hetu_short_conv_bwd, which XLA inlines: a profile reads them by
+    # the scopes hetu.fwd/ShortConvOp/ and
+    # hetu.bwd/_ShortConvGradientOp/); reason no_kernel (there is none
+    # to choose: the composed form ran first, PERF.md section 6 PR 56
+    # says what its trace showed)
+    "short_conv_plan": {"form": _req(_STR), "reason": _opt(_STR),
+                        "rows": _req(_INT), "channels": _req(_INT),
+                        "taps": _req(_INT)},
 }
 
 

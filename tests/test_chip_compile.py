@@ -1215,3 +1215,35 @@ def test_a_kernels_bytes_do_not_depend_on_who_traced_it(one_chip, frames,
             jax.config.update(n, v)
     assert "tpu_custom_call" in direct
     assert (direct == nested) == same
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_short_conv_compiles_at_the_hybrid_cells_widths(one_chip, direction):
+    """The gated short convolution (``ops/short_conv.py``, PR 56) is
+    composed: at the cell's widths (one 8,192-token sequence, 2,048
+    channels, 3 taps, bfloat16) each direction compiles for the
+    described chip into fusions alone (no custom call), writes results
+    of the widths the graph op promises, and keeps what it holds beside
+    its operands and results under the float32 ``[T, 3C]`` that a form
+    materialising every intermediate would pass."""
+    from hetu_tpu.ops import short_conv
+    rows, channels, taps = 8192, 2048, 3
+
+    def arr(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    proj, w = arr(1, rows, 3 * channels), arr(channels, taps,
+                                               dtype=jnp.float32)
+    if direction == "forward":
+        compiled = short_conv._forward.lower(proj, w).compile()
+        out = [(1, rows, channels)]
+    else:
+        compiled = short_conv._backward.lower(
+            proj, w, arr(1, rows, channels)).compile()
+        out = [(1, rows, 3 * channels), (channels, taps)]
+    assert "custom-call" not in compiled.as_text()
+    got = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [tuple(o.shape) for o in got] == out
+    assert got[0].dtype == jnp.bfloat16
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < rows * 3 * channels * 4
