@@ -1531,6 +1531,12 @@ class Executor:
         loops), ``moe_row_tiles_of`` (the tiles that ALL ``T x k`` rows
         are, a step each: the quotient of the two is the share of the
         passes' work that the held extent leaves standing),
+        ``moe_kernel_rows`` (the rows that the row tiles of the
+        forward's grouped products computed: a step adds, for every
+        held expert that got a row, the ``tm``-row tiles its group
+        touches times ``tm``; over ``moe_routed_rows`` it is what the
+        tiles' padding costs under the chosen ``tm``, and where the
+        ragged product runs, off a TPU, the two are equal),
         ``moe_back_rows`` (the rows of the grouped products' outputs
         that the way back to token order read: a step adds the held
         pairs once a direction), ``moe_back_rows_of`` (what all ``T x
@@ -1562,6 +1568,8 @@ class Executor:
                         "moe_row_tiles": int(state.get("moe_row_tiles", 0)),
                         "moe_row_tiles_of": int(
                             state.get("moe_row_tiles_of", 0)),
+                        "moe_kernel_rows": int(
+                            state.get("moe_kernel_rows", 0)),
                         "moe_back_rows": int(state.get("moe_back_rows", 0)),
                         "moe_back_rows_of": int(
                             state.get("moe_back_rows_of", 0)),

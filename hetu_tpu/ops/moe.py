@@ -24,7 +24,8 @@ elsewhere would add is the exchange's business, not this module's.
   stable name ``hetu_moe_experts`` (the name its events carry in a
   profile; ``ops/pallas_norm.py`` says why the jitted function carries
   it); elsewhere, and for widths the kernel's tiles do not take,
-  ``jax.lax.ragged_dot``.
+  ``jax.lax.ragged_dot``. Its tiles are arithmetic on the product's
+  static shapes (**The tiles**, below).
 * :func:`router_op` / :func:`router_picks_op` — a router as graph
   nodes: float32 logits at the highest matmul precision from the
   float32 MASTER of the router's weights; by default the ``top_k``
@@ -78,6 +79,47 @@ left) and the rows the way back read (``moe_back_rows``, beside
 are). The permutations of a scalar a pair or a token (``back``,
 ``w_row``, the pairs' weight gradient, a token's place in the way
 back's order) are sorts (:func:`_moved`), not gathers or scatters.
+
+**The tiles** of the three grouped kernels (:func:`_kernel_tiles`) are
+read from what a product is given and from nothing else: ``m``, ``k``,
+``n``, the operands' width in bytes, which kernel it is and whether it
+writes into ``out``. No table by model, nothing measured at run time,
+no option: two copies of one tree run the same programs. megablox walks ``gmm``'s grid as (column tiles, row-tile
+visits, k-steps); with more than one k-step the weight block's index
+changes on every grid step, so an expert's ``[tk, tn]`` block is
+fetched again for EVERY row tile of its group and a step brings ``tm
+tn / (tm + tn)`` operations a byte, at or under the chip's ridge (240)
+for any tile that fits. So:
+
+* ``gmm`` (forward, and the rows' gradient with its right side
+  transposed): the contraction is ONE tile (``tk = k``) wherever the
+  blocks fit ``KERNEL_BLOCK_BYTES`` (:func:`_block_bytes`: every block
+  twice, and the float32 accumulator). The weight block ``(group, 0,
+  n_i)`` then keeps its index across the consecutive row tiles of a
+  group and is fetched once a column tile; what streams a step is the
+  row tile and its output, ``k tn / (k + 2 tn)`` operations a byte
+  whatever ``tm`` is. ``tn`` is the widest multiple of a lane block
+  dividing ``n`` that still fits; where ``k`` whole does not fit, the
+  fewest k-steps that do.
+* ``tgmm`` (the weights' gradient) walks (column tiles, k tiles, row-tile
+  visits): a step reads a ``[tm, tk]`` and a ``[tm, tn]`` row block for
+  a float32 ``[tk, tn]`` tile that stays on chip over a group's rows,
+  ``tk tn / (tk + tn)`` operations a byte. The output tile is the one
+  that brings the most of them under the budget (its accumulator and
+  double-buffered float32 result are 12 ``tk tn`` bytes).
+* the row tile ``tm`` is ``KERNEL_ROW_TILE`` (256) rows where that
+  divides ``m``, else 128. Where the rows are ONE tile (serving's decode
+  step, 128 padded rows) an expert is visited once and no block is used
+  twice: ``gmm`` then takes blocks of at most ``ONE_TILE_SIDE`` (1,024)
+  a side under the same budget, many short fetches that the pipeline
+  hides, not two or three long ones whose first stands exposed. The
+  boundary is ``m == tm``: 128 or 256 rows are one tile; 384 rows are
+  three tiles of 128 and 512 two of 256, and take the contraction whole.
+
+The op counts the rows its forward's row tiles compute
+(``moe_kernel_rows``: visits x ``tm``; :func:`_kernel_rows`): over the
+rows that landed it is what the tiles' padding costs under the ``tm``
+chosen.
 """
 from __future__ import annotations
 
@@ -120,6 +162,27 @@ ROW_TILE = 2048
 # a token's k ranks of held picks rounds its tokens up to a tile, so a
 # small one (14 us of gather); 256 and 1,024 both ran slower on the chip
 TOKEN_TILE = 512
+# on-chip memory that the blocks of a grouped product may take
+# (``_block_bytes``): the 16 MiB a kernel is given by default less a
+# margin for what the compiler keeps beside the blocks. Compiled for the
+# described chip at row tiles of 128 and 256 rows, every tile up to 15.0
+# MiB by this count fit and the first refusals came at 15.75
+# (``tests/test_chip_compile.py`` holds the train cells' tiles to it)
+KERNEL_BLOCK_BYTES = 14 * 2 ** 20
+# rows of a grouped product's row tile where they divide the sorted
+# rows. Timed alone on the chip at both train cells' shapes (groups of
+# 800-1,800 rows) and at four serving models' prefill chunks (groups of
+# 64-256 rows), 256 and 128 read within 3% of each other and 512 read
+# 8-40% slower: a group is visited ``rows / tm + 1`` times, so the
+# tiles' padding falls with ``tm``, and with the contraction whole a
+# step's operations a byte do not depend on it. At 512 rows the
+# compiler also keeps more beside the blocks (a refusal at 13.0 MiB)
+KERNEL_ROW_TILE = 256
+# the longest side of a weight block where a product's rows are ONE row
+# tile (a decode step visits an expert once: each block is fetched once
+# whatever its size, and a visit of two or three large blocks cannot
+# hide the first one's fetch; 1,024 x 1,024 is what PR 56's rule gave)
+ONE_TILE_SIDE = 1024
 
 
 def _use_pallas():
@@ -172,16 +235,62 @@ def route(x, w_router, bias, top_k, scale, n_group=1, topk_group=1,
         weights / (summed + norm_eps if norm_eps else summed), scores
 
 
-def _kernel_tiles(m, k, n):
-    """(tm, tk, tn) of the grouped-matmul kernel for ``[m, k] x [g, k,
-    n]``, or None where its tiles do not take the widths (whole lanes,
-    and a contraction its k tile divides)."""
+def _lane_divisors(x):
+    """The multiples of a lane block that divide ``x``, largest first."""
+    return [t for t in range(x, 0, -LANES) if x % t == 0]
+
+
+def _block_bytes(kind, tm, tk, tn, itemsize, out):
+    """What a grouped product's blocks take of on-chip memory at tiles
+    ``(tm, tk, tn)``: every operand and result block twice (Pallas
+    fetches the next while one is worked on) and the float32
+    accumulator. ``"weights"`` (``tgmm``): the two row blocks, a float32
+    ``[tk, tn]`` result and accumulator. The others (``gmm``): the row
+    block, the weight block, the result block in the operands' dtype,
+    and ``out``'s block beside it where the product writes into one."""
+    if kind == "weights":
+        return 2 * tm * (tk + tn) * itemsize + 12 * tk * tn
+    return 2 * (tm * tk + tk * tn) * itemsize \
+        + (4 if out else 2) * tm * tn * itemsize + 4 * tm * tn
+
+
+def _kernel_tiles(kind, m, k, n, itemsize=2, out=False):
+    """``(tm, tk, tn)`` of a grouped product from its static shapes, or
+    None where the kernel's tiles do not take the widths (whole lanes).
+    ``kind``: ``"forward"`` (``[m, k] x [groups, k, n]``, megablox's
+    ``gmm``), ``"rows"`` (the same with the right side transposed, ``[m,
+    k] x [groups, n, k]^T``: the same blocks, so the same tiles) or
+    ``"weights"`` (``tgmm``: ``[m, k]^T x [m, n]`` a group, float32
+    ``[groups, k, n]``). The module's docstring says what the rule is
+    after; every tile is a multiple of a lane block that divides its
+    extent, and the blocks stay under ``KERNEL_BLOCK_BYTES``."""
     if k % LANES or n % LANES or m % LANES:
         return None
-    tk = next(t for t in (1024, 512, 256, 128) if k % t == 0)
-    tn = next(t for t in (1024, 512, 256, 128) if n % t == 0)
-    tm = next(t for t in (512, 256, 128) if m % t == 0)
-    return tm, tk, tn
+    tm = next(t for t in (KERNEL_ROW_TILE, LANES) if m % t == 0)
+
+    def fits(tk, tn):
+        return _block_bytes(kind, tm, tk, tn, itemsize, out) \
+            <= KERNEL_BLOCK_BYTES
+
+    if kind == "weights":
+        # the output tile whose two row blocks bring the most
+        # operations a byte, tk tn / (tk + tn); of equals the larger tk
+        _, tk, tn = max((tk * tn / (tk + tn), tk, tn)
+                        for tk in _lane_divisors(k)
+                        for tn in _lane_divisors(n) if fits(tk, tn))
+        return tm, tk, tn
+    # ONE row tile (serving's decode step, ``m == tm``): no block is used
+    # twice, so the contraction whole gains nothing, and before the
+    # pipeline fills the first block's fetch stands exposed: no side of
+    # a weight block is longer than ``ONE_TILE_SIDE`` there
+    side = ONE_TILE_SIDE if m == tm else max(k, n)
+    # the fewest steps over the contraction, then the widest column tile
+    for tk in _lane_divisors(k):
+        tn = next((tn for tn in _lane_divisors(n)
+                   if max(tk, tn) <= side and fits(tk, tn)), None)
+        if tn is not None:
+            return tm, tk, tn
+    return None
 
 
 @functools.lru_cache(maxsize=None)
@@ -234,6 +343,16 @@ def _grad_kernel(which, tiles, out_dtype, interpret, groups=None):
 INTERPRET = False
 
 
+def _product_tiles(kind, lhs, n, out=None):
+    """The kernel's tiles for a product of ``lhs [m, k]`` with ``n``
+    result columns (``_kernel_tiles``), or None where the ragged product
+    runs: off a TPU, or at widths the kernel does not take."""
+    if not (_use_pallas() or INTERPRET):
+        return None
+    return _kernel_tiles(kind, *lhs.shape, n,
+                         jnp.dtype(lhs.dtype).itemsize, out is not None)
+
+
 def grouped_matmul(lhs, rhs, group_sizes, out=None):
     """``out[rows of group g] = lhs[rows of group g] @ rhs[g]``.
 
@@ -247,9 +366,7 @@ def grouped_matmul(lhs, rhs, group_sizes, out=None):
     passes ``out``, an ``[m, n]`` array in ``lhs``'s dtype: the held
     groups' rows are written into it and the rows behind them keep what
     it held, so nothing is spent on zeros that nobody reads."""
-    m, k = lhs.shape
-    tiles = _kernel_tiles(m, k, rhs.shape[-1]) \
-        if (_use_pallas() or INTERPRET) else None
+    tiles = _product_tiles("forward", lhs, rhs.shape[-1], out)
     if tiles is not None:
         return _kernel(tiles, jnp.dtype(lhs.dtype), INTERPRET)(
             lhs, rhs, group_sizes, out)
@@ -275,9 +392,7 @@ def grouped_matmul_rows_grad(dy, rhs, group_sizes, out=None):
     """``d lhs`` of :func:`grouped_matmul`: ``dy[rows of g] @ rhs[g]^T``,
     ``[m, k]`` in ``dy``'s dtype; the rows behind the held groups come
     back as zeros, or as ``out``'s where that is given."""
-    m, n = dy.shape
-    tiles = _kernel_tiles(m, n, rhs.shape[1]) \
-        if (_use_pallas() or INTERPRET) else None
+    tiles = _product_tiles("rows", dy, rhs.shape[1], out)
     if tiles is not None:
         return _grad_kernel("rows", tiles, jnp.dtype(dy.dtype), INTERPRET)(
             dy, rhs, group_sizes, out)
@@ -290,10 +405,9 @@ def grouped_matmul_weights_grad(lhs, dy, group_sizes):
     """``d rhs`` of :func:`grouped_matmul`: ``lhs[rows of g]^T @ dy[rows
     of g]`` for the ``G`` held groups, ``[G, k, n]`` float32 (a group
     that got no row is zeros)."""
-    m, k = lhs.shape
+    m = lhs.shape[0]
     groups = group_sizes.shape[0] - 1
-    tiles = _kernel_tiles(m, k, dy.shape[-1]) \
-        if (_use_pallas() or INTERPRET) else None
+    tiles = _product_tiles("weights", lhs, dy.shape[-1])
     if tiles is not None:
         return _grad_kernel("weights", tiles, jnp.dtype(jnp.float32),
                             INTERPRET, groups)(lhs, dy, group_sizes)
@@ -615,6 +729,22 @@ def _held_row_tiles(sizes, rows):
     return tile, (jnp.sum(sizes[:-1]) + (tile - 1)) // tile
 
 
+def _kernel_rows(sizes, tm):
+    """The rows that the row tiles of a grouped product over the held
+    groups compute (a traced int32): megablox visits, for every group
+    that got a row, each ``tm``-row tile the group touches, so visits x
+    ``tm``; the landed rows themselves where the ragged product runs
+    (``tm`` None). Over the landed rows it is what the tiles' padding
+    costs under the chosen ``tm``."""
+    landed = sizes[:-1]
+    if tm is None:
+        return jnp.sum(landed)
+    ends = jnp.cumsum(landed)
+    visits = jnp.where(landed > 0,
+                       (ends + tm - 1) // tm - (ends - landed) // tm, 0)
+    return jnp.sum(visits) * tm
+
+
 def _tile_start(i, tile, rows):
     """Where tile ``i`` of ``rows`` rows starts. A plain multiple of the
     tile where the tiles divide the rows: the compiler then knows the
@@ -790,15 +920,16 @@ class HeldExpertsOp(Op):
     def state_shapes(self, input_shapes):
         return {"moe_rows_by_expert": (input_shapes[3][0],),
                 "moe_expert_visits": (), "moe_row_tiles": (),
-                "moe_row_tiles_of": (), "moe_back_rows": (),
+                "moe_row_tiles_of": (), "moe_kernel_rows": (),
+                "moe_back_rows": (),
                 "moe_back_rows_of": (), "steps": ()}
 
     def compute(self, input_vals, ectx):
         x, weights, experts, w_gate_up, w_down = input_vals
         flat, weights, held, order, sizes, back, token = _expert_rows(
             x, weights, experts, self.first, w_gate_up.shape[0])
-        h = _into_fresh(grouped_matmul, _held_rows_of(flat, token, sizes),
-                        w_gate_up, sizes)
+        xs = _held_rows_of(flat, token, sizes)
+        h = _into_fresh(grouped_matmul, xs, w_gate_up, sizes)
 
         def activated(h):
             gate, up = _gate_up(h, self.activation)
@@ -815,15 +946,20 @@ class HeldExpertsOp(Op):
                 landed = sizes[:-1]
                 rows = order.shape[0]
                 tile, tiles = _held_row_tiles(sizes, rows)
+                # the row tile follows the rows alone: given ``out`` or
+                # not, both of the forward's products walk the same
+                product = _product_tiles("forward", xs, w_gate_up.shape[-1])
                 ectx.put_state(self, {
                     "moe_rows_by_expert": state["moe_rows_by_expert"]
                     + landed,
                     "moe_expert_visits": state["moe_expert_visits"]
                     + jnp.sum(landed > 0, dtype=jnp.int32),
-                    # (a state restored from before these two has none)
+                    # (a state restored from before these three has none)
                     "moe_row_tiles": state.get("moe_row_tiles", 0) + tiles,
                     "moe_row_tiles_of": state.get("moe_row_tiles_of", 0)
                     + jnp.int32(-(-rows // tile)),
+                    "moe_kernel_rows": state.get("moe_kernel_rows", 0)
+                    + _kernel_rows(sizes, product and product[0]),
                     **_way_back_counted(state, sizes, back),
                     "steps": state["steps"] + 1})
         return out.reshape(x.shape)

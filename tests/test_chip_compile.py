@@ -897,43 +897,63 @@ def test_the_banded_walk_leaves_out_a_quarter_of_the_pairs():
     assert band["tiles_masked"] == 32 + 16
 
 
+# (sorted rows, hidden, gate|up, experts held) of the two sparse train
+# cells: 8,192 x 6 picks at smallthinker's widths, 8,192 x 4 at lfm2's
+EXPERT_CELLS = {"smallthinker": (8192 * 6, 2560, 1536, 16),
+                "lfm2": (8192 * 4, 2048, 3584, 8)}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
 @pytest.mark.parametrize("which", ["forward", "rows", "weights"])
 def test_expert_products_compile_at_the_sparse_decoders_cell(one_chip,
-                                                              which):
-    """The three grouped products of a held-expert layer's training
-    step at the cell's widths: 8,192 x 6 sorted rows, 16 experts held,
-    hidden 2,560, gate|up 1,536 wide — the forward kernel, the same with
-    its right side transposed (``hetu_moe_experts_dx``) and megablox's
-    ``tgmm`` (``hetu_moe_experts_dw``)."""
+                                                              which, cell):
+    """The six grouped products of a held-expert layer's training step
+    at a cell's widths, under the tiles the rule gives them
+    (``moe._kernel_tiles``: a tile over the kernel's on-chip memory is
+    refused here, off the chip): the forward kernel for gate|up and
+    down, the same with its right side transposed
+    (``hetu_moe_experts_dx``) for ``da`` and ``dxs``, and megablox's
+    ``tgmm`` (``hetu_moe_experts_dw``) for the two weight gradients.
+    Each is ONE custom call under its stable name."""
     from hetu_tpu.ops import moe
-    rows, hidden, wide, held = 8192 * 6, 2560, 1536, 16
+    rows, hidden, wide, held = EXPERT_CELLS[cell]
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     sizes = sds((held + 1,), jnp.int32)
-    xs, h, w = sds((rows, hidden)), sds((rows, wide)), \
-        sds((held, hidden, wide))
+    xs, h, a = sds((rows, hidden)), sds((rows, wide)), \
+        sds((rows, wide // 2))
+    w_in, w_down = sds((held, hidden, wide)), sds((held, wide // 2, hidden))
+    bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+    # (lhs, the other operand, result columns, the result's type)
     if which == "forward":
-        fn = moe._kernel(moe._kernel_tiles(rows, hidden, wide),
-                         jnp.dtype(jnp.bfloat16), False)
-        text, name = fn.lower(xs, w, sizes).compile().as_text(), \
-            moe.KERNEL_NAME
+        name = moe.KERNEL_NAME
+        calls = [(xs, w_in, wide, f"bf16[{rows},{wide}]"),
+                 (a, w_down, hidden, f"bf16[{rows},{hidden}]")]
     elif which == "rows":
-        fn = moe._grad_kernel("rows", moe._kernel_tiles(rows, wide, hidden),
-                              jnp.dtype(jnp.bfloat16), False)
-        text, name = fn.lower(h, w, sizes).compile().as_text(), \
-            moe.ROWS_GRAD_KERNEL_NAME
-        assert "bf16[49152,2560]" in text
+        name = moe.ROWS_GRAD_KERNEL_NAME
+        calls = [(xs, w_down, wide // 2, f"bf16[{rows},{wide // 2}]"),
+                 (h, w_in, hidden, f"bf16[{rows},{hidden}]")]
     else:
-        fn = moe._grad_kernel("weights",
-                              moe._kernel_tiles(rows, hidden, wide),
-                              jnp.dtype(jnp.float32), False, held)
-        text, name = fn.lower(xs, h, sizes).compile().as_text(), \
-            moe.WEIGHTS_GRAD_KERNEL_NAME
-        assert "f32[16,2560,1536]" in text
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
-    assert name in text
+        name = moe.WEIGHTS_GRAD_KERNEL_NAME
+        calls = [(a, xs, hidden, f"f32[{held},{wide // 2},{hidden}]"),
+                 (xs, h, wide, f"f32[{held},{hidden},{wide}]")]
+    for lhs, other, n, result in calls:
+        tiles = moe._kernel_tiles(which, *lhs.shape, n, 2,
+                                  which != "weights")
+        if which == "forward":
+            fn = moe._kernel(tiles, bf16, False)
+            args = (lhs, other, sizes, sds((rows, n)))
+        elif which == "rows":
+            fn = moe._grad_kernel("rows", tiles, bf16, False)
+            args = (lhs, other, sizes, sds((rows, n)))
+        else:
+            fn = moe._grad_kernel("weights", tiles, f32, False, held)
+            args = (lhs, other, sizes)
+        text = fn.lower(*args).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1, tiles
+        assert name in text and result in text
 
 
 def _sparse_decoder_step_text(v5e_device, monkeypatch):

@@ -522,7 +522,8 @@ def test_a_router_without_a_bias_keeps_the_counters_it_had():
         "moe_bias_flipped_picks": ()}
     assert sorted(shapes) == sorted([
         "moe_rows_by_expert", "moe_expert_visits", "moe_row_tiles",
-        "moe_row_tiles_of", "moe_back_rows", "moe_back_rows_of", "steps"])
+        "moe_row_tiles_of", "moe_kernel_rows", "moe_back_rows",
+        "moe_back_rows_of", "steps"])
 
 
 # -- the share ties to the model ---------------------------------------------

@@ -822,6 +822,8 @@ def test_the_step_counts_rows_and_visits_on_the_device(monkeypatch):
         assert c["moe_row_tiles"] == 3 * -(-sum(by_hand) // 16)
         assert c["moe_row_tiles_of"] == 3 * 12
         assert 0 < c["moe_row_tiles"] < c["moe_row_tiles_of"]
+        # the ragged product computes the rows that landed and no other
+        assert c["moe_kernel_rows"] == c["moe_routed_rows"]
         # the way back reads the held pairs' rows, once a direction
         assert c["moe_back_rows"] == 2 * 3 * sum(by_hand)
         assert c["moe_back_rows_of"] == 2 * 3 * 192
@@ -830,14 +832,56 @@ def test_the_step_counts_rows_and_visits_on_the_device(monkeypatch):
     # they read 0 and count from there
     for state in g.executor.state.values():
         if "moe_back_rows" in state:
-            del state["moe_back_rows"], state["moe_back_rows_of"]
+            del state["moe_back_rows"], state["moe_back_rows_of"], \
+                state["moe_kernel_rows"]
     assert all(c["moe_back_rows"] == 0 and c["moe_back_rows_of"] == 0
+               and c["moe_kernel_rows"] == 0
                and c["steps"] == 3 for c in g.executor.moe_counters())
     g.run("default", ids, labels)
     for layer, c in zip(picks, g.executor.moe_counters()):
+        assert c["moe_kernel_rows"] == int(
+            ((layer >= 2) & (layer < 6)).sum())
         assert c["moe_back_rows"] == 2 * int(
             ((layer >= 2) & (layer < 6)).sum())
         assert c["moe_back_rows_of"] == 2 * 192 and c["steps"] == 4
+
+
+class _Counting(_Training):
+    """A training context that keeps ONE op's state, from zeros."""
+
+    def __init__(self, op, input_shapes):
+        super().__init__()
+        self.new_state = {}
+        self.state = {k: jnp.zeros(shape, jnp.int32) for k, shape
+                      in op.state_shapes(input_shapes).items()}
+
+    def get_state(self, key, default=None):
+        return self.state
+
+    def put_state(self, key, value):
+        self.new_state[key] = value
+
+
+def test_the_op_counts_the_rows_its_row_tiles_compute(kernels):
+    """Under the kernels ``moe_kernel_rows`` is visits x ``tm``: every
+    ``tm``-row tile that a held group with a row touches, from the op's
+    own ``sizes`` on the device."""
+    arrays, first = _expert_case("random", hidden=128, width=128,
+                                 tokens=(1, 200))
+    nodes = [ht.Variable(f"n{i}", trainable=False) for i in range(5)]
+    op = ht.held_experts_op(*nodes, first=first)
+    ectx = _Counting(op, [a.shape for a in arrays])
+    op.compute([jnp.asarray(a) for a in arrays], ectx)
+    counted = ectx.new_state[op]
+    # 200 x 3 pairs padded to 640 sorted rows: the rule's row tile
+    tm = moe._kernel_tiles("forward", 640, 128, 256, 4, True)[0]
+    assert 640 // tm > 1
+    landed = [int((arrays[2] == first + e).sum()) for e in range(4)]
+    assert counted["moe_rows_by_expert"].tolist() == landed
+    ends = np.cumsum(landed)
+    visits = sum(-(-end // tm) - (end - n) // tm
+                 for end, n in zip(ends, landed) if n)
+    assert int(counted["moe_kernel_rows"]) == visits * tm > sum(landed)
 
 
 def _hlo_computations(text):
