@@ -339,7 +339,7 @@ def predict(eval_nodes, feed_shapes=None, config=None, costdb=None,
             ms_threshold=None):
     """Priced lint over a graph in one call: shape-propagate, run
     :func:`efficiency_pass`, return an :class:`EfficiencyResult` —
-    the CLI's, graphboard's and bench's entry point."""
+    the CLI's and graphboard's entry point."""
     from .shapes import shape_pass
     from ..graph.autodiff import find_topo_sort
 

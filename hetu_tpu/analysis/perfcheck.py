@@ -121,14 +121,14 @@ def soundness_pass(findings, measured_buckets, report=None,
 
 def serving_claim_check(claimed_tokens_per_s, counted_tokens, wall_s,
                         factor=SOUND_FACTOR):
-    """The serving half of the HT910 attribution discipline: a bench's
-    *claimed* tokens/sec must agree with the rate its own telemetry
-    counters support — ``counted_tokens`` (the engine's ``<name>_tokens``
-    counter delta over the measured window) divided by the window's
-    wall clock. Within ``factor`` either way the claim is attributed;
-    outside it, the bench's workload arithmetic and the engine's token
-    accounting have drifted apart and the number is asserted, not
-    measured. Returns ``(ok, measured_tokens_per_s)``."""
+    """The serving half of the HT910 attribution discipline: a
+    *claimed* tokens/sec must agree with the rate the engine's own
+    telemetry counters support — ``counted_tokens`` (the engine's
+    ``<name>_tokens`` counter delta over the measured window) divided
+    by the window's wall clock. Within ``factor`` either way the claim
+    is attributed; outside it, the claimant's workload arithmetic and
+    the engine's token accounting have drifted apart and the number is
+    asserted, not measured. Returns ``(ok, measured_tokens_per_s)``."""
     wall_s = float(wall_s)
     if wall_s <= 0 or counted_tokens <= 0:
         return False, 0.0

@@ -13,8 +13,8 @@ The compile cache can be placed from outside: where
 own overrides (``HETU_COSTDB``, ``HETU_RANGEDB``).
 
 Only the chip entry points call :func:`enable_compile_cache`
-(``chip_smoke.py``, ``bench.py``; ``heturun`` exports the directory to
-its workers) — the CPU test harness compiles for described devices
+(``heturun`` exports the directory to its workers) — the CPU test
+harness compiles for described devices
 whose cache entries cannot be read back without a chip.
 """
 from __future__ import annotations

@@ -1178,7 +1178,7 @@ class SubExecutor:
 
     def trace_args(self, executor, feed_map):
         """The argument tuple ``step_fn`` expects for this feed map —
-        used by compile-check harnesses (__graft_entry__) and run()."""
+        used by compile-check harnesses and run()."""
         # host numpy scalars: tiny committed args, no eager device ops
         lr = np.float32(0.0)
         for opt in self.optimizer_ops:
@@ -1473,8 +1473,8 @@ class Executor:
 
         # -- async-ingest accounting (hetu_tpu/ingest.py) --------------
         # every engine this session runs folds its wait/busy numbers in
-        # here, so bench/metric code can report ingest_wait_ms and
-        # overlap_fraction per measurement window (reset + read)
+        # here, so metric code can report ingest_wait_ms and
+        # overlap_fraction
         self._ingest_stats = _ingest_engine.new_stats()
 
         # -- HT502 run-loop advisory (analysis/overlap.py) -------------
@@ -1580,16 +1580,12 @@ class Executor:
 
     # ------------------------------------------------------------------
     def ingest_stats(self):
-        """Async-ingest accounting since the last reset:
+        """Async-ingest accounting of this executor's life:
         ``ingest_wait_ms`` (p50 of per-pop consumer stalls — ~0 when
         the host is fully hidden), wait/busy sums, and
         ``overlap_fraction`` (share of ingest host time hidden behind
         the device). See hetu_tpu/ingest.py."""
         return _ingest_engine.stats_fields(self._ingest_stats)
-
-    def reset_ingest_stats(self):
-        """Zero the ingest accounting (bench: exclude warmup windows)."""
-        self._ingest_stats = _ingest_engine.new_stats()
 
     # ------------------------------------------------------------------
     def run(self, name="default", eval_node_list=None, feed_dict=None,

@@ -85,8 +85,8 @@ def _hashed_bits(k0, k1, shape):
     none (the generic interpreter has no rule for ``prng_seed``, the
     TPU one returns zeros): murmur3's finalizer, twice, over the
     element's index and the block's key. The tests of the kernel's
-    arithmetic run on it; the generator's own bits are checked on the
-    chip (``chip_smoke.py``)."""
+    arithmetic run on it; the generator's own bits can be checked only
+    on the chip."""
     def fmix(x):
         x = x ^ jax.lax.shift_right_logical(x, 16)
         x = x * jnp.int32(0x85EBCA6B - 2 ** 32)

@@ -822,7 +822,7 @@ def apply_plan(eval_nodes, plan, info=None, _splice_rules=True):
             "bucket_bytes": recommend_bucket_bytes(info.get("db"))}
     bindings = plan.bindings
     if plan.tp > 1 and _splice_rules:
-        # a plan is often applied to a REBUILT graph (the bench's
+        # a plan is often applied to a REBUILT graph (a
         # measure-per-candidate loop, a fresh training process reusing
         # a cached plan): stored bindings reference the scored graph's
         # nodes, so recompile the rules against THIS graph whenever

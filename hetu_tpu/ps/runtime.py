@@ -134,7 +134,7 @@ class PSRuntime:
     @contextlib.contextmanager
     def _phase(self, name):
         """One PS step phase: accumulates host seconds into the legacy
-        ``times`` counter (StepLogger deltas, bench breakdown) AND — when
+        ``times`` counter (StepLogger deltas, ``phase_breakdown``) AND — when
         telemetry is on — emits a ``ps:<name>`` span plus a per-phase
         latency histogram, so PS RPC cost shows up on the Perfetto
         timeline next to the device dispatches it delays."""
@@ -279,8 +279,8 @@ class PSRuntime:
         push_bound steps — off the per-step path): per-table
         ``ps_table_<tid>_spill_hit_rate`` / ``ps_table_<tid>_row_bytes``
         and the fleet-wide ``ps_repl_queue_depth`` backlog. Gauges are
-        informational (the fleet timeline rides them into its records;
-        bench stamps stay the source of record for regress.py)."""
+        informational (the fleet timeline rides them into its
+        records)."""
         tel = self.config.telemetry
         if not tel.enabled or not self._store_tids:
             return
@@ -1221,15 +1221,8 @@ class PSRuntime:
             import sys
             print(f"[hetu-ps] teardown drain failed: {e}", file=sys.stderr)
 
-    def reset_phase_times(self):
-        """Zero the phase counters (bench: exclude warmup from the
-        steady-state breakdown)."""
-        with self._times_mu:
-            for k in self.times:
-                self.times[k] = 0.0
-
     def phase_breakdown(self):
-        """Accumulated per-phase host seconds (bench attribution); also
+        """Accumulated per-phase host seconds; also
         publishes the device-cache hit/miss/evict counters as telemetry
         gauges so a Prometheus scrape sees them."""
         with self._times_mu:

@@ -33,8 +33,7 @@ router rotation.
 The backend is either an :class:`InferenceSession` (each request runs
 its own forward) or anything with ``submit(...) -> Future`` (a
 :class:`MicroBatcher`, a :class:`ContinuousBatchingEngine` front, a
-:class:`ReplicaRouter` — the configuration the load driver in
-``bench.py serving`` measures). A production frontend would speak gRPC;
+:class:`ReplicaRouter`). A production frontend would speak gRPC;
 this is deliberately the smallest thing that lets a multi-threaded
 closed-loop client exercise the batching + bucketing stack end to end.
 """

@@ -13,7 +13,7 @@ types in ``SPAN_SCHEMA`` below, and an exported trace whose known span
 carries an attr of the wrong type — or an attr the schema has never
 heard of — fails validation. That is the drift gate: PR 7's
 ``overlapped=`` attr shipped with no schema at all, so a consumer (the doctor's
-hidden/exposed split, the regress field comparisons) could silently
+hidden/exposed split) could silently
 misread them. New span kinds/attrs must be added HERE and covered by a
 fixture trace in ``tests/test_doctor.py``.
 """

@@ -44,7 +44,7 @@ that the host is actually hidden.
 CLI::
 
     python -m hetu_tpu.telemetry.doctor TELEMETRY_DIR [--json]
-        [--bench BENCH_r07.json] [--costdb PATH] [--tolerance 0.1]
+        [--costdb PATH] [--tolerance 0.1]
 
 prints a ranked diagnosis — top exposed bucket, bubble fraction,
 comm:compute ratio, transfer hidden fraction, cost-DB coverage gaps —
@@ -562,7 +562,7 @@ def summarize_requests(reqs, tolerance=0.05):
 
 def attribute_request_events(events, tolerance=0.05):
     """One event list (e.g. an in-process ``tracer.drain()``) ->
-    serving summary. ``bench.py serving_continuous`` gates on this."""
+    serving summary."""
     return summarize_requests(parse_request_events(events, tolerance),
                               tolerance)
 
@@ -675,7 +675,7 @@ def _remedy(bucket):
     return text
 
 
-def diagnose(per_rank, costdb=None, bench=None, tolerance=0.10):
+def diagnose(per_rank, costdb=None, tolerance=0.10):
     """Fleet-level diagnosis over ``attribute_trace`` output: straggler
     rank, ranked exposed buckets, ratios, cost-DB coverage, remediation
     pointers. Returns a JSON-able dict."""
@@ -732,27 +732,7 @@ def diagnose(per_rank, costdb=None, bench=None, tolerance=0.10):
             "path": costdb.path, "entries": len(costdb),
             "kinds": len(costdb.kinds()), "comm_covered": present,
             "comm_gaps": missing, "curves": curves}
-    if bench:
-        diag["bench"] = bench
     return diag
-
-
-def _bench_summary(path):
-    """Headline metrics from a BENCH_*.json (or bench JSONL) file, for
-    printing beside the trace attribution."""
-    from .regress import load_metrics
-    try:
-        metrics = load_metrics(path)
-    except OSError:
-        return None
-    out = {}
-    for name, rec in metrics.items():
-        keep = {k: rec[k] for k in
-                ("value", "unit", "step_ms_p50", "step_ms_p95",
-                 "h2d_MBps", "overlap_fraction", "ingest_wait_ms")
-                if k in rec}
-        out[name] = keep
-    return out
 
 
 def _fmt_ms(v):
@@ -811,15 +791,6 @@ def render_text(diag):
         if cdb["comm_gaps"]:
             lines.append(f"  coverage gaps: {cdb['comm_gaps']} — run "
                          f"python -m hetu_tpu.telemetry.costdb --sweep")
-    bench = diag.get("bench")
-    if bench:
-        lines.append("bench headline(s) beside the trace:")
-        for name, rec in sorted(bench.items())[:8]:
-            extra = "".join(
-                f", {k}={rec[k]}" for k in
-                ("step_ms_p50", "overlap_fraction") if k in rec)
-            lines.append(f"  {name}: {rec.get('value')} "
-                         f"{rec.get('unit', '')}{extra}")
     return "\n".join(lines)
 
 
@@ -830,9 +801,6 @@ def main(argv=None):
                     "ranked perf diagnosis from a telemetry dir")
     parser.add_argument("telemetry", help="telemetry dir (per-rank "
                         "trace_rank*.json) or one trace file")
-    parser.add_argument("--bench", default=None,
-                        help="BENCH_*.json (or bench JSONL) to print "
-                             "beside the attribution")
     parser.add_argument("--costdb", default=None,
                         help="cost DB path for the coverage report "
                              "(default: the standard DB if it exists)")
@@ -868,9 +836,7 @@ def main(argv=None):
         db = CostDB(args.costdb)
     elif os.path.exists(default_db_path()):
         db = CostDB()
-    bench = _bench_summary(args.bench) if args.bench else None
-    diag = diagnose(per_rank, costdb=db, bench=bench,
-                    tolerance=args.tolerance)
+    diag = diagnose(per_rank, costdb=db, tolerance=args.tolerance)
     if args.json:
         print(json.dumps(diag, indent=1, sort_keys=True))
     else:

@@ -57,7 +57,7 @@ import weakref
 import numpy as np
 
 __all__ = ["HealthOptions", "HealthMonitor", "HealthError",
-           "observe_staleness", "active", "last_summary",
+           "observe_staleness", "active",
            "merge_records", "diagnose", "format_report", "main"]
 
 log = logging.getLogger(__name__)
@@ -67,12 +67,6 @@ log = logging.getLogger(__name__)
 # abandoned executors' monitors are collectable; ``active()`` is the
 # disabled path's entire cost — one falsy check, zero allocations.
 _MONITORS = weakref.WeakSet()
-
-# last sampled health summary in this process — bench.py emit() stamps
-# loss_finite / grad_norm_final from it onto headline metrics. Reset
-# when a new monitor is constructed so a bench unit that never sampled
-# can't inherit the previous unit's verdict.
-_LAST = None
 
 # jsonl paths this process already opened: the FIRST open per process
 # truncates (a rerun reusing a telemetry dir must not merge two runs'
@@ -86,13 +80,6 @@ def active():
     """True when any health monitor is live in this process (the
     sparse-side hooks' zero-cost gate)."""
     return bool(_MONITORS)
-
-
-def last_summary():
-    """The most recent sampled health record's summary fields (or None
-    when no monitor has sampled yet): ``{"step", "loss_finite",
-    "grad_norm_total"}``."""
-    return _LAST
 
 
 def observe_staleness(kind, tid, values, bound, monitor=None):
@@ -271,11 +258,6 @@ class HealthMonitor:
         self._closed = False
         self._last_good = None
         _MONITORS.add(self)
-        # a fresh monitor means a fresh executor: the process-global
-        # summary must not carry the previous executor's verdict into
-        # this one's bench stamps
-        global _LAST
-        _LAST = None
 
     # -- executor hooks --------------------------------------------------
     def after_step(self, sub, runtime=None):
@@ -510,9 +492,6 @@ class HealthMonitor:
         if not trips:
             self._last_good = rec
         self._write(rec)
-        global _LAST
-        _LAST = {"step": rec["step"], "loss_finite": rec["loss_finite"],
-                 "grad_norm_total": rec["grad_norm_total"]}
 
         if tel is not None and tel.enabled:
             if math.isfinite(total):

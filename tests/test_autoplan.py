@@ -480,7 +480,7 @@ def test_costdb_coverage_measured_vs_guessed(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_apply_plan_to_rebuilt_graph_resplices():
-    """A plan applied to a REBUILT graph (the bench's per-candidate
+    """A plan applied to a REBUILT graph (a per-candidate
     measurement loop) must recompile its rules against that graph —
     stored bindings reference the scored graph's nodes, and silently
     splicing nothing would report a tp plan while running unsplit."""

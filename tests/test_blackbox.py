@@ -1,7 +1,7 @@
 """Black-box observability (hetu_tpu/telemetry/{flight,watchdog,memory,
-blackbox,regress}): flight-recorder ring semantics, seq-divergence
+blackbox}): flight-recorder ring semantics, seq-divergence
 detection, memory accounting, heartbeats + fleet watchdog, truncated-
-trace salvage, the regress CLI, and the acceptance scenario — one rank
+trace salvage, and the acceptance scenario — one rank
 of a 2-process GPipe dryrun SIGKILLed mid-run."""
 import gc
 import json
@@ -18,7 +18,7 @@ import hetu_tpu as ht
 from hetu_tpu.executor import Executor
 from hetu_tpu.telemetry import (Telemetry, FlightRecorder, MetricsRegistry,
                                 NULL, merge_traces, validate)
-from hetu_tpu.telemetry import blackbox, memory, regress
+from hetu_tpu.telemetry import blackbox, memory
 from hetu_tpu.telemetry.watchdog import (EXIT_WATCHDOG, FleetWatchdog,
                                          Heartbeat)
 
@@ -345,62 +345,6 @@ def test_merge_salvages_truncated_trace(tmp_path, capsys):
     assert pids == {0, 1}          # the crashed rank still contributes
     r1 = [e for e in events if e["pid"] == 1 and e["ph"] == "X"]
     assert 0 < len(r1) < 20        # a prefix, not everything
-
-
-# ---------------------------------------------------------------------------
-# regress CLI (satellite)
-# ---------------------------------------------------------------------------
-
-def _bench_file(path, metrics):
-    lines = "\n".join(json.dumps(m) for m in metrics)
-    with open(path, "w") as f:
-        json.dump({"n": 1, "cmd": "bench", "rc": 0, "tail": lines,
-                   "parsed": metrics[-1]}, f)
-
-
-def test_regress_cli_gates_on_regression(tmp_path):
-    old = tmp_path / "OLD.json"
-    new_ok = tmp_path / "NEW_OK.json"
-    new_bad = tmp_path / "NEW_BAD.json"
-    base = [
-        {"metric": "step_time", "value": 10.0, "unit": "ms/step"},
-        {"metric": "tput", "value": 1000.0, "unit": "samples/sec/chip"},
-        {"metric": "broken", "value": -1, "unit": "error"},
-    ]
-    _bench_file(old, base)
-    _bench_file(new_ok, [
-        {"metric": "step_time", "value": 10.9, "unit": "ms/step"},
-        {"metric": "tput", "value": 950.0, "unit": "samples/sec/chip"},
-        {"metric": "broken", "value": -1, "unit": "error"},
-        {"metric": "fresh", "value": 1.0, "unit": "ms/step"},
-    ])
-    _bench_file(new_bad, [
-        {"metric": "step_time", "value": 14.0, "unit": "ms/step"},
-        {"metric": "tput", "value": 1000.0, "unit": "samples/sec/chip"},
-    ])
-    ok = subprocess.run(
-        [sys.executable, "-m", "hetu_tpu.telemetry.regress",
-         str(old), str(new_ok), "--tolerance", "0.15"],
-        capture_output=True, text=True, env=_cli_env())
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    assert "0 regression(s)" in ok.stdout
-    bad = subprocess.run(
-        [sys.executable, "-m", "hetu_tpu.telemetry.regress",
-         str(old), str(new_bad), "--tolerance", "0.15"],
-        capture_output=True, text=True, env=_cli_env())
-    assert bad.returncode == 1
-    assert "REGRESSED" in bad.stdout and "step_time" in bad.stdout
-    assert "tput" in bad.stdout
-
-
-def test_regress_direction_inference():
-    # ms-like units regress UP, throughput units regress DOWN
-    old = {"a": {"metric": "a", "value": 10.0, "unit": "ms/step"},
-           "b": {"metric": "b", "value": 100.0, "unit": "tokens/sec"}}
-    new = {"a": {"metric": "a", "value": 8.0, "unit": "ms/step"},
-           "b": {"metric": "b", "value": 130.0, "unit": "tokens/sec"}}
-    rows = {r[0]: r[4] for r in regress.compare(old, new, 0.15)}
-    assert rows == {"a": "improved", "b": "improved"}
 
 
 # ---------------------------------------------------------------------------

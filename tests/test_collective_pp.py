@@ -112,7 +112,7 @@ def _ref(M=8, steps=3):
 
 
 @pytest.mark.parametrize("opts", [
-    # every tick-loop/feed-transport variant the bench A/Bs must stay
+    # every tick-loop/feed-transport variant must stay
     # loss-equivalent to the staged runner (ISSUE 1 acceptance)
     {"feed_mode": "replicated", "fuse_ticks": 1,
      "unroll_fill_drain": False},

@@ -1,11 +1,9 @@
 """Perf doctor (hetu_tpu/telemetry/{doctor,costdb}): bucket attribution
 with conservation, hidden/exposed transfer split, the doctor CLI, the
 measured cost database (persistence across reload, comm curves,
-span/profile producers), the span-attr schema fixtures, and the bench
-emit auto-attribution."""
+span/profile producers) and the span-attr schema fixtures."""
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -15,8 +13,6 @@ from hetu_tpu.executor import Executor
 from hetu_tpu.telemetry import Telemetry, Tracer, check, doctor
 from hetu_tpu.telemetry.costdb import (CostDB, comm_microbench,
                                        record_spans)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -452,69 +448,3 @@ def test_check_cli_no_attrs_flag(tmp_path, capsys):
     assert "INVALID" in capsys.readouterr().out
     assert check.main(["--no-attrs", path]) == 0
     assert "OK" in capsys.readouterr().out
-
-
-# ---------------------------------------------------------------------------
-# regress --history (satellite)
-# ---------------------------------------------------------------------------
-
-def _round_file(tmp_path, label, value, extra=""):
-    p = tmp_path / f"BENCH_{label}.json"
-    tail = json.dumps({"metric": "m_tput", "value": value,
-                       "unit": "samples/sec"}) + "\n" + extra
-    p.write_text(json.dumps({"n": 1, "tail": tail}))
-    return str(p)
-
-
-def test_regress_history_markdown(tmp_path):
-    from hetu_tpu.telemetry import regress
-    files = [_round_file(tmp_path, "r01", 100.0),
-             _round_file(tmp_path, "r02", 200.0),
-             _round_file(tmp_path, "r03", 120.0)]
-    labels, table = regress.history(files)
-    assert labels == ["r01", "r02", "r03"]
-    assert table["m_tput"]["values"] == [100.0, 200.0, 120.0]
-    md = regress.history_markdown(labels, table)
-    assert "| r01 | r02 | r03 |" in md
-    assert "REGRESSED" in md        # 200 -> 120 throughput drop
-    out = tmp_path / "hist.md"
-    assert regress.main(["--history", *files,
-                         "--markdown", str(out)]) == 0
-    assert "m_tput" in out.read_text()
-
-
-def test_regress_two_file_cli_still_works(tmp_path, capsys):
-    from hetu_tpu.telemetry import regress
-    a = _round_file(tmp_path, "a", 100.0)
-    b = _round_file(tmp_path, "b", 99.0)
-    assert regress.main([a, b]) == 0
-    assert regress.main([a]) == 2       # old/new pair still required
-
-
-# ---------------------------------------------------------------------------
-# bench emit auto-attribution (tentpole: every headline metric)
-# ---------------------------------------------------------------------------
-
-def test_bench_emit_stamps_doctor_buckets(tmp_path, capsys):
-    sys.path.insert(0, REPO)
-    import bench
-    import hetu_tpu.telemetry as tmod
-    tel = tmod.configure(enabled=True)
-    bench._doctor_seen_ts = 0.0
-    t = tel.clock()
-    tel.complete("step", t, t + 10_000_000, {"subgraph": "default"})
-    tel.complete("device_dispatch", t, t + 6_000_000,
-                 {"subgraph": "default"})
-    bench.emit("stamped_metric", 1.0, "ms/step", 1.0, h2d_MBps=10.0,
-               step_ms_p50=1.0, step_ms_p95=2.0)
-    rec = json.loads(capsys.readouterr().out.splitlines()[-1])
-    assert rec["buckets_conserve"] is True
-    assert rec["bucket_ms_per_step"]["compute"] == pytest.approx(
-        6.0, rel=1e-3)
-    assert rec["bucket_ms_per_step"]["unaccounted"] == pytest.approx(
-        4.0, rel=1e-3)
-    # second emit with no new spans: no stale re-stamp
-    bench.emit("quiet_metric", 1.0, "ms/step", 1.0, h2d_MBps=10.0,
-               step_ms_p50=1.0, step_ms_p95=2.0)
-    rec2 = json.loads(capsys.readouterr().out.splitlines()[-1])
-    assert "bucket_ms_per_step" not in rec2

@@ -27,8 +27,8 @@ from hetu_tpu.analysis.efficiency import (
     sorted_by_savings)
 from hetu_tpu.analysis.findings import Finding
 from hetu_tpu.analysis.perfcheck import (
-    AB_TOLERANCE, ab_bucketed_allreduce, perfcheck_model,
-    soundness_pass, _constant_feeds)
+    AB_TOLERANCE, SOUND_FACTOR, ab_bucketed_allreduce, perfcheck_model,
+    serving_claim_check, soundness_pass, _constant_feeds)
 from hetu_tpu.analysis.shapes import shape_pass
 from hetu_tpu.graph.autodiff import find_topo_sort
 from hetu_tpu.telemetry.costdb import (CostDB, latency_crossover_bytes,
@@ -488,7 +488,7 @@ def test_graphboard_waste_overlay(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# satellites: doctor cross-link, regress, autoplan bucket default
+# satellites: doctor cross-link, autoplan bucket default
 # ---------------------------------------------------------------------------
 
 def test_doctor_remediation_cites_ht_codes():
@@ -507,21 +507,6 @@ def test_doctor_remediation_cites_ht_codes():
     assert "analysis.efficiency" in top["remedy"]
     ranked = {r["bucket"]: r for r in diag["ranked_exposed"]}
     assert ranked["collective"]["ht_code"] == "HT904"
-
-
-def test_regress_estimated_ms_informational():
-    from hetu_tpu.telemetry.regress import compare
-
-    old = {"m": {"metric": "m", "value": 10.0, "unit": "ms/step",
-                 "estimated_ms_per_step": 1.0, "ht9xx_findings": 2}}
-    new = {"m": {"metric": "m", "value": 10.0, "unit": "ms/step",
-                 "estimated_ms_per_step": 99.0, "ht9xx_findings": 0}}
-    rows = compare(old, new, 0.15)
-    by_name = {r[0]: r for r in rows}
-    # reported on their face, never direction-compared
-    assert by_name["m.estimated_ms_per_step"][4] == "info"
-    assert by_name["m.ht9xx_findings"][4] == "info"
-    assert by_name["m"][4] == "ok"
 
 
 def test_recommend_bucket_bytes():
@@ -590,6 +575,23 @@ def test_ht910_escape_fixture():
     # unmeasured buckets and unpriced advisories are vacuous
     report2, checked2 = soundness_pass([big_claim], {"compute": 1.0})
     assert checked2 == 0 and not report2.findings
+
+
+@pytest.mark.parametrize("claimed,counted,wall_s,ok,measured", [
+    (120.0, 240, 2.0, True, 120.0),         # the counters' own rate
+    (120.0 * SOUND_FACTOR, 240, 2.0, True, 120.0),      # at the bound
+    (120.0 * SOUND_FACTOR + 1.0, 240, 2.0, False, 120.0),   # over-claim
+    (120.0 / SOUND_FACTOR - 1.0, 240, 2.0, False, 120.0),   # under-claim
+    (120.0, 0, 2.0, False, 0.0),            # the engine counted nothing
+    (120.0, 240, 0.0, False, 0.0),          # no window
+    (0.0, 240, 2.0, False, 120.0),          # nothing claimed
+])
+def test_serving_claim_check_holds_a_claim_to_the_counters(
+        claimed, counted, wall_s, ok, measured):
+    """A claimed tokens/s is attributed only within SOUND_FACTOR of
+    what the engine's token counter supports over the same window;
+    a window or a counter that measured nothing attributes nothing."""
+    assert serving_claim_check(claimed, counted, wall_s) == (ok, measured)
 
 
 def test_ht904_ab_measured_confirms_prediction():

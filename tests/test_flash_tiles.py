@@ -59,7 +59,7 @@ def test_cell_call_resolves_to_the_tiles_read_on_the_chip(call):
 
 
 @pytest.mark.parametrize("s,d,kind,causal,has_mask", [
-    (2048, 64, "fwd_lse", False, True),     # bench.py's bert_s2048
+    (2048, 64, "fwd_lse", False, True),     # BERT-small at S = 2048
     (2048, 64, "bwd", False, True),
     (512, 64, "fwd_lse", False, True),      # BERT at S = 512
     (1024, 64, "bwd", True, True),          # causal beside padding

@@ -56,8 +56,8 @@ def test_gpt_learns_periodic_sequence():
 def test_gpt_logits_are_causal(flash):
     """Changing ONLY the last input token must not change any earlier
     position's logits — direct probe of the causal masking, on BOTH
-    the composed-mask path and the flash-op path (the one bench_gpt
-    and every use_flash_attention=True user runs)."""
+    the composed-mask path and the flash-op path (the one every
+    use_flash_attention=True user runs)."""
     ids, labels, logits, lm, train = _build(flash=flash)
     exe = Executor([logits])
     rng = np.random.RandomState(0)

@@ -1,7 +1,7 @@
 """Training health monitor (hetu_tpu/telemetry/health.py): device-side
 sentinels fused into the jitted step, cadence sampling, the trip ladder
 (warn/dump/raise), staleness + hot-key + table telemetry, the
-divergence-doctor CLI, the blackbox/bench/regress integrations, the
+divergence-doctor CLI, the blackbox integration, the
 overhead contract, and the 2-rank injected-NaN acceptance run."""
 import gc
 import json
@@ -104,9 +104,6 @@ def test_sentinels_sampled_at_cadence(tmp_path):
              (tmp_path / "health_rank0.jsonl").read_text().splitlines()]
     assert [r["step"] for r in lines] == [5, 10]
     exe.close()
-    # last_summary feeds bench.emit's loss_finite stamp
-    s = health.last_summary()
-    assert s["step"] == 10 and s["loss_finite"] is True
 
 
 def test_nan_trip_names_step_and_layer_and_dumps(tmp_path):
@@ -629,7 +626,7 @@ def test_health_schema_drift_rejected(tmp_path, name, args, match):
 
 
 # ---------------------------------------------------------------------------
-# blackbox / bench / regress integration
+# blackbox integration
 # ---------------------------------------------------------------------------
 
 def test_blackbox_ingests_health_records(tmp_path):
@@ -649,41 +646,6 @@ def test_blackbox_ingests_health_records(tmp_path):
     assert rep["suspect_ranks"] == [0]
     text = blackbox.format_report(rep)
     assert "HEALTH: first bad step 10" in text
-
-
-def test_bench_emit_stamps_loss_finite(capsys):
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    health._LAST = {"step": 40, "loss_finite": True,
-                    "grad_norm_total": 1.25}
-    bench.emit("unit_test_metric", 10.0, "samples/sec", 1.0,
-               h2d_MBps=1.0, step_ms_p50=1.0, step_ms_p95=2.0)
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["loss_finite"] is True
-    assert rec["grad_norm_final"] == 1.25
-    # no summary -> no stamp (health not armed)
-    health._LAST = None
-    bench.emit("unit_test_metric2", 10.0, "samples/sec", 1.0,
-               h2d_MBps=1.0, step_ms_p50=1.0, step_ms_p95=2.0)
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "loss_finite" not in rec
-
-
-def test_regress_health_fields_informational():
-    from hetu_tpu.telemetry.regress import compare
-    old = {"m": {"metric": "m", "value": 100.0, "unit": "samples/sec",
-                 "loss_finite": True, "grad_norm_final": 1.0}}
-    new = {"m": {"metric": "m", "value": 99.0, "unit": "samples/sec",
-                 "loss_finite": False, "grad_norm_final": 900.0}}
-    rows = compare(old, new, tolerance=0.15)
-    by_name = {r[0]: r for r in rows}
-    assert by_name["m.loss_finite"][4] == "info"
-    assert by_name["m.grad_norm_final"][4] == "info"
-    # a loss_finite flip (or a 900x grad norm) is never a perf verdict
-    assert all(r[4] != "REGRESSED" for r in rows)
 
 
 # ---------------------------------------------------------------------------

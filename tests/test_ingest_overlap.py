@@ -460,7 +460,7 @@ def test_executor_bucket_bytes_is_numeric_noop(ps_env):
 
 
 # ---------------------------------------------------------------------------
-# HT5xx advisory + regress direction + bench gate
+# HT5xx advisory
 # ---------------------------------------------------------------------------
 
 def test_overlapped_spans_marked_in_trace(ps_env):
@@ -534,44 +534,3 @@ def test_ht502_plain_run_loop_advisory(ps_env, monkeypatch):
     codes = [f.code for f in exe.config.analysis_report.findings]
     assert codes.count("HT502") == 1
     exe.close()
-
-
-def test_regress_overlap_field_direction():
-    """overlap_fraction regresses when it goes DOWN (higher-is-better);
-    ingest_wait_ms when it goes UP — both ride the metric record."""
-    from hetu_tpu.telemetry import regress
-
-    def rec(of, wait):
-        return {"m": {"metric": "m", "value": 100.0,
-                      "unit": "samples/sec/chip",
-                      "overlap_fraction": of, "ingest_wait_ms": wait}}
-
-    rows = {name: status for name, _, _, _, status
-            in regress.compare(rec(0.9, 10.0), rec(0.4, 2.0), 0.15)}
-    assert rows["m.overlap_fraction"] == "REGRESSED"
-    assert rows["m.ingest_wait_ms"] == "improved"
-    rows = {name: status for name, _, _, _, status
-            in regress.compare(rec(0.4, 2.0), rec(0.9, 10.0), 0.15)}
-    assert rows["m.overlap_fraction"] == "improved"
-    assert rows["m.ingest_wait_ms"] == "REGRESSED"
-
-
-def test_bench_emit_requires_overlap_fields():
-    """Feed-bound bench units must stamp the overlap accounting — the
-    BENCH_r07 acceptance fields can't silently drop."""
-    import importlib.util
-    import pathlib
-    spec = importlib.util.spec_from_file_location(
-        "bench", pathlib.Path(__file__).resolve().parent.parent
-        / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    base = {"h2d_MBps": 20.0, "step_ms_p50": 1.0, "step_ms_p95": 2.0}
-    with pytest.raises(ValueError, match="overlap"):
-        bench.emit("wdl_criteo_ps_samples_per_sec_per_chip",
-                   1.0, "samples/sec/chip", 1.0, **base)
-    bench.emit("wdl_criteo_ps_samples_per_sec_per_chip",
-               1.0, "samples/sec/chip", 1.0, ingest_wait_ms=0.1,
-               overlap_fraction=0.9, **base)        # must not raise
-    bench.emit("mlp_cifar10_step_time", 1.0, "ms", 1.0, **base)

@@ -414,3 +414,62 @@ def test_ctr_ps_roundtrip_and_readonly_guard(ps_env, tmp_path):
     with pytest.raises(RuntimeError, match="read-only"):
         sess.ps_client.sparse_push(123, np.zeros(1), np.zeros((1, 4)), 4)
     sess.close()
+
+
+def test_ctr_ps_session_behind_batcher_and_http(ps_env):
+    """The PS-backed serving session under load: four closed-loop HTTP
+    clients send 1-4 rows a request through the micro-batcher; every
+    response has its request's rows, equal to what the session predicts
+    for those rows alone, and the frontend counted every request."""
+    from hetu_tpu.models.ctr import wdl_adult
+    rng = np.random.RandomState(12)
+    dense = ht.Variable("dense_input", trainable=False)
+    sparse = ht.Variable("sparse_input", trainable=False)
+    y_ = ht.Variable("y_", trainable=False)
+    loss, y, y_, train_op = wdl_adult(dense, sparse, y_)
+    exe = Executor([loss, train_op], comm_mode="PS")
+    for _ in range(2):          # registers + trains the table on the PS
+        exe.run(feed_dict={
+            dense: rng.randn(32, 6).astype("f"),
+            sparse: rng.randint(0, 50000, (32, 8)),
+            y_: np.eye(2, dtype="f")[rng.randint(0, 2, 32)]})
+    exe.close()
+
+    tel = _tel()
+    eval_nodes = [y]
+    serve_embeddings_from_ps(eval_nodes)
+    sess = InferenceSession(eval_nodes, comm_mode="PS",
+                            embed_cache_rows=1 << 12, telemetry=tel)
+    dn = rng.randn(64, 6).astype("f")
+    sp = rng.randint(0, 50000, (64, 8))
+    want = np.asarray(sess.predict({"dense_input": dn,
+                                    "sparse_input": sp})[0])
+    errors = []
+    with MicroBatcher(sess.predict, max_batch_size=16, max_wait_ms=2,
+                      telemetry=tel) as mb, \
+            ServingHTTPServer(mb, telemetry=tel) as srv:
+        def client(k):
+            try:
+                for i in range(6):
+                    n, at = 1 + (k + i) % 4, (k * 6 + i) * 2
+                    resp = _post(srv.port, {"inputs": {
+                        "dense_input": dn[at:at + n].tolist(),
+                        "sparse_input": sp[at:at + n].tolist()}})
+                    np.testing.assert_allclose(
+                        np.asarray(resp["outputs"][0]), want[at:at + n],
+                        rtol=1e-5, atol=1e-6)
+            except Exception as e:              # noqa: BLE001
+                errors.append(e)
+
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads), "a client hung"
+        assert not errors, errors
+    snap = {s["name"]: s for s in tel.metrics.snapshot()}
+    assert snap["serve_requests"]["value"] == 24
+    assert sess.ps_client.hit_rate > 0
+    sess.close()

@@ -13,7 +13,7 @@ import hetu_tpu as ht
 from hetu_tpu import telemetry
 import hetu_tpu.models as M
 from hetu_tpu.serving import ContinuousBatchingEngine, InferenceSession
-from hetu_tpu.telemetry import blackbox, regress
+from hetu_tpu.telemetry import blackbox
 from hetu_tpu.telemetry.check import check_args, validate
 from hetu_tpu.telemetry.doctor import (SERVE_BUCKETS,
                                        attribute_request_events,
@@ -342,7 +342,7 @@ def test_doctor_serving_cli_roundtrip(tmp_path, capsys):
     assert diag["conserved"] and diag["complete"]
     assert diag["top_bucket"]["bucket"] in SERVE_BUCKETS
     assert diag["top_bucket"]["remedy"]
-    # the bench-stamped / regress-gated percentile fields exist here too
+    # the percentile fields the serve cells report exist here too
     for field in ("serve_ttft_p99_ms", "serve_tpot_p50_ms",
                   "serve_queue_wait_p99_ms"):
         assert diag[field] > 0, field
@@ -423,32 +423,3 @@ def test_blackbox_ingests_requests_without_flight_dump(tmp_path):
     assert rep is not None
     text = blackbox.format_report(rep)
     assert "lone-1" in text and "3 KV blocks held" in text
-
-
-# ---------------------------------------------------------------------------
-# regress gate directions for the stamped serving fields
-# ---------------------------------------------------------------------------
-
-def test_regress_directions_for_serving_fields():
-    for field in ("serve_ttft_p99_ms", "serve_tpot_p50_ms",
-                  "serve_queue_wait_p99_ms"):
-        assert regress._FIELD_DIRECTION[field] is True, \
-            f"{field} must be lower-is-better"
-    # a dropping prefix hit rate is a regression, not an improvement
-    assert regress._FIELD_DIRECTION["serve_prefix_hit_rate"] is False
-
-    base = {"serving_tokens_per_sec_per_chip": {
-        "metric": "serving_tokens_per_sec_per_chip", "value": 400.0,
-        "unit": "tokens/sec/chip", "serve_ttft_p99_ms": 100.0}}
-    worse = {"serving_tokens_per_sec_per_chip": {
-        "metric": "serving_tokens_per_sec_per_chip", "value": 400.0,
-        "unit": "tokens/sec/chip", "serve_ttft_p99_ms": 200.0}}
-    rows = regress.compare(base, worse, tolerance=0.15)
-    ttft = next(r for r in rows
-                if r[0].endswith(".serve_ttft_p99_ms"))
-    assert ttft[4] == "REGRESSED"
-    # and the improvement direction reads as improvement, not noise
-    rows = regress.compare(worse, base, tolerance=0.15)
-    ttft = next(r for r in rows
-                if r[0].endswith(".serve_ttft_p99_ms"))
-    assert ttft[4] == "improved"

@@ -48,29 +48,72 @@ def _drive(engine, futures, limit=500):
 # timelines: completeness + conservation on a live engine
 # ---------------------------------------------------------------------------
 
-def test_request_timelines_conserve_end_to_end():
+def _closed_loop(eng, work):
+    """One client thread a row of ``work``, each sending its requests
+    one after another: more clients than batch slots, so requests
+    wait, join and leave a running batch. Returns every request's
+    output in submission order a client."""
+    outs, errors = [None] * len(work), []
+
+    def client(k):
+        try:
+            outs[k] = [eng.submit(p, g, request_id=f"obs-{k}-{i}")
+                       .result(120) for i, (p, g) in enumerate(work[k])]
+        except Exception as e:                      # noqa: BLE001
+            errors.append(e)
+
+    threads = [threading.Thread(target=client, args=(k,))
+               for k in range(len(work))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(300)
+    assert not any(t.is_alive() for t in threads), "a client hung"
+    assert not errors, errors
+    return outs
+
+
+@pytest.mark.parametrize("closed_loop", [False, True],
+                         ids=["hand_driven", "closed_loop"])
+def test_request_timelines_conserve_end_to_end(closed_loop):
     """Every retired request carries a complete timeline whose
     queue/prefill/decode/replay/overhead buckets sum to its measured
-    e2e — the tentpole acceptance check, in-process."""
+    e2e — the tentpole acceptance check, in-process: on an engine
+    stepped by hand, and on a running engine behind a closed loop of
+    twice as many client threads as batch slots, where every request
+    also gets the tokens it asked for and the engine's token counter
+    counts exactly those."""
     tel = _tel()
     cfg, sess = gpt_session(seed=0)
     eng = ContinuousBatchingEngine.from_session(
         sess, cfg, num_blocks=30, block_size=4, max_batch_size=4,
-        telemetry=tel, start=False)
+        telemetry=tel, start=closed_loop)
     rng = np.random.RandomState(1)
-    futs = [eng.submit(rng.randint(0, VOCAB, (int(rng.randint(2, 10)),)),
-                       int(g), request_id=f"obs-{i}")
-            for i, g in enumerate(rng.randint(1, 7, 6))]
-    _drive(eng, futs)
+    if closed_loop:
+        work = [[(rng.randint(0, VOCAB, (int(rng.randint(2, 10)),)),
+                  int(rng.randint(1, 7))) for _ in range(3)]
+                for _ in range(8)]
+        outs = _closed_loop(eng, work)
+        asked = [g for reqs in work for _, g in reqs]
+        assert [len(o) for client in outs for o in client] == asked
+        assert tel.counter_value("engine_tokens") == sum(asked)
+        ids = {f"obs-{k}-{i}" for k in range(8) for i in range(3)}
+    else:
+        futs = [eng.submit(rng.randint(0, VOCAB,
+                                       (int(rng.randint(2, 10)),)),
+                           int(g), request_id=f"obs-{i}")
+                for i, g in enumerate(rng.randint(1, 7, 6))]
+        _drive(eng, futs)
+        ids = {f"obs-{i}" for i in range(6)}
     eng.close()
 
     diag = attribute_request_events(tel.tracer.drain())
-    assert diag["requests"] == 6
+    assert diag["requests"] == len(ids)
     assert diag["conserved"], f"violations: {diag['violations']}"
     assert diag["complete"], f"incomplete: {diag['incomplete']}"
     # the ingress-supplied ids survived to the attribution
     seen = {r["request_id"] for r in diag["slowest_requests"]}
-    assert seen <= {f"obs-{i}" for i in range(6)}
+    assert seen <= ids
     # per-request invariants: TTFT exists, buckets non-negative
     for r in diag["slowest_requests"]:
         assert r["ttft_ms"] is not None and r["ttft_ms"] >= 0

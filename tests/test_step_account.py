@@ -168,7 +168,7 @@ def test_an_inference_subexecutor_is_all_forward():
 @pytest.fixture
 def as_a_chip_entry_point(monkeypatch, tmp_path):
     """The jax config of ``cachedir.enable_compile_cache()`` (what
-    ``benchmark/run.py``, ``chip_smoke.py`` and ``bench.py`` call),
+    ``benchmark/run.py`` and ``chip_smoke.py`` call),
     restored afterwards; the cache itself stays where it was."""
     from hetu_tpu import cachedir
     names = ("jax_include_full_tracebacks_in_locations",

@@ -452,29 +452,6 @@ def test_steplogger_compat_wrapper(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench attribution gate
-# ---------------------------------------------------------------------------
-
-def test_bench_emit_requires_attribution(capsys):
-    """bench.emit fails loudly when a metric drops its h2d/percentile
-    attribution fields; the error unit stays exempt."""
-    sys.path.insert(0, REPO)
-    import bench
-    with pytest.raises(ValueError, match="attribution"):
-        bench.emit("naked_metric", 1.0, "ms/step", 1.0)
-    with pytest.raises(ValueError, match="step_ms_p95"):
-        bench.emit("half_dressed", 1.0, "ms/step", 1.0,
-                   h2d_MBps=100.0, step_ms_p50=1.0)
-    bench.emit("dressed", 1.0, "ms/step", 1.0, h2d_MBps=100.0,
-               step_ms_p50=1.0, step_ms_p95=2.0)
-    bench.emit("bench_broken", -1, "error", 0,
-               error="RuntimeError: x")     # error path stays exempt
-    out = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
-    assert out[0]["metric"] == "dressed" and out[0]["h2d_MBps"] == 100.0
-    assert out[1]["unit"] == "error"
-
-
-# ---------------------------------------------------------------------------
 # 2-process GPipe dryrun with --telemetry (the acceptance scenario)
 # ---------------------------------------------------------------------------
 
