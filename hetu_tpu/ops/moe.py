@@ -46,39 +46,52 @@ elsewhere would add is the exchange's business, not this module's.
   (``hetu_moe_experts_dw``: megablox's ``tgmm``); the rows of experts
   held elsewhere are never computed in either direction.
 
-**The held extent** (the graph op only; :func:`held_experts`, which
-serving runs at 128 padded rows a decode step, keeps its whole-array
-body). The sort puts the ``n = sum(sizes[:-1])`` rows of the experts
-held here first, and ``n`` is on the device. Every composed pass of the
-op whose result is indexed by SORTED ROW — the gathers ``flat[token]``
-and ``dy[token]``, the activation between the two products, the
-backward's ``da``, ``da * act``, ``w_row * act``, the activation's slope
-and ``dh`` — runs under :func:`_over_held_rows`: a loop over row tiles
-of ``ROW_TILE`` rows whose trip count is ``ceil(n / tile)``, each tile
-written in place into a buffer of the full ``[T x k, ...]`` shape that
-starts with no value (:func:`_fresh`). Nothing is chosen: no capacity,
-no fallback, no dropped row; the extent follows the routing. The rows
-past the last tile that ran hold whatever the allocation held, and every
-reader selects them away: the grouped kernels mask their operands and
-their store by group. The way back to token order (:func:`_token_sums`:
-the forward's weighted sum and the backward's ``dx`` are one function)
-is indexed by (token, pick) and visits the pairs that landed here, too:
-the tokens sorted by how many picks they hold here, a loop over the
-tiles of ``TOKEN_TILE`` tokens that hold any, and inside it a loop over
-a token's held picks that gathers a tile of the product's rows and adds
-it to a float32 tile; a token then reads its sum from its place in that
-order. So nothing reads a row of the op's four grouped products (``h``,
-``ys``, ``da``, ``dxs``) behind the held groups, and the op has the
-kernels write into buffers that start with no value
-(``grouped_matmul(..., out=)``): the library's zero fill behind the
-groups is serving's alone. The op's state counts the tiles
+**The held extent** (:func:`_held_extent_passes`, the ONE body of the
+graph op's forward and of :func:`held_experts` wherever a pass has more
+sorted rows than one row tile: ``T x k > ROW_TILE``, a STATIC fact of
+the call and the only thing the choice reads. A prefill pass of the
+serving blocks has 512 to 32,768 sorted rows and takes it from 2,049 on;
+a decode step's ``B x k`` rows padded to 128, at most 256, and the
+shortest prompts keep every pass over the whole arrays in
+:func:`held_experts` itself. The graph op takes it at any size: its
+training cells have 49,152 rows a layer, and its lowered text at the
+tests' 192 is pinned. The border is where the chip put it: both forms
+timed alone at sarvam's widths (``PERF.md`` section 6, PR 59), the
+held-extent form read 0.05-0.09 ms a layer behind at 512 rows, level at
+2,048, 2-6% ahead at 4,096 and 8,192 and 33-46% ahead at 32,768 at held
+shares of 1/8 and 1/4; with every expert held it skips nothing and reads
+within 3.5% either way at every size.) The sort puts the ``n =
+sum(sizes[:-1])`` rows of the experts held here first, and ``n`` is on
+the device. Every composed pass of the op whose result is indexed by
+SORTED ROW — the gathers ``flat[token]`` and ``dy[token]``, the
+activation between the two products, the backward's ``da``, ``da *
+act``, ``w_row * act``, the activation's slope and ``dh`` — runs under
+:func:`_over_held_rows`: a loop over row tiles of ``ROW_TILE`` rows
+whose trip count is ``ceil(n / tile)``, each tile written in place into
+a buffer of the full ``[T x k, ...]`` shape that starts with no value
+(:func:`_fresh`). Nothing is chosen: no capacity, no fallback, no
+dropped row; the extent follows the routing. The rows past the last tile
+that ran hold whatever the allocation held, and every reader selects
+them away: the grouped kernels mask their operands and their store by
+group. The way back to token order (:func:`_token_sums`: the forward's
+weighted sum and the backward's ``dx`` are one function) is indexed by
+(token, pick) and visits the pairs that landed here, too: the tokens
+sorted by how many picks they hold here, a loop over the tiles of
+``TOKEN_TILE`` tokens that hold any, and inside it a loop over a token's
+held picks that gathers a tile of the product's rows and adds it to a
+float32 tile; a token then reads its sum from its place in that order.
+So nothing reads a row of the op's four grouped products (``h``, ``ys``,
+``da``, ``dxs``) behind the held groups, and the op has the kernels
+write into buffers that start with no value (``grouped_matmul(...,
+out=)``): the library's zero fill behind the groups is left to the
+whole-array form of a decode step. The op's state counts the tiles
 (``moe_row_tiles``, beside ``moe_row_tiles_of``, the tiles that all ``T
 x k`` rows are: their quotient is the share of the passes' work that is
 left) and the rows the way back read (``moe_back_rows``, beside
-``moe_back_rows_of``, the ``T x k`` a direction that all the pairs
-are). The permutations of a scalar a pair or a token (``back``,
-``w_row``, the pairs' weight gradient, a token's place in the way
-back's order) are sorts (:func:`_moved`), not gathers or scatters.
+``moe_back_rows_of``, the ``T x k`` a direction that all the pairs are).
+The permutations of a scalar a pair or a token (``back``, ``w_row``, the
+pairs' weight gradient, a token's place in the way back's order) are
+sorts (:func:`_moved`), not gathers or scatters.
 
 **The tiles** of the three grouped kernels (:func:`_kernel_tiles`) are
 read from what a product is given and from nothing else: ``m``, ``k``,
@@ -152,11 +165,12 @@ LANES = 128
 # [tokens * top_k, hidden], 268 MB at 4096 tokens x 8 picks x 4096 wide
 # in bfloat16, whatever the prompt bucket
 TOKEN_CHUNK = 4096
-# sorted rows a composed pass of the graph op takes at a time: its loop
-# runs the row tiles that hold a held expert's row and no other
-# (``_over_held_rows``). A tile's gather moves 10 MB at 2560 wide in
-# bfloat16, tens of microseconds beside a loop iteration's few, and a
-# pass rounds its rows up by half a tile on average
+# sorted rows a composed pass takes at a time: its loop runs the row
+# tiles that hold a held expert's row and no other (``_over_held_rows``).
+# Also the border of serving's ``held_experts``: a pass of at most one
+# such tile runs over the whole arrays. A tile's gather moves 10 MB at
+# 2560 wide in bfloat16, tens of microseconds beside a loop iteration's
+# few, and a pass rounds its rows up by half a tile on average
 ROW_TILE = 2048
 # sorted tokens the way back takes at a time (``_token_sums``): each of
 # a token's k ranks of held picks rounds its tokens up to a tile, so a
@@ -299,8 +313,9 @@ def _kernel(tiles, out_dtype, interpret):
     name. The library's own entry point is a ``jax.jit`` called
     ``gmm``, and a program's instructions are named for the innermost
     jitted function, so its body is wrapped anew. Given ``out`` (the
-    graph op's: :func:`grouped_matmul`), the kernel writes the held
-    groups' rows into it and the library fills nothing behind them."""
+    held-extent form's: :func:`grouped_matmul`), the kernel writes the
+    held groups' rows into it and the library fills nothing behind
+    them."""
     gmm = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm").gmm.__wrapped__
 
@@ -362,10 +377,10 @@ def grouped_matmul(lhs, rhs, group_sizes, out=None):
     which are not computed and come back as zeros. Returns ``[m, n]``
     in ``lhs``'s dtype, accumulated in float32.
 
-    A caller that reads no row behind the held groups (the graph op)
-    passes ``out``, an ``[m, n]`` array in ``lhs``'s dtype: the held
-    groups' rows are written into it and the rows behind them keep what
-    it held, so nothing is spent on zeros that nobody reads."""
+    A caller that reads no row behind the held groups (the held-extent
+    form) passes ``out``, an ``[m, n]`` array in ``lhs``'s dtype: the
+    held groups' rows are written into it and the rows behind them keep
+    what it held, so nothing is spent on zeros that nobody reads."""
     tiles = _product_tiles("forward", lhs, rhs.shape[-1], out)
     if tiles is not None:
         return _kernel(tiles, jnp.dtype(lhs.dtype), INTERPRET)(
@@ -462,10 +477,23 @@ def held_experts(x, experts, weights, valid, w_gate_up, w_down, first=0,
     of weight * expert(x) [T, hidden] float32, rows by held expert
     [held] int32)``. The sorted copies are ``[T * k, hidden]``: a
     caller with many tokens passes ``TOKEN_CHUNK`` at a time.
-    ``activation`` names the gate's (``ACTIVATIONS``)."""
+    ``activation`` names the gate's (``ACTIVATIONS``).
+
+    The form of the passes round the two products follows the STATIC
+    number of sorted rows and nothing else (the module's docstring,
+    **The held extent**): more than ``ROW_TILE`` of them (a prefill
+    pass) run :func:`_held_extent_passes`, the graph op's body; a
+    decode step's one or two hundred run every pass over the whole
+    arrays, where the loops skip nothing worth their own cost."""
     t, k = experts.shape
     held_n = w_gate_up.shape[0]
     order, sizes, held, rows = _sorted_pairs(experts, valid, held_n, first)
+    if order.shape[0] > ROW_TILE:
+        back = _moved(jnp.arange(rows, dtype=jnp.int32), order[:rows])
+        out, _, _ = _held_extent_passes_once(
+            x, order // k, sizes, back, held, jnp.where(held, weights, 0.0),
+            w_gate_up, w_down, activation, jnp.float32)
+        return out, sizes[:held_n]
     xs = x[order // k]
     h = grouped_matmul(xs, w_gate_up, sizes)
     width = h.shape[-1] // 2
@@ -886,6 +914,39 @@ def _token_sums(rows, back, held, coeff, dtype):
     return jnp.where((count > 0)[:, None], sums[place], 0)
 
 
+def _held_extent_passes(x, token, sizes, back, held, weights, w_gate_up,
+                        w_down, activation, dtype):
+    """The forward of the held experts with every pass run to the held
+    extent, the ONE body of :class:`HeldExpertsOp` and of
+    :func:`held_experts` over ``ROW_TILE`` sorted rows: the gather of
+    the tokens to the sorted rows that landed here (``x [T, hidden]``,
+    ``token`` a sorted row's token), gate|up into a buffer with no
+    value, the activation under :func:`_over_held_rows`, down into
+    another such buffer, and the way back (:func:`_token_sums`: ``back``
+    a pair's sorted row, ``held`` ``[T, k]``, ``weights`` ``[T, k]``
+    with 0 for a pair held elsewhere). Returns ``(the sums [T, hidden]
+    in dtype, xs, h)``; the last two are what the op's backward and its
+    counters read again."""
+    xs = _held_rows_of(x, token, sizes)
+    h = _into_fresh(grouped_matmul, xs, w_gate_up, sizes)
+
+    def activated(h):
+        gate, up = _gate_up(h, activation)
+        return ((gate * up).astype(x.dtype),)
+
+    (act,) = _over_held_rows(activated, sizes, (h,))
+    ys = _into_fresh(grouped_matmul, act, w_down, sizes)
+    return _token_sums(ys, back, held, weights, dtype), xs, h
+
+
+# serving's entry: a program's expert layers have one shape, so the
+# passes are traced once a shape and lowered once a program, not once a
+# layer (a 30-program warm-up spent 7 s more in tracing without it).
+# The graph op calls the body itself: its step's text is pinned
+_held_extent_passes_once = jax.jit(_held_extent_passes,
+                                   static_argnums=(8, 9))
+
+
 class HeldExpertsOp(Op):
     """:func:`held_experts` as a graph node: ``x [B, S, hidden]``, the
     router's ``weights`` and ``experts`` ``[B, S, k]``, and the stacked
@@ -928,16 +989,9 @@ class HeldExpertsOp(Op):
         x, weights, experts, w_gate_up, w_down = input_vals
         flat, weights, held, order, sizes, back, token = _expert_rows(
             x, weights, experts, self.first, w_gate_up.shape[0])
-        xs = _held_rows_of(flat, token, sizes)
-        h = _into_fresh(grouped_matmul, xs, w_gate_up, sizes)
-
-        def activated(h):
-            gate, up = _gate_up(h, self.activation)
-            return ((gate * up).astype(x.dtype),)
-
-        (act,) = _over_held_rows(activated, sizes, (h,))
-        ys = _into_fresh(grouped_matmul, act, w_down, sizes)
-        out = _token_sums(ys, back, held, weights, x.dtype)
+        out, xs, h = _held_extent_passes(
+            flat, token, sizes, back, held, weights, w_gate_up, w_down,
+            self.activation, x.dtype)
         if ectx.training:
             # the backward reads the sort and the first product again
             ectx.cache[("held_experts", self.id)] = (order, sizes, back, h)

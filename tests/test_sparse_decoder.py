@@ -243,6 +243,15 @@ def kernels(monkeypatch):
     monkeypatch.setattr(attention, "_use_pallas", lambda: True)
 
 
+@pytest.fixture
+def plain_passes(monkeypatch):
+    """Serving's entry to the held-extent passes without its ``jax.jit``:
+    the jitted one keeps a trace a shape, which would outlive a test's
+    patched tiles and buffers, and hides the loops' trip counts."""
+    monkeypatch.setattr(moe, "_held_extent_passes_once",
+                        moe._held_extent_passes)
+
+
 class _Training:
     """What an op's ``compute`` needs of a training step's context."""
     training = True
@@ -388,30 +397,42 @@ def test_held_experts_kernels_interpreted(kernels, routing):
         close(got, wanted, 1e-5)
 
 
-def _whole_array_experts(x, weights, picks, w_in, w_out, first,
-                         act=jax.nn.relu):
-    """The graph op as it stood before the held extent, kept here as the
-    plain reference: every pass over ALL ``T x k`` sorted rows (the
-    gather, both ragged products with the rows of the last group zeroed,
-    the activation, the way back), differentiated by ``jax.vjp``."""
-    k, hidden = picks.shape[-1], x.shape[-1]
+def _whole_array_serving(x, picks, weights, valid, w_in, w_out, first,
+                         act=jax.nn.silu):
+    """Serving's ``held_experts`` as it stood while every pass ran over
+    ALL ``T x k`` sorted rows, kept here as the plain reference: a
+    padded token's pairs in the last group, both ragged products with
+    that group's rows zeroed, the way back a gather of every pair and a
+    float32 sum over ``k`` in pick order."""
+    t, k = picks.shape
     held_n, width = w_in.shape[0], w_in.shape[-1] // 2
-    local = picks.reshape(-1, k) - first
-    held = (local >= 0) & (local < held_n)
+    local = picks - first
+    held = (local >= 0) & (local < held_n) & valid[:, None]
     group = jnp.where(held, local, held_n).reshape(-1)
     sizes = jnp.zeros(held_n + 1, jnp.int32).at[group].add(1)
     order = jnp.argsort(group, stable=True)
-    rows = order.shape[0]
-    here = jnp.arange(rows)[:, None] < jnp.sum(sizes[:-1])
-    xs = x.reshape(-1, hidden)[order // k]
+    here = jnp.arange(t * k)[:, None] < jnp.sum(sizes[:-1])
+    xs = x[order // k]
     h = jnp.where(here, jax.lax.ragged_dot(xs, w_in, sizes[:-1]), 0.0)
     a = act(h[:, :width]) * h[:, width:]
     ys = jnp.where(here, jax.lax.ragged_dot(a, w_out, sizes[:-1]), 0.0)
-    back = jnp.zeros(rows, jnp.int32).at[order].set(
-        jnp.arange(rows, dtype=jnp.int32))
-    out = jnp.einsum("tk,tkh->th",
-                     jnp.where(held, weights.reshape(-1, k), 0.0),
-                     ys[back].reshape(-1, k, hidden))
+    back = jnp.zeros(t * k, jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    out = jnp.einsum("tk,tkh->th", jnp.where(held, weights, 0.0),
+                     ys[back].reshape(t, k, -1).astype(jnp.float32))
+    return out, sizes[:held_n], held
+
+
+def _whole_array_experts(x, weights, picks, w_in, w_out, first,
+                         act=jax.nn.relu):
+    """The graph op as it stood before the held extent, kept here as the
+    plain reference (``_whole_array_serving`` with no padded token),
+    differentiated by ``jax.vjp``."""
+    k = picks.shape[-1]
+    out, _, _ = _whole_array_serving(
+        x.reshape(-1, x.shape[-1]), picks.reshape(-1, k),
+        weights.reshape(-1, k), jnp.ones(picks.size // k, bool), w_in,
+        w_out, first, act)
     return out.reshape(x.shape)
 
 
@@ -494,22 +515,152 @@ def test_held_experts_passes_run_to_the_held_extent(case, monkeypatch):
     _check_op_against_the_whole_array_form(arrays, first)
 
 
-def test_serving_held_experts_hands_the_products_no_out(monkeypatch):
-    """``held_experts`` (serving) reads ``ys[back]`` past the extent and
-    keeps the library's zeros there: it never passes ``out``."""
+def _serving_case(routing, interpreted, padded):
+    """``held_experts``' arguments from ``_expert_case``: one sequence of
+    64 tokens (40 of whole-lane widths under the kernels); ``padded``
+    makes every third token and the last five padding."""
+    arrays, first = _expert_case(routing, hidden=128, width=128,
+                                 tokens=(1, 40)) if interpreted \
+        else _expert_case(routing, tokens=(1, 2 * S))
+    x, weights, picks = (jnp.asarray(a[0]) for a in arrays[:3])
+    tokens = x.shape[0]
+    valid = (jnp.arange(tokens) % 3 != 1) & (jnp.arange(tokens) < tokens - 5)\
+        if padded else jnp.ones(tokens, bool)
+    return (x, picks, weights, valid, jnp.asarray(arrays[3]),
+            jnp.asarray(arrays[4]), first)
+
+
+# (routing, the passes' row tile, interpreted kernels, poisoned buffers,
+# padded tokens); 64 tokens x 3 picks = 192 sorted rows composed, 40 x 3
+# = 120 padded to 128 under the kernels: over every tile but the last
+# case's, which is the border itself
+SERVING_EXTENT_CASES = {
+    "no_pair_held": ("none_held", 16, False, False, False),
+    "every_pair_held": ("all_held", 16, False, False, False),
+    "all_to_one_expert": ("all_to_one", 16, False, False, False),
+    "extent_no_multiple_of_the_tile": ("random", 16, False, False, False),
+    "rows_no_multiple_of_the_tile": ("random", 80, False, False, False),
+    "padded_tokens": ("random", 16, False, False, True),
+    "every_pair_held_but_the_padded": ("all_held", 16, False, True, True),
+    "kernels_interpreted": ("random", 48, True, False, False),
+    "kernels_interpreted_every_pair_held": ("all_held", 48, True, False,
+                                            False),
+    "rows_past_the_extent_poisoned": ("random", 16, False, True, False),
+    "rows_past_the_extent_poisoned_kernels": ("random", 48, True, True,
+                                              False),
+    "no_pair_held_poisoned_kernels": ("none_held", 48, True, True, False),
+    "padded_tokens_poisoned_kernels": ("random", 48, True, True, True),
+    "one_tile_takes_the_whole_array_form": ("random", 192, False, True,
+                                            True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SERVING_EXTENT_CASES))
+def test_serving_held_experts_over_the_border_runs_to_the_held_extent(
+        case, monkeypatch, plain_passes):
+    """``held_experts`` (serving) with more sorted rows than one row
+    tile runs the graph op's passes (``_held_extent_passes``): its
+    float32 sums and its rows by expert against the whole-array form
+    kept above, a token with no held pick and a padded token exactly 0,
+    the two row loops' trip count ``ceil(held rows / tile)``. A poisoned
+    case starts every buffer from NaN. At or under one tile no loop
+    runs and no buffer is made: the whole-array form."""
+    routing, tile, interpreted, poisoned, padded = SERVING_EXTENT_CASES[case]
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    monkeypatch.setattr(moe, "TOKEN_TILE", 8)
+    if interpreted:
+        monkeypatch.setattr(moe, "INTERPRET", True)
+        monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    made = []
+
+    def fresh(shapes, after, real=moe._fresh):
+        made.extend(shapes)
+        return _nan_buffers(shapes, after) if poisoned \
+            else real(shapes, after)
+
+    monkeypatch.setattr(moe, "_fresh", fresh)
+    trips, loop = [], jax.lax.fori_loop
+
+    def counted(lower, upper, body, init):
+        trips.append(upper)
+        return loop(lower, upper, body, init)
+
+    monkeypatch.setattr(jax.lax, "fori_loop", counted)
+    args = _serving_case(routing, interpreted, padded)
+    x, picks, _, valid = args[:4]
+    got, rows = moe.held_experts(*args, activation="silu")
+    monkeypatch.setattr(jax.lax, "fori_loop", loop)
+    want, want_rows, held = _whole_array_serving(*args)
+    assert got.dtype == jnp.float32 and got.shape == x.shape
+    assert np.isfinite(np.asarray(got)).all()
+    close(got, want, 1e-5)
+    assert np.asarray(rows).tolist() == np.asarray(want_rows).tolist()
+    idle = ~np.asarray(held).any(axis=1)
+    assert idle[~np.asarray(valid)].all()
+    assert not np.asarray(got)[idle].any()
+    n, sorted_rows = int(held.sum()), -(-picks.size // 128) * 128 \
+        if interpreted else picks.size
+    if case == "extent_no_multiple_of_the_tile":
+        assert n % tile and n > tile
+    if case == "rows_no_multiple_of_the_tile":
+        assert sorted_rows % tile and n > tile
+    if sorted_rows <= tile:
+        assert case == "one_tile_takes_the_whole_array_form"
+        assert not trips and not made
+        return
+    # the gather of the tokens and the activation, then the way back
+    assert [int(t) for t in trips[:2]] == [-(-n // tile)] * 2
+    assert int(trips[2]) == -(-int((~idle).sum()) // 8)
+    # xs, h, act, ys by sorted row and the sums by token: nothing else
+    assert [s.shape[0] for s in made] == [sorted_rows] * 4 + [x.shape[0]]
+
+
+def test_serving_held_experts_at_the_modules_own_tiles():
+    """Nothing patched: 1,024 tokens x 3 picks = 3,072 sorted rows, over
+    ``ROW_TILE`` = 2,048, through the jitted entry a program's layers
+    share; 512 tokens, 1,536 rows, the whole-array form. Both against
+    the plain expression, every other token padding."""
+    assert moe.ROW_TILE == 2048 and moe.TOKEN_TILE == 512
+    for tokens in (1024, 512):
+        arrays, first = _expert_case("random", tokens=(1, tokens))
+        x, weights, picks = (jnp.asarray(a[0]) for a in arrays[:3])
+        args = (x, picks, weights, jnp.arange(tokens) % 2 == 0,
+                jnp.asarray(arrays[3]), jnp.asarray(arrays[4]), first)
+        got, rows = jax.jit(moe.held_experts, static_argnums=6)(*args)
+        want, want_rows, _ = _whole_array_serving(*args)
+        close(got, want, 1e-5)
+        assert np.asarray(rows).tolist() == np.asarray(want_rows).tolist()
+        assert not np.asarray(got)[1::2].any()
+
+
+@pytest.mark.parametrize("rows", ["over_the_border", "one_tile"])
+def test_serving_held_experts_hands_the_products_out_over_the_border(
+        rows, monkeypatch, plain_passes):
+    """Over one row tile of sorted rows ``held_experts`` (serving) reads
+    no row of a product past the held extent, and both products write
+    into a buffer that starts with no value (``out``); at or under a
+    tile it reads ``ys[back]`` past the extent and keeps the library's
+    zeros there: no ``out``."""
     seen = []
     real = moe.grouped_matmul
 
     def recorded(lhs, rhs, group_sizes, out=None):
-        seen.append(out)
+        if not isinstance(lhs, jax.core.Tracer):    # (``eval_shape``'s)
+            seen.append(out)
         return real(lhs, rhs, group_sizes, out)
 
     monkeypatch.setattr(moe, "grouped_matmul", recorded)
+    monkeypatch.setattr(moe, "ROW_TILE", S * TOP_K - 16
+                        if rows == "over_the_border" else S * TOP_K)
     arrays, first = _expert_case("random", tokens=(1, S))
     x, w, p = (jnp.asarray(a[0]) for a in arrays[:3])
     moe.held_experts(x, p, w, jnp.ones(S, bool), jnp.asarray(arrays[3]),
                      jnp.asarray(arrays[4]), first, activation="relu")
-    assert seen == [None, None]
+    assert len(seen) == 2
+    if rows == "one_tile":
+        assert seen == [None, None]
+    else:
+        assert all(o is not None and o.shape[0] == S * TOP_K for o in seen)
 
 
 # (routing, the passes' row tile, the way back's token tile, the gate's
@@ -905,6 +1056,23 @@ def _dims(type_text):
                  if d)
 
 
+def _loops_of(comps):
+    """The computations that run inside a ``while`` body of a lowered
+    module (``_hlo_computations``), however deep."""
+    called = {c: {w for *_, line in instrs for w in re.findall(
+        r"(?:body|to_apply|calls)=([\w.\-]+)", line)}
+        for c, instrs in comps.items()}
+    in_a_loop = {w for instrs in comps.values() for *_, op, _, line in instrs
+                 if op == "while"
+                 for w in re.findall(r"body=([\w.\-]+)", line)}
+    grown = True
+    while grown:
+        more = {w for c in in_a_loop for w in called.get(c, ())} - in_a_loop
+        in_a_loop |= more
+        grown = bool(more)
+    return in_a_loop
+
+
 def test_no_gather_of_the_tokens_to_all_sorted_rows_outside_a_loop(
         monkeypatch):
     """In the lowered training step every gather of an expert layer
@@ -928,17 +1096,7 @@ def test_no_gather_of_the_tokens_to_all_sorted_rows_outside_a_loop(
     text = jax.jit(step).lower(*sub.trace_args(g.executor, feed)) \
         .compiler_ir(dialect="hlo").as_hlo_text()
     comps = _hlo_computations(text)
-    called = {c: {w for *_, line in instrs for w in re.findall(
-        r"(?:body|to_apply|calls)=([\w.\-]+)", line)}
-        for c, instrs in comps.items()}
-    in_a_loop = {w for instrs in comps.values() for *_, op, _, line in instrs
-                 if op == "while"
-                 for w in re.findall(r"body=([\w.\-]+)", line)}
-    grown = True
-    while grown:
-        more = {w for c in in_a_loop for w in called.get(c, ())} - in_a_loop
-        in_a_loop |= more
-        grown = bool(more)
+    in_a_loop = _loops_of(comps)
     tokens, rows = ids.size, ids.size * TOP_K
     tiled, way_back, token_order = [], [], []
     for comp, instrs in comps.items():
@@ -965,6 +1123,99 @@ def test_no_gather_of_the_tokens_to_all_sorted_rows_outside_a_loop(
     assert len(token_order) == 2 * 4
     # and no float32 [T, k, hidden] array anywhere
     assert not re.search(rf"f32\[{tokens},{TOP_K},{HIDDEN}\]", text)
+
+
+@pytest.mark.parametrize("form", ["held_extent", "one_tile"])
+@pytest.mark.parametrize("model", ["latent", "window"])
+def test_a_prefill_pass_gathers_no_tokens_to_all_sorted_rows_outside_a_loop(
+        model, form, monkeypatch, plain_passes):
+    """The lowered 1 x 4,096-token prefill of the tiny latent-attention
+    and window configurations (whole-lane widths, the grouped kernels
+    interpreted; 2 picks a token: 8,192 sorted rows a pass) at a row
+    tile of 1,024: every gather of an expert layer that makes sorted
+    rows from the ``[T, hidden]`` tokens is a row tile inside a
+    ``while`` body, the way back reads the products' outputs a token
+    tile at a time inside its loops, NO gather has a ``[T x k, hidden]``
+    result, no whole-array ``select`` stands behind a grouped kernel
+    (``hetu_moe_experts``: the library's zero fill), and no float32 ``[T,
+    k, hidden]`` array is anywhere. An expert layer has two loops over
+    row tiles (the tokens' gather, the activation) and one over token
+    tiles; their trip counts are
+    ``test_serving_held_experts_over_the_border_runs_to_the_held_extent``'s.
+    ``one_tile`` (the row tile the pass's 8,192 rows): the whole-array
+    form, in which every one of those is there, so each check above can
+    fail."""
+    import test_latent_moe_serving as latent
+    import test_window_moe_serving as window
+    made = latent if model == "latent" else window
+    tokens, hidden, k = 4096, 128, 2
+    rows, tile, token_tile = tokens * k, 1024, 512
+    monkeypatch.setattr(moe, "ROW_TILE", tile if form == "held_extent"
+                        else rows)
+    monkeypatch.setattr(moe, "TOKEN_TILE", token_tile)
+    monkeypatch.setattr(moe, "INTERPRET", True)
+    monkeypatch.setattr(pk, "INTERPRET", True)
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    config = made.tiny()
+    config.update(hidden_size=hidden, moe_intermediate_size=128,
+                  max_position_embeddings=2 * tokens)
+    expert_layers = 2 if model == "latent" else 3
+    engine = made.engine_for(
+        config, made.family.seeded_weights(config, 7), max_batch_size=1,
+        max_len=tokens + 64, num_blocks=80, block_size=64)
+    found = []
+
+    def record(key, fn, *args):
+        if key[0] == "prefill":
+            assert key == ("prefill", 1, tokens)
+            found.append(fn.lower(*args).compiler_ir(dialect="hlo")
+                         .as_hlo_text())
+        return jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(fn, *args))
+
+    engine._dispatch = record
+    engine.warm_up((tokens, tokens), 1)
+    engine.close()
+    (text,) = found
+    comps = _hlo_computations(text)
+    in_a_loop = _loops_of(comps)
+    whole, tiled, way_back, fills, row_loops = [], [], [], [], 0
+    for comp, instrs in comps.items():
+        types = {name: result for name, result, *_ in instrs}
+        for name, result, opcode, operands, line in instrs:
+            if result.startswith("("):
+                continue
+            got = _dims(result)
+            # (``jnp.where`` lowers to a call of a jitted ``_where``)
+            if comp.startswith(moe.KERNEL_NAME) and got[:1] == (rows,) \
+                    and (opcode == "select" or "to_apply=_where" in line):
+                fills.append(name)
+            if opcode == "dynamic-update-slice" and comp in in_a_loop \
+                    and got[:1] == (rows,) \
+                    and _dims(types[operands[1]])[:1] == (tile,):
+                row_loops += 1
+            if opcode != "gather":
+                continue
+            source = _dims(types[operands[0]])
+            if got == (rows, hidden):
+                whole.append(name)
+            if source == (rows, hidden) and comp in in_a_loop:
+                assert got == (token_tile, hidden), (comp, name)
+                way_back.append(name)
+            if source == (tokens, hidden) and got == (tile, hidden) \
+                    and comp in in_a_loop:
+                tiled.append(name)
+    wide = re.findall(rf"f32\[{tokens},{k},{hidden}\]", text)
+    if form == "one_tile":
+        # xs and ys[back] a layer, both products' fills, the float32 pairs
+        assert len(whole) == 2 * expert_layers
+        assert fills and wide and not tiled and not way_back
+        return
+    assert not whole and not fills and not wide
+    assert len(tiled) == expert_layers and len(way_back) == expert_layers
+    # the gather's tile and the activation's, written in place
+    assert row_loops == 2 * expert_layers
 
 
 # -- the share test ----------------------------------------------------------
