@@ -14,6 +14,7 @@ from .gpt import GPTConfig, GPTModel, GPTLMHeadModel
 from .latent_moe import LatentMoEConfig
 from .ssm_hybrid import SSMHybridConfig
 from .window_moe import WindowMoEConfig
+from .shared_cache_decoder import SharedCacheConfig
 from .sparse_decoder import (SparseDecoderConfig, SparseDecoderModel,
                              SparseDecoderLMHeadModel)
 from .hybrid_decoder import (HybridDecoderConfig, HybridDecoderModel,

@@ -35,6 +35,12 @@ bucket, and ``ops/ssm.py:ssm_scan`` stops each row at its own count. A
 decode step updates slot and tail in place; a suffix chunk continues
 from the slot where its ``starts`` is not 0.
 
+The three RMS norms are the CONFIGURATION's (its ``ssm_inner_norms``,
+True here): ``models/shared_cache_decoder.py``'s configuration says
+False and runs this mixer as Mamba-1 publishes it, without them, and
+takes the scan's output BEFORE the gate (:func:`scan_prefill`,
+:func:`scan_step`).
+
 Matrices are in the model's dtype (bfloat16); norms' gains, the
 convolution, ``A``, ``D``, ``b_dt``, the state, ``delta``, softmax and
 logits float32. Embedding and head are tied.
@@ -52,7 +58,7 @@ import numpy as np
 
 __all__ = ["SSMHybridConfig", "SSMHybridServingModel",
            "ssm_hybrid_param_shapes", "layer_params", "mixer_prefill",
-           "mixer_step"]
+           "mixer_step", "scan_prefill", "scan_step"]
 
 # int32 counters every program returns: real tokens x Mamba layers
 COUNTERS = ("ssm_rows",)
@@ -81,6 +87,9 @@ class SSMHybridConfig:
         self.ssm_conv_width = ssm_conv_width
         self.ssm_dt_rank = ssm_dt_rank or -(-hidden_size // 16)
         self.d_inner = ssm_expand * hidden_size
+        # the jamba family's RMS norms on dt, B and C (Mamba-1 as
+        # published has none: ``models/shared_cache_decoder.py``)
+        self.ssm_inner_norms = True
         self.head_dim = head_dim or hidden_size // num_attention_heads
         self.rms_norm_eps = rms_norm_eps
         self.max_position_embeddings = max_position_embeddings
@@ -223,9 +232,16 @@ def _selective(config, blk, x):
     c = config
     r, n = c.ssm_dt_rank, c.ssm_state_size
     dbc = jnp.dot(x, blk["x_proj"], preferred_element_type=jnp.float32)
-    dt = _rms32(dbc[..., :r], blk["dt_norm"], c.rms_norm_eps)
-    b = _rms32(dbc[..., r:r + n], blk["b_norm"], c.rms_norm_eps)
-    cc = _rms32(dbc[..., r + n:], blk["c_norm"], c.rms_norm_eps)
+
+    def normed(part, gain):
+        # the jamba family's norms are the CONFIGURATION's: Mamba-1 as
+        # published has none
+        return _rms32(part, blk[gain], c.rms_norm_eps) \
+            if c.ssm_inner_norms else part
+
+    dt = normed(dbc[..., :r], "dt_norm")
+    b = normed(dbc[..., r:r + n], "b_norm")
+    cc = normed(dbc[..., r + n:], "c_norm")
     delta = jax.nn.softplus(
         jnp.dot(dt.astype(x.dtype), blk["dt_proj"],
                 preferred_element_type=jnp.float32) + blk["dt_bias"])
@@ -244,6 +260,13 @@ def mixer_prefill(config, blk, u, tail, s0, lengths):
     d]`` and the state ``s0 [B, N, d]`` each row starts with; ``lengths
     [B]`` real tokens a row. Returns ``(out [B, T, hidden], tail, S)``,
     the last two as of each row's last real token."""
+    y, z, new_tail, s = scan_prefill(config, blk, u, tail, s0, lengths)
+    return _gated_out(blk, y, z), new_tail, s
+
+
+def scan_prefill(config, blk, u, tail, s0, lengths):
+    """:func:`mixer_prefill` up to the gate: ``(y [B, T, d], z, tail,
+    S)``, the scan's output before ``silu(z)`` and ``W_out``."""
     import jax.numpy as jnp
     from ..ops.ssm import ssm_scan
     d = config.d_inner
@@ -256,13 +279,20 @@ def mixer_prefill(config, blk, u, tail, s0, lengths):
     x = _conv(config, blk, window)
     delta, b, c = _selective(config, blk, x)
     y, s = ssm_scan(x, delta, blk["a_t"], b, c, blk["d"], s0, lengths)
-    return _gated_out(blk, y, z), new_tail, s
+    return y, z, new_tail, s
 
 
 def mixer_step(config, blk, u, tail, pool, slots, layer):
     """One token a row: ``u [B, hidden]``, ``tail [B, K - 1, d]``, the
     state in ``pool [slots, layers, N, d]`` at ``[slots [B], layer]``.
     Returns ``(out [B, hidden], tail, pool)``."""
+    y, z, window, pool = scan_step(config, blk, u, tail, pool, slots, layer)
+    return _gated_out(blk, y, z), window[:, 1:], pool
+
+
+def scan_step(config, blk, u, tail, pool, slots, layer):
+    """:func:`mixer_step` up to the gate: ``(y [B, d], z, window [B,
+    K, d], pool)``; the new tail is the window's last ``K - 1`` rows."""
     import jax.numpy as jnp
     from ..ops.ssm import ssm_step
     d = config.d_inner
@@ -273,7 +303,7 @@ def mixer_step(config, blk, u, tail, pool, slots, layer):
     delta, b, c = _selective(config, blk, x)
     y, pool = ssm_step(pool, slots, layer, x, delta, blk["a_t"], b, c,
                        blk["d"])
-    return _gated_out(blk, y, z), window[:, 1:], pool
+    return y, z, window, pool
 
 
 # ---------------------------------------------------------------------------

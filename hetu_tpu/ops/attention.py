@@ -29,7 +29,9 @@ __all__ = ["flash_attention_op", "FlashAttentionOp", "attention_reference",
            "prefill_attention",
            "paged_decode_attention", "paged_prefill_attention",
            "grouped_decode_attention", "grouped_ring_decode_attention",
-           "bracketed",
+           "ring_valid", "diff_rows_attention", "diff_rows_extent",
+           "diff_prefill_attention", "diff_combine", "gather_rows_once",
+           "bracketed", "event_markers", "kernels_run",
            "mla_expanded_attention", "mla_decode_attention",
            "mla_prefill_attention"]
 
@@ -211,12 +213,140 @@ def grouped_ring_decode_attention(q, k_pool, v_pool, ring_idx, positions,
     is a block longer than the window, so its oldest rows are outside
     it. The shape is the ring's whatever the context. Returns ``[B, H,
     D]``."""
-    r = ring_idx.shape[1]
-    at = positions[:, None]
-    held = at - (at - jnp.arange(r, dtype=at.dtype)[None, :]) % r
-    valid = (held >= 0) & (at - held < window)
+    valid = ring_valid(ring_idx.shape[1], positions, window)
     return _grouped(q[:, None], k_pool, v_pool, ring_idx, valid[:, None],
                     sm_scale)[:, 0]
+
+
+def ring_valid(r, positions, window):
+    """``[B, r]``: the slots of a ring of ``r`` that a query at
+    ``positions [B]`` sees (:func:`grouped_ring_decode_attention` says
+    what a slot holds)."""
+    at = positions[:, None]
+    held = at - (at - jnp.arange(r, dtype=at.dtype)[None, :]) % r
+    return (held >= 0) & (at - held < window)
+
+
+# ---------------------------------------------------------------------------
+# differential attention (Diff Transformer, arXiv:2410.05258): heads in
+# PAIRS. Query heads ``(2p, 2p + 1)`` are the two queries of pair ``p``,
+# key heads ``(2r, 2r + 1)`` the two keys of key pair ``r`` and value
+# heads ``(2r, 2r + 1)`` side by side its ONE value ``U_r`` (``2 D``
+# wide); query pair ``p`` reads key pair ``p // (pairs / key pairs)``. A
+# pair is two softmax maps over that one value,
+#
+#     o_p = rms(A1 U - lambda A2 U) * (1 - lambda_init)
+#
+# The calls below return BOTH maps' products (``[..., pairs, 2, 2 D]``:
+# map 1, map 2) and :func:`diff_combine` makes ``o`` of them, so a cache
+# backend chooses how the rows are read and the difference is written
+# once.
+# ---------------------------------------------------------------------------
+
+def diff_rows_attention(q, k_rows, v_rows, valid, sm_scale, positions=None):
+    """One query token a sequence, ``q [B, H, D]``, against rows that
+    are already in position order: ``k_rows`` / ``v_rows [B, S, G x
+    D]`` (the key/value heads side by side, as a pool row holds them),
+    ``valid [B, S]`` the rows the query sees, or with ``positions [B]``
+    the rows ``j <= positions[b]`` (``valid`` then ``None``; on a TPU
+    the kernel ``hetu_diff_attn_decode`` runs,
+    ``ops/pallas_diff_attention.py``, which reads a sequence's rows to
+    its own position and not to the bucket's end). The rows are read AS THEY
+    LIE, once for the scores and once for the products of every pair
+    and both maps: each query head is laid out over a whole row, zeros
+    but for its own key head's lanes, so the scores are ONE product of
+    ``[H, G x D]`` queries with the rows' minor axis (no relayout of the
+    rows into heads, which cost a transposed copy of them), and each
+    map's product with ``U`` is one product with the ``v`` rows of which
+    a head keeps its key pair's ``2 D`` lanes. The matrix unit multiplies
+    zeros for it (``G`` times the useful work, on ``H`` rows: still
+    under the rows' read time). Float32 softmax. Returns the two maps'
+    products ``[B, H / 2, 2, 2 D]`` float32."""
+    b, h, d = q.shape
+    width = k_rows.shape[-1]
+    key_pairs = width // (2 * d)
+    per = h // 2 // key_pairs
+    # head (r, p, m) reads key head (r, m): lanes [(2 r + m) D, + D)
+    q = (q * sm_scale).astype(k_rows.dtype).reshape(b, key_pairs, per, 2, d)
+    own = jnp.eye(key_pairs, dtype=q.dtype)[:, None, None, :, None, None] \
+        * jnp.eye(2, dtype=q.dtype)[None, None, :, None, :, None]
+    laid = (q[:, :, :, :, None, None, :] * own).reshape(b, h, width)
+    if positions is not None and _diff_kernel(h, width, k_rows.shape[1]):
+        from . import pallas_diff_attention as kernel
+        wide = kernel.diff_decode(laid, k_rows, v_rows, positions)
+    else:
+        if valid is None:
+            valid = jnp.arange(k_rows.shape[1])[None, :] \
+                <= positions[:, None]
+        scores = jnp.einsum("bhw,bsw->bhs", laid, k_rows,
+                            preferred_element_type=jnp.float32)
+        scores = jnp.where(valid[:, None], scores, -1e9)
+        probs = jax.nn.softmax(scores, axis=-1)
+        wide = jnp.einsum("bhs,bsw->bhw", probs.astype(v_rows.dtype),
+                          v_rows, preferred_element_type=jnp.float32)
+    # a head keeps the lanes of its key pair's value
+    wide = wide.reshape(b, key_pairs, 2 * per, key_pairs, 2 * d)
+    maps = jnp.sum(wide * jnp.eye(key_pairs, dtype=wide.dtype)[
+        None, :, None, :, None], axis=3)
+    return maps.reshape(b, h // 2, 2, 2 * d)
+
+
+def _diff_kernel(heads, width, context):
+    """Whether :func:`diff_rows_attention` by ``positions`` runs the
+    kernel ``hetu_diff_attn_decode`` at these shapes."""
+    from . import pallas_diff_attention as kernel
+    return (_use_pallas() or kernel.INTERPRET) \
+        and kernel.supported(heads, width, context)
+
+
+def diff_rows_extent(heads, width, context, positions):
+    """``[B]`` int32: how many of a sequence's rows, from the first,
+    :func:`diff_rows_attention` by ``positions`` may multiply (the
+    kernel's whole blocks up to the position; the composed form's
+    products take every row of the bucket and select none away)."""
+    if not _diff_kernel(heads, width, context):
+        return jnp.full(positions.shape, context, jnp.int32)
+    from .pallas_diff_attention import BLOCK_K
+    block = min(BLOCK_K, context)
+    return ((positions // block + 1) * block).astype(jnp.int32)
+
+
+def diff_prefill_attention(q, k, v, sm_scale, window=None):
+    """A whole prompt among its own tokens, token-major ``q [B, S, H,
+    D]`` and ``k`` / ``v [B, S, G, D]``, causal, with ``window`` the
+    band ``i - window < j <= i``. Each map is a head of the flash
+    kernel: query and key padded with zeros to the value's ``2 D`` (the
+    scores are the same; on a 128-wide matrix unit a contraction of 64
+    costs what one of 128 does), each key pair's keys and ``U`` under
+    the maps that read them; a banded call is the window kernel's
+    (``hetu_flash_window``). Returns ``[B, S, H / 2, 2, 2 D]`` in
+    ``q``'s dtype."""
+    b, s, h, d = q.shape
+    key_pairs = k.shape[2] // 2
+    per = h // 2 // key_pairs
+    pad = ((0, 0),) * 3 + ((0, d),)
+    q = jnp.pad(q, pad)
+    k = jnp.broadcast_to(
+        jnp.pad(k, pad).reshape(b, s, key_pairs, 1, 2, 2 * d),
+        (b, s, key_pairs, per, 2, 2 * d)).reshape(b, s, h, 2 * d)
+    u = jnp.broadcast_to(
+        v.reshape(b, s, key_pairs, 1, 2 * d),
+        (b, s, key_pairs, 2 * per, 2 * d)).reshape(b, s, h, 2 * d)
+    ctx = prefill_attention(
+        *(t.transpose(0, 2, 1, 3) for t in (q, k, u)), sm_scale=sm_scale,
+        causal=True, window=window)
+    return ctx.transpose(0, 2, 1, 3).reshape(b, s, h // 2, 2, 2 * d)
+
+
+def diff_combine(maps, lam, gain, out_scale, eps):
+    """``o`` of the two maps' products ``[..., pairs, 2, 2 D]``:
+    ``rms(map 1 - lam x map 2; gain [2 D], eps) x out_scale`` a pair,
+    float32 (``lam`` and ``out_scale = 1 - lambda_init`` float32
+    scalars of the layer). Returns ``[..., pairs x 2 D]`` float32."""
+    maps = maps.astype(jnp.float32)
+    x = maps[..., 0, :] - lam * maps[..., 1, :]
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (x * gain * out_scale).reshape(*x.shape[:-2], -1)
 
 
 @functools.lru_cache(maxsize=None)
@@ -240,6 +370,21 @@ def _marker(name):
 
     marker.__name__ = marker.__qualname__ = name
     return jax.jit(marker)
+
+
+def event_markers(name):
+    """``(enter, leave)``: two functions that hand their arrays through
+    a device event ``<name>_in`` / ``<name>_out`` (:func:`bracketed`
+    says what lies between them and why), for a caller whose bracketed
+    part is more than one call."""
+    return _marker(name + "_in"), _marker(name + "_out")
+
+
+def kernels_run():
+    """Whether the Pallas kernels run here (a TPU backend, or a
+    rehearsal that steers ``_use_pallas``): what a model asks to decide
+    a static keyword of a program."""
+    return _use_pallas()
 
 
 def bracketed(name, attention, q, k_pool, v_pool, *rest, **static):
@@ -297,6 +442,32 @@ def _gather_latent_rows(pool, slot_idx):
         blocks = slot_idx[:, ::block_size] // block_size
         return pool[blocks].reshape(b, s, width)
     return pool.reshape(-1, width)[slot_idx]
+
+
+def gather_rows_once(groups, slot_idx, extent=None):
+    """The rows at ``slot_idx [B, S]`` of several pools of ONE block
+    table in position order, for readers that share the copy. ``groups``
+    is a sequence of sequences of pools ``[blocks, block_size, W]``
+    (the pools of a group of one shape: the same entry of several
+    layers); returns one ``[len(group), B, S, W]`` array a group.
+    ``extent [B]``: the rows of a sequence, from the first, that a
+    reader may multiply. On a TPU the kernel ``hetu_block_gather``
+    (``ops/pallas_block_gather.py``) copies a sequence's blocks up to
+    its extent and leaves the rest of the bucket unwritten, WITHOUT A
+    DEFINED VALUE; elsewhere, or where the grid is not whole blocks,
+    XLA's gather of the whole bucket."""
+    from . import pallas_block_gather as kernel
+    first = groups[0][0]
+    block_size = first.shape[-2]
+    b, s = slot_idx.shape
+    if (_use_pallas() or kernel.INTERPRET) and first.ndim == 3 \
+            and s % block_size == 0:
+        tables = slot_idx[:, ::block_size] // block_size
+        counts = jnp.full((b,), s // block_size, jnp.int32) \
+            if extent is None else -(-extent // block_size)
+        return kernel.gather_blocks(groups, tables, counts)
+    return [jnp.stack([_gather_latent_rows(pool, slot_idx)
+                       for pool in group]) for group in groups]
 
 
 def mla_decode_attention(q_abs, q_rope, pool, slot_idx, positions,
