@@ -91,7 +91,17 @@ left) and the rows the way back read (``moe_back_rows``, beside
 ``moe_back_rows_of``, the ``T x k`` a direction that all the pairs are).
 The permutations of a scalar a pair or a token (``back``, ``w_row``, the
 pairs' weight gradient, a token's place in the way back's order) are
-sorts (:func:`_moved`), not gathers or scatters.
+sorts (:func:`_moved`), not gathers or scatters; and where the layer
+indexes by a scalar a pair it compares against the expert axis and
+reduces over it, one fused pass: the COUNT of the pairs of each held
+expert is ``sum(group[:, None] == arange(held + 1))``
+(:func:`_sorted_pairs`) and a pick's SCORE the one score its compare
+selects (:func:`_picked`, the router's and the graph router's counter
+and gradient). XLA walks a scatter-add or a gather of a scalar an index
+one by one: on the chip the count of a smallthinker layer's 49,152
+pairs took 0.430 ms as a scatter-add and takes 0.008, the scores of an
+lfm2 router's 32,768 picks 0.334 ms as a gather and 0.003 (``PERF.md``
+section 6, PR 62).
 
 **The tiles** of the three grouped kernels (:func:`_kernel_tiles`) are
 read from what a product is given and from nothing else: ``m``, ``k``,
@@ -242,11 +252,26 @@ def route(x, w_router, bias, top_k, scale, n_group=1, topk_group=1,
         choice = jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
             choice.shape)
     _, experts = jax.lax.top_k(choice, top_k)
-    picked = jnp.take_along_axis(scores, experts, axis=-1)
+    picked = _picked(scores, experts)
     weights = scale * picked
     summed = jnp.sum(picked, axis=-1, keepdims=True)
     return experts.astype(jnp.int32), \
         weights / (summed + norm_eps if norm_eps else summed), scores
+
+
+def _picked(scores, experts):
+    """``scores [..., E]`` at ``experts [..., k]``, ``[..., k]``: each
+    pick compared against the expert axis and the one score that
+    matches selected, bit for bit the gathered score. One fused
+    compare-select-reduce over ``[..., k, E]``; ``take_along_axis`` is a
+    gather of a scalar a pick, which the chip walks one by one (the
+    module's docstring has the figures). The reduction is a MAX over
+    ``-inf``, not a sum over zeros: XLA merges a sum over ``E`` with the
+    callers' sum over the ``k`` picks into one reduction over ``[k,
+    E]`` and adds the chosen scores in another order (weights one or two
+    units in the last place off the gathered form's)."""
+    at = experts[..., None] == jnp.arange(scores.shape[-1])
+    return jnp.max(jnp.where(at, scores[..., None, :], -jnp.inf), axis=-1)
 
 
 def _lane_divisors(x):
@@ -455,13 +480,16 @@ def _sorted_pairs(experts, valid, held_n, first):
     local = experts - first
     held = (local >= 0) & (local < held_n) & valid[:, None]
     group = jnp.where(held, local, held_n).reshape(-1)       # [t * k]
-    sizes = jnp.zeros(held_n + 1, jnp.int32).at[group].add(1)
+    # each pair compared against the groups and summed: one fused pass
+    # (a scatter-add of a one a pair is walked pair by pair on the chip)
+    bins = jnp.arange(held_n + 1)
+    sizes = jnp.sum(group[:, None] == bins, axis=0, dtype=jnp.int32)
     order = jnp.argsort(group, stable=True)
     rows = t * k
     pad = -rows % LANES if (_use_pallas() or INTERPRET) else 0
     if pad:     # the kernel's row tile; the pad rows join the last group
         order = jnp.concatenate([order, jnp.zeros(pad, order.dtype)])
-        sizes = sizes.at[held_n].add(pad)
+        sizes = sizes + pad * (bins == held_n)
     return order, sizes, held, rows
 
 
@@ -500,7 +528,10 @@ def held_experts(x, experts, weights, valid, w_gate_up, w_down, first=0,
     act = (ACTIVATIONS[activation](h[:, :width].astype(jnp.float32))
            * h[:, width:].astype(jnp.float32)).astype(x.dtype)
     ys = grouped_matmul(act, w_down, sizes)
-    # back to (token, pick) order; a pair held elsewhere weighs nothing
+    # back to (token, pick) order; a pair held elsewhere weighs nothing.
+    # This scatter of a scalar a row stays: the branch runs at most
+    # ``ROW_TILE`` rows, a decode step's 128-256, where walking them
+    # costs 1-3 us and ``_moved``'s sort has a larger fixed cost
     back = jnp.zeros(rows, jnp.int32).at[order[:rows]].set(
         jnp.arange(rows, dtype=jnp.int32))
     pairs = ys[back].reshape(t, k, -1)
@@ -602,7 +633,7 @@ class RouterOp(Op):
             # index, as ``top_k`` breaks them): one fused compare and
             # sum over ``[T, k, E]``, no second top-k
             experts, _, scores = routed
-            picked = jnp.take_along_axis(scores, experts, axis=-1)
+            picked = _picked(scores, experts)
             others, own = scores[..., None, :], picked[..., :, None]
             ahead = (others > own) | ((others == own) & (
                 jnp.arange(scores.shape[-1]) < experts[..., :, None]))
@@ -666,7 +697,7 @@ class _RouterGradientOp(Op):
             dpicked = weights * (dweights - jnp.sum(
                 weights * dweights, axis=-1, keepdims=True))
         else:
-            p = jnp.take_along_axis(routed[2], experts, axis=-1)
+            p = _picked(routed[2], experts)
             total = jnp.sum(p, axis=-1, keepdims=True) + fwd.norm_eps
             dpicked = (fwd.scale * dweights - jnp.sum(
                 weights * dweights, axis=-1, keepdims=True)) / total \

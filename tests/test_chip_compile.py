@@ -1202,6 +1202,90 @@ def test_step_updates_its_sparse_tables_in_one_call_each(
 
 
 # ---------------------------------------------------------------------------
+# the expert layer's bookkeeping: no scalar walked one by one (PR 62)
+# ---------------------------------------------------------------------------
+
+def _scalar_walks(text):
+    """``[(instruction, scalars moved)]``: the ``gather`` and ``scatter``
+    instructions of a compiled module whose slice / update is ONE scalar
+    an index (no offset, no window dimension), which the chip walks
+    element by element, with the elements of the gather's result or of
+    the scatter's updates. A row gather (an embedding's) or a row update
+    has such dimensions and is not one."""
+    from hlo_matmuls import _computations, describe
+    found = []
+    for body in _computations(text)[0].values():
+        for name, (result, opcode, operands, line) in body.items():
+            if opcode == "gather" and "offset_dims={}" in line:
+                moved = result
+            elif opcode == "scatter" and "update_window_dims={}" in line:
+                moved = body[operands[-1]][0]
+            else:
+                continue
+            found.append((name, math.prod(describe(moved)[1])))
+    return found
+
+
+def _bookkeeping_text(one_chip, tokens, top_k, experts, held, walked=False):
+    """``route`` + ``_sorted_pairs`` compiled for the described chip;
+    ``walked``: the forms they replaced (a scatter-add of a one a pair,
+    a gather of a score a pick), to show that the search finds them."""
+    from hetu_tpu.ops import moe
+
+    def fn(x, w, bias):
+        picks, weights, scores = moe.route(x, w, bias, top_k, 2.5)
+        order, sizes, _, _ = moe._sorted_pairs(
+            picks, jnp.ones(tokens, bool), held, 0)
+        if walked:
+            sizes = jnp.zeros(held + 1, jnp.int32).at[
+                jnp.minimum(picks, held).reshape(-1)].add(1)
+            weights = jnp.take_along_axis(scores, picks, axis=-1)
+        return weights, order, sizes
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dtype, sharding=one_chip)
+    return jax.jit(fn).lower(
+        sds((tokens, 256), jnp.bfloat16), sds((256, experts), jnp.float32),
+        sds((experts,), jnp.float32)).compile().as_text()
+
+
+@pytest.mark.parametrize("tokens,top_k,experts,held", [
+    (8192, 6, 64, 16), (8192, 4, 32, 8), (4096, 8, 128, 32),
+    (4096, 8, 512, 128)])
+def test_the_expert_layers_bookkeeping_walks_no_scalar(
+        one_chip, tokens, top_k, experts, held):
+    """Counting the pairs of each held expert and reading a pick's score
+    at both train cells' shapes, a sarvam pass's and a ling pass's
+    (whole row tiles: no pad on either path): compare-and-reduce
+    fusions, no ``scatter`` and no ``gather`` at all (the sort of the
+    pairs stays a sort)."""
+    text = _bookkeeping_text(one_chip, tokens, top_k, experts, held)
+    assert " gather(" not in text and " scatter(" not in text
+    assert " sort(" in text
+
+
+def test_the_search_finds_the_scalar_walks_that_went(one_chip):
+    found = _scalar_walks(_bookkeeping_text(one_chip, 8192, 4, 32, 8,
+                                            walked=True))
+    assert sorted(n for _, n in found) == [8192 * 4] * 2, found
+
+
+def test_sparse_decoder_step_walks_no_scalar_a_pair(v5e, monkeypatch):
+    """The sparse decoder's whole training step: its gathers and
+    scatters move rows (the token table's lookup, the way back's tiles).
+    What is left of scalars walked one by one is megablox's own group
+    metadata before each grouped product (``make_group_metadata``: a
+    ``searchsorted`` and two scatter-adds over the row tiles and the
+    groups, 200 scalars at the cell's shapes); nothing walks a scalar a
+    (token, pick) pair or a token. On the parent the count of the pairs
+    was one: 49,152 scalars, 0.43 ms a layer on the chip."""
+    text = _sparse_decoder_step_text(v5e[0], monkeypatch)
+    walks = _scalar_walks(text)
+    assert walks                        # the search has something to read
+    assert max(n for _, n in walks) < 1024, walks
+
+
+# ---------------------------------------------------------------------------
 # a kernel's bytes under the chip entry points' locations (PR 52)
 # ---------------------------------------------------------------------------
 

@@ -103,12 +103,15 @@ def test_one_group_is_todays_router_bit_for_bit():
     w = jnp.asarray(rng.normal(size=(32, 24)), jnp.float32)
     bias = jnp.asarray(0.05 * rng.normal(size=24), jnp.float32)
 
-    def before(x, w_router, bias, top_k, scale):    # the parent's text
+    def before(x, w_router, bias, top_k, scale, pick=None):
+        # the text before the groups; ``pick=None``: PR 54's parent's,
+        # which gathered the chosen scores (the values to this day)
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), w_router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
         _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
-        picked = jnp.take_along_axis(scores, experts, axis=-1)
+        picked = pick(scores, experts) if pick \
+            else jnp.take_along_axis(scores, experts, axis=-1)
         weights = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
         return experts.astype(jnp.int32), weights, scores
 
@@ -117,7 +120,8 @@ def test_one_group_is_todays_router_bit_for_bit():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     text = [jax.jit(fn, static_argnums=(3, 4)).lower(
         x, w, bias, 3, 2.0).as_text().split("\n", 1)[1]
-        for fn in (lambda *a: moe.route(*a), before)]
+        for fn in (lambda *a: moe.route(*a),
+                   lambda *a: before(*a, pick=moe._picked))]
     assert text[0] == text[1]
 
 

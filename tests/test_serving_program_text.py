@@ -6,8 +6,12 @@ fingerprint of its StableHLO text (``Lowered.as_text()`` carries no
 locations). The fingerprints in ``tests/data/serving_program_text.json``
 were taken on the tree BEFORE PR 44 stacked GPT's parameters and pools
 over layers (the engine and the cache are shared by all four models),
-so this runs without that tree. A PR that means to change one of these
-programs takes them again::
+so this runs without that tree; the two expert models' were taken
+again on PR 62's tree, whose router reads a pick's score and whose
+expert layer counts its pairs by compare-and-reduce
+(``ops/moe.py:_picked``, ``_sorted_pairs``; the state-space hybrid's,
+which routes nothing, stayed to the byte). A PR that means to change one
+of these programs takes them again::
 
     python tests/test_serving_program_text.py --write
 
