@@ -1540,7 +1540,14 @@ class Executor:
         ``moe_back_rows`` (the rows of the grouped products' outputs
         that the way back to token order read: a step adds the held
         pairs once a direction), ``moe_back_rows_of`` (what all ``T x
-        k`` pairs would be, twice a step), ``steps``; and for a layer
+        k`` pairs would be, twice a step), ``moe_dw_tiles`` (the row-tile
+        visits of ONE of the layer's two weight gradients,
+        ``hetu_moe_experts_dw``: a step adds, for every held expert that
+        got a row, the ``tm``-row tiles its group touches; 0 where the
+        composed form runs, off a TPU), ``moe_dw_cut_tiles`` (those of
+        them that a group's edge cuts: part of what such a tile computes
+        is another group's rows, masked away), ``steps``; and for a
+        layer
         whose router selects by a bias (``router_op(bias=)``)
         ``moe_bias_flipped_picks``, the router's own count: the picks,
         of ALL the layer's ``T x k``, that are not among the ``top_k`` of
@@ -1573,6 +1580,9 @@ class Executor:
                         "moe_back_rows": int(state.get("moe_back_rows", 0)),
                         "moe_back_rows_of": int(
                             state.get("moe_back_rows_of", 0)),
+                        "moe_dw_tiles": int(state.get("moe_dw_tiles", 0)),
+                        "moe_dw_cut_tiles": int(
+                            state.get("moe_dw_cut_tiles", 0)),
                         **{k: int(v) for k, v in routers.get(
                             nid, {}).items()},
                         "steps": int(state["steps"])})

@@ -43,8 +43,9 @@ elsewhere would add is the exchange's business, not this module's.
   changed by it). Its backward is two grouped products for
   the rows (``hetu_moe_experts_dx``: the kernel with its right side
   transposed) and two transposed grouped products for the weights
-  (``hetu_moe_experts_dw``: megablox's ``tgmm``); the rows of experts
-  held elsewhere are never computed in either direction.
+  (``hetu_moe_experts_dw``: a kernel of the repo's own,
+  ``ops/pallas_grouped.py``); the rows of experts held elsewhere are
+  never computed in either direction.
 
 **The held extent** (:func:`_held_extent_passes`, the ONE body of the
 graph op's forward and of :func:`held_experts` wherever a pass has more
@@ -124,12 +125,18 @@ for any tile that fits. So:
   whatever ``tm`` is. ``tn`` is the widest multiple of a lane block
   dividing ``n`` that still fits; where ``k`` whole does not fit, the
   fewest k-steps that do.
-* ``tgmm`` (the weights' gradient) walks (column tiles, k tiles, row-tile
+* the weights' gradient (``ops/pallas_grouped.py``, since PR 64 in the
+  place of megablox's ``tgmm``) walks (column tiles, k tiles, row-tile
   visits): a step reads a ``[tm, tk]`` and a ``[tm, tn]`` row block for
   a float32 ``[tk, tn]`` tile that stays on chip over a group's rows,
-  ``tk tn / (tk + tn)`` operations a byte. The output tile is the one
-  that brings the most of them under the budget (its accumulator and
-  double-buffered float32 result are 12 ``tk tn`` bytes).
+  ``tk tn / (tk + tn)`` operations a byte, and the finished tile leaves
+  by a DMA of its own while the next group's sums go into a second
+  accumulator. The kernel asks for the on-chip memory its blocks take,
+  so the output tile is the one that brings the most operations a byte
+  under ``WEIGHTS_BLOCK_BYTES`` (two row blocks twice and two float32
+  accumulators, ``8 tk tn`` bytes): the whole ``[k, n]`` at three of the
+  train cells' four products and half of it at the fourth, so a row
+  block is read once a call, or twice.
 * the row tile ``tm`` is ``KERNEL_ROW_TILE`` (256) rows where that
   divides ``m``, else 128. Where the rows are ONE tile (serving's decode
   step, 128 padded rows) an expert is visited once and no block is used
@@ -154,6 +161,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..graph.node import Op
+from . import pallas_grouped
 from .norm import PackedPartOp as _Part
 
 __all__ = ["route", "held_experts", "grouped_matmul", "swiglu",
@@ -186,13 +194,23 @@ ROW_TILE = 2048
 # a token's k ranks of held picks rounds its tokens up to a tile, so a
 # small one (14 us of gather); 256 and 1,024 both ran slower on the chip
 TOKEN_TILE = 512
+# what the compiler keeps on chip beside a grouped product's blocks
+KERNEL_MARGIN_BYTES = 2 * 2 ** 20
 # on-chip memory that the blocks of a grouped product may take
-# (``_block_bytes``): the 16 MiB a kernel is given by default less a
-# margin for what the compiler keeps beside the blocks. Compiled for the
-# described chip at row tiles of 128 and 256 rows, every tile up to 15.0
-# MiB by this count fit and the first refusals came at 15.75
-# (``tests/test_chip_compile.py`` holds the train cells' tiles to it)
-KERNEL_BLOCK_BYTES = 14 * 2 ** 20
+# (``_block_bytes``): the 16 MiB a kernel is given by default less that
+# margin. Compiled for the described chip at row tiles of 128 and 256
+# rows, every tile up to 15.0 MiB by this count fit and the first
+# refusals came at 15.75 (``tests/test_chip_compile.py`` holds the train
+# cells' tiles to it)
+KERNEL_BLOCK_BYTES = 16 * 2 ** 20 - KERNEL_MARGIN_BYTES
+# the same for the weights' gradient, a kernel that asks for what its
+# blocks take (``vmem_limit_bytes``: their bytes and the same margin) of
+# the chip's 128 MiB. Timed alone on the chip at both train cells' four
+# products (``PERF.md`` section 6, PR 64) the tiles it admits, 18-34 MiB
+# of blocks, ran 14-21% shorter than those under 14 MiB and 0-4% shorter
+# than those under 28 MiB; a 59 MiB pair of accumulators (lfm2's
+# ``[2048, 3584]`` whole) ran TWICE as long as its halves
+WEIGHTS_BLOCK_BYTES = 40 * 2 ** 20
 # rows of a grouped product's row tile where they divide the sorted
 # rows. Timed alone on the chip at both train cells' shapes (groups of
 # 800-1,800 rows) and at four serving models' prefill chunks (groups of
@@ -281,16 +299,24 @@ def _lane_divisors(x):
 
 def _block_bytes(kind, tm, tk, tn, itemsize, out):
     """What a grouped product's blocks take of on-chip memory at tiles
-    ``(tm, tk, tn)``: every operand and result block twice (Pallas
-    fetches the next while one is worked on) and the float32
-    accumulator. ``"weights"`` (``tgmm``): the two row blocks, a float32
-    ``[tk, tn]`` result and accumulator. The others (``gmm``): the row
-    block, the weight block, the result block in the operands' dtype,
-    and ``out``'s block beside it where the product writes into one."""
+    ``(tm, tk, tn)``. ``"weights"`` (``ops/pallas_grouped.py``): the two
+    row blocks twice (Pallas fetches the next while one is worked on)
+    and the two float32 ``[tk, tn]`` accumulators that take the groups
+    in turn; the result is no block, it leaves from an accumulator. The
+    others (megablox's ``gmm``): every operand and result block twice
+    and the float32 accumulator: the row block, the weight block, the
+    result block in the operands' dtype, and ``out``'s block beside it
+    where the product writes into one."""
     if kind == "weights":
-        return 2 * tm * (tk + tn) * itemsize + 12 * tk * tn
+        return 2 * tm * (tk + tn) * itemsize + 8 * tk * tn
     return 2 * (tm * tk + tk * tn) * itemsize \
         + (4 if out else 2) * tm * tn * itemsize + 4 * tm * tn
+
+
+def _row_tile(m):
+    """The rows of a grouped product's row tile over ``m`` sorted rows
+    (a multiple of a lane block)."""
+    return next(t for t in (KERNEL_ROW_TILE, LANES) if m % t == 0)
 
 
 def _kernel_tiles(kind, m, k, n, itemsize=2, out=False):
@@ -299,17 +325,18 @@ def _kernel_tiles(kind, m, k, n, itemsize=2, out=False):
     ``kind``: ``"forward"`` (``[m, k] x [groups, k, n]``, megablox's
     ``gmm``), ``"rows"`` (the same with the right side transposed, ``[m,
     k] x [groups, n, k]^T``: the same blocks, so the same tiles) or
-    ``"weights"`` (``tgmm``: ``[m, k]^T x [m, n]`` a group, float32
-    ``[groups, k, n]``). The module's docstring says what the rule is
-    after; every tile is a multiple of a lane block that divides its
-    extent, and the blocks stay under ``KERNEL_BLOCK_BYTES``."""
+    ``"weights"`` (``ops/pallas_grouped.py``: ``[m, k]^T x [m, n]`` a
+    group, float32 ``[groups, k, n]``). The module's docstring says what
+    the rule is after; every tile is a multiple of a lane block that
+    divides its extent, and the blocks stay under ``KERNEL_BLOCK_BYTES``
+    (``WEIGHTS_BLOCK_BYTES`` for the kernel that asks for its own)."""
     if k % LANES or n % LANES or m % LANES:
         return None
-    tm = next(t for t in (KERNEL_ROW_TILE, LANES) if m % t == 0)
+    tm = _row_tile(m)
+    budget = WEIGHTS_BLOCK_BYTES if kind == "weights" else KERNEL_BLOCK_BYTES
 
     def fits(tk, tn):
-        return _block_bytes(kind, tm, tk, tn, itemsize, out) \
-            <= KERNEL_BLOCK_BYTES
+        return _block_bytes(kind, tm, tk, tn, itemsize, out) <= budget
 
     if kind == "weights":
         # the output tile whose two row blocks bring the most
@@ -355,25 +382,33 @@ def _kernel(tiles, out_dtype, interpret):
 @functools.lru_cache(maxsize=None)
 def _grad_kernel(which, tiles, out_dtype, interpret, groups=None):
     """The backward's two kernels under their stable names: ``"rows"``
-    is ``gmm`` with the right side transposed (``dy @ rhs[g]^T``),
-    ``"weights"`` megablox's ``tgmm`` over the first ``groups`` groups
-    (``lhs[rows of g]^T @ dy[rows of g]``). Neither has a vjp of its
-    own in the library's ``__wrapped__`` form: these ARE the vjp."""
-    lib = importlib.import_module(
-        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    is megablox's ``gmm`` with the right side transposed (``dy @
+    rhs[g]^T``; it has no vjp of its own in the library's
+    ``__wrapped__`` form: this IS the vjp), ``"weights"`` the repo's
+    own kernel over the first ``groups`` groups (``lhs[rows of g]^T @
+    dy[rows of g]``, ``ops/pallas_grouped.py``), given the visit list
+    of its group sizes where the caller has one (a layer's two weight
+    gradients share theirs)."""
     if which == "rows":
+        gmm = importlib.import_module(
+            "jax.experimental.pallas.ops.tpu.megablox.gmm").gmm.__wrapped__
+
         def fn(dy, rhs, group_sizes, out=None):
-            return lib.gmm.__wrapped__(
+            return gmm(
                 dy, rhs, group_sizes, preferred_element_type=out_dtype,
                 tiling=tiles, transpose_rhs=True, interpret=interpret,
                 existing_out=out)
         name = ROWS_GRAD_KERNEL_NAME
     else:
-        def fn(lhs, dy, group_sizes):
-            return lib.tgmm.__wrapped__(
-                lhs.swapaxes(0, 1), dy, group_sizes,
-                preferred_element_type=out_dtype, tiling=tiles,
-                num_actual_groups=groups, interpret=interpret)
+        def fn(lhs, dy, group_sizes, visits=None):
+            if visits is None:
+                visits = pallas_grouped.visits(group_sizes, lhs.shape[0],
+                                               tiles[0])
+            itemsize = jnp.dtype(lhs.dtype).itemsize
+            return pallas_grouped.weights_grad(
+                lhs, dy, visits, tiles, groups, interpret=interpret,
+                vmem_limit_bytes=_block_bytes(
+                    "weights", *tiles, itemsize, False) + KERNEL_MARGIN_BYTES)
         name = WEIGHTS_GRAD_KERNEL_NAME
     fn.__name__ = fn.__qualname__ = name
     return jax.jit(fn)
@@ -441,16 +476,35 @@ def grouped_matmul_rows_grad(dy, rhs, group_sizes, out=None):
     return _behind_kept(got, group_sizes, out).astype(dy.dtype)
 
 
-def grouped_matmul_weights_grad(lhs, dy, group_sizes):
+def _weights_row_tile(m):
+    """The row tile of the weight gradients' kernel over ``m`` sorted
+    rows, or None where the composed form runs (off a TPU, or rows that
+    are no whole lane blocks)."""
+    if not (_use_pallas() or INTERPRET) or m % LANES:
+        return None
+    return _row_tile(m)
+
+
+def _weights_grad_visits(group_sizes, m):
+    """The visit list that the weight gradients over ``m`` sorted rows
+    walk (``pallas_grouped.visits``; it follows the rows and the group
+    sizes alone, so a layer's two gradients share one), or None where
+    the composed form runs."""
+    tm = _weights_row_tile(m)
+    return None if tm is None else pallas_grouped.visits(group_sizes, m, tm)
+
+
+def grouped_matmul_weights_grad(lhs, dy, group_sizes, visits=None):
     """``d rhs`` of :func:`grouped_matmul`: ``lhs[rows of g]^T @ dy[rows
     of g]`` for the ``G`` held groups, ``[G, k, n]`` float32 (a group
-    that got no row is zeros)."""
+    that got no row is zeros). ``visits``: :func:`_weights_grad_visits`
+    of the same sizes and rows, where the caller made one."""
     m = lhs.shape[0]
     groups = group_sizes.shape[0] - 1
     tiles = _product_tiles("weights", lhs, dy.shape[-1])
     if tiles is not None:
         return _grad_kernel("weights", tiles, jnp.dtype(jnp.float32),
-                            INTERPRET, groups)(lhs, dy, group_sizes)
+                            INTERPRET, groups)(lhs, dy, group_sizes, visits)
     ends = jnp.cumsum(group_sizes[:-1])
     row = jnp.arange(m)
     member = (row[None, :] >= (ends - group_sizes[:-1])[:, None]) \
@@ -790,18 +844,26 @@ def _held_row_tiles(sizes, rows):
 
 def _kernel_rows(sizes, tm):
     """The rows that the row tiles of a grouped product over the held
-    groups compute (a traced int32): megablox visits, for every group
-    that got a row, each ``tm``-row tile the group touches, so visits x
-    ``tm``; the landed rows themselves where the ragged product runs
-    (``tm`` None). Over the landed rows it is what the tiles' padding
-    costs under the chosen ``tm``."""
-    landed = sizes[:-1]
+    groups compute (a traced int32): a grouped kernel visits, for every
+    group that got a row, each ``tm``-row tile the group touches, so
+    visits x ``tm``; the landed rows themselves where the ragged product
+    runs (``tm`` None). Over the landed rows it is what the tiles'
+    padding costs under the chosen ``tm``."""
     if tm is None:
-        return jnp.sum(landed)
-    ends = jnp.cumsum(landed)
-    visits = jnp.where(landed > 0,
-                       (ends + tm - 1) // tm - (ends - landed) // tm, 0)
-    return jnp.sum(visits) * tm
+        return jnp.sum(sizes[:-1])
+    return pallas_grouped.cut_visits(sizes, tm)[0] * tm
+
+
+def _weights_grad_tiles(sizes, m):
+    """``(row-tile visits, those a group's edge cuts)`` of ONE weight
+    gradient over the held groups at ``m`` sorted rows (traced int32; a
+    layer's two walk the same list): part of what a cut tile computes
+    is another group's rows, masked away. Zeros where the composed form
+    runs."""
+    tm = _weights_row_tile(m)
+    if tm is None:
+        return jnp.int32(0), jnp.int32(0)
+    return pallas_grouped.cut_visits(sizes, tm)
 
 
 def _tile_start(i, tile, rows):
@@ -993,7 +1055,8 @@ class HeldExpertsOp(Op):
     A training step counts on the device, in the op's state: rows by
     held expert, held experts that got a row, the row tiles its passes
     ran and the tiles that all the rows are, the rows the way back read
-    and the pairs there are, steps
+    and the pairs there are, the row-tile visits of a weight gradient
+    and those of them that a group's edge cuts, steps
     (``Executor.moe_counters()``; nothing is read inside a step)."""
 
     stateful = True
@@ -1013,8 +1076,8 @@ class HeldExpertsOp(Op):
         return {"moe_rows_by_expert": (input_shapes[3][0],),
                 "moe_expert_visits": (), "moe_row_tiles": (),
                 "moe_row_tiles_of": (), "moe_kernel_rows": (),
-                "moe_back_rows": (),
-                "moe_back_rows_of": (), "steps": ()}
+                "moe_back_rows": (), "moe_back_rows_of": (),
+                "moe_dw_tiles": (), "moe_dw_cut_tiles": (), "steps": ()}
 
     def compute(self, input_vals, ectx):
         x, weights, experts, w_gate_up, w_down = input_vals
@@ -1034,6 +1097,8 @@ class HeldExpertsOp(Op):
                 # the row tile follows the rows alone: given ``out`` or
                 # not, both of the forward's products walk the same
                 product = _product_tiles("forward", xs, w_gate_up.shape[-1])
+                # (the backward's weight gradients walk the same sizes)
+                dw_tiles, dw_cut = _weights_grad_tiles(sizes, rows)
                 ectx.put_state(self, {
                     "moe_rows_by_expert": state["moe_rows_by_expert"]
                     + landed,
@@ -1046,6 +1111,9 @@ class HeldExpertsOp(Op):
                     "moe_kernel_rows": state.get("moe_kernel_rows", 0)
                     + _kernel_rows(sizes, product and product[0]),
                     **_way_back_counted(state, sizes, back),
+                    "moe_dw_tiles": state.get("moe_dw_tiles", 0) + dw_tiles,
+                    "moe_dw_cut_tiles": state.get("moe_dw_cut_tiles", 0)
+                    + dw_cut,
                     "steps": state["steps"] + 1})
         return out.reshape(x.shape)
 
@@ -1121,8 +1189,10 @@ class _HeldExpertsGradientOp(Op):
         rows = back.shape[0]
         dweights = _moved(dw_row[:rows], order[:rows]).reshape(-1, k)
         dweights = jnp.where(held, dweights, 0.0).reshape(experts.shape)
-        dw_down = grouped_matmul_weights_grad(weighted, dys, sizes)
-        dw_gate_up = grouped_matmul_weights_grad(xs, dh, sizes)
+        # one visit list for the layer's two weight gradients
+        visits = _weights_grad_visits(sizes, order.shape[0])
+        dw_down = grouped_matmul_weights_grad(weighted, dys, sizes, visits)
+        dw_gate_up = grouped_matmul_weights_grad(xs, dh, sizes, visits)
         dxs = _into_fresh(grouped_matmul_rows_grad, dh, w_gate_up, sizes)
         dx = _token_sums(dxs, back, held, None, x.dtype)
         if kept is not None and ectx.get_state(fwd) is not None:

@@ -912,9 +912,13 @@ def test_expert_products_compile_at_the_sparse_decoders_cell(one_chip,
     (``moe._kernel_tiles``: a tile over the kernel's on-chip memory is
     refused here, off the chip): the forward kernel for gate|up and
     down, the same with its right side transposed
-    (``hetu_moe_experts_dx``) for ``da`` and ``dxs``, and megablox's
-    ``tgmm`` (``hetu_moe_experts_dw``) for the two weight gradients.
-    Each is ONE custom call under its stable name."""
+    (``hetu_moe_experts_dx``) for ``da`` and ``dxs``, and the repo's own
+    kernel (``ops/pallas_grouped.py``, ``hetu_moe_experts_dw``) for the
+    two weight gradients, at tiles whose blocks, two float32
+    accumulators among them, the kernel asks for itself
+    (``WEIGHTS_BLOCK_BYTES``). Each is ONE custom call under its stable
+    name; the weight gradient's visit list is compare-and-sum fusions:
+    no loop (``searchsorted``), no gather, no scatter beside the call."""
     from hetu_tpu.ops import moe
     rows, hidden, wide, held = EXPERT_CELLS[cell]
 
@@ -942,6 +946,9 @@ def test_expert_products_compile_at_the_sparse_decoders_cell(one_chip,
     for lhs, other, n, result in calls:
         tiles = moe._kernel_tiles(which, *lhs.shape, n, 2,
                                   which != "weights")
+        assert moe._block_bytes(which, *tiles, 2, which != "weights") <= (
+            moe.WEIGHTS_BLOCK_BYTES if which == "weights"
+            else moe.KERNEL_BLOCK_BYTES)
         if which == "forward":
             fn = moe._kernel(tiles, bf16, False)
             args = (lhs, other, sizes, sds((rows, n)))
@@ -954,6 +961,8 @@ def test_expert_products_compile_at_the_sparse_decoders_cell(one_chip,
         text = fn.lower(*args).compile().as_text()
         assert text.count('custom_call_target="tpu_custom_call"') == 1, tiles
         assert name in text and result in text
+        if which == "weights":
+            assert not re.search(r" (while|gather|scatter)\(", text)
 
 
 def _sparse_decoder_step_text(v5e_device, monkeypatch):
@@ -1274,15 +1283,22 @@ def test_sparse_decoder_step_walks_no_scalar_a_pair(v5e, monkeypatch):
     """The sparse decoder's whole training step: its gathers and
     scatters move rows (the token table's lookup, the way back's tiles).
     What is left of scalars walked one by one is megablox's own group
-    metadata before each grouped product (``make_group_metadata``: a
-    ``searchsorted`` and two scatter-adds over the row tiles and the
-    groups, 200 scalars at the cell's shapes); nothing walks a scalar a
-    (token, pick) pair or a token. On the parent the count of the pairs
-    was one: 49,152 scalars, 0.43 ms a layer on the chip."""
+    metadata before the forward's and the rows' grouped products
+    (``make_group_metadata``: a ``searchsorted`` and two scatter-adds
+    over the row tiles and the groups, 200 scalars at the cell's
+    shapes); nothing walks a scalar a (token, pick) pair or a token. On
+    PR 62's parent the count of the pairs was one: 49,152 scalars, 0.43
+    ms a layer on the chip. The weight gradients' visit list is none
+    (PR 64: compare-and-sum; with megablox's ``tgmm`` this one-layer
+    step held ten such instructions, five of them its metadata)."""
     text = _sparse_decoder_step_text(v5e[0], monkeypatch)
     walks = _scalar_walks(text)
     assert walks                        # the search has something to read
     assert max(n for _, n in walks) < 1024, walks
+    assert len(walks) <= 5, walks
+    for line in text.splitlines():
+        if "jit(hetu_moe_experts_dw)" in line:
+            assert not re.search(r" (while|gather|scatter)\(", line), line
 
 
 # ---------------------------------------------------------------------------

@@ -523,7 +523,7 @@ def test_a_router_without_a_bias_keeps_the_counters_it_had():
     assert sorted(shapes) == sorted([
         "moe_rows_by_expert", "moe_expert_visits", "moe_row_tiles",
         "moe_row_tiles_of", "moe_kernel_rows", "moe_back_rows",
-        "moe_back_rows_of", "steps"])
+        "moe_back_rows_of", "moe_dw_tiles", "moe_dw_cut_tiles", "steps"])
 
 
 # -- the share ties to the model ---------------------------------------------

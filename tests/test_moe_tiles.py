@@ -2,13 +2,16 @@
 _kernel_tiles``): arithmetic on a product's static shapes, held here to
 the shapes the benchmark's cells run, and the three kernels under the
 rule's tiles against the ragged products; the counter of the rows the
-row tiles compute (``moe_kernel_rows``)."""
+row tiles compute (``moe_kernel_rows``); and the weights' gradient's own
+kernel (``ops/pallas_grouped.py``) over the groupings that cut its row
+tiles every way, with the counters of its visits (``moe_dw_tiles``,
+``moe_dw_cut_tiles``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hetu_tpu.ops import attention, moe
+from hetu_tpu.ops import attention, moe, pallas_grouped
 
 
 def _layer(rows, hidden, gate_up):
@@ -47,6 +50,11 @@ PRODUCTS = [(f"{cell}-{i}", p) for cell, ps in TRAIN.items()
        for i, p in enumerate(_serving(rows, *widths))]
 
 
+def _budget(kind):
+    return moe.WEIGHTS_BLOCK_BYTES if kind == "weights" \
+        else moe.KERNEL_BLOCK_BYTES
+
+
 @pytest.mark.parametrize("name,product", PRODUCTS,
                          ids=[name for name, _ in PRODUCTS])
 def test_the_rule_gives_tiles_the_kernels_take(name, product):
@@ -56,8 +64,10 @@ def test_the_rule_gives_tiles_the_kernels_take(name, product):
     # a tile that covers k or n with a remainder, at a mask a step)
     assert tm % 128 == tk % 128 == tn % 128 == 0
     assert m % tm == k % tk == n % tn == 0
-    assert moe._block_bytes(kind, tm, tk, tn, 2, out) \
-        <= moe.KERNEL_BLOCK_BYTES < 16 * 2 ** 20
+    # megablox's kernels live in the 16 MiB a kernel gets unasked; the
+    # weights' gradient asks for its blocks' bytes itself
+    assert moe._block_bytes(kind, tm, tk, tn, 2, out) <= _budget(kind)
+    assert moe.KERNEL_BLOCK_BYTES < 16 * 2 ** 20 < moe.WEIGHTS_BLOCK_BYTES
     if m == 128:
         # a decode step: one row tile, blocks no longer than a side of
         # 1,024 (at sarvam's and trinity's widths the tiles PR 56 ran)
@@ -68,8 +78,10 @@ def test_the_rule_gives_tiles_the_kernels_take(name, product):
         assert tk == k, "an expert's weights once a column tile"
     if kind == "weights":
         # the two row blocks a step bring more operations a byte than
-        # the chip's ridge (197e12 / 819e9 = 240)
+        # the chip's ridge (197e12 / 819e9 = 240), and a row block is
+        # read at most twice a call (once: the result tile is whole)
         assert tk * tn / (tk + tn) > 240
+        assert (k // tk) * (n // tn) <= 2
     # static shapes in, the same tiles out: nothing measured, no store
     assert moe._kernel_tiles(kind, m, k, n, 2, out) == (tm, tk, tn)
 
@@ -88,8 +100,7 @@ def test_wider_operands_get_narrower_tiles():
                 ("rows", 256, 1024, 4096, False)]
     for kind, m, k, n, out in TRAIN["smallthinker"] + one_tile:
         tiles = moe._kernel_tiles(kind, m, k, n, 4, out)
-        assert moe._block_bytes(kind, *tiles, 4, out) \
-            <= moe.KERNEL_BLOCK_BYTES
+        assert moe._block_bytes(kind, *tiles, 4, out) <= _budget(kind)
     # ONE 256-row tile writing into ``out``: blocks of 1,024 a side are
     # 15.0 MiB at float32, so the column tile gives way
     assert moe._kernel_tiles(*one_tile[0][:4], 4, True) == (256, 1024, 512)
@@ -148,6 +159,8 @@ def test_row_products_equal_the_ragged_product(kernels, monkeypatch,
 
 def test_weights_product_equals_the_composed_form(kernels, monkeypatch):
     monkeypatch.setattr(moe, "KERNEL_BLOCK_BYTES", SMALL_BUDGET)
+    # (the one line PR 64 added: the kernel's budget is its own now)
+    monkeypatch.setattr(moe, "WEIGHTS_BLOCK_BYTES", SMALL_BUDGET)
     k, n = 256, 384
     lhs, _, sizes, here = _ragged_case(k, n)
     rng = np.random.RandomState(4)
@@ -161,6 +174,142 @@ def test_weights_product_equals_the_composed_form(kernels, monkeypatch):
     want = moe.grouped_matmul_weights_grad(lhs, jnp.asarray(dy), sizes)
     assert not np.asarray(got[0]).any()            # the empty group
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+
+# -- the weights' gradient's own kernel --------------------------------------
+
+# group sizes over row tiles of 256 (the last entry: the rows behind the
+# held groups, which hold NaN below)
+GROUPINGS = {
+    "groups_end_on_tile_edges": [256, 512, 256, 1024],
+    "several_groups_in_one_tile": [40, 50, 60, 70, 1828],
+    "a_group_inside_one_tile_mid_tile": [300, 100, 400, 1248],
+    "an_empty_group_first": [0, 300, 500, 1248],
+    "empty_groups_in_the_middle": [300, 0, 0, 500, 1248],
+    "an_empty_group_last": [300, 500, 0, 1248],
+    "every_group_empty": [0, 0, 0, 2048],
+    "one_group_holds_every_row": [2048, 0],
+    "the_extent_ends_mid_tile": [300, 410, 1338],
+    "the_extent_ends_on_a_tile_edge": [300, 468, 1280],
+    "m_of_one_tile": [100, 60, 96],
+    "m_of_one_tile_one_group": [256, 0],
+}
+# the four weight gradients of the two train cells at 1/8 of their rows,
+# the widths whole: (m, k, n, held)
+CELL_SHAPES = {
+    "smallthinker_gate_up": (6144, 2560, 1536, 16),
+    "smallthinker_down": (6144, 768, 2560, 16),
+    "lfm2_gate_up": (4096, 2048, 3584, 8),
+    "lfm2_down": (4096, 1792, 2048, 8),
+}
+
+
+def _by_group(lhs, dy, sizes):
+    """The product a group at a time, float32 in numpy (NaN rows behind
+    the extent are in no group)."""
+    ends = np.cumsum(sizes[:-1])
+    return np.stack([lhs[b - size:b].T @ dy[b - size:b]
+                     for size, b in zip(sizes[:-1], ends)])
+
+
+@pytest.mark.parametrize("case", list(GROUPINGS) + list(CELL_SHAPES))
+def test_the_weights_kernel_equals_the_composed_form(kernels, monkeypatch,
+                                                     case):
+    """The kernel under ``INTERPRET`` over every way a group's edge can
+    cut a row tile, with NaN in every row behind the held extent (the
+    result finite and equal: those rows are selected away). The small
+    cases against the composed form that the op keeps for widths off
+    whole lanes, under a budget that cuts the result into several (k,
+    column) tiles; the cells' shapes, bfloat16 operands under the cells'
+    own tiles, against the product a group at a time."""
+    rng = np.random.RandomState(len(case))
+    if case in GROUPINGS:
+        monkeypatch.setattr(moe, "WEIGHTS_BLOCK_BYTES", 1_400_000)
+        sizes, k, n, dtype = GROUPINGS[case], 256, 384, jnp.float32
+    else:
+        m, k, n, held = CELL_SHAPES[case]
+        # ragged groups of 100-400 rows, as a step's routing leaves them
+        landed = rng.randint(100, 400, size=held)
+        landed[rng.randint(held)] = 0
+        sizes, dtype = [*landed, m - landed.sum()], jnp.bfloat16
+    m, here = sum(sizes), sum(sizes[:-1])
+    lhs = rng.randn(m, k).astype(np.float32)
+    dy = rng.randn(m, n).astype(np.float32)
+    lhs[here:] = dy[here:] = np.nan
+    lhs, dy = jnp.asarray(lhs, dtype), jnp.asarray(dy, dtype)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    tiles = moe._kernel_tiles("weights", m, k, n, lhs.dtype.itemsize)
+    got = np.asarray(moe.grouped_matmul_weights_grad(lhs, dy, sizes))
+    assert got.shape == (len(sizes) - 1, k, n) and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    if case in GROUPINGS:
+        assert (k // tiles[1]) * (n // tiles[2]) > 1
+        monkeypatch.setattr(moe, "INTERPRET", False)
+        monkeypatch.setattr(attention, "_use_pallas", lambda: False)
+        want = np.asarray(moe.grouped_matmul_weights_grad(lhs, dy, sizes))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    else:
+        assert tiles == moe._kernel_tiles("weights", m * 8, k, n, 2)
+        want = _by_group(np.asarray(lhs.astype(jnp.float32)),
+                         np.asarray(dy.astype(jnp.float32)),
+                         np.asarray(sizes))
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+    for g, size in enumerate(np.asarray(sizes)[:-1]):
+        assert size or not got[g].any()         # an empty group: zeros
+
+
+def test_a_layers_two_weight_gradients_share_one_visit_list(kernels):
+    """The visit list follows the sizes and the rows alone: made once,
+    it serves both products of a layer, and a product given none makes
+    the same."""
+    rng = np.random.RandomState(8)
+    sizes = jnp.asarray(GROUPINGS["empty_groups_in_the_middle"], jnp.int32)
+    m = int(sizes.sum())
+    visits = moe._weights_grad_visits(sizes, m)
+    for k, n in ((256, 128), (128, 384)):
+        lhs = jnp.asarray(rng.randn(m, k), jnp.float32)
+        dy = jnp.asarray(rng.randn(m, n), jnp.float32)
+        np.testing.assert_array_equal(
+            moe.grouped_matmul_weights_grad(lhs, dy, sizes, visits),
+            moe.grouped_matmul_weights_grad(lhs, dy, sizes))
+
+
+def _count_tiles(sizes, tm):
+    """``(visits, cut)`` by walking every group's tiles in numpy."""
+    visits = cut = 0
+    start = 0
+    for size in sizes[:-1]:
+        end = start + size
+        for tile in range(start // tm, -(-end // tm) if size else 0):
+            visits += 1
+            cut += tile * tm < start or (tile + 1) * tm > end
+        start = end
+    return visits, cut
+
+
+@pytest.mark.parametrize("case", list(GROUPINGS))
+@pytest.mark.parametrize("tm", [128, 256])
+def test_dw_tiles_count_the_visits_and_those_an_edge_cuts(case, tm):
+    """``moe_dw_tiles`` / ``moe_dw_cut_tiles`` (``pallas_grouped.
+    cut_visits``) against a walk in numpy on the same sizes; and the
+    kernel's visit list holds exactly those visits and one for every
+    empty group."""
+    sizes = GROUPINGS[case]
+    m = sum(sizes)
+    got = pallas_grouped.cut_visits(jnp.asarray(sizes, jnp.int32), tm)
+    want = _count_tiles(sizes, tm)
+    assert tuple(int(x) for x in got) == want
+    group, tile, offsets, count = (np.asarray(a) for a in
+                                   pallas_grouped.visits(
+                                       jnp.asarray(sizes, jnp.int32), m, tm))
+    empty = sum(1 for size in sizes[:-1] if size == 0)
+    assert int(count) == want[0] + empty <= len(group)
+    starts = np.cumsum([0] + sizes[:-1])
+    assert offsets.tolist() == starts.tolist()
+    walked = [(g, t) for g, size in enumerate(sizes[:-1])
+              for t in (range(starts[g] // tm, -(-(starts[g] + size) // tm))
+                        if size else [min(starts[g] // tm, m // tm - 1)])]
+    assert list(zip(group[:count].tolist(), tile[:count].tolist())) == walked
 
 
 # -- the counter -------------------------------------------------------------

@@ -975,6 +975,8 @@ def test_the_step_counts_rows_and_visits_on_the_device(monkeypatch):
         assert 0 < c["moe_row_tiles"] < c["moe_row_tiles_of"]
         # the ragged product computes the rows that landed and no other
         assert c["moe_kernel_rows"] == c["moe_routed_rows"]
+        # and the composed weight gradient visits no row tile
+        assert c["moe_dw_tiles"] == c["moe_dw_cut_tiles"] == 0
         # the way back reads the held pairs' rows, once a direction
         assert c["moe_back_rows"] == 2 * 3 * sum(by_hand)
         assert c["moe_back_rows_of"] == 2 * 3 * 192
@@ -1033,6 +1035,14 @@ def test_the_op_counts_the_rows_its_row_tiles_compute(kernels):
     visits = sum(-(-end // tm) - (end - n) // tm
                  for end, n in zip(ends, landed) if n)
     assert int(counted["moe_kernel_rows"]) == visits * tm > sum(landed)
+    # a weight gradient walks the same visits; a group's edge cuts those
+    # that do not lie wholly inside their group
+    assert int(counted["moe_dw_tiles"]) == visits
+    starts = ends - landed
+    cut = sum(min(-(-end // tm) - start // tm,
+                  int(start % tm != 0) + int(end % tm != 0))
+              for start, end, n in zip(starts, ends, landed) if n)
+    assert int(counted["moe_dw_cut_tiles"]) == cut > 0
 
 
 def _hlo_computations(text):
